@@ -110,6 +110,7 @@ A_BULK_SHARD = "indices:data/write/bulk[s]"
 A_GET = "indices:data/read/get[s]"
 A_TERMVECTOR = "indices:data/read/termvector[s]"
 A_QUERY_PHASE = "indices:data/read/search[phase/query]"
+A_QUERY_PROGRESS = "indices:data/read/search[phase/query/progress]"
 A_FETCH_PHASE = "indices:data/read/search[phase/fetch]"
 A_FREE_CONTEXT = "indices:data/read/search[free-context]"
 A_DFS_PHASE = "indices:data/read/search[phase/dfs]"
@@ -227,6 +228,9 @@ class ActionModule:
         t.register_handler(A_GET, self._s_get, executor="get")
         t.register_handler(A_TERMVECTOR, self._s_termvector, executor="get")
         t.register_handler(A_QUERY_PHASE, self._s_query_phase, executor="search")
+        # not on the search pool: it is asked about a node whose searches are late
+        t.register_handler(A_QUERY_PROGRESS, self._s_query_progress,
+                           executor="management")
         t.register_handler(A_FETCH_PHASE, self._s_fetch_phase, executor="search")
         t.register_handler(A_FREE_CONTEXT, self._s_free_context, executor="search")
         t.register_handler(A_DFS_PHASE, self._s_dfs_phase, executor="search")
@@ -1580,7 +1584,7 @@ class ActionModule:
                                             preference, affinity=affinity)
 
         # co-located shards + flat query → one SPMD program over the device mesh
-        # (DFS psum + all_gather top-k on ICI) instead of per-shard RPC scatter-gather;
+        # (host-summed DFS stats + all_gather top-k on ICI) instead of per-shard RPC scatter-gather;
         # None = ineligible or failed, fall through to the transport path unchanged
         mesh_results = self.mesh_serving.try_search(
             state, self.node.local_node.id, indices, alias_filters, shards, req,
@@ -1674,7 +1678,8 @@ class ActionModule:
         max_chain = max((getattr(f, "max_attempts", 1) for f in query_futs
                          if f is not None), default=1)
         backstop = deadline.clamp(
-            self.QUERY_ATTEMPT_TIMEOUT * max(1, max_chain))
+            (self.QUERY_ATTEMPT_TIMEOUT + self.QUERY_PROGRESS_TIMEOUT)
+            * (max(1, max_chain) + self.QUERY_ATTEMPT_EXTENSIONS))
         collect_by = time.monotonic() + backstop + 5.0
         for ordinal, (copy, fut) in enumerate(zip(shards, query_futs)):
             if fut is None:
@@ -1809,7 +1814,20 @@ class ActionModule:
                 return s.index
         return None
 
+    # The failover timer for a WEDGED copy (a merely slow one is the hedge's
+    # job — adaptive routing). A cold device is neither: it compiles every
+    # (segment size, bucket) program a request meets ON THE QUERY PATH, up to
+    # ~18 s each on a v5e, and the first search over an 11-segment index took
+    # 88.5 s there (PR 22) — this timer alone failed that healthy shard at 60 s.
+    # So when it runs out the coordinator asks the copy's node
+    # (A_QUERY_PROGRESS) and waits another window while the node's last
+    # compile is younger than one: at most QUERY_ATTEMPT_EXTENSIONS more per
+    # chain, 600 s in all, the timeout a client should give a cold server. A
+    # copy whose node compiles nothing, or does not answer within
+    # QUERY_PROGRESS_TIMEOUT, fails over after one window as before.
     QUERY_ATTEMPT_TIMEOUT = 60.0
+    QUERY_ATTEMPT_EXTENSIONS = 9
+    QUERY_PROGRESS_TIMEOUT = 5.0
 
     def _dfs_shard_result(self, state, copy: ShardRouting, body, first_fut,
                           deadline: Deadline = NO_DEADLINE):
@@ -1906,6 +1924,7 @@ class ActionModule:
         chain_lock = threading.Lock()
         launched: set[int] = set()
         in_flight = [0]
+        extensions_left = [self.QUERY_ATTEMPT_EXTENSIONS]  # the chain's, not an attempt's
 
         def resolve(result, node, err) -> bool:
             return complete_fut(done, (result, node, err))
@@ -2017,7 +2036,7 @@ class ActionModule:
                     settled[0] = True
                     return True
 
-            def on_timeout():
+            def fail_over():
                 if selector is not None and settle():
                     selector.end_attempt(candidate)
                     selector.failure(candidate)
@@ -2026,8 +2045,48 @@ class ActionModule:
                         f"query phase attempt to [{candidate.node_id}] timed out")
                     attempt_failed(candidate, err, hedge)
 
-            timer = self.node.threadpool.schedule(
-                deadline.clamp(self.QUERY_ATTEMPT_TIMEOUT), "generic", on_timeout)
+            def on_timeout():
+                # a node that is compiling is busy, not wedged: ask it before
+                # failing its copy over (QUERY_ATTEMPT_TIMEOUT's comment)
+                with chain_lock:
+                    ask = extensions_left[0] > 0 and not deadline.expired() \
+                        and not cancelled.is_set() and not done.done()
+                    if ask:
+                        extensions_left[0] -= 1
+                if not ask:
+                    fail_over()
+                    return
+                self.transport.send_request(
+                    node, A_QUERY_PROGRESS, {},
+                    timeout=self.QUERY_PROGRESS_TIMEOUT,
+                ).add_done_callback(on_progress)
+
+            def on_progress(f):
+                idle = None if f.exception() is not None else \
+                    (f.result() or {}).get("compile_idle_s")
+                if idle is None or idle >= self.QUERY_ATTEMPT_TIMEOUT \
+                        or not arm_timer():
+                    fail_over()
+                    return
+                self.logger.info(
+                    "query phase attempt to [%s] for [%s][%d] is late and its "
+                    "node compiled %.1f s ago: waiting another window",
+                    candidate.node_id, candidate.index, candidate.shard_id, idle)
+
+            timer = [None]
+
+            def arm_timer() -> bool:
+                """False once the response consumed the attempt: no timer
+                outlives it (on_done cancels the one armed last)."""
+                with consumed_lock:
+                    if consumed[0]:
+                        return False
+                    timer[0] = self.node.threadpool.schedule(
+                        deadline.clamp(self.QUERY_ATTEMPT_TIMEOUT), "generic",
+                        on_timeout)
+                    return True
+
+            arm_timer()
 
             if allow_hedge and not hedge and selector is not None:
                 with chain_lock:
@@ -2079,7 +2138,7 @@ class ActionModule:
                                          load=r0.get("load")
                                          if isinstance(r0, dict) else None)
                     return
-                timer.cancel()
+                timer[0].cancel()
                 if err0 is not None:
                     # ANY per-attempt failure fails over to the next copy —
                     # including transport errors to a node that died after
@@ -2161,6 +2220,13 @@ class ActionModule:
         if v is not None and v[1] == index and v[2] == shard_id:
             return v[3]
         return None
+
+    def _s_query_progress(self, request, channel):
+        """What a coordinator's attempt timer asks before it fails a late copy
+        over: is this node compiling (QUERY_ATTEMPT_TIMEOUT's comment)."""
+        from .common.jaxenv import seconds_since_compile
+
+        return {"compile_idle_s": seconds_since_compile()}
 
     def _s_free_context(self, request, channel):
         """ES's free-context: the coordinator releases pinned searchers of shards
@@ -2559,10 +2625,14 @@ class ActionModule:
                         "index": index, "shard": copy.shard_id, "op": op,
                         **(extra or {}),
                     }))
+        # a force-merge runs as long as the merge takes (the reference's
+        # _optimize waits for it too): a timeout here would report a shard
+        # `failed` while its merge goes on and completes
+        wait = None if op == "optimize" else 30.0
         ok = 0
         for fut in futs:
             try:
-                fut_result(fut, 30.0)
+                fut_result(fut, wait)
                 ok += 1
             except SearchEngineError:
                 pass

@@ -30,9 +30,9 @@ off-path answer (ROADMAP item 5), three legs sharing one registry:
     persist to `<path.data>/compile_manifest.json` (atomic rename) on warm
     cycles and node close; a restarted node loads the manifest and its startup
     warm cycle replays exactly what production ran. Paired with the persistent
-    XLA compilation cache (jaxenv.enable_persistent_compile_cache under
-    `path.data`), the restart warm pays a disk deserialize, not a fleet
-    recompile. NOTE: a persistent-cache HIT still emits a
+    XLA compilation cache (jaxenv.enable_persistent_compile_cache: at
+    JAX_COMPILATION_CACHE_DIR where set, else `<checkout>/.jax_cache`), the
+    restart warm pays a disk deserialize, not a fleet recompile. NOTE: a persistent-cache HIT still emits a
     backend_compile_duration event (pxla wraps compile_or_get_cached), so the
     manifest replay — not the disk cache — is what buys the serving path its
     zero-event steady state.
@@ -427,7 +427,7 @@ class CompileWarmRegistry:
     # -- wiring ---------------------------------------------------------------
     def configure(self, settings, data_path: str | None) -> None:
         """Node-boot hook: read knobs, load this path's manifest, arm the
-        persistent XLA compilation cache under path.data."""
+        persistent XLA compilation cache (placed by jaxenv's one rule)."""
         self.enabled = bool(settings.get_bool("node.compile_warming.enabled",
                                               True))
         self.persist = bool(settings.get_bool("node.compile_warming.persist",
@@ -447,8 +447,7 @@ class CompileWarmRegistry:
         if settings.get_bool("node.compile_cache.persist", True):
             from . import jaxenv
 
-            jaxenv.enable_persistent_compile_cache(
-                os.path.join(data_path, "jax_cache"))
+            jaxenv.enable_persistent_compile_cache()
         from . import jaxenv
 
         jaxenv.register_compile_observer(self._on_compile_event)
@@ -681,9 +680,12 @@ class CompileWarmRegistry:
 
     # -- observability ---------------------------------------------------------
     def stats(self) -> dict:
+        from . import jaxenv
+
         with self._lock:
             return {
                 "enabled": self.enabled,
+                "persistent_cache_dir": jaxenv.armed_compile_cache_dir(),
                 "specs": len(self._specs),
                 "specs_recorded": self.specs_recorded,
                 "specs_loaded": self.specs_loaded,

@@ -9,8 +9,7 @@ unseeded RNG) steers the trace, processes enqueue DIFFERENT collective launch
 sequences — the mesh deadlocks on the first mismatched collective, with no
 error message, on hardware only. Under `ESTPU_MESHTRACE=1`:
 
-- `shard_map` (jax.shard_map and jax.experimental.shard_map.shard_map) is
-  wrapped so each traced mesh program records its collective launch sequence:
+- `jax.shard_map` is wrapped so each traced mesh program records its collective launch sequence:
   every patched `jax.lax` collective (psum/pmax/pmin/pmean/all_gather/
   all_to_all/ppermute/psum_scatter/axis_index) appends a
   (primitive, axis, shape, call site) entry while the program body is being
@@ -340,26 +339,20 @@ def _wrap_collective(lax_mod, name: str) -> None:
 
 def install() -> MeshTracer:
     """Arm the tracer (idempotent). Prefer maybe_install() — the env knob.
-    Must run after jax is importable; patches jax.lax collectives plus every
-    public shard_map entry point. The wrappers carry functools.wraps, so
-    signature sniffing (mesh_search probes shard_map for check_vma) still
-    resolves through __wrapped__."""
+    Must run after jax is importable; patches jax.lax collectives plus
+    jax.shard_map, the one shard_map entry point the package uses."""
     global _REAL_SHARD_MAP
     if TRACER.enabled:
         return TRACER
     import jax
-    from jax.experimental import shard_map as sm_mod
 
     for name in COLLECTIVES:
         _wrap_collective(jax.lax, name)
 
-    real = getattr(jax, "shard_map", None) or sm_mod.shard_map
+    real = jax.shard_map
     if not getattr(real, "_estpu_meshtrace", False):
         _REAL_SHARD_MAP = real
-        patched = _wrap_shard_map(real)
-        if getattr(jax, "shard_map", None) is not None:
-            jax.shard_map = patched
-        sm_mod.shard_map = patched
+        jax.shard_map = _wrap_shard_map(real)
     TRACER.enabled = True
     return TRACER
 

@@ -126,7 +126,13 @@ class HttpServer:
             def log_message(self, fmt, *args):  # quiet
                 pass
 
-        self._server = ThreadingHTTPServer((host, port), Handler)
+        class Server(ThreadingHTTPServer):
+            # socketserver's default accept backlog is 5: a burst of 16
+            # connections at once (chip_smoke.py's, PR 22) was reset by the
+            # kernel before a handler thread ever saw it
+            request_queue_size = 128
+
+        self._server = Server((host, port), Handler)
         self.port = self._server.server_port
         self.host = host
         self._thread: threading.Thread | None = None

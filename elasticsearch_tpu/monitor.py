@@ -113,7 +113,8 @@ def runtime_stats() -> dict:
         devices = jax.devices()
         dev_stats = []
         for d in devices:
-            entry = {"platform": d.platform, "device": str(d)}
+            entry = {"platform": d.platform, "device_kind": d.device_kind,
+                     "device": str(d)}
             ms = getattr(d, "memory_stats", None)
             if callable(ms):
                 try:
@@ -126,6 +127,10 @@ def runtime_stats() -> dict:
         out["devices"] = dev_stats
     except Exception:  # noqa: BLE001 — no device backend in this process
         out["devices"] = []
+    out["device_count"] = len(out["devices"])
+    from . import native
+
+    out["native"] = native.implementation()
     return out
 
 

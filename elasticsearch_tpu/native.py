@@ -46,3 +46,12 @@ def get_native():
         # avoid shadowing other modules named "build"
         sys.modules.pop("build", None)
     return _NATIVE
+
+
+def implementation() -> str:
+    """Which implementation serves the native entry points in this process
+    (nodes stats `runtime.native`): the C extension, the pure-Python fallbacks,
+    or "not_loaded" before the first caller asked — a stats read never builds."""
+    if not _TRIED:
+        return "not_loaded"
+    return "c_extension" if _NATIVE is not None else "python_fallback"

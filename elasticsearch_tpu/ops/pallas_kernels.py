@@ -24,13 +24,13 @@ merge, bool semantics, `lax.top_k`) and writes only the [k] winners. The full
 traffic is one streaming read of the touched postings (6 B/posting quantized)
 plus [Qb, k] results.
 
-Opt-in, TPU-only: scoring.py uses it when ESTPU_PALLAS=1 AND the backend is a
-TPU (pending on-silicon benchmarking before any default flips).
-ESTPU_PALLAS=interpret forces the kernel in interpret mode on any backend —
+Opt-in: ESTPU_PALLAS=1 asks for the COMPILED kernel and nothing else — off a
+TPU, or where the compiler refuses it, the launch raises; it never interprets
+quietly (pending on-silicon benchmarking before any default flips).
+ESTPU_PALLAS=interpret is the one way to interpret it, on any backend —
 bitwise-identical semantics BY CONSTRUCTION (the final phase executes the same
 sparse_reduce the composed path runs), which is how the parity suite exercises
-it on the CPU test mesh; interpret mode is orders of magnitude slower, so it
-never engages implicitly.
+it on the CPU test mesh; interpret mode is orders of magnitude slower.
 """
 
 from __future__ import annotations
@@ -44,22 +44,30 @@ from .device_index import BLOCK, TFN_BM25
 
 
 def estpu_pallas_enabled() -> bool:
-    """ESTPU_PALLAS=1 → only on a real TPU backend (interpret-mode Pallas on the
-    serving path would be a silent orders-of-magnitude regression);
-    ESTPU_PALLAS=interpret → force anywhere (tests/dev)."""
+    """ESTPU_PALLAS=1 → the compiled kernel (raises off a TPU: interpret-mode
+    Pallas on the serving path would be a silent orders-of-magnitude
+    regression); ESTPU_PALLAS=interpret → the interpreted kernel, anywhere
+    (tests/dev); anything else → off."""
     flag = os.environ.get("ESTPU_PALLAS", "0")
     if flag == "interpret":
         return True
-    return flag == "1" and _is_tpu()
+    if flag != "1":
+        return False
+    _require_tpu()
+    return True
 
 
-def _is_tpu() -> bool:
+def _require_tpu() -> None:
+    """The compiled kernel needs a TPU. A backend that fails to initialise
+    raises from jax.devices() itself; any other platform raises here."""
     import jax
 
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # noqa: BLE001 — backend probe failure → interpret mode
-        return False
+    platform = jax.devices()[0].platform
+    if platform != "tpu":
+        raise RuntimeError(
+            "ESTPU_PALLAS=1 asks for the compiled Pallas kernel, which needs a "
+            f"TPU (jax reports {platform!r}); ESTPU_PALLAS=interpret is the only "
+            "way to interpret it")
 
 
 def _sparse_score_kernel(qblk_s, qfid_s, qmode_s, n_must_s, msm_s,  # SMEM prefetch
@@ -184,8 +192,11 @@ def sparse_score(qblk, qw, qconst, qcnt, qfid, qmode, n_must, msm, coord,
     import jax.numpy as jnp
 
     # ESTPU_PALLAS=interpret forces interpretation EVERYWHERE (incl. on TPU —
-    # that's the escape hatch for comparing interpreted vs compiled output)
-    interpret = (os.environ.get("ESTPU_PALLAS") == "interpret") or not _is_tpu()
+    # that's the escape hatch for comparing interpreted vs compiled output);
+    # nothing else interprets
+    interpret = os.environ.get("ESTPU_PALLAS") == "interpret"
+    if not interpret:
+        _require_tpu()
     scores, docs, totals = _sparse_score_call(
         jnp.asarray(qblk, jnp.int32), jnp.asarray(qw, jnp.float32),
         jnp.asarray(qconst).astype(jnp.int32),

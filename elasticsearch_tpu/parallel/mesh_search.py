@@ -2,10 +2,14 @@
 
 This is the TPU-native replacement for the reference's coordinator-loop scatter/gather
 (SURVEY.md §5.8): an N-shard index maps 1:1 onto an N-device mesh axis "shards", and the
-three distributed phases of a search become collectives INSIDE one jitted program:
+query and merge phases of a search become ONE jitted program with collectives inside:
 
-  DFS phase      → df/maxDoc/sumTTF psum over the shards axis
-                   (ref: DfsPhase + SearchPhaseController.aggregateDfs — an all-reduce)
+  DFS phase      → df/maxDoc/sumTTF summed over the shards ON THE HOST, which assembles
+                   every shard's clause table anyway (ref: DfsPhase +
+                   SearchPhaseController.aggregateDfs). idf and the BM25 norm cache come
+                   from the similarity classes in f64-then-f32, the numbers the
+                   single-shard path feeds its kernel: an f32 log on the device is not
+                   the host's (MeshSearchExecutor._clause_weights)
   query phase    → per-shard fused scoring (same math as ops/scoring.py)
   top-k merge    → all_gather of per-shard top-k, then a second lax.top_k
                    (ref: SearchPhaseController.sortDocs — the coordinator merge)
@@ -33,7 +37,7 @@ from dataclasses import dataclass, field as dc_field
 import numpy as np
 
 from ..common.jaxenv import compile_tag
-from ..common.smallfloat import jnp_doclen_table, jnp_norm_table
+from ..common.smallfloat import jnp_norm_table
 from ..index.engine import Searcher
 from ..ops.device_index import (
     _TF_DTYPE,
@@ -150,8 +154,8 @@ class ShardedIndex:
     live: object  # [S, Dpad] bool
     shard_term_blocks: list  # per shard: (field, term) -> (blk_start, blk_end)
     shard_term_df: list  # per shard: (field, term) -> df
-    max_doc: np.ndarray  # [S] int32 (host; also fed to psum)
-    sum_ttf: np.ndarray  # [S, F] f32
+    max_doc: np.ndarray  # [S] int64 (host: statistics never reach the device)
+    sum_ttf: np.ndarray  # [S, F] int64 (host)
     mesh: object = None
     # fused-agg state (built lazily by mesh_serving, lives and dies with this
     # packed generation): per-FIELD host rows so overlapping field sets never
@@ -194,8 +198,8 @@ def build_sharded_index(searchers: list[Searcher], fields: list[str],
     live = np.zeros((S, doc_pad), dtype=bool)
     shard_term_blocks = []
     shard_term_df = []
-    max_doc = np.zeros(S, dtype=np.int32)
-    sum_ttf = np.zeros((S, len(fields)), dtype=np.float32)
+    max_doc = np.zeros(S, dtype=np.int64)
+    sum_ttf = np.zeros((S, len(fields)), dtype=np.int64)
 
     for si, c in enumerate(csrs):
         counts = np.diff(c.post_offsets)
@@ -309,7 +313,6 @@ def ensure_mesh_agg_stack(index: ShardedIndex, fields: tuple):
 
 
 def _mesh_score_program(k: int, n_queries: int, doc_pad: int, similarity_kind: int,
-                        k1: float, b: float, use_global_stats: bool = True,
                         use_filter: bool = False, use_aggs: bool = False,
                         use_post: bool = False, use_min_score: bool = False,
                         use_sort: bool = False, sort_desc: bool = False,
@@ -317,9 +320,10 @@ def _mesh_score_program(k: int, n_queries: int, doc_pad: int, similarity_kind: i
                         bucket_specs: tuple = ()):
     """Returns the shard_map-able function (static shapes closed over).
 
-    use_global_stats=True is dfs_query_then_fetch (term stats psum'd over the shards
-    axis — the DFS all-reduce); False is plain query_then_fetch (each shard weighs
-    with its local stats, exactly like the reference's per-shard IndexSearcher).
+    Term statistics never enter the program: each shard's slice of `weight_c`
+    and `norm_cache` carries them, resolved on the host from index-wide
+    (dfs_query_then_fetch) or shard-local (query_then_fetch) stats, so both
+    search types run the SAME executable.
     use_filter adds per-shard FilteredQuery masks; use_aggs adds fused metric-agg
     stats (device_index.agg_doc_rows folds reduced under the match mask, gathered
     per shard — the SPMD embodiment of the reference's per-shard agg collect +
@@ -346,14 +350,12 @@ def _mesh_score_program(k: int, n_queries: int, doc_pad: int, similarity_kind: i
     import jax.numpy as jnp
 
     # device-side byte315 decode (common/smallfloat.py): norms stay 1 B/doc
-    # into the program; these 1 KB tables fold as compile-time constants
-    DL_TABLE = jnp_doclen_table()
+    # into the program; this 1 KB table folds as a compile-time constant
     NORM_DECODE = jnp_norm_table()
 
     def program(blk_docs, blk_tf, norms, live,  # local shard slices [1, ...]
                 qidx, blk, clause_id, fidx, group, tfmode,  # entries [1, M]
-                df_local, boost, clause_qidx, clause_scoring,  # clauses [1?, C]
-                max_doc_local, sum_ttf_local,  # [1], [1, F]
+                weight_c, norm_cache,  # host-resolved stats [1, C], [1, F, 256]
                 n_must, msm, coord,  # per query [Qd], [Qd], [Qd, C+1]
                 *extra):  # optional inputs gated by the use_* flags, in order:
         # filter_masks [1, Qd, Dpad] | agg_rows [1, F, 5, Dpad] |
@@ -382,34 +384,10 @@ def _mesh_score_program(k: int, n_queries: int, doc_pad: int, similarity_kind: i
         live_l = live[0]
         qidx, blk, clause_id = qidx[0], blk[0], clause_id[0]
         fidx, group, tfmode = fidx[0], group[0], tfmode[0]
-        df_local = df_local[0]
-
-        if use_global_stats:
-            # ---- DFS phase: global stats as collectives over the shards axis ----
-            df_g = jax.lax.psum(df_local.astype(jnp.float32), "shards")  # [C]
-            N = jax.lax.psum(max_doc_local[0].astype(jnp.float32), "shards")  # scalar
-            ttf_g = jax.lax.psum(sum_ttf_local[0], "shards")  # [F]
-        else:
-            df_g = df_local.astype(jnp.float32)
-            N = max_doc_local[0].astype(jnp.float32)
-            ttf_g = sum_ttf_local[0]
-
-        if similarity_kind == 0:  # BM25
-            idf = jnp.log(1.0 + (N - df_g + 0.5) / (df_g + 0.5))
-            weight_c = idf * boost * jnp.float32(k1 + 1.0)
-            qn_per_query = jnp.ones(n_queries, jnp.float32)
-        else:  # TF-IDF
-            idf = 1.0 + jnp.log(N / (df_g + 1.0))
-            w_unnorm = idf * boost
-            ssw = jnp.zeros(n_queries, jnp.float32).at[clause_qidx].add(
-                jnp.where(clause_scoring & (df_g > 0), w_unnorm * w_unnorm, 0.0))
-            qn_per_query = jnp.where(ssw > 0, 1.0 / jnp.sqrt(ssw), 1.0)
-            weight_c = idf * idf * boost
-        weight_c = jnp.where(df_g > 0, weight_c, 0.0)
-
-        # per-field norm caches from global stats
-        avgdl = jnp.where(ttf_g > 0, ttf_g / jnp.maximum(N, 1.0), 1.0)  # [F]
-        bm25_cache = jnp.float32(k1) * (1.0 - b + b * DL_TABLE[None, :] / avgdl[:, None])
+        # per-clause weight (idf x boost x (k1+1); TF-IDF: idf^2 x boost x
+        # queryNorm; 0 where df == 0) and the per-field BM25 norm cache
+        weight_c = weight_c[0]
+        norm_cache = norm_cache[0]
 
         # ---- query phase: fused scoring (same pipeline as ops/scoring.py) ----
         docs = blk_docs[blk]  # [M, B]
@@ -417,14 +395,11 @@ def _mesh_score_program(k: int, n_queries: int, doc_pad: int, similarity_kind: i
         valid = docs < doc_pad
         docs_safe = jnp.where(valid, docs, 0)
         nb = norms_l[fidx[:, None], docs_safe].astype(jnp.int32)
-        w = weight_c[clause_id]  # [M]
-        if similarity_kind == 1:
-            w = w * qn_per_query[qidx]
-        w = w[:, None]
+        w = weight_c[clause_id][:, None]  # [M, 1]
         # tf factor first, then weight — the rounding order every other scorer uses
         # (ops/device_index.tfn_values, HostScorer._term_scores)
         if similarity_kind == 0:
-            cache_vals = bm25_cache[fidx[:, None], nb]
+            cache_vals = norm_cache[fidx[:, None], nb]
             contrib = w * (freqs / (freqs + cache_vals))
         else:
             contrib = w * (jnp.sqrt(freqs) * NORM_DECODE[nb])
@@ -583,13 +558,69 @@ class MeshSearchExecutor:
     (query-batch data parallelism)."""
 
     def __init__(self, index: ShardedIndex, mesh, similarity="BM25",
-                 k1: float = 1.2, b: float = 0.75, use_global_stats: bool = True):
+                 k1: float = 1.2, b: float = 0.75, use_global_stats: bool = True,
+                 compiled: dict | None = None):
         self.index = index
         self.mesh = mesh
         self.similarity_kind = 0 if str(similarity).upper() == "BM25" else 1
         self.k1, self.b = k1, b
         self.use_global_stats = use_global_stats
-        self._compiled: dict = {}
+        # statistics are inputs, not program structure: executors over one
+        # ShardedIndex that differ only in use_global_stats share executables
+        self._compiled: dict = {} if compiled is None else compiled
+        self._norm_cache = self._norm_caches()
+
+    # -- host-side statistics (the DFS phase) -------------------------------
+    def _norm_caches(self) -> np.ndarray:
+        """[S, F, 256] f32 BM25 norm cache per shard and field, from index-wide
+        or shard-local sumTTF/maxDoc — BM25Similarity.norm_cache itself, so a
+        mesh score decodes norms with the single-shard path's table. Fixed for
+        the life of the packed generation."""
+        from types import SimpleNamespace
+
+        idx = self.index
+        out = np.ones((idx.n_shards, max(len(idx.fields), 1), 256), np.float32)
+        if self.similarity_kind != 0:
+            return out  # TF-IDF decodes norms with a constant table in-program
+        sim = BM25Similarity(self.k1, self.b)
+        max_doc, sum_ttf = idx.max_doc, idx.sum_ttf
+        if self.use_global_stats:
+            max_doc = np.full_like(max_doc, max_doc.sum())
+            sum_ttf = np.broadcast_to(sum_ttf.sum(axis=0), sum_ttf.shape)
+        for si, (n, ttfs) in enumerate(zip(max_doc.tolist(), sum_ttf.tolist())):
+            for fi, ttf in enumerate(ttfs):
+                out[si, fi] = sim.norm_cache(SimpleNamespace(sum_ttf=ttf), n)
+        return out
+
+    def _clause_weights(self, df_local: np.ndarray, boost: np.ndarray,
+                        clause_qidx: np.ndarray, clause_scoring: np.ndarray,
+                        n_queries: int) -> np.ndarray:
+        """[S, C] f32 clause weights from index-wide (DFS: df and maxDoc summed
+        over the shards) or shard-local statistics. idf is the similarity
+        classes' own (f64 log, then f32 — Lucene's order), the product is f32
+        in finalize_flat's order: what the single-shard kernel is fed."""
+        max_doc = self.index.max_doc
+        if self.use_global_stats:
+            df = np.broadcast_to(df_local.sum(axis=0), df_local.shape)
+            max_doc = np.full_like(max_doc, max_doc.sum())
+        else:
+            df = df_local
+        idf_of = (BM25Similarity.idf if self.similarity_kind == 0
+                  else TFIDFSimilarity.idf)
+        idf = np.zeros(df.shape, np.float32)
+        for si, (n, dfs) in enumerate(zip(max_doc.tolist(), df.tolist())):
+            idf[si] = [idf_of(d, n) if d > 0 else 0.0 for d in dfs]
+        if self.similarity_kind == 0:
+            return idf * boost[None, :] * np.float32(self.k1 + 1.0)
+        # TF-IDF: queryNorm spans a query's scoring clauses with df > 0
+        w_unnorm = np.where(clause_scoring[None, :], idf * boost[None, :],
+                            np.float32(0.0))
+        ssw = np.zeros((df.shape[0], n_queries), np.float32)
+        for si in range(df.shape[0]):
+            np.add.at(ssw[si], clause_qidx, w_unnorm[si] * w_unnorm[si])
+        qn = np.where(ssw > 0, np.float32(1.0) / np.sqrt(np.maximum(ssw, 1e-38)),
+                      np.float32(1.0)).astype(np.float32)
+        return idf * idf * boost[None, :] * qn[:, clause_qidx]
 
     # -- host-side batch assembly -------------------------------------------
     def _assemble(self, plans: list[FlatPlan]):
@@ -674,8 +705,10 @@ class MeshSearchExecutor:
                 row = np.arange(n_scoring_max + 1, dtype=np.float32) / np.float32(n_sc)
                 coord[qi] = np.minimum(row, 1.0)
                 coord[qi, : n_sc + 1] = np.arange(n_sc + 1, dtype=np.float32) / np.float32(n_sc)
-        return (qidx, blk, clause_id, fidx, group, tfmode, df_local, boost,
-                clause_qidx, clause_scoring, n_must, msm, coord)
+        weight_c = self._clause_weights(df_local, boost, clause_qidx,
+                                        clause_scoring, Qp)
+        return (qidx, blk, clause_id, fidx, group, tfmode, weight_c,
+                n_must, msm, coord)
 
     def search(self, plans: list[FlatPlan], k: int,
                filter_masks: np.ndarray | None = None,
@@ -698,26 +731,15 @@ class MeshSearchExecutor:
         mirrors SortSpec.reverse. active: bool [S] shard-subset mask.
         bucket_pairs: per bucket agg (pdoc [S, P], pbucket [S, P], nb,
         sub_row_idx tuple|None) — results in MeshTopDocs.bucket_results."""
-        import inspect
-
         import jax
         import jax.numpy as jnp
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
-
-        try:
-            from jax import shard_map  # jax >= 0.7 public API
-        except ImportError:  # pragma: no cover - older jax
-            from jax.experimental.shard_map import shard_map
-        # the replication-check knob was renamed check_rep -> check_vma across
-        # jax versions; same semantics (outputs here are P() by construction)
-        _sm_params = inspect.signature(shard_map).parameters
-        sm_relax = ({"check_vma": False} if "check_vma" in _sm_params
-                    else {"check_rep": False})
 
         idx = self.index
         Q = len(plans)
-        (qidx, blk, clause_id, fidx, group, tfmode, df_local, boost, clause_qidx,
-         clause_scoring, n_must, msm, coord) = self._assemble(plans)
+        (qidx, blk, clause_id, fidx, group, tfmode, weight_c,
+         n_must, msm, coord) = self._assemble(plans)
         # the pow-2 query bucket _assemble padded to — the program and its
         # cache key are shaped by Qp, outputs slice back to the real Q below
         Qp = n_must.shape[0]
@@ -748,8 +770,7 @@ class MeshSearchExecutor:
         in_specs = [
             P("shards"), P("shards"), P("shards"), P("shards"),  # index
             P("shards"), P("shards"), P("shards"), P("shards"), P("shards"), P("shards"),  # entries
-            P("shards"), P(), P(), P(),  # clause tables (df sharded)
-            P("shards"), P("shards"),  # stats
+            P("shards"), P("shards"),  # host-resolved weights + norm caches
             P(), P(), P(),  # per-query
         ]
         if has_filter:
@@ -769,7 +790,6 @@ class MeshSearchExecutor:
         fn = self._compiled.get(key)
         if fn is None:
             program = _mesh_score_program(k, Qp, idx.doc_pad, self.similarity_kind,
-                                          self.k1, self.b, self.use_global_stats,
                                           use_filter=has_filter,
                                           use_aggs=has_aggs,
                                           use_post=has_post,
@@ -784,15 +804,14 @@ class MeshSearchExecutor:
                 program, mesh=self.mesh,
                 in_specs=tuple(in_specs),
                 out_specs=tuple(P() for _ in range(n_out)),
-                **sm_relax,
+                check_vma=False,  # outputs here are P() by construction
             )
             fn = jax.jit(fn)
             self._compiled[key] = fn
         raw = [
             idx.blk_docs, idx.blk_tf, idx.norms, idx.live,
             qidx, blk, clause_id, fidx, group, tfmode,
-            df_local, boost, clause_qidx, clause_scoring,
-            idx.max_doc, idx.sum_ttf, n_must, msm, coord,
+            weight_c, self._norm_cache, n_must, msm, coord,
         ]
         if has_filter:
             raw.append(filter_masks)

@@ -6,8 +6,8 @@ fans query-phase requests to every shard copy and reduces
 search/controller/SearchPhaseController.java:137). Here, when an index's shards all
 live on THIS node and a device mesh can hold one shard per device, the whole
 scatter/score/reduce collapses into ONE jitted SPMD program (mesh_search.py): DFS
-stats ride psum, the reduce rides all_gather + top_k — collectives over ICI instead
-of RPC over DCN. Anything the program can't express (aggregations, sort, rescore,
+stats are summed on the host that assembles the batch, the reduce rides all_gather +
+top_k — collectives over ICI instead of RPC over DCN. Anything the program can't express (aggregations, sort, rescore,
 filters, non-flat queries, remote shards) falls back to the transport scatter-gather
 unchanged — same results either way, checked by tests/test_mesh_serving.py.
 
@@ -821,10 +821,11 @@ class MeshServingService:
             f"[{sharded.tf_layout}], resident postings "
             f"~{sharded.resident_postings_bytes() // 1024} KiB")
         execs = {}
+        compiled: dict = {}  # one executable serves both search types
         for gs in (False, True):
             execs[gs] = MeshSearchExecutor(
                 sharded, mesh, similarity=kind,
                 k1=getattr(default_sim, "k1", 1.2),
                 b=getattr(default_sim, "b", 0.75),
-                use_global_stats=gs)
+                use_global_stats=gs, compiled=compiled)
         return execs

@@ -1,7 +1,7 @@
 """Cross-request device micro-batching — the serving-path throughput lever.
 
-The bench proves the device path is batch-hungry (BENCH_r05: 128 queries score
-in one ~17 ms pipelined launch) yet live serving dispatched ONE request per
+The device path is batch-hungry (one launch scores many queries) yet live
+serving dispatched ONE request per
 device launch, paying a full launch + host merge per query under concurrent
 load. DeviceBatcher coalesces concurrent `execute_query_phase` calls into one
 bucketed `execute_flat_batch` launch — the same continuous/micro-batching

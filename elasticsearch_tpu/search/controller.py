@@ -4,8 +4,8 @@ Analogue of search/controller/SearchPhaseController.java (SURVEY.md §2.5):
 - sortDocs: merge per-shard top-k into the global top-k (score order or field-sort
   order, ties broken by shard index then doc — SearchPhaseController.java:137-214)
 - aggregateDfs: sum per-shard term/field statistics for exact global IDF
-  (SearchPhaseController.java:83-135) — the host-side form; the mesh executor does the
-  same reduction as a psum over the shards axis (parallel/mesh_search.py)
+  (SearchPhaseController.java:83-135) — the mesh executor sums the same way, on the
+  host, over its shards' packed statistics (parallel/mesh_search.py)
 - merge: reduce aggregations/facets/suggest partials and assemble the final response
 
 Pure functions over shard results — unit-testable exactly like the reference's

@@ -168,7 +168,7 @@ def _count(path: str):
 
 
 def _device_failed(e: BaseException, ctx: "ShardContext | None" = None):
-    """A device launch failed (broken backend, OOM, plugin init): the search
+    """A device launch failed (broken backend, OOM, failed init): the search
     must still answer — count it, log each distinct error once, serve host.
     Mirrors mesh_serving's any-mesh-failure-must-not-fail-the-search rule.
 

@@ -1,0 +1,193 @@
+"""Ask the chip's compiler, without the chip.
+
+The TPU compiler is installed here and compiles for a chip that is described, not
+attached (`jax.experimental.topologies`). These cases compile the launches
+`chip_smoke.py` produces at the r03 shape (100k documents, one force-merged segment:
+524,288 block rows, doc_pad 131,072; read from the rehearsal's compile manifest) for a
+`v5e:2x2` topology: the sparse launch at its largest bucket in both variants, the dense
+launch an overflow query takes, and the mesh program of `chip_smoke.py --chips 4` on a
+four-device `Mesh`. A compile that passes is not a chip run; it says the chip's
+compiler accepts the program and how much device memory it plans.
+
+One strict-xfail case compiles the fused Pallas kernel and pins the compiler's refusal,
+so the PR that repairs or deletes the kernel has to touch it (ROADMAP S3/D2).
+
+The topology is described in a module-scoped fixture, never at import: only one
+process may hold the TPU library, every xdist worker imports every test file, and this
+file runs in one worker (`--dist loadfile`). The persistent compilation cache is off
+around these compiles — an executable compiled for a described chip is written to the
+cache but cannot be read back without one.
+"""
+
+from __future__ import annotations
+
+import functools
+import os
+
+import numpy as np
+import pytest
+
+BLOCK = 128
+ROWS = 524_288  # block rows of the force-merged 100k-document segment
+DOC_PAD = 131_072
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here, or its lock is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _shapes(sharding, *specs):
+    import jax
+    import jax.numpy as jnp
+
+    return [jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=sharding)
+            for shape, dt in specs]
+
+
+def _sparse_args(sharding, Qb, TB, coord_w):
+    return _shapes(
+        sharding,
+        ((ROWS, BLOCK), "int32"), ((ROWS, BLOCK), "uint8"), ((ROWS, BLOCK), "uint8"),
+        ((1, 256), "float32"), ((1,), "int32"),  # SimTables caches, modes
+        ((Qb, TB), "int32"), ((Qb, TB), "float32"), ((Qb, TB), "bool"),
+        ((Qb, TB), "int32"), ((Qb, TB), "int32"),
+        ((Qb,), "int32"), ((Qb,), "int32"), ((Qb, coord_w), "float32"))
+
+
+@pytest.mark.parametrize("TB,k,passes,simple,coord_w", [
+    (512, 128, 2, True, 5),    # four-term should, size 100, tb_max blocks
+    (256, 16, 1, False, 3),    # two-term `operator: and`, size 10
+], ids=["should4-k128-TB512", "and2-k16-TB256"])
+def test_sparse_launch_compiles_for_v5e(one_chip, TB, k, passes, simple, coord_w):
+    import jax
+
+    from elasticsearch_tpu.common.jaxenv import compile_tag
+    from elasticsearch_tpu.ops.scoring import _sparse_impl
+
+    fn = jax.jit(functools.partial(
+        _sparse_impl, k=k, doc_pad=DOC_PAD, passes=passes, simple=simple,
+        use_coord=False))
+    with compile_tag("sparse"):
+        compiled = fn.lower(*_sparse_args(one_chip, 8, TB, coord_w)).compile()
+    mem = compiled.memory_analysis()
+    # the [Qb, TB*128] candidate matrix and its sort temporaries: megabytes, not HBM
+    assert 0 < mem.temp_size_in_bytes < 256 << 20
+    assert "tpu_custom_call" not in compiled.as_text()  # the composed launch, no kernel
+
+
+def test_dense_overflow_launch_compiles_for_v5e(one_chip):
+    """One query whose terms span more than tb_max blocks takes the dense launch:
+    a [Q, doc_pad] f32 accumulator over the lazily faulted f32 freqs plane."""
+    import jax
+
+    from elasticsearch_tpu.common.jaxenv import compile_tag
+    from elasticsearch_tpu.ops.scoring import _score_batch_impl
+
+    Q, E = 1, 2048  # (query, block) entries, bucketed
+    args = _shapes(
+        one_chip,
+        ((ROWS, BLOCK), "int32"), ((ROWS, BLOCK), "float32"),  # docs, f32 freqs
+        ((DOC_PAD,), "bool"), ((1, DOC_PAD), "uint8"), ((1, 256), "float32"),
+        ((E,), "int32"), ((E,), "int32"), ((E,), "float32"), ((E,), "int32"),
+        ((E,), "int32"), ((E,), "int32"),
+        ((Q,), "int32"), ((Q,), "int32"), ((Q, 5), "float32"))
+    fn = jax.jit(functools.partial(
+        _score_batch_impl, n_queries=Q, k=128, doc_pad=DOC_PAD, simple=True))
+    with compile_tag("dense"):
+        compiled = fn.lower(*args).compile()
+    mem = compiled.memory_analysis()
+    # docs i32 + freqs f32 planes dominate the arguments: 2 * 524288 * 128 * 4 bytes
+    assert mem.argument_size_in_bytes >= 2 * ROWS * BLOCK * 4
+    assert mem.temp_size_in_bytes < 1 << 30
+
+
+def test_mesh_program_compiles_for_four_v5e_chips(topo):
+    """`chip_smoke.py --chips 4`: one index of 4 x 25,000 documents, one shard a
+    device, statistics resolved on the host (per-shard weight and norm-cache
+    rows), the global top-k by all_gather."""
+    import jax
+    import jax.numpy as jnp
+    from jax import shard_map
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from elasticsearch_tpu.common.jaxenv import compile_tag
+    from elasticsearch_tpu.parallel.mesh_search import _mesh_score_program
+
+    S, rows, doc_pad, E, C, Qp, k = 4, 131_072, 32_768, 512, 4, 1, 128
+    mesh = Mesh(np.array(topo.devices[:S]), ("shards",))
+    sh, rep = P("shards"), P()
+    layout = [  # (shape, dtype, spec) as MeshSearchExecutor.search places them
+        ((S, rows, BLOCK), "int32", sh), ((S, rows, BLOCK), "uint8", sh),  # docs, tf
+        ((S, 1, doc_pad), "uint8", sh), ((S, doc_pad), "bool", sh),  # norms, live
+        *[((S, E), "int32", sh)] * 6,  # qidx, blk, clause_id, fidx, group, tfmode
+        ((S, C), "float32", sh), ((S, 1, 256), "float32", sh),  # weight_c, norm_cache
+        ((Qp,), "int32", rep), ((Qp,), "int32", rep), ((Qp, 5), "float32", rep)]
+    program = _mesh_score_program(k, Qp, doc_pad, 0)
+    fn = jax.jit(shard_map(
+        program, mesh=mesh, in_specs=tuple(spec for _s, _d, spec in layout),
+        out_specs=tuple(rep for _ in range(4)), check_vma=False))
+    args = [jax.ShapeDtypeStruct(shape, jnp.dtype(dt),
+                                 sharding=NamedSharding(mesh, spec))
+            for shape, dt, spec in layout]
+    with compile_tag("mesh"):
+        compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    assert "all-gather" in text  # the top-k merge crosses the chips
+    # per device: its own shard's planes, not all four
+    per_device = compiled.memory_analysis().argument_size_in_bytes
+    assert per_device < 2 * rows * BLOCK * 5
+
+
+@pytest.mark.xfail(
+    strict=True, raises=ValueError,
+    reason="the chip's compiler refuses the fused Pallas kernel at its first block "
+           "spec: 'the last two dimensions of your block shape are divisible by 8 "
+           "and 128 respectively' — the (1, BLOCK) row-gather specs "
+           "(ops/pallas_kernels.py). It has never compiled; ROADMAP S3/D2 decide it.")
+def test_fused_pallas_kernel_compiles_for_v5e(one_chip):
+    import jax
+
+    from elasticsearch_tpu.common.jaxenv import compile_tag
+    from elasticsearch_tpu.ops.pallas_kernels import _sparse_score_call
+
+    Qb, TB, rows = 8, 32, 4096
+    args = _shapes(
+        one_chip,
+        ((Qb, TB), "int32"), ((Qb, TB), "float32"), ((Qb, TB), "int32"),
+        ((Qb, TB), "int32"), ((Qb, TB), "int32"), ((Qb, TB), "int32"),
+        ((Qb,), "int32"), ((Qb,), "int32"), ((Qb, 5), "float32"),
+        ((rows, BLOCK), "int32"), ((rows, BLOCK), "uint8"), ((rows, BLOCK), "uint8"),
+        ((1, 256), "float32"))
+    fn = jax.jit(functools.partial(
+        _sparse_score_call, k=128, doc_pad=DOC_PAD, passes=2, simple=True,
+        use_coord=False, interpret=False))
+    try:
+        with compile_tag("sparse"):
+            fn.lower(*args).compile()
+    except ValueError as e:
+        # any OTHER refusal is news: fail for real instead of xfailing on it
+        assert "divisible by 8 and 128" in str(e), e
+        raise

@@ -358,6 +358,45 @@ class TestRestartPersistence:
         finally:
             node.close()
 
+    @pytest.mark.parametrize("env_dir", [None, "/x/placed-by-the-caller"])
+    def test_compile_cache_has_one_placement_rule(self, registry_guard, monkeypatch,
+                                                  tmp_path, env_dir):
+        """Where JAX_COMPILATION_CACHE_DIR is set no code sets another directory;
+        unset, the cache is <checkout>/.jax_cache — never under path.data, whose
+        default is a mkdtemp name that moves on every boot and so never hits."""
+        import os
+
+        import jax
+
+        from elasticsearch_tpu.common import jaxenv
+        from elasticsearch_tpu.common.settings import Settings
+
+        checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        if env_dir is None:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            assert jaxenv.compile_cache_dir() == os.path.join(checkout, ".jax_cache")
+        else:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+            assert jaxenv.compile_cache_dir() == env_dir
+        monkeypatch.setattr(jaxenv, "_persistent_cache_armed", False)
+        assert jaxenv.armed_compile_cache_dir() is None
+        before = jax.config.jax_compilation_cache_dir
+        try:
+            REGISTRY.configure(Settings.from_flat({}), str(tmp_path))  # node boot
+            placed = jax.config.jax_compilation_cache_dir
+            # what /_nodes/stats/compile_warming reports is jax's own setting
+            assert REGISTRY.stats()["persistent_cache_dir"] == placed
+        finally:  # the tests' cache stays where conftest put it
+            from jax.experimental.compilation_cache import compilation_cache
+
+            jax.config.update("jax_compilation_cache_dir", before)
+            compilation_cache.reset_cache()
+        if env_dir is None:
+            assert placed == os.path.join(checkout, ".jax_cache")
+        else:
+            assert placed == before  # jax read the variable itself; code set nothing
+        assert not str(placed or "").startswith(str(tmp_path))
+
     def test_compile_warming_kill_switch(self, registry_guard, tmp_path):
         REGISTRY.reset()
         node = _boot(str(tmp_path / "n1"),

@@ -1,5 +1,5 @@
 """Mesh serving: a REST _search over a co-located multi-shard index executes the
-SPMD shard_map program (DFS psum + all_gather top-k over the virtual 8-device CPU
+SPMD shard_map program (host-summed DFS stats + all_gather top-k over the virtual 8-device CPU
 mesh) and produces results identical to the transport scatter-gather path.
 
 ref: the scatter-gather this replaces is TransportSearchTypeAction.java:117,135-216

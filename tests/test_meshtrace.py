@@ -77,11 +77,7 @@ def test_overhead_zero_when_knob_off():
     for name in meshtrace.COLLECTIVES:
         fn = getattr(jax.lax, name, None)
         assert fn is None or not getattr(fn, "_estpu_meshtrace", False), name
-    from jax.experimental import shard_map as sm_mod
-
-    assert not getattr(sm_mod.shard_map, "_estpu_meshtrace", False)
-    if getattr(jax, "shard_map", None) is not None:
-        assert not getattr(jax.shard_map, "_estpu_meshtrace", False)
+    assert not getattr(jax.shard_map, "_estpu_meshtrace", False)
 
 
 # ---------------------------------------------------------------------------
@@ -129,7 +125,7 @@ def test_driver_runs_clean_without_the_knob():
 
 
 def test_warmed_mesh_serving_records_clean_sequences():
-    """The real SPMD serving path (2-shard mesh, DFS psum + all_gather top-k)
+    """The real SPMD serving path (2-shard mesh, all_gather top-k)
     with the tracer armed: the warmed loop must run with 0 recompiles under
     the hard transfer guard, record real collective launches, show ZERO
     sequence mismatches, and replay cleanly at the end — the invariant the
@@ -150,22 +146,13 @@ def test_warmed_mesh_serving_records_clean_sequences():
 
 
 def _mesh_and_relax():
-    import inspect
-
     import jax
     import numpy as np
+    from jax import shard_map
     from jax.sharding import Mesh
 
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-
     mesh = Mesh(np.array(jax.devices()), ("d",))
-    params = inspect.signature(shard_map).parameters
-    relax = {"check_vma": False} if "check_vma" in params \
-        else {"check_rep": False}
-    return shard_map, mesh, relax
+    return shard_map, mesh, {"check_vma": False}
 
 
 def _divergent_program(x):

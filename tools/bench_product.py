@@ -12,8 +12,9 @@ CPU reference = the framework's vectorized numpy host scorer (search_shard
 use_device=False), a stronger baseline than Lucene's per-doc scoring loops.
 Correctness gate: device and host must produce identical hit ordering per query.
 
-Run: python tools/bench_product.py          (TPU; falls back to CPU like bench.py)
-     BENCH_PRODUCT_DOCS=20000 python tools/bench_product.py
+Run: python tools/bench_product.py          (needs a TPU; exits non-zero off one)
+     JAX_PLATFORMS=cpu BENCH_PRODUCT_DOCS=20000 python tools/bench_product.py
+     (a CPU run the caller asked for: labelled "cpu", never a device measurement)
 """
 
 from __future__ import annotations
@@ -240,21 +241,15 @@ def run_fused_paths(eng, svc, queries, platform):
 
 
 def main():
-    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    import bench as kernel_bench
+    from elasticsearch_tpu.common.jaxenv import (
+        enable_persistent_compile_cache, require_accelerator)
 
-    platform = kernel_bench._ensure_backend()
+    device = require_accelerator("bench_product")
+    platform = device["platform"]
     global N_DOCS
-    if platform.startswith("cpu"):
+    if platform == "cpu":
         N_DOCS = min(N_DOCS, 20_000)
-    import jax
-
-    try:
-        jax.config.update("jax_compilation_cache_dir", os.path.join(CACHE, "xla"))
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    except Exception as e:  # noqa: BLE001
-        print(f"# compilation cache unavailable: {e}", file=sys.stderr)
+    enable_persistent_compile_cache()  # placed by jaxenv's one rule
 
     def wrap_script(qd):
         # config #4 (BASELINE.md): BM25 sub query + _score-reading script_score —
@@ -284,7 +279,7 @@ def main():
                               tie_rel=tie_rel)
         line = {"metric": f"{cfg} product-path qps ({N_DOCS} docs, {platform})",
                 "value": round(dev, 1), "unit": "queries/sec",
-                "vs_baseline": round(dev / cpu, 2)}
+                "vs_baseline": round(dev / cpu, 2), **device}
         results.append(line)
         print(json.dumps(line))
         print(f"# {cfg}: device {dev:.0f} qps  host {cpu:.0f} qps", file=sys.stderr)

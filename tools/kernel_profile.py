@@ -2,7 +2,8 @@
 
 Times each stage of ops/scoring.py's fused program in isolation on the live device:
   gather+FMA, scatter-add, top_k (full), top_k (two-stage), sort-based sparse path.
-Run: python tools/kernel_profile.py
+Run: python tools/kernel_profile.py   (needs a TPU: exits non-zero off one unless the
+caller set JAX_PLATFORMS=cpu, and then the times are CPU times)
 """
 import os
 import sys
@@ -34,6 +35,9 @@ def timeit(fn, *args, n=5):
 
 
 def main():
+    from elasticsearch_tpu.common.jaxenv import require_accelerator
+
+    require_accelerator("kernel_profile")  # exits non-zero off a TPU
     rng = np.random.default_rng(0)
     blk_docs = jnp.asarray(rng.integers(0, DPAD, (NB, BLOCK), dtype=np.int32))
     blk_freqs = jnp.asarray(rng.random((NB, BLOCK), dtype=np.float32) * 5 + 1)

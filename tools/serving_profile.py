@@ -2,8 +2,8 @@
 through execute_query_phase on a real Engine-built corpus (Q=1, the latency
 shape), plus the host mask path for comparison.
 
-Run on TPU:  python tools/serving_profile.py
-CPU:         JAX_PLATFORMS=cpu python tools/serving_profile.py
+Run on TPU:  python tools/serving_profile.py   (exits non-zero off a TPU)
+CPU:         JAX_PLATFORMS=cpu python tools/serving_profile.py   (labelled "cpu")
 Env:         SERVING_PROFILE_DOCS=50000 (default 20000)
 """
 
@@ -15,9 +15,10 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-import bench as kernel_bench  # noqa: E402 — backend probe/fallback
+from elasticsearch_tpu.common.jaxenv import require_accelerator  # noqa: E402
 
-platform = kernel_bench._ensure_backend()
+# off a TPU this exits non-zero unless the caller set JAX_PLATFORMS=cpu
+platform = require_accelerator("serving_profile")["platform"]
 
 import numpy as np  # noqa: E402
 
