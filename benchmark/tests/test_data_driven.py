@@ -1,0 +1,145 @@
+"""The harness finds a configuration, a mix, a query family and a layer metric that
+were added as files and entries alone, and names none of them in its code."""
+
+import json
+import os
+import re
+import shutil
+
+import numpy as np
+import pytest
+
+from benchmark.harness import readers, registry
+
+
+@pytest.fixture()
+def copy(tmp_path, monkeypatch):
+    """A copy of the benchmark in which a later PR's files can be added."""
+    bench_dir = tmp_path / "benchmark"
+    shutil.copytree(registry.BENCH_DIR, bench_dir,
+                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    shutil.copy(os.path.join(registry.CHECKOUT, "BENCHMARK.json"), tmp_path)
+    monkeypatch.setattr(registry, "BENCH_DIR", str(bench_dir))
+    monkeypatch.setattr(registry, "CHECKOUT", str(tmp_path))
+    return tmp_path
+
+
+def test_pieces_added_as_files_alone_are_found(copy):
+    bench = json.loads((copy / "BENCHMARK.json").read_text())
+    before = {p: (copy / "benchmark" / p).read_bytes()
+              for p in ("run.py", "harness/cell.py", "harness/registry.py",
+                        "harness/readers.py")}
+    # a later PR: one configuration, one mix with a new family, one metric, one cell
+    config = json.loads((copy / "benchmark/configs" /
+                         (bench["configs"][0]["name"] + ".json")).read_text())
+    config["name"] = "tiny-logs"
+    config["documents"] = 300
+    (copy / "benchmark/configs/tiny-logs.json").write_text(json.dumps(config))
+    (copy / "benchmark/queries/one_term.py").write_text(
+        "from benchmark.harness.reference import word\n"
+        "def plan(params, rng, n):\n"
+        "    return [float(rng.random()) for _ in range(n)]\n"
+        "def build(params, ref, plans):\n"
+        "    out = []\n"
+        "    for u in plans:\n"
+        "        t = int(ref.by_df[int(u * ref.n_present)])\n"
+        "        out.append({'terms': [t], 'must_all': False, 'size': 5, 'allowed': None,\n"
+        "                    'body': {'query': {'match': {params['field']: word(t)}},\n"
+        "                             'size': 5}})\n"
+        "    return out\n"
+        "def expected(ref, q):\n"
+        "    return ref.score_all(q['terms'], False)\n")
+    (copy / "benchmark/traffic/single.json").write_text(json.dumps({
+        "name": "single", "loop": "closed", "clients": 1, "keep_alive": True,
+        "pool": 32, "plan_seed": 1, "who": "one caller", "why": "latency floor",
+        "families": [{"family": "one_term", "weight": 1,
+                      "params": {"field": "body"}}]}))
+    (copy / "benchmark/layer_metrics/full_flush_share.json").write_text(json.dumps({
+        "layer": "batcher", "reader": "counter_ratio", "scale": 100.0,
+        "numerator": ["search.batcher.full_flushes"],
+        "denominator": ["search.batcher.launches"]}))
+    bench["configs"].append({"name": "tiny-logs", "source": "a test",
+                             "file": "benchmark/configs/tiny-logs.json",
+                             "reduced": [], "why": "a test"})
+    bench["workloads"].append({"name": "logs.single", "config": "tiny-logs",
+                               "traffic": "single", "chips": 1, "why": "a test"})
+    bench["per_layer"].append({"name": "full_flush_share", "unit": "%",
+                               "better": "higher", "source": "program_counter",
+                               "layer": "batcher", "moves": "searches_per_s",
+                               "workloads": ["logs.single"]})
+    (copy / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    from benchmark.harness.cell import Pool
+    from benchmark.harness.reference import Reference
+
+    bench = registry.benchmark()
+    cell = registry.cell(bench, "logs.single")
+    config = registry.config(bench, cell["config"])
+    assert config["documents"] == 300
+    mix = registry.mix(cell["traffic"])
+    gen = registry.module("corpora", config["corpus"]["generator"])
+    corpus = gen.generate(config["corpus"]["params"], 5, config["documents"])
+    ref = Reference(corpus, 1.2, 0.75)
+    pool = Pool(mix, ref, "idx")
+    assert len(pool.queries) == 32 and pool.queries[0]["size"] == 5
+    names = [m["name"] for m, _ in
+             registry.metrics_of(bench, "logs.single", "per_layer", "layer_metrics")]
+    assert "full_flush_share" in names and "gen_late_p95_ms" not in names
+    obs = readers.Observations("idx")
+    obs.stats_before = {"search": {"batcher": {"full_flushes": 0, "launches": 0}}}
+    obs.stats_after = {"search": {"batcher": {"full_flushes": 5, "launches": 20}}}
+    _entry, definition = [(m, d) for m, d in registry.metrics_of(
+        bench, "logs.single", "per_layer", "layer_metrics")
+        if m["name"] == "full_flush_share"][0]
+    assert readers.read(definition, obs) == pytest.approx(25.0)
+    for p, content in before.items():
+        assert (copy / "benchmark" / p).read_bytes() == content
+
+
+def test_harness_names_no_configuration_mix_family_or_metric():
+    bench = registry.benchmark()
+    names = {c["name"] for c in bench["configs"]} | \
+        {w["name"] for w in bench["workloads"]} | \
+        {w["traffic"] for w in bench["workloads"]} | \
+        {m["name"] for m in bench["end_to_end"] + bench["per_layer"]}
+    for d in ("queries", "corpora", "reductions"):
+        names |= {f[:-3] for f in os.listdir(os.path.join(registry.BENCH_DIR, d))
+                  if f.endswith(".py")}
+    code = [os.path.join(registry.BENCH_DIR, "run.py")]
+    harness = os.path.join(registry.BENCH_DIR, "harness")
+    code += [os.path.join(harness, f) for f in os.listdir(harness) if f.endswith(".py")]
+    for path in code:
+        text = open(path).read()
+        for name in names:
+            assert not re.search(r"(?<![\w.])" + re.escape(name) + r"(?![\w])", text), \
+                f"{path} names {name!r}"
+
+
+def test_an_unknown_device_is_an_error_not_a_default():
+    assert registry.peaks("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
+    with pytest.raises(KeyError):
+        registry.peaks("TPU v9")
+
+
+def test_benchmark_json_and_its_files_agree():
+    bench = registry.benchmark()
+    cells = {w["name"] for w in bench["workloads"]}
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    assert "setup_s" in e2e and "workloads" not in e2e["setup_s"]
+    for w in bench["workloads"]:
+        registry.config(bench, w["config"])
+        mix = registry.mix(w["traffic"])
+        for fam in mix["families"]:
+            registry.module("queries", fam["family"])
+        reported = [m for m, _ in registry.metrics_of(
+            bench, w["name"], "end_to_end", "end_to_end")]
+        assert len(reported) >= 2
+        assert registry.metrics_of(bench, w["name"], "per_layer", "layer_metrics")
+    for m in bench["per_layer"]:
+        assert m["moves"] in e2e
+        for c in m.get("workloads", cells):
+            assert c in cells
+            moved = e2e[m["moves"]]
+            assert c in moved.get("workloads", cells), (m["name"], c)
+    for c in bench["configs"]:
+        assert registry.config(bench, c["name"])["reduced"] == c["reduced"]

@@ -1,0 +1,68 @@
+"""The rest of a run, driven on the CPU at a tiny size with the harness's look for a
+chip skipped: a sound server comes out `correct: true`, and a server whose timed path
+is broken underneath (every BM25 weight one part in a thousand off, where the score is
+produced) comes out `correct: false`. The CPU rehearsal as a user runs it never says
+true. Each case starts a server: about a minute."""
+
+import argparse
+import json
+import os
+import time
+
+import pytest
+
+from benchmark.harness import cell, registry
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+SHORT_WARMUP = {"warmup": {"pool_pass_max_seconds": 20, "rehearsals": 1}}
+
+
+def _run(capsys, monkeypatch, workload, **options):
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    args = argparse.Namespace(workload=workload, seed=2**31 + 17, seconds=3.0,
+                              trace=0, docs=1500)
+    rc = cell.run(args, time.perf_counter(), settings=SHORT_WARMUP, **options)
+    lines = [json.loads(line) for line in capsys.readouterr().out.splitlines()]
+    return rc, lines
+
+
+def _paths(*extra):
+    return os.pathsep.join(list(extra) + [registry.CHECKOUT] +
+                           [p for p in os.environ.get("PYTHONPATH", "").split(os.pathsep)
+                            if p])
+
+
+@pytest.mark.parametrize("workload", [w["name"] for w in
+                                      registry.benchmark()["workloads"]])
+def test_a_sound_run_is_correct_and_reports_its_metrics(capsys, monkeypatch, workload):
+    rc, lines = _run(capsys, monkeypatch, workload, assume_chip=True)
+    result = lines[-1]
+    assert rc == 0 and result["correct"] is True, lines[-3:]
+    assert set(result) >= {"correct", "attempted", "failed", "metrics", "device"}
+    bench = registry.benchmark()
+    want = {m["name"] for m, _ in registry.metrics_of(
+        bench, workload, "end_to_end", "end_to_end")}
+    assert set(result["metrics"]) == want
+    assert all(v["value"] > 0 for v in result["metrics"].values())
+    compared = [l for l in lines if l.get("phase") == "compare"]
+    assert len(compared) == 3
+    for l in compared:
+        assert all(n["value"] <= n["limit"] for n in l["numbers"].values())
+
+
+def test_a_broken_timed_path_is_not_correct(capsys, monkeypatch):
+    workload = registry.benchmark()["workloads"][0]["name"]
+    rc, lines = _run(capsys, monkeypatch, workload, assume_chip=True, server_env={
+        "PYTHONPATH": _paths(os.path.join(HERE, "broken_server"))})
+    result = lines[-1]
+    assert rc == 1 and result["correct"] is False
+    window = [l for l in lines if l.get("phase") == "compare"
+              and l["sample"].startswith("the window")][0]
+    assert window["numbers"]["rel_dev"]["value"] > window["numbers"]["rel_dev"]["limit"]
+
+
+def test_the_rehearsal_never_says_correct(capsys, monkeypatch):
+    workload = registry.benchmark()["workloads"][0]["name"]
+    rc, lines = _run(capsys, monkeypatch, workload)
+    assert rc == 2 and lines[-1]["correct"] is False
+    assert all(l.get("rehearsal") for l in lines[:-1])
