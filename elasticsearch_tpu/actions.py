@@ -1582,15 +1582,27 @@ class ActionModule:
             affinity = request_fingerprint(body)
         shards = self.routing.search_shards(state, indices, routing,
                                             preference, affinity=affinity)
+        # a sampled search divides its coordinator span into phases (an
+        # unsampled one reads no clock and allocates nothing for them): plan
+        # ends here, query runs until the last shard has answered, and the
+        # transport round-trips of a phase nest under it
+        span = tracing.current_span()
+        if span:
+            span.record("coordinator.plan", t0, time.monotonic())
 
-        # co-located shards + flat query → one SPMD program over the device mesh
-        # (host-summed DFS stats + all_gather top-k on ICI) instead of per-shard RPC scatter-gather;
-        # None = ineligible or failed, fall through to the transport path unchanged
-        mesh_results = self.mesh_serving.try_search(
-            state, self.node.local_node.id, indices, alias_filters, shards, req,
-            use_global_stats=search_type in ("dfs_query_then_fetch",
-                                             "dfs_query_and_fetch"),
-            deadline=deadline)
+        with tracing.child_scope(span, "coordinator.query", shards=len(shards)):
+            # co-located shards + flat query → one SPMD program over the device mesh
+            # (host-summed DFS stats + all_gather top-k on ICI) instead of per-shard RPC scatter-gather;
+            # None = ineligible or failed, fall through to the transport path unchanged
+            mesh_results = self.mesh_serving.try_search(
+                state, self.node.local_node.id, indices, alias_filters, shards, req,
+                use_global_stats=search_type in ("dfs_query_then_fetch",
+                                                 "dfs_query_and_fetch"),
+                deadline=deadline)
+            if mesh_results is None:
+                results, failures, chain_terminals, shard_meta = \
+                    self._query_phase(state, shards, body, alias_filters,
+                                      search_type, preference, deadline)
         if mesh_results is not None:
             # mesh-served searches never reach _s_query_phase, so the
             # query-shape classification happens HERE instead (one record per
@@ -1608,7 +1620,29 @@ class ActionModule:
                           for o, copy in enumerate(shards)}
             return self._finish_search(req, body, mesh_results, [], shards,
                                        shard_meta, t0)
+        overload = [e for e in chain_terminals
+                    if isinstance(e, (CircuitBreakingError,
+                                      RejectedExecutionError))]
+        if not results and chain_terminals \
+                and len(overload) == len(chain_terminals):
+            # EVERY shard's failover chain died on overload protection — this
+            # is a load-shed, not a data failure: surface the 429 (with its
+            # Retry-After hint) so clients back off instead of retrying hot.
+            # Any chain that died on something ELSE keeps the normal partial
+            # response with its _shards.failures entries — a permanent data
+            # failure must not masquerade as "retry later"
+            raise overload[-1]
+        # shard-side partials mark timed_out in the reduce (sort_docs); chain
+        # exhaustion by deadline must surface it too, even with no results back
+        return self._finish_search(req, body, results, failures, shards,
+                                   shard_meta, t0, timed_out=deadline.expired())
 
+    def _query_phase(self, state, shards, body, alias_filters, search_type,
+                     preference, deadline):
+        """The transport query phase of one search (the DFS fan-out first,
+        where the search type asks for it): every shard's chain dispatched at
+        once, then collected. Returns (results, failures, chain_terminals,
+        shard_meta); the caller decides between a 429 and a partial answer."""
         dfs_stats = None
         dfs_failed: set[int] = set()  # ordinals excluded from the query phase
         if search_type in ("dfs_query_then_fetch", "dfs_query_and_fetch"):
@@ -1731,22 +1765,7 @@ class ActionModule:
                     self.admission.observe(
                         getattr(fut, "completed_at", time.monotonic())
                         - t_fanout)
-        overload = [e for e in chain_terminals
-                    if isinstance(e, (CircuitBreakingError,
-                                      RejectedExecutionError))]
-        if not results and chain_terminals \
-                and len(overload) == len(chain_terminals):
-            # EVERY shard's failover chain died on overload protection — this
-            # is a load-shed, not a data failure: surface the 429 (with its
-            # Retry-After hint) so clients back off instead of retrying hot.
-            # Any chain that died on something ELSE keeps the normal partial
-            # response with its _shards.failures entries — a permanent data
-            # failure must not masquerade as "retry later"
-            raise overload[-1]
-        # shard-side partials mark timed_out in the reduce (sort_docs); chain
-        # exhaustion by deadline must surface it too, even with no results back
-        return self._finish_search(req, body, results, failures, shards,
-                                   shard_meta, t0, timed_out=deadline.expired())
+        return results, failures, chain_terminals, shard_meta
 
     def _finish_search(self, req, body, results, failures, shards, shard_meta, t0,
                        timed_out: bool = False):
@@ -1757,6 +1776,8 @@ class ActionModule:
         PARTIAL answer instead of an empty one (ref: the reference's fetch runs
         after TimeLimitingCollector fires too). `timed_out` ORs in coordinator-
         level budget expiry; shard-level partials are folded in by sort_docs."""
+        span = tracing.current_span()  # sampled: reduce / fetch / render
+        t_reduce = time.monotonic() if span else 0.0
         merged = sort_docs(req, results)
         merged.timed_out = merged.timed_out or timed_out
         page = merged.hits[req.from_: req.from_ + req.size]
@@ -1765,6 +1786,25 @@ class ActionModule:
         by_shard: dict = {}
         for rank, (score, ordinal, doc, sort_values) in enumerate(page):
             by_shard.setdefault(ordinal, []).append((rank, score, doc, sort_values))
+        with tracing.child_scope(span, "coordinator.fetch",
+                                 shards=len(by_shard)) as fetch_span:
+            fetched, fetch_failed = self._fetch_phase(
+                body, by_shard, shard_meta, failures)
+        hits = [fetched[r] for r in sorted(fetched)]
+        response = merge_responses(req, merged, results, hits,
+                                   took_ms=int((time.monotonic() - t0) * 1000),
+                                   total_shards=len(shards),
+                                   successful=len(results) - fetch_failed,
+                                   failures=failures)
+        if span:
+            span.record("coordinator.reduce", t_reduce, fetch_span.t0)
+            span.record("coordinator.render", fetch_span.t1, time.monotonic())
+        return response
+
+    def _fetch_phase(self, body, by_shard, shard_meta, failures):
+        """Hydrate the page's winners: one fetch per contributing shard, all in
+        flight at once; a shard lost between the phases drops ITS hits and is
+        appended to `failures`. Returns ({rank: hit}, shards that failed)."""
         fetched: dict[int, dict] = {}
         fetch_failed = 0
         fetch_futs = []
@@ -1800,12 +1840,7 @@ class ActionModule:
                 with contextlib.suppress(Exception):
                     self.transport.send_request(node, A_FREE_CONTEXT, {
                         "index": index_name, "shard": real_shard, "ctx": ctx_id})
-        hits = [fetched[r] for r in sorted(fetched)]
-        return merge_responses(req, merged, results, hits,
-                               took_ms=int((time.monotonic() - t0) * 1000),
-                               total_shards=len(shards),
-                               successful=len(results) - fetch_failed,
-                               failures=failures)
+        return fetched, fetch_failed
 
     @staticmethod
     def _shard_index(shards, shard_id):
@@ -2275,24 +2310,30 @@ class ActionModule:
         if isinstance(body, dict) and bool(body.get("profile")):
             prof = profiling.ProfileCollector(node=self.node.name,
                                               index=index, shard=shard_id)
-        req = parse_search_body(body)
-        if prof is not None:
-            prof.phase_s("parse", time.monotonic() - prof.t0)
-        ctx = self._shard_ctx(index, shard_id, request.get("dfs"))
+        # continue the coordinator's trace from the wire context (the sender
+        # only injects one for sampled traces) BEFORE the parse, so that the
+        # shard span covers it (`shard.lower` is cut from its start); the
+        # shard span is the parent every batcher span of this request
+        # attaches to
+        tracer = getattr(self.node, "tracer", None)
+        trace = tracer.continue_trace(request.get(tracing.TRACE_WIRE_KEY),
+                                      "shard") if tracer is not None \
+            else tracing.NOOP_TRACE
+        shard_span = trace.root.tag(index=index, shard=shard_id)
+        try:
+            req = parse_search_body(body)
+            if prof is not None:
+                prof.phase_s("parse", time.monotonic() - prof.t0)
+            ctx = self._shard_ctx(index, shard_id, request.get("dfs"))
+        except BaseException:
+            shard_span.end()  # a body that does not parse still ends its span
+            raise
         # shard-side budget: the tighter of the coordinator's remaining budget
         # (shipped as a duration in `deadline_s`) and the body's own `timeout`
         budget = request.get("deadline_s")
         if req.timeout_s is not None:
             budget = req.timeout_s if budget is None else min(budget, req.timeout_s)
         deadline = Deadline.after(budget) if budget is not None else NO_DEADLINE
-        # continue the coordinator's trace from the wire context (the sender
-        # only injects one for sampled traces); the shard span is the parent
-        # every batcher span of this request attaches to
-        tracer = getattr(self.node, "tracer", None)
-        trace = tracer.continue_trace(request.get(tracing.TRACE_WIRE_KEY),
-                                      "shard") if tracer is not None \
-            else tracing.NOOP_TRACE
-        shard_span = trace.root.tag(index=index, shard=shard_id)
         if request.get("hedge"):
             # speculative (hedged) attempt: its shard span shows as a sibling
             # of the primary attempt's in the stitched ?trace=true tree

@@ -140,6 +140,10 @@ def enable_persistent_compile_cache() -> None:
 # (jax 0.4.x: /jax/core/compile/backend_compile_duration); counting them is
 # backend-agnostic and — unlike parsing jax_log_compiles output — race-free
 _COMPILE_EVENT_SUBSTR = "backend_compile"
+# the persistent compilation cache's own plain events (jax/_src/compiler.py,
+# compilation_cache.py): a retrieval that succeeded, one that did not
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
 
 
 @dataclass
@@ -200,6 +204,17 @@ def current_compile_family() -> str | None:
     return getattr(_tag_local, "tag", None)
 
 
+_thread_compiles = threading.local()
+
+
+def thread_compile_totals() -> tuple[int, float]:
+    """(events, seconds) of the backend compiles THIS thread has triggered so
+    far — one thread-local read. The difference across a launch says whether
+    that launch compiled, and for how long (search/batcher.py tags the batch)."""
+    return (getattr(_thread_compiles, "n", 0),
+            getattr(_thread_compiles, "s", 0.0))
+
+
 def _pool_label() -> str:
     """Which named threadpool the current thread belongs to — pool workers are
     named "estpu[<pool>]_N" (threadpool._BoundedPool); anything else reads as
@@ -254,6 +269,13 @@ class _CompileCounter:
         self.last_at: float | None = None
         # plan-family attribution (compile_tag): family -> count
         self.by_family: dict = {}
+        # what the events cost: the listener's `duration` summed, whole and
+        # by family (a persistent-cache hit still takes its retrieval time),
+        # beside the persistent cache's own hit/miss events
+        self.seconds = 0.0
+        self.seconds_by_family: dict = {}
+        self.cache_hits = 0
+        self.cache_misses = 0
         # untagged-compile origin sites ("path:line" -> count), recorded only
         # when record_untagged_origins() armed it — the runtime twin of the
         # compile-surface manifest's families cross-check
@@ -278,12 +300,19 @@ class _CompileCounter:
         origin = _package_origin() \
             if family == "untagged" and self._record_origins else None
         pool = _pool_label()
+        # XLA compiles on the triggering thread, so the thread's own tally
+        # lets the batcher drainer name the batch a stall fell into
+        _thread_compiles.n = getattr(_thread_compiles, "n", 0) + 1
+        _thread_compiles.s = getattr(_thread_compiles, "s", 0.0) + duration
         # note() under the lock: concurrent pool-thread compiles must not lose
         # increments, or a blown budget could pass silently
         with self._lock:
             self.total += 1
             self.last_at = time.monotonic()
             self.by_family[family] = self.by_family.get(family, 0) + 1
+            self.seconds += duration
+            self.seconds_by_family[family] = \
+                self.seconds_by_family.get(family, 0.0) + duration
             self.by_pool[pool] = self.by_pool.get(pool, 0) + 1
             if origin is not None and (origin in self.untagged_origins
                                        or len(self.untagged_origins)
@@ -299,12 +328,21 @@ class _CompileCounter:
             except Exception:  # noqa: BLE001
                 pass
 
+    def _cache_listener(self, key: str, **_kw) -> None:
+        if key == _CACHE_HIT_EVENT:
+            with self._lock:
+                self.cache_hits += 1
+        elif key == _CACHE_MISS_EVENT:
+            with self._lock:
+                self.cache_misses += 1
+
     def ensure_installed(self) -> None:
         import jax.monitoring
 
         with self._lock:
             if not self._installed:
                 jax.monitoring.register_event_duration_secs_listener(self._listener)
+                jax.monitoring.register_event_listener(self._cache_listener)
                 self._installed = True
 
     def subscribe(self, report: SanitizerReport) -> None:
@@ -359,6 +397,22 @@ def compile_events_by_family() -> dict:
         pass
     with _counter._lock:
         return dict(_counter.by_family)
+
+
+def compile_seconds() -> dict:
+    """What the counted compile events cost: `seconds` whole and
+    `seconds_by_family` (sums to it), plus the persistent compilation cache's
+    `cache_hits` / `cache_misses` — the `/_nodes/stats` `device.compile`
+    fields beside `total` and `by_family`."""
+    try:
+        _counter.ensure_installed()
+    except Exception:  # noqa: BLE001 — no jax in this process: zeros
+        pass
+    with _counter._lock:
+        return {"seconds": _counter.seconds,
+                "seconds_by_family": dict(_counter.seconds_by_family),
+                "cache_hits": _counter.cache_hits,
+                "cache_misses": _counter.cache_misses}
 
 
 def compile_events_by_pool() -> dict:

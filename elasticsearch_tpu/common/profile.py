@@ -17,8 +17,7 @@ Design rules (the repo's device + lock discipline applies here too):
   before wrapping, so an unprofiled request touches this module only through
   `current()`.
 - **Sync only when opted in.** Profiled requests get precise per-phase
-  device timings by blocking on the dispatched launches (the
-  `ESTPU_TRACE_SYNC` pattern from the tracing layer, but PER REQUEST —
+  device timings by blocking on the dispatched launches (PER REQUEST —
   legal because `"profile": true` is the opt-in). The unprofiled serving
   path adds ZERO device syncs (pinned by tests/test_profile.py).
 - **Batcher interaction is explicit.** A profiled request bypasses the

@@ -17,9 +17,9 @@ Design rules (the repo's device + lock discipline applies to tracing too):
   around operations the serving path performs ANYWAY — in particular the
   device span's end rides the batch's existing single `jax.device_get`
   (search/execute._merge_flat_plain stamps pull timestamps on the pending
-  handle). Tracing never calls `block_until_ready` per span; the opt-in
-  `ESTPU_TRACE_SYNC=1` precise mode (bench/debug only) is the ONE exception,
-  and it lives in the batcher drainer, not in span code.
+  handle). Tracing never calls `block_until_ready`: device time per named
+  program comes from the profiler's device trace, and the batcher's
+  `estpu.batch.*` annotations put every dispatch on that trace's clock.
 - **Lock discipline (TPU004/TPU011-TPU013).** Trace/ring locks are leaves:
   span recording only appends to lists under its own lock — it never blocks,
   never dispatches device work, never acquires another lock while held.
@@ -42,6 +42,8 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass
+
+from .jaxenv import thread_compile_totals
 
 # the request-dict key the transport layer injects the wire context under
 # (handlers read it with .get(); unknown keys are ignored everywhere else)
@@ -92,6 +94,113 @@ def activate(span):
         _local.span = prev
 
 
+# ---------------------------------------------------------------------------
+# the dispatch clock (what one batch dispatch did, for every batch)
+# ---------------------------------------------------------------------------
+
+
+class DispatchClock:
+    """Host-monotonic intervals of ONE batch dispatch, written by the thread
+    that dispatches: `dispatch.stage` (host staging up to a compiled call),
+    `dispatch.launch` (the call into the compiled program, where a first
+    sighting compiles) and, on the families that pull inside the dispatch,
+    `device_pull` (their one device_get). Each mark closes the interval since
+    the previous one, so the intervals are gap-free and never overlap; an
+    interval in which this thread compiled carries `compiled`/`compile_s`.
+    The batcher records them under `batcher.dispatch` for sampled members
+    and sums `pull_s` into its drainer states for every batch."""
+
+    __slots__ = ("spans", "pull_s", "compiled", "compile_s", "_t", "_n", "_s")
+
+    def __init__(self):
+        self.spans: list = []  # (name, t0, t1, tags | None)
+        self.pull_s = 0.0
+        self.compiled = 0
+        self.compile_s = 0.0
+        self._n, self._s = thread_compile_totals()
+        self._t = time.monotonic()
+
+    def mark(self, name: str) -> None:
+        now = time.monotonic()
+        tags = None
+        n, s = thread_compile_totals()
+        if n != self._n:
+            tags = {"compiled": n - self._n, "compile_s": round(s - self._s, 6)}
+            self.compiled += n - self._n
+            self.compile_s += s - self._s
+            self._n, self._s = n, s
+        if name == "device_pull":
+            self.pull_s += now - self._t
+        self.spans.append((name, self._t, now, tags))
+        self._t = now
+
+    def record_under(self, parent, **tags) -> None:
+        """The clock's intervals as born-finished children of `parent`."""
+        for name, t0, t1, own in self.spans:
+            parent.record(name, t0, t1, **tags, **(own or {}))
+
+
+@contextlib.contextmanager
+def timing_dispatch():
+    """Run one batch dispatch under a fresh DispatchClock on this thread."""
+    prev = getattr(_local, "clock", None)
+    clock = _local.clock = DispatchClock()
+    try:
+        yield clock
+    finally:
+        _local.clock = prev
+
+
+def mark(name: str) -> None:
+    """Close the running interval of this thread's dispatch clock under
+    `name`; one thread-local read where no dispatch is being timed."""
+    clock = getattr(_local, "clock", None)
+    if clock is not None:
+        clock.mark(name)
+
+
+class _ChildScope:
+    """A child span that is the thread's current span while the scope runs."""
+
+    __slots__ = ("span", "prev")
+
+    def __init__(self, span):
+        self.span = span
+
+    def __enter__(self):
+        self.prev = getattr(_local, "span", None)
+        _local.span = self.span
+        return self.span
+
+    def __exit__(self, *exc) -> None:
+        _local.span = self.prev
+        self.span.end()
+
+
+class _NullScope:
+    __slots__ = ()
+
+    def __enter__(self):
+        return NOOP_SPAN
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+
+_NULL_SCOPE = _NullScope()
+
+
+def child_scope(parent, name: str, **tags):
+    """Open `name` under `parent` and make it the thread's current span for
+    the scope, so that spans started inside (the transport round-trips of a
+    coordinator phase) nest under it; ended on exit. An unsampled parent
+    (None or the NOOP span) gets one shared no-op scope: no allocation, no
+    clock read, the thread-local untouched."""
+    if not parent:
+        return _NULL_SCOPE
+    return _ChildScope(parent.child(name).tag(**tags))
+
+
 def wire_context(span) -> TraceContext | None:
     """The context to ship with an outbound request parented at `span` —
     the ONE construction site for the wire shape (transport injection and
@@ -99,14 +208,6 @@ def wire_context(span) -> TraceContext | None:
     if not span:
         return None
     return TraceContext(span.trace.trace_id, span.span_id)
-
-
-def sync_armed() -> bool:
-    """ESTPU_TRACE_SYNC=1: precise device timing for bench/debug — the batcher
-    drainer blocks until the dispatched launches complete so the dispatch span
-    measures true device time. NEVER the default: it serializes the
-    double-buffered dispatch/merge overlap."""
-    return os.environ.get("ESTPU_TRACE_SYNC", "") == "1"
 
 
 # ---------------------------------------------------------------------------
@@ -236,7 +337,8 @@ class Trace:
                  "_lock", "_spans", "_open", "_finished", "_in_ring", "_seq")
 
     def __init__(self, tracer: "Tracer", name: str,
-                 trace_id: str | None = None, parent_id: int | None = None):
+                 trace_id: str | None = None, parent_id: int | None = None,
+                 t0: float | None = None):
         self.tracer = tracer
         # not uuid4: ~30us/call vs ~1us for getrandbits, and a trace id only
         # needs uniqueness, not RFC-4122 shape — this runs once per sampled
@@ -251,7 +353,7 @@ class Trace:
         self._in_ring = False  # snapshot committed (guarded by tracer ring lock)
         self._seq = next(tracer._trace_seq)  # ring identity (trace_id repeats
         # within one tracer when two local shards continue the same trace)
-        self.root = Span(self, name, parent_id)
+        self.root = Span(self, name, parent_id, t0)
 
     def __bool__(self) -> bool:
         return True
@@ -443,12 +545,16 @@ class Tracer:
         r = self.sample_rate
         return r > 0.0 and (r >= 1.0 or random.random() < r)
 
-    def start_trace(self, name: str, force: bool = False):
+    def start_trace(self, name: str, force: bool = False,
+                    t0: float | None = None):
         """Root a new trace here (REST ingress / coordinator). `force=True` is
-        the `?trace=true` override — sampled regardless of the rate."""
+        the `?trace=true` override — sampled regardless of the rate. `t0`
+        back-dates the root to a host-monotonic instant the caller already
+        read (the HTTP layer's arrival stamp), so the root covers the body
+        parse that ran before the sampling decision."""
         if not force and not self._sampled():
             return NOOP_TRACE
-        return self._register(Trace(self, name))
+        return self._register(Trace(self, name, t0=t0))
 
     def continue_trace(self, wire, name: str):
         """Continue a trace whose context arrived over the wire (shard side).

@@ -10,11 +10,13 @@ from __future__ import annotations
 
 import json
 import threading
+import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from urllib.parse import parse_qsl, unquote_plus, urlparse
 
 from ..common import xcontent
 from ..common.logging import get_logger
+from ..common.metrics import HistogramMetric
 from ..rest.controller import RestController, RestRequest, RestResponse
 
 
@@ -24,11 +26,16 @@ class HttpServer:
         self.rest = rest_controller
         self.logger = get_logger("http")
         rest = self.rest
+        # what follows the handler: encoding the response and writing it to
+        # the socket. The request's span tree closes before this, so it is
+        # timed here for every request (`/_nodes/stats` `http.respond`)
+        respond = self.respond = HistogramMetric()
 
         class Handler(BaseHTTPRequestHandler):
             protocol_version = "HTTP/1.1"
 
             def _handle(self, method: str):
+                t_arrival = time.monotonic()
                 parsed = urlparse(self.path)
                 length = int(self.headers.get("Content-Length") or 0)
                 raw_bytes = self.rfile.read(length) if length else b""
@@ -79,8 +86,10 @@ class HttpServer:
                     if seg and "=" not in seg:
                         params.setdefault(unquote_plus(seg), "")
                 request = RestRequest(
-                    method=method, path=parsed.path, params=params, body=body)
+                    method=method, path=parsed.path, params=params, body=body,
+                    t_arrival=t_arrival)
                 response = rest.dispatch(request)
+                t_handled = time.monotonic()
                 # response rides the request's format, or an explicit ?format=
                 out_fmt = xcontent.from_content_type(
                     "application/" + request.params.get("format", "")) or fmt
@@ -107,6 +116,7 @@ class HttpServer:
                 self.end_headers()
                 if method != "HEAD":
                     self.wfile.write(payload)
+                respond.observe(time.monotonic() - t_handled)
 
             def do_GET(self):
                 self._handle("GET")
@@ -143,6 +153,11 @@ class HttpServer:
         self._thread.start()
         self.logger.info("http listening on %s:%d", self.host, self.port)
         return self
+
+    def stats(self) -> dict:
+        """`/_nodes/stats` `http`: `respond` is response encode + socket
+        write, seconds summed and as percentiles."""
+        return {"respond": {**self.respond.stats(), "sum_s": self.respond.sum}}
 
     def stop(self):
         self._server.shutdown()

@@ -15,6 +15,36 @@ import resource
 import time
 
 
+class GcPauses:
+    """Seconds the process stood still in full (generation 2) collections:
+    a `gc.callbacks` hook that reads the clock at the start and the stop of
+    each one. The younger generations run thousands of times a second and
+    are left alone. One per process, installed by the first MonitorService."""
+
+    def __init__(self):
+        self.pause_s = 0.0
+        self._t0: float | None = None
+        self._installed = False
+
+    def install(self) -> None:
+        if not self._installed:
+            self._installed = True
+            gc.callbacks.append(self._on_gc)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        # called with the GIL held, by whichever thread tripped the collector
+        if info.get("generation") != 2:
+            return
+        if phase == "start":
+            self._t0 = time.monotonic()
+        elif self._t0 is not None:
+            self.pause_s += time.monotonic() - self._t0
+            self._t0 = None
+
+
+GC_PAUSES = GcPauses()
+
+
 def os_stats(proc: str = "/proc") -> dict:
     """`proc` overrides the procfs root so tests can feed canned fixtures
     (tests/test_monitor.py) — production always reads the real /proc."""
@@ -104,7 +134,9 @@ def runtime_stats() -> dict:
         "runtime": "python",
         "version": sys.version.split()[0],
         "gc": {"collections": gc.get_stats()[-1].get("collections", 0)
-               if gc.get_stats() else 0, "pending": sum(counts)},
+               if gc.get_stats() else 0, "pending": sum(counts),
+               # seconds inside those full collections, since the hook went in
+               "pause_s": GC_PAUSES.pause_s},
         "uptime_in_millis": int(time.monotonic() * 1000),
     }
     try:
@@ -137,6 +169,7 @@ def runtime_stats() -> dict:
 class MonitorService:
     def __init__(self, node):
         self.node = node
+        GC_PAUSES.install()
 
     def sections(self) -> dict:
         """Monitor stats as name -> thunk, so `/_nodes/stats/{metric}` can

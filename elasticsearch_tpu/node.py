@@ -1060,6 +1060,10 @@ class Client:
             # vs host scorer; process-wide rollup)
             ms = getattr(self.node.actions, "mesh_serving", None)
             serving = dict(SERVING_COUNTERS)
+            # what the scoring launches touched (ops/scoring.LaunchCounters)
+            from .ops.scoring import LAUNCHES
+
+            serving["launch"] = LAUNCHES.snapshot()
             if ms is not None:
                 serving["mesh_spmd"] = ms.mesh_queries
                 serving["mesh_fallbacks"] = ms.mesh_fallbacks
@@ -1108,6 +1112,8 @@ class Client:
                 "journal": self.node.events.stats(),
                 "watchdog": self.node.watchdog.stats()},
             "search_serving": serving_stats,
+            # response encode + socket write, which no span can hold
+            "http": lambda: self.node.http.stats() if self.node.http else {},
             # request-scoped tracing: sample rate, ring occupancy, in-flight
             "tracing": lambda: self.node.tracer.stats(),
             # adaptive replica selection: per-copy rank inputs (latency EWMA/
@@ -1137,12 +1143,15 @@ class Client:
         from .common.devicehealth import DEVICE_HEALTH
         from .common.jaxenv import (compile_events_by_family,
                                     compile_events_by_pool,
-                                    compile_events_total)
+                                    compile_events_total, compile_seconds)
         from .ops.device_index import capacity_report
 
         out = capacity_report(self.node.indices)
         out["compile"] = {"total": compile_events_total(),
                           "by_family": compile_events_by_family(),
+                          # what the events cost, and the persistent
+                          # cache's hits and misses among them
+                          **compile_seconds(),
                           # pool attribution: a warmed node's serving pools
                           # (search/flat/mesh) should read 0 here — every
                           # compile lands on warmer/startup threads

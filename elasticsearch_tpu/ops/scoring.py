@@ -33,6 +33,7 @@ from dataclasses import dataclass, field as dc_field
 import numpy as np
 
 from ..common import profile as _profile
+from ..common import tracing as _tracing
 from ..common.breaker import reserve
 from ..common.compilecache import REGISTRY as _WARM
 from ..common.jaxenv import current_compile_family
@@ -74,6 +75,7 @@ class TermBatch:
     norm_fields: list = dc_field(default_factory=list)  # field names, order = fidx
     caches: np.ndarray | None = None  # float32 [F, 256]
     simple: bool | None = None  # cached fast-path eligibility (computed on first use)
+    blocks_real: int = 0  # triples that name a postings block (the rest of M pads)
 
 
 @dataclass
@@ -100,21 +102,17 @@ def _score_batch_impl(blk_docs, blk_freqs, live_parent, norms_stack, caches,
         tfmode, Q=Q, doc_pad=doc_pad)
 
     if simple:
-        match = (scores > 0.0) & live_parent[None, :doc_pad]
-        neg_inf = jnp.float32(-jnp.inf)
-        masked = jnp.where(match, scores, neg_inf)
+        with jax.named_scope("match_coord"):
+            match = (scores > 0.0) & live_parent[None, :doc_pad]
+    else:
+        scores, match = _dense_semantics(scores, flat_idx, valid, group, live_parent,
+                                         n_must, msm, coord, Q=Q, doc_pad=doc_pad)
+    # sentinel substitution + max_score are [Q, k]-tiny — done host-side in
+    # score_term_batch, not appended to this program
+    with jax.named_scope("top_k"):
+        masked = jnp.where(match, scores, jnp.float32(-jnp.inf))
         top_scores, top_docs = jax.lax.top_k(masked, k)
         total = match.sum(axis=1, dtype=jnp.int32)
-        # sentinel substitution + max_score are [Q, k]-tiny — done host-side in
-        # score_term_batch, not appended to this program
-        return top_scores, top_docs, total
-
-    scores, match = _dense_semantics(scores, flat_idx, valid, group, live_parent,
-                                     n_must, msm, coord, Q=Q, doc_pad=doc_pad)
-    neg_inf = jnp.float32(-jnp.inf)
-    masked = jnp.where(match, scores, neg_inf)
-    top_scores, top_docs = jax.lax.top_k(masked, k)
-    total = match.sum(axis=1, dtype=jnp.int32)
     return top_scores, top_docs, total
 
 
@@ -123,34 +121,38 @@ def _dense_accumulate(blk_docs, blk_freqs, norms_stack, caches,
     """Steps 1-3 of the dense kernel: gather postings blocks, per-posting
     contributions, scatter-add into the [Q, doc_pad] accumulator. Returns
     (scores, flat_idx, valid) for the semantics pass."""
+    import jax
     import jax.numpy as jnp
 
-    docs = blk_docs[blk]  # [M, B] int32; padded rows → doc_pad sentinel
-    freqs = blk_freqs[blk]  # [M, B]
-    valid = docs < doc_pad
-    docs_safe = jnp.where(valid, docs, 0)
+    with jax.named_scope("gather_decode"):
+        docs = blk_docs[blk]  # [M, B] int32; padded rows → doc_pad sentinel
+        freqs = blk_freqs[blk]  # [M, B]
+        valid = docs < doc_pad
+        docs_safe = jnp.where(valid, docs, 0)
 
-    nb = norms_stack[fidx[:, None], docs_safe]  # [M, B] uint8
-    cache_vals = caches[fidx[:, None], nb.astype(jnp.int32)]  # [M, B]
+        nb = norms_stack[fidx[:, None], docs_safe]  # [M, B] uint8
+        cache_vals = caches[fidx[:, None], nb.astype(jnp.int32)]  # [M, B]
 
-    # float op ORDER matters for bit-parity with the host scorer and the sparse
-    # kernel's in-scan tfn (sparse_candidates): the tf factor is computed FIRST,
-    # then multiplied by the weight — Lucene's weight·tfNorm order
-    # (BM25Similarity.BM25DocScorer / TFIDFSimilarity.ExactSimScorer)
-    mode = tfmode[:, None]
-    w = weight[:, None]
-    bm25 = w * (freqs / (freqs + cache_vals))
-    tfidf = w * (jnp.sqrt(freqs) * cache_vals)
-    contrib = jnp.where(mode == MODE_BM25, bm25, jnp.where(mode == MODE_TFIDF, tfidf, w))
-    scoring = (group[:, None] != GROUP_MUST_NOT) & valid
-    contrib = jnp.where(scoring, contrib, 0.0)
+        # float op ORDER matters for bit-parity with the host scorer and the sparse
+        # kernel's in-scan tfn (sparse_candidates): the tf factor is computed FIRST,
+        # then multiplied by the weight — Lucene's weight·tfNorm order
+        # (BM25Similarity.BM25DocScorer / TFIDFSimilarity.ExactSimScorer)
+        mode = tfmode[:, None]
+        w = weight[:, None]
+        bm25 = w * (freqs / (freqs + cache_vals))
+        tfidf = w * (jnp.sqrt(freqs) * cache_vals)
+        contrib = jnp.where(mode == MODE_BM25, bm25,
+                            jnp.where(mode == MODE_TFIDF, tfidf, w))
+        scoring = (group[:, None] != GROUP_MUST_NOT) & valid
+        contrib = jnp.where(scoring, contrib, 0.0)
 
-    qd = (qidx[:, None] * (doc_pad + 1))
-    flat_idx = jnp.where(valid, qd + docs_safe, Q * (doc_pad + 1))  # OOB → dropped
+    with jax.named_scope("scatter_add"):
+        qd = (qidx[:, None] * (doc_pad + 1))
+        flat_idx = jnp.where(valid, qd + docs_safe, Q * (doc_pad + 1))  # OOB → dropped
 
-    scores = jnp.zeros(Q * (doc_pad + 1), jnp.float32).at[flat_idx.reshape(-1)].add(
-        contrib.reshape(-1), mode="drop"
-    ).reshape(Q, doc_pad + 1)[:, :doc_pad]
+        scores = jnp.zeros(Q * (doc_pad + 1), jnp.float32).at[flat_idx.reshape(-1)].add(
+            contrib.reshape(-1), mode="drop"
+        ).reshape(Q, doc_pad + 1)[:, :doc_pad]
     return scores, flat_idx, valid
 
 
@@ -159,46 +161,129 @@ def _dense_semantics(scores, flat_idx, valid, group, live_parent, n_must, msm, c
     """Bool-query semantics + coord over the dense accumulator: returns the
     coord-scaled scores and the match mask (shared by the plain dense kernel and
     the function_score variants below)."""
+    import jax
     import jax.numpy as jnp
 
-    counters = (
-        jnp.where(group == GROUP_SHOULD, 1, 0)
-        + jnp.where(group == GROUP_MUST, 1 << _MUST_SHIFT, 0)
-        + jnp.where(group == GROUP_MUST_NOT, 1 << _NOT_SHIFT, 0)
-    ).astype(jnp.int32)
-    counter_vals = jnp.where(valid, counters[:, None], 0)
-    counts = jnp.zeros(Q * (doc_pad + 1), jnp.int32).at[flat_idx.reshape(-1)].add(
-        counter_vals.reshape(-1), mode="drop"
-    ).reshape(Q, doc_pad + 1)[:, :doc_pad]
+    with jax.named_scope("scatter_add"):
+        counters = (
+            jnp.where(group == GROUP_SHOULD, 1, 0)
+            + jnp.where(group == GROUP_MUST, 1 << _MUST_SHIFT, 0)
+            + jnp.where(group == GROUP_MUST_NOT, 1 << _NOT_SHIFT, 0)
+        ).astype(jnp.int32)
+        counter_vals = jnp.where(valid, counters[:, None], 0)
+        counts = jnp.zeros(Q * (doc_pad + 1), jnp.int32).at[flat_idx.reshape(-1)].add(
+            counter_vals.reshape(-1), mode="drop"
+        ).reshape(Q, doc_pad + 1)[:, :doc_pad]
 
-    m_should = counts & 0x3FF
-    m_must = (counts >> _MUST_SHIFT) & 0x3FF
-    m_not = counts >> _NOT_SHIFT
+    with jax.named_scope("match_coord"):
+        m_should = counts & 0x3FF
+        m_must = (counts >> _MUST_SHIFT) & 0x3FF
+        m_not = counts >> _NOT_SHIFT
 
-    match = (m_must == n_must[:, None]) & (m_should >= msm[:, None]) & (m_not == 0)
-    match = match & ((m_should + m_must) > 0) & live_parent[None, :doc_pad]
+        match = (m_must == n_must[:, None]) & (m_should >= msm[:, None]) & (m_not == 0)
+        match = match & ((m_should + m_must) > 0) & live_parent[None, :doc_pad]
 
-    overlap = jnp.minimum(m_should + m_must, coord.shape[1] - 1)
-    # per-row lookup into the small [Q, C+1] coord table as a static select-sum —
-    # take_along_axis lowers to a serialized per-element gather on TPU (measured
-    # ~1.3s for [1024, 128k] vs ~5ms for C+1 fused compare+FMA passes)
-    coord_fac = jnp.zeros_like(scores)
-    for j in range(coord.shape[1]):
-        coord_fac = coord_fac + jnp.where(overlap == j, coord[:, j][:, None], 0.0)
-    return scores * coord_fac, match
+        overlap = jnp.minimum(m_should + m_must, coord.shape[1] - 1)
+        # per-row lookup into the small [Q, C+1] coord table as a static select-sum —
+        # take_along_axis lowers to a serialized per-element gather on TPU (measured
+        # ~1.3s for [1024, 128k] vs ~5ms for C+1 fused compare+FMA passes)
+        coord_fac = jnp.zeros_like(scores)
+        for j in range(coord.shape[1]):
+            coord_fac = coord_fac + jnp.where(overlap == j, coord[:, j][:, None], 0.0)
+        return scores * coord_fac, match
 
 
 _compiled_cache: dict = {}
 
 
-def _record(site: str, family: str, params: tuple, args) -> None:
-    """Register this launch's executable with the compile-warm registry
-    (common/compilecache): first sighting of a (site, params, arg shapes)
-    signature stores a JSON-able WarmSpec the warmer replays at startup /
-    post-restart, so the NEXT process never pays this compile on-path. The
-    active compile_tag family wins attribution (a percolation's inner dense
-    launch warms under its `compile:percolate` circuit)."""
-    _WARM.record_launch(site, current_compile_family() or family, params, args)
+def _named(site: str, fn, variant: str = ""):
+    """`fn` renamed after its launch site, for jax.jit: `scoring.sparse`
+    compiles as `jit_estpu_scoring_sparse`, so the profiler's `XLA Modules`
+    line, HLO dumps and compile logs tell the programs apart (every one was
+    `jit_wrapper`). `variant` is for a site that serves two programs through
+    a static flag. The name is part of the persistent compile cache's key."""
+    fn.__name__ = fn.__qualname__ = "estpu_" + site.replace(".", "_") + (
+        "_" + variant if variant else "")
+    return fn
+
+
+class LaunchCounters:
+    """Always-on tallies of what the scoring launches touched, from numbers
+    the launch site holds anyway (`/_nodes/stats` `search_serving.launch`; a
+    process rollup like service.SERVING_COUNTERS).
+
+    `blocks_real` are the postings blocks the queries name, `blocks_launched`
+    what the padded launch shape scans (`blocks_padding` their difference).
+    `posting_bytes` is the program's own reckoning of the HBM bytes a launch
+    reads, no measurement; each formula sits beside its launch
+    (score_sparse_batch_async, _count_dense). `dense_rows` counts the
+    [doc_pad]-wide score rows of the dense launches."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._c = dict.fromkeys(
+            ("blocks_real", "blocks_launched", "blocks_padding",
+             "posting_bytes", "dense_rows", "launches_sparse",
+             "launches_dense"), 0)
+
+    def add(self, real: int, launched: int, nbytes: int,
+            dense_rows: int = 0) -> None:
+        with self._lock:
+            c = self._c
+            c["blocks_real"] += real
+            c["blocks_launched"] += launched
+            c["blocks_padding"] += launched - real
+            c["posting_bytes"] += nbytes
+            c["dense_rows"] += dense_rows
+            c["launches_dense" if dense_rows else "launches_sparse"] += 1
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return dict(self._c)
+
+
+LAUNCHES = LaunchCounters()
+
+
+def _launch(fn, args, site: str | None = None, family: str = "", params=()):
+    """One call into a compiled program, with this thread's dispatch clock
+    (common/tracing.DispatchClock) marked on both sides: what ran since the
+    last mark was host staging, the call itself is the launch (a first
+    sighting compiles inside it). `site` registers the executable with the
+    compile-warm registry (common/compilecache): the first sighting of a
+    (site, params, arg shapes) signature stores a JSON-able WarmSpec the
+    warmer replays at startup / post-restart, so the NEXT process never pays
+    this compile on-path. The active compile_tag family wins attribution (a
+    percolation's inner dense launch warms under `compile:percolate`)."""
+    _tracing.mark("dispatch.stage")
+    out = fn(*args)
+    if site is not None:
+        _WARM.record_launch(site, current_compile_family() or family, params,
+                            args)
+    _tracing.mark("dispatch.launch")
+    return out
+
+
+def _pull(out):
+    """The one device_get of a family that pulls inside its dispatch (the
+    whole result pytree in ONE explicit transfer; None leaves pass through),
+    closed on the dispatch clock as `device_pull` and named on the profiler's
+    clock inside the drainer's estpu.batch.dispatch annotation."""
+    import jax
+
+    with jax.profiler.TraceAnnotation("estpu.batch.pull"):
+        host = jax.device_get(out)
+    _tracing.mark("device_pull")
+    return host
+
+
+def _count_dense(packed: PackedSegment, batch: TermBatch) -> None:
+    """A dense launch reads, per launched (query, block) triple, BLOCK slots
+    of doc id i32 + freq f32 + one gathered norm byte, and top_k reads the
+    [Q, doc_pad] f32 score plane back."""
+    m, q = len(batch.blk), batch.n_queries
+    LAUNCHES.add(batch.blocks_real, m,
+                 m * BLOCK * (4 + 4 + 1) + q * packed.doc_pad * 4, dense_rows=q)
 
 
 def _get_compiled(n_queries: int, k: int, doc_pad: int, simple: bool = False):
@@ -211,7 +296,7 @@ def _get_compiled(n_queries: int, k: int, doc_pad: int, simple: bool = False):
             return _score_batch_impl(*args, n_queries=n_queries, k=k, doc_pad=doc_pad,
                                      simple=simple)
 
-        fn = jax.jit(wrapper)
+        fn = jax.jit(_named("scoring.dense", wrapper, "simple" if simple else "bool"))
         _compiled_cache[key] = fn
     return fn
 
@@ -339,7 +424,7 @@ def _get_fs_compiled(kind: str, n_queries: int, k: int, doc_pad: int, **statics)
         def wrapper(*args):
             return impl(*args, n_queries=n_queries, k=k, doc_pad=doc_pad, **statics)
 
-        fn = jax.jit(wrapper)
+        fn = jax.jit(_named("scoring.fs_" + kind, wrapper))
         _compiled_cache[key] = fn
     return fn
 
@@ -374,7 +459,6 @@ def score_fs_rows_batch(packed: PackedSegment, batch: TermBatch, k: int,
                         min_score, bmode: str, no_functions: bool):
     """Dense launch with host-combined function rows; returns (scores, docs, total)
     numpy [Q, k]/[Q]."""
-    import jax
     import jax.numpy as jnp
 
     norms_stack, caches = _stack_args(packed, batch)
@@ -393,11 +477,10 @@ def score_fs_rows_batch(packed: PackedSegment, batch: TermBatch, k: int,
         _scalar_f32(max_boost), _scalar_f32(fboost),
         _scalar_f32(min_score if min_score is not None else 0.0),
     )
-    out = fn(*args)
+    _count_dense(packed, batch)
     # the script variant is NOT recorded: its executable closes over a live
     # sandboxed script object that has no JSON form to replay from a manifest
-    _record("scoring.fs_rows", "function_score", params, args)
-    return jax.device_get(out)
+    return _pull(_launch(fn, args, "scoring.fs_rows", "function_score", params))
 
 
 def score_fs_script_batch(packed: PackedSegment, batch: TermBatch, k: int,
@@ -414,7 +497,8 @@ def score_fs_script_batch(packed: PackedSegment, batch: TermBatch, k: int,
         script=script, used_fields=used_fields, bmode=bmode,
         use_min_score=min_score is not None, has_filter=has_filter,
         has_weight=weight is not None)
-    top_scores, top_docs, total, bad = fn(
+    _count_dense(packed, batch)
+    return _pull(_launch(fn, (
         packed.blk_docs, ensure_blk_freqs(packed), packed.live_parent,
         norms_stack, caches,
         jnp.asarray(batch.qidx), jnp.asarray(batch.blk), jnp.asarray(batch.weight),
@@ -426,9 +510,7 @@ def score_fs_script_batch(packed: PackedSegment, batch: TermBatch, k: int,
         _scalar_f32(weight if weight is not None else 1.0),
         _scalar_f32(max_boost), _scalar_f32(fboost),
         _scalar_f32(min_score if min_score is not None else 0.0),
-    )
-    return (np.asarray(top_scores), np.asarray(top_docs), np.asarray(total),
-            np.asarray(bad))
+    )))
 
 
 # ---------------------------------------------------------------------------
@@ -450,7 +532,7 @@ def score_filtered_batch(packed: PackedSegment, batch: TermBatch, k: int, fmask)
     kernel family to keep in sync. Returns numpy (scores, docs, total)."""
     empty = np.zeros((0, 5, packed.doc_pad), np.float32)
     scores, docs, total, _counts, _stats, _buckets = score_agg_batch(
-        packed, batch, k, empty, (), fmask=fmask)
+        packed, batch, k, empty, (), fmask=fmask, filtered=True)
     return scores, docs, total
 
 
@@ -500,7 +582,7 @@ def _get_sorted_compiled(n_queries: int, k: int, doc_pad: int,
             return _dense_sort_impl(*args, n_queries=n_queries, k=k,
                                     doc_pad=doc_pad, descending=descending)
 
-        fn = jax.jit(wrapper)
+        fn = jax.jit(_named("scoring.sorted", wrapper))
         _compiled_cache[key] = fn
     return fn
 
@@ -510,7 +592,6 @@ def score_sorted_batch(packed: PackedSegment, batch: TermBatch, k: int,
     """Field-sorted dense launch; returns numpy (keys, docs, scores, qmax,
     total). Matched docs occupy the first min(total, k) slots per query
     (padding ranks strictly after ±FLT_MAX missing keys)."""
-    import jax
     import jax.numpy as jnp
 
     norms_stack, caches = _stack_args(packed, batch)
@@ -527,10 +608,8 @@ def score_sorted_batch(packed: PackedSegment, batch: TermBatch, k: int,
         jnp.asarray(batch.n_must), jnp.asarray(batch.msm), jnp.asarray(batch.coord),
         jnp.asarray(fmask), jnp.asarray(key_row),
     )
-    top_keys, top_docs, top_scores, qmax, total = fn(*args)
-    _record("scoring.sorted", "sorted", params, args)
-    return (np.asarray(top_keys), np.asarray(top_docs), np.asarray(top_scores),
-            np.asarray(qmax), np.asarray(total))
+    _count_dense(packed, batch)
+    return _pull(_launch(fn, args, "scoring.sorted", "sorted", params))
 
 
 def agg_stat_reduction(match, agg_rows):
@@ -617,39 +696,44 @@ def _dense_aggstats_impl(blk_docs, blk_freqs, live_parent, norms_stack, caches,
     return top_scores, top_docs, total, counts, stats, bucket_counts
 
 
-def _get_agg_compiled(n_queries: int, k: int, doc_pad: int, nb_bucket: int):
+def _get_agg_compiled(n_queries: int, k: int, doc_pad: int, nb_bucket: int,
+                      filtered: bool = False):
     import jax
 
     # bucket-agg count rides the pow-2 ladder: the wrapper is generic over the
     # pairs pytree (jit retraces per structure under ONE cache entry), so a
-    # raw len() here would admit one executable per distinct agg count
-    key = ("aggstats", n_queries, k, doc_pad, nb_bucket)
+    # raw len() here would admit one executable per distinct agg count.
+    # `filtered` only names the program: the filtered family rides this site
+    # with an empty agg stack, and its launches should read as its own.
+    key = ("aggstats", n_queries, k, doc_pad, nb_bucket, filtered)
     fn = _compiled_cache.get(key)
     if fn is None:
         def wrapper(*args):
             return _dense_aggstats_impl(*args, n_queries=n_queries, k=k,
                                         doc_pad=doc_pad)
 
-        fn = jax.jit(wrapper)
+        fn = jax.jit(_named("scoring.aggs", wrapper, "filtered" if filtered else ""))
         _compiled_cache[key] = fn
     return fn
 
 
 def score_agg_batch(packed: PackedSegment, batch: TermBatch, k: int,
-                    agg_row_stack, bucket_pairs=(), fmask=None):
+                    agg_row_stack, bucket_pairs=(), fmask=None,
+                    filtered: bool = False):
     """Dense launch returning (scores, docs, total, counts [Q, F] int,
     stats [Q, F, 4], bucket results) numpy. stats rows: (sum, min(+inf if none),
     max(-inf), sumsq) over matched docs per agg field; bucket_pairs: per bucket
     agg, (pair_doc, pair_bucket, zeros[NB], sub_stack [Fs,5,Dpad]|None) device
     arrays — each bucket result is (doc counts [Q,NB], sub value-counts
     [Q,Fs,NB]|None, sub stats [Q,Fs,NB,4]|None); fmask: optional bool [Q, Dpad]
-    FilteredQuery match gates."""
-    import jax
+    FilteredQuery match gates; `filtered` marks the filtered family's launches
+    (no aggregation at all), which compile under their own program name."""
     import jax.numpy as jnp
 
     norms_stack, caches = _stack_args(packed, batch)
     params = (batch.n_queries, min(k, packed.doc_pad), packed.doc_pad,
-              _pow2_bucket(len(bucket_pairs), 1) if bucket_pairs else 0)
+              _pow2_bucket(len(bucket_pairs), 1) if bucket_pairs else 0,
+              filtered)
     fn = _get_agg_compiled(*params)
     if fmask is None:
         # broadcastable no-op mask: [1, 1] & [Q, Dpad] — avoids allocating and
@@ -665,12 +749,11 @@ def score_agg_batch(packed: PackedSegment, batch: TermBatch, k: int,
         # arrays); a raw numpy arg would be an implicit H2D at dispatch
         jnp.asarray(agg_row_stack), tuple(bucket_pairs), jnp.asarray(fmask),
     )
-    out = fn(*args)
-    _record("scoring.aggs", "aggs", params, args)
-    # ONE explicit pull for the whole result pytree (None leaves pass through):
-    # per-leaf np.asarray was a transfer per output — and an implicit one, which
-    # the promoted transfer_guard("disallow") sanitizer now rejects
-    return jax.device_get(out)
+    _count_dense(packed, batch)
+    # ONE explicit pull for the whole result pytree: per-leaf np.asarray was a
+    # transfer per output — and an implicit one, which the promoted
+    # transfer_guard("disallow") sanitizer now rejects
+    return _pull(_launch(fn, args, "scoring.aggs", "aggs", params))
 
 
 def _detect_simple(batch: TermBatch) -> bool:
@@ -707,9 +790,8 @@ def score_term_batch_async(packed: PackedSegment, batch: TermBatch, k: int):
         jnp.asarray(batch.fidx), jnp.asarray(batch.group), jnp.asarray(batch.tfmode),
         jnp.asarray(batch.n_must), jnp.asarray(batch.msm), jnp.asarray(batch.coord),
     )
-    out = fn(*args)
-    _record("scoring.dense", "dense", params, args)
-    return out
+    _count_dense(packed, batch)
+    return _launch(fn, args, "scoring.dense", "dense", params)
 
 
 def score_term_batch(packed: PackedSegment, batch: TermBatch, k: int) -> ScoreResult:
@@ -728,10 +810,10 @@ def score_term_batch(packed: PackedSegment, batch: TermBatch, k: int) -> ScoreRe
         jnp.asarray(batch.fidx), jnp.asarray(batch.group), jnp.asarray(batch.tfmode),
         jnp.asarray(batch.n_must), jnp.asarray(batch.msm), jnp.asarray(batch.coord),
     )
-    top_scores, top_docs, total = fn(*args)
-    _record("scoring.dense", "dense", params, args)
-    return finalize_score_result(np.asarray(top_scores), np.asarray(top_docs),
-                                 np.asarray(total), packed.doc_pad)
+    _count_dense(packed, batch)
+    top_scores, top_docs, total = _pull(
+        _launch(fn, args, "scoring.dense", "dense", params))
+    return finalize_score_result(top_scores, top_docs, total, packed.doc_pad)
 
 
 def finalize_score_result(scores: np.ndarray, docs: np.ndarray, total: np.ndarray,
@@ -861,6 +943,7 @@ class SparseBatch:
     coord: np.ndarray  # float32 [Qb, C+1]
     passes: int  # segment-sum doubling passes = ceil(log2(max clauses per query))
     simple: bool  # pure-should all-BM25 msm<=1 no-coord (match ≡ score>0)
+    blocks_real: int = 0  # block slots of [Qb, TB] that name a postings block
 
 
 def sparse_candidates(blk_docs, blk_tf, blk_nb, caches, modes,
@@ -874,21 +957,23 @@ def sparse_candidates(blk_docs, blk_tf, blk_nb, caches, modes,
 
     Returns (docs [Qb, TB, B] i32, contrib [Qb, TB, B] f32 — zeroed on invalid
     slots, valid [Qb, TB, B] bool)."""
+    import jax
     import jax.numpy as jnp
 
-    docs = blk_docs[qblk]  # [Qb, TB, B]
-    tf = blk_tf[qblk].astype(jnp.float32)  # u8/i16 widen; f32 escape = no-op
-    nb = blk_nb[qblk].astype(jnp.int32)
-    # per-field LUT decode as ONE flat gather (row*256 + byte) — XLA lowers a
-    # single-index gather better than the 2-axis advanced-indexing form
-    cv = caches.reshape(-1)[qfid[:, :, None] * 256 + nb]  # [Qb, TB, B]
-    mode = modes[qfid][:, :, None]
-    # tf factor first, then weight — Lucene's weight·tfNorm rounding order
-    # (shared with the dense kernel and HostScorer)
-    tfn = jnp.where(mode == TFN_BM25, tf / (tf + cv), jnp.sqrt(tf) * cv)
-    contrib = qw[:, :, None] * jnp.where(qconst[:, :, None], 1.0, tfn)
-    valid = docs < doc_pad
-    return docs, jnp.where(valid, contrib, 0.0), valid
+    with jax.named_scope("gather_decode"):
+        docs = blk_docs[qblk]  # [Qb, TB, B]
+        tf = blk_tf[qblk].astype(jnp.float32)  # u8/i16 widen; f32 escape = no-op
+        nb = blk_nb[qblk].astype(jnp.int32)
+        # per-field LUT decode as ONE flat gather (row*256 + byte) — XLA lowers a
+        # single-index gather better than the 2-axis advanced-indexing form
+        cv = caches.reshape(-1)[qfid[:, :, None] * 256 + nb]  # [Qb, TB, B]
+        mode = modes[qfid][:, :, None]
+        # tf factor first, then weight — Lucene's weight·tfNorm rounding order
+        # (shared with the dense kernel and HostScorer)
+        tfn = jnp.where(mode == TFN_BM25, tf / (tf + cv), jnp.sqrt(tf) * cv)
+        contrib = qw[:, :, None] * jnp.where(qconst[:, :, None], 1.0, tfn)
+        valid = docs < doc_pad
+        return docs, jnp.where(valid, contrib, 0.0), valid
 
 
 def sparse_reduce(docs, contrib, cnt, n_must, msm, coord,
@@ -923,39 +1008,47 @@ def sparse_reduce(docs, contrib, cnt, n_must, msm, coord,
             vals_list = out
         return vals_list
 
+    def top(docs_s, c_s, match):
+        with jax.named_scope("top_k"):
+            masked = jnp.where(match, c_s, -jnp.inf)
+            top_scores, idx = jax.lax.top_k(masked, k)
+            top_docs = jnp.take_along_axis(docs_s, idx, axis=1)
+            return top_scores, top_docs, match.sum(axis=1, dtype=jnp.int32)
+
     if simple:
-        docs_s, c_s = jax.lax.sort((docs, contrib), num_keys=1)
-        (c_s,) = segsum(docs_s, [c_s])
+        with jax.named_scope("sort_by_doc"):
+            docs_s, c_s = jax.lax.sort((docs, contrib), num_keys=1)
+        with jax.named_scope("segment_sum"):
+            (c_s,) = segsum(docs_s, [c_s])
+        with jax.named_scope("match_coord"):
+            is_last = jnp.concatenate(
+                [docs_s[:, :-1] != docs_s[:, 1:], jnp.ones((Qb, 1), bool)], axis=1)
+            match = is_last & (docs_s < doc_pad) & (c_s > 0.0)
+        return top(docs_s, c_s, match)
+
+    with jax.named_scope("sort_by_doc"):
+        docs_s, c_s, n_s = jax.lax.sort((docs, contrib, cnt), num_keys=1)
+    with jax.named_scope("segment_sum"):
+        c_s, n_s = segsum(docs_s, [c_s, n_s])
+    with jax.named_scope("match_coord"):
         is_last = jnp.concatenate(
             [docs_s[:, :-1] != docs_s[:, 1:], jnp.ones((Qb, 1), bool)], axis=1)
-        match = is_last & (docs_s < doc_pad) & (c_s > 0.0)
-        masked = jnp.where(match, c_s, -jnp.inf)
-        top_scores, idx = jax.lax.top_k(masked, k)
-        top_docs = jnp.take_along_axis(docs_s, idx, axis=1)
-        return top_scores, top_docs, match.sum(axis=1, dtype=jnp.int32)
-
-    docs_s, c_s, n_s = jax.lax.sort((docs, contrib, cnt), num_keys=1)
-    c_s, n_s = segsum(docs_s, [c_s, n_s])
-    is_last = jnp.concatenate(
-        [docs_s[:, :-1] != docs_s[:, 1:], jnp.ones((Qb, 1), bool)], axis=1)
-    m_should = n_s & 0x3FF
-    m_must = (n_s >> _MUST_SHIFT) & 0x3FF
-    m_not = n_s >> _NOT_SHIFT
-    match = (
-        is_last & (docs_s < doc_pad)
-        & (m_must == n_must[:, None]) & (m_should >= msm[:, None]) & (m_not == 0)
-        & ((m_should + m_must) > 0)
-    )
-    if use_coord:
-        overlap = jnp.minimum(m_should + m_must, coord.shape[1] - 1)
-        coord_fac = jnp.zeros_like(c_s)
-        for j in range(coord.shape[1]):
-            coord_fac = coord_fac + jnp.where(overlap == j, coord[:, j][:, None], 0.0)
-        c_s = c_s * coord_fac
-    masked = jnp.where(match, c_s, -jnp.inf)
-    top_scores, idx = jax.lax.top_k(masked, k)
-    top_docs = jnp.take_along_axis(docs_s, idx, axis=1)
-    return top_scores, top_docs, match.sum(axis=1, dtype=jnp.int32)
+        m_should = n_s & 0x3FF
+        m_must = (n_s >> _MUST_SHIFT) & 0x3FF
+        m_not = n_s >> _NOT_SHIFT
+        match = (
+            is_last & (docs_s < doc_pad)
+            & (m_must == n_must[:, None]) & (m_should >= msm[:, None]) & (m_not == 0)
+            & ((m_should + m_must) > 0)
+        )
+        if use_coord:
+            overlap = jnp.minimum(m_should + m_must, coord.shape[1] - 1)
+            coord_fac = jnp.zeros_like(c_s)
+            for j in range(coord.shape[1]):
+                coord_fac = coord_fac + jnp.where(overlap == j,
+                                                  coord[:, j][:, None], 0.0)
+            c_s = c_s * coord_fac
+    return top(docs_s, c_s, match)
 
 
 def _sparse_impl(blk_docs, blk_tf, blk_nb, caches, modes,
@@ -1009,7 +1102,7 @@ def _get_sparse_compiled(Qb: int, TB: int, k: int, doc_pad: int, passes: int,
                                 simple=simple, use_coord=use_coord,
                                 use_pallas=use_pallas)
 
-        fn = jax.jit(wrapper)
+        fn = jax.jit(_named("scoring.sparse", wrapper))
         _compiled_cache[key] = fn
     return fn
 
@@ -1035,9 +1128,11 @@ def score_sparse_batch_async(packed: PackedSegment, sb: SparseBatch, k: int,
         jnp.asarray(sb.qcnt), jnp.asarray(sb.qfid), jnp.asarray(sb.n_must),
         jnp.asarray(sb.msm), jnp.asarray(sb.coord),
     )
-    out = fn(*args)
-    _record("scoring.sparse", "sparse", params, args)
-    return out
+    # what the launch scans against what the queries name: [Qb, TB] block
+    # slots, each BLOCK postings of (doc id i32, tf, norm byte)
+    LAUNCHES.add(sb.blocks_real, Qb * TB,
+                 Qb * P * (4 + packed.blk_tf.dtype.itemsize + 1))
+    return _launch(fn, args, "scoring.sparse", "sparse", params)
 
 
 def plan_sparse_buckets(clause_lists: list, n_must: np.ndarray, msm: np.ndarray,
@@ -1112,7 +1207,8 @@ def plan_sparse_buckets(clause_lists: list, n_must: np.ndarray, msm: np.ndarray,
             batches.append(SparseBatch(
                 n_queries=len(chunk), qids=qids, qblk=qblk, qw=qw, qconst=qconst,
                 qcnt=qcnt, qfid=qfid, n_must=bn_must, msm=bmsm, coord=bcoord,
-                passes=passes, simple=simple))
+                passes=passes, simple=simple,
+                blocks_real=sum(tb_host[qi] for qi in chunk)))
     return batches, overflow
 
 
@@ -1216,6 +1312,7 @@ def build_term_batch(entries: list, n_queries: int, n_must: np.ndarray, msm: np.
         n_queries=n_queries, qidx=qidx, blk=blk, weight=weight, fidx=fidx, group=group,
         tfmode=tfmode, n_must=n_must.astype(np.int32), msm=msm.astype(np.int32),
         coord=coord.astype(np.float32), norm_fields=norm_fields, caches=caches,
+        blocks_real=len(entries),
     )
 
 
@@ -1276,9 +1373,10 @@ def _concat_impl(blk_term, blk_j0, cum, starts, bases, doc_pads,
 def _get_concat_compiled(doc_pad_new: int, tf_layout: str):
     import jax
 
-    return jax.jit(
+    return jax.jit(_named(
+        "scoring.concat",
         functools.partial(_concat_impl, doc_pad_new=doc_pad_new,
-                          tf_layout=tf_layout))
+                          tf_layout=tf_layout)))
 
 
 def concat_pack_planes(blk_term, blk_j0, cum, starts, bases, doc_pads,

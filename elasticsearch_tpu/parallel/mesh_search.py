@@ -806,7 +806,11 @@ class MeshSearchExecutor:
                 out_specs=tuple(P() for _ in range(n_out)),
                 check_vma=False,  # outputs here are P() by construction
             )
-            fn = jax.jit(fn)
+            # named like the scoring launch sites (ops/scoring._named): the
+            # device trace reads `jit_estpu_mesh_search`, not `jit_<lambda>`
+            from ..ops.scoring import _named
+
+            fn = jax.jit(_named("mesh.search", fn))
             self._compiled[key] = fn
         raw = [
             idx.blk_docs, idx.blk_tf, idx.norms, idx.live,

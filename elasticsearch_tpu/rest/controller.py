@@ -29,6 +29,9 @@ class RestRequest:
     params: dict = dc_field(default_factory=dict)
     body: dict | list | str | None = None
     path_params: dict = dc_field(default_factory=dict)
+    # host-monotonic instant the HTTP layer began handling the request (None
+    # for an in-process request): a sampled search's `rest` root starts there
+    t_arrival: float | None = None
 
     def param(self, name: str, default=None):
         # a blank value (a bare `?from` token surfaced by the http layer)
@@ -765,7 +768,6 @@ def build_rest_controller(node) -> RestController:
         return body
 
     def search(req):
-        body = _search_body(req)
         index = req.path_params.get("index", "_all")
         search_type = req.param("search_type", "query_then_fetch")
         scroll = req.param("scroll")
@@ -775,10 +777,17 @@ def build_rest_controller(node) -> RestController:
         # lands in the /_traces ring. The scroll branch roots here too — the
         # initial scan/scroll search is a normal fan-out, only pagination of
         # the buffered hits (the /_search/scroll handler) is untraced.
+        # The root is back-dated to the HTTP layer's arrival stamp, and
+        # `rest.parse` covers what ran before any span could: reading and
+        # decoding the body, routing, and the search-body assembly here.
         want_trace = req.bool_param("trace")
-        trace = node.tracer.start_trace("rest", force=want_trace)
+        trace = node.tracer.start_trace("rest", force=want_trace,
+                                        t0=req.t_arrival)
         root = trace.root.tag(path=req.path, index=index)
         try:
+            body = _search_body(req)
+            if trace:
+                root.record("rest.parse", root.t0, time.monotonic())
             with tracing.activate(root):
                 if scroll:
                     r = _scrolled_search(index, body, scroll,
@@ -1268,7 +1277,25 @@ def build_rest_controller(node) -> RestController:
     # an XPlane trace of the query-phase kernels viewable in tensorboard/xprof)
     profiler_state = {"dir": None}
 
+    def _clock_anchor() -> dict:
+        """One `estpu.clock monotonic_s=<s>` annotation in the running trace
+        and the same reading returned: the trace's own timestamp of that
+        event maps time.monotonic(), the clock of every span and of the
+        drainer's annotations, onto the profiler's clock. Two anchors (after
+        start, before stop) show the drift between them."""
+        import jax
+
+        clock = {"monotonic_s": time.monotonic(), "epoch_ns": time.time_ns()}
+        with jax.profiler.TraceAnnotation(
+                f"estpu.clock monotonic_s={clock['monotonic_s']!r}"):
+            pass
+        return clock
+
     def _profiler_start(req):
+        """Body: `dir`, `python_tracer` (default false: the Python tracer
+        makes the host several times slower and `stop` take four times the
+        traced seconds; host TraceMe events stay on) and `host_tracer_level`
+        (jax's default, 2, where absent)."""
         import jax
 
         if profiler_state["dir"] is not None:
@@ -1279,21 +1306,30 @@ def build_rest_controller(node) -> RestController:
             node.data_path or ".", "profiler",
             time.strftime("%Y%m%d-%H%M%S"))
         os.makedirs(trace_dir, exist_ok=True)
-        jax.profiler.start_trace(trace_dir)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 1 if body.get("python_tracer") else 0
+        if body.get("host_tracer_level") is not None:
+            options.host_tracer_level = int(body["host_tracer_level"])
+        jax.profiler.start_trace(trace_dir, profiler_options=options)
         profiler_state["dir"] = trace_dir
-        return {"started": True, "dir": trace_dir}
+        return {"started": True, "dir": trace_dir,
+                "python_tracer": bool(options.python_tracer_level),
+                "host_tracer_level": options.host_tracer_level,
+                "clock": _clock_anchor()}
 
     def _profiler_stop(req):
         import jax
 
         if profiler_state["dir"] is None:
             return RestResponse(400, {"error": "profiler not running", "status": 400})
+        clock = _clock_anchor()
         jax.profiler.stop_trace()
         trace_dir, profiler_state["dir"] = profiler_state["dir"], None
         files = []
         for root_, _d, fs in os.walk(trace_dir):
             files.extend(os.path.join(root_, f) for f in fs)
-        return {"stopped": True, "dir": trace_dir, "files": sorted(files)}
+        return {"stopped": True, "dir": trace_dir, "files": sorted(files),
+                "clock": clock}
 
     rc.register("POST", "/_nodes/_local/profiler/start", _profiler_start)
     rc.register("POST", "/_nodes/_local/profiler/stop", _profiler_stop)

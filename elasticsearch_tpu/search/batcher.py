@@ -251,6 +251,20 @@ class DeviceBatcher:
         self._inflight_q: deque[tuple] = deque()
         self._flat = _FlatFamily()
         self._mesh = _MeshFamily()
+        # drainer state-seconds: where the ONE thread that feeds the device
+        # spent its wall time, every batch, sampled member or not. Written
+        # only by the drainer (each _tick books the time since the previous
+        # one, so the states partition its lifetime), read unlocked by stats()
+        self._state_s = {"wait": 0.0, "linger": 0.0, "dispatch": 0.0,
+                         "merge": 0.0, "pull": 0.0}
+        self._t_state = 0.0
+        self._batches = 0
+        # the drainer's phases as annotations on the profiler's own clock (its
+        # host plane, beside `XLA Ops`) for EVERY batch: a flag check each
+        # while no profiler session is on
+        from jax.profiler import TraceAnnotation
+
+        self._annotate = TraceAnnotation
 
     # -- public entry points -------------------------------------------------
     def execute(self, plan, ctx, k: int, deadline: Deadline = NO_DEADLINE):
@@ -334,7 +348,15 @@ class DeviceBatcher:
                                 f"{e}); serving falls back to direct launches")
             self._fail_queued(e)
 
+    def _tick(self, state: str, now: float | None = None) -> float:
+        """Book the drainer's time since its last tick to `state`."""
+        now = time.monotonic() if now is None else now
+        self._state_s[state] += now - self._t_state
+        self._t_state = now
+        return now
+
     def _drain(self):
+        self._t_state = time.monotonic()
         pending = None  # (family, items, handle, t0) — dispatched, not merged
         while True:
             batch = None
@@ -343,8 +365,17 @@ class DeviceBatcher:
                     if pending is not None:
                         break  # merge the in-flight batch instead of idling
                     self._cv.wait(0.1)
+                    self._tick("wait")
+                t0 = self._tick("wait")
                 if self._queue and not self._shutdown:
-                    batch = self._collect_locked(urgent=pending is not None)
+                    with self._annotate("estpu.batch.collect") as note:
+                        batch = self._collect_locked(urgent=pending is not None)
+                        batch_id = next(self._batch_ids)
+                        note.set_metadata(batch=batch_id, reason=batch[1],
+                                          occupancy=len(batch[0]),
+                                          family=batch[0][0].family.name)
+                    # queued items in hand: the batch starts here
+                    t0 = self._tick("linger")
             if batch is None:
                 if pending is not None:
                     self._finish(*pending)
@@ -354,9 +385,7 @@ class DeviceBatcher:
                     break
                 continue
             items, reason = batch
-            batch_id = next(self._batch_ids)
             traced = [it for it in items if it.span]
-            t0 = time.monotonic()
             # enqueue-wait: t_enq -> the drainer taking the batch (span
             # recording happens OUTSIDE the condition/stats locks — trace
             # locks are leaves, and record() never blocks or dispatches)
@@ -383,27 +412,38 @@ class DeviceBatcher:
             self._inflight_q.append(
                 (batch_id, t0, family.name, len(items),
                  getattr(ctx0, "index_name", None) or family.name))
-            try:
-                # dispatch-then-merge double buffering: batch N+1's device
-                # work is enqueued BEFORE batch N's host merge runs, so the
-                # merge overlaps device compute (no device_get in this half)
-                handle = family.dispatch(items, items[0].kb)
-            except Exception as e:  # noqa: BLE001 — replay decides per item
-                self._retire_inflight(batch_id)
-                self._split(family, items, e)
-                continue
-            if traced and tracing.sync_armed():
-                # ESTPU_TRACE_SYNC=1 precise mode (bench/debug ONLY): wait for
-                # the dispatched launches so the dispatch span measures true
-                # device time — this deliberately forfeits the double-buffer
-                # overlap, which is why it is never the default
-                sync = getattr(handle, "sync", None)
-                if sync is not None:
-                    sync()
-            t_disp = time.monotonic()
+            self._batches += 1
+            clock = None
+            with self._annotate("estpu.batch.dispatch", batch=batch_id,
+                                family=family.name, occupancy=len(items),
+                                reason=reason) as note:
+                try:
+                    # dispatch-then-merge double buffering: batch N+1's device
+                    # work is enqueued BEFORE batch N's host merge runs, so the
+                    # merge overlaps device compute (no device_get in this half)
+                    handle = family.dispatch(items, items[0].kb)
+                except Exception as e:  # noqa: BLE001 — replay decides per item
+                    self._retire_inflight(batch_id)
+                    self._split(family, items, e)
+                    self._tick("dispatch")
+                    continue
+                # the dispatch's own stage / launch intervals (and, where the
+                # family pulls inside its dispatch, the pull)
+                clock = getattr(handle, "clock", None)
+                if clock is not None and clock.compiled:
+                    # a compile stall names its batch
+                    note.set_metadata(compiled=clock.compiled,
+                                      compile_s=round(clock.compile_s, 6))
+            t_disp = self._tick("dispatch")
+            if clock is not None and clock.pull_s:
+                self._state_s["dispatch"] -= clock.pull_s
+                self._state_s["pull"] += clock.pull_s
             for it in traced:
-                it.span.record("batcher.dispatch", t0, t_disp, batch=batch_id,
-                               occupancy=len(items), family=family.name)
+                disp = it.span.record("batcher.dispatch", t0, t_disp,
+                                      batch=batch_id, occupancy=len(items),
+                                      family=family.name)
+                if clock is not None:
+                    clock.record_under(disp, batch=batch_id)
             self._note_flush(reason)
             if pending is not None:
                 self._finish(*pending)
@@ -473,12 +513,15 @@ class DeviceBatcher:
     def _finish(self, family, items, handle, t0: float, batch_id: int = 0):
         """Merge a dispatched batch and fan results out to the item futures."""
         t_m0 = time.monotonic()
-        try:
-            results = family.fan_out(handle, items)
-        except Exception as e:  # noqa: BLE001 — replay decides per item
-            self._retire_inflight(batch_id)
-            self._split(family, items, e)
-            return
+        with self._annotate("estpu.batch.merge", batch=batch_id,
+                            family=family.name, occupancy=len(items)):
+            try:
+                results = family.fan_out(handle, items)
+            except Exception as e:  # noqa: BLE001 — replay decides per item
+                self._retire_inflight(batch_id)
+                self._split(family, items, e)
+                self._tick("merge")
+                return
         t_m1 = time.monotonic()
         self._retire_inflight(batch_id)  # merged: the stall marker retires
         dt = t_m1 - t0
@@ -488,18 +531,26 @@ class DeviceBatcher:
         # ride the existing batched device_get, no extra sync)
         pull_t0 = getattr(handle, "pull_t0", None)
         pull_t1 = getattr(handle, "pull_t1", None)
+        merged_pull = pull_t0 is not None and pull_t1 is not None
+        if merged_pull:
+            pull_s = pull_t1 - pull_t0
+        else:
+            # a family that pulls inside its dispatch timed it on the dispatch
+            # clock (and its device_pull is under batcher.dispatch already)
+            clock = getattr(handle, "clock", None)
+            pull_s = clock.pull_s if clock is not None else 0.0
         for it in items:
             if it.obs is not None:
                 # device time rides the batch's existing single pull window
                 # (zero added clocks/syncs — the insights contract)
-                if pull_t0 is not None and pull_t1 is not None:
-                    it.obs.device_s = pull_t1 - pull_t0
+                if pull_s:
+                    it.obs.device_s = pull_s
                 it.obs.occupancy = len(items)
             if not it.span:
                 continue
             merge_span = it.span.record("batcher.merge", t_m0, t_m1,
                                         batch=batch_id)
-            if pull_t0 is not None and pull_t1 is not None:
+            if merged_pull:
                 merge_span.record("device_pull", pull_t0, pull_t1,
                                   batch=batch_id)
         self.service_hist.observe(dt)  # own stripe locks — outside _stats_lock
@@ -509,6 +560,12 @@ class DeviceBatcher:
             self._items_launched += len(items)
         for it, res in zip(items, results):
             it.future.set_result(res)
+        # everything since the dispatch tick — the merge, its bookkeeping and
+        # waking the waiters — is merge time, less the pull inside it
+        self._tick("merge")
+        if merged_pull:
+            self._state_s["merge"] -= pull_s
+            self._state_s["pull"] += pull_s
 
     def _split(self, family, items, err):
         """A coalesced launch failed (breaker trip, device error): replay every
@@ -620,6 +677,13 @@ class DeviceBatcher:
                 "queue": len(self._queue),
                 "ewma_batch_ms": round(self._ewma_cost * 1000.0, 3),
             }
+        # drainer state-seconds (drainer-written, read unlocked: each value
+        # is one float, a reading is at most one batch stale). They sum to
+        # the drainer's lifetime: wait = queue empty and nothing to merge,
+        # linger = items queued inside _collect_locked, dispatch / merge less
+        # the device_get inside them, which is pull
+        out["drainer"] = {**{k + "_s": v for k, v in self._state_s.items()},
+                          "batches": self._batches}
         # batch service-time percentiles (HistogramMetric — the tail the EWMA
         # can't show); stripe locks are leaves, summed outside _stats_lock
         out["batch"] = self.service_hist.stats()

@@ -24,6 +24,7 @@ SearchPhaseController.aggregateDfs.
 
 from __future__ import annotations
 
+import contextlib
 import math
 import re
 import time
@@ -72,6 +73,7 @@ from .queries import (
     TermQuery,
     WildcardQuery,
 )
+from ..common import tracing
 from ..common.breaker import reserve
 from ..common.devicehealth import tag_domain as _tag_domain
 from ..common.jaxenv import compile_tag
@@ -566,7 +568,7 @@ class _PendingFlat:
     (search/batcher.py: batch N+1 dispatches while batch N merges)."""
 
     __slots__ = ("Q", "k", "breaker", "seg_work", "releases",
-                 "pull_t0", "pull_t1", "index")
+                 "pull_t0", "pull_t1", "index", "clock")
 
     def __init__(self, Q: int, k: int, breaker, seg_work: list, releases: list,
                  index: str | None = None):
@@ -586,14 +588,17 @@ class _PendingFlat:
         # pull instead of adding any sync of its own (common/tracing.py)
         self.pull_t0: float | None = None
         self.pull_t1: float | None = None
+        # the dispatch's stage/launch intervals (tracing.DispatchClock), set
+        # by dispatch_flat_batch for the batcher; None on direct launches
+        self.clock = None
 
     def merge(self) -> list[TopDocs]:
         return _merge_flat_plain(self)
 
     def sync(self):
-        """Block until every dispatched launch completes — ESTPU_TRACE_SYNC=1
-        precise device timing ONLY (bench/debug); the serving path never calls
-        this, its one sync is the batched pull in merge()."""
+        """Block until every dispatched launch completes — the profile API's
+        per-request sync ONLY (_execute_flat_plain); the serving path never
+        calls this, its one sync is the batched pull in merge()."""
         import jax
 
         for (_seg, _base, _doc_pad, launches, dense) in self.seg_work:
@@ -605,13 +610,16 @@ class _PendingFlat:
 
 class _PendingDone:
     """Already-merged results behind the pending interface — the fs/filtered
-    plan families execute synchronously inside the dispatch half (they are
-    rare on the serving hot path and their kernels pull per launch)."""
+    plan families execute synchronously inside the dispatch half (their
+    kernels pull per launch). `clock` holds that dispatch's stage / launch /
+    device_pull intervals (tracing.DispatchClock): the pull happened INSIDE
+    the dispatch, so the batcher records it there, not under its merge."""
 
-    __slots__ = ("results",)
+    __slots__ = ("results", "clock")
 
-    def __init__(self, results: list):
+    def __init__(self, results: list, clock=None):
         self.results = results
+        self.clock = clock
 
     def merge(self) -> list[TopDocs]:
         return self.results
@@ -622,9 +630,27 @@ def dispatch_flat_batch(plans: list[FlatPlan], ctx: ShardContext, k: int):
     returns a pending handle whose merge() yields the per-plan TopDocs.
     Plain plans enqueue device work without syncing; batches carrying
     function_score/filtered plans run whole (synchronously) here."""
-    if plans and all(p.fs is None and p.filt is None for p in plans):
-        return _dispatch_flat_plain(plans, ctx, k)
-    return _PendingDone(execute_flat_batch(plans, ctx, k))
+    with tracing.timing_dispatch() as clock:
+        if plans and all(p.fs is None and p.filt is None for p in plans):
+            pending = _dispatch_flat_plain(plans, ctx, k)
+            pending.clock = clock
+            return pending
+        return _PendingDone(execute_flat_batch(plans, ctx, k), clock)
+
+
+@contextlib.contextmanager
+def traced_dispatch():
+    """The unbatched dense families (field sort, aggregations) launch and pull
+    on the request thread: a SAMPLED request records their stage / launch /
+    device_pull intervals under its own active span; an unsampled one pays
+    the thread-local read."""
+    span = tracing.current_span()
+    if not span:
+        yield
+        return
+    with tracing.timing_dispatch() as clock:
+        yield
+    clock.record_under(span)
 
 
 def _dispatch_flat_plain(plans: list[FlatPlan], ctx: ShardContext,
@@ -670,7 +696,7 @@ def _dispatch_flat_plain(plans: list[FlatPlan], ctx: ShardContext,
         # scan decodes tf→tfn in-kernel against these stacked cache rows
         sim = ensure_sim_tables(packed, sim_tables)
         clause_lists = []
-        blocks_scanned = postings_scanned = 0
+        postings_scanned = 0
         for (resolved, _f, _c, _coord) in finals:
             cl = []
             for (f, t, w, _fi, g, mode, df) in resolved:
@@ -680,7 +706,6 @@ def _dispatch_flat_plain(plans: list[FlatPlan], ctx: ShardContext,
                 b0, b1 = packed.blocks_for_term(tid)
                 cl.append((b0, b1, w, g, mode == MODE_CONST, sim.fid[f]))
                 if prof is not None:
-                    blocks_scanned += b1 - b0
                     postings_scanned += int(seg.post_offsets[tid + 1]
                                             - seg.post_offsets[tid])
             clause_lists.append(cl)
@@ -721,7 +746,10 @@ def _dispatch_flat_plain(plans: list[FlatPlan], ctx: ShardContext,
                 path=("sparse_fused" if estpu_pallas_enabled()
                       else "sparse_composed"),
                 tf_layout=packed.tf_layout,
-                blocks_scanned=int(blocks_scanned),
+                # the launch counters' own sum (ops/scoring.LAUNCHES
+                # `blocks_real`): the blocks every launch above named
+                blocks_scanned=sum(sb.blocks_real for (sb, _r) in launches)
+                + (dense[2] if dense is not None else 0),
                 postings_scanned=int(postings_scanned),
                 staged_bytes=sum(
                     SparseScratchPool.staging_bytes(*sb.qblk.shape)
@@ -765,8 +793,11 @@ def _merge_flat_plain(pending: _PendingFlat) -> list[TopDocs]:
             _DEVICE_FAULTS.check(f"pull:{pending.index}")
         # stamp the pull window for tracing (host clocks around the pull the
         # serving path performs anyway — the device span's end rides this)
+        # and name it on the profiler's own clock, inside the drainer's
+        # estpu.batch.merge annotation (a flag check while no session is on)
         pending.pull_t0 = time.monotonic()
-        pulled = iter(jax.device_get(refs) if refs else [])
+        with jax.profiler.TraceAnnotation("estpu.batch.pull"):
+            pulled = iter(jax.device_get(refs) if refs else [])
         pending.pull_t1 = time.monotonic()
     except Exception as e:  # noqa: BLE001 — abandoning the batch
         # drain whatever the device will still write into the staging
@@ -794,7 +825,7 @@ def _merge_flat_plain(pending: _PendingFlat) -> list[TopDocs]:
         scores, docs, tq = collect_flat_sparse(launches, sparse_pulled, Q, k,
                                                doc_pad)
         if dense is not None:
-            sub, _ref = dense
+            sub = dense[0]
             # already host arrays — the batch's single device_get pulled them
             ts, td, tt = next(pulled)
             res = finalize_score_result(ts, td, tt, doc_pad)
@@ -911,7 +942,8 @@ def _launch_dense_fallback(overflow, finals, field_idx, all_fields, caches_stack
                            breaker=None):
     """Launch overflow queries (block count past the sparse planner's tb_max)
     on the dense scatter kernel WITHOUT syncing; returns (sub indices, device
-    result triple) for the merge half, or None when no entries resolved."""
+    result triple, blocks the launch named) for the merge half, or None when
+    no entries resolved."""
     from ..ops.scoring import build_term_batch, score_term_batch_async
 
     _ensure_norm_rows(packed, all_fields, breaker=breaker)
@@ -922,17 +954,17 @@ def _launch_dense_fallback(overflow, finals, field_idx, all_fields, caches_stack
     batch = build_term_batch(entries, len(overflow), n_must[sub], msm[sub],
                              coord_tbl[sub], list(all_fields), caches_stack,
                              nb_pad_row=packed.blk_docs.shape[0] - 1)
-    return sub, score_term_batch_async(packed, batch, k)
+    return sub, score_term_batch_async(packed, batch, k), batch.blocks_real
 
 
-def _prof_dense_segment(prof, seg, packed, entries, path: str, t_seg: float):
+def _prof_dense_segment(prof, seg, packed, batch, path: str, t_seg: float):
     """Per-segment profile record for the dense kernel families (fs /
-    filtered / sorted / aggs) — entries are one (query, block) triple per
-    scanned block, so len(entries) IS the blocks-scanned count."""
+    filtered / sorted / aggs) — the batch holds one (query, block) triple per
+    scanned block, and `blocks_real` is the count the launch counters took."""
     if prof is None:
         return
     prof.segment(seg.gen, docs=int(seg.doc_count), path=path,
-                 tf_layout=packed.tf_layout, blocks_scanned=len(entries),
+                 tf_layout=packed.tf_layout, blocks_scanned=batch.blocks_real,
                  launches=1, ms=(time.monotonic() - t_seg) * 1000.0)
 
 
@@ -1036,7 +1068,7 @@ def _execute_flat_fs(plans: list[FlatPlan], ctx: ShardContext, k: int) -> list[T
             valid = (docs < min(doc_pad, D)) & np.isfinite(scores)
             gdocs = np.where(valid, docs.astype(np.int64) + base, np.int64(2**62))
             seg_hits.append((np.where(valid, scores, -np.inf), gdocs))
-            _prof_dense_segment(prof, seg, packed, entries,
+            _prof_dense_segment(prof, seg, packed, batch,
                                 "dense_function_score", t_seg)
     except ScriptError:
         # a host-side per-doc evaluation raised while building rows — the host
@@ -1144,7 +1176,7 @@ def _execute_flat_filtered(plans: list[FlatPlan], ctx: ShardContext,
         valid = (docs < min(packed.doc_pad, seg.doc_count)) & np.isfinite(scores)
         gdocs = np.where(valid, docs.astype(np.int64) + base, np.int64(2**62))
         seg_hits.append((np.where(valid, scores, -np.inf), gdocs))
-        _prof_dense_segment(prof, seg, packed, entries, "dense_filtered",
+        _prof_dense_segment(prof, seg, packed, batch, "dense_filtered",
                             t_seg)
     return _merge_seg_hits(seg_hits, totals, Q, k,
                            breaker=ctx.breaker("request"))
@@ -1206,7 +1238,7 @@ def execute_flat_sorted(plan: FlatPlan, ctx: ShardContext, k: int, spec):
             (ki, base + di, si, di, sc)
             for ki, di, sc in zip(keys[0, :n].tolist(), docs[0, :n].tolist(),
                                   scores[0, :n].tolist()))
-        _prof_dense_segment(prof, seg, packed, entries, "dense_sorted", t_seg)
+        _prof_dense_segment(prof, seg, packed, batch, "dense_sorted", t_seg)
     cand.sort(key=lambda e: (-e[0] if spec.reverse else e[0], e[1]))
     return total, max_score, cand[: max(k, 0)]
 
@@ -1295,7 +1327,7 @@ def execute_flat_aggs(plan: FlatPlan, ctx: ShardContext, k: int,
              None if ss is None else ss[0])
             for keys, (bc, sc, ss) in zip(seg_keys, bcounts)
         ]))
-        _prof_dense_segment(prof, seg, packed, entries, "dense_aggs", t_seg)
+        _prof_dense_segment(prof, seg, packed, batch, "dense_aggs", t_seg)
     return _merge_seg_hits(seg_hits, totals, 1, k,
                            breaker=ctx.breaker("request"))[0], seg_stats
 

@@ -18,6 +18,7 @@ import numpy as np
 
 from ..common import insights as _insights
 from ..common import profile as _profile
+from ..common import tracing
 from ..common.breaker import reserve as breaker_reserve
 from ..common.deadline import NO_DEADLINE, Deadline, parse_timevalue
 from ..common.devicehealth import DEVICE_HEALTH
@@ -250,6 +251,12 @@ def _execute_flat_single(ctx: ShardContext, plan, k: int,
     if ctx.batcher is not None and not ctx.global_stats:
         prof = _profile.current()
         if prof is None:
+            span = tracing.current_span()
+            if span:
+                # sampled: what the shard did before handing the plan to the
+                # batcher (request parse, lower_flat), from the shard span's
+                # own start to the enqueue
+                span.record("shard.lower", span.t0, time.monotonic())
             return ctx.batcher.execute(plan, ctx, k, deadline=deadline)
         # recorded ONLY when the batcher would actually have served this
         # request — a DFS search or batcher-less node launches directly
@@ -612,7 +619,7 @@ def _try_device_aggs(ctx: ShardContext, req: ParsedSearchRequest, k: int,
     from .aggregations import (device_agg_field, device_bucket_eligible,
                                device_bucket_partial, device_bucket_subs,
                                device_partial)
-    from .execute import execute_flat_aggs
+    from .execute import execute_flat_aggs, traced_dispatch
 
     metric_fields = {}
     bucket_names = []
@@ -642,7 +649,9 @@ def _try_device_aggs(ctx: ShardContext, req: ParsedSearchRequest, k: int,
     # kernel k is at least 1 so max_score stays observable; hits trim to the
     # requested size below (size=0 agg-only requests return no docs, like the
     # host mask path)
-    td, seg_stats = execute_flat_aggs(plan, ctx, max(k, 1), fields, bucket_aggs)
+    with traced_dispatch():
+        td, seg_stats = execute_flat_aggs(plan, ctx, max(k, 1), fields,
+                                          bucket_aggs)
     if td is None:
         return None  # a column wasn't f32-exact — host path
     bpos = {n: i for i, n in enumerate(bucket_names)}
@@ -709,7 +718,7 @@ def _try_device_sort(ctx: ShardContext, req: ParsedSearchRequest, k: int,
     (exact f64 / None-for-missing), only the ORDERING rides the device. Requests
     that ALSO carry device-eligible aggs get a second fused launch for the
     partials (same match set — both kernels share the dense core)."""
-    from .execute import execute_flat_sorted, lower_flat
+    from .execute import execute_flat_sorted, lower_flat, traced_dispatch
 
     spec = req.sort[0]
     if spec.kind != "field":
@@ -722,7 +731,8 @@ def _try_device_sort(ctx: ShardContext, req: ParsedSearchRequest, k: int,
     plan = lower_flat(req.query, ctx)
     if plan is None or plan.fs is not None:
         return None
-    res = execute_flat_sorted(plan, ctx, max(k, 1), spec)
+    with traced_dispatch():
+        res = execute_flat_sorted(plan, ctx, max(k, 1), spec)
     if res is None:
         return None
     total, max_score, entries = res

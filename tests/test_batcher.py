@@ -422,3 +422,78 @@ class TestPendingMergeFlush:
             assert ok, (elapsed, b.stats())
         finally:
             b.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# drainer state-seconds (PR 24): where the one thread that feeds the device
+# spent its wall time, for every batch
+# ---------------------------------------------------------------------------
+
+
+def _drainer(b):
+    d = b.stats()["drainer"]
+    return d, sum(v for k, v in d.items() if k.endswith("_s"))
+
+
+class TestDrainerStates:
+    def test_states_partition_the_drainers_wall_time(self, shard_ctx):
+        b = make_batcher(**{"search.batch.linger_ms": 20,
+                            "search.batch.max_batch": 4})
+        try:
+            texts = ["quick brown", "lazy dog", "red bear", "summer snack"]
+            run_concurrent(b, shard_ctx, texts)  # starts the drainer, compiles
+            d0, s0 = _drainer(b)
+            t0 = time.monotonic()
+            for _ in range(5):
+                run_concurrent(b, shard_ctx, texts)
+                time.sleep(0.05)
+            time.sleep(0.25)  # an idle tick closes the last wait
+            d1, s1 = _drainer(b)
+            elapsed = time.monotonic() - t0
+            assert set(d1) == {"wait_s", "linger_s", "dispatch_s", "merge_s",
+                               "pull_s", "batches"}
+            # every second since the first reading is in exactly one state;
+            # the reading itself is at most one idle tick (0.1 s) stale
+            assert abs((s1 - s0) - elapsed) <= 0.12, (s1 - s0, elapsed)
+            for k in ("linger_s", "dispatch_s", "merge_s", "pull_s"):
+                assert d1[k] > d0[k] >= 0.0, (k, d0, d1)
+            assert d1["batches"] - d0["batches"] == \
+                b.stats()["launches"] - 1 >= 5
+        finally:
+            b.shutdown()
+
+    def test_an_idle_drainer_waits(self, shard_ctx):
+        b = make_batcher()
+        try:
+            run_concurrent(b, shard_ctx, ["quick brown"])
+            time.sleep(0.15)
+            d0, _ = _drainer(b)
+            time.sleep(0.45)
+            d1, _ = _drainer(b)
+            assert d1["wait_s"] - d0["wait_s"] >= 0.3
+            for k in ("linger_s", "dispatch_s", "merge_s", "pull_s", "batches"):
+                assert d1[k] == d0[k], k
+        finally:
+            b.shutdown()
+
+    def test_a_failed_dispatch_is_dispatch_time(self):
+        """The per-item replay of a batch whose coalesced launch failed is
+        booked too: no second of the drainer goes missing."""
+        fam = _TrippingFamily()
+        b = make_batcher(**{"search.batch.linger_ms": 5000,
+                            "search.batch.max_batch": 2})
+        try:
+            items = [_Item(fam, ("fake", "key"), p, 10, 16, NO_DEADLINE)
+                     for p in ("a", "b")]
+            threads = [threading.Thread(target=b._submit, args=(it,))
+                       for it in items]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(30)
+            assert [it.future.result(0) for it in items] == ["ok:a", "ok:b"]
+            d, _total = _drainer(b)
+            assert b.stats()["splits"] == 1 and d["batches"] == 1
+            assert d["dispatch_s"] > 0.0 and d["merge_s"] == 0.0
+        finally:
+            b.shutdown()

@@ -254,3 +254,65 @@ def test_scalar_f32_idiom_is_committed():
     v = jax.device_put(np.float32(0.5))
     assert v.dtype == np.float32
     assert not getattr(v, "weak_type", False)
+
+
+def test_every_launch_site_lowers_under_its_own_name():
+    """Each scoring launch site compiles a program named after it
+    (`jit_estpu_<site>[_<variant>]`): the device trace's `XLA Modules` line
+    tells the sparse launch from the dense overflow, the filtered family from
+    the aggregations. None is `jit_wrapper` any more."""
+    import jax
+    import jax.numpy as jnp
+
+    from elasticsearch_tpu.ops import scoring
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    nb, dpad, m, q, f = 16, 256, 32, 2, 1
+    dense = (s((nb, 128), jnp.int32), s((nb, 128), jnp.float32),
+             s((dpad,), jnp.bool_), s((f, dpad), jnp.uint8),
+             s((f, 256), jnp.float32), s((m,), jnp.int32), s((m,), jnp.int32),
+             s((m,), jnp.float32), s((m,), jnp.int32), s((m,), jnp.int32),
+             s((m,), jnp.int32), s((q,), jnp.int32), s((q,), jnp.int32),
+             s((q, 2), jnp.float32))
+    scalar = s((), jnp.float32)
+    sparse = (s((nb, 128), jnp.int32), s((nb, 128), jnp.uint8),
+              s((nb, 128), jnp.uint8), s((f, 256), jnp.float32),
+              s((f,), jnp.int32), s((8, 8), jnp.int32), s((8, 8), jnp.float32),
+              s((8, 8), jnp.bool_), s((8, 8), jnp.int32), s((8, 8), jnp.int32),
+              s((8,), jnp.int32), s((8,), jnp.int32), s((8, 2), jnp.float32))
+    no_aggs = (s((0, 5, dpad), jnp.float32), (), s((q, dpad), jnp.bool_))
+    term = s((4,), jnp.int32)
+    plane = tuple(s((4, 128), d) for d in (jnp.int32, jnp.uint8, jnp.uint8))
+    sites = [
+        (scoring._get_compiled(q, 10, dpad, True), dense),
+        (scoring._get_compiled(q, 10, dpad, False), dense),
+        (scoring._get_fs_compiled("rows", q, 10, dpad, bmode="multiply",
+                                  use_min_score=False, no_functions=False),
+         dense + (s((dpad,), jnp.float32), s((dpad,), jnp.bool_),
+                  scalar, scalar, scalar)),
+        (scoring._get_sorted_compiled(q, 10, dpad, False),
+         dense + (s((1, 1), jnp.bool_), s((dpad,), jnp.float32))),
+        (scoring._get_agg_compiled(q, 10, dpad, 0), dense + no_aggs),
+        (scoring._get_agg_compiled(q, 10, dpad, 0, True), dense + no_aggs),
+        (scoring._get_sparse_compiled(8, 8, 10, dpad, 1, True, False, 2),
+         sparse),
+        (scoring._get_concat_compiled(dpad, "u8"),
+         (term, term, s((2, 4), jnp.int32), s((1, 4), jnp.int32),
+          s((1,), jnp.int32), s((1,), jnp.int32),
+          (plane[0],), (plane[1],), (plane[2],))),
+    ]
+    names = [fn.lower(*args).as_text().split("@", 1)[1].split(" ", 1)[0]
+             for fn, args in sites]
+    assert names == [
+        "jit_estpu_scoring_dense_simple", "jit_estpu_scoring_dense_bool",
+        "jit_estpu_scoring_fs_rows", "jit_estpu_scoring_sorted",
+        "jit_estpu_scoring_aggs", "jit_estpu_scoring_aggs_filtered",
+        "jit_estpu_scoring_sparse", "jit_estpu_scoring_concat"], names
+    # the stages inside them carry names too (metadata only: no operation is
+    # added, removed or reordered by a named_scope)
+    text = sites[6][0].lower(*sparse).as_text(debug_info=True)
+    for scope in ("gather_decode", "sort_by_doc", "segment_sum",
+                  "match_coord", "top_k"):
+        assert scope in text, scope

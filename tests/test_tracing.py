@@ -650,44 +650,233 @@ class TestTracedSanitized:
         finally:
             batcher.shutdown()
 
-    def test_trace_sync_mode_is_opt_in_and_correct(self, tmp_path,
-                                                   monkeypatch):
-        """ESTPU_TRACE_SYNC=1 (precise device timing for bench/debug) still
-        returns identical results — it only moves the dispatch span's end to
-        launch completion."""
-        from elasticsearch_tpu.index import Engine
-        from elasticsearch_tpu.mapper import MapperService
-        from elasticsearch_tpu.search import ShardContext, parse_query
-        from elasticsearch_tpu.search.batcher import DeviceBatcher
-        from elasticsearch_tpu.search.execute import (execute_flat_batch,
-                                                      lower_flat)
-        from elasticsearch_tpu.search.similarity import SimilarityService
 
-        assert not tracing.sync_armed()
-        monkeypatch.setenv("ESTPU_TRACE_SYNC", "1")
-        assert tracing.sync_armed()
-        settings = Settings.from_flat({})
-        svc = MapperService(settings)
-        e = Engine(str(tmp_path / "shard0"), svc)
-        for i in range(30):
-            e.index("doc", str(i), {"body": f"{WORDS[i % 8]} {WORDS[(i + 1) % 8]}"})
-        e.refresh()
-        ctx = ShardContext(e.acquire_searcher(), svc,
-                           SimilarityService(settings, mapper_service=svc))
-        plan = lower_flat(parse_query({"match": {"body": "quick"}}), ctx)
-        expected = execute_flat_batch([plan], ctx, 10)[0]
-        batcher = DeviceBatcher(Settings.from_flat({}))
-        tracer = _tracer("0")
-        trace = tracer.start_trace("search", force=True)
+# ---------------------------------------------------------------------------
+# the launch timeline (PR 24): host-phase spans, dispatch stage/launch, the
+# synchronous families' pull, and the counters beside them
+# ---------------------------------------------------------------------------
+
+FILTERED_BODY = {"query": {"filtered": {
+    "query": {"match": {"body": "quick brown"}},
+    "filter": {"term": {"body": "fox"}}}}, "size": 5}
+
+
+def _traced_search(rc, body, arrived_ago: float = 0.0):
+    resp = rc.dispatch(RestRequest(
+        method="POST", path="/traced/_search", params={"trace": "true"},
+        body=dict(body), t_arrival=time.monotonic() - arrived_ago))
+    assert resp.status == 200, resp.body
+    return resp.body["trace"]["tree"]
+
+
+def _assert_nested(n):
+    """Every span lies inside its parent, and children sum to no more."""
+    for c in n["children"]:
+        assert c["t0"] >= n["t0"] - 1e-6 and c["t1"] <= n["t1"] + 1e-6, \
+            (n["name"], c["name"])
+        _assert_nested(c)
+    total = sum(c["duration_ms"] for c in n["children"])
+    assert total <= n["duration_ms"] + 1.0, (n["name"], total, n["duration_ms"])
+
+
+class TestLaunchTimeline:
+    def test_tree_holds_the_host_phase_spans(self, live):
+        _cluster, _node, rc = live
+        tree = _traced_search(rc, SEARCH_BODY, arrived_ago=0.002)
+        names = {n["name"] for n in _flatten(tree)}
+        assert {"rest.parse", "coordinator.plan", "coordinator.query",
+                "coordinator.reduce", "coordinator.fetch",
+                "coordinator.render", "shard.lower", "dispatch.stage",
+                "dispatch.launch"} <= names, names
+        _assert_nested(tree)
+        # the root starts where the HTTP layer stamped the arrival, and
+        # rest.parse is what ran before the handler could open a span
+        (parse,) = _find(tree, "rest.parse")
+        assert parse["t0"] == tree["t0"] and parse["duration_ms"] >= 2.0
+        (coord,) = _find(tree, "coordinator")
+        assert [c["name"] for c in coord["children"]] == [
+            "coordinator.plan", "coordinator.query", "coordinator.reduce",
+            "coordinator.fetch", "coordinator.render"]
+        # the transport round-trips of a phase nest under it
+        (query,) = _find(tree, "coordinator.query")
+        (fetch,) = _find(tree, "coordinator.fetch")
+        assert any(c["name"].startswith("transport[") for c in query["children"])
+        assert any(c["name"].startswith("transport[") for c in fetch["children"])
+        (shard,) = _find(tree, "shard")
+        assert shard["children"][0]["name"] == "shard.lower"
+        (dispatch,) = _find(tree, "batcher.dispatch")
+        kinds = [c["name"] for c in dispatch["children"]]
+        assert kinds and set(kinds) == {"dispatch.stage", "dispatch.launch"}
+        assert kinds[0] == "dispatch.stage"  # staging precedes the first call
+
+    def test_filtered_search_pulls_under_its_dispatch(self, live):
+        """The dense filtered family pulls inside batcher.dispatch: its
+        device_pull is recorded there, with the stage and launch beside it."""
+        _cluster, _node, rc = live
+        _traced_search(rc, FILTERED_BODY)  # first sighting compiles
+        tree = _traced_search(rc, FILTERED_BODY)
+        _assert_nested(tree)
+        (dispatch,) = _find(tree, "batcher.dispatch")
+        kinds = [c["name"] for c in dispatch["children"]]
+        assert kinds[:3] == ["dispatch.stage", "dispatch.launch", "device_pull"]
+        (merge,) = _find(tree, "batcher.merge")
+        assert merge["children"] == []
+
+    def test_unsampled_search_allocates_no_span(self, live, monkeypatch):
+        _cluster, node, rc = live
+        made = []
+        real_init = tracing.Span.__init__
+        real_record = tracing.Span.record
+
+        def counting_init(self, *a, **kw):
+            made.append(a[1] if len(a) > 1 else "?")
+            real_init(self, *a, **kw)
+
+        def counting_record(self, name, *a, **kw):
+            made.append(name)
+            return real_record(self, name, *a, **kw)
+
+        monkeypatch.setattr(tracing.Span, "__init__", counting_init)
+        monkeypatch.setattr(tracing.Span, "record", counting_record)
+        monkeypatch.setattr(node.tracer, "sample_rate", 0.0)
+        for body in (SEARCH_BODY, FILTERED_BODY):
+            resp = rc.dispatch(RestRequest(
+                method="POST", path="/traced/_search", body=dict(body),
+                t_arrival=time.monotonic()))
+            assert resp.status == 200 and "trace" not in resp.body
+        assert made == []
+        # the pin bites: a sampled search does allocate them
+        _traced_search(rc, SEARCH_BODY)
+        assert "coordinator.query" in made and "dispatch.stage" in made
+
+    def test_a_compile_names_its_launch(self, live):
+        """A first sighting compiles inside dispatch.launch: the span says how
+        many events and how many seconds, and device.compile keeps both."""
+        _cluster, node, rc = live
+
+        def compile_stats():
+            resp = rc.dispatch(RestRequest(
+                method="GET", path="/_nodes/stats/device"))
+            return next(iter(resp.body["nodes"].values()))["device"]["compile"]
+
+        before = compile_stats()
+        body = dict(SEARCH_BODY, size=300)  # a k bucket nothing here has used
+        tree = _traced_search(rc, body)
+        after = compile_stats()
+        assert after["total"] > before["total"]
+        assert after["seconds"] > before["seconds"]
+        assert abs(sum(after["seconds_by_family"].values())
+                   - after["seconds"]) < 1e-6
+        assert set(after["seconds_by_family"]) == set(after["by_family"])
+        assert after["cache_hits"] + after["cache_misses"] >= \
+            before["cache_hits"] + before["cache_misses"]
+        compiled = [n for n in _find(tree, "dispatch.launch")
+                    if n["tags"].get("compiled")]
+        assert compiled and all(n["tags"]["compile_s"] > 0 for n in compiled)
+        assert sum(n["tags"]["compiled"] for n in compiled) <= \
+            after["total"] - before["total"]
+
+    def test_launch_counters_and_the_profile_api_share_one_sum(self, live):
+        _cluster, node, rc = live
+
+        def launch():
+            resp = rc.dispatch(RestRequest(
+                method="GET", path="/_nodes/stats/search_serving"))
+            return next(iter(
+                resp.body["nodes"].values()))["search_serving"]["launch"]
+
+        rc.dispatch(RestRequest(method="POST", path="/traced/_search",
+                                body=dict(SEARCH_BODY)))
+        warm = launch()
+        assert warm["blocks_launched"] >= warm["blocks_real"] > 0
+        assert warm["blocks_padding"] == \
+            warm["blocks_launched"] - warm["blocks_real"]
+        assert warm["launches_sparse"] > 0 and warm["posting_bytes"] > 0
+        before = launch()
+        resp = rc.dispatch(RestRequest(
+            method="POST", path="/traced/_search",
+            body=dict(SEARCH_BODY, profile=True)))
+        after = launch()
+        (shard,) = resp.body["profile"]["shards"]
+        scanned = sum(seg["blocks_scanned"] for seg in shard["segments"])
+        assert scanned == after["blocks_real"] - before["blocks_real"] > 0
+        # the dense families count too, rows of the [Q, doc_pad] plane included
+        rc.dispatch(RestRequest(method="POST", path="/traced/_search",
+                                body=dict(FILTERED_BODY)))
+        dense = launch()
+        assert dense["launches_dense"] > after["launches_dense"]
+        assert dense["dense_rows"] > after["dense_rows"]
+
+    @pytest.mark.parametrize("python_tracer", [False, True])
+    def test_profiler_puts_every_batch_on_the_traces_clock(
+            self, live, tmp_path, python_tracer):
+        import jax
+
+        _cluster, _node, rc = live
+        start = rc.dispatch(RestRequest(
+            method="POST", path="/_nodes/_local/profiler/start",
+            body={"dir": str(tmp_path / "prof"), **(
+                {"python_tracer": True} if python_tracer else {})}))
         try:
-            with tracing.activate(trace.root):
-                got = batcher.execute(plan, ctx, 10)
+            assert start.status == 200, start.body
+            assert start.body["python_tracer"] is python_tracer
+            for _ in range(2):
+                rc.dispatch(RestRequest(method="POST", path="/traced/_search",
+                                        body=dict(SEARCH_BODY)))
         finally:
-            trace.root.end()
-            batcher.shutdown()
-        assert got.hits == expected.hits and got.total == expected.total
-        names = {s["name"] for s in trace.span_dicts()}
-        assert "batcher.dispatch" in names
+            stop = rc.dispatch(RestRequest(
+                method="POST", path="/_nodes/_local/profiler/stop"))
+        assert stop.status == 200, stop.body
+        for clock in (start.body["clock"], stop.body["clock"]):
+            assert set(clock) == {"monotonic_s", "epoch_ns"}
+        assert stop.body["clock"]["monotonic_s"] > start.body["clock"]["monotonic_s"]
+        (pb,) = [f for f in stop.body["files"] if f.endswith(".xplane.pb")]
+        names = [e.name for plane in jax.profiler.ProfileData.from_file(pb).planes
+                 if plane.name.startswith("/host:")
+                 for line in plane.lines for e in line.events]
+        anchors = [n for n in names if n.startswith("estpu.clock monotonic_s=")]
+        assert [float(a.split("=")[1]) for a in anchors] == [
+            start.body["clock"]["monotonic_s"], stop.body["clock"]["monotonic_s"]]
+        for phase in ("collect", "dispatch", "merge", "pull"):
+            assert names.count(f"estpu.batch.{phase}") >= 2, phase
+        # with the Python tracer off the host plane holds TraceMe events only
+        python_events = [n for n in names if n.startswith("$")]
+        assert bool(python_events) is python_tracer
+
+    def test_gc_pause_seconds_rise_across_a_full_collection(self, live):
+        import gc
+
+        _cluster, _node, rc = live
+
+        def gc_stats():
+            resp = rc.dispatch(RestRequest(
+                method="GET", path="/_nodes/stats/runtime"))
+            return next(iter(resp.body["nodes"].values()))["runtime"]["gc"]
+
+        before = gc_stats()
+        junk = [[i] for i in range(200000)]  # something for the collector to walk
+        gc.collect()
+        after = gc_stats()
+        del junk
+        assert after["collections"] > before["collections"]
+        assert after["pause_s"] > before["pause_s"]
+
+    def test_http_respond_times_what_follows_the_handler(self, live):
+        import urllib.request
+
+        _cluster, node, _rc = live
+        http = node.http or node.start_http(0)
+        url = f"http://127.0.0.1:{http.port}"
+        req = urllib.request.Request(
+            url + "/traced/_search", data=json.dumps(SEARCH_BODY).encode(),
+            headers={"Content-Type": "application/json"})
+        with urllib.request.urlopen(req, timeout=30) as r:
+            assert json.loads(r.read())["hits"]["total"] > 0
+        with urllib.request.urlopen(url + "/_nodes/stats/http", timeout=30) as r:
+            stats = next(iter(json.loads(r.read())["nodes"].values()))
+        respond = stats["http"]["respond"]
+        assert respond["count"] >= 1 and respond["sum_s"] > 0
+        assert respond["p99_ms"] >= respond["p50_ms"] > 0
 
 
 # ---------------------------------------------------------------------------
@@ -714,6 +903,9 @@ def test_observability_files_tpulint_clean():
         "elasticsearch_tpu/threadpool.py",
         "elasticsearch_tpu/parallel/mesh_serving.py",
         "elasticsearch_tpu/monitor.py",
+        "elasticsearch_tpu/http/server.py",
+        "elasticsearch_tpu/ops/scoring.py",
+        "elasticsearch_tpu/common/jaxenv.py",
     }
     findings = [f for f in lint_paths(None) if f.path in wanted]
     assert findings == [], [f.to_dict() for f in findings]
