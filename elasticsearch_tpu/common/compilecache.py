@@ -385,6 +385,10 @@ def _freeze_spec(argspec) -> tuple:
 
 
 MANIFEST_NAME = "compile_manifest.json"
+# the launch sites' operand lists are part of every recorded argspec: a
+# manifest written under another list would replay calls the programs no
+# longer take, so its specs are dropped at load (the ladders are kept)
+MANIFEST_VERSION = 2  # 2: operand planes (scoring.TermBatch.tri / SparseBatch.slots)
 _MESH_RING = 4  # recent mesh plan batches kept per index
 
 
@@ -610,7 +614,7 @@ class CompileWarmRegistry:
         with self._lock:
             if not self._dirty:
                 return
-            payload = {"version": 1,
+            payload = {"version": MANIFEST_VERSION,
                        "specs": [s.to_json() for s in self._specs.values()],
                        "ladders": LADDERS.to_json(),
                        "mesh": {i: list(r) for i, r in self._mesh.items()}}
@@ -634,7 +638,9 @@ class CompileWarmRegistry:
             return 0
         LADDERS.load_json(payload.get("ladders") or {})
         loaded = 0
-        for d in payload.get("specs", ()):
+        specs = (payload.get("specs", ())
+                 if payload.get("version") == MANIFEST_VERSION else ())
+        for d in specs:
             try:
                 spec = WarmSpec.from_json(d)
                 key = spec.key()

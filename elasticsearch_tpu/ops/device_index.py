@@ -184,6 +184,10 @@ class PackedSegment:
     agg_rows: dict = dc_field(default_factory=dict)  # field -> HOST f32 [5, Dpad] | None (not f32-exact)
     agg_stacks: dict = dc_field(default_factory=dict)  # fields-tuple -> device [F, 5, Dpad], FIFO-bounded
     bucket_cols: dict = dc_field(default_factory=dict)  # bucket-agg cache key -> device (pair_doc, pair_bucket, zeros[NB])
+    # the dense launches' stacked tables (scoring._stack_args), FIFO-bounded:
+    # fields-tuple -> (host caches f32 [F, 256], device norms_stack u8 [F, Dpad],
+    # device caches) — a warmed launch restacks and re-puts neither
+    dense_tables: dict = dc_field(default_factory=dict)
     # reusable [Qb, TB] staging arrays for the sparse planner (scoring.
     # SparseScratchPool, lazily created) — the per-bucket padding scratch lives
     # WITH the segment cache so warmed repeat batches re-pad in place instead
@@ -291,6 +295,8 @@ def packed_tier_bytes(packed: PackedSegment) -> dict:
         norms += _plane_bytes(col)
     for col in packed.dv_single.values():
         norms += _plane_bytes(col)
+    for (_host, norms_stack, _caches) in list(packed.dense_tables.values()):
+        norms += _plane_bytes(norms_stack)
     return {
         "postings": postings,
         "dense_plane": _plane_bytes(packed.blk_freqs),

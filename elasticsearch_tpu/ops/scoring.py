@@ -54,12 +54,23 @@ MODE_TFIDF = 1  # contribution = w * sqrt(freq) * cache[normbyte]
 MODE_CONST = 2  # contribution = w per matching term (constant-score / filters)
 
 
+# rows of TermBatch.tri, the per-triple operand plane of every dense launch
+_T_QIDX, _T_BLK, _T_WEIGHT, _T_FIDX, _T_GROUP, _T_TFMODE = range(6)
+
+
 @dataclass
 class TermBatch:
     """Flattened (query, term, block) triples + per-query bool-semantics arrays.
-    Built host-side by the query planner (search/execute.py)."""
+    Built host-side by the query planner (search/execute.py).
+
+    A launch hands the device two planes: `tri` (int32 [6, M], one row per
+    triple column, the f32 weights as their bits) and `qplane` (int32
+    [Q, 2 + C+1]: n_must, msm, then the coord row as its bits). The
+    per-triple columns below are host VIEWS of `tri`."""
 
     n_queries: int
+    tri: np.ndarray  # int32 [6, M] — rows _T_*
+    qplane: np.ndarray  # int32 [Q, 2 + C+1]
     # per triple (padded to bucket):
     qidx: np.ndarray  # int32 [M]
     blk: np.ndarray  # int32 [M] — block row in the packed segment (pad: NBpad-? safe row)
@@ -217,14 +228,17 @@ class LaunchCounters:
     `posting_bytes` is the program's own reckoning of the HBM bytes a launch
     reads, no measurement; each formula sits beside its launch
     (score_sparse_batch_async, _count_dense). `dense_rows` counts the
-    [doc_pad]-wide score rows of the dense launches."""
+    [doc_pad]-wide score rows of the dense launches. `operand_puts` counts
+    the host arrays launch sites put on the device (_put_operands: each leaf
+    of a launch's one device_put is its own transfer), two for a warmed
+    plain launch."""
 
     def __init__(self):
         self._lock = threading.Lock()
         self._c = dict.fromkeys(
             ("blocks_real", "blocks_launched", "blocks_padding",
              "posting_bytes", "dense_rows", "launches_sparse",
-             "launches_dense"), 0)
+             "launches_dense", "operand_puts"), 0)
 
     def add(self, real: int, launched: int, nbytes: int,
             dense_rows: int = 0) -> None:
@@ -237,12 +251,48 @@ class LaunchCounters:
             c["dense_rows"] += dense_rows
             c["launches_dense" if dense_rows else "launches_sparse"] += 1
 
+    def puts(self, n: int) -> None:
+        with self._lock:
+            self._c["operand_puts"] += n
+
     def snapshot(self) -> dict:
         with self._lock:
             return dict(self._c)
 
 
 LAUNCHES = LaunchCounters()
+
+
+def _put_operands(*host):
+    """A launch's ONE explicit host→device transfer: every host operand goes
+    down in a single jax.device_put (legal under transfer_guard("disallow");
+    leaves already on the device pass through untouched). Returns the
+    operands as device arrays, in order."""
+    import jax
+
+    LAUNCHES.puts(sum(isinstance(leaf, (np.ndarray, np.generic))
+                      for leaf in jax.tree_util.tree_leaves(host)))
+    return jax.device_put(host)
+
+
+def _unpack_qplane(qplane):
+    """(n_must, msm, coord) from the per-query operand plane, inside a
+    program: the coord bits back to the f32 they are (exact)."""
+    import jax
+    import jax.numpy as jnp
+
+    return (qplane[:, 0], qplane[:, 1],
+            jax.lax.bitcast_convert_type(qplane[:, 2:], jnp.float32))
+
+
+def _pack_qplane(n_must, msm, coord) -> np.ndarray:
+    """The per-query operand plane (host side of _unpack_qplane)."""
+    coord = np.ascontiguousarray(coord, np.float32)
+    qplane = np.empty((coord.shape[0], 2 + coord.shape[1]), np.int32)
+    qplane[:, 0] = n_must
+    qplane[:, 1] = msm
+    qplane[:, 2:] = coord.view(np.int32)
+    return qplane
 
 
 def _launch(fn, args, site: str | None = None, family: str = "", params=()):
@@ -286,16 +336,33 @@ def _count_dense(packed: PackedSegment, batch: TermBatch) -> None:
                  m * BLOCK * (4 + 4 + 1) + q * packed.doc_pad * 4, dense_rows=q)
 
 
+def _dense_abi(impl, **statics):
+    """`impl` behind the dense launch ABI (blk_docs, blk_freqs, live_parent,
+    norms_stack, caches, tri, qplane, *extra): the two operand planes a launch
+    puts on the device are taken apart INSIDE the program into the columns
+    every dense kernel takes (row slices and a bit-exact bitcast)."""
+    def wrapper(blk_docs, blk_freqs, live_parent, norms_stack, caches,
+                tri, qplane, *extra):
+        import jax
+        import jax.numpy as jnp
+
+        weight = jax.lax.bitcast_convert_type(tri[_T_WEIGHT], jnp.float32)
+        return impl(blk_docs, blk_freqs, live_parent, norms_stack, caches,
+                    tri[_T_QIDX], tri[_T_BLK], weight, tri[_T_FIDX],
+                    tri[_T_GROUP], tri[_T_TFMODE], *_unpack_qplane(qplane),
+                    *extra, **statics)
+
+    return wrapper
+
+
 def _get_compiled(n_queries: int, k: int, doc_pad: int, simple: bool = False):
     import jax
 
     key = (n_queries, k, doc_pad, simple)
     fn = _compiled_cache.get(key)
     if fn is None:
-        def wrapper(*args):
-            return _score_batch_impl(*args, n_queries=n_queries, k=k, doc_pad=doc_pad,
-                                     simple=simple)
-
+        wrapper = _dense_abi(_score_batch_impl, n_queries=n_queries, k=k,
+                             doc_pad=doc_pad, simple=simple)
         fn = jax.jit(_named("scoring.dense", wrapper, "simple" if simple else "bool"))
         _compiled_cache[key] = fn
     return fn
@@ -421,37 +488,53 @@ def _get_fs_compiled(kind: str, n_queries: int, k: int, doc_pad: int, **statics)
         impl = functools.partial(_fs_script_impl, script=script)
     fn = _compiled_cache.get(key)
     if fn is None:
-        def wrapper(*args):
-            return impl(*args, n_queries=n_queries, k=k, doc_pad=doc_pad, **statics)
-
+        wrapper = _dense_abi(impl, n_queries=n_queries, k=k, doc_pad=doc_pad,
+                             **statics)
         fn = jax.jit(_named("scoring.fs_" + kind, wrapper))
         _compiled_cache[key] = fn
     return fn
 
 
+_DENSE_TABLES_MAX = 8  # field tuples kept per segment (FIFO, like agg_stacks)
+
+
 def _stack_args(packed: PackedSegment, batch: TermBatch):
     """Kernel ABI: the stacked norm-byte and cache tables every dense launch takes
-    (single construction site — the fallback shapes are load-bearing)."""
+    (single construction site — the fallback shapes are load-bearing).
+
+    Kept on the packed segment per field tuple, so a warmed launch runs no
+    eager stack program and puts no table: a tuple's norm rows never change
+    once every field has one (execute._ensure_norm_rows adds the missing
+    ones BEFORE this runs), and the cache rows are compared by value — they
+    move with avgdl, as the sparse path's SimTables do."""
     import jax.numpy as jnp
 
-    norms_stack = (
-        jnp.stack([packed.norm_bytes[f] for f in batch.norm_fields])
-        if batch.norm_fields
-        else jnp.zeros((1, packed.doc_pad), jnp.uint8)
-    )
-    caches = jnp.asarray(
-        batch.caches if batch.caches is not None else np.ones((1, 256), np.float32)
-    )
-    return norms_stack, caches
+    key = tuple(batch.norm_fields)
+    caches = (batch.caches if batch.caches is not None
+              else np.ones((1, 256), np.float32))
+    held = packed.dense_tables.get(key)
+    if held is not None and np.array_equal(held[0], caches):
+        return held[1], held[2]
+    norms_stack = held[1] if held is not None else (
+        jnp.stack([packed.norm_bytes[f] for f in key]) if key
+        else jnp.zeros((1, packed.doc_pad), jnp.uint8))
+    (caches_dev,) = _put_operands(caches)
+    if held is None:
+        while len(packed.dense_tables) >= _DENSE_TABLES_MAX:
+            packed.dense_tables.pop(next(iter(packed.dense_tables)), None)
+    packed.dense_tables[key] = (caches, norms_stack, caches_dev)
+    return norms_stack, caches_dev
 
 
-def _scalar_f32(x):
-    """Device f32 scalar via EXPLICIT placement: eager jnp.float32(x) routes a
-    0-d convert_element_type through an implicit host→device transfer, which
-    the transfer_guard("disallow") sanitizer rejects at dispatch sites."""
-    import jax
-
-    return jax.device_put(np.float32(x))
+def _dense_args(packed: PackedSegment, batch: TermBatch, *host):
+    """The argument list of a dense launch (the _dense_abi order): resident
+    planes and tables, then the batch's two operand planes and the family's
+    own `host` operands, all put on the device in one transfer."""
+    norms_stack, caches = _stack_args(packed, batch)
+    _count_dense(packed, batch)
+    return (packed.blk_docs, ensure_blk_freqs(packed), packed.live_parent,
+            norms_stack, caches,
+            *_put_operands(batch.tri, batch.qplane, *host))
 
 
 def score_fs_rows_batch(packed: PackedSegment, batch: TermBatch, k: int,
@@ -459,25 +542,16 @@ def score_fs_rows_batch(packed: PackedSegment, batch: TermBatch, k: int,
                         min_score, bmode: str, no_functions: bool):
     """Dense launch with host-combined function rows; returns (scores, docs, total)
     numpy [Q, k]/[Q]."""
-    import jax.numpy as jnp
-
-    norms_stack, caches = _stack_args(packed, batch)
     params = (batch.n_queries, min(k, packed.doc_pad), packed.doc_pad,
               bmode, min_score is not None, no_functions)
     fn = _get_fs_compiled(
         "rows", params[0], params[1], params[2],
         bmode=bmode, use_min_score=min_score is not None, no_functions=no_functions)
-    args = (
-        packed.blk_docs, ensure_blk_freqs(packed), packed.live_parent,
-        norms_stack, caches,
-        jnp.asarray(batch.qidx), jnp.asarray(batch.blk), jnp.asarray(batch.weight),
-        jnp.asarray(batch.fidx), jnp.asarray(batch.group), jnp.asarray(batch.tfmode),
-        jnp.asarray(batch.n_must), jnp.asarray(batch.msm), jnp.asarray(batch.coord),
-        jnp.asarray(g_row, jnp.float32), jnp.asarray(applies_row, bool),
-        _scalar_f32(max_boost), _scalar_f32(fboost),
-        _scalar_f32(min_score if min_score is not None else 0.0),
-    )
-    _count_dense(packed, batch)
+    args = _dense_args(
+        packed, batch,
+        np.asarray(g_row, np.float32), np.asarray(applies_row, bool),
+        np.float32(max_boost), np.float32(fboost),
+        np.float32(min_score if min_score is not None else 0.0))
     # the script variant is NOT recorded: its executable closes over a live
     # sandboxed script object that has no JSON form to replay from a manifest
     return _pull(_launch(fn, args, "scoring.fs_rows", "function_score", params))
@@ -489,28 +563,19 @@ def score_fs_script_batch(packed: PackedSegment, batch: TermBatch, k: int,
                           fboost: float, min_score, bmode: str, has_filter: bool):
     """Dense launch with the script traced into the kernel; returns
     (scores, docs, total, bad) numpy."""
-    import jax.numpy as jnp
-
-    norms_stack, caches = _stack_args(packed, batch)
     fn = _get_fs_compiled(
         "script", batch.n_queries, min(k, packed.doc_pad), packed.doc_pad,
         script=script, used_fields=used_fields, bmode=bmode,
         use_min_score=min_score is not None, has_filter=has_filter,
         has_weight=weight is not None)
-    _count_dense(packed, batch)
-    return _pull(_launch(fn, (
-        packed.blk_docs, ensure_blk_freqs(packed), packed.live_parent,
-        norms_stack, caches,
-        jnp.asarray(batch.qidx), jnp.asarray(batch.blk), jnp.asarray(batch.weight),
-        jnp.asarray(batch.fidx), jnp.asarray(batch.group), jnp.asarray(batch.tfmode),
-        jnp.asarray(batch.n_must), jnp.asarray(batch.msm), jnp.asarray(batch.coord),
-        tuple(jnp.asarray(c, jnp.float32) for c in col_rows),
-        jnp.asarray(fmask_row, bool), jnp.asarray(bad_row, bool),
-        jnp.asarray(parent_row, bool),
-        _scalar_f32(weight if weight is not None else 1.0),
-        _scalar_f32(max_boost), _scalar_f32(fboost),
-        _scalar_f32(min_score if min_score is not None else 0.0),
-    )))
+    return _pull(_launch(fn, _dense_args(
+        packed, batch,
+        tuple(col_rows),
+        np.asarray(fmask_row, bool), np.asarray(bad_row, bool),
+        np.asarray(parent_row, bool),
+        np.float32(weight if weight is not None else 1.0),
+        np.float32(max_boost), np.float32(fboost),
+        np.float32(min_score if min_score is not None else 0.0))))
 
 
 # ---------------------------------------------------------------------------
@@ -530,7 +595,10 @@ def score_filtered_batch(packed: PackedSegment, batch: TermBatch, k: int, fmask)
     reference's FilteredQuery — the filter gates matching, never scoring,
     XFilteredQuery). Rides score_agg_batch with an empty agg stack (F=0): one
     kernel family to keep in sync. Returns numpy (scores, docs, total)."""
-    empty = np.zeros((0, 5, packed.doc_pad), np.float32)
+    empty = packed.agg_stacks.get(())  # device_index.ensure_agg_rows' key
+    if empty is None:
+        (empty,) = _put_operands(np.zeros((0, 5, packed.doc_pad), np.float32))
+        packed.agg_stacks[()] = empty
     scores, docs, total, _counts, _stats, _buckets = score_agg_batch(
         packed, batch, k, empty, (), fmask=fmask, filtered=True)
     return scores, docs, total
@@ -578,10 +646,8 @@ def _get_sorted_compiled(n_queries: int, k: int, doc_pad: int,
     key = ("sorted", n_queries, k, doc_pad, descending)
     fn = _compiled_cache.get(key)
     if fn is None:
-        def wrapper(*args):
-            return _dense_sort_impl(*args, n_queries=n_queries, k=k,
-                                    doc_pad=doc_pad, descending=descending)
-
+        wrapper = _dense_abi(_dense_sort_impl, n_queries=n_queries, k=k,
+                             doc_pad=doc_pad, descending=descending)
         fn = jax.jit(_named("scoring.sorted", wrapper))
         _compiled_cache[key] = fn
     return fn
@@ -592,23 +658,12 @@ def score_sorted_batch(packed: PackedSegment, batch: TermBatch, k: int,
     """Field-sorted dense launch; returns numpy (keys, docs, scores, qmax,
     total). Matched docs occupy the first min(total, k) slots per query
     (padding ranks strictly after ±FLT_MAX missing keys)."""
-    import jax.numpy as jnp
-
-    norms_stack, caches = _stack_args(packed, batch)
     params = (batch.n_queries, min(k, packed.doc_pad), packed.doc_pad,
               descending)
     fn = _get_sorted_compiled(*params)
     if fmask is None:
         fmask = np.ones((1, 1), dtype=bool)
-    args = (
-        packed.blk_docs, ensure_blk_freqs(packed), packed.live_parent,
-        norms_stack, caches,
-        jnp.asarray(batch.qidx), jnp.asarray(batch.blk), jnp.asarray(batch.weight),
-        jnp.asarray(batch.fidx), jnp.asarray(batch.group), jnp.asarray(batch.tfmode),
-        jnp.asarray(batch.n_must), jnp.asarray(batch.msm), jnp.asarray(batch.coord),
-        jnp.asarray(fmask), jnp.asarray(key_row),
-    )
-    _count_dense(packed, batch)
+    args = _dense_args(packed, batch, fmask, key_row)
     return _pull(_launch(fn, args, "scoring.sorted", "sorted", params))
 
 
@@ -708,10 +763,8 @@ def _get_agg_compiled(n_queries: int, k: int, doc_pad: int, nb_bucket: int,
     key = ("aggstats", n_queries, k, doc_pad, nb_bucket, filtered)
     fn = _compiled_cache.get(key)
     if fn is None:
-        def wrapper(*args):
-            return _dense_aggstats_impl(*args, n_queries=n_queries, k=k,
-                                        doc_pad=doc_pad)
-
+        wrapper = _dense_abi(_dense_aggstats_impl, n_queries=n_queries, k=k,
+                             doc_pad=doc_pad)
         fn = jax.jit(_named("scoring.aggs", wrapper, "filtered" if filtered else ""))
         _compiled_cache[key] = fn
     return fn
@@ -728,9 +781,6 @@ def score_agg_batch(packed: PackedSegment, batch: TermBatch, k: int,
     [Q,Fs,NB]|None, sub stats [Q,Fs,NB,4]|None); fmask: optional bool [Q, Dpad]
     FilteredQuery match gates; `filtered` marks the filtered family's launches
     (no aggregation at all), which compile under their own program name."""
-    import jax.numpy as jnp
-
-    norms_stack, caches = _stack_args(packed, batch)
     params = (batch.n_queries, min(k, packed.doc_pad), packed.doc_pad,
               _pow2_bucket(len(bucket_pairs), 1) if bucket_pairs else 0,
               filtered)
@@ -739,17 +789,9 @@ def score_agg_batch(packed: PackedSegment, batch: TermBatch, k: int,
         # broadcastable no-op mask: [1, 1] & [Q, Dpad] — avoids allocating and
         # transferring a full all-true mask on the unfiltered aggs hot path
         fmask = np.ones((1, 1), dtype=bool)
-    args = (
-        packed.blk_docs, ensure_blk_freqs(packed), packed.live_parent,
-        norms_stack, caches,
-        jnp.asarray(batch.qidx), jnp.asarray(batch.blk), jnp.asarray(batch.weight),
-        jnp.asarray(batch.fidx), jnp.asarray(batch.group), jnp.asarray(batch.tfmode),
-        jnp.asarray(batch.n_must), jnp.asarray(batch.msm), jnp.asarray(batch.coord),
-        # jnp.asarray commits a host stack explicitly (no-op for device
-        # arrays); a raw numpy arg would be an implicit H2D at dispatch
-        jnp.asarray(agg_row_stack), tuple(bucket_pairs), jnp.asarray(fmask),
-    )
-    _count_dense(packed, batch)
+    # a host agg stack or mask rides the launch's one put (device arrays
+    # pass through it); a raw numpy arg would be an implicit H2D at dispatch
+    args = _dense_args(packed, batch, agg_row_stack, tuple(bucket_pairs), fmask)
     # ONE explicit pull for the whole result pytree: per-leaf np.asarray was a
     # transfer per output — and an implicit one, which the promoted
     # transfer_guard("disallow") sanitizer now rejects
@@ -777,42 +819,16 @@ def _detect_simple(batch: TermBatch) -> bool:
 def score_term_batch_async(packed: PackedSegment, batch: TermBatch, k: int):
     """Like score_term_batch but returns device arrays without syncing — callers that
     pipeline many batches block once at the end (the serving/bench throughput path)."""
-    import jax.numpy as jnp
-
-    Q = batch.n_queries
-    norms_stack, caches = _stack_args(packed, batch)
-    params = (Q, min(k, packed.doc_pad), packed.doc_pad, _detect_simple(batch))
-    fn = _get_compiled(*params)
-    args = (
-        packed.blk_docs, ensure_blk_freqs(packed), packed.live_parent,
-        norms_stack, caches,
-        jnp.asarray(batch.qidx), jnp.asarray(batch.blk), jnp.asarray(batch.weight),
-        jnp.asarray(batch.fidx), jnp.asarray(batch.group), jnp.asarray(batch.tfmode),
-        jnp.asarray(batch.n_must), jnp.asarray(batch.msm), jnp.asarray(batch.coord),
-    )
-    _count_dense(packed, batch)
-    return _launch(fn, args, "scoring.dense", "dense", params)
+    params = (batch.n_queries, min(k, packed.doc_pad), packed.doc_pad,
+              _detect_simple(batch))
+    return _launch(_get_compiled(*params), _dense_args(packed, batch),
+                   "scoring.dense", "dense", params)
 
 
 def score_term_batch(packed: PackedSegment, batch: TermBatch, k: int) -> ScoreResult:
     """Execute a term batch against one packed segment; returns per-query top-k with
     local doc ids (doc_count/doc_pad sentinel = no hit)."""
-    import jax.numpy as jnp
-
-    Q = batch.n_queries
-    norms_stack, caches = _stack_args(packed, batch)
-    params = (Q, min(k, packed.doc_pad), packed.doc_pad, _detect_simple(batch))
-    fn = _get_compiled(*params)
-    args = (
-        packed.blk_docs, ensure_blk_freqs(packed), packed.live_parent,
-        norms_stack, caches,
-        jnp.asarray(batch.qidx), jnp.asarray(batch.blk), jnp.asarray(batch.weight),
-        jnp.asarray(batch.fidx), jnp.asarray(batch.group), jnp.asarray(batch.tfmode),
-        jnp.asarray(batch.n_must), jnp.asarray(batch.msm), jnp.asarray(batch.coord),
-    )
-    _count_dense(packed, batch)
-    top_scores, top_docs, total = _pull(
-        _launch(fn, args, "scoring.dense", "dense", params))
+    top_scores, top_docs, total = _pull(score_term_batch_async(packed, batch, k))
     return finalize_score_result(top_scores, top_docs, total, packed.doc_pad)
 
 
@@ -858,14 +874,14 @@ def finalize_score_result(scores: np.ndarray, docs: np.ndarray, total: np.ndarra
 
 
 class SparseScratchPool:
-    """Reusable per-bucket padded staging arrays for plan_sparse_buckets.
+    """Reusable per-bucket padded staging planes for plan_sparse_buckets.
 
-    The sparse planner re-materialized four [Qb, TB] host arrays (qblk/qw/
-    qconst/qcnt) for every bucket of every launch, even when the shapes repeat
-    on every warmed batch — pure allocator churn on the serving hot path.
-    The pool hands out (and takes back) array SETS keyed by (Qb, TB): a warmed
-    repeat batch performs 0 new host allocations (`allocs` stays flat, pinned
-    by tests/test_batcher.py). Arrays are borrowed from take() until the
+    The sparse planner re-materialized its [Qb, TB] host staging for every
+    bucket of every launch, even when the shapes repeat on every warmed
+    batch — pure allocator churn on the serving hot path. The pool hands out
+    (and takes back) one slot plane (SparseBatch.slots) keyed by (Qb, TB): a
+    warmed repeat batch performs 0 new host allocations (`allocs` stays flat,
+    pinned by tests/test_batcher.py). A plane is borrowed from take() until the
     launch's results have been PULLED — device transfers are asynchronous (and
     on CPU possibly zero-copy aliases of the numpy buffer), so giving an array
     back while its launch is still in flight would let the next take() mutate
@@ -875,7 +891,7 @@ class SparseScratchPool:
     the free-list is bounded so a concurrency burst can't pin staging memory
     forever."""
 
-    _MAX_FREE = 4  # sets kept per shape
+    _MAX_FREE = 4  # planes kept per shape
 
     def __init__(self):
         self._free: dict[tuple, list] = {}
@@ -885,57 +901,63 @@ class SparseScratchPool:
 
     @staticmethod
     def staging_bytes(Qb: int, tb: int) -> int:
-        # qblk i32 + qw f32 + qconst bool + qcnt i32 + qfid i32
-        return Qb * tb * (4 + 4 + 1 + 4 + 4)
+        return len(_S_ROWS) * Qb * tb * 4  # one int32 plane, a row per column
 
     def take(self, Qb: int, tb: int, sentinel_row: int):
         with self._lock:
             lst = self._free.get((Qb, tb))
-            arrs = lst.pop() if lst else None
+            slots = lst.pop() if lst else None
+            if slots is None:
+                self.allocs += 1
+            else:
+                self.reuses += 1
         # profile attribution: whether this launch's staging came from the
         # pool or a fresh allocation (recorded OUTSIDE the pool lock — the
         # hook is record-only and must never run under another lock)
         prof = _profile.current()
-        if arrs is None:
-            with self._lock:
-                self.allocs += 1
-            if prof is not None:
-                prof.event("scratch", cache="alloc", shape=[int(Qb), int(tb)])
-            return (np.full((Qb, tb), sentinel_row, np.int32),
-                    np.zeros((Qb, tb), np.float32),
-                    np.zeros((Qb, tb), bool),
-                    np.zeros((Qb, tb), np.int32),
-                    np.zeros((Qb, tb), np.int32))
-        with self._lock:
-            self.reuses += 1
         if prof is not None:
-            prof.event("scratch", cache="reuse", shape=[int(Qb), int(tb)])
-        qblk, qw, qconst, qcnt, qfid = arrs
-        qblk.fill(sentinel_row)
-        qw.fill(0.0)
-        qconst.fill(False)
-        qcnt.fill(0)
-        qfid.fill(0)
-        return arrs
+            prof.event("scratch", cache="alloc" if slots is None else "reuse",
+                       shape=[int(Qb), int(tb)])
+        return _blank_slots(Qb, tb, sentinel_row, slots)
 
-    def give(self, arrs):
-        qblk = arrs[0]
-        key = qblk.shape
+    def give(self, slots):
         with self._lock:
-            lst = self._free.setdefault(key, [])
+            lst = self._free.setdefault(slots.shape[1:], [])
             if len(lst) < self._MAX_FREE:
-                lst.append(arrs)
+                lst.append(slots)
+
+
+# rows of SparseBatch.slots, the per-slot operand plane of a sparse launch
+_S_ROWS = _S_QBLK, _S_QW, _S_QCONST, _S_QCNT, _S_QFID = range(5)
+
+
+def _blank_slots(Qb: int, tb: int, sentinel_row: int, slots=None) -> np.ndarray:
+    """An all-padding slot plane: every block slot the sentinel row, weight
+    bits / const flag / counter / field row zero. Refills `slots` in place
+    when given one (the scratch pool's reuse)."""
+    if slots is None:
+        slots = np.empty((len(_S_ROWS), Qb, tb), np.int32)
+    slots[_S_QBLK] = sentinel_row
+    slots[_S_QBLK + 1:] = 0
+    return slots
 
 
 @dataclass
 class SparseBatch:
-    """One bucket of queries sharing a [Qb, TB] block layout."""
+    """One bucket of queries sharing a [Qb, TB] block layout.
+
+    A launch hands the device two planes: `slots` (int32 [5, Qb, TB], a row
+    per slot column, the f32 weights as their bits, the const flag as 0/1)
+    and `qplane` (int32 [Qb, 2 + C+1], as TermBatch's). The [Qb, TB] columns
+    below are host VIEWS of `slots`."""
 
     n_queries: int  # real queries (rows beyond are padding)
     qids: np.ndarray  # int32 [Qb] — caller's query index per row (-1 padding)
+    slots: np.ndarray  # int32 [5, Qb, TB] — rows _S_*
+    qplane: np.ndarray  # int32 [Qb, 2 + C+1]
     qblk: np.ndarray  # int32 [Qb, TB] — block rows (pad: sentinel all-doc_pad row)
     qw: np.ndarray  # float32 [Qb, TB] — clause weight (0 for must_not/padding)
-    qconst: np.ndarray  # bool [Qb, TB] — constant-score clause (contribution = w)
+    qconst: np.ndarray  # int32 0/1 [Qb, TB] — constant-score clause (contribution = w)
     qcnt: np.ndarray  # int32 [Qb, TB] — packed group counter (should/must/must_not bit)
     qfid: np.ndarray  # int32 [Qb, TB] — SimTables cache row of the clause's field
     n_must: np.ndarray  # int32 [Qb]
@@ -1097,10 +1119,17 @@ def _get_sparse_compiled(Qb: int, TB: int, k: int, doc_pad: int, passes: int,
            use_pallas)
     fn = _compiled_cache.get(key)
     if fn is None:
-        def wrapper(*args):
-            return _sparse_impl(*args, k=k, doc_pad=doc_pad, passes=passes,
-                                simple=simple, use_coord=use_coord,
-                                use_pallas=use_pallas)
+        def wrapper(blk_docs, blk_tf, blk_nb, caches, modes, slots, qplane):
+            import jax.numpy as jnp
+
+            # the launch's two operand planes, taken apart inside the program
+            qw = jax.lax.bitcast_convert_type(slots[_S_QW], jnp.float32)
+            return _sparse_impl(
+                blk_docs, blk_tf, blk_nb, caches, modes,
+                slots[_S_QBLK], qw, slots[_S_QCONST] != 0, slots[_S_QCNT],
+                slots[_S_QFID], *_unpack_qplane(qplane),
+                k=k, doc_pad=doc_pad, passes=passes, simple=simple,
+                use_coord=use_coord, use_pallas=use_pallas)
 
         fn = jax.jit(_named("scoring.sparse", wrapper))
         _compiled_cache[key] = fn
@@ -1112,8 +1141,6 @@ def score_sparse_batch_async(packed: PackedSegment, sb: SparseBatch, k: int,
     """Launch one sparse bucket; returns device arrays (scores, docs, totals)
     without syncing. `sim` is the SimTables the planner resolved fids against
     (device_index.ensure_sim_tables); defaults to the segment's current one."""
-    import jax.numpy as jnp
-
     sim = sim if sim is not None else packed.sim
     Qb, TB = sb.qblk.shape
     P = TB * BLOCK
@@ -1122,12 +1149,8 @@ def score_sparse_batch_async(packed: PackedSegment, sb: SparseBatch, k: int,
     params = (Qb, TB, k_eff, packed.doc_pad, sb.passes, sb.simple, use_coord,
               sb.coord.shape[1])
     fn = _get_sparse_compiled(*params)
-    args = (
-        packed.blk_docs, packed.blk_tf, packed.blk_nb, sim.caches, sim.modes,
-        jnp.asarray(sb.qblk), jnp.asarray(sb.qw), jnp.asarray(sb.qconst),
-        jnp.asarray(sb.qcnt), jnp.asarray(sb.qfid), jnp.asarray(sb.n_must),
-        jnp.asarray(sb.msm), jnp.asarray(sb.coord),
-    )
+    args = (packed.blk_docs, packed.blk_tf, packed.blk_nb, sim.caches, sim.modes,
+            *_put_operands(sb.slots, sb.qplane))
     # what the launch scans against what the queries name: [Qb, TB] block
     # slots, each BLOCK postings of (doc id i32, tf, norm byte)
     LAUNCHES.add(sb.blocks_real, Qb * TB,
@@ -1170,14 +1193,11 @@ def plan_sparse_buckets(clause_lists: list, n_must: np.ndarray, msm: np.ndarray,
         for start in range(0, len(qis), max_q):
             chunk = qis[start: start + max_q]
             Qb = _ladder_bucket("sparse_qb", len(chunk), 8)
-            if scratch is not None:
-                qblk, qw, qconst, qcnt, qfid = scratch.take(Qb, tb, sentinel_row)
-            else:
-                qblk = np.full((Qb, tb), sentinel_row, np.int32)
-                qw = np.zeros((Qb, tb), np.float32)
-                qconst = np.zeros((Qb, tb), bool)
-                qcnt = np.zeros((Qb, tb), np.int32)
-                qfid = np.zeros((Qb, tb), np.int32)
+            slots = (scratch.take(Qb, tb, sentinel_row) if scratch is not None
+                     else _blank_slots(Qb, tb, sentinel_row))
+            qblk, qconst, qcnt, qfid = (
+                slots[r] for r in (_S_QBLK, _S_QCONST, _S_QCNT, _S_QFID))
+            qw = slots[_S_QW].view(np.float32)
             qids = np.full(Qb, -1, np.int32)
             bn_must = np.zeros(Qb, np.int32)
             bmsm = np.zeros(Qb, np.int32)
@@ -1205,8 +1225,9 @@ def plan_sparse_buckets(clause_lists: list, n_must: np.ndarray, msm: np.ndarray,
                     off += nb
             passes = max(0, (maxc - 1).bit_length())
             batches.append(SparseBatch(
-                n_queries=len(chunk), qids=qids, qblk=qblk, qw=qw, qconst=qconst,
-                qcnt=qcnt, qfid=qfid, n_must=bn_must, msm=bmsm, coord=bcoord,
+                n_queries=len(chunk), qids=qids, slots=slots,
+                qplane=_pack_qplane(bn_must, bmsm, bcoord), qblk=qblk, qw=qw, qconst=qconst, qcnt=qcnt, qfid=qfid,
+                n_must=bn_must, msm=bmsm, coord=bcoord,
                 passes=passes, simple=simple,
                 blocks_real=sum(tb_host[qi] for qi in chunk)))
     return batches, overflow
@@ -1245,7 +1266,7 @@ def launch_flat_sparse(packed: PackedSegment, clause_lists: list,
 
     def release():
         for sb in batches:
-            scratch.give((sb.qblk, sb.qw, sb.qconst, sb.qcnt, sb.qfid))
+            scratch.give(sb.slots)
 
     return launches, overflow, release
 
@@ -1295,24 +1316,39 @@ def score_flat_sparse(packed: PackedSegment, clause_lists: list, n_must: np.ndar
 def build_term_batch(entries: list, n_queries: int, n_must: np.ndarray, msm: np.ndarray,
                      coord: np.ndarray, norm_fields: list[str], caches: np.ndarray,
                      nb_pad_row: int) -> TermBatch:
-    """Assemble + bucket-pad the flat triple arrays.
+    """Expand clause block ranges into the flat triple plane + bucket-pad it.
 
-    `entries` = list of (qidx, blk_row, weight, fidx, group, tfmode); padding rows point
-    at `nb_pad_row` (a row of doc_pad sentinels — contributes nothing)."""
-    M = _ladder_bucket("terms", max(len(entries), 1), 16)
-    qidx = np.zeros(M, np.int32)
-    blk = np.full(M, nb_pad_row, np.int32)
-    weight = np.zeros(M, np.float32)
-    fidx = np.zeros(M, np.int32)
-    group = np.zeros(M, np.int32)
-    tfmode = np.zeros(M, np.int32)
-    for i, (q, b, w, f, g, m) in enumerate(entries):
-        qidx[i], blk[i], weight[i], fidx[i], group[i], tfmode[i] = q, b, w, f, g, m
+    `entries` = one (qidx, b0, b1, weight, fidx, group, tfmode) per resolved
+    clause (execute._dense_entries): the clause contributes a triple per block
+    row of [b0, b1), clauses in entry order, blocks ascending. The expansion is
+    one np.repeat of the clause columns, never a Python loop over blocks;
+    padding rows point at `nb_pad_row` (a row of doc_pad sentinels —
+    contributes nothing) with every other column zero."""
+    cols = np.zeros((6, len(entries)), np.int32)
+    nb = np.zeros(len(entries), np.int64)
+    if entries:
+        q, b0, b1, w, f, g, m = zip(*entries)
+        b0 = np.asarray(b0, np.int64)
+        nb = np.maximum(np.asarray(b1, np.int64) - b0, 0)
+        cols[_T_QIDX], cols[_T_FIDX], cols[_T_GROUP], cols[_T_TFMODE] = q, f, g, m
+        cols[_T_WEIGHT] = np.asarray(w, np.float32).view(np.int32)
+        # a clause's first block row less the index of its first triple: adding
+        # the triple index back (below) walks [b0, b1)
+        cols[_T_BLK] = b0 - (np.cumsum(nb) - nb)
+    n = int(nb.sum())
+    M = _ladder_bucket("terms", max(n, 1), 16)
+    tri = np.zeros((6, M), np.int32)
+    tri[:, :n] = np.repeat(cols, nb, axis=1)
+    tri[_T_BLK, :n] += np.arange(n, dtype=np.int32)
+    tri[_T_BLK, n:] = nb_pad_row
+    n_must, msm = n_must.astype(np.int32), msm.astype(np.int32)
+    coord = coord.astype(np.float32)
     return TermBatch(
-        n_queries=n_queries, qidx=qidx, blk=blk, weight=weight, fidx=fidx, group=group,
-        tfmode=tfmode, n_must=n_must.astype(np.int32), msm=msm.astype(np.int32),
-        coord=coord.astype(np.float32), norm_fields=norm_fields, caches=caches,
-        blocks_real=len(entries),
+        n_queries=n_queries, tri=tri, qplane=_pack_qplane(n_must, msm, coord),
+        qidx=tri[_T_QIDX], blk=tri[_T_BLK], weight=tri[_T_WEIGHT].view(np.float32),
+        fidx=tri[_T_FIDX], group=tri[_T_GROUP], tfmode=tri[_T_TFMODE],
+        n_must=n_must, msm=msm, coord=coord, norm_fields=norm_fields,
+        caches=caches, blocks_real=n,
     )
 
 

@@ -695,20 +695,13 @@ def _dispatch_flat_plain(plans: list[FlatPlan], ctx: ShardContext,
         # cheap LUT swap (1 KB/field), not a postings re-bake: the quantized
         # scan decodes tf→tfn in-kernel against these stacked cache rows
         sim = ensure_sim_tables(packed, sim_tables)
-        clause_lists = []
-        postings_scanned = 0
-        for (resolved, _f, _c, _coord) in finals:
-            cl = []
-            for (f, t, w, _fi, g, mode, df) in resolved:
-                tid = seg.term_id(f, t)
-                if tid is None:
-                    continue
-                b0, b1 = packed.blocks_for_term(tid)
-                cl.append((b0, b1, w, g, mode == MODE_CONST, sim.fid[f]))
-                if prof is not None:
-                    postings_scanned += int(seg.post_offsets[tid + 1]
-                                            - seg.post_offsets[tid])
-            clause_lists.append(cl)
+        # every clause resolved ONCE per segment: the sparse planner's block
+        # ranges and the dense fallback's are the same records
+        entries = _dense_entries(finals, seg, packed, field_idx)
+        fid_of = [sim.fid[f] for f in all_fields]
+        clause_lists = [[] for _ in range(Q)]
+        for (qi, b0, b1, w, fi, g, mode) in entries:
+            clause_lists[qi].append((b0, b1, w, g, mode == MODE_CONST, fid_of[fi]))
         # compile_tag: backend compiles triggered by these launches land in
         # the capacity ledger's per-family attribution (common/jaxenv).
         # Launch failures are tagged with their compile-family fault domain
@@ -731,8 +724,8 @@ def _dispatch_flat_plain(plans: list[FlatPlan], ctx: ShardContext,
                     _DEVICE_FAULTS.check("compile:dense")
                 with compile_tag("dense"):
                     dense = _launch_dense_fallback(
-                        overflow, finals, field_idx, all_fields, caches_stack,
-                        n_must, msm, coord_tbl, packed, seg, k,
+                        overflow, entries, all_fields, caches_stack,
+                        n_must, msm, coord_tbl, packed, k,
                         breaker=ctx.breaker("fielddata"))
             except Exception as e:  # noqa: BLE001 — re-raised tagged
                 raise _tag_domain(e, "compile:dense")
@@ -750,7 +743,7 @@ def _dispatch_flat_plain(plans: list[FlatPlan], ctx: ShardContext,
                 # `blocks_real`): the blocks every launch above named
                 blocks_scanned=sum(sb.blocks_real for (sb, _r) in launches)
                 + (dense[2] if dense is not None else 0),
-                postings_scanned=int(postings_scanned),
+                postings_scanned=_postings_scanned(finals, seg),
                 staged_bytes=sum(
                     SparseScratchPool.staging_bytes(*sb.qblk.shape)
                     for (sb, _r) in launches),
@@ -923,8 +916,9 @@ def _ensure_norm_rows(packed, all_fields, breaker=None):
 
 
 def _dense_entries(finals, seg, packed, field_idx) -> list:
-    """(qidx, block_row, weight, fidx, group, mode) triples for the dense kernel,
-    qidx = position in `finals`."""
+    """One (qidx, b0, b1, weight, fidx, group, mode) record per clause whose
+    term this segment holds — [b0, b1) its block rows in the packed planes,
+    qidx = position in `finals`. scoring.build_term_batch expands the ranges."""
     entries = []
     for qi, (resolved, _f, _c, _coord) in enumerate(finals):
         for (f, t, w, _fi, g, mode, df) in resolved:
@@ -932,22 +926,30 @@ def _dense_entries(finals, seg, packed, field_idx) -> list:
             if tid is None:
                 continue
             b0, b1 = packed.blocks_for_term(tid)
-            for b in range(b0, b1):
-                entries.append((qi, b, w, field_idx[f], g, mode))
+            entries.append((qi, b0, b1, w, field_idx[f], g, mode))
     return entries
 
 
-def _launch_dense_fallback(overflow, finals, field_idx, all_fields, caches_stack,
-                           n_must, msm, coord_tbl, packed, seg, k,
-                           breaker=None):
+def _postings_scanned(finals, seg) -> int:
+    """Postings under every clause's term in this segment (profile API only)."""
+    tids = [seg.term_id(f, t) for (resolved, _f, _c, _coord) in finals
+            for (f, t, *_rest) in resolved]
+    return sum(int(seg.post_offsets[tid + 1] - seg.post_offsets[tid])
+               for tid in tids if tid is not None)
+
+
+def _launch_dense_fallback(overflow, entries, all_fields, caches_stack,
+                           n_must, msm, coord_tbl, packed, k, breaker=None):
     """Launch overflow queries (block count past the sparse planner's tb_max)
-    on the dense scatter kernel WITHOUT syncing; returns (sub indices, device
-    result triple, blocks the launch named) for the merge half, or None when
-    no entries resolved."""
+    on the dense scatter kernel WITHOUT syncing; `entries` are the batch's
+    _dense_entries records. Returns (sub indices, device result triple,
+    blocks the launch named) for the merge half, or None when no entries
+    resolved."""
     from ..ops.scoring import build_term_batch, score_term_batch_async
 
     _ensure_norm_rows(packed, all_fields, breaker=breaker)
-    entries = _dense_entries([finals[qi] for qi in overflow], seg, packed, field_idx)
+    row = {qi: i for i, qi in enumerate(overflow)}
+    entries = [(row[e[0]], *e[1:]) for e in entries if e[0] in row]
     if not entries:
         return None
     sub = np.asarray(overflow, dtype=np.int64)
@@ -1188,8 +1190,6 @@ def execute_flat_sorted(plan: FlatPlan, ctx: ShardContext, k: int, spec):
     or None when any segment's column refuses device keys
     (sorting.device_sort_key_row). Ordering: (key asc/desc, global doc asc) —
     the host lexsort order."""
-    import jax.numpy as jnp
-
     from ..ops.device_index import packed_for
     from ..ops.scoring import build_term_batch, score_sorted_batch
     from .sorting import device_sort_key_row
@@ -1224,8 +1224,7 @@ def execute_flat_sorted(plan: FlatPlan, ctx: ShardContext, k: int, spec):
                                  nb_pad_row=packed.blk_docs.shape[0] - 1)
         with compile_tag("sorted"):
             keys, docs, scores, qmax, tq = score_sorted_batch(
-                packed, batch, max(k, 1), jnp.asarray(key_row), spec.reverse,
-                fmask=fmask)
+                packed, batch, max(k, 1), key_row, spec.reverse, fmask=fmask)
         # batched host pulls: one .tolist() per row instead of a float()/int()
         # scalar conversion per hit (tpulint TPU001)
         (seg_total,) = tq.tolist()

@@ -71,9 +71,8 @@ def _sparse_args(sharding, Qb, TB, coord_w):
         sharding,
         ((ROWS, BLOCK), "int32"), ((ROWS, BLOCK), "uint8"), ((ROWS, BLOCK), "uint8"),
         ((1, 256), "float32"), ((1,), "int32"),  # SimTables caches, modes
-        ((Qb, TB), "int32"), ((Qb, TB), "float32"), ((Qb, TB), "bool"),
-        ((Qb, TB), "int32"), ((Qb, TB), "int32"),
-        ((Qb,), "int32"), ((Qb,), "int32"), ((Qb, coord_w), "float32"))
+        # the launch's two operand planes (SparseBatch.slots / .qplane)
+        ((5, Qb, TB), "int32"), ((Qb, 2 + coord_w), "int32"))
 
 
 @pytest.mark.parametrize("TB,k,passes,simple,coord_w", [
@@ -81,14 +80,11 @@ def _sparse_args(sharding, Qb, TB, coord_w):
     (256, 16, 1, False, 3),    # two-term `operator: and`, size 10
 ], ids=["should4-k128-TB512", "and2-k16-TB256"])
 def test_sparse_launch_compiles_for_v5e(one_chip, TB, k, passes, simple, coord_w):
-    import jax
-
     from elasticsearch_tpu.common.jaxenv import compile_tag
-    from elasticsearch_tpu.ops.scoring import _sparse_impl
+    from elasticsearch_tpu.ops.scoring import _get_sparse_compiled
 
-    fn = jax.jit(functools.partial(
-        _sparse_impl, k=k, doc_pad=DOC_PAD, passes=passes, simple=simple,
-        use_coord=False))
+    # the launch site's own program, operand planes unpacked inside it
+    fn = _get_sparse_compiled(8, TB, k, DOC_PAD, passes, simple, False, coord_w)
     with compile_tag("sparse"):
         compiled = fn.lower(*_sparse_args(one_chip, 8, TB, coord_w)).compile()
     mem = compiled.memory_analysis()
@@ -100,21 +96,17 @@ def test_sparse_launch_compiles_for_v5e(one_chip, TB, k, passes, simple, coord_w
 def test_dense_overflow_launch_compiles_for_v5e(one_chip):
     """One query whose terms span more than tb_max blocks takes the dense launch:
     a [Q, doc_pad] f32 accumulator over the lazily faulted f32 freqs plane."""
-    import jax
-
     from elasticsearch_tpu.common.jaxenv import compile_tag
-    from elasticsearch_tpu.ops.scoring import _score_batch_impl
+    from elasticsearch_tpu.ops.scoring import _get_compiled
 
     Q, E = 1, 2048  # (query, block) entries, bucketed
     args = _shapes(
         one_chip,
         ((ROWS, BLOCK), "int32"), ((ROWS, BLOCK), "float32"),  # docs, f32 freqs
         ((DOC_PAD,), "bool"), ((1, DOC_PAD), "uint8"), ((1, 256), "float32"),
-        ((E,), "int32"), ((E,), "int32"), ((E,), "float32"), ((E,), "int32"),
-        ((E,), "int32"), ((E,), "int32"),
-        ((Q,), "int32"), ((Q,), "int32"), ((Q, 5), "float32"))
-    fn = jax.jit(functools.partial(
-        _score_batch_impl, n_queries=Q, k=128, doc_pad=DOC_PAD, simple=True))
+        # the launch's two operand planes (TermBatch.tri / .qplane)
+        ((6, E), "int32"), ((Q, 2 + 5), "int32"))
+    fn = _get_compiled(Q, 128, DOC_PAD, True)  # the launch site's own program
     with compile_tag("dense"):
         compiled = fn.lower(*args).compile()
     mem = compiled.memory_analysis()

@@ -272,16 +272,15 @@ def test_every_launch_site_lowers_under_its_own_name():
     nb, dpad, m, q, f = 16, 256, 32, 2, 1
     dense = (s((nb, 128), jnp.int32), s((nb, 128), jnp.float32),
              s((dpad,), jnp.bool_), s((f, dpad), jnp.uint8),
-             s((f, 256), jnp.float32), s((m,), jnp.int32), s((m,), jnp.int32),
-             s((m,), jnp.float32), s((m,), jnp.int32), s((m,), jnp.int32),
-             s((m,), jnp.int32), s((q,), jnp.int32), s((q,), jnp.int32),
-             s((q, 2), jnp.float32))
+             s((f, 256), jnp.float32),
+             # the launch's two operand planes (TermBatch.tri / .qplane)
+             s((6, m), jnp.int32), s((q, 2 + 2), jnp.int32))
     scalar = s((), jnp.float32)
     sparse = (s((nb, 128), jnp.int32), s((nb, 128), jnp.uint8),
               s((nb, 128), jnp.uint8), s((f, 256), jnp.float32),
-              s((f,), jnp.int32), s((8, 8), jnp.int32), s((8, 8), jnp.float32),
-              s((8, 8), jnp.bool_), s((8, 8), jnp.int32), s((8, 8), jnp.int32),
-              s((8,), jnp.int32), s((8,), jnp.int32), s((8, 2), jnp.float32))
+              s((f,), jnp.int32),
+              # SparseBatch.slots / .qplane
+              s((5, 8, 8), jnp.int32), s((8, 2 + 2), jnp.int32))
     no_aggs = (s((0, 5, dpad), jnp.float32), (), s((q, dpad), jnp.bool_))
     term = s((4,), jnp.int32)
     plane = tuple(s((4, 128), d) for d in (jnp.int32, jnp.uint8, jnp.uint8))

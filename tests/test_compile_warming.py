@@ -228,6 +228,34 @@ class TestRegistryWarm:
         assert rep.compiles == 0
         assert float(jnp.max(y)) == 7.0  # real math, not a stub
 
+    def test_manifest_of_another_operand_list_replays_nothing(
+            self, registry_guard, tmp_path):
+        """A manifest written before the launch sites' operand lists changed
+        holds argspecs the programs no longer take: its specs are dropped at
+        load, its ladders kept."""
+        import json as _json
+
+        import numpy as np
+
+        REGISTRY.reset()
+        LADDERS.reset()
+        REGISTRY.record_launch("test.site", "dense", (2, 16),
+                               [np.zeros((2, 64), np.float32)])
+        LADDERS.ladder("terms").load_json({"rungs": [24]})
+        path = tmp_path / MANIFEST_NAME
+        REGISTRY.save_manifest(str(path))
+        payload = _json.loads(path.read_text())
+        assert payload["version"] == 2 and len(payload["specs"]) == 1
+        REGISTRY.reset()
+        assert REGISTRY.load_manifest(str(path)) == 1
+        payload["version"] = 1
+        path.write_text(_json.dumps(payload))
+        REGISTRY.reset()
+        LADDERS.reset()
+        assert REGISTRY.load_manifest(str(path)) == 0
+        assert REGISTRY.pending_count() == 0
+        assert LADDERS.bucket("terms", 17, 1) == 24
+
     def test_warm_failure_trips_compile_circuit_off_path(self, registry_guard):
         import numpy as np
 
