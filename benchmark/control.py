@@ -20,7 +20,7 @@ import numpy as np
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmark.harness import registry  # noqa: E402
-from benchmark.harness.cell import Compared, Pool, say  # noqa: E402
+from benchmark.harness.cell import Compared, Pool, say, search_path  # noqa: E402
 from benchmark.harness.reference import Reference  # noqa: E402
 
 
@@ -51,7 +51,7 @@ def read(workload: str, seed: int, docs: int | None, precision: str) -> dict:
     sim = config["similarity"]
     ref = Reference(corpus, sim["k1"], sim["b"])
     low = Reference(corpus, sim["k1"], sim["b"], precision=precision)
-    pool = Pool(mix, ref, settings["index"])
+    pool = Pool(mix, ref, search_path(settings["index"], config))
     picks = np.random.default_rng(seed).choice(
         len(pool.queries), settings["sample"], replace=False)
     limits = dict(settings["limits"], rel_dev=config["guarantees"]["score_rel_tol"])

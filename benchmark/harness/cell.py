@@ -10,6 +10,7 @@ import shutil
 import sys
 import tempfile
 import time
+import urllib.parse
 
 import numpy as np
 
@@ -23,11 +24,19 @@ def say(obj: dict) -> None:
     print(json.dumps(obj), flush=True)
 
 
+def search_path(index: str, config: dict) -> str:
+    """The path of every `_search` a run sends for this configuration: its
+    `search.params`, where it has any, as the URL's query string."""
+    params = (config.get("search") or {}).get("params")
+    path = f"/{index}/_search"
+    return path + "?" + urllib.parse.urlencode(params) if params else path
+
+
 class Pool:
     """The cell's searches: plans from the mix's own generator (the same shapes for
     every seed), words from the seeded corpus."""
 
-    def __init__(self, mix: dict, ref: Reference, index: str):
+    def __init__(self, mix: dict, ref: Reference, path: str):
         plan_rng = np.random.default_rng(mix["plan_seed"])
         weights = np.array([f["weight"] for f in mix["families"]], np.float64)
         counts = np.floor(weights / weights.sum() * mix["pool"]).astype(int)
@@ -40,7 +49,7 @@ class Pool:
                 self.queries.append(q)
                 self.expected.append(mod.expected)
         self.bodies = [json.dumps(q["body"]).encode() for q in self.queries]
-        self.path = f"/{index}/_search"
+        self.path = path
 
     def compare(self, ref: Reference, i: int, resp: dict, tol: float) -> dict:
         q = self.queries[i]
@@ -90,8 +99,8 @@ class Run:
         self.index = self.settings["index"]
         self.type = self.settings["doc_type"]
         self.assume_chip = assume_chip
-        self.rehearsal = not assume_chip and \
-            os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
+        self.on_cpu = os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
+        self.rehearsal = not assume_chip and self.on_cpu
         if args.docs is not None and not self.rehearsal and not assume_chip:
             raise BenchFailure("--docs is for the CPU rehearsal (JAX_PLATFORMS=cpu): "
                                "a run at another size is not the cell")
@@ -103,6 +112,7 @@ class Run:
         self.profiled_spans: list = []
         self.obs = readers.Observations(self.index)
         self.problems: list = []
+        self.compared: dict = {}  # every number compared: name -> [value, limit]
         self.hbm_seen: list = []
         self.order_rng = np.random.default_rng(args.seed)
         self.plan = None
@@ -113,12 +123,26 @@ class Run:
     def line(self, obj: dict) -> None:
         say({**obj, **self.tag})
 
+    def compare_line(self, short: str, sample: str, got: Compared, **more) -> None:
+        """One sample's numbers: a `compare` line now, and under `short` in the
+        result's last key and the last lines of standard error."""
+        line = got.line(sample)
+        for k, v in line["numbers"].items():
+            self.compared[f"{short}.{k}"] = [v["value"], v["limit"]]
+        self.line({**line, **more})
+
     # -- set-up -----------------------------------------------------------------
     def start(self) -> None:
         root = os.path.join(registry.CHECKOUT, self.settings["run_directory"])
         os.makedirs(root, exist_ok=True)
         self.run_dir = tempfile.mkdtemp(prefix="run_", dir=root)
-        self.server = Server(registry.CHECKOUT, self.run_dir, self.server_env)
+        env = dict(self.server_env or {})
+        if self.on_cpu and self.cell["chips"] > 1:
+            # on the CPU a cell of several chips gets as many virtual devices
+            flags = env.get("XLA_FLAGS", os.environ.get("XLA_FLAGS", ""))
+            env["XLA_FLAGS"] = (flags + " --xla_force_host_platform_device_count="
+                                f"{self.cell['chips']}").strip()
+        self.server = Server(registry.CHECKOUT, self.run_dir, env)
         self.http = Client(self.server)
         self.line({"phase": "sizes", "workload": self.cell["name"],
                    "config": self.cell["config"], "traffic": self.cell["traffic"],
@@ -204,7 +228,7 @@ class Run:
         t0 = time.perf_counter()
         sim = self.config["similarity"]
         self.ref = Reference(self.corpus, sim["k1"], sim["b"])
-        self.pool = Pool(self.mix, self.ref, self.index)
+        self.pool = Pool(self.mix, self.ref, search_path(self.index, self.config))
         self.reference_s += time.perf_counter() - t0
         self.line({"phase": "reference", "pool": len(self.pool.queries),
                    "seconds": round(self.reference_s, 3), "counted_in_setup": False})
@@ -326,10 +350,24 @@ class Run:
                    "reference_s_not_in_setup": round(self.reference_s, 3)})
         if not self.attempted:
             raise BenchFailure("the window sent no search")
-        for path in self.settings["must_not_rise"]:
+        for path in self.must_not_rise():
             rose = self.obs.delta(path)
+            self.compared[f"rose.{path}"] = [rose, 0]
             if rose is None or rose > 0:
                 self.problems.append(f"{path} rose by {rose} during the window")
+
+    def must_not_rise(self) -> list:
+        """The counters that may not rise over the window: those of every cell and
+        those the configuration adds for its own."""
+        return self.settings["must_not_rise"] + self.config.get("must_not_rise", [])
+
+    def reductions(self) -> list:
+        """The reductions of a traced run: those `settings.json` lists, and every one
+        that a per-layer metric of the cell names."""
+        named = [d.get("reduction") for _m, d in registry.metrics_of(
+            self.bench, self.cell["name"], "per_layer", "layer_metrics")]
+        return list(dict.fromkeys(
+            self.settings["trace"]["reductions"] + [n for n in named if n]))
 
     def profile_the_end(self, t0: float) -> None:
         """One profiler window over the window's last seconds, opened and closed over
@@ -389,7 +427,7 @@ class Run:
                  "host_spans": host_spans, "requests": (sent + shift, done + shift)}
         in_profile = ok & (done + shift >= 0) & (done + shift <= window_s)
         self.obs.facts["profile.searches"] = int(in_profile.sum())
-        for name in tr["reductions"]:
+        for name in self.reductions():
             self.obs.reduced[name] = registry.module("reductions", name).reduce(trace)
         self.line({"phase": "trace", "file_bytes": os.path.getsize(traces[0]),
                    "planes": {k: {n: len(l["names"]) for n, l in v["lines"].items()}
@@ -415,7 +453,7 @@ class Run:
         pre = Compared(self.limits)
         for i, resp in zip(self.pre_sample, self.pre_answers):
             pre.add(self.pool.compare(self.ref, i, resp, tol))
-        self.line(pre.line("before the window"))
+        self.compare_line("before", "before the window", pre)
         res = self.obs.window
         done_ok = [j for j, ok in enumerate(res.ok) if ok]
         n = min(self.settings["sample"], len(done_ok))
@@ -427,7 +465,7 @@ class Run:
             for j in sorted(picks):
                 win.add(self.pool.compare(self.ref, res.query[j],
                                           as_response(res.answer[j]), tol))
-        self.line(win.line("the window's own responses"))
+        self.compare_line("window", "the window's own responses", win)
         for name, c in (("before the window", pre), ("the window", win)):
             if not c.passed:
                 self.problems.append(f"a response of the sample {name} differs from "
@@ -458,14 +496,15 @@ class Run:
         found = Compared(self.limits)
         for j in range(n):
             term = self.corpus.n_vocab + j
-            resp = self.http.call("POST", f"/{self.index}/_search",
+            resp = self.http.call("POST", self.pool.path,
                                   {"query": {"match": {field: word(term)}}, "size": 10})
             scores, matched = ref.score_all([term], False)
             found.add(check_hits(ref, scores, matched, 10, resp, self.limits["rel_dev"]))
             if [h["_id"] for h in resp["hits"]["hits"]] != [str(n0 + j)]:
                 bad.append(f"write {n0 + j} not found by _search after the refresh")
-        self.line({**found.line("late writes"), "documents": n,
-                   "read_back": "GET at once, _search after _refresh", "problems": bad})
+        self.compare_line("late_writes", "late writes", found, documents=n,
+                          read_back="GET at once, _search after _refresh", problems=bad)
+        self.compared["late_writes.not_read_back"] = [len(bad), 0]
         if bad or not found.passed:
             self.problems.append(f"late writes: {bad or found.numbers}")
 
@@ -490,8 +529,8 @@ class Run:
             out["metrics"] = self.metrics("per_layer", "layer_metrics")
             busy = self.obs.reduced.get(tr["busy"]) or {}
             if busy:
-                device["busy_s"] = busy["busy_s"]
-                device["window_s"] = busy["window_s"]
+                device.update({k: busy[k] for k in ("busy_s", "window_s",
+                                                    "busy_s_by_chip") if k in busy})
             elif not self.rehearsal and not self.assume_chip:
                 self.problems.append("no operation ran on the device in the traced "
                                      "window")
@@ -505,6 +544,7 @@ class Run:
         else:
             out["metrics"] = self.metrics("end_to_end", "end_to_end")
         out["device"] = device
+        out["compared"] = self.compared  # last: every number compared, beside its limit
         return out
 
 
@@ -536,6 +576,8 @@ def run(args, t_process: float, **test_options) -> int:
                    "problems": run_.problems,
                    "seconds": round(time.perf_counter() - t_process, 3)})
         say(result)
+        for name, (value, limit) in run_.compared.items():
+            sys.stderr.write(f"compared {name}: {value} (limit {limit})\n")
         if run_.rehearsal:
             return 2
         return 0 if result["correct"] else 1

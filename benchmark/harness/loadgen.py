@@ -96,7 +96,7 @@ def run_load(port: int, path: str, bodies: list, order: np.ndarray, seconds: flo
     lock = threading.Lock()
     state = {"next": 0}
     per_worker: list = [[] for _ in range(clients)]
-    traced_path = path + "?trace=true"
+    traced_path = path + ("&" if "?" in path else "?") + "trace=true"
     t0 = time.perf_counter() + 0.05
     t_end = t0 + seconds
     n_order = len(order)

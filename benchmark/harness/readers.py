@@ -6,6 +6,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from . import registry
 from .loadgen import REQUEST_TIMEOUT_S
 
 
@@ -89,9 +90,17 @@ def counter_delta(d: dict, obs):
     return None if v is None else v * d.get("scale", 1.0)
 
 
+def _paths(d: dict, key: str) -> list:
+    """A definition's list of counter paths under `key`; where it gives a metric's
+    name in the list's place, that metric's list, so that two shares of one sum keep
+    it in one file."""
+    v = d[key]
+    return registry.layer_metric(v)[key] if isinstance(v, str) else v
+
+
 def counter_ratio(d: dict, obs):
-    num = [obs.delta(p) for p in d["numerator"]]
-    den = [obs.delta(p) for p in d["denominator"]]
+    num = [obs.delta(p) for p in _paths(d, "numerator")]
+    den = [obs.delta(p) for p in _paths(d, "denominator")]
     if any(v is None for v in num + den) or not sum(den):
         return None
     return sum(num) / sum(den) * d.get("scale", 1.0)

@@ -54,6 +54,11 @@ def peaks(device_kind: str) -> dict:
     return table["devices"][device_kind]
 
 
+def layer_metric(name: str) -> dict:
+    """The definition file of the per-layer metric `name`."""
+    return _json(BENCH_DIR, "layer_metrics", name + ".json")
+
+
 def metrics_of(bench: dict, cell_name: str, group: str, directory: str) -> list:
     """The metrics of `group` (`end_to_end` or `per_layer`) that this cell reports,
     each as (entry in BENCHMARK.json, its definition file)."""

@@ -1,5 +1,7 @@
 """Busy and idle time of the device over the traced window: busy is the union of the
-intervals in which an operation ran (the `XLA Ops` line), averaged over the chips."""
+intervals in which an operation ran (the `XLA Ops` line), averaged over the chips; the
+seconds of each chip are kept beside the mean, and the distance between the busiest
+and the idlest chip as a share of the window."""
 
 from __future__ import annotations
 
@@ -23,7 +25,7 @@ def merged(start_ns: np.ndarray, dur_ns: np.ndarray):
 
 def reduce(trace: dict) -> dict:
     busy = []
-    for plane in trace["planes"].values():
+    for _name, plane in sorted(trace["planes"].items()):
         line = plane["lines"].get(OPS_LINE)
         if line is None:
             continue
@@ -34,4 +36,6 @@ def reduce(trace: dict) -> dict:
     window_s = trace["window_s"]
     busy_s = float(np.mean(busy))
     return {"busy_s": busy_s, "window_s": window_s,
-            "idle_share_pct": 100.0 * (1.0 - busy_s / window_s)}
+            "idle_share_pct": 100.0 * (1.0 - busy_s / window_s),
+            "busy_s_by_chip": busy,
+            "busy_spread_pct": 100.0 * (max(busy) - min(busy)) / window_s}

@@ -1,6 +1,9 @@
-"""The harness finds a configuration, a mix, a query family and a layer metric that
-were added as files and entries alone, and names none of them in its code."""
+"""The harness finds a configuration (with URL parameters for its searches and
+counters of its own that may not rise), a mix, a query family, a layer metric and a
+trace reduction that were added as files and entries alone, and names none of them in
+its code."""
 
+import argparse
 import json
 import os
 import re
@@ -26,14 +29,18 @@ def copy(tmp_path, monkeypatch):
 
 def test_pieces_added_as_files_alone_are_found(copy):
     bench = json.loads((copy / "BENCHMARK.json").read_text())
-    before = {p: (copy / "benchmark" / p).read_bytes()
-              for p in ("run.py", "harness/cell.py", "harness/registry.py",
-                        "harness/readers.py")}
+    general = ["run.py", "settings.json"] + [
+        "harness/" + f for f in os.listdir(copy / "benchmark/harness")
+        if f.endswith(".py")]
+    before = {p: (copy / "benchmark" / p).read_bytes() for p in general}
     # a later PR: one configuration, one mix with a new family, one metric, one cell
     config = json.loads((copy / "benchmark/configs" /
                          (bench["configs"][0]["name"] + ".json")).read_text())
     config["name"] = "tiny-logs"
     config["documents"] = 300
+    config["search"] = {"params": {"search_type": "dfs_query_then_fetch",
+                                   "preference": "_local"}}
+    config["must_not_rise"] = ["search_serving.mesh_fallbacks"]
     (copy / "benchmark/configs/tiny-logs.json").write_text(json.dumps(config))
     (copy / "benchmark/queries/one_term.py").write_text(
         "from benchmark.harness.reference import word\n"
@@ -58,6 +65,20 @@ def test_pieces_added_as_files_alone_are_found(copy):
         "layer": "batcher", "reader": "counter_ratio", "scale": 100.0,
         "numerator": ["search.batcher.full_flushes"],
         "denominator": ["search.batcher.launches"]}))
+    (copy / "benchmark/reductions/longest_op.py").write_text(
+        "def reduce(trace):\n"
+        "    longest = [float(line['dur_ns'].max()) for p in trace['planes'].values()\n"
+        "               for n, line in p['lines'].items() if n == 'XLA Ops']\n"
+        "    return {'longest_us': max(longest) / 1e3} if longest else {}\n")
+    (copy / "benchmark/layer_metrics/longest_op_us.json").write_text(json.dumps({
+        "layer": "kernels", "reader": "reduction", "reduction": "longest_op",
+        "field": "longest_us"}))
+    bench["per_layer"].append({"name": "longest_op_us", "unit": "us",
+                               "better": "lower", "source": "device_trace",
+                               "layer": "kernels", "moves": "searches_per_s",
+                               "workloads": ["logs.single"]})
+    bench["end_to_end"][2]["workloads"].append("logs.single")
+    assert bench["end_to_end"][2]["name"] == "searches_per_s"
     bench["configs"].append({"name": "tiny-logs", "source": "a test",
                              "file": "benchmark/configs/tiny-logs.json",
                              "reduced": [], "why": "a test"})
@@ -69,19 +90,34 @@ def test_pieces_added_as_files_alone_are_found(copy):
                                "workloads": ["logs.single"]})
     (copy / "BENCHMARK.json").write_text(json.dumps(bench))
 
-    from benchmark.harness.cell import Pool
-    from benchmark.harness.reference import Reference
+    from benchmark.harness.cell import Run
 
     bench = registry.benchmark()
-    cell = registry.cell(bench, "logs.single")
-    config = registry.config(bench, cell["config"])
-    assert config["documents"] == 300
-    mix = registry.mix(cell["traffic"])
-    gen = registry.module("corpora", config["corpus"]["generator"])
-    corpus = gen.generate(config["corpus"]["params"], 5, config["documents"])
-    ref = Reference(corpus, 1.2, 0.75)
-    pool = Pool(mix, ref, "idx")
+    run = Run(argparse.Namespace(workload="logs.single", seed=5, seconds=1.0, trace=1,
+                                 docs=None), 0.0, assume_chip=True)
+    assert run.config["documents"] == 300 and run.n_docs == 300
+    run.make_corpus()
+    run.make_reference()
+    pool = run.pool
     assert len(pool.queries) == 32 and pool.queries[0]["size"] == 5
+    # every search of the run goes to the configuration's path
+    assert pool.path == \
+        "/bench/_search?search_type=dfs_query_then_fetch&preference=_local"
+    shared = registry.settings()
+    assert run.must_not_rise() == shared["must_not_rise"] + \
+        ["search_serving.mesh_fallbacks"]
+    # the reduction that the new metric names runs beside those of settings.json
+    assert run.reductions() == shared["trace"]["reductions"] + ["longest_op"]
+    line = {"names": ["a", "b"], "start_ns": np.array([0.0, 9e3]),
+            "dur_ns": np.array([2e3, 5e3])}
+    trace = {"planes": {"/device:TPU:0": {"lines": {"XLA Ops": line}}}, "window_s": 1.0}
+    traced = readers.Observations("idx")
+    for name in run.reductions():
+        traced.reduced[name] = registry.module("reductions", name).reduce(trace)
+    definition = [d for m, d in registry.metrics_of(
+        bench, "logs.single", "per_layer", "layer_metrics")
+        if m["name"] == "longest_op_us"][0]
+    assert readers.read(definition, traced) == pytest.approx(5.0)
     names = [m["name"] for m, _ in
              registry.metrics_of(bench, "logs.single", "per_layer", "layer_metrics")]
     assert "full_flush_share" in names and "gen_late_p95_ms" not in names
@@ -94,6 +130,23 @@ def test_pieces_added_as_files_alone_are_found(copy):
     assert readers.read(definition, obs) == pytest.approx(25.0)
     for p, content in before.items():
         assert (copy / "benchmark" / p).read_bytes() == content
+
+
+def test_a_cell_without_the_new_keys_is_held_to_what_all_cells_share():
+    from benchmark.harness.cell import Run
+
+    bench = registry.benchmark()
+    shared = registry.settings()
+    for w in bench["workloads"]:
+        config = registry.config(bench, w["config"])
+        run = Run(argparse.Namespace(workload=w["name"], seed=5, seconds=1.0, trace=1,
+                                     docs=None), 0.0, assume_chip=True)
+        assert run.must_not_rise() == \
+            shared["must_not_rise"] + config.get("must_not_rise", [])
+        assert run.reductions()[:3] == shared["trace"]["reductions"]
+        if "search" not in config:
+            assert "must_not_rise" not in config
+            assert run.must_not_rise() == shared["must_not_rise"]
 
 
 def test_harness_names_no_configuration_mix_family_or_metric():

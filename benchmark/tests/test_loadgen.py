@@ -1,11 +1,16 @@
-"""The open-loop schedule (due times from the seed) and the lateness and latency
-arithmetic of the readers."""
+"""The open-loop schedule (due times from the seed), the lateness and latency
+arithmetic of the readers, and the request lines the generator sends."""
+
+import http.server
+import json
+import threading
 
 import numpy as np
 import pytest
 
-from benchmark.harness import readers
-from benchmark.harness.loadgen import REQUEST_TIMEOUT_S, LoadResult, schedule
+from benchmark.harness import readers, registry
+from benchmark.harness.cell import search_path
+from benchmark.harness.loadgen import REQUEST_TIMEOUT_S, LoadResult, run_load, schedule
 
 
 def _schedule(seed, rate=200.0, seconds=5.0, plan=99):
@@ -87,3 +92,73 @@ def test_counters_read_as_deltas_and_missing_ones_give_nothing():
     assert readers.read({"reader": "counter_value", "path": "d.{index}.ms",
                          "scale": 0.001}, obs) == pytest.approx(1.5)
     assert readers.read({"reader": "counter_delta", "path": "a.absent"}, obs) is None
+
+
+def test_two_shares_of_one_sum_keep_its_list_in_one_file():
+    """`mesh_served_share` names `device_served_share` where its denominator would
+    stand: a new outcome of `search_serving` is added in that one file, and the two
+    shares go on summing to what the host left."""
+    served = registry.layer_metric("device_served_share")
+    mesh = registry.layer_metric("mesh_served_share")
+    assert mesh["denominator"] == "device_served_share"
+    assert set(mesh["numerator"]) <= set(served["denominator"])
+    obs = readers.Observations("idx")
+    paths = [p.split(".", 1)[1] for p in served["denominator"]]
+    obs.stats_before = {"search_serving": {k: 0 for k in paths}}
+    obs.stats_after = {"search_serving": {k: 1 for k in paths}}
+    obs.stats_after["search_serving"]["mesh_spmd"] = 1 + len(paths)
+    both = readers.read(mesh, obs) + readers.read(served, obs)
+    assert both == pytest.approx(100.0 * (2 * len(paths) - 1) / (2 * len(paths)))
+    assert readers.read(mesh, obs) == pytest.approx(100.0 * (len(paths) + 1)
+                                                    / (2 * len(paths)))
+
+
+ANSWER = json.dumps({"_shards": {"total": 1, "successful": 1, "failed": 0},
+                     "timed_out": False, "hits": {"total": 0, "hits": []}}).encode()
+
+
+@pytest.fixture()
+def request_lines():
+    """A server that answers every search with no hit and keeps the request lines."""
+    lines = []
+
+    class Handler(http.server.BaseHTTPRequestHandler):
+        protocol_version = "HTTP/1.1"
+
+        def do_POST(self):
+            self.rfile.read(int(self.headers["Content-Length"]))
+            lines.append(self.requestline)
+            self.send_response(200)
+            self.send_header("Content-Length", str(len(ANSWER)))
+            self.end_headers()
+            self.wfile.write(ANSWER)
+
+        def log_message(self, *args):
+            pass
+
+    server = http.server.ThreadingHTTPServer(("127.0.0.1", 0), Handler)
+    thread = threading.Thread(target=server.serve_forever, daemon=True)
+    thread.start()
+    yield server.server_address[1], lines
+    server.shutdown()
+    server.server_close()
+    thread.join()
+
+
+@pytest.mark.parametrize("config, plain, sampled", [
+    ({}, "/bench/_search", "/bench/_search?trace=true"),
+    ({"search": {"params": {"search_type": "dfs_query_then_fetch"}}},
+     "/bench/_search?search_type=dfs_query_then_fetch",
+     "/bench/_search?search_type=dfs_query_then_fetch&trace=true")])
+def test_the_request_line_with_and_without_url_parameters(request_lines, config, plain,
+                                                          sampled):
+    """Without a `search` block the request line is the one of before the block
+    existed, byte for byte; a sampled search joins `trace=true` to what is there."""
+    port, lines = request_lines
+    path = search_path("bench", config)
+    assert path == plain
+    res = run_load(port, path, [b"{}"], np.array([0]), 5.0, 1, None, trace_every=3,
+                   count=6)
+    assert len(res.done) == 6 and all(res.ok)
+    assert lines == [f"POST {sampled if i % 3 == 0 else plain} HTTP/1.1"
+                     for i in range(6)]

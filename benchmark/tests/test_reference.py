@@ -113,8 +113,8 @@ def test_check_hits_passes_its_own_answer_and_catches_each_fault(corpus):
 
 
 @pytest.mark.parametrize("seed", [11, 12, 13])
-@pytest.mark.parametrize("workload", ["passage.steady", "wiki.filtered",
-                                      "passage.saturated"])
+@pytest.mark.parametrize("workload", [w["name"] for w in
+                                      registry.benchmark()["workloads"]])
 def test_the_control_fails(workload, seed):
     """The reference in bfloat16, put in the program's place, is not correct; in
     float32 (the control of the control) it is."""

@@ -2,7 +2,10 @@
 chip skipped: a sound server comes out `correct: true`, and a server whose timed path
 is broken underneath (every BM25 weight one part in a thousand off, where the score is
 produced) comes out `correct: false`. The CPU rehearsal as a user runs it never says
-true. Each case starts a server: about a minute."""
+true. The sound runs' server writes down every request it is handed: each `_search`
+carries the URL parameters its configuration states (`search.params`), late writes'
+searches included, and no other. A cell of several chips gets as many virtual CPU
+devices. Each case starts a server: about a minute."""
 
 import argparse
 import json
@@ -34,8 +37,12 @@ def _paths(*extra):
 
 @pytest.mark.parametrize("workload", [w["name"] for w in
                                       registry.benchmark()["workloads"]])
-def test_a_sound_run_is_correct_and_reports_its_metrics(capsys, monkeypatch, workload):
-    rc, lines = _run(capsys, monkeypatch, workload, assume_chip=True)
+def test_a_sound_run_is_correct_and_reports_its_metrics(capsys, monkeypatch, tmp_path,
+                                                        workload):
+    requests = tmp_path / "requests.jsonl"
+    rc, lines = _run(capsys, monkeypatch, workload, assume_chip=True, server_env={
+        "PYTHONPATH": _paths(os.path.join(HERE, "logging_server")),
+        "BENCH_TEST_REQUESTS": str(requests)})
     result = lines[-1]
     assert rc == 0 and result["correct"] is True, lines[-3:]
     assert set(result) >= {"correct", "attempted", "failed", "metrics", "device"}
@@ -48,6 +55,25 @@ def test_a_sound_run_is_correct_and_reports_its_metrics(capsys, monkeypatch, wor
     assert len(compared) == 3
     for l in compared:
         assert all(n["value"] <= n["limit"] for n in l["numbers"].values())
+    # every number compared, beside its limit, comes last in the result's line
+    assert list(result)[-1] == "compared"
+    assert all(value <= limit for value, limit in result["compared"].values())
+    assert {"rose." + p for p in registry.settings()["must_not_rise"]} | \
+        {"before.rel_dev", "window.rel_dev", "late_writes.rel_dev"} <= \
+        set(result["compared"])
+    # what the server was handed: every search with the configuration's parameters
+    cell_ = registry.cell(bench, workload)
+    config = registry.config(bench, cell_["config"])
+    stated = (config.get("search") or {}).get("params") or {}
+    searches = [r for r in map(json.loads, requests.read_text().splitlines())
+                if r["path"].endswith("/_search")]
+    shared = registry.settings()
+    # the first answers, the warm-up (a rehearsal at least), the window, late writes
+    assert len(searches) >= shared["sample"] + 2 * result["attempted"] * 0.8 + \
+        shared["late_writes"]
+    assert all(r["method"] == "POST" and r["params"] == stated for r in searches)
+    device = [l for l in lines if l.get("phase") == "device"][0]
+    assert device["count"] == cell_["chips"] or cell_["chips"] == 1
 
 
 def test_a_broken_timed_path_is_not_correct(capsys, monkeypatch):
@@ -59,6 +85,8 @@ def test_a_broken_timed_path_is_not_correct(capsys, monkeypatch):
     window = [l for l in lines if l.get("phase") == "compare"
               and l["sample"].startswith("the window")][0]
     assert window["numbers"]["rel_dev"]["value"] > window["numbers"]["rel_dev"]["limit"]
+    value, limit = result["compared"]["window.rel_dev"]
+    assert value == window["numbers"]["rel_dev"]["value"] and value > limit == 1e-5
 
 
 def test_the_rehearsal_never_says_correct(capsys, monkeypatch):
