@@ -5,10 +5,11 @@ one the configuration states (bfloat16 for float32), put in the program's place.
 
 For each seed it builds the cell's corpus and pool at the cell's own size, lets the
 lower-precision reference answer the run's sample of searches (its own top-k, ids and
-scores), and compares those answers with the float32 reference exactly as a run
-compares the program's. The control has to fail a limit; PERF.md sets the smallest
-`rel_dev` it reads beside the largest that sound runs of the program give. numpy only:
-it needs no chip, and runs there to read the cell's own size.
+scores; where the query family states an `answer` of its own, that), and compares
+those answers with the float32 reference exactly as a run compares the program's. The
+control has to fail a limit; PERF.md sets the smallest `rel_dev` it reads beside the
+largest that sound runs of the program give. numpy only: it needs no chip, and runs
+there to read the cell's own size.
 """
 
 import argparse
@@ -24,22 +25,6 @@ from benchmark.harness.cell import Compared, Pool, say, search_path  # noqa: E40
 from benchmark.harness.reference import Reference  # noqa: E402
 
 
-def control_answers(low: Reference, pool: Pool, picks) -> list:
-    """What the lower precision would serve: its own ranking and scores."""
-    out = []
-    for i in picks:
-        q = pool.queries[i]
-        scores, matched = pool.expected[i](low, q)
-        total, ranked = low.top(scores, matched, q["size"])
-        top = ranked[: q["size"]]
-        out.append({"_shards": {"total": 1, "successful": 1, "failed": 0},
-                    "timed_out": False,
-                    "hits": {"total": total,
-                             "hits": [{"_id": str(int(d)), "_score": float(scores[d])}
-                                      for d in top]}})
-    return out
-
-
 def read(workload: str, seed: int, docs: int | None, precision: str) -> dict:
     bench = registry.benchmark()
     cell = registry.cell(bench, workload)
@@ -51,14 +36,15 @@ def read(workload: str, seed: int, docs: int | None, precision: str) -> dict:
     sim = config["similarity"]
     ref = Reference(corpus, sim["k1"], sim["b"])
     low = Reference(corpus, sim["k1"], sim["b"], precision=precision)
-    pool = Pool(mix, ref, search_path(settings["index"], config))
+    limits = dict(settings["limits"], rel_dev=config["guarantees"]["score_rel_tol"])
+    pool = Pool(mix, ref, search_path(settings["index"], config), limits)
     picks = np.random.default_rng(seed).choice(
         len(pool.queries), settings["sample"], replace=False)
-    limits = dict(settings["limits"], rel_dev=config["guarantees"]["score_rel_tol"])
-    got = Compared(limits)
+    got = Compared(pool.limits)
     devs = []
-    for i, resp in zip(picks, control_answers(low, pool, picks)):
-        numbers = pool.compare(ref, int(i), resp, limits["rel_dev"])
+    for i in picks:
+        # what the lower precision would serve: its own ranking and scores
+        numbers = pool.compare(ref, int(i), pool.answer(low, int(i)), limits["rel_dev"])
         devs.append(numbers["rel_dev"])
         got.add(numbers)
     return {**got.line(f"control: reference in {precision}"), "workload": workload,

@@ -16,7 +16,7 @@ import numpy as np
 
 from . import readers, registry, xplane
 from .loadgen import as_response, run_load, schedule
-from .reference import Reference, check_hits, word
+from .reference import Reference, check_hits, hits_answer, word
 from .server import BenchFailure, Client, Server
 
 
@@ -34,27 +34,58 @@ def search_path(index: str, config: dict) -> str:
 
 class Pool:
     """The cell's searches: plans from the mix's own generator (the same shapes for
-    every seed), words from the seeded corpus."""
+    every seed), words from the seeded corpus. A query family compares a response as
+    `check_hits` does unless it brings a `compare` of its own, with the `LIMITS` of the
+    numbers that returns; `KEEP` says what the window has to keep of its responses
+    beyond total, ids and scores, and `answer` what the reference itself would serve
+    (the control's answer) where that is more than hits."""
 
-    def __init__(self, mix: dict, ref: Reference, path: str):
+    def __init__(self, mix: dict, ref: Reference, path: str, base_limits: dict):
         plan_rng = np.random.default_rng(mix["plan_seed"])
         weights = np.array([f["weight"] for f in mix["families"]], np.float64)
         counts = np.floor(weights / weights.sum() * mix["pool"]).astype(int)
         counts[0] += mix["pool"] - counts.sum()
         self.queries = []
-        self.expected = []
+        self.family = []
+        self.limits = dict(base_limits)
         for fam, n in zip(mix["families"], counts):
             mod = registry.module("queries", fam["family"])
+            for name, limit in getattr(mod, "LIMITS", {}).items():
+                if self.limits.setdefault(name, limit) != limit:
+                    raise BenchFailure(
+                        f"query family {fam['family']!r} gives {name} the limit "
+                        f"{limit}; it has {self.limits[name]}")
+            first = len(self.queries)
             for q in mod.build(fam["params"], ref, mod.plan(fam["params"], plan_rng, n)):
                 self.queries.append(q)
-                self.expected.append(mod.expected)
+                self.family.append(mod)
+            if n:
+                # the family's numbers on the reference's own answer, now: a number
+                # with no limit stops the run here, not after the window
+                unnamed = set(self.compare(
+                    ref, first, self.answer(ref, first), 0.0)) - set(self.limits)
+                if unnamed:
+                    raise BenchFailure(
+                        f"query family {fam['family']!r} compares {sorted(unnamed)} "
+                        "and gives no limit for it (LIMITS)")
         self.bodies = [json.dumps(q["body"]).encode() for q in self.queries]
         self.path = path
+        keeps = [getattr(mod, "KEEP", None) for mod in self.family]
+        self.keeps = keeps if any(keeps) else None
 
     def compare(self, ref: Reference, i: int, resp: dict, tol: float) -> dict:
-        q = self.queries[i]
-        scores, matched = self.expected[i](ref, q)
+        q, mod = self.queries[i], self.family[i]
+        if hasattr(mod, "compare"):
+            return mod.compare(ref, q, resp, tol)
+        scores, matched = mod.expected(ref, q)
         return check_hits(ref, scores, matched, q["size"], resp, tol)
+
+    def answer(self, ref: Reference, i: int) -> dict:
+        q, mod = self.queries[i], self.family[i]
+        if hasattr(mod, "answer"):
+            return mod.answer(ref, q)
+        scores, matched = mod.expected(ref, q)
+        return hits_answer(ref, scores, matched, q["size"])
 
 
 class Compared:
@@ -228,7 +259,8 @@ class Run:
         t0 = time.perf_counter()
         sim = self.config["similarity"]
         self.ref = Reference(self.corpus, sim["k1"], sim["b"])
-        self.pool = Pool(self.mix, self.ref, search_path(self.index, self.config))
+        self.pool = Pool(self.mix, self.ref, search_path(self.index, self.config),
+                         self.limits)
         self.reference_s += time.perf_counter() - t0
         self.line({"phase": "reference", "pool": len(self.pool.queries),
                    "seconds": round(self.reference_s, 3), "counted_in_setup": False})
@@ -265,7 +297,8 @@ class Run:
         order, due = self.plan
         return run_load(self.server.port, self.pool.path, self.pool.bodies, order,
                         self.args.seconds, self.mix["clients"], due,
-                        self.mix["keep_alive"], trace_every, meanwhile)
+                        self.mix["keep_alive"], trace_every, meanwhile,
+                        keeps=self.pool.keeps)
 
     def warm_up(self) -> None:
         """Every search of the pool once, from a few closed-loop clients (each
@@ -450,14 +483,14 @@ class Run:
     # -- after the window -------------------------------------------------------
     def compare(self) -> None:
         tol = self.limits["rel_dev"]
-        pre = Compared(self.limits)
+        pre = Compared(self.pool.limits)
         for i, resp in zip(self.pre_sample, self.pre_answers):
             pre.add(self.pool.compare(self.ref, i, resp, tol))
         self.compare_line("before", "before the window", pre)
         res = self.obs.window
         done_ok = [j for j, ok in enumerate(res.ok) if ok]
         n = min(self.settings["sample"], len(done_ok))
-        win = Compared(self.limits)
+        win = Compared(self.pool.limits)
         if n:
             picks = set(int(j) for j in self.order_rng.choice(done_ok, n, False))
             # the longest search the window finished is always in the sample

@@ -41,7 +41,7 @@ class LoadResult:
         self.done: list = []
         self.query: list = []      # index into the pool
         self.ok: list = []         # answered whole: 2xx, no failed shard, not timed out
-        self.answer: list = []     # (total, ids, scores) or None
+        self.answer: list = []     # (total, ids, scores[, kept parts]) or None
         self.spans: list = []      # (sent, done, [(name, t0, t1), ...]) of sampled searches
         self.cpu_s = 0.0
         self.wall_s = 0.0
@@ -59,8 +59,11 @@ def _flatten(tree: dict, out: list) -> list:
     return out
 
 
-def _digest(status: int, body: bytes):
-    """(answered whole, compact answer, spans or None) of one response."""
+def _digest(status: int, body: bytes, keep: dict | None = None):
+    """(answered whole, compact answer, spans or None) of one response. `keep` is what
+    the search's query family asks the window to keep beyond total, ids and scores:
+    `{"response": [keys of the response], "hit": [keys of each hit]}`, kept as one
+    string of JSON (one object to hold, whatever the number of buckets or hits)."""
     if status >= 300:
         return False, None, None
     resp = json.loads(body)
@@ -70,27 +73,42 @@ def _digest(status: int, body: bytes):
     hits = resp["hits"]["hits"]
     answer = (resp["hits"]["total"], tuple(h["_id"] for h in hits),
               tuple(h["_score"] for h in hits))
+    if keep:
+        answer += (json.dumps(
+            {"response": {k: resp[k] for k in keep.get("response", ()) if k in resp},
+             "hit": {k: [h.get(k) for h in hits] for k in keep.get("hit", ())}},
+            separators=(",", ":")),)
     spans = _flatten(resp["trace"]["tree"], []) if "trace" in resp else None
     return whole, answer, spans
 
 
 def as_response(answer) -> dict:
-    """A compact answer in the shape the comparison reads."""
-    total, ids, scores = answer
-    return {"_shards": {"total": 1, "successful": 1, "failed": 0}, "timed_out": False,
-            "hits": {"total": total,
-                     "hits": [{"_id": i, "_score": s} for i, s in zip(ids, scores)]}}
+    """A compact answer in the shape the comparison reads, the kept parts back in
+    their places."""
+    total, ids, scores, *kept = answer
+    hits = [{"_id": i, "_score": s} for i, s in zip(ids, scores)]
+    resp = {"_shards": {"total": 1, "successful": 1, "failed": 0}, "timed_out": False,
+            "hits": {"total": total, "hits": hits}}
+    if kept:
+        parts = json.loads(kept[0])
+        resp.update(parts["response"])
+        for key, values in parts["hit"].items():
+            for hit, value in zip(hits, values):
+                hit[key] = value
+    return resp
 
 
 def run_load(port: int, path: str, bodies: list, order: np.ndarray, seconds: float,
              clients: int, due: np.ndarray | None, keep_alive: bool = True,
              trace_every: int = 0, meanwhile=None,
-             count: int | None = None) -> LoadResult:
+             count: int | None = None, keeps: list | None = None) -> LoadResult:
     """One window. `bodies` are the pool's encoded searches and `order` the sequence
     in which the pool is sent (cycled). With `due` (open loop) `clients` threads send
     on that schedule; without (closed loop) each of `clients` threads sends its next
     search as soon as its last one is answered, until `seconds` have passed or, with
-    `count`, until that many searches have been sent.
+    `count`, until that many searches have been sent. `keeps`, where a family of the
+    pool asks for it, holds for each search of the pool what `_digest` keeps of its
+    responses beyond total, ids and scores.
     `meanwhile(t0)` runs on the caller's thread while the load runs."""
     result = LoadResult(seconds)
     lock = threading.Lock()
@@ -129,7 +147,8 @@ def run_load(port: int, path: str, bodies: list, order: np.ndarray, seconds: flo
                 status, body = conn.request(
                     "POST", traced_path if traced else path, bodies[q])
                 t_done = time.perf_counter()
-                whole, answer, spans = _digest(status, body)
+                whole, answer, spans = _digest(
+                    status, body, keeps[q] if keeps is not None else None)
                 err = None if whole else f"status {status}: {body[:200]!r}"
             except (OSError, http.client.HTTPException, ValueError, KeyError) as e:
                 t_done = time.perf_counter()

@@ -1,6 +1,8 @@
 """The plain reference: a corpus as term ids, Lucene 4.x BM25 over it in numpy, and the
 comparison of one response's hits with it. A copy of `chip_smoke.py`'s `Corpus`,
-`Reference` and `check_hits`; it imports nothing of the program.
+`Reference` and `check_hits`; it imports nothing of the program. Beside them, for the
+query families whose answers are not scored hits: exact bucket counts of a matched set
+over a numeric column, and the ranking of a matched set by a column with its check.
 """
 
 from __future__ import annotations
@@ -149,6 +151,21 @@ class Reference:
         return len(cand), cand[np.lexsort((cand, -scores[cand]))]
 
 
+def _not_whole(resp: dict) -> bool:
+    sh = resp.get("_shards", {})
+    return bool(resp.get("timed_out") or sh.get("failed")
+                or sh.get("successful") != sh.get("total"))
+
+
+def _hit_ids(hits: list, matched: np.ndarray):
+    """(the hits' doc ids, -1 where an `_id` is no number; how many do not match)."""
+    got_ids = np.array([int(h["_id"]) if str(h["_id"]).isdigit() else -1
+                        for h in hits], np.int64)
+    ok = (got_ids >= 0) & (got_ids < len(matched))
+    ok[ok] = matched[got_ids[ok]]
+    return got_ids, int((~ok).sum())
+
+
 def check_hits(ref: Reference, scores: np.ndarray, matched: np.ndarray, size: int,
                resp: dict, tol_rel: float) -> dict:
     """One response against the reference. Returns the numbers compared, each of which
@@ -158,9 +175,7 @@ def check_hits(ref: Reference, scores: np.ndarray, matched: np.ndarray, size: in
     that differ at ranks whose gap to both neighbours is clear of `tol_rel`."""
     out = {"not_whole": 0, "total_off": 0, "hits_off": 0, "not_matching": 0,
            "rel_dev": 0.0, "ids_off": 0}
-    sh = resp.get("_shards", {})
-    if resp.get("timed_out") or sh.get("failed") or \
-            sh.get("successful") != sh.get("total"):
+    if _not_whole(resp):
         out["not_whole"] = 1
         return out
     total, ranked = ref.top(scores, matched, size)
@@ -172,12 +187,8 @@ def check_hits(ref: Reference, scores: np.ndarray, matched: np.ndarray, size: in
         return out
     order = ranked[:k]
     ref_scores = scores[order]
-    got_ids = np.array([int(h["_id"]) if str(h["_id"]).isdigit() else -1
-                        for h in hits], np.int64)
+    got_ids, out["not_matching"] = _hit_ids(hits, matched)
     got_scores = np.array([h["_score"] for h in hits], np.float32)
-    ok = (got_ids >= 0) & (got_ids < ref.n_docs)
-    ok[ok] = matched[got_ids[ok]]
-    out["not_matching"] = int((~ok).sum())
     if out["not_matching"]:
         return out
     own = scores[got_ids]
@@ -190,4 +201,76 @@ def check_hits(ref: Reference, scores: np.ndarray, matched: np.ndarray, size: in
     last_clear = len(ranked) == k or abs(ref_scores[-1] - scores[ranked[k]]) > tol[-1]
     clear = np.concatenate([[True], gap]) & np.concatenate([gap, [last_clear]])
     out["ids_off"] = int((got_ids[clear] != order[clear]).sum())
+    return out
+
+
+def hits_answer(ref: Reference, scores: np.ndarray, matched: np.ndarray,
+                size: int) -> dict:
+    """What a system that computed `scores` and `matched` would serve: its own top
+    `size` by score, as a response. A control's answer, and a test's sound one."""
+    total, ranked = ref.top(scores, matched, size)
+    return {"_shards": {"total": 1, "successful": 1, "failed": 0}, "timed_out": False,
+            "hits": {"total": total,
+                     "hits": [{"_id": str(int(d)), "_score": float(scores[d])}
+                              for d in ranked[:size]]}}
+
+
+def bucket_counts(matched: np.ndarray, column: np.ndarray,
+                  edges: np.ndarray) -> np.ndarray:
+    """Exact counts of the matched documents in each bucket of a numeric column:
+    bucket b holds `edges[b] <= value < edges[b + 1]`, `edges` ascending. A matched
+    value outside the edges is an error of the caller's edges, not a dropped count."""
+    values = np.asarray(column)[matched]
+    edges = np.asarray(edges)
+    b = np.searchsorted(edges, values, side="right") - 1
+    if len(b) and (b.min() < 0 or b.max() >= len(edges) - 1):
+        raise ValueError("a matched value lies outside the bucket edges")
+    return np.bincount(b, minlength=len(edges) - 1).astype(np.int64)
+
+
+def rank_by_column(matched: np.ndarray, keys: np.ndarray, descending: bool):
+    """(total, ranked doc ids) of a matched set sorted by a column's value, ties by
+    doc id ascending whichever way the sort runs, as Lucene's `TopFieldCollector`
+    breaks them."""
+    cand = np.flatnonzero(matched)
+    k = np.asarray(keys)[cand]
+    return len(cand), cand[np.lexsort((cand, -k if descending else k))]
+
+
+def check_sorted_hits(keys: np.ndarray, matched: np.ndarray, size: int, resp: dict,
+                      descending: bool) -> dict:
+    """One response of a search sorted by a column against the reference. `keys` holds
+    each document's sort value as a response states it (`sort[0]` of a hit). Numbers,
+    each with a limit of its own: whether it answered whole, its total, the number of
+    hits, returned docs that do not match, `sort_keys_off` (hits whose sort value is
+    not the reference's at that rank: any misordering shows here, ties or not),
+    `sort_ids_off` (ids that differ at ranks whose key differs from both neighbours')
+    and `sort_ties_off` (ids that differ at the other ranks: a tie broken otherwise
+    than by doc id, which holds on one shard and is no guarantee across several)."""
+    out = {"not_whole": 0, "total_off": 0, "hits_off": 0, "not_matching": 0,
+           "sort_keys_off": 0, "sort_ids_off": 0, "sort_ties_off": 0}
+    if _not_whole(resp):
+        out["not_whole"] = 1
+        return out
+    total, ranked = rank_by_column(matched, keys, descending)
+    out["total_off"] = abs(int(resp["hits"]["total"]) - total)
+    hits = resp["hits"]["hits"]
+    k = min(size, total)
+    out["hits_off"] = abs(len(hits) - k)
+    if out["hits_off"] or k == 0:
+        return out
+    order = ranked[:k]
+    got_ids, out["not_matching"] = _hit_ids(hits, matched)
+    if out["not_matching"]:
+        return out
+    want = np.asarray(keys)[order]
+    got = [(h.get("sort") or [None])[0] for h in hits]
+    out["sort_keys_off"] = int(sum(g is None or g != w for g, w in zip(got, want)))
+    gap = want[1:] != want[:-1]
+    # the hit just past k closes the last gap
+    last_clear = len(ranked) == k or keys[ranked[k]] != want[-1]
+    clear = np.concatenate([[True], gap]) & np.concatenate([gap, [last_clear]])
+    off = got_ids != order
+    out["sort_ids_off"] = int((off & clear).sum())
+    out["sort_ties_off"] = int((off & ~clear).sum())
     return out

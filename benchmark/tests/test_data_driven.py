@@ -1,7 +1,8 @@
 """The harness finds a configuration (with URL parameters for its searches and
-counters of its own that may not rise), a mix, a query family, a layer metric and a
-trace reduction that were added as files and entries alone, and names none of them in
-its code."""
+counters of its own that may not rise), a mix, a query family (with a comparison, numbers
+and limits of its own, and what the window keeps for it), a layer metric and a trace
+reduction that were added as files and entries alone, and names none of them in its
+code."""
 
 import argparse
 import json
@@ -130,6 +131,101 @@ def test_pieces_added_as_files_alone_are_found(copy):
     assert readers.read(definition, obs) == pytest.approx(25.0)
     for p, content in before.items():
         assert (copy / "benchmark" / p).read_bytes() == content
+
+
+FAMILY_WITH_ITS_OWN_NUMBER = (
+    "from benchmark.harness.reference import check_hits, hits_answer, word\n"
+    "LIMITS = {LIMITS}\n"
+    "KEEP = {'response': ['max_of_hits'], 'hit': ['_type']}\n"
+    "def plan(params, rng, n):\n"
+    "    return [float(rng.random()) for _ in range(n)]\n"
+    "def build(params, ref, plans):\n"
+    "    out = []\n"
+    "    for u in plans:\n"
+    "        t = int(ref.by_df[int(u * ref.n_present)])\n"
+    "        out.append({'terms': [t], 'must_all': False, 'size': 5, 'allowed': None,\n"
+    "                    'body': {'query': {'match': {params['field']: word(t)}},\n"
+    "                             'size': 5}})\n"
+    "    return out\n"
+    "def expected(ref, q):\n"
+    "    return ref.score_all(q['terms'], False)\n"
+    "def answer(ref, q):\n"
+    "    resp = hits_answer(ref, *expected(ref, q), q['size'])\n"
+    "    resp['max_of_hits'] = max(h['_score'] for h in resp['hits']['hits'])\n"
+    "    return resp\n"
+    "def compare(ref, q, resp, tol):\n"
+    "    out = check_hits(ref, *expected(ref, q), q['size'], resp, tol)\n"
+    "    top = max(h['_score'] for h in resp['hits']['hits'])\n"
+    "    out['max_off'] = int(resp.get('max_of_hits') != top)\n"
+    "    return out\n")
+
+
+def _a_cell_of_a_family_with_its_own_number(copy, limits: str):
+    bench = json.loads((copy / "BENCHMARK.json").read_text())
+    (copy / "benchmark/queries/topped.py").write_text(
+        FAMILY_WITH_ITS_OWN_NUMBER.replace("{LIMITS}", limits))
+    (copy / "benchmark/traffic/topped_alone.json").write_text(json.dumps({
+        "name": "topped_alone", "loop": "closed", "clients": 1, "keep_alive": True,
+        "pool": 8, "plan_seed": 2, "who": "a test", "why": "a test",
+        "families": [{"family": "topped", "weight": 1, "params": {"field": "body"}}]}))
+    bench["workloads"].append({"name": "passage.topped", "config":
+                               bench["configs"][0]["name"], "traffic": "topped_alone",
+                               "chips": 1, "why": "a test"})
+    (copy / "BENCHMARK.json").write_text(json.dumps(bench))
+    from benchmark.harness.cell import Run
+
+    run = Run(argparse.Namespace(workload="passage.topped", seed=5, seconds=1.0,
+                                 trace=0, docs=300), 0.0, assume_chip=True)
+    run.make_corpus()
+    return run
+
+
+def test_a_family_brings_its_comparison_numbers_and_limits_as_files_alone(copy, capsys):
+    from benchmark.harness.cell import Compared
+    from benchmark.harness.loadgen import _digest, as_response
+
+    general = ["run.py", "settings.json"] + [
+        "harness/" + f for f in os.listdir(copy / "benchmark/harness")
+        if f.endswith(".py")]
+    before = {p: (copy / "benchmark" / p).read_bytes() for p in general}
+    run = _a_cell_of_a_family_with_its_own_number(copy, "{'max_off': 0}")
+    run.make_reference()
+    assert run.pool.limits == {**run.limits, "max_off": 0}
+    assert run.pool.keeps == [{"response": ["max_of_hits"], "hit": ["_type"]}] * 8
+    # a response of the window: through the compact answer, then the family's compare
+    sound = run.pool.answer(run.ref, 3)
+    for h in sound["hits"]["hits"]:
+        h.update(_type="doc", _source={"body": "w1"})
+    _whole, answer, _spans = _digest(200, json.dumps(sound).encode(), run.pool.keeps[3])
+    kept = as_response(answer)
+    assert kept["max_of_hits"] == sound["max_of_hits"]
+    assert all(set(h) == {"_id", "_score", "_type"} for h in kept["hits"]["hits"])
+    got = Compared(run.pool.limits)
+    got.add(run.pool.compare(run.ref, 3, kept, 1e-5))
+    assert got.passed
+    wrong = dict(kept, max_of_hits=kept["max_of_hits"] * 2)
+    got.add(run.pool.compare(run.ref, 3, wrong, 1e-5))
+    assert not got.passed
+    # the family's number stands beside its limit wherever the shared ones do
+    run.compare_line("window", "a test", got)
+    assert run.compared["window.max_off"] == [1, 0]
+    line = json.loads(capsys.readouterr().out.splitlines()[-1])
+    assert line["numbers"]["max_off"] == {"value": 1, "limit": 0}
+    assert list(line["numbers"])[:6] == list(run.limits)
+    for p, content in before.items():
+        assert (copy / "benchmark" / p).read_bytes() == content
+
+
+@pytest.mark.parametrize("limits, message", [
+    ("{}", "gives no limit"),                 # a number of its own without a limit
+    ("{'max_off': 0, 'ids_off': 3}", "it has 0")])  # a shared limit is not loosened
+def test_a_family_number_without_a_limit_is_refused_before_any_search(copy, limits,
+                                                                      message):
+    from benchmark.harness.server import BenchFailure
+
+    run = _a_cell_of_a_family_with_its_own_number(copy, limits)
+    with pytest.raises(BenchFailure, match=message):
+        run.make_reference()
 
 
 def test_a_cell_without_the_new_keys_is_held_to_what_all_cells_share():

@@ -8,7 +8,7 @@ import pytest
 
 from benchmark.harness import registry
 from benchmark.harness.reference import (Reference, byte315_to_float, check_hits,
-                                         float_to_byte315)
+                                         float_to_byte315, hits_answer)
 
 PARAMS = {"vocabulary": 300, "mean_length": 30, "min_length": 5, "max_length": 100,
           "zipf_a": 1.35, "text_field": "body",
@@ -66,18 +66,11 @@ def test_reference_matches_brute_force(corpus, must_all, filtered):
         assert scores[i] == pytest.approx(s, rel=2e-6)
 
 
-def _answer(ref, scores, matched, k):
-    total, ranked = ref.top(scores, matched, k)
-    return {"_shards": {"total": 1, "successful": 1, "failed": 0}, "timed_out": False,
-            "hits": {"total": total, "hits": [
-                {"_id": str(int(d)), "_score": float(scores[d])} for d in ranked[:k]]}}
-
-
 def test_check_hits_passes_its_own_answer_and_catches_each_fault(corpus):
     ref = Reference(corpus, K1, B)
     terms = [int(t) for t in ref.by_df[[1, 5]]]
     scores, matched = ref.score_all(terms, False)
-    good = _answer(ref, scores, matched, 10)
+    good = hits_answer(ref, scores, matched, 10)
     assert not any(check_hits(ref, scores, matched, 10, good, 1e-5).values())
 
     def numbers(resp):

@@ -1,7 +1,8 @@
 """The rest of a run, driven on the CPU at a tiny size with the harness's look for a
 chip skipped: a sound server comes out `correct: true`, and a server whose timed path
 is broken underneath (every BM25 weight one part in a thousand off, where the score is
-produced) comes out `correct: false`. The CPU rehearsal as a user runs it never says
+produced; a bucket of an aggregation one document short) comes out `correct: false`,
+on the number that names the fault. The CPU rehearsal as a user runs it never says
 true. The sound runs' server writes down every request it is handed: each `_search`
 carries the URL parameters its configuration states (`search.params`), late writes'
 searches included, and no other. A cell of several chips gets as many virtual CPU
@@ -76,17 +77,38 @@ def test_a_sound_run_is_correct_and_reports_its_metrics(capsys, monkeypatch, tmp
     assert device["count"] == cell_["chips"] or cell_["chips"] == 1
 
 
-def test_a_broken_timed_path_is_not_correct(capsys, monkeypatch):
-    workload = registry.benchmark()["workloads"][0]["name"]
+def _first_cell(own_comparison: bool) -> str:
+    """The first cell whose mix has (or has not) a query family that brings a
+    comparison of its own."""
+    for w in registry.benchmark()["workloads"]:
+        mods = [registry.module("queries", f["family"])
+                for f in registry.mix(w["traffic"])["families"]]
+        if any(hasattr(m, "compare") for m in mods) == own_comparison:
+            return w["name"]
+    raise KeyError(own_comparison)
+
+
+@pytest.mark.parametrize("fault, own_comparison, number", [
+    ("broken_server", False, "rel_dev"),
+    ("lost_count_server", True, "agg_counts_off")])
+def test_a_broken_timed_path_is_not_correct(capsys, monkeypatch, fault, own_comparison,
+                                            number):
+    workload = _first_cell(own_comparison)
     rc, lines = _run(capsys, monkeypatch, workload, assume_chip=True, server_env={
-        "PYTHONPATH": _paths(os.path.join(HERE, "broken_server"))})
+        "PYTHONPATH": _paths(os.path.join(HERE, fault))})
     result = lines[-1]
     assert rc == 1 and result["correct"] is False
     window = [l for l in lines if l.get("phase") == "compare"
               and l["sample"].startswith("the window")][0]
-    assert window["numbers"]["rel_dev"]["value"] > window["numbers"]["rel_dev"]["limit"]
-    value, limit = result["compared"]["window.rel_dev"]
-    assert value == window["numbers"]["rel_dev"]["value"] and value > limit == 1e-5
+    got = window["numbers"][number]
+    assert got["value"] > got["limit"]
+    assert result["compared"]["window." + number] == [got["value"], got["limit"]]
+    if number != "rel_dev":
+        # the hits of the same responses are sound: only the family's number bites
+        assert window["numbers"]["rel_dev"]["value"] <= window["numbers"]["rel_dev"]["limit"]
+        assert window["numbers"]["ids_off"]["value"] == 0
+    else:
+        assert got["limit"] == 1e-5
 
 
 def test_the_rehearsal_never_says_correct(capsys, monkeypatch):
