@@ -388,7 +388,9 @@ MANIFEST_NAME = "compile_manifest.json"
 # the launch sites' operand lists are part of every recorded argspec: a
 # manifest written under another list would replay calls the programs no
 # longer take, so its specs are dropped at load (the ladders are kept)
-MANIFEST_VERSION = 2  # 2: operand planes (scoring.TermBatch.tri / SparseBatch.slots)
+# 2: operand planes (scoring.TermBatch.tri / SparseBatch.slots); 3: the dense
+# launches take the head rows and the head-slot plane (TermBatch.head)
+MANIFEST_VERSION = 3
 _MESH_RING = 4  # recent mesh plan batches kept per index
 
 

@@ -31,6 +31,19 @@ the shape buckets stabilize — segment churn from NRT refresh reuses cached exe
 Norm bytes stay uint8 on device; similarity-specific 256-entry decode tables
 (ensure_sim_tables) are gathered at score time, preserving Lucene's exact 1-byte
 quantization.
+
+Head terms also live as rows over documents:
+
+    head_rows : uint8/int16 [Hpad, Dpad] — one row of term frequencies per term
+               that matches Dpad / HEAD_DF_SHARE documents of the segment or
+               more, in the tf plane's dtype; rows from H on are zero
+
+For such a term the postings list is the wrong form on this chip: the dense
+programs add the row to their [Q, Dpad] accumulator in one elementwise pass,
+where they paid a serial gather and scatter for each posting. The plane is
+faulted in with the dense f32 plane (`ensure_head_rows`); the term → row map
+is host arithmetic at pack time. The postings of a head term stay where they
+are: the sparse program and the mesh packer read them.
 """
 
 from __future__ import annotations
@@ -135,6 +148,25 @@ def tf_plane_integral(post_freqs: np.ndarray, layout: str) -> bool:
     return True
 
 
+# A term has a row in `head_rows` when df * HEAD_DF_SHARE >= doc_pad. Chosen on
+# the chip between 6 (a u8 row is then never larger than the postings it
+# shadows: doc_pad B against df x 6 B) and 32; PERF.md section 6, PR 28, has
+# the measurement that chose it.
+HEAD_DF_SHARE = 16
+
+
+def head_terms(post_offsets: np.ndarray, doc_pad: int, tf_layout: str) -> dict:
+    """term id -> row of `head_rows`, rows in term order: the terms whose df
+    reaches doc_pad / HEAD_DF_SHARE. Empty on the TF_F32 escape layout, whose
+    segments run the dense programs on postings alone. Host arithmetic over
+    the CSR offsets; ensure_head_rows builds the plane these rows index."""
+    if tf_layout == TF_F32:
+        return {}
+    df = np.diff(post_offsets)
+    tids = np.nonzero(df * HEAD_DF_SHARE >= doc_pad)[0]
+    return {int(t): i for i, t in enumerate(tids.tolist())}
+
+
 @dataclass
 class SimTables:
     """Stacked per-field similarity decode state for the quantized sparse scan:
@@ -177,6 +209,10 @@ class PackedSegment:
     # dense-fallback plane, uploaded LAZILY (ensure_blk_freqs): most segments
     # only ever serve the sparse path and never pay these 4 B/posting
     blk_freqs: object = None  # jnp float32 [NBpad, B] or None until dense use
+    # head terms as rows over documents (module docstring): the host map is
+    # pack-time arithmetic, the plane is faulted in with blk_freqs
+    head_row_of: dict = dc_field(default_factory=dict)  # term id -> row
+    head_rows: object = None  # jnp tf dtype [Hpad, Dpad] or None until dense use
     # device metric-agg state: per-doc (count, sum, min, max, sumsq) rows per
     # numeric field, exact for MULTI-valued columns because the per-doc folds
     # happen host-side at build time (ops/scoring.score_agg_batch reduces them
@@ -184,9 +220,9 @@ class PackedSegment:
     agg_rows: dict = dc_field(default_factory=dict)  # field -> HOST f32 [5, Dpad] | None (not f32-exact)
     agg_stacks: dict = dc_field(default_factory=dict)  # fields-tuple -> device [F, 5, Dpad], FIFO-bounded
     bucket_cols: dict = dc_field(default_factory=dict)  # bucket-agg cache key -> device (pair_doc, pair_bucket, zeros[NB])
-    # the dense launches' stacked tables (scoring._stack_args), FIFO-bounded:
+    # the dense launches' per-document table (scoring._doc_table), FIFO-bounded:
     # fields-tuple -> (host caches f32 [F, 256], device norms_stack u8 [F, Dpad],
-    # device caches) — a warmed launch restacks and re-puts neither
+    # device table f32 [F, Dpad]) — a warmed launch restacks and remakes neither
     dense_tables: dict = dc_field(default_factory=dict)
     # reusable [Qb, TB] staging arrays for the sparse planner (scoring.
     # SparseScratchPool, lazily created) — the per-bucket padding scratch lives
@@ -256,11 +292,12 @@ def pack_estimate_bytes(seg: FrozenSegment) -> int:
 
 def packed_resident_bytes(packed: PackedSegment) -> int:
     """Actual device-RESIDENT postings-plane bytes of a packed segment (docs +
-    tf + nb, plus the dense f32 plane if it has been faulted in) — what the
-    bench `kernel` row and the breaker-estimate test compare against."""
+    tf + nb, plus the dense f32 plane and the head rows if they have been
+    faulted in) — what the bench `kernel` row and the breaker-estimate test
+    compare against."""
     total = 0
     for plane in (packed.blk_docs, packed.blk_tf, packed.blk_nb,
-                  packed.blk_freqs):
+                  packed.blk_freqs, packed.head_rows):
         if plane is not None:
             total += int(np.prod(plane.shape)) * np.dtype(plane.dtype).itemsize
     return total
@@ -276,7 +313,8 @@ def packed_tier_bytes(packed: PackedSegment) -> dict:
     device capacity ledger's taxonomy (ARCHITECTURE.md "Observability"):
 
       postings     the quantized sparse planes (blk_docs i32 + blk_tf + blk_nb)
-      dense_plane  the lazily-faulted f32 freqs plane (0 until dense use)
+      dense_plane  the lazily-faulted f32 freqs plane and the head-term rows
+                   (0 until dense use)
       sim_tables   the stacked per-field similarity LUTs (modes + caches)
       agg_rows     FIFO-bounded device metric-agg stacks
       norms        per-field norm-byte columns + live mask + dv columns
@@ -295,11 +333,12 @@ def packed_tier_bytes(packed: PackedSegment) -> dict:
         norms += _plane_bytes(col)
     for col in packed.dv_single.values():
         norms += _plane_bytes(col)
-    for (_host, norms_stack, _caches) in list(packed.dense_tables.values()):
-        norms += _plane_bytes(norms_stack)
+    for (_host, norms_stack, table) in list(packed.dense_tables.values()):
+        norms += _plane_bytes(norms_stack) + _plane_bytes(table)
     return {
         "postings": postings,
-        "dense_plane": _plane_bytes(packed.blk_freqs),
+        "dense_plane": (_plane_bytes(packed.blk_freqs)
+                        + _plane_bytes(packed.head_rows)),
         "sim_tables": sim,
         "agg_rows": agg,
         "norms": norms,
@@ -597,6 +636,7 @@ def pack_segment(seg: FrozenSegment, fields: list[str] | None = None,
         host_freqs=flat_freqs,
         blk_field=blk_field,
         field_names=field_names,
+        head_row_of=head_terms(seg.post_offsets, Dpad, tf_layout),
     )
 
 
@@ -772,6 +812,7 @@ def pack_segment_concat(merged: FrozenSegment,
         host_freqs=flat_freqs,
         blk_field=blk_field,
         field_names=field_names,
+        head_row_of=head_terms(merged.post_offsets, Dpad, layout),
     )
 
 
@@ -800,6 +841,36 @@ def ensure_blk_freqs(packed: PackedSegment, breaker=None):
     elif prof is not None:
         prof.event("blk_freqs", cache="resident")
     return packed.blk_freqs
+
+
+def ensure_head_rows(packed: PackedSegment, breaker=None):
+    """Lazily fault in the head-term rows (module docstring), beside the dense
+    f32 plane and under the same contract: built from the pack's own host
+    copy, reserved on `breaker` around the build and the upload, idempotent.
+
+    Row i holds the raw tf of term i's postings by doc id, deleted and
+    non-parent documents included: every dense program ends in
+    `& live_parent`, so a live-mask change re-bakes nothing here. The row
+    count rides the pow-2 ladder (it shapes the dense programs' operand);
+    rows from len(head_row_of) on are zero, so the last row always is, and
+    padding slots point there."""
+    if packed.head_rows is None:
+        import jax.numpy as jnp
+
+        dtype = _TF_DTYPE[TF_U8 if packed.tf_layout == TF_F32
+                          else packed.tf_layout]
+        n_rows = _pow2_bucket(len(packed.head_row_of) + 1, 1)
+        est = n_rows * packed.doc_pad * np.dtype(dtype).itemsize
+        with reserve(breaker, est, "<head_rows>"):
+            rows = np.zeros((n_rows, packed.doc_pad), dtype)
+            for tid, row in packed.head_row_of.items():
+                b0, b1 = packed.blocks_for_term(tid)
+                docs = packed.host_docs[b0 * BLOCK: b1 * BLOCK]
+                real = docs < packed.doc_pad
+                rows[row, docs[real]] = \
+                    packed.host_freqs[b0 * BLOCK: b1 * BLOCK][real]
+            packed.head_rows = jnp.asarray(rows)
+    return packed.head_rows
 
 
 def agg_doc_rows(seg: FrozenSegment, field: str) -> np.ndarray | None:

@@ -44,6 +44,7 @@ from .device_index import (
     _ladder_bucket,
     _pow2_bucket,
     ensure_blk_freqs,
+    ensure_head_rows,
 )
 
 GROUP_SHOULD, GROUP_MUST, GROUP_MUST_NOT = 0, 1, 2
@@ -56,21 +57,35 @@ MODE_CONST = 2  # contribution = w per matching term (constant-score / filters)
 
 # rows of TermBatch.tri, the per-triple operand plane of every dense launch
 _T_QIDX, _T_BLK, _T_WEIGHT, _T_FIDX, _T_GROUP, _T_TFMODE = range(6)
+# rows of TermBatch.head, the per-query head-slot plane of every dense launch
+_H_ROW, _H_WEIGHT, _H_FIDX, _H_GROUP, _H_TFMODE = range(5)
+# head slots a query: one fixed width, so the count of head clauses is no
+# dimension of a program's key; a query's clauses past it keep their blocks
+HEAD_SLOTS = 16
+# the foot of a dense launch's `terms` rung: with the head terms gone into
+# rows a batch's tails are tens to hundreds of blocks, and a rung for each
+# power of two between them is a program each (a first sighting stalls the
+# drainer); padding blocks cost the device 1.8 us each
+TAIL_FLOOR = 256
 
 
 @dataclass
 class TermBatch:
-    """Flattened (query, term, block) triples + per-query bool-semantics arrays.
-    Built host-side by the query planner (search/execute.py).
+    """Flattened (query, term, block) triples, the head-term slots and the
+    per-query bool-semantics arrays. Built host-side by the query planner
+    (search/execute.py).
 
-    A launch hands the device two planes: `tri` (int32 [6, M], one row per
-    triple column, the f32 weights as their bits) and `qplane` (int32
-    [Q, 2 + C+1]: n_must, msm, then the coord row as its bits). The
+    A launch hands the device three planes: `tri` (int32 [6, M], one row per
+    triple column, the f32 weights as their bits), `qplane` (int32
+    [Q, 2 + C+1]: n_must, msm, then the coord row as its bits) and `head`
+    (int32 [5, Q, HEAD_SLOTS]: a clause whose term has a row of
+    device_index head_rows names that row here and no block in `tri`). The
     per-triple columns below are host VIEWS of `tri`."""
 
     n_queries: int
     tri: np.ndarray  # int32 [6, M] — rows _T_*
     qplane: np.ndarray  # int32 [Q, 2 + C+1]
+    head: np.ndarray  # int32 [5, Q, HEAD_SLOTS] — rows _H_*; pad: head_rows' last (zero) row
     # per triple (padded to bucket):
     qidx: np.ndarray  # int32 [M]
     blk: np.ndarray  # int32 [M] — block row in the packed segment (pad: NBpad-? safe row)
@@ -87,6 +102,9 @@ class TermBatch:
     caches: np.ndarray | None = None  # float32 [F, 256]
     simple: bool | None = None  # cached fast-path eligibility (computed on first use)
     blocks_real: int = 0  # triples that name a postings block (the rest of M pads)
+    head_slots: int = 0  # slots of `head` that name a row
+    head_trips: int = 0  # the fullest query's count of them: the head loop's trips
+    blocks_as_rows: int = 0  # postings blocks those rows stand in for
 
 
 @dataclass
@@ -97,29 +115,12 @@ class ScoreResult:
     max_score: np.ndarray  # [Q] float32
 
 
-def _score_batch_impl(blk_docs, blk_freqs, live_parent, norms_stack, caches,
-                      qidx, blk, weight, fidx, group, tfmode,
-                      n_must, msm, coord, *, n_queries: int, k: int, doc_pad: int,
-                      simple: bool = False):
-    """simple=True is a host-detected static fast path: every clause is a SHOULD with
-    msm<=1, no coord — match reduces to score>0, so the int counters scatter and the
-    per-doc match bookkeeping are skipped entirely (the bulk-query hot shape)."""
+def _top_k_tail(scores, match, *, k: int):
+    """The plain dense program's tail. Sentinel substitution + max_score are
+    [Q, k]-tiny — done host-side in score_term_batch, not appended here."""
     import jax
     import jax.numpy as jnp
 
-    Q = n_queries
-    scores, flat_idx, valid = _dense_accumulate(
-        blk_docs, blk_freqs, norms_stack, caches, qidx, blk, weight, fidx, group,
-        tfmode, Q=Q, doc_pad=doc_pad)
-
-    if simple:
-        with jax.named_scope("match_coord"):
-            match = (scores > 0.0) & live_parent[None, :doc_pad]
-    else:
-        scores, match = _dense_semantics(scores, flat_idx, valid, group, live_parent,
-                                         n_must, msm, coord, Q=Q, doc_pad=doc_pad)
-    # sentinel substitution + max_score are [Q, k]-tiny — done host-side in
-    # score_term_batch, not appended to this program
     with jax.named_scope("top_k"):
         masked = jnp.where(match, scores, jnp.float32(-jnp.inf))
         top_scores, top_docs = jax.lax.top_k(masked, k)
@@ -127,64 +128,101 @@ def _score_batch_impl(blk_docs, blk_freqs, live_parent, norms_stack, caches,
     return top_scores, top_docs, total
 
 
-def _dense_accumulate(blk_docs, blk_freqs, norms_stack, caches,
-                      qidx, blk, weight, fidx, group, tfmode, *, Q: int, doc_pad: int):
-    """Steps 1-3 of the dense kernel: gather postings blocks, per-posting
-    contributions, scatter-add into the [Q, doc_pad] accumulator. Returns
-    (scores, flat_idx, valid) for the semantics pass."""
+def _contribution(tf, cache_vals, w, mode):
+    """One clause's per-document (or per-posting) score term. Float op ORDER
+    matters for bit-parity with the host scorer and the sparse kernel's
+    in-scan tfn (sparse_candidates): the tf factor is computed FIRST, then
+    multiplied by the weight — Lucene's weight·tfNorm order
+    (BM25Similarity.BM25DocScorer / TFIDFSimilarity.ExactSimScorer)."""
+    import jax.numpy as jnp
+
+    bm25 = w * (tf / (tf + cache_vals))
+    tfidf = w * (jnp.sqrt(tf) * cache_vals)
+    return jnp.where(mode == MODE_BM25, bm25,
+                     jnp.where(mode == MODE_TFIDF, tfidf, w))
+
+
+def _group_counter(group):
+    import jax.numpy as jnp
+
+    return (jnp.where(group == GROUP_SHOULD, 1, 0)
+            + jnp.where(group == GROUP_MUST, 1 << _MUST_SHIFT, 0)
+            + jnp.where(group == GROUP_MUST_NOT, 1 << _NOT_SHIFT, 0)
+            ).astype(jnp.int32)
+
+
+def _dense_accumulate(blk_docs, blk_freqs, head_rows, cv, tri, head,
+                      *, Q: int, doc_pad: int, counters: bool):
+    """Steps 1-4 of the dense kernel into [Q, doc_pad] accumulators: each head
+    slot adds its term's row over documents in one elementwise pass (rows in
+    clause order), then the blocks that are left are gathered, scored per
+    posting and scatter-added. `cv` is the per-document table (_doc_table).
+    Returns (scores, counts); `counts` (the packed match counters) is None
+    unless `counters`."""
     import jax
     import jax.numpy as jnp
 
+    with jax.named_scope("head_rows"):
+        h_weight = jax.lax.bitcast_convert_type(head[_H_WEIGHT], jnp.float32)
+        h_counter = _group_counter(head[_H_GROUP])
+        # padded slots name the plane's last row (always zero): the loop runs
+        # to the batch's fullest query, a trip count read from the operand
+        used = head[_H_ROW] != head_rows.shape[0] - 1  # [Q, HEAD_SLOTS]
+        trips = jnp.max(jnp.sum(used, axis=1, dtype=jnp.int32))
+
+        def add_slot(j, acc):
+            row, fid, grp, mode = (
+                jax.lax.dynamic_index_in_dim(head[r], j, axis=1, keepdims=False)
+                for r in (_H_ROW, _H_FIDX, _H_GROUP, _H_TFMODE))
+            w = jax.lax.dynamic_index_in_dim(h_weight, j, axis=1)  # [Q, 1]
+            tf = head_rows[row].astype(jnp.float32)  # [Q, doc_pad]
+            there = tf > 0.0  # also keeps a normless field's 0/0 out
+            contrib = _contribution(tf, cv[fid], w, mode[:, None])
+            scoring = there & (grp != GROUP_MUST_NOT)[:, None]
+            scores = acc[0] + jnp.where(scoring, contrib, 0.0)
+            if not counters:
+                return (scores,)
+            cnt = jax.lax.dynamic_index_in_dim(h_counter, j, axis=1)
+            return scores, acc[1] + jnp.where(there, cnt, 0)
+
+        acc = (jnp.zeros((Q, doc_pad), jnp.float32),)
+        if counters:
+            acc += (jnp.zeros((Q, doc_pad), jnp.int32),)
+        acc = jax.lax.fori_loop(0, trips, add_slot, acc)
+        scores, counts = acc[0], (acc[1] if counters else None)
+
+    qidx, blk, fidx = tri[_T_QIDX], tri[_T_BLK], tri[_T_FIDX]
+    group = tri[_T_GROUP]
     with jax.named_scope("gather_decode"):
         docs = blk_docs[blk]  # [M, B] int32; padded rows → doc_pad sentinel
         freqs = blk_freqs[blk]  # [M, B]
         valid = docs < doc_pad
         docs_safe = jnp.where(valid, docs, 0)
-
-        nb = norms_stack[fidx[:, None], docs_safe]  # [M, B] uint8
-        cache_vals = caches[fidx[:, None], nb.astype(jnp.int32)]  # [M, B]
-
-        # float op ORDER matters for bit-parity with the host scorer and the sparse
-        # kernel's in-scan tfn (sparse_candidates): the tf factor is computed FIRST,
-        # then multiplied by the weight — Lucene's weight·tfNorm order
-        # (BM25Similarity.BM25DocScorer / TFIDFSimilarity.ExactSimScorer)
-        mode = tfmode[:, None]
-        w = weight[:, None]
-        bm25 = w * (freqs / (freqs + cache_vals))
-        tfidf = w * (jnp.sqrt(freqs) * cache_vals)
-        contrib = jnp.where(mode == MODE_BM25, bm25,
-                            jnp.where(mode == MODE_TFIDF, tfidf, w))
+        cache_vals = cv.reshape(-1)[fidx[:, None] * doc_pad + docs_safe]
+        weight = jax.lax.bitcast_convert_type(tri[_T_WEIGHT], jnp.float32)
+        contrib = _contribution(freqs, cache_vals, weight[:, None],
+                                tri[_T_TFMODE][:, None])
         scoring = (group[:, None] != GROUP_MUST_NOT) & valid
         contrib = jnp.where(scoring, contrib, 0.0)
 
     with jax.named_scope("scatter_add"):
-        qd = (qidx[:, None] * (doc_pad + 1))
-        flat_idx = jnp.where(valid, qd + docs_safe, Q * (doc_pad + 1))  # OOB → dropped
+        # invalid slots index past the end and are dropped
+        flat_idx = jnp.where(valid, qidx[:, None] * doc_pad + docs_safe,
+                             Q * doc_pad).reshape(-1)
+        scores = scores.reshape(-1).at[flat_idx].add(
+            contrib.reshape(-1), mode="drop").reshape(Q, doc_pad)
+        if counters:
+            counter_vals = jnp.where(valid, _group_counter(group)[:, None], 0)
+            counts = counts.reshape(-1).at[flat_idx].add(
+                counter_vals.reshape(-1), mode="drop").reshape(Q, doc_pad)
+    return scores, counts
 
-        scores = jnp.zeros(Q * (doc_pad + 1), jnp.float32).at[flat_idx.reshape(-1)].add(
-            contrib.reshape(-1), mode="drop"
-        ).reshape(Q, doc_pad + 1)[:, :doc_pad]
-    return scores, flat_idx, valid
 
-
-def _dense_semantics(scores, flat_idx, valid, group, live_parent, n_must, msm, coord,
-                     *, Q: int, doc_pad: int):
-    """Bool-query semantics + coord over the dense accumulator: returns the
-    coord-scaled scores and the match mask (shared by the plain dense kernel and
-    the function_score variants below)."""
+def _dense_semantics(scores, counts, live_parent, n_must, msm, coord):
+    """Bool-query semantics + coord over the dense accumulators: returns the
+    coord-scaled scores and the match mask."""
     import jax
     import jax.numpy as jnp
-
-    with jax.named_scope("scatter_add"):
-        counters = (
-            jnp.where(group == GROUP_SHOULD, 1, 0)
-            + jnp.where(group == GROUP_MUST, 1 << _MUST_SHIFT, 0)
-            + jnp.where(group == GROUP_MUST_NOT, 1 << _NOT_SHIFT, 0)
-        ).astype(jnp.int32)
-        counter_vals = jnp.where(valid, counters[:, None], 0)
-        counts = jnp.zeros(Q * (doc_pad + 1), jnp.int32).at[flat_idx.reshape(-1)].add(
-            counter_vals.reshape(-1), mode="drop"
-        ).reshape(Q, doc_pad + 1)[:, :doc_pad]
 
     with jax.named_scope("match_coord"):
         m_should = counts & 0x3FF
@@ -192,7 +230,7 @@ def _dense_semantics(scores, flat_idx, valid, group, live_parent, n_must, msm, c
         m_not = counts >> _NOT_SHIFT
 
         match = (m_must == n_must[:, None]) & (m_should >= msm[:, None]) & (m_not == 0)
-        match = match & ((m_should + m_must) > 0) & live_parent[None, :doc_pad]
+        match = match & ((m_should + m_must) > 0) & live_parent[None, :]
 
         overlap = jnp.minimum(m_should + m_must, coord.shape[1] - 1)
         # per-row lookup into the small [Q, C+1] coord table as a static select-sum —
@@ -228,20 +266,24 @@ class LaunchCounters:
     `posting_bytes` is the program's own reckoning of the HBM bytes a launch
     reads, no measurement; each formula sits beside its launch
     (score_sparse_batch_async, _count_dense). `dense_rows` counts the
-    [doc_pad]-wide score rows of the dense launches. `operand_puts` counts
-    the host arrays launch sites put on the device (_put_operands: each leaf
-    of a launch's one device_put is its own transfer), two for a warmed
-    plain launch."""
+    [doc_pad]-wide score rows of the dense launches, `head_slots` the rows
+    of device_index head_rows they added and `blocks_as_rows` the postings
+    blocks those rows stood in for (`blocks_real` / `blocks_launched` count
+    what is still scattered). `operand_puts` counts the host arrays launch
+    sites put on the device (_put_operands: each leaf of a launch's one
+    device_put is its own transfer): two for a warmed sparse launch, three
+    for a warmed dense one."""
 
     def __init__(self):
         self._lock = threading.Lock()
         self._c = dict.fromkeys(
             ("blocks_real", "blocks_launched", "blocks_padding",
-             "posting_bytes", "dense_rows", "launches_sparse",
-             "launches_dense", "operand_puts"), 0)
+             "posting_bytes", "dense_rows", "head_slots", "blocks_as_rows",
+             "launches_sparse", "launches_dense", "operand_puts"), 0)
 
     def add(self, real: int, launched: int, nbytes: int,
-            dense_rows: int = 0) -> None:
+            dense_rows: int = 0, head_slots: int = 0,
+            blocks_as_rows: int = 0) -> None:
         with self._lock:
             c = self._c
             c["blocks_real"] += real
@@ -249,6 +291,8 @@ class LaunchCounters:
             c["blocks_padding"] += launched - real
             c["posting_bytes"] += nbytes
             c["dense_rows"] += dense_rows
+            c["head_slots"] += head_slots
+            c["blocks_as_rows"] += blocks_as_rows
             c["launches_dense" if dense_rows else "launches_sparse"] += 1
 
     def puts(self, n: int) -> None:
@@ -329,28 +373,47 @@ def _pull(out):
 
 def _count_dense(packed: PackedSegment, batch: TermBatch) -> None:
     """A dense launch reads, per launched (query, block) triple, BLOCK slots
-    of doc id i32 + freq f32 + one gathered norm byte, and top_k reads the
+    of doc id i32 + freq f32 + one gathered table value f32; per trip of the
+    head loop (it runs to the fullest query) a [Q, doc_pad] gather of rows in
+    the segment's tf dtype and one of table values f32; and top_k reads the
     [Q, doc_pad] f32 score plane back."""
     m, q = len(batch.blk), batch.n_queries
     LAUNCHES.add(batch.blocks_real, m,
-                 m * BLOCK * (4 + 4 + 1) + q * packed.doc_pad * 4, dense_rows=q)
+                 m * BLOCK * (4 + 4 + 4)
+                 + q * batch.head_trips * packed.doc_pad
+                 * (packed.head_rows.dtype.itemsize + 4)
+                 + q * packed.doc_pad * 4,
+                 dense_rows=q, head_slots=batch.head_slots,
+                 blocks_as_rows=batch.blocks_as_rows)
 
 
-def _dense_abi(impl, **statics):
-    """`impl` behind the dense launch ABI (blk_docs, blk_freqs, live_parent,
-    norms_stack, caches, tri, qplane, *extra): the two operand planes a launch
-    puts on the device are taken apart INSIDE the program into the columns
-    every dense kernel takes (row slices and a bit-exact bitcast)."""
-    def wrapper(blk_docs, blk_freqs, live_parent, norms_stack, caches,
-                tri, qplane, *extra):
+def _dense_abi(tail, *, n_queries: int, doc_pad: int, simple: bool = False,
+               **statics):
+    """`tail` behind the dense launch ABI (blk_docs, blk_freqs, head_rows,
+    live_parent, doc_table, tri, qplane, head, *extra): the ONE
+    scoring core every dense family shares. The three operand planes a launch
+    puts on the device are taken apart INSIDE the program (row slices and a
+    bit-exact bitcast), accumulated (_dense_accumulate) and matched; the
+    family's `tail(scores, match, *extra, **statics)` ranks and reduces.
+
+    simple=True is a host-detected static fast path: every clause is a SHOULD
+    with msm<=1, no coord — match reduces to score>0, so the int counters and
+    the per-doc match bookkeeping are skipped entirely (the bulk-query hot
+    shape)."""
+    def wrapper(blk_docs, blk_freqs, head_rows, live_parent, doc_table,
+                tri, qplane, head, *extra):
         import jax
-        import jax.numpy as jnp
 
-        weight = jax.lax.bitcast_convert_type(tri[_T_WEIGHT], jnp.float32)
-        return impl(blk_docs, blk_freqs, live_parent, norms_stack, caches,
-                    tri[_T_QIDX], tri[_T_BLK], weight, tri[_T_FIDX],
-                    tri[_T_GROUP], tri[_T_TFMODE], *_unpack_qplane(qplane),
-                    *extra, **statics)
+        scores, counts = _dense_accumulate(
+            blk_docs, blk_freqs, head_rows, doc_table, tri, head,
+            Q=n_queries, doc_pad=doc_pad, counters=not simple)
+        if simple:
+            with jax.named_scope("match_coord"):
+                match = (scores > 0.0) & live_parent[None, :]
+        else:
+            scores, match = _dense_semantics(scores, counts, live_parent,
+                                             *_unpack_qplane(qplane))
+        return tail(scores, match, *extra, **statics)
 
     return wrapper
 
@@ -361,8 +424,8 @@ def _get_compiled(n_queries: int, k: int, doc_pad: int, simple: bool = False):
     key = (n_queries, k, doc_pad, simple)
     fn = _compiled_cache.get(key)
     if fn is None:
-        wrapper = _dense_abi(_score_batch_impl, n_queries=n_queries, k=k,
-                             doc_pad=doc_pad, simple=simple)
+        wrapper = _dense_abi(_top_k_tail, n_queries=n_queries, doc_pad=doc_pad,
+                             simple=simple, k=k)
         fn = jax.jit(_named("scoring.dense", wrapper, "simple" if simple else "bool"))
         _compiled_cache[key] = fn
     return fn
@@ -406,20 +469,11 @@ def _bmode_combine(sub, comb, applied, bmode: str):
     raise ValueError(f"unknown boost_mode [{bmode}]")
 
 
-def _fs_rows_impl(blk_docs, blk_freqs, live_parent, norms_stack, caches,
-                  qidx, blk, weight, fidx, group, tfmode, n_must, msm, coord,
-                  g_row, applies_row, max_boost, fboost, min_score,
-                  *, n_queries: int, k: int, doc_pad: int, bmode: str,
-                  use_min_score: bool, no_functions: bool):
-    import jax
+def _fs_rows_impl(scores, match, g_row, applies_row, max_boost, fboost,
+                  min_score, *, k: int, bmode: str, use_min_score: bool,
+                  no_functions: bool):
     import jax.numpy as jnp
 
-    Q = n_queries
-    scores, flat_idx, valid = _dense_accumulate(
-        blk_docs, blk_freqs, norms_stack, caches, qidx, blk, weight, fidx, group,
-        tfmode, Q=Q, doc_pad=doc_pad)
-    scores, match = _dense_semantics(scores, flat_idx, valid, group, live_parent,
-                                     n_must, msm, coord, Q=Q, doc_pad=doc_pad)
     if no_functions:
         out = scores * fboost
     else:
@@ -429,29 +483,16 @@ def _fs_rows_impl(blk_docs, blk_freqs, live_parent, norms_stack, caches,
         out = _bmode_combine(scores, comb, applied, bmode) * fboost
     if use_min_score:
         match = match & (out >= min_score)
-    masked = jnp.where(match, out, jnp.float32(-jnp.inf))
-    top_scores, top_docs = jax.lax.top_k(masked, k)
-    return top_scores, top_docs, match.sum(axis=1, dtype=jnp.int32)
+    return _top_k_tail(out, match, k=k)
 
 
-def _fs_script_impl(blk_docs, blk_freqs, live_parent, norms_stack, caches,
-                    qidx, blk, weight, fidx, group, tfmode, n_must, msm, coord,
-                    col_rows, fmask_row, bad_row, parent_row,
+def _fs_script_impl(scores, match, col_rows, fmask_row, bad_row, parent_row,
                     weight_s, max_boost, fboost, min_score,
-                    *, n_queries: int, k: int, doc_pad: int, script,
-                    used_fields: tuple, bmode: str, use_min_score: bool,
-                    has_filter: bool, has_weight: bool):
-    import jax
+                    *, k: int, script, used_fields: tuple, bmode: str,
+                    use_min_score: bool, has_filter: bool, has_weight: bool):
     import jax.numpy as jnp
 
     from ..script import jax_vectorizer_cls
-
-    Q = n_queries
-    scores, flat_idx, valid = _dense_accumulate(
-        blk_docs, blk_freqs, norms_stack, caches, qidx, blk, weight, fidx, group,
-        tfmode, Q=Q, doc_pad=doc_pad)
-    scores, match = _dense_semantics(scores, flat_idx, valid, group, live_parent,
-                                     n_must, msm, coord, Q=Q, doc_pad=doc_pad)
 
     cols = dict(zip(used_fields, col_rows))
     vec = jax_vectorizer_cls()(script, lambda f: cols[f], scores)
@@ -469,9 +510,7 @@ def _fs_script_impl(blk_docs, blk_freqs, live_parent, norms_stack, caches,
     # per-doc path (which may raise ScriptError) — flag the query so the caller
     # reruns it on the host
     bad = (bad_row[None, :] | (parent_row[None, :] & ~jnp.isfinite(val))).any(axis=1)
-    masked = jnp.where(match, out, jnp.float32(-jnp.inf))
-    top_scores, top_docs = jax.lax.top_k(masked, k)
-    return top_scores, top_docs, match.sum(axis=1, dtype=jnp.int32), bad
+    return (*_top_k_tail(out, match, k=k), bad)
 
 
 def _get_fs_compiled(kind: str, n_queries: int, k: int, doc_pad: int, **statics):
@@ -488,7 +527,7 @@ def _get_fs_compiled(kind: str, n_queries: int, k: int, doc_pad: int, **statics)
         impl = functools.partial(_fs_script_impl, script=script)
     fn = _compiled_cache.get(key)
     if fn is None:
-        wrapper = _dense_abi(impl, n_queries=n_queries, k=k, doc_pad=doc_pad,
+        wrapper = _dense_abi(impl, n_queries=n_queries, doc_pad=doc_pad, k=k,
                              **statics)
         fn = jax.jit(_named("scoring.fs_" + kind, wrapper))
         _compiled_cache[key] = fn
@@ -498,15 +537,36 @@ def _get_fs_compiled(kind: str, n_queries: int, k: int, doc_pad: int, **statics)
 _DENSE_TABLES_MAX = 8  # field tuples kept per segment (FIFO, like agg_stacks)
 
 
-def _stack_args(packed: PackedSegment, batch: TermBatch):
-    """Kernel ABI: the stacked norm-byte and cache tables every dense launch takes
-    (single construction site — the fallback shapes are load-bearing).
+def _doc_table_impl(norms_stack, caches):
+    """[F, doc_pad] f32: each document's norm byte through its field's
+    256-entry table, as ONE flat gather (row*256 + byte)."""
+    import jax.numpy as jnp
 
-    Kept on the packed segment per field tuple, so a warmed launch runs no
-    eager stack program and puts no table: a tuple's norm rows never change
-    once every field has one (execute._ensure_norm_rows adds the missing
-    ones BEFORE this runs), and the cache rows are compared by value — they
-    move with avgdl, as the sparse path's SimTables do."""
+    rows = jnp.arange(norms_stack.shape[0], dtype=jnp.int32)[:, None]
+    return caches.reshape(-1)[rows * 256 + norms_stack.astype(jnp.int32)]
+
+
+@functools.lru_cache(maxsize=None)
+def _get_doc_table_compiled():
+    import jax
+
+    return jax.jit(_named("scoring.doc_table", _doc_table_impl))
+
+
+def _doc_table(packed: PackedSegment, batch: TermBatch):
+    """Kernel ABI: the per-document table every dense launch takes — for each
+    of the batch's fields, the similarity's cache value of every document's
+    norm byte, f32 [F, doc_pad] (single construction site — the fallback
+    shapes are load-bearing). The TPU runs an element-wise gather serially, so
+    the table is made once per document and field, not once per posting, and
+    once per table, not once per launch.
+
+    Kept on the packed segment per field tuple beside the stacked norm rows
+    it is made from, so a warmed launch runs no other program and puts no
+    table: a tuple's norm rows never change once every field has one
+    (execute._ensure_norm_rows adds the missing ones BEFORE this runs), and
+    the cache rows are compared by value — they move with avgdl, as the
+    sparse path's SimTables do."""
     import jax.numpy as jnp
 
     key = tuple(batch.norm_fields)
@@ -514,27 +574,28 @@ def _stack_args(packed: PackedSegment, batch: TermBatch):
               else np.ones((1, 256), np.float32))
     held = packed.dense_tables.get(key)
     if held is not None and np.array_equal(held[0], caches):
-        return held[1], held[2]
+        return held[2]
     norms_stack = held[1] if held is not None else (
         jnp.stack([packed.norm_bytes[f] for f in key]) if key
         else jnp.zeros((1, packed.doc_pad), jnp.uint8))
-    (caches_dev,) = _put_operands(caches)
+    table = _get_doc_table_compiled()(norms_stack, *_put_operands(caches))
     if held is None:
         while len(packed.dense_tables) >= _DENSE_TABLES_MAX:
             packed.dense_tables.pop(next(iter(packed.dense_tables)), None)
-    packed.dense_tables[key] = (caches, norms_stack, caches_dev)
-    return norms_stack, caches_dev
+    packed.dense_tables[key] = (caches, norms_stack, table)
+    return table
 
 
 def _dense_args(packed: PackedSegment, batch: TermBatch, *host):
     """The argument list of a dense launch (the _dense_abi order): resident
-    planes and tables, then the batch's two operand planes and the family's
+    planes and tables, then the batch's three operand planes and the family's
     own `host` operands, all put on the device in one transfer."""
-    norms_stack, caches = _stack_args(packed, batch)
+    doc_table = _doc_table(packed, batch)
+    head_rows = ensure_head_rows(packed)
     _count_dense(packed, batch)
-    return (packed.blk_docs, ensure_blk_freqs(packed), packed.live_parent,
-            norms_stack, caches,
-            *_put_operands(batch.tri, batch.qplane, *host))
+    return (packed.blk_docs, ensure_blk_freqs(packed), head_rows,
+            packed.live_parent, doc_table,
+            *_put_operands(batch.tri, batch.qplane, batch.head, *host))
 
 
 def score_fs_rows_batch(packed: PackedSegment, batch: TermBatch, k: int,
@@ -590,6 +651,14 @@ def score_fs_script_batch(packed: PackedSegment, batch: TermBatch, k: int,
 # device_index.agg_doc_rows — exact for multi-valued fields.
 
 
+@functools.lru_cache(maxsize=None)
+def _no_mask():
+    """The unfiltered sorted / aggregated launch's mask: a broadcastable
+    [1, 1] & [Q, Dpad] no-op, kept on the device so that such a launch neither
+    allocates a full all-true mask nor puts one more operand."""
+    return _put_operands(np.ones((1, 1), dtype=bool))[0]
+
+
 def score_filtered_batch(packed: PackedSegment, batch: TermBatch, k: int, fmask):
     """Dense launch with match-gating filter masks (the device form of the
     reference's FilteredQuery — the filter gates matching, never scoring,
@@ -604,10 +673,9 @@ def score_filtered_batch(packed: PackedSegment, batch: TermBatch, k: int, fmask)
     return scores, docs, total
 
 
-def _dense_sort_impl(blk_docs, blk_freqs, live_parent, norms_stack, caches,
-                     qidx, blk, weight, fidx, group, tfmode, n_must, msm, coord,
+def _dense_sort_impl(scores, match,
                      fmask, key_row,  # f32 [Dpad] ascending-semantics sort keys
-                     *, n_queries: int, k: int, doc_pad: int, descending: bool):
+                     *, k: int, descending: bool):
     """Dense kernel + field-sort top-k: the device form of the reference's
     sorted TopFieldCollector (QueryPhase sorted search). Keys come pre-folded
     per doc (sorting.device_sort_key_row — mode + missing policy baked in);
@@ -616,12 +684,6 @@ def _dense_sort_impl(blk_docs, blk_freqs, live_parent, norms_stack, caches,
     import jax
     import jax.numpy as jnp
 
-    Q = n_queries
-    scores, flat_idx, valid = _dense_accumulate(
-        blk_docs, blk_freqs, norms_stack, caches, qidx, blk, weight, fidx, group,
-        tfmode, Q=Q, doc_pad=doc_pad)
-    scores, match = _dense_semantics(scores, flat_idx, valid, group, live_parent,
-                                     n_must, msm, coord, Q=Q, doc_pad=doc_pad)
     match = match & fmask
     key = jnp.broadcast_to(key_row[None, :], match.shape)
     pad = jnp.float32(-jnp.inf) if descending else jnp.float32(jnp.inf)
@@ -646,8 +708,8 @@ def _get_sorted_compiled(n_queries: int, k: int, doc_pad: int,
     key = ("sorted", n_queries, k, doc_pad, descending)
     fn = _compiled_cache.get(key)
     if fn is None:
-        wrapper = _dense_abi(_dense_sort_impl, n_queries=n_queries, k=k,
-                             doc_pad=doc_pad, descending=descending)
+        wrapper = _dense_abi(_dense_sort_impl, n_queries=n_queries,
+                             doc_pad=doc_pad, k=k, descending=descending)
         fn = jax.jit(_named("scoring.sorted", wrapper))
         _compiled_cache[key] = fn
     return fn
@@ -661,9 +723,8 @@ def score_sorted_batch(packed: PackedSegment, batch: TermBatch, k: int,
     params = (batch.n_queries, min(k, packed.doc_pad), packed.doc_pad,
               descending)
     fn = _get_sorted_compiled(*params)
-    if fmask is None:
-        fmask = np.ones((1, 1), dtype=bool)
-    args = _dense_args(packed, batch, fmask, key_row)
+    args = _dense_args(packed, batch, _no_mask() if fmask is None else fmask,
+                       key_row)
     return _pull(_launch(fn, args, "scoring.sorted", "sorted", params))
 
 
@@ -724,31 +785,18 @@ def _bucket_scatter(match, pdoc, pbucket, nb: int, sub_stack):
     return counts, sub_cnt, sub_stats  # [Q,Fs,nb], [Q,Fs,nb,4]=(sum,min,max,sumsq)
 
 
-def _dense_aggstats_impl(blk_docs, blk_freqs, live_parent, norms_stack, caches,
-                         qidx, blk, weight, fidx, group, tfmode, n_must, msm, coord,
+def _dense_aggstats_impl(scores, match,
                          agg_rows,  # [F, 5, Dpad] f32 (F may be 0)
                          bucket_pairs,  # tuple of (pair_doc, pair_bucket, nb zeros, sub_stack|None)
                          fmask,  # bool [Q, Dpad] — FilteredQuery masks (all-true when none)
-                         *, n_queries: int, k: int, doc_pad: int):
-    import jax
-    import jax.numpy as jnp
-
-    Q = n_queries
-    scores, flat_idx, valid = _dense_accumulate(
-        blk_docs, blk_freqs, norms_stack, caches, qidx, blk, weight, fidx, group,
-        tfmode, Q=Q, doc_pad=doc_pad)
-    scores, match = _dense_semantics(scores, flat_idx, valid, group, live_parent,
-                                     n_must, msm, coord, Q=Q, doc_pad=doc_pad)
+                         *, k: int):
     match = match & fmask
-    masked = jnp.where(match, scores, jnp.float32(-jnp.inf))
-    top_scores, top_docs = jax.lax.top_k(masked, k)
-    total = match.sum(axis=1, dtype=jnp.int32)
     counts, stats = agg_stat_reduction(match, agg_rows)
     bucket_counts = tuple(
         _bucket_scatter(match, pdoc, pbucket, zeros_nb.shape[0], sub_stack)
         for (pdoc, pbucket, zeros_nb, sub_stack) in bucket_pairs
     )
-    return top_scores, top_docs, total, counts, stats, bucket_counts
+    return (*_top_k_tail(scores, match, k=k), counts, stats, bucket_counts)
 
 
 def _get_agg_compiled(n_queries: int, k: int, doc_pad: int, nb_bucket: int,
@@ -763,8 +811,8 @@ def _get_agg_compiled(n_queries: int, k: int, doc_pad: int, nb_bucket: int,
     key = ("aggstats", n_queries, k, doc_pad, nb_bucket, filtered)
     fn = _compiled_cache.get(key)
     if fn is None:
-        wrapper = _dense_abi(_dense_aggstats_impl, n_queries=n_queries, k=k,
-                             doc_pad=doc_pad)
+        wrapper = _dense_abi(_dense_aggstats_impl, n_queries=n_queries,
+                             doc_pad=doc_pad, k=k)
         fn = jax.jit(_named("scoring.aggs", wrapper, "filtered" if filtered else ""))
         _compiled_cache[key] = fn
     return fn
@@ -786,9 +834,7 @@ def score_agg_batch(packed: PackedSegment, batch: TermBatch, k: int,
               filtered)
     fn = _get_agg_compiled(*params)
     if fmask is None:
-        # broadcastable no-op mask: [1, 1] & [Q, Dpad] — avoids allocating and
-        # transferring a full all-true mask on the unfiltered aggs hot path
-        fmask = np.ones((1, 1), dtype=bool)
+        fmask = _no_mask()
     # a host agg stack or mask rides the launch's one put (device arrays
     # pass through it); a raw numpy arg would be an implicit H2D at dispatch
     args = _dense_args(packed, batch, agg_row_stack, tuple(bucket_pairs), fmask)
@@ -800,7 +846,7 @@ def score_agg_batch(packed: PackedSegment, batch: TermBatch, k: int,
 
 def _detect_simple(batch: TermBatch) -> bool:
     """Pure-should all-BM25 batches reduce match to score>0 — see
-    _score_batch_impl(simple=). BM25 is the only mode whose contribution is provably
+    _dense_abi(simple=). BM25 is the only mode whose contribution is provably
     positive for every posting hit ((w·freq)/(freq+cache) with w>0, cache>0): CONST
     clauses can carry weight 0, and TFIDF clauses score 0 on normless fields (norm
     byte 0 → cache 0 — the meta-field case: term _id/_uid/_type), yet both still
@@ -809,9 +855,11 @@ def _detect_simple(batch: TermBatch) -> bool:
     if batch.simple is None:
         batch.simple = bool(
             np.all(np.asarray(batch.group) == GROUP_SHOULD)
+            and np.all(batch.head[_H_GROUP] == GROUP_SHOULD)
             and np.all(np.asarray(batch.msm) <= 1)
             and np.all(np.asarray(batch.n_must) == 0)
             and np.all(np.asarray(batch.tfmode) == MODE_BM25)
+            and np.all(batch.head[_H_TFMODE] == MODE_BM25)
             and (batch.coord is None or np.all(np.asarray(batch.coord) == 1.0)))
     return batch.simple
 
@@ -1315,19 +1363,42 @@ def score_flat_sparse(packed: PackedSegment, clause_lists: list, n_must: np.ndar
 
 def build_term_batch(entries: list, n_queries: int, n_must: np.ndarray, msm: np.ndarray,
                      coord: np.ndarray, norm_fields: list[str], caches: np.ndarray,
-                     nb_pad_row: int) -> TermBatch:
-    """Expand clause block ranges into the flat triple plane + bucket-pad it.
+                     nb_pad_row: int, head_pad_row: int = 0) -> TermBatch:
+    """Lay a batch's clauses out as the head-slot plane and the flat triple
+    plane, bucket-padded.
 
-    `entries` = one (qidx, b0, b1, weight, fidx, group, tfmode) per resolved
-    clause (execute._dense_entries): the clause contributes a triple per block
-    row of [b0, b1), clauses in entry order, blocks ascending. The expansion is
-    one np.repeat of the clause columns, never a Python loop over blocks;
-    padding rows point at `nb_pad_row` (a row of doc_pad sentinels —
-    contributes nothing) with every other column zero."""
-    cols = np.zeros((6, len(entries)), np.int32)
-    nb = np.zeros(len(entries), np.int64)
-    if entries:
-        q, b0, b1, w, f, g, m = zip(*entries)
+    `entries` = one (qidx, b0, b1, weight, fidx, group, tfmode, row) per
+    resolved clause (execute._dense_entries). A clause whose term has a row
+    (row >= 0) takes the next of its query's HEAD_SLOTS, in entry order;
+    padded slots point at `head_pad_row` (the plane's last row, always zero)
+    with every other column zero. Every other clause, and a query's head
+    clauses past its slots, contributes a triple per block row of [b0, b1),
+    clauses in entry order, blocks ascending. The expansion is one np.repeat
+    of the clause columns, never a Python loop over blocks; padding triples
+    point at `nb_pad_row` (a row of doc_pad sentinels — contributes nothing)
+    with every other column zero. M rides the `terms` ladder from
+    TAIL_FLOOR; the coord table's width rides the pow-2 ladder from 4."""
+    used = [0] * n_queries
+    heads, tail = [], []
+    for e in entries:
+        q = e[0]
+        if e[7] < 0 or used[q] == HEAD_SLOTS:
+            tail.append(e)
+        else:
+            heads.append((*e, used[q]))
+            used[q] += 1
+    head = np.zeros((5, n_queries, HEAD_SLOTS), np.int32)
+    head[_H_ROW] = head_pad_row
+    blocks_as_rows = 0
+    if heads:
+        q, b0, b1, w, f, g, m, row, slot = zip(*heads)
+        head[:, q, slot] = (row, np.asarray(w, np.float32).view(np.int32),
+                            f, g, m)
+        blocks_as_rows = sum(b1) - sum(b0)
+    cols = np.zeros((6, len(tail)), np.int32)
+    nb = np.zeros(len(tail), np.int64)
+    if tail:
+        q, b0, b1, w, f, g, m, _row = zip(*tail)
         b0 = np.asarray(b0, np.int64)
         nb = np.maximum(np.asarray(b1, np.int64) - b0, 0)
         cols[_T_QIDX], cols[_T_FIDX], cols[_T_GROUP], cols[_T_TFMODE] = q, f, g, m
@@ -1336,19 +1407,27 @@ def build_term_batch(entries: list, n_queries: int, n_must: np.ndarray, msm: np.
         # the triple index back (below) walks [b0, b1)
         cols[_T_BLK] = b0 - (np.cumsum(nb) - nb)
     n = int(nb.sum())
-    M = _ladder_bucket("terms", max(n, 1), 16)
+    M = _ladder_bucket("terms", max(n, 1), TAIL_FLOOR)
     tri = np.zeros((6, M), np.int32)
     tri[:, :n] = np.repeat(cols, nb, axis=1)
     tri[_T_BLK, :n] += np.arange(n, dtype=np.int32)
     tri[_T_BLK, n:] = nb_pad_row
     n_must, msm = n_must.astype(np.int32), msm.astype(np.int32)
-    coord = coord.astype(np.float32)
+    # the coord table's width is a dimension of the program's key too: up the
+    # pow-2 ladder, each row continued with its last value (what the overlap
+    # clamp reads past a row's end anyway)
+    wide = np.empty((n_queries, _pow2_bucket(coord.shape[1], 4)), np.float32)
+    wide[:, :coord.shape[1]] = coord
+    wide[:, coord.shape[1]:] = coord[:, -1:]
+    coord = wide
     return TermBatch(
         n_queries=n_queries, tri=tri, qplane=_pack_qplane(n_must, msm, coord),
+        head=head,
         qidx=tri[_T_QIDX], blk=tri[_T_BLK], weight=tri[_T_WEIGHT].view(np.float32),
         fidx=tri[_T_FIDX], group=tri[_T_GROUP], tfmode=tri[_T_TFMODE],
         n_must=n_must, msm=msm, coord=coord, norm_fields=norm_fields,
-        caches=caches, blocks_real=n,
+        caches=caches, blocks_real=n, head_slots=len(heads),
+        head_trips=max(used, default=0), blocks_as_rows=blocks_as_rows,
     )
 
 

@@ -700,7 +700,7 @@ def _dispatch_flat_plain(plans: list[FlatPlan], ctx: ShardContext,
         entries = _dense_entries(finals, seg, packed, field_idx)
         fid_of = [sim.fid[f] for f in all_fields]
         clause_lists = [[] for _ in range(Q)]
-        for (qi, b0, b1, w, fi, g, mode) in entries:
+        for (qi, b0, b1, w, fi, g, mode, _row) in entries:
             clause_lists[qi].append((b0, b1, w, g, mode == MODE_CONST, fid_of[fi]))
         # compile_tag: backend compiles triggered by these launches land in
         # the capacity ledger's per-family attribution (common/jaxenv).
@@ -902,32 +902,52 @@ def _merge_seg_hits(seg_hits, totals, Q: int, k: int,
 
 def _ensure_norm_rows(packed, all_fields, breaker=None):
     """Dense-launch prologue (every dense path funnels through here): fault in
-    the lazy f32 freqs plane under the fielddata `breaker` (the blk_freqs-drop
-    rule — sparse-only segments never allocated it), and zero-fill norms_stack
-    rows for queried fields this segment never indexed."""
+    the lazy f32 freqs plane and the head-term rows under the fielddata
+    `breaker` (the blk_freqs-drop rule — sparse-only segments never allocated
+    them), and zero-fill norms_stack rows for queried fields this segment
+    never indexed."""
     import jax.numpy as jnp
 
-    from ..ops.device_index import ensure_blk_freqs
+    from ..ops.device_index import ensure_blk_freqs, ensure_head_rows
 
     ensure_blk_freqs(packed, breaker=breaker)
+    ensure_head_rows(packed, breaker=breaker)
     for f in all_fields:
         if f not in packed.norm_bytes:
             packed.norm_bytes[f] = jnp.zeros(packed.doc_pad, dtype=jnp.uint8)
 
 
 def _dense_entries(finals, seg, packed, field_idx) -> list:
-    """One (qidx, b0, b1, weight, fidx, group, mode) record per clause whose
-    term this segment holds — [b0, b1) its block rows in the packed planes,
-    qidx = position in `finals`. scoring.build_term_batch expands the ranges."""
+    """One (qidx, b0, b1, weight, fidx, group, mode, row) record per clause
+    whose term this segment holds — [b0, b1) its block rows in the packed
+    planes, `row` its row of the segment's head_rows (-1: the term has none),
+    qidx = position in `finals`. scoring.build_term_batch gives a dense launch
+    the row where there is one and expands the range where there is not; the
+    sparse planner reads the ranges alone."""
     entries = []
+    row_of = packed.head_row_of
     for qi, (resolved, _f, _c, _coord) in enumerate(finals):
         for (f, t, w, _fi, g, mode, df) in resolved:
             tid = seg.term_id(f, t)
             if tid is None:
                 continue
             b0, b1 = packed.blocks_for_term(tid)
-            entries.append((qi, b0, b1, w, field_idx[f], g, mode))
+            entries.append((qi, b0, b1, w, field_idx[f], g, mode,
+                            row_of.get(tid, -1)))
     return entries
+
+
+def _term_batch(entries, Q, n_must, msm, coord_tbl, all_fields, caches_stack,
+                packed):
+    """scoring.build_term_batch over `packed`'s planes (after
+    _ensure_norm_rows): padding triples point at its all-sentinel block row,
+    padding head slots at its head plane's last row."""
+    from ..ops.scoring import build_term_batch
+
+    return build_term_batch(entries, Q, n_must, msm, coord_tbl, all_fields,
+                            caches_stack,
+                            nb_pad_row=packed.blk_docs.shape[0] - 1,
+                            head_pad_row=packed.head_rows.shape[0] - 1)
 
 
 def _postings_scanned(finals, seg) -> int:
@@ -945,7 +965,7 @@ def _launch_dense_fallback(overflow, entries, all_fields, caches_stack,
     _dense_entries records. Returns (sub indices, device result triple,
     blocks the launch named) for the merge half, or None when no entries
     resolved."""
-    from ..ops.scoring import build_term_batch, score_term_batch_async
+    from ..ops.scoring import score_term_batch_async
 
     _ensure_norm_rows(packed, all_fields, breaker=breaker)
     row = {qi: i for i, qi in enumerate(overflow)}
@@ -953,9 +973,8 @@ def _launch_dense_fallback(overflow, entries, all_fields, caches_stack,
     if not entries:
         return None
     sub = np.asarray(overflow, dtype=np.int64)
-    batch = build_term_batch(entries, len(overflow), n_must[sub], msm[sub],
-                             coord_tbl[sub], list(all_fields), caches_stack,
-                             nb_pad_row=packed.blk_docs.shape[0] - 1)
+    batch = _term_batch(entries, len(overflow), n_must[sub], msm[sub],
+                        coord_tbl[sub], list(all_fields), caches_stack, packed)
     return sub, score_term_batch_async(packed, batch, k), batch.blocks_real
 
 
@@ -984,8 +1003,7 @@ def _execute_flat_fs(plans: list[FlatPlan], ctx: ShardContext, k: int) -> list[T
     docs) rerun on the host so error semantics are preserved."""
     from ..common.errors import ScriptError
     from ..ops.device_index import packed_for
-    from ..ops.scoring import (build_term_batch, score_fs_rows_batch,
-                               score_fs_script_batch)
+    from ..ops.scoring import score_fs_rows_batch, score_fs_script_batch
     from ..script import compile_script, script_vector_info
     from .functions import _column_first_value, combined_doc_rows
     from .filters import segment_mask
@@ -1021,9 +1039,8 @@ def _execute_flat_fs(plans: list[FlatPlan], ctx: ShardContext, k: int) -> list[T
             _ensure_norm_rows(packed, all_fields,
                               breaker=ctx.breaker("fielddata"))
             entries = _dense_entries(finals, seg, packed, field_idx)
-            batch = build_term_batch(entries, Q, n_must, msm, coord_tbl,
-                                     list(all_fields), caches_stack,
-                                     nb_pad_row=packed.blk_docs.shape[0] - 1)
+            batch = _term_batch(entries, Q, n_must, msm, coord_tbl,
+                                list(all_fields), caches_stack, packed)
             D, doc_pad = seg.doc_count, packed.doc_pad
             if kind == "rows":
                 if fsq.functions:
@@ -1144,7 +1161,7 @@ def _execute_flat_filtered(plans: list[FlatPlan], ctx: ShardContext,
     dense kernel. Scores/weights are untouched, so sub-query scoring parity is
     inherited from the plain path."""
     from ..ops.device_index import packed_for
-    from ..ops.scoring import build_term_batch, score_filtered_batch
+    from ..ops.scoring import score_filtered_batch
 
     if len(plans) > _FS_CHUNK:
         out: list[TopDocs] = []
@@ -1169,9 +1186,8 @@ def _execute_flat_filtered(plans: list[FlatPlan], ctx: ShardContext,
         fmask = _filter_mask_matrix([plan.filt for plan in plans], seg,
                                     packed, ctx)
         entries = _dense_entries(finals, seg, packed, field_idx)
-        batch = build_term_batch(entries, Q, n_must, msm, coord_tbl,
-                                 list(all_fields), caches_stack,
-                                 nb_pad_row=packed.blk_docs.shape[0] - 1)
+        batch = _term_batch(entries, Q, n_must, msm, coord_tbl,
+                            list(all_fields), caches_stack, packed)
         with compile_tag("filtered"):
             scores, docs, tq = score_filtered_batch(packed, batch, k, fmask)
         totals += tq
@@ -1191,7 +1207,7 @@ def execute_flat_sorted(plan: FlatPlan, ctx: ShardContext, k: int, spec):
     (sorting.device_sort_key_row). Ordering: (key asc/desc, global doc asc) —
     the host lexsort order."""
     from ..ops.device_index import packed_for
-    from ..ops.scoring import build_term_batch, score_sorted_batch
+    from ..ops.scoring import score_sorted_batch
     from .sorting import device_sort_key_row
 
     finals = [finalize_flat(plan, ctx)]
@@ -1219,9 +1235,8 @@ def execute_flat_sorted(plan: FlatPlan, ctx: ShardContext, k: int, spec):
         if plan.filt is not None:
             fmask = _filter_mask_matrix([plan.filt], seg, packed, ctx)
         entries = _dense_entries(finals, seg, packed, field_idx)
-        batch = build_term_batch(entries, 1, n_must, msm, coord_tbl,
-                                 list(all_fields), caches_stack,
-                                 nb_pad_row=packed.blk_docs.shape[0] - 1)
+        batch = _term_batch(entries, 1, n_must, msm, coord_tbl,
+                            list(all_fields), caches_stack, packed)
         with compile_tag("sorted"):
             keys, docs, scores, qmax, tq = score_sorted_batch(
                 packed, batch, max(k, 1), key_row, spec.reverse, fmask=fmask)
@@ -1257,7 +1272,7 @@ def execute_flat_aggs(plan: FlatPlan, ctx: ShardContext, k: int,
     import jax.numpy as jnp
 
     from ..ops.device_index import _pow2_bucket, ensure_agg_rows, packed_for
-    from ..ops.scoring import build_term_batch, score_agg_batch
+    from ..ops.scoring import score_agg_batch
     from .aggregations import bucket_cache_key, bucket_cols_for
 
     finals = [finalize_flat(plan, ctx)]
@@ -1307,9 +1322,8 @@ def execute_flat_aggs(plan: FlatPlan, ctx: ShardContext, k: int,
             pair_args.append((dev[0], dev[1], dev[2], sub_stack))
             seg_keys.append(keys)
         entries = _dense_entries(finals, seg, packed, field_idx)
-        batch = build_term_batch(entries, 1, n_must, msm, coord_tbl,
-                                 list(all_fields), caches_stack,
-                                 nb_pad_row=packed.blk_docs.shape[0] - 1)
+        batch = _term_batch(entries, 1, n_must, msm, coord_tbl,
+                            list(all_fields), caches_stack, packed)
         fmask = None
         if plan.filt is not None:
             fmask = _filter_mask_matrix([plan.filt], seg, packed, ctx)

@@ -5,7 +5,8 @@ attached (`jax.experimental.topologies`). These cases compile the launches
 `chip_smoke.py` produces at the r03 shape (100k documents, one force-merged segment:
 524,288 block rows, doc_pad 131,072; read from the rehearsal's compile manifest) for a
 `v5e:2x2` topology: the sparse launch at its largest bucket in both variants, the dense
-launch an overflow query takes, and the mesh program of `chip_smoke.py --chips 4` on a
+launch an overflow query takes (64 head rows, three searches, both variants), and the
+mesh program of `chip_smoke.py --chips 4` on a
 four-device `Mesh`. A compile that passes is not a chip run; it says the chip's
 compiler accepts the program and how much device memory it plans.
 
@@ -93,26 +94,32 @@ def test_sparse_launch_compiles_for_v5e(one_chip, TB, k, passes, simple, coord_w
     assert "tpu_custom_call" not in compiled.as_text()  # the composed launch, no kernel
 
 
-def test_dense_overflow_launch_compiles_for_v5e(one_chip):
-    """One query whose terms span more than tb_max blocks takes the dense launch:
-    a [Q, doc_pad] f32 accumulator over the lazily faulted f32 freqs plane."""
+@pytest.mark.parametrize("simple", [True, False], ids=["simple", "bool"])
+def test_dense_overflow_launch_compiles_for_v5e(one_chip, simple):
+    """Queries whose terms span more than tb_max blocks take the dense launch: a
+    [Q, doc_pad] f32 accumulator that the head terms' rows are added to, then the
+    blocks that are left scattered from the lazily faulted f32 freqs plane."""
     from elasticsearch_tpu.common.jaxenv import compile_tag
-    from elasticsearch_tpu.ops.scoring import _get_compiled
+    from elasticsearch_tpu.ops.scoring import HEAD_SLOTS, _get_compiled
 
-    Q, E = 1, 2048  # (query, block) entries, bucketed
+    Q, E, H = 3, 256, 64  # searches, (query, block) entries of the tails, head rows
     args = _shapes(
         one_chip,
         ((ROWS, BLOCK), "int32"), ((ROWS, BLOCK), "float32"),  # docs, f32 freqs
-        ((DOC_PAD,), "bool"), ((1, DOC_PAD), "uint8"), ((1, 256), "float32"),
-        # the launch's two operand planes (TermBatch.tri / .qplane)
-        ((6, E), "int32"), ((Q, 2 + 5), "int32"))
-    fn = _get_compiled(Q, 128, DOC_PAD, True)  # the launch site's own program
+        ((H, DOC_PAD), "uint8"),  # head rows, the tf plane's dtype
+        ((DOC_PAD,), "bool"), ((1, DOC_PAD), "float32"),  # live, per-document table
+        # the launch's three operand planes (TermBatch.tri / .qplane / .head)
+        ((6, E), "int32"), ((Q, 2 + 8), "int32"), ((5, Q, HEAD_SLOTS), "int32"))
+    fn = _get_compiled(Q, 16, DOC_PAD, simple)  # the launch site's own program
     with compile_tag("dense"):
         compiled = fn.lower(*args).compile()
     mem = compiled.memory_analysis()
     # docs i32 + freqs f32 planes dominate the arguments: 2 * 524288 * 128 * 4 bytes
-    assert mem.argument_size_in_bytes >= 2 * ROWS * BLOCK * 4
+    assert mem.argument_size_in_bytes >= 2 * ROWS * BLOCK * 4 + H * DOC_PAD
     assert mem.temp_size_in_bytes < 1 << 30
+    # the rows are added in a loop whose trip count is data: one program whatever
+    # the number of head clauses
+    assert "while" in compiled.as_text()
 
 
 def test_mesh_program_compiles_for_four_v5e_chips(topo):

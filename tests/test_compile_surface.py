@@ -271,10 +271,11 @@ def test_every_launch_site_lowers_under_its_own_name():
 
     nb, dpad, m, q, f = 16, 256, 32, 2, 1
     dense = (s((nb, 128), jnp.int32), s((nb, 128), jnp.float32),
-             s((dpad,), jnp.bool_), s((f, dpad), jnp.uint8),
-             s((f, 256), jnp.float32),
-             # the launch's two operand planes (TermBatch.tri / .qplane)
-             s((6, m), jnp.int32), s((q, 2 + 2), jnp.int32))
+             s((4, dpad), jnp.uint8),  # head rows
+             s((dpad,), jnp.bool_), s((f, dpad), jnp.float32),  # doc table
+             # the launch's three operand planes (TermBatch.tri / .qplane / .head)
+             s((6, m), jnp.int32), s((q, 2 + 2), jnp.int32),
+             s((5, q, scoring.HEAD_SLOTS), jnp.int32))
     scalar = s((), jnp.float32)
     sparse = (s((nb, 128), jnp.int32), s((nb, 128), jnp.uint8),
               s((nb, 128), jnp.uint8), s((f, 256), jnp.float32),
@@ -297,6 +298,8 @@ def test_every_launch_site_lowers_under_its_own_name():
         (scoring._get_agg_compiled(q, 10, dpad, 0, True), dense + no_aggs),
         (scoring._get_sparse_compiled(8, 8, 10, dpad, 1, True, False, 2),
          sparse),
+        (scoring._get_doc_table_compiled(),
+         (s((f, dpad), jnp.uint8), s((f, 256), jnp.float32))),
         (scoring._get_concat_compiled(dpad, "u8"),
          (term, term, s((2, 4), jnp.int32), s((1, 4), jnp.int32),
           s((1,), jnp.int32), s((1,), jnp.int32),
@@ -308,10 +311,15 @@ def test_every_launch_site_lowers_under_its_own_name():
         "jit_estpu_scoring_dense_simple", "jit_estpu_scoring_dense_bool",
         "jit_estpu_scoring_fs_rows", "jit_estpu_scoring_sorted",
         "jit_estpu_scoring_aggs", "jit_estpu_scoring_aggs_filtered",
-        "jit_estpu_scoring_sparse", "jit_estpu_scoring_concat"], names
+        "jit_estpu_scoring_sparse", "jit_estpu_scoring_doc_table",
+        "jit_estpu_scoring_concat"], names
     # the stages inside them carry names too (metadata only: no operation is
     # added, removed or reordered by a named_scope)
     text = sites[6][0].lower(*sparse).as_text(debug_info=True)
     for scope in ("gather_decode", "sort_by_doc", "segment_sum",
+                  "match_coord", "top_k"):
+        assert scope in text, scope
+    text = sites[1][0].lower(*dense).as_text(debug_info=True)
+    for scope in ("head_rows", "gather_decode", "scatter_add",
                   "match_coord", "top_k"):
         assert scope in text, scope

@@ -2,9 +2,10 @@
 
 Counts and equality only, on the CPU: the range-built TermBatch equals,
 array for array, one built by the per-block loops it replaced (kept HERE as
-the reference), and a warmed plain batch with a dense overflow puts at most
-two host arrays on the device per launch and compiles nothing, under the
-transfer guard."""
+the reference), and a warmed plain batch with a dense overflow puts its
+operand planes on the device in one transfer a launch (two for the sparse
+launch, three for the dense one) and compiles nothing, under the transfer
+guard."""
 
 import functools
 
@@ -63,12 +64,12 @@ def _plan(ctx, body):
 def _per_block(entries):
     """execute._dense_entries' inner loop: one 6-tuple per (clause, block)."""
     return [(q, b, w, f, g, m)
-            for (q, b0, b1, w, f, g, m) in entries for b in range(b0, b1)]
+            for (q, b0, b1, w, f, g, m, _row) in entries for b in range(b0, b1)]
 
 
 def _reference_batch(blocks, n_must, msm, coord, nb_pad_row):
     """scoring.build_term_batch storing one scalar at a time."""
-    M = _ladder_bucket("terms", max(len(blocks), 1), 16)
+    M = _ladder_bucket("terms", max(len(blocks), 1), scoring.TAIL_FLOOR)
     qidx = np.zeros(M, np.int32)
     blk = np.full(M, nb_pad_row, np.int32)
     weight = np.zeros(M, np.float32)
@@ -79,8 +80,19 @@ def _reference_batch(blocks, n_must, msm, coord, nb_pad_row):
         qidx[i], blk[i], weight[i], fidx[i], group[i], tfmode[i] = q, b, w, f, g, m
     return dict(qidx=qidx, blk=blk, weight=weight, fidx=fidx, group=group,
                 tfmode=tfmode, n_must=n_must.astype(np.int32),
-                msm=msm.astype(np.int32), coord=coord.astype(np.float32),
+                msm=msm.astype(np.int32), coord=_widened(coord),
                 blocks_real=len(blocks))
+
+
+def _widened(coord):
+    """The coord table up the pow-2 ladder from 4 columns, each row continued
+    with its last value."""
+    width = 4
+    while width < coord.shape[1]:
+        width *= 2
+    out = np.repeat(coord[:, -1:].astype(np.float32), width, axis=1)
+    out[:, : coord.shape[1]] = coord
+    return out
 
 
 def _assert_same_batch(batch, ref):
@@ -106,8 +118,9 @@ def _assert_same_batch(batch, ref):
 
 S, MU, NOT = GROUP_SHOULD, GROUP_MUST, GROUP_MUST_NOT
 # (qidx, b0, b1, weight, fidx, group, mode) per clause; weights that float32
-# cannot hold exactly, as finalize_flat's float64 products are
-CASES = {
+# cannot hold exactly, as finalize_flat's float64 products are. No clause's
+# term has a head row here (tests/test_head_rows.py has those).
+_CASES = {
     "one_clause": (1, [(0, 3, 8, 1.7, 0, S, MODE_BM25)]),
     "many_clauses_across_queries": (3, [
         (0, 0, 5, 0.1 * 3.7, 0, S, MODE_BM25),
@@ -127,12 +140,14 @@ CASES = {
         (0, 20, 21, 2.0, 1, S, MODE_CONST),
         (1, 30, 33, 0.0, 1, S, MODE_CONST),
         (1, 5, 6, 4.4, 0, NOT, MODE_TFIDF)]),
-    # 16 + 48 = 64 blocks: a rung of the pow-2 ladder, so no pad row
+    # 16 + 240 = 256 blocks: the foot of the ladder, so no pad row
     "exact_ladder_fit": (2, [
         (0, 0, 16, 1.9, 0, S, MODE_BM25),
-        (1, 16, 64, 0.3, 0, S, MODE_BM25)]),
+        (1, 16, 256, 0.3, 0, S, MODE_BM25)]),
     "no_clause_resolved": (1, []),
 }
+CASES = {name: (Q, [(*e, -1) for e in entries])
+         for name, (Q, entries) in _CASES.items()}
 
 
 @pytest.mark.parametrize("case", list(CASES))
@@ -143,14 +158,16 @@ def test_range_built_batch_equals_the_per_block_one(case):
     coord = np.linspace(0.25, 1.0, Q * 3, dtype=np.float64).reshape(Q, 3)
     caches = np.ones((2, 256), np.float32)
     batch = build_term_batch(entries, Q, n_must, msm, coord, ["a", "b"],
-                             caches, nb_pad_row=255)
-    ref = _reference_batch(_per_block(entries), n_must, msm, coord, 255)
+                             caches, nb_pad_row=1023)
+    ref = _reference_batch(_per_block(entries), n_must, msm, coord, 1023)
     _assert_same_batch(batch, ref)
+    assert batch.head_slots == batch.blocks_as_rows == 0
+    assert not batch.head.any()  # every slot the pad row (0 here), weight 0
     if case == "exact_ladder_fit":
-        assert batch.blocks_real == len(batch.blk) == 64
+        assert batch.blocks_real == len(batch.blk) == 256
     else:
         assert batch.blocks_real < len(batch.blk)
-        assert np.all(batch.blk[batch.blocks_real:] == 255)
+        assert np.all(batch.blk[batch.blocks_real:] == 1023)
 
 
 def test_a_term_missing_from_the_segment_names_no_block(shard_ctx):
@@ -181,6 +198,10 @@ def test_a_term_missing_from_the_segment_names_no_block(shard_ctx):
     entries = _dense_entries(finals, seg, packed, field_idx)
     assert len(entries) == 4  # common, rare | half, rare: two terms resolve nowhere
     assert sum(b1 - b0 for (_q, b0, b1, *_r) in entries) == len(blocks) == 10
+    # "common" and "half" match enough of the segment to have a row; the
+    # blocks are compared here, so the batch is built as if none had
+    assert [e[7] >= 0 for e in entries] == [True, False, True, False]
+    entries = [(*e[:7], -1) for e in entries]
     batch = build_term_batch(entries, 2, n_must, msm, coord_tbl,
                              list(all_fields), caches_stack, nb_pad_row=pad_row)
     _assert_same_batch(batch, _reference_batch(blocks, n_must, msm, coord_tbl,
@@ -192,12 +213,12 @@ def test_a_term_missing_from_the_segment_names_no_block(shard_ctx):
 # ---------------------------------------------------------------------------
 
 
-def test_warmed_overflow_batch_puts_two_operands_a_launch_and_compiles_nothing(
+def test_warmed_overflow_batch_puts_its_planes_once_a_launch_and_compiles_nothing(
         shard_ctx, monkeypatch):
     """A plain batch of which one query overflows the sparse planner: the
-    sparse launch and the dense launch each hand the device their two operand
-    planes in one explicit put, and nothing else (the stacked tables are
-    kept on the segment), with no compile event once warmed."""
+    sparse launch hands the device its two operand planes and the dense
+    launch its three in one explicit put each, and nothing else (the stacked
+    tables are kept on the segment), with no compile event once warmed."""
     # steer the overflow in the test: two blocks a query, so the searches of
     # "common" (5 blocks) take the dense program on 600 documents
     monkeypatch.setattr(scoring, "launch_flat_sparse", functools.partial(
@@ -215,7 +236,7 @@ def test_warmed_overflow_batch_puts_two_operands_a_launch_and_compiles_nothing(
     sparse = after["launches_sparse"] - before["launches_sparse"]
     dense = after["launches_dense"] - before["launches_dense"]
     assert sparse >= 1 and dense == 1
-    assert after["operand_puts"] - before["operand_puts"] == 2 * (sparse + dense)
+    assert after["operand_puts"] - before["operand_puts"] == 2 * sparse + 3 * dense
     for w, a in zip(warm, again):
         assert a.hits == w.hits and a.total == w.total
     # and the overflowed searches answer what the sparse program answers
