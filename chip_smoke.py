@@ -15,6 +15,8 @@ host fallback — served every search.
 
 `--chips 4` (run by hand, never by the driver): only the co-located multi-shard path,
 one index of 4 shards served by one shard_map program, and what it is compared with.
+It is the bring-up smoke of that path and claims no speed: the four-chip deployment
+is measured by the benchmark's cell `passage.mesh4.single` (`benchmark/run.py`).
 
 Every line printed is one JSON object. The last line is the contract:
 `{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}` and exit 0

@@ -1,7 +1,7 @@
 """Compile warming: shape-driven executable pre-warming + autotuned bucket ladders.
 
 Every first sighting of a (plan family × bucket shape) pays a full XLA compile on
-the serving path — BENCH_WRITES' merge-window p99 cliff. This module is the
+the serving path: a stall of the one drainer. This module is the
 off-path answer (ROADMAP item 5), three legs sharing one registry:
 
   * **WarmSpec registry** — every kernel launch site records, once per distinct
@@ -663,7 +663,7 @@ class CompileWarmRegistry:
         return loaded
 
     def reset(self) -> None:
-        """Test/bench hook: forget ALL in-process warm state. Paired with
+        """Test hook: forget ALL in-process warm state. Paired with
         jax.clear_caches() (and a LADDERS.reset()) this simulates a process
         restart inside one interpreter — the restarted 'node' must re-earn
         its warmth from the manifest, exactly like a real rolling restart."""

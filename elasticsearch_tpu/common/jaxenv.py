@@ -49,32 +49,6 @@ def force_cpu_platform(n_devices: int | None = None) -> None:
     jax.config.update("jax_platforms", "cpu")
 
 
-def cpu_requested() -> bool:
-    """Whether the CALLER asked for the CPU backend (JAX_PLATFORMS=cpu in the
-    environment) — the only way a measurement entry point runs off a TPU."""
-    return os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
-
-
-def require_accelerator(what: str) -> dict:
-    """The measurement entry points' one device question, asked once in the
-    process that measures: returns {"platform", "device_kind", "device_count"}
-    as jax reports them. Off a TPU it raises SystemExit — a measurement path that
-    finds no chip fails, it does not fall back — unless the CALLER set
-    JAX_PLATFORMS=cpu, in which case the run goes on and is labelled "cpu"."""
-    import jax
-
-    devices = jax.devices()
-    dev = {"platform": devices[0].platform, "device_kind": devices[0].device_kind,
-           "device_count": len(devices)}
-    print(f"# {what}: platform={dev['platform']} device_kind={dev['device_kind']} "
-          f"device_count={dev['device_count']}", file=sys.stderr, flush=True)
-    if dev["platform"] != "tpu" and not cpu_requested():
-        raise SystemExit(
-            f"{what}: no TPU (jax reports {dev['platform']!r}) and the caller did "
-            "not set JAX_PLATFORMS=cpu — refusing to measure on a fallback")
-    return dev
-
-
 # The persistent compilation cache has ONE placement rule: where the caller set
 # JAX_COMPILATION_CACHE_DIR, jax has already read it and no code sets another
 # directory; where it is unset, the cache lives at <checkout>/.jax_cache. The

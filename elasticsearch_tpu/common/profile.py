@@ -3,7 +3,7 @@
 Where tracing (common/tracing.py) answers *where* a request spent its time
 (spans across REST → coordinator → shard → batcher → device pull), the
 profiler answers *why*: which clause, which segment, which execution path
-(fused Pallas vs composed sparse vs dense fallback vs host scorer), which
+(composed sparse vs dense fallback vs host scorer), which
 cache miss (segment pack, SimTables swap, lazy dense-plane fault, scratch
 checkout) made it expensive, and how many postings/blocks/bytes the plan
 actually touched. The response shape is the reference's `profile` section:

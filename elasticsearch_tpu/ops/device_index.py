@@ -88,7 +88,7 @@ def _ladder_bucket(dim: str, n: int, minimum: int) -> int:
 def expand_ranges(starts: np.ndarray, counts: np.ndarray) -> np.ndarray:
     """Flatten half-open ranges [starts[i], starts[i]+counts[i]) into one int64 array
     — the CSR expansion idiom (repeat + within-range offset) shared by segment
-    packing, the mesh assembler, and the bench."""
+    packing and the mesh assembler."""
     total = int(counts.sum())
     excl = np.zeros(len(counts), dtype=np.int64)
     if len(counts) > 1:
@@ -293,8 +293,7 @@ def pack_estimate_bytes(seg: FrozenSegment) -> int:
 def packed_resident_bytes(packed: PackedSegment) -> int:
     """Actual device-RESIDENT postings-plane bytes of a packed segment (docs +
     tf + nb, plus the dense f32 plane and the head rows if they have been
-    faulted in) — what the bench `kernel` row and the breaker-estimate test
-    compare against."""
+    faulted in) — what the breaker-estimate test compares against."""
     total = 0
     for plane in (packed.blk_docs, packed.blk_tf, packed.blk_nb,
                   packed.blk_freqs, packed.head_rows):
@@ -1241,7 +1240,7 @@ def tfn_values(freqs: np.ndarray, nb: np.ndarray, cache: np.ndarray,
     """The per-posting tfn formula — the single HOST definition of what the
     quantized scan computes on device (ops/scoring.sparse_candidates decodes
     blk_tf/blk_nb and applies exactly this, f32 op order included). Kept as
-    the reference the parity tests and the bench check against."""
+    the reference the parity tests check against."""
     cv = cache[nb]
     if mode == TFN_BM25:
         return (freqs / (freqs + cv)).astype(np.float32)

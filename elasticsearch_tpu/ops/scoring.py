@@ -866,7 +866,7 @@ def _detect_simple(batch: TermBatch) -> bool:
 
 def score_term_batch_async(packed: PackedSegment, batch: TermBatch, k: int):
     """Like score_term_batch but returns device arrays without syncing — callers that
-    pipeline many batches block once at the end (the serving/bench throughput path)."""
+    pipeline many batches block once at the end (the serving throughput path)."""
     params = (batch.n_queries, min(k, packed.doc_pad), packed.doc_pad,
               _detect_simple(batch))
     return _launch(_get_compiled(*params), _dense_args(packed, batch),
@@ -891,7 +891,7 @@ def finalize_score_result(scores: np.ndarray, docs: np.ndarray, total: np.ndarra
 
 
 # ---------------------------------------------------------------------------
-# sparse candidate-centric path (the serving/bench hot path)
+# sparse candidate-centric path (the serving hot path)
 # ---------------------------------------------------------------------------
 #
 # The dense kernel above scatter-adds into a [Q, doc_pad] accumulator — measured on the
@@ -1052,11 +1052,7 @@ def sparse_reduce(docs, contrib, cnt, n_must, msm, coord,
     """The reduction half: sort candidates by doc id, segment-sum duplicate
     docs (log2 doubling), bool semantics on the folded counters, top-k.
     [Qb, P] in → ([Qb, k] scores, [Qb, k] docs, [Qb] totals).
-
-    ONE definition executed by BOTH the composed-jnp path and the fused Pallas
-    kernel's final grid step (pallas_kernels.sparse_score runs it on the VMEM
-    accumulator with Qb=1) — bitwise parity between the two paths is by
-    construction, not by test tolerance. `cnt` may be None when simple."""
+    `cnt` may be None when simple."""
     import jax
     import jax.numpy as jnp
 
@@ -1124,26 +1120,11 @@ def sparse_reduce(docs, contrib, cnt, n_must, msm, coord,
 def _sparse_impl(blk_docs, blk_tf, blk_nb, caches, modes,
                  qblk, qw, qconst, qcnt, qfid, n_must, msm, coord,
                  *, k: int, doc_pad: int, passes: int, simple: bool,
-                 use_coord: bool, use_pallas: bool = False):
+                 use_coord: bool):
     import jax.numpy as jnp
 
     Qb, TB = qblk.shape
     P = TB * BLOCK
-    if use_pallas:
-        # fully-fused Pallas kernel: scalar-prefetch streaming of the quantized
-        # block rows, in-scan decode, counter fold and per-query VMEM candidate
-        # accumulator — the [Qb, P] matrix never round-trips HBM
-        # (ops/pallas_kernels.py sparse_score; parity by shared sparse_reduce)
-        from .pallas_kernels import sparse_score
-
-        # jnp.take (not advanced indexing): this may run EAGERLY in tests, and
-        # eager fancy indexing routes a scalar through an implicit transfer
-        # the transfer_guard("disallow") sanitizer rejects
-        return sparse_score(
-            qblk, qw, qconst, qcnt, qfid, jnp.take(modes, qfid), n_must, msm,
-            coord, blk_docs, blk_tf, blk_nb, caches,
-            k=k, doc_pad=doc_pad, passes=passes, simple=simple,
-            use_coord=use_coord)
     docs, contrib, valid = sparse_candidates(
         blk_docs, blk_tf, blk_nb, caches, modes, qblk, qw, qconst, qfid,
         doc_pad=doc_pad)
@@ -1160,11 +1141,7 @@ def _get_sparse_compiled(Qb: int, TB: int, k: int, doc_pad: int, passes: int,
                          simple: bool, use_coord: bool, coord_w: int):
     import jax
 
-    from .pallas_kernels import estpu_pallas_enabled
-
-    use_pallas = estpu_pallas_enabled()
-    key = ("sparse", Qb, TB, k, doc_pad, passes, simple, use_coord, coord_w,
-           use_pallas)
+    key = ("sparse", Qb, TB, k, doc_pad, passes, simple, use_coord, coord_w)
     fn = _compiled_cache.get(key)
     if fn is None:
         def wrapper(blk_docs, blk_tf, blk_nb, caches, modes, slots, qplane):
@@ -1177,7 +1154,7 @@ def _get_sparse_compiled(Qb: int, TB: int, k: int, doc_pad: int, passes: int,
                 slots[_S_QBLK], qw, slots[_S_QCONST] != 0, slots[_S_QCNT],
                 slots[_S_QFID], *_unpack_qplane(qplane),
                 k=k, doc_pad=doc_pad, passes=passes, simple=simple,
-                use_coord=use_coord, use_pallas=use_pallas)
+                use_coord=use_coord)
 
         fn = jax.jit(_named("scoring.sparse", wrapper))
         _compiled_cache[key] = fn
@@ -1221,7 +1198,7 @@ def plan_sparse_buckets(clause_lists: list, n_must: np.ndarray, msm: np.ndarray,
     `scratch` (the packed segment's SparseScratchPool) supplies the [Qb, TB]
     staging arrays; callers that pass one MUST give the arrays back after the
     device launch (launch_flat_sparse does) — None allocates fresh arrays the
-    caller owns outright (the bench keeps its batches alive across runs)."""
+    caller owns outright."""
     Q = len(clause_lists)
     tb_q = np.array([sum(b1 - b0 for (b0, b1, _w, _g, _c, _fi) in cl)
                      for cl in clause_lists], dtype=np.int64)
@@ -1335,30 +1312,6 @@ def collect_flat_sparse(launches: list, pulled: list, Q: int, k: int,
         docs[qid, :kk] = d[rows]
         totals[qid] = t[rows]
     return scores, docs, totals
-
-
-def score_flat_sparse(packed: PackedSegment, clause_lists: list, n_must: np.ndarray,
-                      msm: np.ndarray, coord: np.ndarray, k: int, *,
-                      simple: bool = False, tb_max: int = 512, breaker=None,
-                      sim=None):
-    """Score a whole flat-query batch through the sparse path: plan buckets, launch all
-    (pipelined), collect into [Q, k] host arrays.
-
-    Returns (scores, docs, totals, overflow_qids); rows for zero-block and overflow
-    queries are empty (caller handles overflow via the dense kernel)."""
-    import jax
-
-    Q = len(clause_lists)
-    launches, overflow, release = launch_flat_sparse(
-        packed, clause_lists, n_must, msm, coord, k, simple=simple,
-        tb_max=tb_max, breaker=breaker, sim=sim)
-    # all buckets launched async above; ONE explicit device_get drains them
-    # (it blocks until ready) instead of a per-bucket-per-array np.asarray pull
-    pulled = jax.device_get([r for (_sb, r) in launches]) if launches else []
-    release()  # results are on the host — staging arrays are reusable now
-    scores, docs, totals = collect_flat_sparse(launches, pulled, Q, k,
-                                               packed.doc_pad)
-    return scores, docs, totals, overflow
 
 
 def build_term_batch(entries: list, n_queries: int, n_must: np.ndarray, msm: np.ndarray,

@@ -731,13 +731,10 @@ def _dispatch_flat_plain(plans: list[FlatPlan], ctx: ShardContext,
                 raise _tag_domain(e, "compile:dense")
         seg_work.append((seg, base, packed.doc_pad, launches, dense))
         if prof is not None:
-            from ..ops.pallas_kernels import estpu_pallas_enabled
             from ..ops.scoring import SparseScratchPool
 
             prof.segment(
-                seg.gen, docs=int(seg.doc_count),
-                path=("sparse_fused" if estpu_pallas_enabled()
-                      else "sparse_composed"),
+                seg.gen, docs=int(seg.doc_count), path="sparse_composed",
                 tf_layout=packed.tf_layout,
                 # the launch counters' own sum (ops/scoring.LAUNCHES
                 # `blocks_real`): the blocks every launch above named

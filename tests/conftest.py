@@ -70,7 +70,7 @@ def rng():
 # (warn instead of raise); ESTPU_COMPILE_BUDGET=<n> makes the compile count
 # a hard per-test ceiling — the runtime twin of tpulint TPU001/TPU002.
 _SANITIZED_MODULES = {
-    "test_pallas_kernels",
+    "test_sparse_program",
     "test_quantized_postings",
     "test_device_aggs",
     "test_device_sort",

@@ -10,9 +10,6 @@ mesh program of `chip_smoke.py --chips 4` on a
 four-device `Mesh`. A compile that passes is not a chip run; it says the chip's
 compiler accepts the program and how much device memory it plans.
 
-One strict-xfail case compiles the fused Pallas kernel and pins the compiler's refusal,
-so the PR that repairs or deletes the kernel has to touch it (ROADMAP S3/D2).
-
 The topology is described in a module-scoped fixture, never at import: only one
 process may hold the TPU library, every xdist worker imports every test file, and this
 file runs in one worker (`--dist loadfile`). The persistent compilation cache is off
@@ -22,7 +19,6 @@ cache but cannot be read back without one.
 
 from __future__ import annotations
 
-import functools
 import os
 
 import numpy as np
@@ -158,35 +154,3 @@ def test_mesh_program_compiles_for_four_v5e_chips(topo):
     # per device: its own shard's planes, not all four
     per_device = compiled.memory_analysis().argument_size_in_bytes
     assert per_device < 2 * rows * BLOCK * 5
-
-
-@pytest.mark.xfail(
-    strict=True, raises=ValueError,
-    reason="the chip's compiler refuses the fused Pallas kernel at its first block "
-           "spec: 'the last two dimensions of your block shape are divisible by 8 "
-           "and 128 respectively' — the (1, BLOCK) row-gather specs "
-           "(ops/pallas_kernels.py). It has never compiled; ROADMAP S3/D2 decide it.")
-def test_fused_pallas_kernel_compiles_for_v5e(one_chip):
-    import jax
-
-    from elasticsearch_tpu.common.jaxenv import compile_tag
-    from elasticsearch_tpu.ops.pallas_kernels import _sparse_score_call
-
-    Qb, TB, rows = 8, 32, 4096
-    args = _shapes(
-        one_chip,
-        ((Qb, TB), "int32"), ((Qb, TB), "float32"), ((Qb, TB), "int32"),
-        ((Qb, TB), "int32"), ((Qb, TB), "int32"), ((Qb, TB), "int32"),
-        ((Qb,), "int32"), ((Qb,), "int32"), ((Qb, 5), "float32"),
-        ((rows, BLOCK), "int32"), ((rows, BLOCK), "uint8"), ((rows, BLOCK), "uint8"),
-        ((1, 256), "float32"))
-    fn = jax.jit(functools.partial(
-        _sparse_score_call, k=128, doc_pad=DOC_PAD, passes=2, simple=True,
-        use_coord=False, interpret=False))
-    try:
-        with compile_tag("sparse"):
-            fn.lower(*args).compile()
-    except ValueError as e:
-        # any OTHER refusal is news: fail for real instead of xfailing on it
-        assert "divisible by 8 and 128" in str(e), e
-        raise

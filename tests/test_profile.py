@@ -206,7 +206,7 @@ class TestLiveProfile:
             # per-segment execution counters + cache attribution
             assert shard["segments"], shard
             for seg in shard["segments"]:
-                assert seg["path"] in ("sparse_composed", "sparse_fused")
+                assert seg["path"] == "sparse_composed"
                 assert seg["tf_layout"] == "u8"
                 assert seg["blocks_scanned"] >= 1
                 assert seg["postings_scanned"] >= 1

@@ -207,33 +207,6 @@ class TestSimTables:
         eng.close()
 
 
-@pytest.mark.pallas
-class TestFusedKernelQuantizedParity:
-    def test_interpret_leg_overflow_segment(self, tmp_path, monkeypatch):
-        """ESTPU_PALLAS=interpret end-to-end on an i16-overflow segment: the
-        fused kernel must serve bit-identical hits to the composed path."""
-        rng = np.random.default_rng(16)
-        words = [f"w{i}" for i in range(15)]
-        docs = [{"b": " ".join(rng.choice(words, size=10))} for _ in range(60)]
-        docs[5] = {"b": "loud " * 280 + "w1"}
-        eng, ctx = _mk_engine(tmp_path, docs)
-        queries = [{"match": {"b": "loud w1"}},
-                   {"bool": {"must": [{"term": {"b": "w2"}}],
-                             "must_not": [{"term": {"b": "w3"}}]}}]
-        # the CI pallas-interpret leg exports ESTPU_PALLAS for the whole job —
-        # the baseline must be the COMPOSED path, not fused-vs-fused
-        monkeypatch.delenv("ESTPU_PALLAS", raising=False)
-        base = [search_shard(ctx, parse_query(q), 15, use_device=True)
-                for q in queries]
-        monkeypatch.setenv("ESTPU_PALLAS", "interpret")
-        flagged = [search_shard(ctx, parse_query(q), 15, use_device=True)
-                   for q in queries]
-        for b, f in zip(base, flagged):
-            assert b.total == f.total
-            assert b.hits == f.hits
-        eng.close()
-
-
 class TestRandomizedQuantizedParity:
     def test_fuzz_multi_field_ordering(self, tmp_path):
         """Randomized differential: multi-field bool queries (distinct fid

@@ -1,6 +1,8 @@
 """Tribe node: inner member per cluster, merged read view, first-wins conflicts,
 write/metadata blocks. ref: tribe/TribeService.java."""
 
+import time
+
 import pytest
 
 from elasticsearch_tpu.common.errors import ClusterBlockError, IndexMissingError
@@ -125,7 +127,14 @@ class TestTribe:
         (a, reg_a), (b, reg_b), tmp = two_clusters
         t = make_tribe(tmp, reg_a, reg_b)
         try:
-            h = t.client().cluster_health()
+            # an inner member joins its cluster asynchronously (it can start with
+            # master=None): give its view a moment to reach the merged health
+            deadline = time.monotonic() + 10.0
+            while True:
+                h = t.client().cluster_health()
+                if h["number_of_nodes"] >= 4 or time.monotonic() > deadline:
+                    break
+                time.sleep(0.05)
             assert h["status"] in ("green", "yellow")
             assert h["number_of_nodes"] >= 4  # 2 cluster nodes + 2 inner members
         finally:

@@ -8,8 +8,8 @@ ladders). A dimension derived from raw request data (`len(hits)`, a helper
 that returns one — resolved cross-module via the compile-surface
 return-calls fixpoint) gives every distinct request size its own executable:
 an unbounded compile family, which is precisely the serving-path compile
-stall ROADMAP item 5 exists to kill (BENCH_WRITES merge-window p99 1197 ms
-vs 480 ms steady — that gap IS first-sighting compiles).
+stall compile warming exists to kill (a first sighting stalls the one
+drainer 0.3-1 s on the chip, PERF.md section 7).
 
 Scope is the compile surface only (tools/tpulint/compilesurface.py's
 `jit_scope`): functions that construct an executable, plus their direct
