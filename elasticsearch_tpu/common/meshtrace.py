@@ -105,15 +105,21 @@ def _value_tag(v, depth: int = 0) -> str:
         items = sorted(v.items(), key=lambda kv: repr(kv[0]))[:16]
         inner = ",".join(f"{k!r}:{_value_tag(e, depth + 1)}" for k, e in items)
         return f"d({inner}{',...' if len(v) > 16 else ''})"
+    if depth < 3 and getattr(v, "__closure__", None):
+        # a program wrapped by another closure (mesh_search._on_plane around
+        # _mesh_score_program's): the wrapped program's static config is what
+        # tells the variants apart
+        inner = ",".join(_closure_fp(v, depth + 1))
+        return f"fn:{getattr(v, '__qualname__', '?')}({inner})"
     return type(v).__name__
 
 
-def _closure_fp(fn) -> tuple:
+def _closure_fp(fn, depth: int = 0) -> tuple:
     cells = getattr(fn, "__closure__", None) or ()
     out = []
     for c in cells:
         try:
-            out.append(_value_tag(c.cell_contents))
+            out.append(_value_tag(c.cell_contents, depth))
         except ValueError:  # empty cell
             out.append("<empty>")
     return tuple(out)

@@ -272,7 +272,8 @@ class LaunchCounters:
     what is still scattered). `operand_puts` counts the host arrays launch
     sites put on the device (_put_operands: each leaf of a launch's one
     device_put is its own transfer): two for a warmed sparse launch, three
-    for a warmed dense one."""
+    for a warmed dense one, one for a plain mesh search (its operand plane;
+    parallel/mesh_search.py)."""
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -307,16 +308,17 @@ class LaunchCounters:
 LAUNCHES = LaunchCounters()
 
 
-def _put_operands(*host):
+def _put_operands(*host, shardings=None):
     """A launch's ONE explicit host→device transfer: every host operand goes
     down in a single jax.device_put (legal under transfer_guard("disallow");
     leaves already on the device pass through untouched). Returns the
-    operands as device arrays, in order."""
+    operands as device arrays, in order. `shardings`, one per operand, is
+    the mesh launch's: where each operand goes on a mesh of several chips."""
     import jax
 
     LAUNCHES.puts(sum(isinstance(leaf, (np.ndarray, np.generic))
                       for leaf in jax.tree_util.tree_leaves(host)))
-    return jax.device_put(host)
+    return jax.device_put(host, shardings)
 
 
 def _unpack_qplane(qplane):

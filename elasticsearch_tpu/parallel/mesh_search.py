@@ -28,6 +28,12 @@ collectives):
     index arrays  [S, ...]        → P("shards", ...)   replicated over "replicas"
     query entries [R, S, M, ...]  → P("replicas", "shards", ...)
     outputs       [R, Qd, k]      → P("replicas", ...)
+
+A launch crosses the host-device boundary once each way: what changes between
+launches (the entry arrays, clause weights and per-query rows _assemble makes)
+goes down as ONE int32 plane [S, L], a row a shard, in one device_put
+(_pack_plane / _on_plane); the index and the BM25 norm cache stay on the mesh;
+the plain search's outputs come up as one packed plane in one device_get.
 """
 
 from __future__ import annotations
@@ -535,6 +541,88 @@ def _mesh_score_program(k: int, n_queries: int, doc_pad: int, similarity_kind: i
     return program
 
 
+# ---------------------------------------------------------------------------
+# the launch's operand plane: what changes between launches, in ONE transfer
+# ---------------------------------------------------------------------------
+
+_ENTRY_ROWS = 6  # qidx, blk, clause_id, fidx, group, tfmode
+
+
+def _pack_plane(qidx, blk, clause_id, fidx, group, tfmode, weight_c,
+                n_must, msm, coord) -> np.ndarray:
+    """_assemble's ten operands as one int32 plane [S, L], a row a shard:
+    the six entry arrays [S, M], the shard's clause weights [S, C], then the
+    per-query plane of the one-shard launches (scoring._pack_qplane: n_must,
+    msm and the coord row of each query, a few dozen bytes, the same in every
+    shard's row). Floats go as their bits, so nothing is rounded.
+    L = 6M + C + Qp(2 + W): the sizes that already decide the program."""
+    from ..ops.scoring import _pack_qplane
+
+    S, m = qidx.shape
+    c = weight_c.shape[1]
+    qplane = _pack_qplane(n_must, msm, coord).reshape(-1)
+    plane = np.empty((S, _ENTRY_ROWS * m + c + qplane.size), np.int32)
+    o = 0
+    for a in (qidx, blk, clause_id, fidx, group, tfmode):
+        plane[:, o:o + m] = a
+        o += m
+    plane[:, o:o + c] = np.ascontiguousarray(weight_c, np.float32).view(np.int32)
+    plane[:, o + c:] = qplane
+    return plane
+
+
+def _unpack_plane(plane, m: int, n_queries: int, width: int):
+    """_pack_plane's ten operands back from a plane [rows, L] inside a
+    program, by static slices (C is what is left of L) and bit-casts: the
+    entries and weights keep the plane's rows, the per-query values are read
+    from its first row."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..ops.scoring import _unpack_qplane
+
+    o = _ENTRY_ROWS * m
+    c = plane.shape[1] - o - n_queries * (2 + width)
+    entries = [plane[:, i * m:(i + 1) * m] for i in range(_ENTRY_ROWS)]
+    weight_c = jax.lax.bitcast_convert_type(plane[:, o:o + c], jnp.float32)
+    return (*entries, weight_c, *_unpack_qplane(
+        plane[0, o + c:].reshape(n_queries, 2 + width)))
+
+
+def _on_plane(program, m: int, n_queries: int, width: int):
+    """`program` (_mesh_score_program's, untouched) behind the launch ABI
+    (blk_docs, blk_tf, norms, live, norm_cache, plane, *extra): the resident
+    index and norm cache, one operand plane, the variants' own large
+    operands. The plain search's four outputs come back as one int32 plane
+    (_unpack_outs is the host's side); a variant's further outputs follow."""
+    import jax
+    import jax.numpy as jnp
+
+    def bits(x):
+        return jax.lax.bitcast_convert_type(x, jnp.int32).reshape(-1)
+
+    def launch(blk_docs, blk_tf, norms, live, norm_cache, plane, *extra):
+        ops = _unpack_plane(plane, m, n_queries, width)
+        top_scores, top_ids, shard_totals, qmax, *more = program(
+            blk_docs, blk_tf, norms, live, *ops[:7], norm_cache, *ops[7:],
+            *extra)
+        packed = jnp.concatenate([bits(top_scores), top_ids.reshape(-1),
+                                  shard_totals.reshape(-1), bits(qmax)])
+        return (packed, *more)
+
+    return launch
+
+
+def _unpack_outs(packed: np.ndarray, n_queries: int, k: int, n_shards: int):
+    """(top_scores [Qp, k] f32, top_ids [Qp, k], shard_totals [S, Qp],
+    qmax [S, Qp] f32) as views of _on_plane's packed output."""
+    a, b = n_queries * k, n_shards * n_queries
+    return (packed[:a].view(np.float32).reshape(n_queries, k),
+            packed[a:2 * a].reshape(n_queries, k),
+            packed[2 * a:2 * a + b].reshape(n_shards, n_queries),
+            packed[2 * a + b:].view(np.float32).reshape(n_shards, n_queries))
+
+
 @dataclass
 class MeshTopDocs:
     scores: np.ndarray  # [Q, k]
@@ -549,6 +637,9 @@ class MeshTopDocs:
     # per bucket agg: (counts [S, Q, NB], sub_cnt [S, Q, Fs, NB]|None,
     #                  sub_stats [S, Q, Fs, NB, 4]|None)
     bucket_results: list = None
+    # the dispatch's stage / launch / device_pull intervals
+    # (tracing.DispatchClock), set by the batcher's mesh family
+    clock: object = None
 
 
 class MeshSearchExecutor:
@@ -568,7 +659,14 @@ class MeshSearchExecutor:
         # statistics are inputs, not program structure: executors over one
         # ShardedIndex that differ only in use_global_stats share executables
         self._compiled: dict = {} if compiled is None else compiled
-        self._norm_cache = self._norm_caches()
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        self._by_shard = NamedSharding(mesh, P("shards"))
+        self._replicated = NamedSharding(mesh, P())
+        # a constant of the packed generation: it lives on the mesh beside
+        # the index it belongs to, and no launch sends it down again
+        self._norm_cache = jax.device_put(self._norm_caches(), self._by_shard)
 
     # -- host-side statistics (the DFS phase) -------------------------------
     def _norm_caches(self) -> np.ndarray:
@@ -732,14 +830,15 @@ class MeshSearchExecutor:
         bucket_pairs: per bucket agg (pdoc [S, P], pbucket [S, P], nb,
         sub_row_idx tuple|None) — results in MeshTopDocs.bucket_results."""
         import jax
-        import jax.numpy as jnp
         from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
+        from ..ops.scoring import _launch, _named, _pull, _put_operands
+
         idx = self.index
         Q = len(plans)
-        (qidx, blk, clause_id, fidx, group, tfmode, weight_c,
-         n_must, msm, coord) = self._assemble(plans)
+        operands = self._assemble(plans)
+        qidx, *_entries, n_must, _msm, coord = operands
         # the pow-2 query bucket _assemble padded to — the program and its
         # cache key are shaped by Qp, outputs slice back to the real Q below
         Qp = n_must.shape[0]
@@ -764,29 +863,29 @@ class MeshSearchExecutor:
         has_active = active is not None
         bucket_specs = tuple((int(nb), tuple(sub) if sub else None)
                              for (_pd, _pb, nb, sub) in bucket_pairs)
-        key = (Qp, k, qidx.shape[1], coord.shape[1], has_filter, has_stack,
+        M, W = qidx.shape[1], coord.shape[1]
+        key = (Qp, k, M, W, has_filter, has_stack,
                has_aggs, has_post, has_min, has_sort, sort_desc, has_active,
                bucket_specs)
-        in_specs = [
-            P("shards"), P("shards"), P("shards"), P("shards"),  # index
-            P("shards"), P("shards"), P("shards"), P("shards"), P("shards"), P("shards"),  # entries
-            P("shards"), P("shards"),  # host-resolved weights + norm caches
-            P(), P(), P(),  # per-query
-        ]
+        # the variants' own large operands, in the program's order, each with
+        # its placement; they ride the operand plane's one device_put
+        by_shard, replicated = self._by_shard, self._replicated
+        extras = []
         if has_filter:
-            in_specs.append(P("shards"))
+            extras.append((filter_masks, by_shard))
         if has_stack:
-            in_specs.append(P("shards"))
+            extras.append((agg_rows, by_shard))
         if has_post:
-            in_specs.append(P("shards"))
+            extras.append((post_masks, by_shard))
         if has_min:
-            in_specs.append(P())
+            extras.append((np.float32(min_score), replicated))
         if has_sort:
-            in_specs.append(P("shards"))
+            extras.append((sort_keys, by_shard))
         if has_active:
-            in_specs.append(P("shards"))
-        for _spec in bucket_specs:
-            in_specs.extend([P("shards"), P("shards")])
+            extras.append((active, by_shard))
+        for (pd, pb, _nb, _sub) in bucket_pairs:
+            extras.append((pd, by_shard))
+            extras.append((pb, by_shard))
         fn = self._compiled.get(key)
         if fn is None:
             program = _mesh_score_program(k, Qp, idx.doc_pad, self.similarity_kind,
@@ -798,64 +897,42 @@ class MeshSearchExecutor:
                                           use_active=has_active,
                                           use_stack=has_stack,
                                           bucket_specs=bucket_specs)
-            n_out = 4 + (1 if has_sort else 0) + (2 if has_aggs else 0) \
+            n_out = 1 + (1 if has_sort else 0) + (2 if has_aggs else 0) \
                 + sum(3 if sub else 1 for (_nb, sub) in bucket_specs)
             fn = shard_map(
-                program, mesh=self.mesh,
-                in_specs=tuple(in_specs),
+                _on_plane(program, M, Qp, W), mesh=self.mesh,
+                # index x4, norm cache, operand plane, then the extras
+                in_specs=(P("shards"),) * 6 + tuple(
+                    placed.spec for _a, placed in extras),
                 out_specs=tuple(P() for _ in range(n_out)),
                 check_vma=False,  # outputs here are P() by construction
             )
             # named like the scoring launch sites (ops/scoring._named): the
             # device trace reads `jit_estpu_mesh_search`, not `jit_<lambda>`
-            from ..ops.scoring import _named
-
             fn = jax.jit(_named("mesh.search", fn))
             self._compiled[key] = fn
-        raw = [
-            idx.blk_docs, idx.blk_tf, idx.norms, idx.live,
-            qidx, blk, clause_id, fidx, group, tfmode,
-            weight_c, self._norm_cache, n_must, msm, coord,
-        ]
-        if has_filter:
-            raw.append(filter_masks)
-        if has_stack:
-            raw.append(agg_rows)
-        if has_post:
-            raw.append(post_masks)
-        if has_min:
-            raw.append(np.float32(min_score))
-        if has_sort:
-            raw.append(sort_keys)
-        if has_active:
-            raw.append(active)
-        for (pd, pb, _nb, _sub) in bucket_pairs:
-            raw.append(pd)
-            raw.append(pb)
-        # EXPLICIT placement with the program's exact shardings. jnp.asarray
-        # committed each arg to the default device, and dispatch then resharded
-        # it onto the mesh — an implicit device-to-device copy per argument per
-        # query, which transfer_guard("disallow") rejects. device_put on an
-        # already-correctly-placed array (the packed index, cached agg stacks)
-        # is a no-op.
-        from jax.sharding import NamedSharding
-
         # compile_tag: first sightings of a (Qp, shapes, feature-set) key trace
         # and compile HERE — attribute them to the "mesh" ledger family (the
         # same family the batcher's mesh launches carry)
         with compile_tag("mesh"):
-            args = [jax.device_put(a, NamedSharding(self.mesh, s))
-                    for a, s in zip(raw, in_specs)]
-
-            # ONE explicit pull for every program output — per-output
-            # np.asarray was an implicit transfer each, which
-            # transfer_guard("disallow") rejects
-            outs = list(jax.device_get(fn(*args)))
+            # ONE explicit transfer down, with the program's exact shardings
+            # (transfer_guard("disallow") rejects an implicit one): the plane
+            # and whatever the variant adds. The index and the norm cache are
+            # on the mesh already and are passed as they are; so is a cached
+            # agg stack, which device_put hands back untouched.
+            plane, *placed = _put_operands(
+                _pack_plane(*operands), *(a for a, _p in extras),
+                shardings=(by_shard, *(p for _a, p in extras)))
+            # ONE explicit pull for every program output. _launch / _pull
+            # close the dispatch clock's stage, launch and device_pull
+            outs = list(_pull(_launch(fn, (
+                idx.blk_docs, idx.blk_tf, idx.norms, idx.live,
+                self._norm_cache, plane, *placed))))
+        top_scores, top_ids, shard_totals, qmax = _unpack_outs(
+            outs.pop(0), Qp, k, idx.n_shards)
         # every per-query axis slices from the padded Qp back to the real Q
-        top_scores = outs.pop(0)[0][:Q]
-        top_ids = outs.pop(0)[0][:Q]
-        shard_totals = outs.pop(0)[0][:, :Q]  # [S, Q]
-        qmax = outs.pop(0)[0][:, :Q]  # [S, Q]
+        top_scores, top_ids = top_scores[:Q], top_ids[:Q]
+        shard_totals, qmax = shard_totals[:, :Q], qmax[:, :Q]  # [S, Q]
         out_sort_keys = outs.pop(0)[0][:Q] if has_sort else None
         agg_counts = agg_stats = None
         if has_aggs:

@@ -29,7 +29,7 @@ from ..common.devicehealth import (DEVICE_HEALTH, classify_device_error,
                                    tag_domain)
 from ..common.errors import CircuitBreakingError
 from ..common.logging import get_logger
-from ..search.execute import lower_flat
+from ..search.execute import lower_flat, traced_dispatch
 from ..search.filters import segment_mask
 from ..search.queries import FilteredQuery
 from ..search.service import ParsedSearchRequest, ShardQueryResult
@@ -413,20 +413,25 @@ class MeshServingService:
                     index=index, shards=S) if cur is not None else None
                 t_launch = time.monotonic() if prof is not None else 0.0
                 try:
-                    out = self._launch_contained(
-                        index, svc, searchers, kind, default_sim,
-                        use_global_stats, executor,
-                        lambda ex: ex.search(
-                            [plan], k, filter_masks=filter_masks,
-                            agg_rows=agg_rows,
-                            use_metric_aggs=bool(metric_fields),
-                            post_masks=post_masks,
-                            min_score=(float(req.min_score)
-                                       if req.min_score is not None else None),
-                            sort_keys=sort_keys,
-                            sort_desc=bool(sort_spec.reverse)
-                            if sort_spec is not None else False,
-                            active=active, bucket_pairs=bucket_pairs or None))
+                    # a sampled request keeps the launch's stage / launch /
+                    # device_pull intervals beside its mesh span
+                    with traced_dispatch():
+                        out = self._launch_contained(
+                            index, svc, searchers, kind, default_sim,
+                            use_global_stats, executor,
+                            lambda ex: ex.search(
+                                [plan], k, filter_masks=filter_masks,
+                                agg_rows=agg_rows,
+                                use_metric_aggs=bool(metric_fields),
+                                post_masks=post_masks,
+                                min_score=(float(req.min_score)
+                                           if req.min_score is not None
+                                           else None),
+                                sort_keys=sort_keys,
+                                sort_desc=bool(sort_spec.reverse)
+                                if sort_spec is not None else False,
+                                active=active,
+                                bucket_pairs=bucket_pairs or None))
                 finally:
                     if mesh_span is not None:
                         mesh_span.end()

@@ -167,11 +167,14 @@ class _MeshFamily:
         plans += [FlatPlan([], msm=1, n_must=0, coord_enabled=False, boost=1.0)
                   for _ in range(qb - len(plans))]
         # executor.search pulls its program output itself (one device_get for
-        # the whole result pytree) — the mesh family merges at dispatch time
+        # the whole result pytree) — the mesh family merges at dispatch time,
+        # so its clock holds the pull beside the stage and the launch
         from ..common.jaxenv import compile_tag
 
-        with compile_tag("mesh"):
-            return executor.search(plans, kb)
+        with tracing.timing_dispatch() as clock, compile_tag("mesh"):
+            out = executor.search(plans, kb)
+        out.clock = clock
+        return out
 
     @staticmethod
     def fan_out(out, items):

@@ -76,6 +76,7 @@ _SANITIZED_MODULES = {
     "test_device_sort",
     "test_parallel_search",
     "test_mesh_serving",
+    "test_mesh_launch",
 }
 
 

@@ -129,21 +129,25 @@ def test_mesh_program_compiles_for_four_v5e_chips(topo):
     from jax.sharding import PartitionSpec as P
 
     from elasticsearch_tpu.common.jaxenv import compile_tag
-    from elasticsearch_tpu.parallel.mesh_search import _mesh_score_program
+    from elasticsearch_tpu.parallel.mesh_search import (
+        _mesh_score_program,
+        _on_plane,
+    )
 
-    S, rows, doc_pad, E, C, Qp, k = 4, 131_072, 32_768, 512, 4, 1, 128
+    S, rows, doc_pad, E, C, Qp, k, W = 4, 131_072, 32_768, 512, 4, 1, 128, 5
     mesh = Mesh(np.array(topo.devices[:S]), ("shards",))
     sh, rep = P("shards"), P()
     layout = [  # (shape, dtype, spec) as MeshSearchExecutor.search places them
         ((S, rows, BLOCK), "int32", sh), ((S, rows, BLOCK), "uint8", sh),  # docs, tf
         ((S, 1, doc_pad), "uint8", sh), ((S, doc_pad), "bool", sh),  # norms, live
-        *[((S, E), "int32", sh)] * 6,  # qidx, blk, clause_id, fidx, group, tfmode
-        ((S, C), "float32", sh), ((S, 1, 256), "float32", sh),  # weight_c, norm_cache
-        ((Qp,), "int32", rep), ((Qp,), "int32", rep), ((Qp, 5), "float32", rep)]
-    program = _mesh_score_program(k, Qp, doc_pad, 0)
+        ((S, 1, 256), "float32", sh),  # the resident norm cache
+        # the launch's one operand plane: six entry arrays, the clause
+        # weights, n_must, msm and the coord rows, a row a shard
+        ((S, 6 * E + C + Qp * (2 + W)), "int32", sh)]
+    program = _on_plane(_mesh_score_program(k, Qp, doc_pad, 0), E, Qp, W)
     fn = jax.jit(shard_map(
         program, mesh=mesh, in_specs=tuple(spec for _s, _d, spec in layout),
-        out_specs=tuple(rep for _ in range(4)), check_vma=False))
+        out_specs=(rep,), check_vma=False))
     args = [jax.ShapeDtypeStruct(shape, jnp.dtype(dt),
                                  sharding=NamedSharding(mesh, spec))
             for shape, dt, spec in layout]
