@@ -1856,7 +1856,9 @@ class ActionModule:
     # 88.5 s there (PR 22) — this timer alone failed that healthy shard at 60 s.
     # So when it runs out the coordinator asks the copy's node
     # (A_QUERY_PROGRESS) and waits another window while the node's last
-    # compile is younger than one: at most QUERY_ATTEMPT_EXTENSIONS more per
+    # compile, or its last segment pack (a merged segment's device concat runs
+    # for a minute and more at a quarter of a million documents, and the first
+    # search waits for it), is younger than one: at most QUERY_ATTEMPT_EXTENSIONS more per
     # chain, 600 s in all, the timeout a client should give a cold server. A
     # copy whose node compiles nothing, or does not answer within
     # QUERY_PROGRESS_TIMEOUT, fails over after one window as before.
@@ -2097,15 +2099,17 @@ class ActionModule:
                 ).add_done_callback(on_progress)
 
             def on_progress(f):
-                idle = None if f.exception() is not None else \
-                    (f.result() or {}).get("compile_idle_s")
+                busy = {} if f.exception() is not None else (f.result() or {})
+                idles = [v for v in (busy.get("compile_idle_s"),
+                                     busy.get("pack_idle_s")) if v is not None]
+                idle = min(idles) if idles else None
                 if idle is None or idle >= self.QUERY_ATTEMPT_TIMEOUT \
                         or not arm_timer():
                     fail_over()
                     return
                 self.logger.info(
                     "query phase attempt to [%s] for [%s][%d] is late and its "
-                    "node compiled %.1f s ago: waiting another window",
+                    "node compiled or packed %.1f s ago: waiting another window",
                     candidate.node_id, candidate.index, candidate.shard_id, idle)
 
             timer = [None]
@@ -2258,10 +2262,13 @@ class ActionModule:
 
     def _s_query_progress(self, request, channel):
         """What a coordinator's attempt timer asks before it fails a late copy
-        over: is this node compiling (QUERY_ATTEMPT_TIMEOUT's comment)."""
+        over: is this node compiling, or packing a segment
+        (QUERY_ATTEMPT_TIMEOUT's comment)."""
         from .common.jaxenv import seconds_since_compile
+        from .ops.device_index import PACK_LEDGER
 
-        return {"compile_idle_s": seconds_since_compile()}
+        return {"compile_idle_s": seconds_since_compile(),
+                "pack_idle_s": PACK_LEDGER.idle_s()}
 
     def _s_free_context(self, request, channel):
         """ES's free-context: the coordinator releases pinned searchers of shards

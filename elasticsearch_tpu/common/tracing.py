@@ -108,12 +108,17 @@ class DispatchClock:
     the previous one, so the intervals are gap-free and never overlap; an
     interval in which this thread compiled carries `compiled`/`compile_s`.
     The batcher records them under `batcher.dispatch` for sampled members
-    and sums `pull_s` into its drainer states for every batch."""
+    and sums `pull_s` into its drainer states for every batch. A `note` is a
+    named part of the running interval (`shard.filter_mask` inside its
+    `dispatch.stage`), recorded as that interval's child: the marks and what
+    they measure are as they were."""
 
-    __slots__ = ("spans", "pull_s", "compiled", "compile_s", "_t", "_n", "_s")
+    __slots__ = ("spans", "pull_s", "compiled", "compile_s", "_t", "_n", "_s",
+                 "_notes")
 
     def __init__(self):
-        self.spans: list = []  # (name, t0, t1, tags | None)
+        self.spans: list = []  # (name, t0, t1, tags | None, notes)
+        self._notes: tuple = ()  # (name, t0, t1) inside the running interval
         self.pull_s = 0.0
         self.compiled = 0
         self.compile_s = 0.0
@@ -131,13 +136,20 @@ class DispatchClock:
             self._n, self._s = n, s
         if name == "device_pull":
             self.pull_s += now - self._t
-        self.spans.append((name, self._t, now, tags))
+        self.spans.append((name, self._t, now, tags, self._notes))
         self._t = now
+        self._notes = ()
+
+    def note(self, name: str, t0: float) -> None:
+        self._notes += ((name, t0, time.monotonic()),)
 
     def record_under(self, parent, **tags) -> None:
-        """The clock's intervals as born-finished children of `parent`."""
-        for name, t0, t1, own in self.spans:
-            parent.record(name, t0, t1, **tags, **(own or {}))
+        """The clock's intervals as born-finished children of `parent`, each
+        interval's notes as its own children."""
+        for name, t0, t1, own, notes in self.spans:
+            span = parent.record(name, t0, t1, **tags, **(own or {}))
+            for part, p0, p1 in notes:
+                span.record(part, p0, p1)
 
 
 @contextlib.contextmanager
@@ -157,6 +169,15 @@ def mark(name: str) -> None:
     clock = getattr(_local, "clock", None)
     if clock is not None:
         clock.mark(name)
+
+
+def note(name: str, t0: float) -> None:
+    """An interval from `t0` to now INSIDE the running interval of this
+    thread's dispatch clock (DispatchClock.note): the next mark still closes
+    the running interval whole."""
+    clock = getattr(_local, "clock", None)
+    if clock is not None:
+        clock.note(name, t0)
 
 
 class _ChildScope:

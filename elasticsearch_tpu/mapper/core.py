@@ -295,6 +295,7 @@ class DocumentMapper:
         self.ttl_enabled = mapping.get("_ttl", {}).get("enabled", False)
         self.default_ttl = mapping.get("_ttl", {}).get("default")
         self.fields: dict[str, FieldType] = {}
+        self.multi_fields: dict[str, list[str]] = {}  # field -> its sub-fields
         self._mapping_dirty = False
         self._parse_properties(mapping.get("properties", {}), prefix="", nested_path=None)
 
@@ -325,6 +326,11 @@ class DocumentMapper:
             self.fields[full] = ft
             for sub, subspec in spec.get("fields", {}).items():
                 self.fields[f"{full}.{sub}"] = self._field_type_from_spec(f"{full}.{sub}", subspec)
+                # a multi-field: the parent's values indexed once more under
+                # the sub-field's own type (`request.raw`, not analysed)
+                subs = self.multi_fields.setdefault(full, [])
+                if f"{full}.{sub}" not in subs:
+                    subs.append(f"{full}.{sub}")
 
     def _field_type_from_spec(self, full: str, spec: dict) -> FieldType:
         ftype = spec.get("type", "string")
@@ -470,6 +476,8 @@ class DocumentMapper:
                 self.fields[full] = ft
                 self._mapping_dirty = True
             self._index_values(ft, values, doc, all_terms)
+            for sub in self.multi_fields.get(full, ()):
+                self._index_values(self.fields[sub], values, doc, all_terms=[])
             for target in ft.copy_to:
                 tft = self.fields.get(target)
                 if tft is None:

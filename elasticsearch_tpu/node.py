@@ -1064,6 +1064,10 @@ class Client:
             from .ops.scoring import LAUNCHES
 
             serving["launch"] = LAUNCHES.snapshot()
+            # a cached answer launches nothing: the shard request cache's
+            # hits beside the outcomes they stand in for
+            serving["request_cache_hits"] = \
+                self.node.request_cache.stats()["hits"]
             if ms is not None:
                 serving["mesh_spmd"] = ms.mesh_queries
                 serving["mesh_fallbacks"] = ms.mesh_fallbacks

@@ -50,7 +50,7 @@ from __future__ import annotations
 
 import threading
 import time
-from collections import OrderedDict
+from collections import OrderedDict, deque
 from dataclasses import dataclass, field as dc_field
 
 import numpy as np
@@ -220,6 +220,10 @@ class PackedSegment:
     agg_rows: dict = dc_field(default_factory=dict)  # field -> HOST f32 [5, Dpad] | None (not f32-exact)
     agg_stacks: dict = dc_field(default_factory=dict)  # fields-tuple -> device [F, 5, Dpad], FIFO-bounded
     bucket_cols: dict = dc_field(default_factory=dict)  # bucket-agg cache key -> device (pair_doc, pair_bucket, zeros[NB])
+    # field-sort key rows (execute._sort_key_row), FIFO-bounded:
+    # (field, mode, order, missing) -> device f32 [Dpad], the column's values
+    # where float32 holds them and their dense ranks where it does not
+    sort_rows: dict = dc_field(default_factory=dict)
     # the dense launches' per-document table (scoring._doc_table), FIFO-bounded:
     # fields-tuple -> (host caches f32 [F, 256], device norms_stack u8 [F, Dpad],
     # device table f32 [F, Dpad]) — a warmed launch restacks and remakes neither
@@ -239,6 +243,34 @@ class PackedSegment:
         return int(self.term_blk_start[tid]), int(self.term_blk_start[tid + 1])
 
 
+# Meta fields whose every term is one document. Blocks never span terms, so
+# packed they would take a 128-slot block a document each (two thirds of a
+# log index's planes, and what refused a segment of a million documents):
+# their postings stay on the host, where such a term is a dictionary lookup,
+# and execute.lower_flat sends a clause on one to the host scorer.
+HOST_ONLY_FIELDS = ("_id", "_uid")
+
+
+def device_counts(seg: FrozenSegment) -> np.ndarray:
+    """Postings per term as the device planes hold them: the segment's own
+    counts, with the terms of HOST_ONLY_FIELDS at 0 (no block, no slot)."""
+    counts = np.diff(seg.post_offsets)
+    for f in HOST_ONLY_FIELDS:
+        td = seg.term_dict.get(f)
+        if td:
+            counts[np.fromiter(td.values(), dtype=np.int64, count=len(td))] = 0
+    return counts
+
+
+def _device_postings(seg: FrozenSegment, counts: np.ndarray):
+    """(post_docs, post_freqs) of the terms `counts` keeps, in CSR order."""
+    full = np.diff(seg.post_offsets)
+    if np.array_equal(full, counts):
+        return seg.post_docs, seg.post_freqs
+    keep = np.repeat(counts > 0, full)
+    return seg.post_docs[keep], seg.post_freqs[keep]
+
+
 def pack_shape_math(seg: FrozenSegment) -> tuple[int, int, str]:
     """(NBpad, Dpad, tf_layout) — the one shape+layout derivation shared by
     pack_estimate_bytes and pack_segment, so the breaker estimate can never
@@ -250,7 +282,7 @@ def pack_shape_math(seg: FrozenSegment) -> tuple[int, int, str]:
         sm = cache.get("shape_math")
         if sm is not None:
             return sm
-    counts = np.diff(seg.post_offsets)
+    counts = device_counts(seg)
     nblks = (counts + BLOCK - 1) // BLOCK
     NBpad = _ladder_bucket("nb", int(nblks.sum()) + 1, 64)
     Dpad = _ladder_bucket("docs", max(seg.doc_count, 1), 128)
@@ -316,6 +348,7 @@ def packed_tier_bytes(packed: PackedSegment) -> dict:
                    (0 until dense use)
       sim_tables   the stacked per-field similarity LUTs (modes + caches)
       agg_rows     FIFO-bounded device metric-agg stacks
+      sort_keys    FIFO-bounded field-sort key rows (values or exact ranks)
       norms        per-field norm-byte columns + live mask + dv columns
 
     Pure host arithmetic over already-known shapes — no device sync, no
@@ -340,6 +373,8 @@ def packed_tier_bytes(packed: PackedSegment) -> dict:
                         + _plane_bytes(packed.head_rows)),
         "sim_tables": sim,
         "agg_rows": agg,
+        "sort_keys": sum(_plane_bytes(row)
+                         for row in list(packed.sort_rows.values())),
         "norms": norms,
     }
 
@@ -388,6 +423,45 @@ class PackLedger:
     def __init__(self):
         self._lock = threading.Lock()
         self._by_index: "OrderedDict[str, dict]" = OrderedDict()
+        self._in_flight = 0  # packs between begin() and end()
+        self._last_end: float | None = None
+        self._pending = None  # the newest pack's device output, till ready
+
+    def begin(self) -> None:
+        with self._lock:
+            self._in_flight += 1
+
+    def end(self, device_result=None) -> None:
+        """The host's part of a pack is over. `device_result` is an array the
+        pack's device program writes (a compaction's concat runs on for a
+        minute and more after the host has enqueued it): the pack stays in
+        flight until it is ready, which idle_s() asks without waiting."""
+        with self._lock:
+            self._in_flight -= 1
+            self._last_end = time.monotonic()
+            if device_result is not None:
+                self._pending = device_result
+
+    def idle_s(self) -> float | None:
+        """Seconds since this process last finished a pack, 0 while one is in
+        flight on the host or still running on the device, None where it never
+        packed. A search that waits for a merged segment's pack waits on a
+        node that is busy, not wedged (the coordinator's attempt timer asks,
+        actions.A_QUERY_PROGRESS)."""
+        with self._lock:
+            pending = self._pending
+            if self._in_flight:
+                return 0.0
+        if pending is not None:
+            if not pending.is_ready():
+                return 0.0
+            with self._lock:
+                if self._pending is pending:
+                    self._pending = None
+                    self._last_end = time.monotonic()
+        with self._lock:
+            return None if self._last_end is None \
+                else time.monotonic() - self._last_end
 
     def record(self, index: str | None, gen: int, ms: float, nbytes: int,
                layout: str, kind: str = "pack", pool: str | None = None,
@@ -455,11 +529,12 @@ def segment_capacity(seg: FrozenSegment) -> dict | None:
         return None
     tiers = packed_tier_bytes(packed) if packed is not None else {
         "postings": 0, "dense_plane": 0, "sim_tables": 0, "agg_rows": 0,
-        "norms": 0}
+        "sort_keys": 0, "norms": 0}
     tiers["filter_masks"] = mask_bytes
     return {
         "generation": int(seg.gen),
         "tf_layout": packed.tf_layout if packed is not None else None,
+        "sort_key_rows": len(packed.sort_rows) if packed is not None else 0,
         "tiers": tiers,
         "total_bytes": int(sum(tiers.values())),
     }
@@ -564,7 +639,7 @@ def pack_segment(seg: FrozenSegment, fields: list[str] | None = None,
     put = device_put or (lambda x: jnp.asarray(x))
 
     T = len(seg.post_offsets) - 1
-    counts = np.diff(seg.post_offsets)
+    counts = device_counts(seg)
     nblks = (counts + BLOCK - 1) // BLOCK
     blk_start = np.zeros(T + 1, dtype=np.int64)
     np.cumsum(nblks, out=blk_start[1:])
@@ -576,11 +651,10 @@ def pack_segment(seg: FrozenSegment, fields: list[str] | None = None,
 
     flat_docs = np.full(NBpad * BLOCK, Dpad, dtype=np.int32)  # pad → out-of-range slot
     flat_freqs = np.zeros(NBpad * BLOCK, dtype=np.float32)
-    if len(seg.post_docs):
+    if NB:
         # slot of entry j of term t = (blk_start[t]*B) + (j - post_offsets[t])
         slots = expand_ranges(blk_start[:-1] * BLOCK, counts)
-        flat_docs[slots] = seg.post_docs
-        flat_freqs[slots] = seg.post_freqs
+        flat_docs[slots], flat_freqs[slots] = _device_postings(seg, counts)
 
     # block -> owning field ordinal (blocks never span terms, terms never span fields)
     field_names = list(seg.term_dict.keys())
@@ -685,7 +759,7 @@ def concat_estimate_bytes(merged: FrozenSegment, sources) -> int:
     NBpad, Dpad, layout = pack_shape_math(merged)
     tf_b = tf_plane_itemsize(layout)
     W = len(sources)
-    T = len(merged.post_offsets) - 1
+    T = int(np.count_nonzero(device_counts(merged)))  # the tables' columns
     n_norm_fields = len(merged.norms)
     n_dv = len(merged.dv_num)
     # retained host planes + device output planes + fused-program transients
@@ -724,27 +798,34 @@ def pack_segment_concat(merged: FrozenSegment,
         return None  # freq drift between sources and merged CSR: re-stage
 
     T = len(merged.post_offsets) - 1
-    counts_m = np.diff(merged.post_offsets)
+    counts_m = device_counts(merged)
     W = len(sources)
+    # the tables hold a column for each term the planes hold (a term of
+    # HOST_ONLY_FIELDS has no block to re-block)
+    col_of = np.full(T, -1, dtype=np.int32)
+    held = np.flatnonzero(counts_m)
+    col_of[held] = np.arange(len(held), dtype=np.int32)
+    if not len(held):
+        return None  # nothing the planes would hold: the staged pack's case
     # per (source, merged-term): posting count + the source's block start.
     # Terms resolve by name through each source's term dict (O(T·W) dict
     # lookups — proportional to vocabulary, not postings)
-    cnt = np.zeros((W, T), dtype=np.int32)
-    starts = np.zeros((W, T), dtype=np.int32)
+    cnt = np.zeros((W, len(held)), dtype=np.int32)
+    starts = np.zeros((W, len(held)), dtype=np.int32)
     for s, (src, packed_s) in enumerate(zip(sources, packs)):
         src_counts = np.diff(src.post_offsets)
         for f, td_m in merged.term_dict.items():
             td_s = src.term_dict.get(f)
-            if not td_s:
+            if not td_s or f in HOST_ONLY_FIELDS:
                 continue
             for term, tid_m in td_m.items():
                 tid_s = td_s.get(term)
                 if tid_s is not None:
-                    cnt[s, tid_m] = src_counts[tid_s]
-                    starts[s, tid_m] = packed_s.term_blk_start[tid_s]
-    cum = np.zeros((W + 1, T), dtype=np.int32)
+                    cnt[s, col_of[tid_m]] = src_counts[tid_s]
+                    starts[s, col_of[tid_m]] = packed_s.term_blk_start[tid_s]
+    cum = np.zeros((W + 1, len(held)), dtype=np.int32)
     np.cumsum(cnt, axis=0, out=cum[1:])
-    if not np.array_equal(cum[-1], counts_m):
+    if not np.array_equal(cum[-1], counts_m[held]):
         return None  # per-term counts disagree with the merged CSR
 
     nblks = (counts_m + BLOCK - 1) // BLOCK
@@ -757,7 +838,7 @@ def pack_segment_concat(merged: FrozenSegment,
     # pack writes there
     blk_j0 = np.full(NBpad, 1 << 30, dtype=np.int32)
     if NB:
-        blk_term[:NB] = np.repeat(np.arange(T, dtype=np.int32), nblks)
+        blk_term[:NB] = np.repeat(col_of, nblks)
         blk_j0[:NB] = ((np.arange(NB, dtype=np.int64)
                         - np.repeat(blk_start[:-1], nblks))
                        * BLOCK).astype(np.int32)
@@ -779,8 +860,7 @@ def pack_segment_concat(merged: FrozenSegment,
     flat_docs = np.full(NBpad * BLOCK, Dpad, dtype=np.int32)
     flat_freqs = np.zeros(NBpad * BLOCK, dtype=np.float32)
     slots = expand_ranges(blk_start[:-1] * BLOCK, counts_m)
-    flat_docs[slots] = merged.post_docs
-    flat_freqs[slots] = merged.post_freqs
+    flat_docs[slots], flat_freqs[slots] = _device_postings(merged, counts_m)
 
     field_names = list(merged.term_dict.keys())
     fid_of_tid = np.full(T, -1, dtype=np.int32)
@@ -967,6 +1047,46 @@ def ensure_agg_rows(seg: FrozenSegment, packed: PackedSegment, fields: list[str]
 # ---------------------------------------------------------------------------
 
 
+class RecentKeys:
+    """How often each key was among the last `horizon` sightings (Lucene's
+    UsageTrackingQueryCachingPolicy keeps the same history of 256): a filter
+    earns a cache entry by recurring while it is remembered, so bounds that
+    never recur, a dashboard's time windows, are counted once, forgotten,
+    and fill neither filter cache. No lock of its own: the caller's leaf
+    lock serializes it."""
+
+    __slots__ = ("_ring", "_count", "_horizon")
+
+    def __init__(self, horizon: int = 256):
+        self._ring: deque = deque()
+        self._count: dict = {}
+        self._horizon = horizon
+
+    def sight(self, key, times: int = 1) -> int:
+        """Count `key` `times` more; its count within the horizon."""
+        ring, count = self._ring, self._count
+        for _ in range(times):
+            ring.append(key)
+            count[key] = count.get(key, 0) + 1
+            if len(ring) > self._horizon:
+                old = ring.popleft()
+                if count[old] == 1:
+                    del count[old]
+                else:
+                    count[old] -= 1
+        return count.get(key, 0)
+
+    def get(self, key) -> int:
+        return self._count.get(key, 0)
+
+    def items(self):
+        return self._count.items()
+
+    def clear(self) -> None:
+        self._ring.clear()
+        self._count.clear()
+
+
 class _SegmentFilterMasks:
     """Per-segment holder of device-resident filter masks, living in
     `seg._device_cache["filter_masks"]`. Copy-on-write tombstoning
@@ -981,7 +1101,7 @@ class _SegmentFilterMasks:
 
     def __init__(self):
         self.masks: dict = {}  # filter key -> (device bool [Dpad], nbytes)
-        self.seen: dict = {}  # filter key -> sighting count
+        self.seen = RecentKeys()  # filter key -> sightings it is remembered for
         self.bytes = 0
         self.dead = False  # evicted with its segment: never re-stores
 
@@ -1050,7 +1170,7 @@ class DeviceFilterCache:
                 self.hits += 1
             else:
                 self.misses += 1
-                holder.seen[key] = holder.seen.get(key, 0) + 1
+                holder.seen.sight(key)
         if prof is not None:
             prof.event("filter_cache", cache="hit" if entry else "miss",
                        filter=key)
@@ -1073,7 +1193,7 @@ class DeviceFilterCache:
             entry = holder.masks.get(key)
             if entry is not None:
                 return entry[0]
-            if holder.seen.get(key, 0) < self.min_sightings:
+            if holder.seen.get(key) < self.min_sightings:
                 return None
         import jax
 
@@ -1205,9 +1325,9 @@ class DeviceFilterCache:
             if holder.dead:
                 return 0
             for k in keys:
-                if k not in holder.masks \
-                        and holder.seen.get(k, 0) < self.min_sightings:
-                    holder.seen[k] = self.min_sightings
+                short = self.min_sightings - holder.seen.get(k)
+                if k not in holder.masks and short > 0:
+                    holder.seen.sight(k, short)
                     seeded += 1
         return seeded
 
@@ -1390,16 +1510,25 @@ def _perform_pack(seg: FrozenSegment, fut, breaker,
             t0 = time.monotonic()
             new_packed = None
             method = "staged" if kind == "compact" else None
-            if kind == "compact" and sources:
-                with reserve(breaker, concat_estimate_bytes(seg, sources),
-                             f"<segment_compact>[{seg.gen}]"):
-                    new_packed = pack_segment_concat(seg, sources)
-                if new_packed is not None:
-                    method = "concat"
-            if new_packed is None:
-                with reserve(breaker, pack_estimate_bytes(seg),
-                             f"<segment_pack>[{seg.gen}]"):
-                    new_packed = pack_segment(seg)
+            PACK_LEDGER.begin()
+            try:
+                if kind == "compact" and sources:
+                    with reserve(breaker, concat_estimate_bytes(seg, sources),
+                                 f"<segment_compact>[{seg.gen}]"):
+                        new_packed = pack_segment_concat(seg, sources)
+                    if new_packed is not None:
+                        method = "concat"
+                if new_packed is None:
+                    with reserve(breaker, pack_estimate_bytes(seg),
+                                 f"<segment_pack>[{seg.gen}]"):
+                        new_packed = pack_segment(seg)
+            finally:
+                # the concat is a device program over every slot of the merged
+                # planes (84-120 s of device time at 67M slots on a v5e while
+                # the host enqueues it in 6 s: PERF.md section 6, PR 31); the
+                # first search queues behind it, so the ledger watches it
+                PACK_LEDGER.end(new_packed.blk_docs if method == "concat"
+                                else None)
             with _PACK_LOCK:
                 cache["packed"] = new_packed
                 cache["live"] = True

@@ -115,6 +115,23 @@ class ScoreResult:
     max_score: np.ndarray  # [Q] float32
 
 
+@dataclass
+class ConstBatch:
+    """The operands of plans with NO scoring clause (match_all, constant_score,
+    a range or numeric term query, a bare filter): no triple, no head slot, no
+    coord — one constant score a query (execute.unscored_score). The match set
+    is the family's filter mask and the live documents; such a batch launches
+    the `scoring_*_unscored` programs (_unscored_abi) behind the same tails as
+    a TermBatch."""
+
+    score: np.ndarray  # float32 [Q]
+    blocks_real: int = 0  # no postings block is read (the profile's record)
+
+    @property
+    def n_queries(self) -> int:
+        return len(self.score)
+
+
 def _top_k_tail(scores, match, *, k: int):
     """The plain dense program's tail. Sentinel substitution + max_score are
     [Q, k]-tiny — done host-side in score_term_batch, not appended here."""
@@ -273,14 +290,17 @@ class LaunchCounters:
     sites put on the device (_put_operands: each leaf of a launch's one
     device_put is its own transfer): two for a warmed sparse launch, three
     for a warmed dense one, one for a plain mesh search (its operand plane;
-    parallel/mesh_search.py)."""
+    parallel/mesh_search.py). The launches of plans with no scoring clause
+    are tallied apart (`bump`): they read no postings."""
 
     def __init__(self):
         self._lock = threading.Lock()
         self._c = dict.fromkeys(
             ("blocks_real", "blocks_launched", "blocks_padding",
              "posting_bytes", "dense_rows", "head_slots", "blocks_as_rows",
-             "launches_sparse", "launches_dense", "operand_puts"), 0)
+             "launches_sparse", "launches_dense", "operand_puts",
+             "unscored_plans", "launches_unscored", "unscored_bytes",
+             "mask_put_bytes"), 0)
 
     def add(self, real: int, launched: int, nbytes: int,
             dense_rows: int = 0, head_slots: int = 0,
@@ -299,6 +319,17 @@ class LaunchCounters:
     def puts(self, n: int) -> None:
         with self._lock:
             self._c["operand_puts"] += n
+
+    def bump(self, **counts: int) -> None:
+        """The tallies of the launches that read no postings: plans with no
+        scoring clause (`unscored_plans`, once a plan whatever its segments),
+        their launches and the bytes those read (`launches_unscored`,
+        `unscored_bytes`: _count_unscored), and the bytes of filter-mask rows
+        a search evaluated on the host and put (`mask_put_bytes`:
+        execute._filter_mask_matrix)."""
+        with self._lock:
+            for name, n in counts.items():
+                self._c[name] += n
 
     def snapshot(self) -> dict:
         with self._lock:
@@ -418,6 +449,45 @@ def _dense_abi(tail, *, n_queries: int, doc_pad: int, simple: bool = False,
         return tail(scores, match, *extra, **statics)
 
     return wrapper
+
+
+def _count_unscored(packed: PackedSegment, batch: ConstBatch,
+                    row_bytes: int) -> None:
+    """A launch of plans with no scoring clause reads, per query, the filter
+    mask's [doc_pad] bool row and the [doc_pad] f32 plane its tail ranks
+    (`row_bytes` more where the tail reads a row of its own: the sort key)."""
+    LAUNCHES.bump(launches_unscored=1, unscored_bytes=batch.n_queries
+                  * packed.doc_pad * (1 + 4 + row_bytes))
+
+
+def _unscored_abi(tail, *, n_queries: int, doc_pad: int, **statics):
+    """`tail` behind the launch ABI of plans with no scoring clause
+    (live_parent, score [Q], *extra): every live document matches and scores
+    its query's constant; the tail gates the match by the filter mask it takes
+    (as it does for a scored plan) and ranks and reduces. No postings plane,
+    head row, document table or coord is an operand."""
+    def wrapper(live_parent, score, *extra):
+        import jax
+        import jax.numpy as jnp
+
+        with jax.named_scope("match_const"):
+            match = jnp.broadcast_to(live_parent[None, :], (n_queries, doc_pad))
+            scores = jnp.broadcast_to(score[:, None], (n_queries, doc_pad))
+        return tail(scores, match, *extra, **statics)
+
+    return wrapper
+
+
+def _site(site: str, suffix: str) -> str:
+    """The warm registry's site of a launch: `scoring.sorted` → with the
+    unscored ABI `scoring.sorted_unscored`, a builder of its own below."""
+    return site + "_" + suffix if suffix else site
+
+
+def _abi_for(batch):
+    """(the launch ABI, the suffix of its programs' names and warm sites)."""
+    return (_unscored_abi, "unscored") if isinstance(batch, ConstBatch) \
+        else (_dense_abi, "")
 
 
 def _get_compiled(n_queries: int, k: int, doc_pad: int, simple: bool = False):
@@ -591,7 +661,10 @@ def _doc_table(packed: PackedSegment, batch: TermBatch):
 def _dense_args(packed: PackedSegment, batch: TermBatch, *host):
     """The argument list of a dense launch (the _dense_abi order): resident
     planes and tables, then the batch's three operand planes and the family's
-    own `host` operands, all put on the device in one transfer."""
+    own `host` operands, all put on the device in one transfer. A ConstBatch
+    (_unscored_abi) names no resident plane but the live mask."""
+    if isinstance(batch, ConstBatch):
+        return (packed.live_parent, *_put_operands(batch.score, *host))
     doc_table = _doc_table(packed, batch)
     head_rows = ensure_head_rows(packed)
     _count_dense(packed, batch)
@@ -661,6 +734,14 @@ def _no_mask():
     return _put_operands(np.ones((1, 1), dtype=bool))[0]
 
 
+@functools.lru_cache(maxsize=None)
+def _false_row(doc_pad: int):
+    """A resident [Dpad] row that matches nothing: what pads a mask matrix of
+    resident rows to the next query count of the ladder
+    (execute._filter_mask_matrix), put once a `doc_pad`."""
+    return _put_operands(np.zeros(doc_pad, dtype=bool))[0]
+
+
 def score_filtered_batch(packed: PackedSegment, batch: TermBatch, k: int, fmask):
     """Dense launch with match-gating filter masks (the device form of the
     reference's FilteredQuery — the filter gates matching, never scoring,
@@ -670,9 +751,21 @@ def score_filtered_batch(packed: PackedSegment, batch: TermBatch, k: int, fmask)
     if empty is None:
         (empty,) = _put_operands(np.zeros((0, 5, packed.doc_pad), np.float32))
         packed.agg_stacks[()] = empty
+    q = batch.n_queries
+    pad = _pow2_bucket(q, 1) - q
+    if pad and isinstance(batch, ConstBatch):
+        # unscored plans coalesce in any number: the query count rides the
+        # pow-2 ladder (padding queries match nothing and are sliced off), so
+        # a window meets four programs, not one for each count. A mask matrix
+        # comes padded (execute._filter_mask_matrix, resident rows or not)
+        batch = ConstBatch(np.concatenate([batch.score,
+                                           np.zeros(pad, np.float32)]))
+        if fmask is None:
+            fmask = np.concatenate([np.ones((q, packed.doc_pad), bool),
+                                    np.zeros((pad, packed.doc_pad), bool)])
     scores, docs, total, _counts, _stats, _buckets = score_agg_batch(
         packed, batch, k, empty, (), fmask=fmask, filtered=True)
-    return scores, docs, total
+    return scores[:q], docs[:q], total[:q]
 
 
 def _dense_sort_impl(scores, match,
@@ -704,15 +797,16 @@ def _dense_sort_impl(scores, match,
 
 
 def _get_sorted_compiled(n_queries: int, k: int, doc_pad: int,
-                         descending: bool):
+                         descending: bool, abi=_dense_abi, suffix: str = ""):
     import jax
 
-    key = ("sorted", n_queries, k, doc_pad, descending)
+    key = ("sorted", n_queries, k, doc_pad, descending) + (
+        (suffix,) if suffix else ())
     fn = _compiled_cache.get(key)
     if fn is None:
-        wrapper = _dense_abi(_dense_sort_impl, n_queries=n_queries,
-                             doc_pad=doc_pad, k=k, descending=descending)
-        fn = jax.jit(_named("scoring.sorted", wrapper))
+        wrapper = abi(_dense_sort_impl, n_queries=n_queries,
+                      doc_pad=doc_pad, k=k, descending=descending)
+        fn = jax.jit(_named("scoring.sorted", wrapper, suffix))
         _compiled_cache[key] = fn
     return fn
 
@@ -724,10 +818,14 @@ def score_sorted_batch(packed: PackedSegment, batch: TermBatch, k: int,
     (padding ranks strictly after ±FLT_MAX missing keys)."""
     params = (batch.n_queries, min(k, packed.doc_pad), packed.doc_pad,
               descending)
-    fn = _get_sorted_compiled(*params)
+    abi, suffix = _abi_for(batch)
+    fn = _get_sorted_compiled(*params, abi, suffix)
+    if suffix:
+        _count_unscored(packed, batch, row_bytes=4)
     args = _dense_args(packed, batch, _no_mask() if fmask is None else fmask,
                        key_row)
-    return _pull(_launch(fn, args, "scoring.sorted", "sorted", params))
+    return _pull(_launch(fn, args, _site("scoring.sorted", suffix), "sorted",
+                         params))
 
 
 def agg_stat_reduction(match, agg_rows):
@@ -802,7 +900,7 @@ def _dense_aggstats_impl(scores, match,
 
 
 def _get_agg_compiled(n_queries: int, k: int, doc_pad: int, nb_bucket: int,
-                      filtered: bool = False):
+                      filtered: bool = False, abi=_dense_abi, suffix: str = ""):
     import jax
 
     # bucket-agg count rides the pow-2 ladder: the wrapper is generic over the
@@ -810,12 +908,14 @@ def _get_agg_compiled(n_queries: int, k: int, doc_pad: int, nb_bucket: int,
     # raw len() here would admit one executable per distinct agg count.
     # `filtered` only names the program: the filtered family rides this site
     # with an empty agg stack, and its launches should read as its own.
-    key = ("aggstats", n_queries, k, doc_pad, nb_bucket, filtered)
+    key = ("aggstats", n_queries, k, doc_pad, nb_bucket, filtered) + (
+        (suffix,) if suffix else ())
     fn = _compiled_cache.get(key)
     if fn is None:
-        wrapper = _dense_abi(_dense_aggstats_impl, n_queries=n_queries,
-                             doc_pad=doc_pad, k=k)
-        fn = jax.jit(_named("scoring.aggs", wrapper, "filtered" if filtered else ""))
+        wrapper = abi(_dense_aggstats_impl, n_queries=n_queries,
+                      doc_pad=doc_pad, k=k)
+        fn = jax.jit(_named("scoring.aggs", wrapper, "_".join(filter(
+            None, ["filtered" if filtered else "", suffix]))))
         _compiled_cache[key] = fn
     return fn
 
@@ -834,7 +934,10 @@ def score_agg_batch(packed: PackedSegment, batch: TermBatch, k: int,
     params = (batch.n_queries, min(k, packed.doc_pad), packed.doc_pad,
               _pow2_bucket(len(bucket_pairs), 1) if bucket_pairs else 0,
               filtered)
-    fn = _get_agg_compiled(*params)
+    abi, suffix = _abi_for(batch)
+    fn = _get_agg_compiled(*params, abi, suffix)
+    if suffix:
+        _count_unscored(packed, batch, row_bytes=0)
     if fmask is None:
         fmask = _no_mask()
     # a host agg stack or mask rides the launch's one put (device arrays
@@ -843,7 +946,8 @@ def score_agg_batch(packed: PackedSegment, batch: TermBatch, k: int,
     # ONE explicit pull for the whole result pytree: per-leaf np.asarray was a
     # transfer per output — and an implicit one, which the promoted
     # transfer_guard("disallow") sanitizer now rejects
-    return _pull(_launch(fn, args, "scoring.aggs", "aggs", params))
+    return _pull(_launch(fn, args, _site("scoring.aggs", suffix), "aggs",
+                         params))
 
 
 def _detect_simple(batch: TermBatch) -> bool:
@@ -1484,6 +1588,16 @@ def _build_sorted(params):
 @_WARM.builder("scoring.aggs")
 def _build_aggs(params):
     return _get_agg_compiled(*params)
+
+
+@_WARM.builder("scoring.sorted_unscored")
+def _build_sorted_unscored(params):
+    return _get_sorted_compiled(*params, _unscored_abi, "unscored")
+
+
+@_WARM.builder("scoring.aggs_unscored")
+def _build_aggs_unscored(params):
+    return _get_agg_compiled(*params, _unscored_abi, "unscored")
 
 
 @_WARM.builder("scoring.fs_rows")

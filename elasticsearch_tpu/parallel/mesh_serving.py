@@ -240,9 +240,10 @@ class MeshServingService:
             filt = query.filter
             query = query.query
         plan = lower_flat(query, ctx0)
-        if plan is None or plan.fs is not None or plan.filt is not None:
-            # function_score / nested-filtered plans carry a device tail the mesh
-            # program doesn't express — transport path (which itself serves them
+        if (plan is None or plan.fs is not None or plan.filt is not None
+                or plan.const is not None):
+            # function_score / nested-filtered / unscored plans carry a device
+            # tail the mesh program doesn't express — transport path (which itself serves them
             # on-device via execute_flat_batch's fs/filtered kernels)
             return None
 

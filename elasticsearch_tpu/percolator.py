@@ -102,7 +102,7 @@ class PercolatorRegistry:
                     plan = lower_flat(query, ctx)
                 except Exception:  # noqa: BLE001 — lowering trouble → host path
                     plan = None
-                if plan is not None:
+                if plan is not None and plan.const is None:
                     flat_plans.append(plan)
                     flat_qids.append(qid)
                 else:

@@ -36,7 +36,15 @@ from ..common import profile as _profile
 from ..common.errors import QueryParsingError
 from ..index.engine import Searcher
 from ..index.segment import FrozenSegment
-from .filters import Filter, MatchAllFilter, segment_mask
+from .filters import (
+    BoolFilter,
+    Filter,
+    MatchAllFilter,
+    QueryWrapperFilter,
+    RangeFilter,
+    TermFilter,
+    segment_mask,
+)
 from .queries import (
     BoolQuery,
     BoostingQuery,
@@ -77,6 +85,7 @@ from ..common import tracing
 from ..common.breaker import reserve
 from ..common.devicehealth import tag_domain as _tag_domain
 from ..common.jaxenv import compile_tag
+from ..ops.device_index import HOST_ONLY_FIELDS
 from ..transport.faults import DEVICE_FAULTS as _DEVICE_FAULTS
 from ..transport.faults import DEVICE_PULL as _DEVICE_PULL
 from .similarity import (
@@ -204,6 +213,14 @@ class FlatPlan:
     # untouched for matched docs — HostScorer FilteredQuery branch); evaluated
     # host-side per segment via the filter cache and shipped as a mask row
     filt: object = None  # Filter | None
+    # a plan with NO scoring clause (match_all, constant_score, a range or
+    # numeric term query, a bare filter or must_not bool — `_unscored`): no
+    # clause at all; every live document `filt` admits (all, where it is
+    # None) matches and scores `const` x queryNorm (unscored_score), the
+    # product of boosts the host scorer gives its constant. `norm_boost`
+    # then holds what the host's queryNorm pre-pass squares for the query
+    # (the same product, or 0 where the pre-pass counts nothing)
+    const: float | None = None
 
 
 # ---------------------------------------------------------------------------
@@ -257,13 +274,69 @@ def lower_flat(query: Query, ctx: ShardContext) -> FlatPlan | None:
     plan = _lower_flat_inner(query, ctx)
     if plan is not None:
         for c in plan.clauses:
+            if c.field in HOST_ONLY_FIELDS:
+                return None  # its postings are not in the device planes
             if not isinstance(ctx.similarity_for(c.field),
                               (BM25Similarity, TFIDFSimilarity)):
                 return None
     return plan
 
 
+def _unscored(query: Query, ctx: ShardContext, boost: float):
+    """(const, filter | None, norm_boost) of a query with no scoring clause,
+    or None: the filter whose mask is the query's whole match set (None: every
+    document), the constant HostScorer scores a match before queryNorm
+    (boosts multiplied downward in its order, so the product is bitwise its
+    own) and what _weight_prepass squares for it."""
+    b = boost * getattr(query, "boost", 1.0)
+    if isinstance(query, MatchAllQuery):
+        return b, None, b
+    if isinstance(query, RangeQuery):
+        return b, RangeFilter(
+            query.field, query.gte, query.gt, query.lte, query.lt), b
+    if isinstance(query, TermQuery):
+        ft = ctx.field_type(query.field)
+        if ft is None or not ft.is_numeric:
+            return None
+        return b, TermFilter(query.field, query.value), 0.0
+    if isinstance(query, ConstantScoreQuery):
+        return b, (query.filter if query.filter is not None
+                   else QueryWrapperFilter(query.query)), b
+    if isinstance(query, BoolQuery) and not query.must and not query.should:
+        # filter/must_not-only bool: all remaining documents (HostScorer.
+        # _eval_bool scores them `b * q.boost`, and the pre-pass sums nothing)
+        filt = None
+        if query.filter or query.must_not:
+            filt = BoolFilter(
+                must=list(query.filter),
+                must_not=[QueryWrapperFilter(q) for q in query.must_not])
+        return b * query.boost, filt, 0.0
+    if isinstance(query, FilteredQuery):
+        sub = _unscored(query.query, ctx, b)
+        if sub is None:
+            return None
+        const, filt, norm_boost = sub
+        return const, (query.filter if filt is None
+                       else BoolFilter(must=[filt, query.filter])), norm_boost
+    return None
+
+
+def unscored_score(plan: FlatPlan, ctx: ShardContext) -> np.float32:
+    """The score of every match of a plan with no scoring clause, bitwise
+    HostScorer._const's: the boost product times queryNorm as float32."""
+    qn = 1.0
+    if isinstance(ctx.default_similarity, TFIDFSimilarity) and plan.norm_boost:
+        qn = float(TFIDFSimilarity.query_norm(
+            float(plan.norm_boost * plan.norm_boost)))
+    return np.float32(plan.const * np.float32(qn))
+
+
 def _lower_flat_inner(query: Query, ctx: ShardContext) -> FlatPlan | None:
+    unscored = _unscored(query, ctx, 1.0)
+    if unscored is not None:
+        const, filt, norm_boost = unscored
+        return FlatPlan([], msm=0, n_must=0, coord_enabled=False, boost=1.0,
+                        norm_boost=norm_boost, filt=filt, const=const)
     if isinstance(query, TermQuery):
         ft = ctx.field_type(query.field)
         if ft is not None and ft.is_numeric:
@@ -305,10 +378,6 @@ def _lower_flat_inner(query: Query, ctx: ShardContext) -> FlatPlan | None:
                 n_scoring += 1
             if group == GROUP_SHOULD:
                 n_should += 1
-        if n_scoring == 0:
-            # must_not-only bool matches all non-excluded docs — the kernel's
-            # "matched at least one scoring clause" gate can't express that; host path
-            return None
         n_must = sum(1 for c in clauses if c.group == GROUP_MUST)
         msm = calculate_msm(query.minimum_should_match, n_should)
         if msm == 0 and n_should > 0 and n_must == 0:
@@ -324,7 +393,8 @@ def _lower_flat_inner(query: Query, ctx: ShardContext) -> FlatPlan | None:
         if query.query is None:
             return None
         sub = _lower_flat_inner(query.query, ctx)
-        if sub is None or sub.fs is not None or sub.filt is not None:
+        if (sub is None or sub.fs is not None or sub.filt is not None
+                or sub.const is not None):
             return None
         kind = _classify_fs(query)
         if kind is None:
@@ -409,6 +479,7 @@ def plan_profile(plan: FlatPlan, query: Query) -> dict:
         "boost": float(plan.boost),
         "function_score": plan.fs_kind,  # None | "rows" | "script"
         "filtered": plan.filt is not None,
+        "unscored": plan.const is not None,  # no scoring clause: mask & const
     }
 
 
@@ -419,10 +490,11 @@ def lower_fallback_reason(query: Query, ctx: ShardContext) -> str:
     pays. The classification mirrors _lower_flat_inner's decline points; when
     the inner lowering actually SUCCEEDS, the decline was lower_flat's
     similarity gate (DFR/IB/LM fields score host-side)."""
-    if _lower_flat_inner(query, ctx) is not None:
+    plan = _lower_flat_inner(query, ctx)
+    if plan is not None:
+        if any(c.field in HOST_ONLY_FIELDS for c in plan.clauses):
+            return "host_only_field"
         return "similarity_not_fused"
-    if isinstance(query, TermQuery):
-        return "numeric_term"
     if isinstance(query, MatchQuery):
         # the only non-lowering match query: fuzzy (empty analysis still
         # lowers — to an empty flat plan that scores nothing on-device)
@@ -431,14 +503,17 @@ def lower_fallback_reason(query: Query, ctx: ShardContext) -> str:
         if query.filter:
             return "bool_filter_clause"
         subs = query.must + query.should + query.must_not
-        if any(_single_term(sub, ctx) is None for sub in subs):
-            return "non_term_subclause"
-        return "must_not_only"
+        # (a bool with no must or should lowers as an unscored plan; one with
+        # them takes term subclauses alone, an unscored one among them not)
+        return "non_term_subclause"
     if isinstance(query, FunctionScoreQuery):
         if query.query is None:
             return "function_score_no_query"
-        if _lower_flat_inner(query.query, ctx) is None:
+        sub = _lower_flat_inner(query.query, ctx)
+        if sub is None:
             return "non_flat_subquery"
+        if sub.const is not None:
+            return "unscored_subquery"
         return "function_score_ineligible"
     if isinstance(query, FilteredQuery):
         return "non_flat_subquery"
@@ -494,31 +569,36 @@ def finalize_flat(plan: FlatPlan, ctx: ShardContext):
 # ---------------------------------------------------------------------------
 
 
+def _is_plain(p: FlatPlan) -> bool:
+    """A plan the sparse candidate path serves: scoring clauses and no tail."""
+    return p.fs is None and p.filt is None and p.const is None
+
+
 def execute_flat_batch(plans: list[FlatPlan], ctx: ShardContext, k: int) -> list[TopDocs]:
     """Run a batch of flat plans through the device kernels. Plain plans ride the
     sparse candidate-centric path; function_score plans are grouped by spec and
     ride the dense kernel with the function tail fused in (_execute_flat_fs);
-    filtered plans ride the dense kernel with per-query mask rows
-    (_execute_flat_filtered)."""
-    if all(p.fs is None and p.filt is None for p in plans):
+    filtered plans ride the dense kernel with per-query mask rows, and plans
+    with no scoring clause the same tail behind the unscored launch ABI
+    (_execute_flat_filtered, a launch for each kind)."""
+    if all(_is_plain(p) for p in plans):
         return _execute_flat_plain(plans, ctx, k)
     out: list[TopDocs | None] = [None] * len(plans)
-    plain_idx = [i for i, p in enumerate(plans) if p.fs is None and p.filt is None]
-    if plain_idx:
-        for i, td in zip(plain_idx,
-                         _execute_flat_plain([plans[i] for i in plain_idx], ctx, k)):
-            out[i] = td
-    filt_idx = [i for i, p in enumerate(plans) if p.filt is not None]
-    if filt_idx:
-        for i, td in zip(filt_idx,
-                         _execute_flat_filtered([plans[i] for i in filt_idx], ctx, k)):
-            out[i] = td
     groups: dict = {}
     for i, p in enumerate(plans):
-        if p.fs is not None:
-            groups.setdefault(_fs_group_key(p.fs), []).append(i)
-    for idxs in groups.values():
-        for i, td in zip(idxs, _execute_flat_fs([plans[i] for i in idxs], ctx, k)):
+        kind = ("unscored",) if p.const is not None else \
+            ("fs", *_fs_group_key(p.fs)) if p.fs is not None else \
+            ("filtered",) if p.filt is not None else ("plain",)
+        groups.setdefault(kind, []).append(i)
+    for kind, idxs in groups.items():
+        group = [plans[i] for i in idxs]
+        if kind[0] == "plain":
+            tds = _execute_flat_plain(group, ctx, k)
+        elif kind[0] == "fs":
+            tds = _execute_flat_fs(group, ctx, k)
+        else:
+            tds = _execute_flat_filtered(group, ctx, k)
+        for i, td in zip(idxs, tds):
             out[i] = td
     return out  # type: ignore[return-value]
 
@@ -631,7 +711,7 @@ def dispatch_flat_batch(plans: list[FlatPlan], ctx: ShardContext, k: int):
     Plain plans enqueue device work without syncing; batches carrying
     function_score/filtered plans run whole (synchronously) here."""
     with tracing.timing_dispatch() as clock:
-        if plans and all(p.fs is None and p.filt is None for p in plans):
+        if plans and all(_is_plain(p) for p in plans):
             pending = _dispatch_flat_plain(plans, ctx, k)
             pending.clock = clock
             return pending
@@ -986,6 +1066,34 @@ def _prof_dense_segment(prof, seg, packed, batch, path: str, t_seg: float):
                  launches=1, ms=(time.monotonic() - t_seg) * 1000.0)
 
 
+def _segment_batches(plans: list[FlatPlan], ctx: ShardContext):
+    """`batch_for(seg, packed)`: the operands of a dense launch of `plans`
+    on one segment. Scored plans are finalized and assembled once and staged a
+    segment at a time (a scoring.TermBatch); plans with no scoring clause
+    (all of `plans` or none: callers do not mix) stage their constant scores
+    alone, the same scoring.ConstBatch for every segment."""
+    if plans[0].const is not None:
+        from ..ops.scoring import LAUNCHES, ConstBatch
+
+        LAUNCHES.bump(unscored_plans=len(plans))
+        batch = ConstBatch(np.array([unscored_score(p, ctx) for p in plans],
+                                    np.float32))
+        return lambda seg, packed: batch
+    Q = len(plans)
+    finals = [finalize_flat(p, ctx) for p in plans]
+    (all_fields, field_idx, _cache_rows, caches_stack,
+     coord_tbl, n_must, msm) = _assemble_batch(plans, finals)
+
+    def batch_for(seg, packed):
+        _ensure_norm_rows(packed, all_fields,
+                          breaker=ctx.breaker("fielddata"))
+        entries = _dense_entries(finals, seg, packed, field_idx)
+        return _term_batch(entries, Q, n_must, msm, coord_tbl,
+                           list(all_fields), caches_stack, packed)
+
+    return batch_for
+
+
 _FS_CHUNK = 256  # dense accumulator is O(Q·doc_pad) — bound the launch width
 
 
@@ -1101,9 +1209,13 @@ def _execute_flat_fs(plans: list[FlatPlan], ctx: ShardContext, k: int) -> list[T
     ]
 
 
-def _filter_mask_matrix(filters: list, seg, packed, ctx: ShardContext):
+def _filter_mask_matrix(filters: list, seg, packed, ctx: ShardContext,
+                        n_rows: int | None = None):
     """The [Q, Dpad] FilteredQuery mask the dense kernels consume — the ONE
-    assembly site for the filtered/sorted paths.
+    assembly site for the filtered/sorted/aggregated paths. A query with no
+    filter (None: an unscored plan that matches every document) takes
+    MatchAllFilter's row; where no query has one the result is None and the
+    launch takes scoring's resident [1, 1] no-op.
 
     Per query: a resident device row from the node's filter cache when the
     (segment, filter-key) mask is already in HBM (zero host evaluation, zero
@@ -1116,13 +1228,22 @@ def _filter_mask_matrix(filters: list, seg, packed, ctx: ShardContext):
     Returns a host bool [Q, Dpad] when every row stayed host-side (the
     pre-cache behavior, one implicit-free jnp.asarray commit at dispatch) or
     a device [Q, Dpad] stack when any row is resident (host stragglers are
-    device_put explicitly)."""
-    from .filters import segment_mask
-
+    device_put explicitly). Where a row was evaluated on the host, the whole
+    assembly is noted on the dispatch clock as `shard.filter_mask` (inside
+    its `dispatch.stage`), and every host row's bytes are counted as
+    `search_serving.launch.mask_put_bytes`. `n_rows` pads the matrix with
+    rows that match nothing (a coalesced batch of unscored plans rides the
+    pow-2 ladder of query counts, whether its rows are resident or not)."""
+    if all(f is None for f in filters):
+        return None
     fc = ctx.filter_cache
     rows = []
     any_dev = False
+    host_bytes = 0
+    t0 = time.monotonic()
     for f in filters:
+        if f is None:
+            f = MatchAllFilter()
         row = None
         key = None
         if fc is not None and fc.enabled and f.cacheable():
@@ -1135,20 +1256,34 @@ def _filter_mask_matrix(filters: list, seg, packed, ctx: ShardContext):
                 row = fc.maybe_store(seg, key, m)
             if row is None:
                 row = m
+            host_bytes += m.nbytes
         if not isinstance(row, np.ndarray):
             any_dev = True
         rows.append(row)
-    if not any_dev:
-        return np.stack(rows)
-    import jax
-    import jax.numpy as jnp
+    if n_rows is not None and n_rows > len(rows):
+        from ..ops.scoring import _false_row
 
-    # compile_tag: the eager stack fuses cached device rows with fresh host
-    # masks for the filtered kernels — outermost scope wins, so launches from
-    # inside dense/sorted paths keep their own family.
-    with compile_tag("filtered"):
-        return jnp.stack([row if not isinstance(row, np.ndarray)
-                          else jax.device_put(row) for row in rows])
+        rows.extend([_false_row(packed.doc_pad) if any_dev
+                     else np.zeros(packed.doc_pad, dtype=bool)]
+                    * (n_rows - len(rows)))
+    if not any_dev:
+        out = np.stack(rows)
+    else:
+        import jax
+        import jax.numpy as jnp
+
+        # compile_tag: the eager stack fuses cached device rows with fresh host
+        # masks for the filtered kernels — outermost scope wins, so launches from
+        # inside dense/sorted paths keep their own family.
+        with compile_tag("filtered"):
+            out = jnp.stack([row if not isinstance(row, np.ndarray)
+                             else jax.device_put(row) for row in rows])
+    if host_bytes:
+        from ..ops.scoring import LAUNCHES
+
+        LAUNCHES.bump(mask_put_bytes=host_bytes)
+        tracing.note("shard.filter_mask", t0)
+    return out
 
 
 def _execute_flat_filtered(plans: list[FlatPlan], ctx: ShardContext,
@@ -1156,8 +1291,10 @@ def _execute_flat_filtered(plans: list[FlatPlan], ctx: ShardContext,
     """Filtered plans: per-query filter masks (host-evaluated via the per-segment
     filter cache — the same masks the host scorer uses) gate matching inside the
     dense kernel. Scores/weights are untouched, so sub-query scoring parity is
-    inherited from the plain path."""
-    from ..ops.device_index import packed_for
+    inherited from the plain path. Plans with no scoring clause come here too
+    (all of `plans` or none): their mask is their whole match set, their
+    score a constant, and hits of equal score merge in document order."""
+    from ..ops.device_index import _pow2_bucket, packed_for
     from ..ops.scoring import score_filtered_batch
 
     if len(plans) > _FS_CHUNK:
@@ -1168,9 +1305,7 @@ def _execute_flat_filtered(plans: list[FlatPlan], ctx: ShardContext,
         return out
 
     Q = len(plans)
-    finals = [finalize_flat(p, ctx) for p in plans]
-    (all_fields, field_idx, _cache_rows, caches_stack,
-     coord_tbl, n_must, msm) = _assemble_batch(plans, finals)
+    batch_for = _segment_batches(plans, ctx)
     totals = np.zeros(Q, dtype=np.int64)
     seg_hits = []
     prof = _profile.current()
@@ -1178,13 +1313,10 @@ def _execute_flat_filtered(plans: list[FlatPlan], ctx: ShardContext,
         t_seg = time.monotonic() if prof is not None else 0.0
         packed = packed_for(seg, breaker=ctx.breaker("fielddata"),
                             owner=ctx.index_name)
-        _ensure_norm_rows(packed, all_fields,
-                          breaker=ctx.breaker("fielddata"))
-        fmask = _filter_mask_matrix([plan.filt for plan in plans], seg,
-                                    packed, ctx)
-        entries = _dense_entries(finals, seg, packed, field_idx)
-        batch = _term_batch(entries, Q, n_must, msm, coord_tbl,
-                            list(all_fields), caches_stack, packed)
+        batch = batch_for(seg, packed)
+        fmask = _filter_mask_matrix(
+            [plan.filt for plan in plans], seg, packed, ctx,
+            n_rows=_pow2_bucket(Q, 1) if plans[0].const is not None else Q)
         with compile_tag("filtered"):
             scores, docs, tq = score_filtered_batch(packed, batch, k, fmask)
         totals += tq
@@ -1197,28 +1329,53 @@ def _execute_flat_filtered(plans: list[FlatPlan], ctx: ShardContext,
                            breaker=ctx.breaker("request"))
 
 
+def _sort_key_row(spec, seg, packed, breaker=None):
+    """The device-resident f32 [Dpad] key row of a field sort on one segment,
+    or None where the host has to sort (sorting.device_sort_key_row /
+    device_sort_rank_row say why). Kept on the packed segment per (field,
+    mode, order, missing), FIFO-bounded like the agg stacks: a warmed sorted
+    launch evaluates no column and puts no row."""
+    from ..ops.scoring import _put_operands
+    from .sorting import device_sort_key_row, device_sort_rank_row
+
+    key = (spec.field, spec.mode, spec.order, repr(spec.missing))
+    row = packed.sort_rows.get(key)
+    if row is None:
+        host = device_sort_key_row(spec, seg, packed.doc_pad)
+        if host is None:
+            host = device_sort_rank_row(spec, seg, packed.doc_pad)
+        if host is None:
+            return None
+        with reserve(breaker, host.nbytes * 2, f"<sort_row>{spec.field}"):
+            (row,) = _put_operands(host)
+        while len(packed.sort_rows) >= 8:
+            packed.sort_rows.pop(next(iter(packed.sort_rows)), None)
+        packed.sort_rows[key] = row
+    return row
+
+
 def execute_flat_sorted(plan: FlatPlan, ctx: ShardContext, k: int, spec):
     """Single-plan field-sorted dense execution: returns
     (total, max_score, ordered entries [(key, gdoc, seg_idx, local, score)])
-    or None when any segment's column refuses device keys
-    (sorting.device_sort_key_row). Ordering: (key asc/desc, global doc asc) —
-    the host lexsort order."""
+    or None when any segment's column refuses device keys (_sort_key_row).
+    Ordering: (key asc/desc, global doc asc) — the host lexsort order; `key`
+    is the document's exact float64 value (sorting.exact_sort_keys), because
+    a segment's device keys may be ranks that another segment's do not
+    compare with."""
     from ..ops.device_index import packed_for
     from ..ops.scoring import score_sorted_batch
-    from .sorting import device_sort_key_row
+    from .sorting import exact_sort_keys
 
-    finals = [finalize_flat(plan, ctx)]
-    (all_fields, field_idx, _cache_rows, caches_stack,
-     coord_tbl, n_must, msm) = _assemble_batch([plan], finals)
     # validate EVERY segment's eligibility before the first launch — a
     # late-segment refusal must not waste completed kernel work
     packeds = [packed_for(seg, breaker=ctx.breaker("fielddata"),
                           owner=ctx.index_name)
                for seg in ctx.searcher.segments]
-    key_rows = [device_sort_key_row(spec, seg, p.doc_pad)
+    key_rows = [_sort_key_row(spec, seg, p, breaker=ctx.breaker("fielddata"))
                 for seg, p in zip(ctx.searcher.segments, packeds)]
     if any(r is None for r in key_rows):
         return None
+    batch_for = _segment_batches([plan], ctx)
     total = 0
     max_score = float("nan")
     cand = []  # (key, gdoc, seg_idx, local, score)
@@ -1226,16 +1383,10 @@ def execute_flat_sorted(plan: FlatPlan, ctx: ShardContext, k: int, spec):
     for si, (seg, base, packed, key_row) in enumerate(zip(
             ctx.searcher.segments, ctx.searcher.bases, packeds, key_rows)):
         t_seg = time.monotonic() if prof is not None else 0.0
-        _ensure_norm_rows(packed, all_fields,
-                          breaker=ctx.breaker("fielddata"))
-        fmask = None
-        if plan.filt is not None:
-            fmask = _filter_mask_matrix([plan.filt], seg, packed, ctx)
-        entries = _dense_entries(finals, seg, packed, field_idx)
-        batch = _term_batch(entries, 1, n_must, msm, coord_tbl,
-                            list(all_fields), caches_stack, packed)
+        batch = batch_for(seg, packed)
+        fmask = _filter_mask_matrix([plan.filt], seg, packed, ctx)
         with compile_tag("sorted"):
-            keys, docs, scores, qmax, tq = score_sorted_batch(
+            _keys, docs, scores, qmax, tq = score_sorted_batch(
                 packed, batch, max(k, 1), key_row, spec.reverse, fmask=fmask)
         # batched host pulls: one .tolist() per row instead of a float()/int()
         # scalar conversion per hit (tpulint TPU001)
@@ -1244,11 +1395,12 @@ def execute_flat_sorted(plan: FlatPlan, ctx: ShardContext, k: int, spec):
         if seg_total:
             (m,) = qmax.tolist()
             max_score = m if max_score != max_score else max(max_score, m)
-        n = min(seg_total, keys.shape[1])
+        n = min(seg_total, docs.shape[1])
+        locals_ = docs[0, :n]
         cand.extend(
             (ki, base + di, si, di, sc)
-            for ki, di, sc in zip(keys[0, :n].tolist(), docs[0, :n].tolist(),
-                                  scores[0, :n].tolist()))
+            for ki, di, sc in zip(exact_sort_keys(spec, seg, locals_).tolist(),
+                                  locals_.tolist(), scores[0, :n].tolist()))
         _prof_dense_segment(prof, seg, packed, batch, "dense_sorted", t_seg)
     cand.sort(key=lambda e: (-e[0] if spec.reverse else e[0], e[1]))
     return total, max_score, cand[: max(k, 0)]
@@ -1272,9 +1424,7 @@ def execute_flat_aggs(plan: FlatPlan, ctx: ShardContext, k: int,
     from ..ops.scoring import score_agg_batch
     from .aggregations import bucket_cache_key, bucket_cols_for
 
-    finals = [finalize_flat(plan, ctx)]
-    (all_fields, field_idx, _cache_rows, caches_stack,
-     coord_tbl, n_must, msm) = _assemble_batch([plan], finals)
+    batch_for = _segment_batches([plan], ctx)
     totals = np.zeros(1, dtype=np.int64)
     seg_hits = []
     seg_stats = []
@@ -1283,8 +1433,7 @@ def execute_flat_aggs(plan: FlatPlan, ctx: ShardContext, k: int,
         t_seg = time.monotonic() if prof is not None else 0.0
         packed = packed_for(seg, breaker=ctx.breaker("fielddata"),
                             owner=ctx.index_name)
-        _ensure_norm_rows(packed, all_fields,
-                          breaker=ctx.breaker("fielddata"))
+        batch = batch_for(seg, packed)
         stack = ensure_agg_rows(seg, packed, fields,
                                 breaker=ctx.breaker("fielddata"))
         if stack is None:
@@ -1318,12 +1467,7 @@ def execute_flat_aggs(plan: FlatPlan, ctx: ShardContext, k: int,
                     return None, None  # sub column not f32-exact → host
             pair_args.append((dev[0], dev[1], dev[2], sub_stack))
             seg_keys.append(keys)
-        entries = _dense_entries(finals, seg, packed, field_idx)
-        batch = _term_batch(entries, 1, n_must, msm, coord_tbl,
-                            list(all_fields), caches_stack, packed)
-        fmask = None
-        if plan.filt is not None:
-            fmask = _filter_mask_matrix([plan.filt], seg, packed, ctx)
+        fmask = _filter_mask_matrix([plan.filt], seg, packed, ctx)
         with compile_tag("aggs"):
             scores, docs, tq, counts, stats, bcounts = score_agg_batch(
                 packed, batch, k, stack, tuple(pair_args), fmask=fmask)

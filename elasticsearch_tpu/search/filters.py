@@ -15,6 +15,7 @@ device fast path via PackedSegment.dv_single (used by function_score and sort).
 from __future__ import annotations
 
 import re
+import threading
 from dataclasses import dataclass, field as dc_field
 from typing import Any
 
@@ -23,6 +24,7 @@ import numpy as np
 from ..common.errors import QueryParsingError
 from ..index.segment import FrozenSegment
 from ..mapper.core import parse_date_math
+from ..ops.device_index import RecentKeys
 
 
 class Filter:
@@ -40,8 +42,14 @@ class Filter:
         return True
 
 
+_SIGHTINGS_LOCK = threading.Lock()  # leaf: guards every segment's RecentKeys
+
+
 def segment_mask(seg: FrozenSegment, f: Filter, ctx) -> np.ndarray:
-    """Cached evaluation (the filter cache). ctx carries the mapper service."""
+    """Cached evaluation (the segment's host filter cache). A mask is kept
+    from its second sighting within the recent history on (RecentKeys, as
+    the device filter cache admits): a filter that never recurs is evaluated
+    and dropped. ctx carries the mapper service."""
     if not f.cacheable():
         return f.evaluate(seg, ctx)
     cache = seg._device_cache.setdefault("filters", {})
@@ -49,7 +57,13 @@ def segment_mask(seg: FrozenSegment, f: Filter, ctx) -> np.ndarray:
     m = cache.get(k)
     if m is None:
         m = f.evaluate(seg, ctx)
-        cache[k] = m
+        with _SIGHTINGS_LOCK:
+            seen = seg._device_cache.get("filter_sightings")
+            if seen is None:
+                seen = seg._device_cache["filter_sightings"] = RecentKeys()
+            keep = seen.sight(k) >= 2
+        if keep:
+            cache[k] = m
     return m
 
 
@@ -68,10 +82,15 @@ def _num_column_mask(seg: FrozenSegment, field: str, pred) -> np.ndarray:
     off, vals = col
     if len(vals) == 0:
         return mask
-    hit = pred(vals)
-    counts = np.diff(off)
-    doc_of_val = np.repeat(np.arange(seg.doc_count), counts)
-    np.logical_or.at(mask, doc_of_val, hit)
+    # each value's document, a pure function of the immutable column: kept
+    # with the segment, so a filter that is the whole query (a dashboard's
+    # window over a million events) pays the predicate and one indexed store
+    ckey = ("doc_of_val", field)
+    doc_of_val = seg._device_cache.get(ckey)
+    if doc_of_val is None:
+        doc_of_val = seg._device_cache[ckey] = np.repeat(
+            np.arange(seg.doc_count), np.diff(off))
+    mask[doc_of_val[pred(vals)]] = True
     return mask
 
 

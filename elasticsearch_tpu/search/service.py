@@ -322,13 +322,23 @@ def execute_query_phase(ctx: ShardContext, req: ParsedSearchRequest,
     if not needs_masks:
         t_low = time.monotonic() if prof is not None else 0.0
         plan = lower_flat(req.query, ctx) if use_device else None
+        if plan is not None and plan.const is not None and k == 0:
+            # a bare count (`_count` is a search of size 0): summing a mask on
+            # the host launches nothing, packs nothing and compiles nothing.
+            # On the device it packed every unmerged segment of a fresh index
+            # and made the force-merge's pack a 25-100 s device concat that
+            # the first real search then waited out (PERF.md section 6, PR 31)
+            plan = None
         if prof is not None:
             prof.phase_s("lower", time.monotonic() - t_low)
             _prof_record_plan(prof, plan, req, ctx, use_device)
         degraded = False
         if plan is not None:
+            # a plan with no scoring clause launches the filtered family's
+            # tail behind its own ABI, whether or not it carries a filter
+            masked = plan.filt is not None or plan.const is not None
             fams = ("function_score",) if plan.fs is not None else \
-                ("filtered",) if plan.filt is not None else ("sparse", "dense")
+                ("filtered",) if masked else ("sparse", "dense")
             dom = _blocked_domain(ctx, fams)
             if dom is not None:
                 _device_degraded(dom)  # open fault domain: host serves, no launch
@@ -349,7 +359,7 @@ def execute_query_phase(ctx: ShardContext, req: ParsedSearchRequest,
                 else:
                     _note_device_ok(ctx, fams)
                     _count("device_function_score" if plan.fs is not None
-                           else "device_filtered" if plan.filt is not None
+                           else "device_filtered" if masked
                            else "device_sparse")
                     return ShardQueryResult(
                         total=td.total, docs=[(s, d, None) for s, d in td.hits],

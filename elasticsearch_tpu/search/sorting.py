@@ -149,9 +149,10 @@ def sort_key_column(spec: SortSpec, seg, ctx, scores: np.ndarray | None) -> np.n
         return out
     col = seg.dv_num.get(spec.field)
     if col is not None:
+        if spec.mode in (None, "min", "max"):
+            return _sort_fold(spec, seg)[0]  # cached with the segment
         off, vals = col
-        mode = spec.mode or ("min" if spec.order == "asc" else "max")
-        return _reduce_multi(off, vals, D, mode)
+        return _reduce_multi(off, vals, D, spec.mode)
     scol = seg.dv_str.get(spec.field)
     if scol is not None:
         # string sort via GLOBAL ordinals would not merge across segments/shards;
@@ -169,60 +170,131 @@ def sort_key_column(spec: SortSpec, seg, ctx, scores: np.ndarray | None) -> np.n
 
 
 _F32_MAX = float(np.finfo(np.float32).max)
+# a rank row is exact in float32 while its ranks, and the half ranks a custom
+# `missing` may take between two of them, stay below 2**23
+_RANKS_MAX = 1 << 23
+
+
+def _fold_mode(spec: SortSpec) -> str:
+    return spec.mode or ("min" if spec.order == "asc" else "max")
+
+
+def _sort_fold(spec: SortSpec, seg):
+    """(per-document key f64 [D] with NaN = missing, how the device may hold
+    it) of a numeric column under the spec's mode: "f32" where every value is
+    exactly a float32, "rank" where the values are whole numbers a float32
+    cannot hold (epoch milliseconds need 40 bits) and few enough distinct for
+    their dense rank to be one, else None (fractional float64: the host
+    sorts). A pure function of the immutable (segment column, mode), cached
+    with the segment: hot sorted searches re-scan no column."""
+    ckey = ("sort_keys", spec.field, _fold_mode(spec))
+    held = seg._device_cache.get(ckey)
+    if held is None:
+        col = seg.dv_num.get(spec.field)
+        if col is None:
+            held = (np.full(seg.doc_count, np.nan), "f32")
+        else:
+            off, vals = col
+            keys = _reduce_multi(off, vals, seg.doc_count, _fold_mode(spec))
+            if not len(vals) or (
+                    np.array_equal(vals.astype(np.float32).astype(np.float64),
+                                   vals)
+                    and np.abs(vals).max() < _F32_MAX / 2):
+                held = (keys, "f32")
+            elif np.array_equal(np.rint(vals), vals):
+                held = (keys, "rank")
+            else:
+                held = (keys, None)
+        seg._device_cache[ckey] = held
+    return held
+
+
+def _missing_fill(spec: SortSpec) -> float | None:
+    """The float32 key a missing document takes, or None (a custom numeric
+    fill that float32 does not hold). ±FLT_MAX, not ±inf: the kernel ranks
+    missing documents after real keys and before its ±inf padding."""
+    if spec.missing == "_last":
+        return _F32_MAX if not spec.reverse else -_F32_MAX
+    if spec.missing == "_first":
+        return -_F32_MAX if not spec.reverse else _F32_MAX
+    try:
+        fill = float(spec.missing)
+    except (TypeError, ValueError):
+        return _F32_MAX
+    return fill if float(np.float32(fill)) == fill else None
+
+
+def _padded_row(keys: np.ndarray, spec: SortSpec, doc_pad: int) -> np.ndarray:
+    row = np.full(doc_pad, _F32_MAX if not spec.reverse else -_F32_MAX,
+                  dtype=np.float32)
+    row[: len(keys)] = keys.astype(np.float32)
+    return row
 
 
 def device_sort_key_row(spec: SortSpec, seg, doc_pad: int) -> np.ndarray | None:
-    """float32 [doc_pad] ascending-semantics key row for the device sort kernel,
-    or None when the spec/column needs the host path.
+    """float32 [doc_pad] ascending-semantics key row of the VALUES for the
+    device sort kernels, or None when the spec/column needs another path.
 
     Sort order is deterministic user-visible state, so only columns whose values
-    are EXACTLY float32-representable ride the kernel (fractional f64 rounding
-    could swap strict orderings); avg/sum modes divide/accumulate in f64 on the
-    host and stay there. Missing docs take ±FLT_MAX (not ±inf) so the kernel can
-    rank them after real keys but before its ±inf padding; custom numeric
-    missing fills must be f32-exact too."""
+    are EXACTLY float32-representable ride as values (rounding could swap strict
+    orderings); avg/sum modes divide/accumulate in f64 on the host and stay
+    there. Custom numeric missing fills must be f32-exact too. The mesh
+    program compares keys across shards, so values are all it can take; one
+    segment's launch also takes ranks (device_sort_rank_row)."""
     if spec.kind != "field" or spec.mode in ("avg", "sum"):
         return None
     if spec.field in seg.dv_str and spec.field not in seg.dv_num:
         return None
-    mode = spec.mode or ("min" if spec.order == "asc" else "max")
-    # the exactness check + per-doc fold are pure functions of the immutable
-    # (segment column, mode) — cache them so hot sorted queries don't re-scan
-    # the column (missing/order handling below is per-spec and cheap)
-    ckey = ("sort_keys", spec.field, mode)
-    keys = seg._device_cache.get(ckey)
-    if keys is None:
-        col = seg.dv_num.get(spec.field)
-        if col is None:
-            keys = np.full(seg.doc_count, np.nan)
-        else:
-            off, vals = col
-            if len(vals) and (
-                    not np.array_equal(
-                        vals.astype(np.float32).astype(np.float64), vals)
-                    or np.abs(vals).max() >= _F32_MAX / 2):
-                keys = "inexact"
-            else:
-                keys = _reduce_multi(off, vals, seg.doc_count, mode)
-        seg._device_cache[ckey] = keys
-    if isinstance(keys, str):
+    keys, how = _sort_fold(spec, seg)
+    fill = _missing_fill(spec)
+    if how != "f32" or fill is None:
         return None
-    if spec.missing == "_last":
-        fill = _F32_MAX if not spec.reverse else -_F32_MAX
-    elif spec.missing == "_first":
-        fill = -_F32_MAX if not spec.reverse else _F32_MAX
+    return _padded_row(np.where(np.isnan(keys), fill, keys), spec, doc_pad)
+
+
+def device_sort_rank_row(spec: SortSpec, seg, doc_pad: int) -> np.ndarray | None:
+    """float32 [doc_pad] key row for ONE segment's launch where the column's
+    whole numbers pass float32: each document's key is the dense rank of its
+    value among the segment's distinct values, which orders as the values do,
+    keeps ties (so the kernel's lower-index preference breaks them by doc id
+    as before) and is exact below 2**23 distinct values. A custom numeric
+    `missing` takes the rank of its value, or the half rank between its
+    neighbours. None where the fold is not "rank" (device_sort_key_row serves
+    an f32-exact column, the host a fractional one). Ranks of two segments do
+    not compare: execute_flat_sorted merges by the fold's exact values."""
+    if spec.kind != "field" or spec.mode in ("avg", "sum"):
+        return None
+    keys, how = _sort_fold(spec, seg)
+    if how != "rank":
+        return None
+    ckey = ("sort_ranks", spec.field, _fold_mode(spec))
+    held = seg._device_cache.get(ckey)
+    if held is None:
+        has = ~np.isnan(keys)
+        uniq, inverse = np.unique(keys[has], return_inverse=True)
+        ranks = np.full(len(keys), np.nan)
+        ranks[has] = inverse
+        held = seg._device_cache[ckey] = (uniq, ranks)
+    uniq, ranks = held
+    if len(uniq) >= _RANKS_MAX:
+        return None
+    if spec.missing in ("_last", "_first"):
+        fill = _missing_fill(spec)
     else:
         try:
-            fill = float(spec.missing)
+            value = float(spec.missing)
         except (TypeError, ValueError):
-            fill = _F32_MAX
-        if float(np.float32(fill)) != fill:
-            return None
-    keys = np.where(np.isnan(keys), fill, keys)
-    row = np.full(doc_pad, _F32_MAX if not spec.reverse else -_F32_MAX,
-                  dtype=np.float32)
-    row[: seg.doc_count] = keys.astype(np.float32)
-    return row
+            value = np.inf
+        at = int(np.searchsorted(uniq, value))
+        fill = float(at) if at < len(uniq) and uniq[at] == value else at - 0.5
+    return _padded_row(np.where(np.isnan(ranks), fill, ranks), spec, doc_pad)
+
+
+def exact_sort_keys(spec: SortSpec, seg, locals_=slice(None)) -> np.ndarray:
+    """The exact float64 sort keys of a segment's documents `locals_` with the
+    `missing` policy applied: what execute_flat_sorted merges segments'
+    winners by."""
+    return apply_missing(_sort_fold(spec, seg)[0][locals_], spec)
 
 
 def apply_missing(keys: np.ndarray, spec: SortSpec) -> np.ndarray:

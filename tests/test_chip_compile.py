@@ -118,6 +118,64 @@ def test_dense_overflow_launch_compiles_for_v5e(one_chip, simple):
     assert "while" in compiled.as_text()
 
 
+DOC_PAD_LOGS = 1 << 20  # a million log events in one force-merged segment
+
+
+@pytest.mark.parametrize("tail", ["hits", "sorted_desc", "sorted_asc", "hourly"])
+def test_unscored_launches_compile_for_v5e(one_chip, tail):
+    """A plan with no scoring clause (match_all, a range query, filtered over
+    them) at the log index's doc_pad: the live mask, one constant a query, the
+    filter's mask row and the tail's own operands; no postings plane at all."""
+    from elasticsearch_tpu.common.jaxenv import compile_tag
+    from elasticsearch_tpu.ops.scoring import (
+        _get_agg_compiled, _get_sorted_compiled, _unscored_abi)
+
+    D = DOC_PAD_LOGS
+    head = (((D,), "bool"), ((1,), "float32"))  # live_parent, score [Q]
+    mask, no_aggs = ((1, D), "bool"), ((0, 5, D), "float32")
+    if tail.startswith("sorted"):
+        fn = _get_sorted_compiled(1, 10, D, tail == "sorted_desc",
+                                  _unscored_abi, "unscored")
+        args = _shapes(one_chip, *head, mask, ((D,), "float32"))  # the key row
+        family = "sorted"
+    elif tail == "hits":
+        fn = _get_agg_compiled(1, 10, D, 0, True, _unscored_abi, "unscored")
+        args = _shapes(one_chip, *head, no_aggs) + [()] + _shapes(one_chip, mask)
+        family = "filtered"
+    else:  # size 0 and one date_histogram: a (doc, hour) pair a document
+        fn = _get_agg_compiled(1, 1, D, 1, False, _unscored_abi, "unscored")
+        pairs = _shapes(one_chip, ((D,), "int32"), ((D,), "int32"),
+                        ((256,), "int32"))
+        args = _shapes(one_chip, *head, no_aggs) + [((*pairs, None),)] \
+            + _shapes(one_chip, mask)
+        family = "aggs"
+    with compile_tag(family):
+        compiled = fn.lower(*args).compile()
+    mem = compiled.memory_analysis()
+    # the operands are the mask and key rows, megabytes; no [ROWS, 128] plane
+    assert mem.argument_size_in_bytes < 64 << 20
+    assert mem.temp_size_in_bytes < 256 << 20
+
+
+@pytest.mark.parametrize("descending", [True, False], ids=["desc", "asc"])
+def test_scored_sort_launch_compiles_for_v5e(one_chip, descending):
+    """A one-term match sorted on a date: the dense launch with the sort tail
+    over a resident key row (values, or exact ranks: float32 either way)."""
+    from elasticsearch_tpu.common.jaxenv import compile_tag
+    from elasticsearch_tpu.ops.scoring import HEAD_SLOTS, _get_sorted_compiled
+
+    args = _shapes(
+        one_chip,
+        ((ROWS, BLOCK), "int32"), ((ROWS, BLOCK), "float32"),
+        ((64, DOC_PAD), "uint8"), ((DOC_PAD,), "bool"), ((1, DOC_PAD), "float32"),
+        ((6, 256), "int32"), ((1, 2 + 4), "int32"), ((5, 1, HEAD_SLOTS), "int32"),
+        ((1, 1), "bool"), ((DOC_PAD,), "float32"))  # the no-op mask, the key row
+    fn = _get_sorted_compiled(1, 10, DOC_PAD, descending)
+    with compile_tag("sorted"):
+        compiled = fn.lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 def test_mesh_program_compiles_for_four_v5e_chips(topo):
     """`chip_smoke.py --chips 4`: one index of 4 x 25,000 documents, one shard a
     device, statistics resolved on the host (per-shard weight and norm-cache

@@ -305,6 +305,16 @@ QUERIES = [
     {"query": {"match": {"body": "alpha beta"}}, "size": 10},
     {"query": {"match": {"body": "gamma"}}, "size": 20},
     {"query": {"match": {"body": "beta"}}, "size": 0},
+    # plans with no scoring clause behind each of the three tails: their
+    # launches are recorded and replayed like the others (a scored dense
+    # launch is not in the mix: its prologue, scoring.doc_table and the eager
+    # stack of norm rows, has no warm site and compiles on a restarted node)
+    {"query": {"match_all": {}}, "size": 10},
+    {"query": {"filtered": {"query": {"match_all": {}},
+                            "filter": {"range": {"n": {"gte": 10}}}}},
+     "sort": [{"n": "desc"}], "size": 5},
+    {"query": {"range": {"n": {"lt": 40}}}, "size": 0, "request_cache": False,
+     "aggs": {"n": {"stats": {"field": "n"}}}},
 ]
 
 

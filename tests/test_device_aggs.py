@@ -282,9 +282,23 @@ def test_f32_inexact_column_falls_back_to_host():
     eng.close()
 
 
-def test_unlowerable_query_falls_back(ctx):
+def test_match_all_aggs_ride_the_device(ctx):
+    # a plan with no scoring clause takes the same fused aggregation tail
     req = parse_search_body({
         "query": {"match_all": {}},
+        "aggs": {"a": {"avg": {"field": "pop"}},
+                 "by_label": {"terms": {"field": "tags_n"}}}})
+    dev = _try_device_aggs(ctx, req, 3, None, 0)
+    assert dev is not None
+    host = execute_query_phase(ctx, req, use_device=False)
+    assert dev.total == host.total
+    _agg_equal(reduce_aggs(req.aggs, dev.agg_partials),
+               reduce_aggs(req.aggs, host.agg_partials))
+
+
+def test_unlowerable_query_falls_back(ctx):
+    req = parse_search_body({
+        "query": {"match_phrase": {"body": "alpha beta"}},
         "aggs": {"a": {"avg": {"field": "price"}}}})
     assert _try_device_aggs(ctx, req, 3, None, 0) is None
     # host path agrees with itself (sanity that fallback serves)

@@ -138,11 +138,14 @@ def test_failover_still_works_under_concurrent_dispatch(tmp_path):
         n1.close()
 
 
-def test_a_compiling_copy_is_late_not_wedged(tmp_path):
+@pytest.mark.parametrize("busy", ["compile_idle_s", "pack_idle_s"])
+def test_a_compiling_or_packing_copy_is_late_not_wedged(tmp_path, busy):
     """A cold device compiles on the query path (88.5 s for a first search on a
-    v5e, PR 22): the attempt timer asks the copy's node and waits on while it
-    reports a recent compile, so the one copy answers instead of failing at the
-    first window. A node that reports no compile is given up after one window."""
+    v5e, PR 22), and a merged segment's device concat keeps the first search
+    after a force-merge waiting for 84-120 s at 250,000 documents (PR 31): the
+    attempt timer asks the copy's node and waits on while it reports a recent
+    compile or a pack in flight, so the one copy answers instead of failing at
+    the first window. A node that reports neither is given up after one window."""
     registry = LocalTransportRegistry()
     n1 = Node(name="cc1", registry=registry, data_path=str(tmp_path / "n1"),
               settings={"index.number_of_shards": 1,
@@ -161,8 +164,8 @@ def test_a_compiling_copy_is_late_not_wedged(tmp_path):
 
     # what the node really reports: seconds since its last compile, or None
     real = n1.actions._s_query_progress({}, None)
-    assert set(real) == {"compile_idle_s"}
-    assert real["compile_idle_s"] is None or real["compile_idle_s"] >= 0.0
+    assert set(real) == {"compile_idle_s", "pack_idle_s"}
+    assert all(v is None or v >= 0.0 for v in real.values())
 
     serve = n1.actions._s_query_phase
     windows = 4  # the copy answers in the fourth window
@@ -172,7 +175,8 @@ def test_a_compiling_copy_is_late_not_wedged(tmp_path):
         time.sleep(delay[0])
         return serve(request, channel)
 
-    reports = {"compile_idle_s": 0.01}
+    idle = {"compile_idle_s": None, "pack_idle_s": None}
+    reports = {**idle, busy: 0.01}
     asked = []
 
     def progress(request, channel):
@@ -189,14 +193,14 @@ def test_a_compiling_copy_is_late_not_wedged(tmp_path):
         assert (r["_shards"]["successful"], r["_shards"]["failed"]) == (1, 0)
         assert r["hits"]["total"] == 8
         assert 1 <= len(asked) <= windows - 1  # as each window ran out
-        # the same late copy on a node that compiles nothing: one window, then failed
-        reports["compile_idle_s"] = None
+        # the same late copy on a node that does neither: one window, then failed
+        reports[busy] = None
         t0 = time.monotonic()
         r = client.search(["t"], body)
         assert r["_shards"]["failed"] == 1 and r["_shards"]["successful"] == 0
         assert time.monotonic() - t0 < delay[0]
         # ... and the extensions are bounded: a copy later than all of them fails
-        reports["compile_idle_s"] = 0.01
+        reports[busy] = 0.01
         cls.QUERY_ATTEMPT_TIMEOUT = 0.05
         delay[0] = 3.0
         del asked[:]

@@ -131,7 +131,6 @@ class TestFallbackReasons:
     def test_vocabulary(self, shard_ctx):
         assert self._reason(shard_ctx, {"match_phrase": {"body": "a b"}}) \
             == "unsupported_query:PhraseQuery"
-        assert self._reason(shard_ctx, {"term": {"n": 3}}) == "numeric_term"
         assert self._reason(
             shard_ctx, {"match": {"body": {"query": "quik",
                                            "fuzziness": "AUTO"}}}) \
@@ -145,12 +144,33 @@ class TestFallbackReasons:
                 {"match_phrase": {"body": "quick brown"}}]}}) \
             == "non_term_subclause"
         assert self._reason(
-            shard_ctx, {"bool": {"must_not": [{"term": {"body": "quick"}}]}}) \
-            == "must_not_only"
+            shard_ctx, {"function_score": {
+                "query": {"range": {"n": {"gte": 3}}},
+                "functions": [{"weight": 2.0}]}}) == "unscored_subquery"
         assert self._reason(
             shard_ctx, {"function_score": {
                 "query": {"match_phrase": {"body": "quick brown"}},
                 "functions": [{"weight": 2.0}]}}) == "non_flat_subquery"
+
+
+    @pytest.mark.parametrize("qdict", [
+        {"term": {"n": 3}},
+        {"bool": {"must_not": [{"term": {"body": "quick"}}]}},
+        {"match_all": {}},
+        {"filtered": {"query": {"match_all": {}},
+                      "filter": {"range": {"n": {"gte": 3}}}}},
+    ], ids=["numeric_term", "must_not_only", "match_all", "filtered_match_all"])
+    def test_unscored_plans_lower(self, shard_ctx, qdict):
+        """What used to fall back as `numeric_term` / `must_not_only` lowers to
+        a plan with no scoring clause, and the plan's profile says so."""
+        from elasticsearch_tpu.search import parse_query
+        from elasticsearch_tpu.search.execute import lower_flat, plan_profile
+
+        q = parse_query(qdict)
+        plan = lower_flat(q, shard_ctx)
+        assert plan is not None and plan.const is not None
+        shape = plan_profile(plan, q)
+        assert shape["unscored"] is True and shape["clauses"] == []
 
 
 # ---------------------------------------------------------------------------

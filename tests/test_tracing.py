@@ -722,6 +722,39 @@ class TestLaunchTimeline:
         (merge,) = _find(tree, "batcher.merge")
         assert merge["children"] == []
 
+    def test_an_unscored_search_records_its_mask_and_its_counters(self, live):
+        """A plan with no scoring clause under a filter at its second
+        sighting (evaluated on the host once more, resident from then on):
+        the mask's assembly is a part of the stage span, and /_nodes/stats
+        books the plan, the row's bytes and (flat) the request cache's hits."""
+        _cluster, _node, rc = live
+
+        def serving():
+            resp = rc.dispatch(RestRequest(
+                method="GET", path="/_nodes/stats/search_serving"))
+            return next(iter(resp.body["nodes"].values()))["search_serving"]
+
+        body = {"query": {"filtered": {
+            "query": {"match_all": {}},
+            "filter": {"term": {"body": "lazy"}}}}, "size": 5}
+        _traced_search(rc, body)  # first sighting compiles
+        before = serving()
+        tree = _traced_search(rc, body)
+        after = serving()
+        _assert_nested(tree)
+        (mask,) = _find(tree, "shard.filter_mask")
+        (stage,) = [n for n in _find(tree, "dispatch.stage")
+                    if any(c["name"] == "shard.filter_mask"
+                           for c in n["children"])]
+        assert stage["t0"] <= mask["t0"] and mask["t1"] <= stage["t1"] + 1e-6
+        assert after["launch"]["unscored_plans"] == \
+            before["launch"]["unscored_plans"] + 1
+        assert after["launch"]["mask_put_bytes"] > before["launch"]["mask_put_bytes"]
+        assert after["launch"]["unscored_bytes"] > before["launch"]["unscored_bytes"]
+        assert after["device_filtered"] == before["device_filtered"] + 1
+        assert after["host"] == before["host"]
+        assert after["request_cache_hits"] == before["request_cache_hits"]
+
     def test_unsampled_search_allocates_no_span(self, live, monkeypatch):
         _cluster, node, rc = live
         made = []

@@ -41,6 +41,7 @@ from elasticsearch_tpu.ops.device_index import (
     begin_warm,
     cancel_warm,
     concat_estimate_bytes,
+    device_counts,
     concat_source_packs,
     pack_segment,
     pack_segment_concat,
@@ -269,7 +270,9 @@ class TestConcatPack:
         merged = merge_segments(sources, 9)
         NBpad, Dpad, layout = pack_shape_math(merged)
         tf_b = tf_plane_itemsize(layout)
-        W, T = len(sources), len(merged.post_offsets) - 1
+        # the tables hold a column a term the planes hold: not _id's, not _uid's
+        W, T = len(sources), int(np.count_nonzero(device_counts(merged)))
+        assert T == len(merged.post_offsets) - 1 - 2 * merged.doc_count
         expect = (NBpad * BLOCK * ((4 + 4) + (4 + tf_b + 1) + 8)
                   + NBpad * 4 * 2 + (2 * W + 1) * T * 4 * 2 + Dpad * 2
                   + Dpad * len(merged.norms) + Dpad * 8 * len(merged.dv_num))
