@@ -215,7 +215,7 @@ class PackedSegment:
     head_rows: object = None  # jnp tf dtype [Hpad, Dpad] or None until dense use
     # device metric-agg state: per-doc (count, sum, min, max, sumsq) rows per
     # numeric field, exact for MULTI-valued columns because the per-doc folds
-    # happen host-side at build time (ops/scoring.score_agg_batch reduces them
+    # happen host-side at build time (ops/scoring.score_agg_batch_async reduces them
     # under the match mask — SURVEY §5.7 "shard-level parallel reduce")
     agg_rows: dict = dc_field(default_factory=dict)  # field -> HOST f32 [5, Dpad] | None (not f32-exact)
     agg_stacks: dict = dc_field(default_factory=dict)  # fields-tuple -> device [F, 5, Dpad], FIFO-bounded

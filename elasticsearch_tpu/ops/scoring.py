@@ -742,30 +742,46 @@ def _false_row(doc_pad: int):
     return _put_operands(np.zeros(doc_pad, dtype=bool))[0]
 
 
-def score_filtered_batch(packed: PackedSegment, batch: TermBatch, k: int, fmask):
+def ladder_const_batch(batch: ConstBatch, fmask, doc_pad: int, width: int):
+    """(batch, fmask) of plans with no scoring clause, padded to `width`
+    queries (a rung of the caller's ladder of query counts) with queries that
+    match nothing: such plans coalesce in any number, so a window meets a few
+    programs, not one for each count. A mask matrix comes padded
+    (execute._filter_mask_matrix, resident rows or not); where no plan has a
+    filter the mask is made here. The caller slices the padding off the
+    results."""
+    q = batch.n_queries
+    pad = width - q
+    if pad:
+        batch = ConstBatch(np.concatenate([batch.score,
+                                           np.zeros(pad, np.float32)]))
+        if fmask is None:
+            fmask = np.concatenate([np.ones((q, doc_pad), bool),
+                                    np.zeros((pad, doc_pad), bool)])
+    return batch, fmask
+
+
+def score_filtered_batch_async(packed: PackedSegment, batch: TermBatch, k: int,
+                               fmask):
     """Dense launch with match-gating filter masks (the device form of the
     reference's FilteredQuery — the filter gates matching, never scoring,
-    XFilteredQuery). Rides score_agg_batch with an empty agg stack (F=0): one
-    kernel family to keep in sync. Returns numpy (scores, docs, total)."""
+    XFilteredQuery). Rides score_agg_batch_async with an empty agg stack
+    (F=0): one kernel family to keep in sync. Returns device (scores, docs,
+    total) without syncing (the caller pulls: execute.launch_flat_filtered),
+    the query count of unscored plans up its ladder: the caller slices the
+    padding off."""
     empty = packed.agg_stacks.get(())  # device_index.ensure_agg_rows' key
     if empty is None:
         (empty,) = _put_operands(np.zeros((0, 5, packed.doc_pad), np.float32))
         packed.agg_stacks[()] = empty
-    q = batch.n_queries
-    pad = _pow2_bucket(q, 1) - q
-    if pad and isinstance(batch, ConstBatch):
+    if isinstance(batch, ConstBatch):
         # unscored plans coalesce in any number: the query count rides the
-        # pow-2 ladder (padding queries match nothing and are sliced off), so
-        # a window meets four programs, not one for each count. A mask matrix
-        # comes padded (execute._filter_mask_matrix, resident rows or not)
-        batch = ConstBatch(np.concatenate([batch.score,
-                                           np.zeros(pad, np.float32)]))
-        if fmask is None:
-            fmask = np.concatenate([np.ones((q, packed.doc_pad), bool),
-                                    np.zeros((pad, packed.doc_pad), bool)])
-    scores, docs, total, _counts, _stats, _buckets = score_agg_batch(
+        # pow-2 ladder, so a window meets four programs
+        batch, fmask = ladder_const_batch(batch, fmask, packed.doc_pad,
+                                          _pow2_bucket(batch.n_queries, 1))
+    scores, docs, total, _counts, _stats, _buckets = score_agg_batch_async(
         packed, batch, k, empty, (), fmask=fmask, filtered=True)
-    return scores[:q], docs[:q], total[:q]
+    return scores, docs, total
 
 
 def _dense_sort_impl(scores, match,
@@ -811,11 +827,12 @@ def _get_sorted_compiled(n_queries: int, k: int, doc_pad: int,
     return fn
 
 
-def score_sorted_batch(packed: PackedSegment, batch: TermBatch, k: int,
-                       key_row, descending: bool, fmask=None):
-    """Field-sorted dense launch; returns numpy (keys, docs, scores, qmax,
-    total). Matched docs occupy the first min(total, k) slots per query
-    (padding ranks strictly after ±FLT_MAX missing keys)."""
+def score_sorted_batch_async(packed: PackedSegment, batch: TermBatch, k: int,
+                             key_row, descending: bool, fmask=None):
+    """Field-sorted dense launch; returns device (keys, docs, scores, qmax,
+    total) without syncing (the caller pulls: execute.launch_flat_sorted).
+    Matched docs occupy the first min(total, k) slots per query (padding
+    ranks strictly after ±FLT_MAX missing keys)."""
     params = (batch.n_queries, min(k, packed.doc_pad), packed.doc_pad,
               descending)
     abi, suffix = _abi_for(batch)
@@ -824,8 +841,7 @@ def score_sorted_batch(packed: PackedSegment, batch: TermBatch, k: int,
         _count_unscored(packed, batch, row_bytes=4)
     args = _dense_args(packed, batch, _no_mask() if fmask is None else fmask,
                        key_row)
-    return _pull(_launch(fn, args, _site("scoring.sorted", suffix), "sorted",
-                         params))
+    return _launch(fn, args, _site("scoring.sorted", suffix), "sorted", params)
 
 
 def agg_stat_reduction(match, agg_rows):
@@ -920,11 +936,14 @@ def _get_agg_compiled(n_queries: int, k: int, doc_pad: int, nb_bucket: int,
     return fn
 
 
-def score_agg_batch(packed: PackedSegment, batch: TermBatch, k: int,
-                    agg_row_stack, bucket_pairs=(), fmask=None,
-                    filtered: bool = False):
-    """Dense launch returning (scores, docs, total, counts [Q, F] int,
-    stats [Q, F, 4], bucket results) numpy. stats rows: (sum, min(+inf if none),
+def score_agg_batch_async(packed: PackedSegment, batch: TermBatch, k: int,
+                          agg_row_stack, bucket_pairs=(), fmask=None,
+                          filtered: bool = False):
+    """Dense launch returning device (scores, docs, total, counts [Q, F] int,
+    stats [Q, F, 4], bucket results) without syncing: the caller pulls the
+    whole result pytree in ONE explicit device_get (execute._run_flat_groups;
+    per-leaf np.asarray was a transfer per output — and an implicit one, which
+    the promoted transfer_guard("disallow") sanitizer rejects). stats rows: (sum, min(+inf if none),
     max(-inf), sumsq) over matched docs per agg field; bucket_pairs: per bucket
     agg, (pair_doc, pair_bucket, zeros[NB], sub_stack [Fs,5,Dpad]|None) device
     arrays — each bucket result is (doc counts [Q,NB], sub value-counts
@@ -943,11 +962,7 @@ def score_agg_batch(packed: PackedSegment, batch: TermBatch, k: int,
     # a host agg stack or mask rides the launch's one put (device arrays
     # pass through it); a raw numpy arg would be an implicit H2D at dispatch
     args = _dense_args(packed, batch, agg_row_stack, tuple(bucket_pairs), fmask)
-    # ONE explicit pull for the whole result pytree: per-leaf np.asarray was a
-    # transfer per output — and an implicit one, which the promoted
-    # transfer_guard("disallow") sanitizer now rejects
-    return _pull(_launch(fn, args, _site("scoring.aggs", suffix), "aggs",
-                         params))
+    return _launch(fn, args, _site("scoring.aggs", suffix), "aggs", params)
 
 
 def _detect_simple(batch: TermBatch) -> bool:

@@ -343,7 +343,7 @@ def _mesh_score_program(k: int, n_queries: int, doc_pad: int, similarity_kind: i
                        mask path order, service.py execute_query_phase)
       use_sort       — single-field sort: per-shard top-k over pre-folded key
                        rows, global merge by (key, shard, doc) — the SPMD form
-                       of execute.execute_flat_sorted + the coordinator merge
+                       of execute.launch_flat_sorted + the coordinator merge
       use_active     — shard-subset serving (routing/preference selected a
                        subset): inactive shards mask out of match entirely
       use_stack      — the agg_rows stack input is present (metric aggs and/or

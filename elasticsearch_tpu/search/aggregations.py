@@ -256,7 +256,7 @@ class ExtendedStatsAgg(StatsAgg):
 
 
 # ---------------------------------------------------------------------------
-# device metric-agg bridge (ops/scoring.score_agg_batch)
+# device metric-agg bridge (ops/scoring.score_agg_batch_async)
 # ---------------------------------------------------------------------------
 
 _DEVICE_METRIC_CLASSES = (SumAgg, AvgAgg, MinAgg, MaxAgg, ValueCountAgg, StatsAgg)
@@ -359,7 +359,7 @@ _BUCKET_CACHE_MAX = 8  # distinct bucket-agg shapes cached per segment
 def bucket_cache_key(agg: Agg) -> tuple:
     """The ONE cache-key constructor for a bucket agg's per-segment columns —
     shared by the host cache here and the device-array cache on PackedSegment
-    (execute.execute_flat_aggs) so the two can never drift. Every spec param
+    (execute.launch_flat_aggs) so the two can never drift. Every spec param
     that changes the (pairs, keys) layout MUST appear here."""
     # finalize-only params don't change the (pairs, keys) layout — excluding
     # them keeps e.g. size:10 / size:50 variants of one terms agg on one cache

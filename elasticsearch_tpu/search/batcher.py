@@ -22,6 +22,18 @@ page sizes share executables — the kernel runs at the bucket, fan-out trims).
 DFS-stats requests bypass the queue entirely (their per-request global stats
 would poison the batch's shared weights).
 
+This is the ONE served launch route of a one-shard search. The key holds no
+kind of search: plain, function_score, filtered, aggregated and sorted plans
+on one view share a collect (seven operations behind 8 clients would
+otherwise collect batches of one and pay a linger each), an aggregated or
+sorted plan carries its execute.FlatTail on its item, and
+execute.execute_flat_batch launches each group of the batch once a segment
+(execute._flat_groups); the filtered, aggregated and sorted groups of a
+batch are pulled together, in one device_get at the dispatch's end
+(execute._run_flat_groups). Only what a shared batch cannot serve launches on
+its request thread: profiled and DFS requests and a node without a batcher
+(service._execute_flat_single). `stats()["kinds"]` tells the launches apart.
+
 Flush policy — whichever fires first:
   * batch-full  : `search.batch.max_batch` same-key plans are waiting
   * linger      : the oldest item has waited `linger_eff`, where
@@ -68,6 +80,9 @@ from ..common.metrics import HistogramMetric
 from ..ops.device_index import _ladder_bucket
 
 _K_MIN = 16  # smallest k bucket (top-10 pages and top-16 share executables)
+# the kinds of launch /_nodes/stats tells apart under search.batcher.kinds:
+# execute._flat_groups' and the mesh family's
+_KINDS = ("plain", "function_score", "filtered", "aggs", "sorted", "mesh")
 
 
 def _k_bucket(k: int) -> int:
@@ -104,9 +119,15 @@ class _Item:
 
 class _FlatFamily:
     """Coalesces single-shard FlatPlans into execute_flat_batch launches.
-    payload = (plan, ShardContext); the batch runs with the LEADER item's
-    context — the key guarantees every member sees the identical segment
-    view and stats sources, so per-plan weights are identical either way."""
+    payload = (plan, ShardContext, FlatTail | None); the batch runs with the
+    LEADER item's context — the key guarantees every member sees the identical
+    segment view and stats sources, so per-plan weights are identical either
+    way. The key does not hold the kind: plain, filtered, aggregated and
+    sorted searches on one view share a collect, and execute_flat_batch
+    launches each group of the batch (execute._flat_groups), so a mix of
+    operations pays one linger and not one a kind. A member with a tail is
+    handed its executor's result as it is (its slices of the group's launch,
+    or None: the host serves); every other one TopDocs trimmed to its k."""
 
     name = "flat"
 
@@ -121,23 +142,25 @@ class _FlatFamily:
         from .execute import dispatch_flat_batch
 
         ctx = items[0].payload[1]
-        return dispatch_flat_batch([it.payload[0] for it in items], ctx, kb)
+        return dispatch_flat_batch([it.payload[0] for it in items], ctx, kb,
+                                   [it.payload[2] for it in items])
 
     @staticmethod
     def fan_out(handle, items):
         from .execute import TopDocs
 
         merged = handle.merge()
-        return [TopDocs(total=td.total, hits=td.hits[: it.k],
-                        max_score=td.max_score, timed_out=td.timed_out)
-                for it, td in zip(items, merged)]
+        return [res if it.payload[2] is not None else
+                TopDocs(total=res.total, hits=res.hits[: it.k],
+                        max_score=res.max_score, timed_out=res.timed_out)
+                for it, res in zip(items, merged)]
 
     @staticmethod
     def execute_single(item):
         from .execute import execute_flat_batch
 
-        plan, ctx = item.payload
-        return execute_flat_batch([plan], ctx, item.k)[0]
+        plan, ctx, tail = item.payload
+        return execute_flat_batch([plan], ctx, item.k, [tail])[0]
 
 
 class _MeshFamily:
@@ -226,6 +249,11 @@ class DeviceBatcher:
         self._stats_lock = threading.Lock()
         self._launches = 0
         self._items_launched = 0  # total items served via coalesced launches
+        # the same two by kind of launch: [groups launched, their members]. A
+        # batch of the flat family launches a group for each kind and key it
+        # holds (execute._flat_groups), so the members add up to `coalesced`
+        # and the groups to `launches` plus the mixed batches' extra groups
+        self._kinds = {kind: [0, 0] for kind in _KINDS}
         self._full_flushes = 0
         self._linger_flushes = 0
         self._deadline_flushes = 0
@@ -270,15 +298,18 @@ class DeviceBatcher:
         self._annotate = TraceAnnotation
 
     # -- public entry points -------------------------------------------------
-    def execute(self, plan, ctx, k: int, deadline: Deadline = NO_DEADLINE):
+    def execute(self, plan, ctx, k: int, deadline: Deadline = NO_DEADLINE,
+                tail=None):
         """Coalesce one shard-local FlatPlan with concurrent callers; blocks
         until the batch lands and returns this plan's TopDocs (hits trimmed
-        to k). Falls back to a direct single-plan launch when batching is
-        disabled, the queue is saturated, or the drainer has died."""
+        to k) or, for an aggregated or sorted search (`tail`, an
+        execute.FlatTail), its executor's result. Falls back to a direct
+        single-plan launch when batching is disabled, the queue is
+        saturated, or the drainer has died."""
         k = max(k, 1)
         kb = _k_bucket(k)
-        item = _Item(self._flat, self._flat.key(ctx, kb), (plan, ctx), k, kb,
-                     deadline or NO_DEADLINE)
+        item = _Item(self._flat, self._flat.key(ctx, kb), (plan, ctx, tail),
+                     k, kb, deadline or NO_DEADLINE)
         return self._submit(item)
 
     def execute_mesh(self, plan, executor, k: int,
@@ -557,10 +588,18 @@ class DeviceBatcher:
                 merge_span.record("device_pull", pull_t0, pull_t1,
                                   batch=batch_id)
         self.service_hist.observe(dt)  # own stripe locks — outside _stats_lock
+        # what the batch launched, by kind: the flat family's handles say
+        # (a group for each kind and key of the batch), any other batch is
+        # one group of its family's name
+        kinds = getattr(handle, "kinds", None) or ((family.name, len(items)),)
         with self._stats_lock:
             self._ewma_cost = 0.2 * dt + 0.8 * self._ewma_cost
             self._launches += 1
             self._items_launched += len(items)
+            for kind, members in kinds:
+                tally = self._kinds.setdefault(kind, [0, 0])
+                tally[0] += 1
+                tally[1] += members
         for it, res in zip(items, results):
             it.future.set_result(res)
         # everything since the dispatch tick — the merge, its bookkeeping and
@@ -679,6 +718,8 @@ class DeviceBatcher:
                 "device_splits": self._device_splits,
                 "queue": len(self._queue),
                 "ewma_batch_ms": round(self._ewma_cost * 1000.0, 3),
+                "kinds": {kind: {"launches": n, "coalesced": members}
+                          for kind, (n, members) in self._kinds.items()},
             }
         # drainer state-seconds (drainer-written, read unlocked: each value
         # is one float, a reading is at most one batch stale). They sum to

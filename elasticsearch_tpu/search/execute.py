@@ -574,33 +574,142 @@ def _is_plain(p: FlatPlan) -> bool:
     return p.fs is None and p.filt is None and p.const is None
 
 
-def execute_flat_batch(plans: list[FlatPlan], ctx: ShardContext, k: int) -> list[TopDocs]:
-    """Run a batch of flat plans through the device kernels. Plain plans ride the
-    sparse candidate-centric path; function_score plans are grouped by spec and
+def _all_plain(plans: list[FlatPlan], tails) -> bool:
+    """Every plan rides the sparse path and asks for TopDocs alone: the batch
+    is one plain launch and skips the grouping (_flat_groups)."""
+    return not (tails and any(tails)) and all(_is_plain(p) for p in plans)
+
+
+@dataclass(frozen=True)
+class FlatTail:
+    """What an aggregated or a sorted search asks of its dense launch besides
+    the top documents, riding beside its plan through the batcher and
+    execute_flat_batch (a plan without one is answered with TopDocs).
+
+    `kind` is "aggs" (`fields`: the sorted metric fields; `bucket_aggs`:
+    (Agg, sub-field order | None) pairs) or "sorted" (`spec`: the one field
+    sort). Plans whose tails have one `kind` and one `key`, and that are all
+    scored or all unscored, launch as one group: the key is everything the
+    launch's resident operands and compiled shape depend on, read off the
+    request alone — for aggregations the metric fields and each bucket
+    aggregation's `bucket_cache_key` with its sub-field order (what
+    ensure_agg_rows and the bucket columns' cache key on), for a sort what
+    _sort_key_row keys its row on. The group's leader lends its Agg objects
+    or its spec to the launch; what differs between members (a terms
+    aggregation's size or order) is applied on the request thread, to each
+    member's own slice."""
+
+    kind: str
+    key: tuple
+    fields: list = ()
+    bucket_aggs: list = ()
+    spec: object = None
+
+
+def aggs_tail(fields: list[str], bucket_aggs: list) -> FlatTail:
+    from .aggregations import bucket_cache_key
+
+    return FlatTail(
+        "aggs", (tuple(fields), tuple(
+            (bucket_cache_key(agg), tuple(sub_order or ()))
+            for agg, sub_order in bucket_aggs)),
+        fields=list(fields), bucket_aggs=list(bucket_aggs))
+
+
+def sort_tail(spec) -> FlatTail:
+    return FlatTail("sorted", _sort_row_key(spec), spec=spec)
+
+
+def _flat_groups(plans: list[FlatPlan], tails=None) -> dict:
+    """group -> positions in `plans`, in order of first sighting: the plans
+    one launch a segment answers together. A group's first element names its
+    kind (`search.batcher.kinds` in /_nodes/stats): plain, function_score
+    (by spec), filtered (scored and unscored apart), and by tail aggs and
+    sorted (by the tail's key; scored and unscored apart, as
+    _segment_batches does not mix them)."""
+    groups: dict = {}
+    for i, p in enumerate(plans):
+        tail = tails[i] if tails else None
+        if tail is not None:
+            group = (tail.kind, tail.key, p.const is not None)
+        elif p.fs is not None:
+            group = ("function_score", *_fs_group_key(p.fs))
+        elif p.filt is not None or p.const is not None:
+            group = ("filtered", p.const is not None)
+        else:
+            group = ("plain",)
+        groups.setdefault(group, []).append(i)
+    return groups
+
+
+def execute_flat_batch(plans: list[FlatPlan], ctx: ShardContext, k: int,
+                       tails: list | None = None) -> list:
+    """Run a batch of flat plans through the device kernels, a launch a
+    segment for each group of _flat_groups. Plain plans ride the sparse
+    candidate-centric path; function_score plans are grouped by spec and
     ride the dense kernel with the function tail fused in (_execute_flat_fs);
     filtered plans ride the dense kernel with per-query mask rows, and plans
     with no scoring clause the same tail behind the unscored launch ABI
-    (_execute_flat_filtered, a launch for each kind)."""
-    if all(_is_plain(p) for p in plans):
+    (launch_flat_filtered, a launch for each kind). `tails` (a FlatTail or
+    None a plan) sends a plan to the fused aggregation or the field-sort
+    program with the plans that share its key (launch_flat_aggs,
+    launch_flat_sorted), and its result is that executor's: everything else
+    is answered with TopDocs."""
+    if _all_plain(plans, tails):
         return _execute_flat_plain(plans, ctx, k)
-    out: list[TopDocs | None] = [None] * len(plans)
-    groups: dict = {}
-    for i, p in enumerate(plans):
-        kind = ("unscored",) if p.const is not None else \
-            ("fs", *_fs_group_key(p.fs)) if p.fs is not None else \
-            ("filtered",) if p.filt is not None else ("plain",)
-        groups.setdefault(kind, []).append(i)
-    for kind, idxs in groups.items():
-        group = [plans[i] for i in idxs]
-        if kind[0] == "plain":
-            tds = _execute_flat_plain(group, ctx, k)
-        elif kind[0] == "fs":
-            tds = _execute_flat_fs(group, ctx, k)
-        else:
-            tds = _execute_flat_filtered(group, ctx, k)
-        for i, td in zip(idxs, tds):
-            out[i] = td
-    return out  # type: ignore[return-value]
+    return _run_flat_groups(plans, ctx, k, tails, _flat_groups(plans, tails))
+
+
+# the groups that launch without a pull (launch_flat_filtered / _aggs /
+# _sorted) and share the batch's one device_get
+_ONE_PULL_KINDS = ("filtered", "aggs", "sorted")
+
+
+def _run_flat_groups(plans: list[FlatPlan], ctx: ShardContext, k: int,
+                     tails, groups: dict) -> list:
+    """A result a plan, group by group. The plain and function_score groups
+    run first and pull as they always did. The filtered, aggregated and
+    sorted groups are then launched one after the other and pulled TOGETHER,
+    in one device_get for the batch: a group's program runs while the next
+    group is staged, and the drainer gives the GIL up once a batch and not
+    once a group (a mix of operations holds two or three groups a batch).
+    They launch last because the device runs its programs in order: a group
+    that pulls at once must not wait behind one that does not."""
+    from ..ops.scoring import _pull
+
+    out: list = [None] * len(plans)
+    launched = []  # (positions, device outputs, finish) a group of one pull
+    for group, idxs in sorted(groups.items(),
+                              key=lambda g: g[0][0] in _ONE_PULL_KINDS):  # stable
+        kind = group[0]
+        if kind in _ONE_PULL_KINDS:
+            tail = tails[idxs[0]] if tails else None
+            # the dense accumulator is O(Q·doc_pad): bound the launch width
+            step = _FS_CHUNK if kind == "filtered" else _GROUP_WIDTH
+            for start in range(0, len(idxs), step):
+                chunk = idxs[start: start + step]
+                members = [plans[i] for i in chunk]
+                if kind == "filtered":
+                    handle = launch_flat_filtered(members, ctx, k)
+                elif kind == "aggs":
+                    handle = launch_flat_aggs(members, ctx, k, tail.fields,
+                                              tail.bucket_aggs)
+                else:
+                    handle = launch_flat_sorted(members, ctx, k, tail.spec)
+                if handle is not None:  # None: the host serves every member
+                    launched.append((chunk, *handle))
+            continue
+        members = [plans[i] for i in idxs]
+        res = _execute_flat_plain(members, ctx, k) if kind == "plain" \
+            else _execute_flat_fs(members, ctx, k)
+        for i, r in zip(idxs, res):
+            out[i] = r
+    if launched:
+        pulled = _pull([refs for _chunk, refs, _finish in launched])
+        for (chunk, _refs, finish), host in zip(launched, pulled):
+            for i, r in zip(chunk, finish(host)):
+                out[i] = r
+    return out
 
 
 def _fs_group_key(fsq) -> tuple:
@@ -675,6 +784,10 @@ class _PendingFlat:
     def merge(self) -> list[TopDocs]:
         return _merge_flat_plain(self)
 
+    @property
+    def kinds(self) -> tuple:
+        return (("plain", self.Q),)
+
     def sync(self):
         """Block until every dispatched launch completes — the profile API's
         per-request sync ONLY (_execute_flat_plain); the serving path never
@@ -689,41 +802,54 @@ class _PendingFlat:
 
 
 class _PendingDone:
-    """Already-merged results behind the pending interface — the fs/filtered
-    plan families execute synchronously inside the dispatch half (their
-    kernels pull per launch). `clock` holds that dispatch's stage / launch /
-    device_pull intervals (tracing.DispatchClock): the pull happened INSIDE
-    the dispatch, so the batcher records it there, not under its merge."""
+    """Already-merged results behind the pending interface — every family but
+    the plain one (function_score, filtered, aggregated, sorted) executes
+    synchronously inside the dispatch half (their kernels pull there: a
+    function_score group per launch, the filtered, aggregated and sorted
+    groups of a batch in one device_get, _run_flat_groups). `clock` holds
+    that dispatch's stage / launch / device_pull intervals
+    (tracing.DispatchClock): the pull happened INSIDE the dispatch, so the
+    batcher records it there, not under its merge. `kinds` is what the batch
+    launched, (kind, members) a group of _flat_groups, for the batcher's
+    per-kind counters."""
 
-    __slots__ = ("results", "clock")
+    __slots__ = ("results", "clock", "kinds")
 
-    def __init__(self, results: list, clock=None):
+    def __init__(self, results: list, clock=None, kinds: tuple = ()):
         self.results = results
         self.clock = clock
+        self.kinds = kinds
 
-    def merge(self) -> list[TopDocs]:
+    def merge(self) -> list:
         return self.results
 
 
-def dispatch_flat_batch(plans: list[FlatPlan], ctx: ShardContext, k: int):
+def dispatch_flat_batch(plans: list[FlatPlan], ctx: ShardContext, k: int,
+                        tails: list | None = None):
     """Dispatch half of execute_flat_batch for the cross-request batcher:
-    returns a pending handle whose merge() yields the per-plan TopDocs.
-    Plain plans enqueue device work without syncing; batches carrying
-    function_score/filtered plans run whole (synchronously) here."""
+    returns a pending handle whose merge() yields a result a plan (TopDocs,
+    or what its FlatTail's executor returns). Plain plans enqueue device work
+    without syncing; batches carrying function_score, filtered, aggregated
+    or sorted plans run whole (synchronously) here, group by group."""
     with tracing.timing_dispatch() as clock:
-        if plans and all(_is_plain(p) for p in plans):
+        if plans and _all_plain(plans, tails):
             pending = _dispatch_flat_plain(plans, ctx, k)
             pending.clock = clock
             return pending
-        return _PendingDone(execute_flat_batch(plans, ctx, k), clock)
+        groups = _flat_groups(plans, tails)
+        return _PendingDone(
+            _run_flat_groups(plans, ctx, k, tails, groups), clock,
+            tuple((group[0], len(idxs)) for group, idxs in groups.items()))
 
 
 @contextlib.contextmanager
 def traced_dispatch():
-    """The unbatched dense families (field sort, aggregations) launch and pull
-    on the request thread: a SAMPLED request records their stage / launch /
-    device_pull intervals under its own active span; an unsampled one pays
-    the thread-local read."""
+    """A launch that bypasses the batcher and pulls on the request thread —
+    the mesh's feature launches, and an aggregated or a sorted search that
+    is profiled, carries DFS statistics or runs on a node without a batcher
+    (service._execute_flat_single; a served one is the drainer's): a SAMPLED
+    request records its stage / launch / device_pull intervals under its own
+    active span; an unsampled one pays the thread-local read."""
     span = tracing.current_span()
     if not span:
         yield
@@ -1055,12 +1181,20 @@ def _launch_dense_fallback(overflow, entries, all_fields, caches_stack,
     return sub, score_term_batch_async(packed, batch, k), batch.blocks_real
 
 
-def _prof_dense_segment(prof, seg, packed, batch, path: str, t_seg: float):
+def _prof_dense_segment(prof, seg, packed, batch, path: str, t_seg: float,
+                        launched=None):
     """Per-segment profile record for the dense kernel families (fs /
     filtered / sorted / aggs) — the batch holds one (query, block) triple per
-    scanned block, and `blocks_real` is the count the launch counters took."""
+    scanned block, and `blocks_real` is the count the launch counters took.
+    `launched`: the segment's device outputs where the family has not pulled
+    them yet (sorted / aggs); the profile's per-request sync waits for them,
+    so `ms` holds the program as it did when the launch pulled."""
     if prof is None:
         return
+    if launched is not None:
+        import jax
+
+        jax.block_until_ready(launched)
     prof.segment(seg.gen, docs=int(seg.doc_count), path=path,
                  tf_layout=packed.tf_layout, blocks_scanned=batch.blocks_real,
                  launches=1, ms=(time.monotonic() - t_seg) * 1000.0)
@@ -1286,30 +1420,25 @@ def _filter_mask_matrix(filters: list, seg, packed, ctx: ShardContext,
     return out
 
 
-def _execute_flat_filtered(plans: list[FlatPlan], ctx: ShardContext,
-                           k: int) -> list[TopDocs]:
+def launch_flat_filtered(plans: list[FlatPlan], ctx: ShardContext, k: int):
     """Filtered plans: per-query filter masks (host-evaluated via the per-segment
     filter cache — the same masks the host scorer uses) gate matching inside the
     dense kernel. Scores/weights are untouched, so sub-query scoring parity is
     inherited from the plain path. Plans with no scoring clause come here too
     (all of `plans` or none): their mask is their whole match set, their
-    score a constant, and hits of equal score merge in document order."""
+    score a constant, and hits of equal score merge in document order. One
+    launch a segment and NO pull: returns (device outputs a segment, finish);
+    `finish(pulled)` takes the outputs on the host (the batch's one
+    device_get: _run_flat_groups) and returns TopDocs a plan."""
     from ..ops.device_index import _pow2_bucket, packed_for
-    from ..ops.scoring import score_filtered_batch
-
-    if len(plans) > _FS_CHUNK:
-        out: list[TopDocs] = []
-        for start in range(0, len(plans), _FS_CHUNK):
-            out.extend(_execute_flat_filtered(plans[start: start + _FS_CHUNK],
-                                              ctx, k))
-        return out
+    from ..ops.scoring import score_filtered_batch_async
 
     Q = len(plans)
     batch_for = _segment_batches(plans, ctx)
-    totals = np.zeros(Q, dtype=np.int64)
-    seg_hits = []
+    launched = []
+    n_docs = []
     prof = _profile.current()
-    for seg, base in zip(ctx.searcher.segments, ctx.searcher.bases):
+    for seg in ctx.searcher.segments:
         t_seg = time.monotonic() if prof is not None else 0.0
         packed = packed_for(seg, breaker=ctx.breaker("fielddata"),
                             owner=ctx.index_name)
@@ -1318,15 +1447,33 @@ def _execute_flat_filtered(plans: list[FlatPlan], ctx: ShardContext,
             [plan.filt for plan in plans], seg, packed, ctx,
             n_rows=_pow2_bucket(Q, 1) if plans[0].const is not None else Q)
         with compile_tag("filtered"):
-            scores, docs, tq = score_filtered_batch(packed, batch, k, fmask)
-        totals += tq
-        valid = (docs < min(packed.doc_pad, seg.doc_count)) & np.isfinite(scores)
-        gdocs = np.where(valid, docs.astype(np.int64) + base, np.int64(2**62))
-        seg_hits.append((np.where(valid, scores, -np.inf), gdocs))
+            launched.append(score_filtered_batch_async(packed, batch, k, fmask))
+        n_docs.append(min(packed.doc_pad, seg.doc_count))
         _prof_dense_segment(prof, seg, packed, batch, "dense_filtered",
-                            t_seg)
-    return _merge_seg_hits(seg_hits, totals, Q, k,
-                           breaker=ctx.breaker("request"))
+                            t_seg, launched[-1])
+
+    def finish(pulled: list) -> list[TopDocs]:
+        totals = np.zeros(Q, dtype=np.int64)
+        seg_hits = []
+        for base, n, (scores, docs, tq) in zip(ctx.searcher.bases, n_docs,
+                                               pulled):
+            scores, docs = scores[:Q], docs[:Q]
+            totals += tq[:Q]
+            valid = (docs < n) & np.isfinite(scores)
+            gdocs = np.where(valid, docs.astype(np.int64) + base,
+                             np.int64(2**62))
+            seg_hits.append((np.where(valid, scores, -np.inf), gdocs))
+        return _merge_seg_hits(seg_hits, totals, Q, k,
+                               breaker=ctx.breaker("request"))
+
+    return launched, finish
+
+
+def _sort_row_key(spec) -> tuple:
+    """What a field sort's device key row depends on: the key of the row on
+    the packed segment (_sort_key_row) and the group key of the sorted
+    searches that can share a launch (sort_tail)."""
+    return (spec.field, spec.mode, spec.order, repr(spec.missing))
 
 
 def _sort_key_row(spec, seg, packed, breaker=None):
@@ -1338,7 +1485,7 @@ def _sort_key_row(spec, seg, packed, breaker=None):
     from ..ops.scoring import _put_operands
     from .sorting import device_sort_key_row, device_sort_rank_row
 
-    key = (spec.field, spec.mode, spec.order, repr(spec.missing))
+    key = _sort_row_key(spec)
     row = packed.sort_rows.get(key)
     if row is None:
         host = device_sort_key_row(spec, seg, packed.doc_pad)
@@ -1354,18 +1501,72 @@ def _sort_key_row(spec, seg, packed, breaker=None):
     return row
 
 
-def execute_flat_sorted(plan: FlatPlan, ctx: ShardContext, k: int, spec):
-    """Single-plan field-sorted dense execution: returns
-    (total, max_score, ordered entries [(key, gdoc, seg_idx, local, score)])
-    or None when any segment's column refuses device keys (_sort_key_row).
-    Ordering: (key asc/desc, global doc asc) — the host lexsort order; `key`
-    is the document's exact float64 value (sorting.exact_sort_keys), because
-    a segment's device keys may be ranks that another segment's do not
-    compare with."""
-    from ..ops.device_index import packed_for
-    from ..ops.scoring import score_sorted_batch
-    from .sorting import exact_sort_keys
+# what pads a group of scored plans up the ladder of query counts: no clause
+# and msm 1, so it matches nothing (the mesh family pads with the same plan)
+_NO_MATCH_PLAN = FlatPlan([], msm=1, n_must=0, coord_enabled=False, boost=1.0)
 
+
+# the most aggregated or sorted plans one launch takes (_run_flat_groups
+# launches a larger group four at a time)
+_GROUP_WIDTH = 4
+
+
+def _group_width(n: int) -> int:
+    """The query count `n` <= _GROUP_WIDTH aggregated or sorted plans launch
+    at: 1 alone, else 4, so a group key has two programs and not one a
+    count. Every program a served window launches must have been met before
+    it (a first sighting compiles for seconds on the one drainer), and a
+    count that a mix of operations reaches a few times a minute is met by no
+    warm-up: with a rung 2 and with a rung 8, one window in six of
+    `logs.dashboard` compiled. The padding is free where it matters: the
+    bucket scatter costs the same at four queries as at one, and a query with
+    no scoring clause costs the device 20-30 us (PERF.md section 6, PR 33)."""
+    return 1 if n == 1 else _GROUP_WIDTH
+
+
+def _group_operands(plans: list[FlatPlan], ctx: ShardContext):
+    """`operands(seg, packed)` -> (batch, fmask): what a dense launch of a
+    group of aggregated or sorted plans takes on one segment, its query count
+    up the group's ladder (_group_width). Scored plans are padded with plans
+    that match nothing before they are staged; plans with no scoring clause
+    (all of `plans` or none) are staged as they are and their ConstBatch and
+    mask padded after (scoring.ladder_const_batch), as the filtered family's
+    are. The callers slice the padding off every result."""
+    from ..ops.scoring import ladder_const_batch
+
+    Qp = _group_width(len(plans))
+    unscored = plans[0].const is not None
+    filters = [p.filt for p in plans]
+    batch_for = _segment_batches(
+        plans if unscored else plans + [_NO_MATCH_PLAN] * (Qp - len(plans)),
+        ctx)
+
+    def operands(seg, packed):
+        batch = batch_for(seg, packed)
+        fmask = _filter_mask_matrix(filters, seg, packed, ctx, n_rows=Qp)
+        if unscored:
+            batch, fmask = ladder_const_batch(batch, fmask, packed.doc_pad,
+                                              Qp)
+        return batch, fmask
+
+    return operands
+
+
+def launch_flat_sorted(plans: list[FlatPlan], ctx: ShardContext, k: int,
+                       spec):
+    """Field-sorted dense launches of a group of plans under ONE sort (all
+    scored or all unscored: sort_tail's key and _flat_groups), one launch a
+    segment and NO pull: returns (device outputs a segment, finish), or None
+    when any segment's column refuses device keys (_sort_key_row: the host
+    sorts every plan). `finish(pulled)` takes the outputs on the host (one
+    device_get for all such groups of a batch: _run_flat_groups) and returns
+    a result a plan: (total, max_score, per segment (segment index, local
+    docs, scores) of the segment's best min(total, k) by its device keys),
+    which sorted_entries merges on the request thread."""
+    from ..ops.device_index import packed_for
+    from ..ops.scoring import score_sorted_batch_async
+
+    Q = len(plans)
     # validate EVERY segment's eligibility before the first launch — a
     # late-segment refusal must not waste completed kernel work
     packeds = [packed_for(seg, breaker=ctx.breaker("fielddata"),
@@ -1375,43 +1576,73 @@ def execute_flat_sorted(plan: FlatPlan, ctx: ShardContext, k: int, spec):
                 for seg, p in zip(ctx.searcher.segments, packeds)]
     if any(r is None for r in key_rows):
         return None
-    batch_for = _segment_batches([plan], ctx)
-    total = 0
-    max_score = float("nan")
-    cand = []  # (key, gdoc, seg_idx, local, score)
+    operands = _group_operands(plans, ctx)
+    launched = []
     prof = _profile.current()
-    for si, (seg, base, packed, key_row) in enumerate(zip(
-            ctx.searcher.segments, ctx.searcher.bases, packeds, key_rows)):
+    for seg, packed, key_row in zip(ctx.searcher.segments, packeds, key_rows):
         t_seg = time.monotonic() if prof is not None else 0.0
-        batch = batch_for(seg, packed)
-        fmask = _filter_mask_matrix([plan.filt], seg, packed, ctx)
+        batch, fmask = operands(seg, packed)
         with compile_tag("sorted"):
-            _keys, docs, scores, qmax, tq = score_sorted_batch(
-                packed, batch, max(k, 1), key_row, spec.reverse, fmask=fmask)
-        # batched host pulls: one .tolist() per row instead of a float()/int()
-        # scalar conversion per hit (tpulint TPU001)
-        (seg_total,) = tq.tolist()
-        total += seg_total
-        if seg_total:
-            (m,) = qmax.tolist()
-            max_score = m if max_score != max_score else max(max_score, m)
-        n = min(seg_total, docs.shape[1])
-        locals_ = docs[0, :n]
+            launched.append(score_sorted_batch_async(
+                packed, batch, max(k, 1), key_row, spec.reverse, fmask=fmask))
+        _prof_dense_segment(prof, seg, packed, batch, "dense_sorted", t_seg,
+                            launched[-1])
+
+    def finish(pulled: list) -> list:
+        totals = [0] * Q
+        max_scores = [float("nan")] * Q
+        parts: list[list] = [[] for _ in range(Q)]
+        for si, (_keys, docs, scores, qmax, tq) in enumerate(pulled):
+            # batched host pulls: one .tolist() per row instead of a
+            # float()/int() scalar conversion per hit (tpulint TPU001)
+            for qi, (seg_total, m) in enumerate(zip(tq[:Q].tolist(),
+                                                    qmax[:Q].tolist())):
+                if not seg_total:
+                    continue
+                totals[qi] += seg_total
+                max_scores[qi] = m if max_scores[qi] != max_scores[qi] \
+                    else max(max_scores[qi], m)
+                n = min(seg_total, docs.shape[1])
+                parts[qi].append((si, docs[qi, :n], scores[qi, :n]))
+        return list(zip(totals, max_scores, parts))
+
+    return launched, finish
+
+
+def sorted_entries(result, ctx: ShardContext, k: int, spec):
+    """The request thread's half of a sorted search: one plan's result of
+    launch_flat_sorted as (total, max_score, ordered entries [(key, gdoc,
+    seg_idx, local, score)]), the best `k`. Ordering: (key asc/desc, global
+    doc asc) — the host lexsort order; `key` is the document's exact float64
+    value (sorting.exact_sort_keys), because a segment's device keys may be
+    ranks that another segment's do not compare with."""
+    from .sorting import exact_sort_keys
+
+    total, max_score, parts = result
+    cand = []  # (key, gdoc, seg_idx, local, score)
+    for si, locals_, scores in parts:
+        seg, base = ctx.searcher.segments[si], ctx.searcher.bases[si]
         cand.extend(
             (ki, base + di, si, di, sc)
             for ki, di, sc in zip(exact_sort_keys(spec, seg, locals_).tolist(),
-                                  locals_.tolist(), scores[0, :n].tolist()))
-        _prof_dense_segment(prof, seg, packed, batch, "dense_sorted", t_seg)
+                                  locals_.tolist(), scores.tolist()))
     cand.sort(key=lambda e: (-e[0] if spec.reverse else e[0], e[1]))
     return total, max_score, cand[: max(k, 0)]
 
 
-def execute_flat_aggs(plan: FlatPlan, ctx: ShardContext, k: int,
-                      fields: list[str], bucket_aggs: list = ()):
-    """Single-plan dense execution with aggregations fused into the kernel:
-    returns (TopDocs, per-segment (counts int [F], stats float32 [F, 4],
-    bucket list of (keys, counts, sub_cnt|None, sub_stats|None))) with
-    F = len(fields), stats = (sum, min, max, sumsq) over matched docs.
+def launch_flat_aggs(plans: list[FlatPlan], ctx: ShardContext, k: int,
+                     fields: list[str], bucket_aggs: list = ()):
+    """Dense launches of a group of plans with ONE set of aggregations fused
+    into the kernel (all scored or all unscored: aggs_tail's key and
+    _flat_groups), one launch a segment and NO pull: returns (device outputs
+    a segment, finish), or None when a column is not f32-exact (→ host
+    collectors for every plan). `finish(pulled)` takes the outputs on the
+    host (one device_get for all such groups of a batch: _run_flat_groups)
+    and returns a result a plan: (TopDocs, per-segment (counts int [F],
+    stats float32 [F, 4], bucket list of (keys, counts, sub_cnt|None,
+    sub_stats|None))) with F = len(fields), stats = (sum, min, max, sumsq)
+    over the plan's matched docs — its own slices of the launch's outputs,
+    which the request thread turns into partials (service._try_device_aggs).
     bucket_aggs: (Agg, sub_field_order|None) pairs whose (doc, bucket) pairs
     ride the kernel's scatter (aggregations.bucket_cols_for); metric sub-agg
     folds scatter along the same pairs. Serving uses this when every
@@ -1421,23 +1652,22 @@ def execute_flat_aggs(plan: FlatPlan, ctx: ShardContext, k: int,
     import jax.numpy as jnp
 
     from ..ops.device_index import _pow2_bucket, ensure_agg_rows, packed_for
-    from ..ops.scoring import score_agg_batch
+    from ..ops.scoring import score_agg_batch_async
     from .aggregations import bucket_cache_key, bucket_cols_for
 
-    batch_for = _segment_batches([plan], ctx)
-    totals = np.zeros(1, dtype=np.int64)
-    seg_hits = []
-    seg_stats = []
+    Q = len(plans)
+    operands = _group_operands(plans, ctx)
+    launched = []
+    keys_by_seg = []
     prof = _profile.current()
-    for seg, base in zip(ctx.searcher.segments, ctx.searcher.bases):
+    for seg in ctx.searcher.segments:
         t_seg = time.monotonic() if prof is not None else 0.0
         packed = packed_for(seg, breaker=ctx.breaker("fielddata"),
                             owner=ctx.index_name)
-        batch = batch_for(seg, packed)
         stack = ensure_agg_rows(seg, packed, fields,
                                 breaker=ctx.breaker("fielddata"))
         if stack is None:
-            return None, None  # column not f32-exact → host collectors
+            return None  # column not f32-exact → host collectors
         pair_args = []
         seg_keys = []
         for agg, sub_order in bucket_aggs:
@@ -1464,26 +1694,42 @@ def execute_flat_aggs(plan: FlatPlan, ctx: ShardContext, k: int,
                 sub_stack = ensure_agg_rows(seg, packed, sub_order,
                                             breaker=ctx.breaker("fielddata"))
                 if sub_stack is None:
-                    return None, None  # sub column not f32-exact → host
+                    return None  # sub column not f32-exact → host
             pair_args.append((dev[0], dev[1], dev[2], sub_stack))
             seg_keys.append(keys)
-        fmask = _filter_mask_matrix([plan.filt], seg, packed, ctx)
+        batch, fmask = operands(seg, packed)
         with compile_tag("aggs"):
-            scores, docs, tq, counts, stats, bcounts = score_agg_batch(
-                packed, batch, k, stack, tuple(pair_args), fmask=fmask)
-        totals += tq
-        valid = (docs < min(packed.doc_pad, seg.doc_count)) & np.isfinite(scores)
-        gdocs = np.where(valid, docs.astype(np.int64) + base, np.int64(2**62))
-        seg_hits.append((np.where(valid, scores, -np.inf), gdocs))
-        seg_stats.append((counts[0], stats[0], [
-            (keys, bc[0],
-             None if sc is None else sc[0],
-             None if ss is None else ss[0])
-            for keys, (bc, sc, ss) in zip(seg_keys, bcounts)
-        ]))
-        _prof_dense_segment(prof, seg, packed, batch, "dense_aggs", t_seg)
-    return _merge_seg_hits(seg_hits, totals, 1, k,
-                           breaker=ctx.breaker("request"))[0], seg_stats
+            launched.append(score_agg_batch_async(
+                packed, batch, k, stack, tuple(pair_args), fmask=fmask))
+        keys_by_seg.append((seg_keys, min(packed.doc_pad, seg.doc_count)))
+        _prof_dense_segment(prof, seg, packed, batch, "dense_aggs", t_seg,
+                            launched[-1])
+
+    def finish(pulled: list) -> list:
+        totals = np.zeros(Q, dtype=np.int64)
+        seg_hits = []
+        seg_stats: list[list] = [[] for _ in range(Q)]
+        for base, (seg_keys, n_docs), out in zip(
+                ctx.searcher.bases, keys_by_seg, pulled):
+            scores, docs, tq, counts, stats, bcounts = out
+            scores, docs = scores[:Q], docs[:Q]
+            totals += tq[:Q]
+            valid = (docs < n_docs) & np.isfinite(scores)
+            gdocs = np.where(valid, docs.astype(np.int64) + base,
+                             np.int64(2**62))
+            seg_hits.append((np.where(valid, scores, -np.inf), gdocs))
+            for qi in range(Q):
+                seg_stats[qi].append((counts[qi], stats[qi], [
+                    (keys, bc[qi],
+                     None if sc is None else sc[qi],
+                     None if ss is None else ss[qi])
+                    for keys, (bc, sc, ss) in zip(seg_keys, bcounts)
+                ]))
+        return list(zip(_merge_seg_hits(seg_hits, totals, Q, k,
+                                        breaker=ctx.breaker("request")),
+                        seg_stats))
+
+    return launched, finish
 
 
 # ---------------------------------------------------------------------------

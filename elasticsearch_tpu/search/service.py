@@ -235,10 +235,15 @@ def _note_device_ok(ctx: "ShardContext", families: tuple):
 
 
 def _execute_flat_single(ctx: ShardContext, plan, k: int,
-                         deadline: Deadline) -> TopDocs:
+                         deadline: Deadline, tail=None):
     """One plan's device execution — through the node's cross-request
     DeviceBatcher when one is wired (coalescing with concurrent searches into
     one bucketed launch; search/batcher.py), else a direct single-plan launch.
+    This is the ONE served launch route: a plain, function_score or filtered
+    plan is answered with TopDocs, an aggregated or sorted one (`tail`, an
+    execute.FlatTail) with its slice of its group's launch
+    (launch_flat_aggs / launch_flat_sorted; None where the executor sends
+    the group to the host).
     DFS-stats requests always launch directly: their per-request global stats
     change clause weights, which a shared batch cannot express.
 
@@ -247,7 +252,8 @@ def _execute_flat_single(ctx: ShardContext, plan, k: int,
     phases belong to the batch, not to one member, and the per-request sync
     the profiler performs must never serialize innocent neighbors' launches.
     The bypass also keeps the collector single-writer — execution never
-    leaves this thread."""
+    leaves this thread. A direct launch with a tail pulls on this thread, and
+    a sampled request records it (execute.traced_dispatch)."""
     if ctx.batcher is not None and not ctx.global_stats:
         prof = _profile.current()
         if prof is None:
@@ -257,13 +263,19 @@ def _execute_flat_single(ctx: ShardContext, plan, k: int,
                 # batcher (request parse, lower_flat), from the shard span's
                 # own start to the enqueue
                 span.record("shard.lower", span.t0, time.monotonic())
-            return ctx.batcher.execute(plan, ctx, k, deadline=deadline)
+            return ctx.batcher.execute(plan, ctx, k, deadline=deadline,
+                                       tail=tail)
         # recorded ONLY when the batcher would actually have served this
         # request — a DFS search or batcher-less node launches directly
         # either way, and must not claim (or count) a profile bypass
         prof.batcher_bypass("profile")
         ctx.batcher.note_profile_bypass()
-    return execute_flat_batch([plan], ctx, k)[0]
+    if tail is None:
+        return execute_flat_batch([plan], ctx, k)[0]
+    from .execute import traced_dispatch
+
+    with traced_dispatch():
+        return execute_flat_batch([plan], ctx, k, [tail])[0]
 
 
 def _prof_record_plan(prof, plan, req: ParsedSearchRequest, ctx: ShardContext,
@@ -389,7 +401,7 @@ def execute_query_phase(ctx: ShardContext, req: ParsedSearchRequest,
 
     # device metric-agg path: when the ONLY mask consumer is a set of
     # device-eligible metric aggs, the agg reduction fuses into the scoring
-    # kernel (execute.execute_flat_aggs) instead of materializing host masks
+    # kernel (execute.launch_flat_aggs) instead of materializing host masks
     if (use_device and req.aggs and not req.facets and not req.sort
             and req.post_filter is None and not req.rescore
             and req.min_score is None and not req.explain):
@@ -400,7 +412,8 @@ def execute_query_phase(ctx: ShardContext, req: ParsedSearchRequest,
             device = None
         else:
             try:
-                device = _try_device_aggs(ctx, req, k, suggest_out, shard_id)
+                device = _try_device_aggs(ctx, req, k, suggest_out, shard_id,
+                                          deadline)
             except CircuitBreakingError as e:
                 if getattr(e, "breaker", None) != "fielddata":
                     raise  # request/parent trip: load-shed (429), not degradable
@@ -469,7 +482,7 @@ def execute_query_phase(ctx: ShardContext, req: ParsedSearchRequest,
         else:
             try:
                 device = _try_device_post_filter(ctx, req, k, suggest_out,
-                                                 shard_id)
+                                                 shard_id, deadline)
             except CircuitBreakingError as e:
                 if getattr(e, "breaker", None) != "fielddata":
                     raise  # request/parent trip: load-shed (429), not degradable
@@ -488,7 +501,7 @@ def execute_query_phase(ctx: ShardContext, req: ParsedSearchRequest,
             return device
 
     # device field-sort path: single numeric field sort, top-k over pre-folded
-    # key rows inside the kernel (execute.execute_flat_sorted); combines with
+    # key rows inside the kernel (execute.launch_flat_sorted); combines with
     # device-eligible aggs (agg launch supplies partials, sort launch ordering)
     if (use_device and req.sort and len(req.sort) == 1
             and not req.facets and req.post_filter is None and not req.rescore
@@ -500,7 +513,8 @@ def execute_query_phase(ctx: ShardContext, req: ParsedSearchRequest,
             device = None
         else:
             try:
-                device = _try_device_sort(ctx, req, k, suggest_out, shard_id)
+                device = _try_device_sort(ctx, req, k, suggest_out, shard_id,
+                                          deadline)
             except CircuitBreakingError as e:
                 if getattr(e, "breaker", None) != "fielddata":
                     raise  # request/parent trip: load-shed (429), not degradable
@@ -621,15 +635,23 @@ def execute_query_phase(ctx: ShardContext, req: ParsedSearchRequest,
 
 
 def _try_device_aggs(ctx: ShardContext, req: ParsedSearchRequest, k: int,
-                     suggest_out, shard_id: int) -> "ShardQueryResult | None":
+                     suggest_out, shard_id: int,
+                     deadline: Deadline = NO_DEADLINE
+                     ) -> "ShardQueryResult | None":
     """Serve query + aggregations in one fused device program per segment; None
     when any agg (or the query) needs the host path. Metric aggs reduce to
     masked stats, bucket aggs (terms/histogram/date_histogram) to exact
-    scatter-add doc counts over host-computed keys."""
+    scatter-add doc counts over host-computed keys.
+
+    The plan goes the one served launch route (_execute_flat_single) with an
+    execute.aggs_tail beside it: the batcher's drainer launches it with the
+    aggregated searches in flight that share its key and hands this thread
+    the search's own slices (counts, stats, bucket counts), which become
+    partials here."""
     from .aggregations import (device_agg_field, device_bucket_eligible,
                                device_bucket_partial, device_bucket_subs,
                                device_partial)
-    from .execute import execute_flat_aggs, traced_dispatch
+    from .execute import aggs_tail
 
     metric_fields = {}
     bucket_names = []
@@ -659,11 +681,11 @@ def _try_device_aggs(ctx: ShardContext, req: ParsedSearchRequest, k: int,
     # kernel k is at least 1 so max_score stays observable; hits trim to the
     # requested size below (size=0 agg-only requests return no docs, like the
     # host mask path)
-    with traced_dispatch():
-        td, seg_stats = execute_flat_aggs(plan, ctx, max(k, 1), fields,
-                                          bucket_aggs)
-    if td is None:
+    res = _execute_flat_single(ctx, plan, max(k, 1), deadline,
+                               aggs_tail(fields, bucket_aggs))
+    if res is None:
         return None  # a column wasn't f32-exact — host path
+    td, seg_stats = res
     bpos = {n: i for i, n in enumerate(bucket_names)}
 
     def bucket_partial(name, agg, buckets, seg):
@@ -692,7 +714,9 @@ def _try_device_aggs(ctx: ShardContext, req: ParsedSearchRequest, k: int,
 
 
 def _try_device_post_filter(ctx: ShardContext, req: ParsedSearchRequest, k: int,
-                            suggest_out, shard_id: int) -> "ShardQueryResult | None":
+                            suggest_out, shard_id: int,
+                            deadline: Deadline = NO_DEADLINE
+                            ) -> "ShardQueryResult | None":
     """post_filter requests: the hit launch gates on (query filter AND post
     filter); the agg launch (when aggs exist and are device-eligible) sees only
     the query's own match set — exactly the host mask path's split."""
@@ -706,7 +730,7 @@ def _try_device_post_filter(ctx: ShardContext, req: ParsedSearchRequest, k: int,
         return None
     agg_result = None
     if req.aggs:
-        agg_result = _try_device_aggs(ctx, req, 0, None, shard_id)
+        agg_result = _try_device_aggs(ctx, req, 0, None, shard_id, deadline)
         if agg_result is None:
             return None
     hit_filter = req.post_filter if plan.filt is None else \
@@ -722,30 +746,38 @@ def _try_device_post_filter(ctx: ShardContext, req: ParsedSearchRequest, k: int,
 
 
 def _try_device_sort(ctx: ShardContext, req: ParsedSearchRequest, k: int,
-                     suggest_out, shard_id: int) -> "ShardQueryResult | None":
+                     suggest_out, shard_id: int,
+                     deadline: Deadline = NO_DEADLINE
+                     ) -> "ShardQueryResult | None":
     """Field-sorted top-k in the fused kernel; None when the spec/columns/query
     need the host path. Sort VALUES in the response come from the host extractor
     (exact f64 / None-for-missing), only the ORDERING rides the device. Requests
-    that ALSO carry device-eligible aggs get a second fused launch for the
-    partials (same match set — both kernels share the dense core)."""
-    from .execute import execute_flat_sorted, lower_flat, traced_dispatch
+    that ALSO carry device-eligible aggs make a second submission for the
+    partials (same match set — both kernels share the dense core).
+
+    The plan goes the one served launch route (_execute_flat_single) with an
+    execute.sort_tail beside it: the drainer launches it with the searches in
+    flight under the same sort and hands this thread each segment's best
+    documents by the device keys; the merge by exact keys
+    (execute.sorted_entries) and the response's sort values are this
+    thread's."""
+    from .execute import lower_flat, sort_tail, sorted_entries
 
     spec = req.sort[0]
     if spec.kind != "field":
         return None
     agg_result = None
     if req.aggs:
-        agg_result = _try_device_aggs(ctx, req, 0, None, shard_id)
+        agg_result = _try_device_aggs(ctx, req, 0, None, shard_id, deadline)
         if agg_result is None:
             return None  # any host-only agg sends the whole request host-side
     plan = lower_flat(req.query, ctx)
     if plan is None or plan.fs is not None:
         return None
-    with traced_dispatch():
-        res = execute_flat_sorted(plan, ctx, max(k, 1), spec)
+    res = _execute_flat_single(ctx, plan, max(k, 1), deadline, sort_tail(spec))
     if res is None:
         return None
-    total, max_score, entries = res
+    total, max_score, entries = sorted_entries(res, ctx, max(k, 1), spec)
     values_by_rank = _sort_values_by_rank(
         req.sort, ctx, [(si, local) for (_key, _g, si, local, _s) in entries])
     docs = [

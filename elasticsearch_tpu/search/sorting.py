@@ -261,7 +261,7 @@ def device_sort_rank_row(spec: SortSpec, seg, doc_pad: int) -> np.ndarray | None
     `missing` takes the rank of its value, or the half rank between its
     neighbours. None where the fold is not "rank" (device_sort_key_row serves
     an f32-exact column, the host a fractional one). Ranks of two segments do
-    not compare: execute_flat_sorted merges by the fold's exact values."""
+    not compare: launch_flat_sorted merges by the fold's exact values."""
     if spec.kind != "field" or spec.mode in ("avg", "sum"):
         return None
     keys, how = _sort_fold(spec, seg)
@@ -292,7 +292,7 @@ def device_sort_rank_row(spec: SortSpec, seg, doc_pad: int) -> np.ndarray | None
 
 def exact_sort_keys(spec: SortSpec, seg, locals_=slice(None)) -> np.ndarray:
     """The exact float64 sort keys of a segment's documents `locals_` with the
-    `missing` policy applied: what execute_flat_sorted merges segments'
+    `missing` policy applied: what launch_flat_sorted merges segments'
     winners by."""
     return apply_missing(_sort_fold(spec, seg)[0][locals_], spec)
 

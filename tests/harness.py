@@ -187,3 +187,45 @@ class SearcherLeakTracker:
         self.engine.acquire_searcher = self._orig
 
 
+def run_query_phases(batcher, ctx, bodies, **ctx_kw):
+    """Every body's query phase from its own thread on a copy of `ctx` wired
+    to `batcher`; returns the ShardQueryResults (or the exception raised)."""
+    import threading
+
+    from elasticsearch_tpu.search import ShardContext
+    from elasticsearch_tpu.search.service import (execute_query_phase,
+                                                  parse_search_body)
+
+    bctx = ShardContext(ctx.searcher, ctx.mapper_service, ctx.similarity_service,
+                        batcher=batcher, **ctx_kw)
+    reqs = [parse_search_body(b) for b in bodies]
+    out = [None] * len(reqs)
+
+    def worker(i):
+        try:
+            out[i] = execute_query_phase(bctx, reqs[i], use_device=True)
+        except Exception as e:  # noqa: BLE001 — surfaced by the caller's asserts
+            out[i] = e
+
+    threads = [threading.Thread(target=worker, args=(i,))
+               for i in range(len(reqs))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(60)
+    return out
+
+
+def run_as_one_batch(ctx, bodies):
+    """run_query_phases through ONE DeviceBatcher that flushes when all the
+    bodies are queued; returns (results, the batcher's stats)."""
+    from elasticsearch_tpu.common.settings import Settings
+    from elasticsearch_tpu.search.batcher import DeviceBatcher
+
+    batcher = DeviceBatcher(Settings.from_flat({
+        "search.batch.max_batch": str(len(bodies)),
+        "search.batch.linger_ms": "5000"}))
+    try:
+        return run_query_phases(batcher, ctx, bodies), batcher.stats()
+    finally:
+        batcher.shutdown()

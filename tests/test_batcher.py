@@ -25,6 +25,8 @@ from elasticsearch_tpu.search.batcher import DeviceBatcher, _Item, _k_bucket
 from elasticsearch_tpu.search.execute import execute_flat_batch, lower_flat
 from elasticsearch_tpu.search.similarity import SimilarityService
 
+from .harness import run_as_one_batch, run_query_phases
+
 pytestmark = pytest.mark.serving
 
 WORDS = ["quick", "brown", "fox", "lazy", "dog", "summer", "red", "bear",
@@ -443,6 +445,7 @@ class TestDrainerStates:
             texts = ["quick brown", "lazy dog", "red bear", "summer snack"]
             run_concurrent(b, shard_ctx, texts)  # starts the drainer, compiles
             d0, s0 = _drainer(b)
+            launches0 = b.stats()["launches"]  # one, or two on a slow start
             t0 = time.monotonic()
             for _ in range(5):
                 run_concurrent(b, shard_ctx, texts)
@@ -458,7 +461,7 @@ class TestDrainerStates:
             for k in ("linger_s", "dispatch_s", "merge_s", "pull_s"):
                 assert d1[k] > d0[k] >= 0.0, (k, d0, d1)
             assert d1["batches"] - d0["batches"] == \
-                b.stats()["launches"] - 1 >= 5
+                b.stats()["launches"] - launches0 >= 5
         finally:
             b.shutdown()
 
@@ -497,3 +500,356 @@ class TestDrainerStates:
             assert d["dispatch_s"] > 0.0 and d["merge_s"] == 0.0
         finally:
             b.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# one served launch route (PR 33): aggregated and sorted searches ride the
+# flat family's items beside plain and filtered ones — one collect, and a
+# launch for each group of the batch
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def feature_ctx(tmp_path_factory):
+    """Two segments, a text field and two integer columns."""
+    settings = Settings.from_flat({})
+    svc = MapperService(settings)
+    e = Engine(str(tmp_path_factory.mktemp("features") / "shard0"), svc)
+    for i in range(240):
+        text = f"{WORDS[i % 10]} {WORDS[(i + 1) % 10]} {WORDS[(i + 3) % 10]}"
+        e.index("doc", str(i), {"body": text, "rank": (i * 37) % 101,
+                                "day": i % 12})
+        if i == 119:
+            e.refresh()
+    e.refresh()
+    yield ShardContext(e.acquire_searcher(), svc,
+                       SimilarityService(settings, mapper_service=svc))
+    e.close()
+
+
+AGGS = {"by_day": {"histogram": {"field": "day", "interval": 3}},
+        "rank_stats": {"stats": {"field": "rank"}}}
+
+
+def _match(i):
+    return {"match": {"body": f"{WORDS[i % 10]} {WORDS[(i + 4) % 10]}"}}
+
+
+def _rank_filter(i):
+    return {"range": {"rank": {"gte": 7 * i}}}
+
+
+def _mixed_bodies(n_tail: int) -> list:
+    """Two plain, two filtered and one unscored search, and `n_tail`
+    aggregated and `n_tail` sorted ones (scored and unscored, with and
+    without filters)."""
+    bodies = [{"query": _match(0), "size": 5}, {"query": _match(1), "size": 10},
+              {"query": {"filtered": {"query": _match(2),
+                                      "filter": _rank_filter(2)}}, "size": 7},
+              {"query": {"filtered": {"query": _match(3),
+                                      "filter": _rank_filter(5)}}, "size": 3},
+              {"query": {"constant_score": {"filter": _rank_filter(4)}},
+               "size": 4}]
+    for i in range(n_tail):
+        scored = {"filtered": {"query": _match(i), "filter": _rank_filter(i)}} \
+            if i % 2 else _match(i)
+        unscored = {"match_all": {}} if i % 3 == 0 else \
+            {"constant_score": {"filter": _rank_filter(i)}}
+        query = unscored if i % 4 == 3 else scored
+        bodies.append({"query": query, "size": 2 + i % 5, "aggs": AGGS})
+        bodies.append({"query": query, "size": 2 + i % 5,
+                       "sort": [{"rank": "desc"}]})
+    return bodies
+
+
+def assert_answers_as_alone(ctx, bodies, got):
+    """Each result is what the same search answers with no batcher on its
+    context (a launch of its own): hits, totals, max_score, every bucket and
+    metric, `sort` values."""
+    from elasticsearch_tpu.search.aggregations import reduce_aggs
+    from elasticsearch_tpu.search.service import (execute_query_phase,
+                                                  parse_search_body)
+
+    def same(a, b):
+        return a == b or (a != a and b != b)  # NaN: an untracked score
+
+    for body, res in zip(bodies, got):
+        assert not isinstance(res, Exception), (body, res)
+        req = parse_search_body(body)
+        alone = execute_query_phase(ctx, req, use_device=True)
+        assert res.total == alone.total and not res.degraded, body
+        assert same(res.max_score, alone.max_score), body
+        assert len(res.docs) == len(alone.docs), body
+        for (gs, gd, gv), (ws, wd, wv) in zip(res.docs, alone.docs):
+            assert (gd, gv) == (wd, wv) and same(gs, ws), body
+        if req.aggs:
+            assert reduce_aggs(req.aggs, res.agg_partials) == \
+                reduce_aggs(req.aggs, alone.agg_partials), body
+
+
+def _kind_sums(stats):
+    kinds = stats["kinds"].values()
+    return (sum(k["launches"] for k in kinds),
+            sum(k["coalesced"] for k in kinds))
+
+
+@pytest.mark.parametrize("n, widths", [(1, [1]), (2, [4]), (3, [4]), (4, [4]),
+                                       (5, [4, 1]), (8, [4, 4]), (9, [4, 4, 1])])
+def test_a_groups_query_count_rides_its_ladder(feature_ctx, monkeypatch, n,
+                                               widths):
+    """A lone sorted (or aggregated) search keeps the one-query program, any
+    company launches at 4, and a group of more is launched four at a time:
+    two programs a group key, both common enough for a warm-up to meet."""
+    import elasticsearch_tpu.ops.scoring as scoring
+
+    launched = []
+    real = scoring.score_sorted_batch_async
+
+    def spy(packed, batch, *args, **kwargs):
+        launched.append(batch.n_queries)
+        return real(packed, batch, *args, **kwargs)
+
+    monkeypatch.setattr(scoring, "score_sorted_batch_async", spy)
+    bodies = [{"query": _match(i), "size": 3, "sort": [{"rank": "desc"}]}
+              for i in range(n)]
+    got, stats = run_as_one_batch(feature_ctx, bodies)
+    assert stats["kinds"]["sorted"] == {"launches": 1, "coalesced": n}
+    segments = len(feature_ctx.searcher.segments)
+    assert launched == [w for w in widths for _ in range(segments)]
+    assert_answers_as_alone(feature_ctx, bodies, got)
+
+
+class TestKindsShareACollect:
+    @pytest.mark.parametrize("n_tail", [1, 2, 3, 5, 8])
+    def test_a_mixed_collect_launches_each_group_once(self, feature_ctx, n_tail):
+        """Plain, filtered, unscored, aggregated and sorted searches on one
+        view are ONE collect (no kind in the flat key), and the batch
+        launches a group for each kind and key it holds."""
+        bodies = _mixed_bodies(n_tail)
+        b = make_batcher(**{"search.batch.linger_ms": 5000,
+                            "search.batch.max_batch": len(bodies)})
+        try:
+            got = run_query_phases(b, feature_ctx, bodies)
+            stats = b.stats()
+        finally:
+            b.shutdown()
+        assert_answers_as_alone(feature_ctx, bodies, got)
+        assert stats["launches"] == 1 and stats["full_flushes"] == 1
+        assert stats["coalesced"] == len(bodies)
+        assert stats["bypassed"] == 0 and stats["splits"] == 0
+        kinds = stats["kinds"]
+        assert kinds["plain"] == {"launches": 1, "coalesced": 2}
+        # scored filtered plans and unscored ones are a launch each
+        assert kinds["filtered"] == {"launches": 2, "coalesced": 3}
+        # scored and unscored members of one key launch apart
+        groups = 2 if n_tail >= 4 else 1
+        assert kinds["aggs"] == {"launches": groups, "coalesced": n_tail}
+        assert kinds["sorted"] == {"launches": groups, "coalesced": n_tail}
+        assert kinds["mesh"] == kinds["function_score"] == \
+            {"launches": 0, "coalesced": 0}
+        # the members add up to `coalesced`; the groups to `launches` plus
+        # the extra groups of a batch that holds more than one
+        n_groups, n_members = _kind_sums(stats)
+        assert n_members == stats["coalesced"]
+        assert n_groups == stats["launches"] + (2 + 2 * groups)
+
+    def test_kind_counters_add_up_over_batches_of_one_kind(self, feature_ctx):
+        """Where no batch mixes kinds, the per-kind launches sum to
+        `launches` and the per-kind members to `coalesced`."""
+        b = make_batcher(**{"search.batch.linger_ms": 5000,
+                            "search.batch.max_batch": 3})
+        try:
+            for extra in ({}, {"aggs": AGGS}, {"sort": [{"day": "asc"}]},
+                          {"aggs": AGGS}):
+                bodies = [{"query": _match(i), "size": 5, **extra}
+                          for i in range(3)]
+                got = run_query_phases(b, feature_ctx, bodies)
+                assert_answers_as_alone(feature_ctx, bodies, got)
+            stats = b.stats()
+        finally:
+            b.shutdown()
+        assert _kind_sums(stats) == (stats["launches"], stats["coalesced"]) \
+            == (4, 12)
+        assert stats["kinds"]["aggs"] == {"launches": 2, "coalesced": 6}
+        assert stats["kinds"]["sorted"] == {"launches": 1, "coalesced": 3}
+        assert stats["kinds"]["plain"] == {"launches": 1, "coalesced": 3}
+
+    @pytest.mark.parametrize("where", ["launch", "finish"])
+    @pytest.mark.parametrize("extra", [{"aggs": AGGS},
+                                       {"sort": [{"rank": "asc"}]}],
+                             ids=["aggs", "sorted"])
+    def test_a_failing_group_is_replayed_per_item(self, feature_ctx,
+                                                  monkeypatch, extra, where):
+        """A group whose launch fails, or whose outputs fail after the
+        batch's one pull, is replayed a member at a time (execute_single is
+        the one-plan call): every member still gets its own answer from the
+        device, and no kind counter books the batch."""
+        import elasticsearch_tpu.search.execute as ex
+
+        name = "launch_flat_aggs" if "aggs" in extra else "launch_flat_sorted"
+        real = getattr(ex, name)
+        calls = []
+
+        def failing(pulled):
+            raise RuntimeError("XLA: the coalesced launch's outputs failed")
+
+        def failing_in_company(plans, *a, **kw):
+            calls.append(len(plans))
+            if len(plans) > 1 and where == "launch":
+                raise RuntimeError("XLA: the coalesced launch failed")
+            refs, finish = real(plans, *a, **kw)
+            return refs, failing if len(plans) > 1 else finish
+
+        monkeypatch.setattr(ex, name, failing_in_company)
+        bodies = [{"query": _match(i), "size": 4, **extra} for i in range(3)]
+        b = make_batcher(**{"search.batch.linger_ms": 5000,
+                            "search.batch.max_batch": 3})
+        try:
+            got = run_query_phases(b, feature_ctx, bodies)
+            stats = b.stats()
+        finally:
+            b.shutdown()
+        monkeypatch.undo()
+        assert calls == [3, 1, 1, 1]
+        assert_answers_as_alone(feature_ctx, bodies, got)
+        assert stats["splits"] == 1 and stats["launches"] == 0
+        assert _kind_sums(stats) == (0, 0)
+
+    def test_a_poisoned_member_degrades_alone(self, feature_ctx, monkeypatch):
+        """The replay's verdict is per request: the one member whose own
+        launch fails is served by the host, marked degraded; its neighbours
+        keep their device answers."""
+        import elasticsearch_tpu.search.execute as ex
+        from elasticsearch_tpu.search.service import (SERVING_COUNTERS,
+                                                      execute_query_phase,
+                                                      parse_search_body)
+
+        real = ex.launch_flat_aggs
+
+        def poisoned(plans, *a, **kw):
+            if any(p.filt is not None for p in plans):
+                raise RuntimeError("XLA: this plan's launch fails")
+            return real(plans, *a, **kw)
+
+        monkeypatch.setattr(ex, "launch_flat_aggs", poisoned)
+        bodies = [{"query": _match(0), "size": 4, "aggs": AGGS},
+                  {"query": {"filtered": {"query": _match(1),
+                                          "filter": _rank_filter(3)}},
+                   "size": 4, "aggs": AGGS},
+                  {"query": _match(2), "size": 4, "aggs": AGGS}]
+        before = dict(SERVING_COUNTERS)
+        b = make_batcher(**{"search.batch.linger_ms": 5000,
+                            "search.batch.max_batch": 3})
+        try:
+            got = run_query_phases(b, feature_ctx, bodies)
+            stats = b.stats()
+        finally:
+            b.shutdown()
+        monkeypatch.undo()
+        from elasticsearch_tpu.common.devicehealth import DEVICE_HEALTH
+
+        DEVICE_HEALTH.reset()
+        assert stats["splits"] == 1
+        assert SERVING_COUNTERS["device_errors"] == before["device_errors"] + 1
+        assert SERVING_COUNTERS["device_aggs"] == before["device_aggs"] + 2
+        assert [r.degraded for r in got] == [False, True, False]
+        assert_answers_as_alone(feature_ctx, [bodies[0], bodies[2]],
+                                [got[0], got[2]])
+        host = execute_query_phase(feature_ctx, parse_search_body(bodies[1]),
+                                   use_device=False)
+        assert got[1].total == host.total and \
+            [d for _s, d, _v in got[1].docs] == [d for _s, d, _v in host.docs]
+
+    @pytest.mark.parametrize("extra", [{"aggs": AGGS},
+                                       {"sort": [{"rank": "asc"}]}],
+                             ids=["aggs", "sorted"])
+    def test_profiled_and_dfs_requests_launch_directly(self, feature_ctx,
+                                                       extra):
+        """What a shared batch cannot serve keeps launching on its request
+        thread: a profiled request (counted as a profile bypass) and one
+        that carries DFS statistics (counted as nothing)."""
+        from elasticsearch_tpu.common import profile
+        from elasticsearch_tpu.search.service import (execute_query_phase,
+                                                      parse_search_body)
+
+        body = {"query": _match(1), "size": 4, **extra}
+        req = parse_search_body(body)
+        alone = execute_query_phase(feature_ctx, req)
+        b = make_batcher()
+        try:
+            ctx = ShardContext(feature_ctx.searcher, feature_ctx.mapper_service,
+                               feature_ctx.similarity_service, batcher=b)
+            prof = profile.ProfileCollector()
+            with profile.activate(prof):
+                profiled = execute_query_phase(ctx, req)
+            assert b.stats()["profile_bypassed"] == 1
+            dfs = ShardContext(
+                feature_ctx.searcher, feature_ctx.mapper_service,
+                feature_ctx.similarity_service, batcher=b,
+                global_stats={"max_doc": feature_ctx.searcher.max_doc})
+            with_dfs = execute_query_phase(dfs, req)
+            stats = b.stats()
+        finally:
+            b.shutdown()
+        assert stats["launches"] == 0 and stats["coalesced"] == 0
+        assert stats["bypassed"] == 0 and stats["profile_bypassed"] == 1
+        assert _kind_sums(stats) == (0, 0)
+        for res in (profiled, with_dfs):
+            assert res.total == alone.total
+            assert [(d, v) for _s, d, v in res.docs] == \
+                [(d, v) for _s, d, v in alone.docs]
+
+    def test_a_sampled_member_gets_the_batchers_spans(self, feature_ctx):
+        """An aggregated and a sorted search in one collect: each sampled
+        member's trace holds batcher.queue, batcher.dispatch with the stages
+        and the launches under it and then the batch's ONE pull, and
+        batcher.merge — and no dispatch.stage of its own thread."""
+        from elasticsearch_tpu.common import tracing
+        from elasticsearch_tpu.common.tracing import Tracer, span_tree
+        from elasticsearch_tpu.search.service import (execute_query_phase,
+                                                      parse_search_body)
+
+        tracer = Tracer(Settings.from_flat({"search.trace.sample_rate": "0"}),
+                        node_name="test")
+        bodies = [{"query": _match(0), "size": 4, "aggs": AGGS},
+                  {"query": _match(1), "size": 4, "sort": [{"rank": "asc"}]}]
+        b = make_batcher(**{"search.batch.linger_ms": 5000,
+                            "search.batch.max_batch": 2})
+        ctx = ShardContext(feature_ctx.searcher, feature_ctx.mapper_service,
+                           feature_ctx.similarity_service, batcher=b)
+        errs = []
+
+        def worker(body):
+            trace = tracer.start_trace("shard", force=True)
+            try:
+                with tracing.activate(trace.root):
+                    execute_query_phase(ctx, parse_search_body(body))
+            except Exception as e:  # noqa: BLE001 — asserted below
+                errs.append(e)
+            finally:
+                trace.root.end()
+
+        try:
+            threads = [threading.Thread(target=worker, args=(body,))
+                       for body in bodies]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(60)
+        finally:
+            b.shutdown()
+        assert errs == []
+        trees = [span_tree(t["spans"]) for t in tracer.traces()]
+        assert len(trees) == 2
+        for tree in trees:
+            top = [c["name"] for c in tree["children"]]
+            assert top == ["shard.lower", "batcher.queue", "batcher.dispatch",
+                           "batcher.merge"], top
+            (dispatch,) = [c for c in tree["children"]
+                           if c["name"] == "batcher.dispatch"]
+            assert dispatch["tags"]["occupancy"] == 2
+            kinds = [c["name"] for c in dispatch["children"]]
+            # two groups, two segments each: a stage and a launch for each
+            # of the four launches, in order, then one pull for them all
+            assert kinds == ["dispatch.stage", "dispatch.launch"] * 4 \
+                + ["device_pull"], kinds

@@ -2,7 +2,7 @@
 
 Eligible requests (metric aggs on numeric columns, no other mask consumers) are
 served by ONE fused device program per segment — scoring + top-k + masked stat
-reductions (ops/scoring.score_agg_batch over device_index.agg_doc_rows) — instead
+reductions (ops/scoring.score_agg_batch_async over device_index.agg_doc_rows) — instead
 of host-side mask materialization. Results must match the host collectors within
 float32 kernel accumulation (double-typed columns round to 7 significant digits;
 int/float columns are exact).
@@ -29,6 +29,8 @@ from elasticsearch_tpu.search.service import (
     parse_search_body,
 )
 from elasticsearch_tpu.search.similarity import SimilarityService
+
+from .harness import run_as_one_batch
 
 
 @pytest.fixture(scope="module")
@@ -520,3 +522,150 @@ def test_device_failure_falls_back_to_host(ctx, monkeypatch):
         host = execute_query_phase(ctx, req, use_device=False)
         assert res.total == host.total
     assert SERVING_COUNTERS["device_errors"] >= before + 3
+
+
+# ---------------------------------------------------------------------------
+# aggregated searches join the batch (PR 33): a group of plans under one set
+# of aggregations is one launch a segment, and every member is answered as
+# the one-plan call answers it
+# ---------------------------------------------------------------------------
+
+GROUP_AGGS = {
+    "p_stats": {"stats": {"field": "price"}},
+    "pop_max": {"max": {"field": "pop"}},
+    "by_label": {"terms": {"field": "label"},
+                 "aggs": {"pop_sum": {"sum": {"field": "pop"}}}},
+    "pop_hist": {"histogram": {"field": "pop", "interval": 20}},
+}
+
+_GROUP_WORDS = ["alpha", "beta", "gamma", "delta", "epsilon"]
+
+
+def _group_query(variant: str, i: int) -> dict:
+    match = {"match": {"body": f"{_GROUP_WORDS[i % 5]} {_GROUP_WORDS[(i + 2) % 5]}"}}
+    price = {"range": {"price": {"gte": 5 + 9 * i}}}
+    if variant == "scored":
+        return match
+    if variant == "scored_some_filtered":
+        return match if i % 2 else {"filtered": {"query": match, "filter": price}}
+    if variant == "unscored":
+        return {"constant_score": {"filter": price}}
+    assert variant == "unscored_some_filtered"
+    return {"match_all": {}} if i % 2 else {"constant_score": {"filter": price}}
+
+
+def _group_bodies(variant: str, Q: int, **extra) -> list:
+    return [{"query": _group_query(variant, i), "size": 2 + i % 4, **extra}
+            for i in range(Q)]
+
+
+def _assert_same_answer(req, got, want):
+    """`got` answers as `want` does: totals, hits, max_score, and every
+    aggregation reduced (bucket keys and counts exact, float stats to float32
+    accumulation)."""
+    assert not isinstance(got, Exception), got
+    assert got.total == want.total
+    assert len(got.docs) == len(want.docs)
+    for (gs, gd, gv), (ws, wd, wv) in zip(got.docs, want.docs):
+        assert (gd, gv) == (wd, wv)
+        assert gs == ws or (gs != gs and ws != ws)  # NaN: an untracked score
+    assert got.max_score == want.max_score or (
+        got.max_score != got.max_score and want.max_score != want.max_score)
+    assert not got.degraded
+    if req.aggs:
+        gr = reduce_aggs(req.aggs, got.agg_partials)
+        wr = reduce_aggs(req.aggs, want.agg_partials)
+        for name in wr:
+            _agg_equal(gr[name], wr[name], name)
+
+
+@pytest.mark.parametrize("variant", ["scored", "scored_some_filtered",
+                                     "unscored", "unscored_some_filtered"])
+@pytest.mark.parametrize("Q", [1, 2, 3, 5, 8])
+def test_a_group_answers_each_member_as_the_one_plan_call_does(ctx, Q, variant):
+    from elasticsearch_tpu.search.service import SERVING_COUNTERS
+
+    bodies = _group_bodies(variant, Q, aggs=GROUP_AGGS)
+    host_before = SERVING_COUNTERS["host"]
+    got, stats = run_as_one_batch(ctx, bodies)
+    assert SERVING_COUNTERS["host"] == host_before
+    # one collect, one group, one launch a segment for all Q
+    assert stats["launches"] == 1 and stats["coalesced"] == Q
+    assert stats["kinds"]["aggs"] == {"launches": 1, "coalesced": Q}
+    assert stats["bypassed"] == 0 and stats["splits"] == 0
+    for body, res in zip(bodies, got):
+        req = parse_search_body(body)
+        # the one-plan call: no batcher on the context, a launch a search
+        _assert_same_answer(req, res, execute_query_phase(ctx, req))
+        assert len(res.docs) == min(body["size"], res.total)
+
+
+def test_groups_split_by_their_aggregations_and_scoring(ctx):
+    """One collect, three launches: what cannot share a program does not —
+    another set of aggregations, and unscored plans beside scored ones."""
+    other = {"pop_hist": {"histogram": {"field": "pop", "interval": 10}}}
+    bodies = (_group_bodies("scored", 3, aggs=GROUP_AGGS)
+              + _group_bodies("scored", 2, aggs=other)
+              + _group_bodies("unscored", 2, aggs=GROUP_AGGS))
+    got, stats = run_as_one_batch(ctx, bodies)
+    assert stats["launches"] == 1 and stats["coalesced"] == 7
+    assert stats["kinds"]["aggs"] == {"launches": 3, "coalesced": 7}
+    for body, res in zip(bodies, got):
+        req = parse_search_body(body)
+        _assert_same_answer(req, res, execute_query_phase(ctx, req))
+
+
+def test_members_differ_in_what_the_request_thread_applies(ctx):
+    """size, order and min_doc_count of a terms aggregation are no part of
+    the group key: one launch, and each member's own finalize."""
+    def body(i, **terms):
+        return {"query": _group_query("scored", i), "size": 1,
+                "aggs": {"by_label": {"terms": {"field": "label", **terms}}}}
+
+    bodies = [body(0), body(1, size=2), body(2, order={"_term": "desc"}),
+              body(3, min_doc_count=30)]
+    got, stats = run_as_one_batch(ctx, bodies)
+    assert stats["kinds"]["aggs"] == {"launches": 1, "coalesced": 4}
+    for b, res in zip(bodies, got):
+        req = parse_search_body(b)
+        _assert_same_answer(req, res, execute_query_phase(ctx, req))
+    assert len(reduce_aggs(parse_search_body(bodies[1]).aggs,
+                           got[1].agg_partials)["by_label"]["buckets"]) == 2
+
+
+def test_a_refused_column_sends_every_member_to_the_host(ctx, monkeypatch):
+    """None from the executor (a column not f32-exact) reaches EVERY member:
+    each falls to the host collectors, none is degraded, none fails."""
+    import elasticsearch_tpu.ops.device_index as di
+    from elasticsearch_tpu.search.service import SERVING_COUNTERS
+
+    bodies = _group_bodies("scored", 3, aggs=GROUP_AGGS)
+    want = [execute_query_phase(ctx, parse_search_body(b), use_device=False)
+            for b in bodies]
+    monkeypatch.setattr(di, "ensure_agg_rows", lambda *a, **k: None)
+    before = dict(SERVING_COUNTERS)
+    got, stats = run_as_one_batch(ctx, bodies)
+    assert stats["kinds"]["aggs"] == {"launches": 1, "coalesced": 3}
+    assert SERVING_COUNTERS["host"] == before["host"] + 3
+    assert SERVING_COUNTERS["device_aggs"] == before["device_aggs"]
+    assert SERVING_COUNTERS["device_errors"] == before["device_errors"]
+    for b, res, host in zip(bodies, got, want):
+        req = parse_search_body(b)
+        assert res.total == host.total and not res.degraded
+        assert [d for _s, d, _v in res.docs] == [d for _s, d, _v in host.docs]
+        _agg_equal(reduce_aggs(req.aggs, res.agg_partials),
+                   reduce_aggs(req.aggs, host.agg_partials))
+
+
+def test_sort_with_aggs_makes_two_submissions(ctx):
+    """A sorted request with aggregations goes the one route twice: its
+    aggregated launch and its sorted launch each join their own group."""
+    bodies = _group_bodies("scored", 3, aggs=GROUP_AGGS,
+                           sort=[{"pop": "desc"}])
+    got, stats = run_as_one_batch(ctx, bodies)
+    assert stats["kinds"]["aggs"]["coalesced"] == 3
+    assert stats["kinds"]["sorted"]["coalesced"] == 3
+    assert stats["coalesced"] == 6
+    for b, res in zip(bodies, got):
+        req = parse_search_body(b)
+        _assert_same_answer(req, res, execute_query_phase(ctx, req))

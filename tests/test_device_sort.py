@@ -26,6 +26,8 @@ from elasticsearch_tpu.search.service import (
 )
 from elasticsearch_tpu.search.similarity import SimilarityService
 
+from .harness import run_as_one_batch
+
 
 @pytest.fixture(scope="module")
 def ctx():
@@ -169,3 +171,102 @@ def test_serving_counters_track_paths(ctx):
         before = SERVING_COUNTERS[path]
         execute_query_phase(ctx, parse_search_body(body), use_device=True)
         assert SERVING_COUNTERS[path] == before + 1, (path, body)
+
+
+# ---------------------------------------------------------------------------
+# sorted searches join the batch (PR 33): the searches in flight under one
+# sort are one launch a segment, and every member is answered as the
+# one-plan call answers it
+# ---------------------------------------------------------------------------
+
+_GROUP_WORDS = ["alpha", "beta", "gamma", "delta"]
+
+
+def _group_query(variant: str, i: int) -> dict:
+    match = {"match": {"body": f"{_GROUP_WORDS[i % 4]} {_GROUP_WORDS[(i + 1) % 4]}"}}
+    rank = {"range": {"rank": {"gte": 300 * i}}}
+    if variant == "scored":
+        return match
+    if variant == "scored_some_filtered":
+        return match if i % 2 else {"filtered": {"query": match, "filter": rank}}
+    if variant == "unscored":
+        return {"constant_score": {"filter": rank}}
+    assert variant == "unscored_some_filtered"
+    return {"match_all": {}} if i % 2 else {"constant_score": {"filter": rank}}
+
+
+def _group_bodies(variant: str, Q: int, sort, **extra) -> list:
+    return [{"query": _group_query(variant, i), "size": 3 + 4 * (i % 3),
+             "sort": [sort], **extra} for i in range(Q)]
+
+
+def _assert_same_answer(got, want):
+    """Totals, max_score, and every hit's document, `sort` values and score."""
+    assert not isinstance(got, Exception), got
+    assert got.total == want.total and not got.degraded
+    assert len(got.docs) == len(want.docs)
+    for (gs, gd, gv), (ws, wd, wv) in zip(got.docs, want.docs):
+        assert (gd, gv) == (wd, wv)
+        assert gs == ws or (math.isnan(gs) and math.isnan(ws))
+    assert got.max_score == want.max_score or (
+        math.isnan(got.max_score) and math.isnan(want.max_score))
+
+
+@pytest.mark.parametrize("variant", ["scored", "scored_some_filtered",
+                                     "unscored", "unscored_some_filtered"])
+@pytest.mark.parametrize("Q", [1, 2, 3, 5, 8])
+def test_a_group_answers_each_member_as_the_one_plan_call_does(ctx, Q, variant):
+    from elasticsearch_tpu.search.service import SERVING_COUNTERS
+
+    sort = {"rank": {"order": "desc" if Q % 2 else "asc", "missing": "_last"}}
+    bodies = _group_bodies(variant, Q, sort, track_scores=True)
+    host_before = SERVING_COUNTERS["host"]
+    got, stats = run_as_one_batch(ctx, bodies)
+    assert SERVING_COUNTERS["host"] == host_before
+    # one collect, one group, one launch a segment for all Q
+    assert stats["launches"] == 1 and stats["coalesced"] == Q
+    assert stats["kinds"]["sorted"] == {"launches": 1, "coalesced": Q}
+    assert stats["bypassed"] == 0 and stats["splits"] == 0
+    for body, res in zip(bodies, got):
+        req = parse_search_body(body)
+        # the one-plan call: no batcher on the context, a launch a search
+        _assert_same_answer(res, execute_query_phase(ctx, req))
+        assert len(res.docs) == min(body["size"], res.total)
+        # and the host's own order
+        host = execute_query_phase(ctx, req, use_device=False)
+        assert [(d, v) for _s, d, v in res.docs] == \
+            [(d, v) for _s, d, v in host.docs]
+
+
+def test_groups_split_by_their_sort(ctx):
+    """One collect, a launch for each (field, mode, order, missing): the
+    order is a static of the program and the key row another row."""
+    sorts = [{"rank": "asc"}, {"rank": "desc"},
+             {"rank": {"order": "asc", "missing": "_first"}},
+             {"multi": {"order": "asc", "mode": "min"}}]
+    bodies = [b for sort in sorts for b in _group_bodies("scored", 2, sort)]
+    got, stats = run_as_one_batch(ctx, bodies)
+    assert stats["launches"] == 1 and stats["coalesced"] == 8
+    assert stats["kinds"]["sorted"] == {"launches": 4, "coalesced": 8}
+    for body, res in zip(bodies, got):
+        _assert_same_answer(res, execute_query_phase(ctx, parse_search_body(body)))
+
+
+def test_a_refused_sort_row_sends_every_member_to_the_host(ctx, monkeypatch):
+    """None from the executor (a segment's column refuses device keys)
+    reaches EVERY member: each is sorted on the host, none is degraded."""
+    import elasticsearch_tpu.search.execute as ex
+    from elasticsearch_tpu.search.service import SERVING_COUNTERS
+
+    bodies = _group_bodies("scored", 3, {"rank": "asc"})
+    want = [execute_query_phase(ctx, parse_search_body(b), use_device=False)
+            for b in bodies]
+    monkeypatch.setattr(ex, "_sort_key_row", lambda *a, **k: None)
+    before = dict(SERVING_COUNTERS)
+    got, stats = run_as_one_batch(ctx, bodies)
+    assert stats["kinds"]["sorted"] == {"launches": 1, "coalesced": 3}
+    assert SERVING_COUNTERS["host"] == before["host"] + 3
+    assert SERVING_COUNTERS["device_sort"] == before["device_sort"]
+    assert SERVING_COUNTERS["device_errors"] == before["device_errors"]
+    for res, host in zip(got, want):
+        _assert_same_answer(res, host)

@@ -722,6 +722,60 @@ class TestLaunchTimeline:
         (merge,) = _find(tree, "batcher.merge")
         assert merge["children"] == []
 
+    @pytest.mark.parametrize("extra", [
+        {"aggs": {"by_n": {"histogram": {"field": "n", "interval": 5}}}},
+        {"sort": [{"n": "desc"}]}], ids=["aggs", "sorted"])
+    def test_aggregated_and_sorted_searches_dispatch_under_the_batcher(
+            self, live, extra):
+        """A served aggregated or sorted search goes the one launch route:
+        its stage, launch and pull are the drainer's, under batcher.dispatch;
+        no dispatch.stage hangs under its shard span, and /_nodes/stats books
+        the launch under its kind."""
+        _cluster, node, rc = live
+        client = node.client()
+        if not client.exists_index("numbered"):
+            client.create_index("numbered", {"settings": {
+                "number_of_shards": 1, "number_of_replicas": 0}})
+            _cluster.ensure_green("numbered")
+            for i in range(40):
+                client.index("numbered", "doc", {
+                    "body": f"{WORDS[i % 8]} {WORDS[(i + 1) % 8]}", "n": i},
+                    id=str(i))
+            client.refresh("numbered")
+
+        def search():
+            resp = rc.dispatch(RestRequest(
+                method="POST", path="/numbered/_search",
+                params={"trace": "true"},
+                body={"query": {"match": {"body": "quick brown"}}, "size": 5,
+                      **extra}))
+            assert resp.status == 200, resp.body
+            return resp.body["trace"]["tree"]
+
+        def batcher_stats():
+            resp = rc.dispatch(RestRequest(
+                method="GET", path="/_nodes/stats/search"))
+            return next(iter(resp.body["nodes"].values()))["search"]["batcher"]
+
+        search()  # first sighting compiles
+        before = batcher_stats()
+        tree = search()
+        after = batcher_stats()
+        _assert_nested(tree)
+        (shard,) = _find(tree, "shard")
+        assert [c["name"] for c in shard["children"]] == [
+            "shard.lower", "batcher.queue", "batcher.dispatch", "batcher.merge"]
+        (dispatch,) = _find(tree, "batcher.dispatch")
+        kinds = [c["name"] for c in dispatch["children"]]
+        assert kinds == ["dispatch.stage", "dispatch.launch", "device_pull"]
+        (merge,) = _find(tree, "batcher.merge")
+        assert merge["children"] == []
+        kind = "aggs" if "aggs" in extra else "sorted"
+        for name in ("launches", "coalesced"):
+            assert after["kinds"][kind][name] == before["kinds"][kind][name] + 1
+            assert after[name] == before[name] + 1
+        assert after["bypassed"] == before["bypassed"]
+
     def test_an_unscored_search_records_its_mask_and_its_counters(self, live):
         """A plan with no scoring clause under a filter at its second
         sighting (evaluated on the host once more, resident from then on):
@@ -905,9 +959,17 @@ class TestLaunchTimeline:
             headers={"Content-Type": "application/json"})
         with urllib.request.urlopen(req, timeout=30) as r:
             assert json.loads(r.read())["hits"]["total"] > 0
-        with urllib.request.urlopen(url + "/_nodes/stats/http", timeout=30) as r:
-            stats = next(iter(json.loads(r.read())["nodes"].values()))
-        respond = stats["http"]["respond"]
+        # the handler books `respond` after the client has its last byte: the
+        # stats request can overtake it, so poll (bounded) until it is booked
+        give_up = time.monotonic() + 10.0
+        while True:
+            with urllib.request.urlopen(url + "/_nodes/stats/http",
+                                        timeout=30) as r:
+                stats = next(iter(json.loads(r.read())["nodes"].values()))
+            respond = stats["http"]["respond"]
+            if respond["count"] >= 1 or time.monotonic() > give_up:
+                break
+            time.sleep(0.01)
         assert respond["count"] >= 1 and respond["sum_s"] > 0
         assert respond["p99_ms"] >= respond["p50_ms"] > 0
 
