@@ -32,7 +32,7 @@ Design rules (the repo's device + lock discipline applies here too):
 Fallback-reason vocabulary (ARCHITECTURE.md "Profile API"): why a query
 left the fused device path —
   fuzzy_match, bool_filter_clause, non_term_subclause,
-  function_score_no_query, function_score_ineligible, unscored_subquery,
+  function_score_no_query, function_score_ineligible,
   non_flat_subquery, similarity_not_fused, host_only_field,
   unsupported_query:<Type>,
   device_disabled, features:<f1,f2,...>, device_error:<Type>.

@@ -217,8 +217,8 @@ class PackedSegment:
     # numeric field, exact for MULTI-valued columns because the per-doc folds
     # happen host-side at build time (ops/scoring.score_agg_batch_async reduces them
     # under the match mask — SURVEY §5.7 "shard-level parallel reduce")
-    agg_rows: dict = dc_field(default_factory=dict)  # field -> HOST f32 [5, Dpad] | None (not f32-exact)
-    agg_stacks: dict = dc_field(default_factory=dict)  # fields-tuple -> device [F, 5, Dpad], FIFO-bounded
+    agg_rows: dict = dc_field(default_factory=dict)  # field -> HOST (f32 [5, Dpad], int32 limbs [L, Dpad] | None)
+    agg_stacks: dict = dc_field(default_factory=dict)  # fields-tuple -> AggStack (device rows + limbs), FIFO-bounded
     bucket_cols: dict = dc_field(default_factory=dict)  # bucket-agg cache key -> device (pair_doc, pair_bucket, zeros[NB])
     # field-sort key rows (execute._sort_key_row), FIFO-bounded:
     # (field, mode, order, missing) -> device f32 [Dpad], the column's values
@@ -347,7 +347,8 @@ def packed_tier_bytes(packed: PackedSegment) -> dict:
       dense_plane  the lazily-faulted f32 freqs plane and the head-term rows
                    (0 until dense use)
       sim_tables   the stacked per-field similarity LUTs (modes + caches)
-      agg_rows     FIFO-bounded device metric-agg stacks
+      agg_rows     FIFO-bounded device metric-agg stacks (float32 folds)
+      agg_limbs    their integer limb rows (exact sums of whole-number columns)
       sort_keys    FIFO-bounded field-sort key rows (values or exact ranks)
       norms        per-field norm-byte columns + live mask + dv columns
 
@@ -359,7 +360,7 @@ def packed_tier_bytes(packed: PackedSegment) -> dict:
     sim = 0
     if packed.sim is not None:
         sim = _plane_bytes(packed.sim.caches) + _plane_bytes(packed.sim.modes)
-    agg = sum(_plane_bytes(stack) for stack in packed.agg_stacks.values())
+    stacks = list(packed.agg_stacks.values())
     norms = _plane_bytes(packed.live_parent)
     for col in packed.norm_bytes.values():
         norms += _plane_bytes(col)
@@ -372,7 +373,8 @@ def packed_tier_bytes(packed: PackedSegment) -> dict:
         "dense_plane": (_plane_bytes(packed.blk_freqs)
                         + _plane_bytes(packed.head_rows)),
         "sim_tables": sim,
-        "agg_rows": agg,
+        "agg_rows": sum(_plane_bytes(stack.rows) for stack in stacks),
+        "agg_limbs": sum(_plane_bytes(stack.limbs) for stack in stacks),
         "sort_keys": sum(_plane_bytes(row)
                          for row in list(packed.sort_rows.values())),
         "norms": norms,
@@ -529,7 +531,7 @@ def segment_capacity(seg: FrozenSegment) -> dict | None:
         return None
     tiers = packed_tier_bytes(packed) if packed is not None else {
         "postings": 0, "dense_plane": 0, "sim_tables": 0, "agg_rows": 0,
-        "sort_keys": 0, "norms": 0}
+        "agg_limbs": 0, "sort_keys": 0, "norms": 0}
     tiers["filter_masks"] = mask_bytes
     return {
         "generation": int(seg.gen),
@@ -952,14 +954,73 @@ def ensure_head_rows(packed: PackedSegment, breaker=None):
     return packed.head_rows
 
 
-def agg_doc_rows(seg: FrozenSegment, field: str) -> np.ndarray | None:
+def _whole_numbers(vals: np.ndarray) -> bool:
+    return bool(np.all(vals == np.floor(vals)))
+
+
+def _agg_column_facts(seg: FrozenSegment, field: str) -> tuple | None:
+    """What agg_device_exact asks of one numeric column of a segment, kept on
+    the segment: None for a column with a fractional value or with no value
+    at all (no exact answer to lose), else (float32 holds every value, the
+    integer limbs hold every document's sum, the absolute values' sum)."""
+    ckey = ("agg_facts", field)
+    if ckey not in seg._device_cache:
+        col = seg.dv_num.get(field)
+        facts = None
+        if col is not None and len(col[1]) and _whole_numbers(col[1]):
+            off, vals = col
+            mags = np.abs(vals)
+            facts = (
+                np.array_equal(vals.astype(np.float32).astype(np.float64), vals),
+                seg.doc_count <= LIMB_MAX_DOCS
+                and float(mags.max()) * int(np.diff(off).max()) < 2.0 ** 62,
+                float(mags.sum()))
+        seg._device_cache[ckey] = facts
+    return seg._device_cache[ckey]
+
+
+def agg_device_exact(segs: list, field: str, needs_values: bool,
+                     f32_sums: bool = False) -> bool:
+    """THE rule of whether a device program answers a metric aggregation of
+    `field` over `segs` as exactly as the host collectors do; both serving
+    paths ask it (service._try_device_aggs, mesh_search.ensure_mesh_agg_stack)
+    and a refusal sends the search to the host collectors, or from the mesh
+    to the transport path. A fractional column always rides: inherently
+    approximate reals, ~1e-7 relative rounding in float32, same as an ES
+    `float`-typed field. A column of whole numbers is semantically exact
+    (epoch millis shifted by f32 rounding would be a wrong answer), so:
+
+    - `needs_values` (the agg serves a min or a max:
+      aggregations.device_agg_needs_values): float32 has to hold every value
+      (longs/dates past 2^24 do not survive the round trip);
+    - its sum: the one-shard program adds integer limbs (agg_int_limbs),
+      which have to hold every document's sum (an int64's worth, in a segment
+      of at most LIMB_MAX_DOCS documents); the mesh program (`f32_sums`)
+      reduces in float32, where every partial sum stays a whole number below
+      2^24 only if the absolute values of `segs` together add up to less.
+
+    No whole-number sum is served from a float32 accumulator that could have
+    rounded it."""
+    total = 0.0
+    for seg in segs:
+        facts = _agg_column_facts(seg, field)
+        if facts is None:
+            continue
+        f32_values, limbs_hold, abs_sum = facts
+        if needs_values and not f32_values:
+            return False
+        if not f32_sums and not limbs_hold:
+            return False
+        total += abs_sum
+    return not f32_sums or total < float(1 << 24)
+
+
+def agg_doc_rows(seg: FrozenSegment, field: str) -> np.ndarray:
     """Per-doc metric folds of one numeric column: float32 [5, doc_count] rows
-    (count, sum, min, max, sumsq), or None when the column is INTEGER-valued but
-    not exactly float32-representable (longs/dates past 2^24: integers are
-    semantically exact — epoch millis shifted by f32 rounding would be a wrong
-    answer, so those columns stay on the exact host collectors). Fractional
-    columns are inherently approximate reals and take the f32 kernel (~1e-7
-    relative rounding, same as an ES `float`-typed field).
+    (count, sum, min, max, sumsq). Whether a value or a sum served from them
+    is exact is agg_device_exact's to say, and both serving paths ask it
+    first; the one-shard program zeroes the sum row of a column of whole
+    numbers and adds its limbs (ensure_agg_rows).
 
     Multi-valued docs fold exactly (cumsum difference / reduceat over the CSR);
     docs with no value carry count 0 and ±inf min/max so the kernel's masked
@@ -972,10 +1033,6 @@ def agg_doc_rows(seg: FrozenSegment, field: str) -> np.ndarray | None:
     if col is None:
         return rows
     off, vals = col
-    if len(vals) and not np.array_equal(
-            vals.astype(np.float32).astype(np.float64), vals) \
-            and np.all(vals == np.floor(vals)):
-        return None
     counts = np.diff(off)
     c = np.zeros(len(vals) + 1)
     np.cumsum(vals, out=c[1:])
@@ -997,6 +1054,50 @@ def agg_doc_rows(seg: FrozenSegment, field: str) -> np.ndarray | None:
     return rows
 
 
+# An integer column's sum, exact on the device whatever its magnitude: each
+# document's sum of values is split into limbs of LIMB_BITS bits held as int32
+# rows, the program adds the limbs of the matched documents as int32 (a limb
+# is below 2^11 and a segment holds at most LIMB_MAX_DOCS documents, so no
+# limb's total reaches 2^31), and the host puts the totals together as Python
+# integers (limb_totals). The low limbs are unsigned and the top one carries
+# the sign (an arithmetic shift), so negative values add up as they should.
+LIMB_BITS = 11
+LIMB_MAX_DOCS = 1 << 20
+_LIMB_RUNGS = (3, 6)  # limbs a column: 33 signed bits, or all of an int64
+
+
+def agg_int_limbs(seg: FrozenSegment, field: str) -> np.ndarray | None:
+    """int32 [L, doc_count] limb rows of each document's summed values, L the
+    first of _LIMB_RUNGS that holds the segment's largest; None for a column
+    with a fractional value (its sum is a float32 sum: agg_doc_rows) and for
+    one this segment lacks. The caller has asked agg_device_exact whether the
+    limbs hold the column."""
+    if _agg_column_facts(seg, field) is None:
+        return None
+    off, vals = seg.dv_num[field]
+    c = np.zeros(len(vals) + 1, np.int64)
+    np.cumsum(vals.astype(np.int64), out=c[1:])
+    sums = c[off[1:]] - c[off[:-1]]
+    peak = int(np.abs(sums).max()) if len(sums) else 0
+    n = next(n for n in _LIMB_RUNGS
+             if peak < 1 << (LIMB_BITS * n - 1) or n == _LIMB_RUNGS[-1])
+    limbs = np.empty((n, seg.doc_count), np.int32)
+    for i in range(n - 1):
+        limbs[i] = (sums >> (LIMB_BITS * i)) & ((1 << LIMB_BITS) - 1)
+    limbs[n - 1] = sums >> (LIMB_BITS * (n - 1))
+    return limbs
+
+
+def limb_totals(limbs: np.ndarray, axis: int) -> np.ndarray:
+    """The limbs' totals (the program's int32 reductions, `axis` the limb
+    axis) put together as Python integers, in an object array."""
+    weights = np.array([1 << (LIMB_BITS * i) for i in range(limbs.shape[axis])],
+                       dtype=object)
+    shape = [1] * limbs.ndim
+    shape[axis] = -1
+    return (limbs.astype(object) * weights.reshape(shape)).sum(axis=axis)
+
+
 def _pad_agg_rows(rows: np.ndarray, doc_pad: int, base: int = 0,
                   out: np.ndarray | None = None) -> np.ndarray:
     """Place [5, D] rows at `base` inside a [5, doc_pad] canvas (empty slots:
@@ -1009,33 +1110,62 @@ def _pad_agg_rows(rows: np.ndarray, doc_pad: int, base: int = 0,
     return out
 
 
-def ensure_agg_rows(seg: FrozenSegment, packed: PackedSegment, fields: list[str],
-                    breaker=None):
-    """Device-resident [F, 5, Dpad] stack for `fields`, or None when any column
-    is not f32-exact (callers fall back to the host collectors). Per-field rows
-    cache HOST-side; only the per-tuple device stacks (FIFO-bounded) hold device
-    memory — mirroring ensure_mesh_agg_stack.
+@dataclass(frozen=True)
+class AggStack:
+    """The device-resident per-doc folds of a tuple of fields on one packed
+    segment: `rows` f32 [F, 5, Dpad] (agg_doc_rows; the sum and sumsq rows of
+    a limbed field are zero: nothing adds whole numbers up in float32) and
+    `limbs` int32 [F, L, Dpad] (agg_int_limbs; zero rows for a field without
+    them and past a field's own count, L = 0 where no field has any).
+    `limbed[i]` says whether field i's sum is its limbs' to give."""
 
-    `breaker` (fielddata) reserves the [F, 5, Dpad] f32 stack (host rows +
-    device copy) before it is built — the per-doc fold columns are the
-    fielddata-load analogue on this engine."""
+    rows: object
+    limbs: object
+    limbed: tuple
+
+
+def ensure_agg_rows(seg: FrozenSegment, packed: PackedSegment, fields: list[str],
+                    breaker=None) -> AggStack | None:
+    """The segment's AggStack for `fields`, or None where the limbs do not
+    hold a column of whole numbers (agg_device_exact; callers fall back to the
+    host collectors). Per-field rows cache HOST-side; only the per-tuple
+    device stacks (FIFO-bounded) hold device memory — mirroring
+    ensure_mesh_agg_stack.
+
+    `breaker` (fielddata) reserves the [F, 5, Dpad] f32 stack and the limb
+    stack (host rows + device copy) before they are built — the per-doc fold
+    columns are the fielddata-load analogue on this engine."""
     import jax.numpy as jnp
 
     key = tuple(fields)
     stack = packed.agg_stacks.get(key)
     if stack is not None:
         return stack
-    est = len(fields) * 5 * packed.doc_pad * 4 * 2  # host rows + device stack
+    if not all(agg_device_exact([seg], f, needs_values=False) for f in fields):
+        return None
+    est = len(fields) * (5 + _LIMB_RUNGS[-1]) * packed.doc_pad * 4 * 2
     with reserve(breaker, est, f"<agg_rows>{list(fields)}"):
         for f in fields:
             if f not in packed.agg_rows:
-                rows = agg_doc_rows(seg, f)
-                packed.agg_rows[f] = (None if rows is None
-                                      else _pad_agg_rows(rows, packed.doc_pad))
-        if any(packed.agg_rows[f] is None for f in fields):
-            return None
-        stack = jnp.asarray(np.stack([packed.agg_rows[f] for f in fields])
-                            if fields else np.zeros((0, 5, packed.doc_pad), np.float32))
+                rows = _pad_agg_rows(agg_doc_rows(seg, f), packed.doc_pad)
+                limbs = agg_int_limbs(seg, f)
+                if limbs is not None:
+                    rows[1] = rows[4] = 0.0
+                    limbs = np.pad(
+                        limbs, ((0, 0), (0, packed.doc_pad - limbs.shape[1])))
+                packed.agg_rows[f] = (rows, limbs)
+        held = [packed.agg_rows[f] for f in fields]
+        n_limbs = max((limbs.shape[0] for _r, limbs in held
+                       if limbs is not None), default=0)
+        limb_stack = np.zeros((len(fields), n_limbs, packed.doc_pad), np.int32)
+        for i, (_rows, limbs) in enumerate(held):
+            if limbs is not None:
+                limb_stack[i, : limbs.shape[0]] = limbs
+        stack = AggStack(
+            jnp.asarray(np.stack([rows for rows, _l in held]) if fields
+                        else np.zeros((0, 5, packed.doc_pad), np.float32)),
+            jnp.asarray(limb_stack),
+            tuple(limbs is not None for _r, limbs in held))
         while len(packed.agg_stacks) >= 8:
             packed.agg_stacks.pop(next(iter(packed.agg_stacks)))
         packed.agg_stacks[key] = stack

@@ -40,6 +40,7 @@ from ..common.jaxenv import current_compile_family
 from .device_index import (
     BLOCK,
     TFN_BM25,
+    AggStack,
     PackedSegment,
     _ladder_bucket,
     _pow2_bucket,
@@ -300,7 +301,8 @@ class LaunchCounters:
              "posting_bytes", "dense_rows", "head_slots", "blocks_as_rows",
              "launches_sparse", "launches_dense", "operand_puts",
              "unscored_plans", "launches_unscored", "unscored_bytes",
-             "mask_put_bytes"), 0)
+             "mask_put_bytes", "launches_fs_unscored", "fs_row_put_bytes",
+             "exact_sum_rows"), 0)
 
     def add(self, real: int, launched: int, nbytes: int,
             dense_rows: int = 0, head_slots: int = 0,
@@ -324,9 +326,14 @@ class LaunchCounters:
         """The tallies of the launches that read no postings: plans with no
         scoring clause (`unscored_plans`, once a plan whatever its segments),
         their launches and the bytes those read (`launches_unscored`,
-        `unscored_bytes`: _count_unscored), and the bytes of filter-mask rows
-        a search evaluated on the host and put (`mask_put_bytes`:
-        execute._filter_mask_matrix)."""
+        `unscored_bytes`: _count_unscored; `launches_fs_unscored`: those of
+        them behind a function_score tail), the bytes of filter-mask rows a
+        search evaluated on the host and put (`mask_put_bytes`:
+        execute._filter_mask_matrix), the bytes of function rows, applies
+        rows and script column rows a function_score launch was handed
+        (`fs_row_put_bytes`: execute._execute_flat_fs), and the integer limb
+        rows the aggregated launches reduced (`exact_sum_rows`:
+        score_agg_batch_async)."""
         with self._lock:
             for name, n in counts.items():
                 self._c[name] += n
@@ -541,11 +548,12 @@ def _bmode_combine(sub, comb, applied, bmode: str):
     raise ValueError(f"unknown boost_mode [{bmode}]")
 
 
-def _fs_rows_impl(scores, match, g_row, applies_row, max_boost, fboost,
+def _fs_rows_impl(scores, match, fmask, g_row, applies_row, max_boost, fboost,
                   min_score, *, k: int, bmode: str, use_min_score: bool,
                   no_functions: bool):
     import jax.numpy as jnp
 
+    match = match & fmask
     if no_functions:
         out = scores * fboost
     else:
@@ -558,14 +566,15 @@ def _fs_rows_impl(scores, match, g_row, applies_row, max_boost, fboost,
     return _top_k_tail(out, match, k=k)
 
 
-def _fs_script_impl(scores, match, col_rows, fmask_row, bad_row, parent_row,
-                    weight_s, max_boost, fboost, min_score,
+def _fs_script_impl(scores, match, fmask, col_rows, fmask_row, bad_row,
+                    parent_row, weight_s, max_boost, fboost, min_score,
                     *, k: int, script, used_fields: tuple, bmode: str,
                     use_min_score: bool, has_filter: bool, has_weight: bool):
     import jax.numpy as jnp
 
     from ..script import jax_vectorizer_cls
 
+    match = match & fmask
     cols = dict(zip(used_fields, col_rows))
     vec = jax_vectorizer_cls()(script, lambda f: cols[f], scores)
     val = jnp.broadcast_to(jnp.asarray(vec.vectorize(), jnp.float32), scores.shape)
@@ -585,7 +594,8 @@ def _fs_script_impl(scores, match, col_rows, fmask_row, bad_row, parent_row,
     return (*_top_k_tail(out, match, k=k), bad)
 
 
-def _get_fs_compiled(kind: str, n_queries: int, k: int, doc_pad: int, **statics):
+def _get_fs_compiled(kind: str, n_queries: int, k: int, doc_pad: int,
+                     abi=_dense_abi, suffix: str = "", **statics):
     import jax
 
     if kind == "rows":
@@ -597,11 +607,12 @@ def _get_fs_compiled(kind: str, n_queries: int, k: int, doc_pad: int, **statics)
                repr(sorted(script.params.items())),
                tuple(sorted((k2, v) for k2, v in statics.items())))
         impl = functools.partial(_fs_script_impl, script=script)
+    key += (suffix,) if suffix else ()
     fn = _compiled_cache.get(key)
     if fn is None:
-        wrapper = _dense_abi(impl, n_queries=n_queries, doc_pad=doc_pad, k=k,
-                             **statics)
-        fn = jax.jit(_named("scoring.fs_" + kind, wrapper))
+        wrapper = abi(impl, n_queries=n_queries, doc_pad=doc_pad, k=k,
+                      **statics)
+        fn = jax.jit(_named("scoring.fs_" + kind, wrapper, suffix))
         _compiled_cache[key] = fn
     return fn
 
@@ -673,45 +684,62 @@ def _dense_args(packed: PackedSegment, batch: TermBatch, *host):
             *_put_operands(batch.tri, batch.qplane, batch.head, *host))
 
 
-def score_fs_rows_batch(packed: PackedSegment, batch: TermBatch, k: int,
-                        g_row, applies_row, max_boost: float, fboost: float,
-                        min_score, bmode: str, no_functions: bool):
-    """Dense launch with host-combined function rows; returns (scores, docs, total)
-    numpy [Q, k]/[Q]."""
+def _count_fs_unscored(packed: PackedSegment, batch, row_bytes: int) -> None:
+    """A function_score launch of plans with no scoring clause: tallied with
+    the unscored launches (_count_unscored; `row_bytes` a query for the
+    function row or the script's column rows its tail reads) and apart."""
+    if isinstance(batch, ConstBatch):
+        _count_unscored(packed, batch, row_bytes)
+        LAUNCHES.bump(launches_fs_unscored=1)
+
+
+def score_fs_rows_batch_async(packed: PackedSegment, batch: TermBatch, k: int,
+                              fmask, g_row, applies_row, max_boost: float,
+                              fboost: float, min_score, bmode: str,
+                              no_functions: bool):
+    """Dense launch with host-combined function rows; returns device (scores,
+    docs, total) [Q, k]/[Q] without syncing (the caller pulls:
+    execute._execute_flat_fs). `fmask`: optional bool [Q, Dpad] match gates of
+    filtered or unscored sub queries."""
     params = (batch.n_queries, min(k, packed.doc_pad), packed.doc_pad,
               bmode, min_score is not None, no_functions)
+    abi, suffix = _abi_for(batch)
     fn = _get_fs_compiled(
-        "rows", params[0], params[1], params[2],
+        "rows", params[0], params[1], params[2], abi, suffix,
         bmode=bmode, use_min_score=min_score is not None, no_functions=no_functions)
+    _count_fs_unscored(packed, batch, row_bytes=4)
     args = _dense_args(
-        packed, batch,
-        np.asarray(g_row, np.float32), np.asarray(applies_row, bool),
+        packed, batch, _no_mask() if fmask is None else fmask,
+        g_row, applies_row,
         np.float32(max_boost), np.float32(fboost),
         np.float32(min_score if min_score is not None else 0.0))
     # the script variant is NOT recorded: its executable closes over a live
     # sandboxed script object that has no JSON form to replay from a manifest
-    return _pull(_launch(fn, args, "scoring.fs_rows", "function_score", params))
+    return _launch(fn, args, _site("scoring.fs_rows", suffix),
+                   "function_score", params)
 
 
-def score_fs_script_batch(packed: PackedSegment, batch: TermBatch, k: int,
-                          script, used_fields: tuple, col_rows, fmask_row,
-                          bad_row, parent_row, weight, max_boost: float,
-                          fboost: float, min_score, bmode: str, has_filter: bool):
-    """Dense launch with the script traced into the kernel; returns
-    (scores, docs, total, bad) numpy."""
+def score_fs_script_batch_async(packed: PackedSegment, batch: TermBatch, k: int,
+                                fmask, script, used_fields: tuple, col_rows,
+                                fmask_row, bad_row, parent_row, weight,
+                                max_boost: float, fboost: float, min_score,
+                                bmode: str, has_filter: bool):
+    """Dense launch with the script traced into the kernel; returns device
+    (scores, docs, total, bad) without syncing."""
+    abi, suffix = _abi_for(batch)
     fn = _get_fs_compiled(
         "script", batch.n_queries, min(k, packed.doc_pad), packed.doc_pad,
+        abi, suffix,
         script=script, used_fields=used_fields, bmode=bmode,
         use_min_score=min_score is not None, has_filter=has_filter,
         has_weight=weight is not None)
-    return _pull(_launch(fn, _dense_args(
-        packed, batch,
-        tuple(col_rows),
-        np.asarray(fmask_row, bool), np.asarray(bad_row, bool),
-        np.asarray(parent_row, bool),
+    _count_fs_unscored(packed, batch, row_bytes=4 * len(col_rows))
+    return _launch(fn, _dense_args(
+        packed, batch, _no_mask() if fmask is None else fmask,
+        tuple(col_rows), fmask_row, bad_row, parent_row,
         np.float32(weight if weight is not None else 1.0),
         np.float32(max_boost), np.float32(fboost),
-        np.float32(min_score if min_score is not None else 0.0))))
+        np.float32(min_score if min_score is not None else 0.0)))
 
 
 # ---------------------------------------------------------------------------
@@ -740,6 +768,19 @@ def _false_row(doc_pad: int):
     resident rows to the next query count of the ladder
     (execute._filter_mask_matrix), put once a `doc_pad`."""
     return _put_operands(np.zeros(doc_pad, dtype=bool))[0]
+
+
+@functools.lru_cache(maxsize=None)
+def ladder_mask(q: int, width: int, doc_pad: int):
+    """A resident [width, Dpad] mask whose first `q` rows match everything and
+    the rest nothing: what a group of `q` unscored plans WITHOUT a filter
+    launches under at a rung of `width` > 1 queries (execute._group_operands),
+    whether the rung is full or padded. One mask shape a rung means one
+    program a rung: with the [1, 1] no-op at a full rung and a put mask at a
+    padded one, a rung had two, and the rarer (four searches of one operation
+    in one batch) was first met inside a measured window (PERF.md section 6,
+    PR 35). Kept for the few (q, width) a group's ladder has."""
+    return _put_operands(np.arange(width)[:, None].repeat(doc_pad, 1) < q)[0]
 
 
 def ladder_const_batch(batch: ConstBatch, fmask, doc_pad: int, width: int):
@@ -772,15 +813,17 @@ def score_filtered_batch_async(packed: PackedSegment, batch: TermBatch, k: int,
     padding off."""
     empty = packed.agg_stacks.get(())  # device_index.ensure_agg_rows' key
     if empty is None:
-        (empty,) = _put_operands(np.zeros((0, 5, packed.doc_pad), np.float32))
-        packed.agg_stacks[()] = empty
+        empty = packed.agg_stacks[()] = AggStack(*_put_operands(
+            np.zeros((0, 5, packed.doc_pad), np.float32),
+            np.zeros((0, 0, packed.doc_pad), np.int32)), ())
     if isinstance(batch, ConstBatch):
         # unscored plans coalesce in any number: the query count rides the
         # pow-2 ladder, so a window meets four programs
         batch, fmask = ladder_const_batch(batch, fmask, packed.doc_pad,
                                           _pow2_bucket(batch.n_queries, 1))
-    scores, docs, total, _counts, _stats, _buckets = score_agg_batch_async(
-        packed, batch, k, empty, (), fmask=fmask, filtered=True)
+    scores, docs, total, _counts, _stats, _sums, _buckets = \
+        score_agg_batch_async(packed, batch, k, empty, (), fmask=fmask,
+                              filtered=True)
     return scores, docs, total
 
 
@@ -844,15 +887,18 @@ def score_sorted_batch_async(packed: PackedSegment, batch: TermBatch, k: int,
     return _launch(fn, args, _site("scoring.sorted", suffix), "sorted", params)
 
 
-def agg_stat_reduction(match, agg_rows):
+def agg_stat_reduction(match, agg_rows, agg_limbs=None):
     """Masked metric stats under a match mask — the ONE implementation both trace
     contexts call (single-shard _dense_aggstats_impl and the mesh SPMD program).
 
     match: bool [Q, Dpad]; agg_rows: f32 [F, 5, Dpad] per-doc folds
-    (device_index.agg_doc_rows). Returns (counts int32 [Q, F], stats f32
-    [Q, F, 4] = (sum, min, max, sumsq)). Counts ride an exact int32 reduction —
-    an f32 accumulator would silently round past 2^24 matched values; sums and
-    sumsq share one [Q, Dpad] @ [Dpad, 2F] matmul (MXU work)."""
+    (device_index.agg_doc_rows); agg_limbs: int32 [F, L, Dpad] limb rows of
+    the whole-number columns' per-doc sums (device_index.agg_int_limbs) or
+    None. Returns (counts int32 [Q, F], stats f32 [Q, F, 4] = (sum, min, max,
+    sumsq), limb totals int32 [Q, F, L] | None). Counts and limbs ride exact
+    int32 reductions — an f32 accumulator would silently round past 2^24;
+    the f32 sums and sumsq (fractional columns) share one [Q, Dpad] @
+    [Dpad, 2F] matmul (MXU work)."""
     import jax.numpy as jnp
 
     F = agg_rows.shape[0]
@@ -866,15 +912,22 @@ def agg_stat_reduction(match, agg_rows):
     mins = jnp.where(has, agg_rows[None, :, 2, :], jnp.inf).min(axis=2)
     maxs = jnp.where(has, agg_rows[None, :, 3, :], -jnp.inf).max(axis=2)
     stats = jnp.stack([sums2[:, :F], mins, maxs, sums2[:, F:]], axis=2)
-    return counts, stats
+    limb_sums = None
+    if agg_limbs is not None:
+        limb_sums = jnp.sum(
+            jnp.where(match[:, None, None, :], agg_limbs[None], 0),
+            axis=3, dtype=jnp.int32)  # [Q, F, L]
+    return counts, stats, limb_sums
 
 
-def _bucket_scatter(match, pdoc, pbucket, nb: int, sub_stack):
+def _bucket_scatter(match, pdoc, pbucket, nb: int, sub_stack, sub_limbs=None):
     """One bucket agg's reductions: exact int32 doc counts per bucket, plus —
-    when the agg carries metric sub-aggs (sub_stack [Fs, 5, Dpad]) — per-bucket
-    masked stats of the per-doc folds, scattered along the SAME (doc, bucket)
-    pairs so a doc contributes once per bucket it belongs to (exactly the host's
-    per-bucket mask collection)."""
+    when the agg carries metric sub-aggs (sub_stack [Fs, 5, Dpad], sub_limbs
+    int32 [Fs, L, Dpad] | None) — per-bucket masked stats of the per-doc
+    folds, scattered along the SAME (doc, bucket) pairs so a doc contributes
+    once per bucket it belongs to (exactly the host's per-bucket mask
+    collection). The limbs of whole-number columns are scattered and added as
+    int32, like the counts."""
     import jax.numpy as jnp
 
     Q = match.shape[0]
@@ -882,7 +935,7 @@ def _bucket_scatter(match, pdoc, pbucket, nb: int, sub_stack):
     counts = jnp.zeros((Q, nb), jnp.int32).at[:, pbucket].add(
         hit.astype(jnp.int32))
     if sub_stack is None:
-        return counts, None, None
+        return counts, None, None, None
     Fs = sub_stack.shape[0]
     m = hit[:, None, :]  # [Q, 1, NP]
     cnt_g = sub_stack[:, 0][:, pdoc].astype(jnp.int32)  # [Fs, NP]
@@ -898,21 +951,32 @@ def _bucket_scatter(match, pdoc, pbucket, nb: int, sub_stack):
         base = jnp.full((Q, Fs, nb), jnp.float32(fill))
         parts.append(getattr(base.at[:, :, pbucket], op)(contrib))
     sub_stats = jnp.stack([parts[0], parts[1], parts[2], parts[3]], axis=3)
-    return counts, sub_cnt, sub_stats  # [Q,Fs,nb], [Q,Fs,nb,4]=(sum,min,max,sumsq)
+    sub_sums = None
+    if sub_limbs is not None:
+        n_limbs, doc_pad = sub_limbs.shape[1:]
+        g = sub_limbs.reshape(Fs * n_limbs, doc_pad)[:, pdoc]  # [Fs*L, NP]
+        sub_sums = jnp.zeros((Q, Fs * n_limbs, nb), jnp.int32).at[
+            :, :, pbucket].add(jnp.where(m, g[None], 0)).reshape(
+                Q, Fs, n_limbs, nb)
+    # [Q,Fs,nb], [Q,Fs,nb,4]=(sum,min,max,sumsq), [Q,Fs,L,nb] limb totals
+    return counts, sub_cnt, sub_stats, sub_sums
 
 
 def _dense_aggstats_impl(scores, match,
                          agg_rows,  # [F, 5, Dpad] f32 (F may be 0)
-                         bucket_pairs,  # tuple of (pair_doc, pair_bucket, nb zeros, sub_stack|None)
+                         agg_limbs,  # [F, L, Dpad] int32 (L may be 0)
+                         bucket_pairs,  # tuple of (pair_doc, pair_bucket, nb zeros, (sub rows, sub limbs)|None)
                          fmask,  # bool [Q, Dpad] — FilteredQuery masks (all-true when none)
                          *, k: int):
     match = match & fmask
-    counts, stats = agg_stat_reduction(match, agg_rows)
+    counts, stats, limb_sums = agg_stat_reduction(match, agg_rows, agg_limbs)
     bucket_counts = tuple(
-        _bucket_scatter(match, pdoc, pbucket, zeros_nb.shape[0], sub_stack)
-        for (pdoc, pbucket, zeros_nb, sub_stack) in bucket_pairs
+        _bucket_scatter(match, pdoc, pbucket, zeros_nb.shape[0],
+                        *(sub or (None, None)))
+        for (pdoc, pbucket, zeros_nb, sub) in bucket_pairs
     )
-    return (*_top_k_tail(scores, match, k=k), counts, stats, bucket_counts)
+    return (*_top_k_tail(scores, match, k=k), counts, stats, limb_sums,
+            bucket_counts)
 
 
 def _get_agg_compiled(n_queries: int, k: int, doc_pad: int, nb_bucket: int,
@@ -937,19 +1001,23 @@ def _get_agg_compiled(n_queries: int, k: int, doc_pad: int, nb_bucket: int,
 
 
 def score_agg_batch_async(packed: PackedSegment, batch: TermBatch, k: int,
-                          agg_row_stack, bucket_pairs=(), fmask=None,
+                          agg_stack, bucket_pairs=(), fmask=None,
                           filtered: bool = False):
     """Dense launch returning device (scores, docs, total, counts [Q, F] int,
-    stats [Q, F, 4], bucket results) without syncing: the caller pulls the
-    whole result pytree in ONE explicit device_get (execute._run_flat_groups;
-    per-leaf np.asarray was a transfer per output — and an implicit one, which
-    the promoted transfer_guard("disallow") sanitizer rejects). stats rows: (sum, min(+inf if none),
-    max(-inf), sumsq) over matched docs per agg field; bucket_pairs: per bucket
-    agg, (pair_doc, pair_bucket, zeros[NB], sub_stack [Fs,5,Dpad]|None) device
-    arrays — each bucket result is (doc counts [Q,NB], sub value-counts
-    [Q,Fs,NB]|None, sub stats [Q,Fs,NB,4]|None); fmask: optional bool [Q, Dpad]
-    FilteredQuery match gates; `filtered` marks the filtered family's launches
-    (no aggregation at all), which compile under their own program name."""
+    stats [Q, F, 4], limb totals [Q, F, L] int, bucket results) without
+    syncing: the caller pulls the whole result pytree in ONE explicit
+    device_get (execute._run_flat_groups; per-leaf np.asarray was a transfer
+    per output — and an implicit one, which the promoted
+    transfer_guard("disallow") sanitizer rejects). `agg_stack`: the
+    segment's device_index.AggStack of the metric fields. stats rows: (sum,
+    min(+inf if none), max(-inf), sumsq) over matched docs per agg field;
+    the sum of a limbed field is its limb totals' (device_index.limb_totals).
+    bucket_pairs: per bucket agg, (pair_doc, pair_bucket, zeros[NB], AggStack
+    of the sub fields | None) — each bucket result is (doc counts [Q,NB], sub
+    value-counts [Q,Fs,NB]|None, sub stats [Q,Fs,NB,4]|None, sub limb totals
+    [Q,Fs,L,NB]|None); fmask: optional bool [Q, Dpad] FilteredQuery match
+    gates; `filtered` marks the filtered family's launches (no aggregation at
+    all), which compile under their own program name."""
     params = (batch.n_queries, min(k, packed.doc_pad), packed.doc_pad,
               _pow2_bucket(len(bucket_pairs), 1) if bucket_pairs else 0,
               filtered)
@@ -959,9 +1027,18 @@ def score_agg_batch_async(packed: PackedSegment, batch: TermBatch, k: int,
         _count_unscored(packed, batch, row_bytes=0)
     if fmask is None:
         fmask = _no_mask()
-    # a host agg stack or mask rides the launch's one put (device arrays
-    # pass through it); a raw numpy arg would be an implicit H2D at dispatch
-    args = _dense_args(packed, batch, agg_row_stack, tuple(bucket_pairs), fmask)
+    pairs = tuple(
+        (pdoc, pbucket, zeros_nb, None if sub is None else (sub.rows, sub.limbs))
+        for (pdoc, pbucket, zeros_nb, sub) in bucket_pairs)
+    stacks = [agg_stack] + [sub for *_pair, sub in bucket_pairs
+                            if sub is not None]
+    limb_rows = sum(sum(st.limbed) * st.limbs.shape[1] for st in stacks)
+    if limb_rows:
+        LAUNCHES.bump(exact_sum_rows=limb_rows)
+    # a host mask rides the launch's one put (device arrays pass through
+    # it); a raw numpy arg would be an implicit H2D at dispatch
+    args = _dense_args(packed, batch, agg_stack.rows, agg_stack.limbs, pairs,
+                       fmask)
     return _launch(fn, args, _site("scoring.aggs", suffix), "aggs", params)
 
 
@@ -1615,12 +1692,21 @@ def _build_aggs_unscored(params):
     return _get_agg_compiled(*params, _unscored_abi, "unscored")
 
 
+def _fs_rows_from(params, abi=_dense_abi, suffix: str = ""):
+    n_queries, k, doc_pad, bmode, use_min_score, no_functions = params
+    return _get_fs_compiled("rows", n_queries, k, doc_pad, abi, suffix,
+                            bmode=bmode, use_min_score=use_min_score,
+                            no_functions=no_functions)
+
+
 @_WARM.builder("scoring.fs_rows")
 def _build_fs_rows(params):
-    n_queries, k, doc_pad, bmode, use_min_score, no_functions = params
-    return _get_fs_compiled("rows", n_queries, k, doc_pad, bmode=bmode,
-                            use_min_score=use_min_score,
-                            no_functions=no_functions)
+    return _fs_rows_from(params)
+
+
+@_WARM.builder("scoring.fs_rows_unscored")
+def _build_fs_rows_unscored(params):
+    return _fs_rows_from(params, _unscored_abi, "unscored")
 
 
 @_WARM.builder("scoring.sparse")

@@ -269,8 +269,10 @@ _AGG_STACK_CACHE_MAX = 8  # distinct fields-tuples kept on device per generation
 
 def ensure_mesh_agg_stack(index: ShardedIndex, fields: tuple):
     """Device [S, F, 5, Dpad] per-doc metric folds for `fields`, sharded along
-    "shards" — or None when any column is not f32-exact (serving falls back to
-    the transport/host path). Per-field host rows are computed once per packed
+    "shards" — or None when float32 does not hold a whole-number column's
+    values or a sum a shard can form of them (device_index.agg_device_exact;
+    serving falls back to the transport path, whose one-shard program adds
+    integer limbs). Per-field host rows are computed once per packed
     generation; per-tuple device stacks are FIFO-bounded so rotating agg field
     sets can't grow device memory unboundedly."""
     import jax
@@ -279,24 +281,27 @@ def ensure_mesh_agg_stack(index: ShardedIndex, fields: tuple):
     stack = index.agg_stacks.get(fields)
     if stack is not None:
         return stack
-    from ..ops.device_index import _pad_agg_rows, agg_doc_rows
+    from ..ops.device_index import (_pad_agg_rows, agg_device_exact,
+                                    agg_doc_rows)
 
     S = index.n_shards
     for f in fields:
         if f in index.agg_field_rows:
+            continue
+        # this program reduces in float32 (ROADMAP S13: the limbs have not
+        # come to the mesh; no cell sends it an aggregation)
+        if not all(agg_device_exact(searcher.segments, f, needs_values=True,
+                                    f32_sums=True)
+                   for searcher in index.searchers):
+            index.agg_field_rows[f] = None
             continue
         host_f = np.zeros((S, 5, index.doc_pad), dtype=np.float32)
         host_f[:, 2] = np.inf
         host_f[:, 3] = -np.inf
         for si, searcher in enumerate(index.searchers):
             for seg, base in zip(searcher.segments, searcher.bases):
-                rows = agg_doc_rows(seg, f)
-                if rows is None:
-                    host_f = None
-                    break
-                _pad_agg_rows(rows, index.doc_pad, base, out=host_f[si])
-            if host_f is None:
-                break
+                _pad_agg_rows(agg_doc_rows(seg, f), index.doc_pad, base,
+                              out=host_f[si])
         index.agg_field_rows[f] = host_f
     if any(index.agg_field_rows[f] is None for f in fields):
         return None
@@ -458,7 +463,8 @@ def _mesh_score_program(k: int, n_queries: int, doc_pad: int, similarity_kind: i
             # ShardQueryResult.agg_partials
             from ..ops.scoring import agg_stat_reduction
 
-            local_counts, local_stats = agg_stat_reduction(match, agg_rows[0])
+            local_counts, local_stats, _limbs = agg_stat_reduction(
+                match, agg_rows[0])
             agg_counts = jax.lax.all_gather(local_counts, "shards")  # [S, Qd, F]
             agg_stats = jax.lax.all_gather(local_stats, "shards")  # [S, Qd, F, 4]
 
@@ -475,7 +481,7 @@ def _mesh_score_program(k: int, n_queries: int, doc_pad: int, similarity_kind: i
                 # trace time (TPU001/TPU009)
                 sub_stack = (agg_rows[0][jnp.asarray(sub_idx)]
                              if sub_idx else None)
-                cnts, sub_cnt, sub_stats = _bucket_scatter(
+                cnts, sub_cnt, sub_stats, _limbs = _bucket_scatter(
                     match, pdoc[0], pbucket[0], nb, sub_stack)
                 out = [jax.lax.all_gather(cnts, "shards")]  # [S, Qd, nb]
                 if sub_idx:

@@ -607,8 +607,10 @@ class MeshServingService:
                 sub_aggs_map, order = bucket_subs[name]
                 sub_data = None
                 if sub_aggs_map:
+                    # (no exact sums: this program takes a whole-number
+                    # column only where its float32 sums are exact)
                     sub_data = (agg.subs, sub_aggs_map, order,
-                                scnt[sid, 0], sstats[sid, 0])
+                                scnt[sid, 0], sstats[sid, 0], None)
                 partial[name] = device_bucket_partial(
                     agg, keys, cnts[sid, 0][: len(keys)], seg=None,
                     sub_data=sub_data)

@@ -304,12 +304,22 @@ def device_agg_fields(aggs: dict, ctx) -> dict | None:
     return out
 
 
-def device_partial(agg: Agg, count, st):
+def device_agg_needs_values(agg: Agg) -> bool:
+    """Whether the agg serves a VALUE of its column (a min or a max), which
+    float32 then has to hold; a sum, an average and a count do not ask it.
+    What device_index.agg_device_exact, the one rule of exactness, is told
+    of an agg."""
+    return isinstance(agg, (MinAgg, MaxAgg, StatsAgg))
+
+
+def device_partial(agg: Agg, count, st, exact_sum=None):
     """One kernel result (count int, st = (sum, min, max, sumsq) f32) → the SAME
     partial shape Agg.collect produces, so merge/finalize stay shared between
-    paths. Counts arrive from an exact int32 device reduction."""
+    paths. Counts arrive from an exact int32 device reduction; `exact_sum` is
+    the sum of a whole-number column as the Python integer its limbs add up
+    to, and stands in st[0]'s place where it is given."""
     count = int(count)
-    total = float(st[0])
+    total = float(st[0]) if exact_sum is None else float(exact_sum)
     mn = float(st[1]) if count and np.isfinite(st[1]) else None
     mx = float(st[2]) if count and np.isfinite(st[2]) else None
     if isinstance(agg, AvgAgg):
@@ -553,20 +563,24 @@ def device_bucket_partial(agg: Agg, keys: list, counts: np.ndarray,
     Range and mask-shaped aggs keep zero-count buckets (the host emits every
     range/filter); ranges carry their converted bounds; significant_terms
     attaches per-term background counts. sub_data = (sub_aggs, field_of,
-    field_order, sub_cnt [Fs, NB] int, sub_stats [Fs, NB, 4]) when metric
-    sub-aggs rode the kernel — their partials assemble in the host shapes via
-    device_partial, so merge/finalize nest unchanged."""
+    field_order, sub_cnt [Fs, NB] int, sub_stats [Fs, NB, 4], sub_sums: a
+    field's exact sums [NB] as Python integers, or None, a field; None where
+    no field has any) when metric sub-aggs rode the kernel — their partials assemble in the host
+    shapes via device_partial, so merge/finalize nest unchanged."""
     sub_rows = None
     if sub_data is not None:
-        sub_aggs, field_of, order, scnt, sstats = sub_data
+        sub_aggs, field_of, order, scnt, sstats, ssums = sub_data
+        ssums = ssums or [None] * len(order)
         fpos = {f: i for i, f in enumerate(order)}
         sub_rows = [(n, s, fpos[field_of[n]]) for n, s in sub_aggs.items()]
 
     def mk(bi: int, key, c) -> dict:
         subs = {}
         if sub_rows is not None:
-            subs = {n: device_partial(s, scnt[fi, bi], sstats[fi, bi])
-                    for n, s, fi in sub_rows}
+            subs = {n: device_partial(
+                s, scnt[fi, bi], sstats[fi, bi],
+                None if ssums[fi] is None else ssums[fi][bi])
+                for n, s, fi in sub_rows}
         return {"key": key, "doc_count": int(c), "subs": subs}
 
     if isinstance(agg, RangeAgg):

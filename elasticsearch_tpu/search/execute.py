@@ -28,7 +28,7 @@ import contextlib
 import math
 import re
 import time
-from dataclasses import dataclass, field as dc_field
+from dataclasses import dataclass, field as dc_field, replace as dc_replace
 
 import numpy as np
 
@@ -389,19 +389,28 @@ def _lower_flat_inner(query: Query, ctx: ShardContext) -> FlatPlan | None:
         # device function_score: sub query must lower flat, and the functions must
         # classify as "rows" or "script" (see _classify_fs); the function tail is
         # fused into the dense kernel (ops/scoring._fs_rows_impl/_fs_script_impl,
-        # ref: common/lucene/search/function/FunctionScoreQuery.java)
+        # ref: common/lucene/search/function/FunctionScoreQuery.java). A sub
+        # query with no scoring clause keeps its constant (`_score` is that
+        # constant) and a filtered one its filter: the tail gates the match by
+        # the mask it takes, behind either launch ABI
         if query.query is None:
             return None
         sub = _lower_flat_inner(query.query, ctx)
-        if (sub is None or sub.fs is not None or sub.filt is not None
-                or sub.const is not None):
+        if sub is None or sub.fs is not None:
             return None
         kind = _classify_fs(query)
         if kind is None:
             return None
+        if sub.const is not None:
+            # the host evaluates the sub query under boost 1 (its constant is
+            # `sub.const`), and its queryNorm pre-pass walks through the
+            # wrapper with the wrapper's boost folded in
+            return dc_replace(sub, fs=query, fs_kind=kind,
+                              norm_boost=sub.norm_boost * query.boost)
         return FlatPlan(sub.clauses, msm=sub.msm, n_must=sub.n_must,
                         coord_enabled=sub.coord_enabled, boost=sub.boost,
-                        fs=query, fs_kind=kind, norm_boost=query.boost)
+                        fs=query, fs_kind=kind, norm_boost=query.boost,
+                        filt=sub.filt)
     if isinstance(query, FilteredQuery):
         # the reference's canonical query+filter idiom (ES 1.x `filtered`):
         # boost folds into the sub clauses (host: eval(q.query, b)), the filter
@@ -510,10 +519,8 @@ def lower_fallback_reason(query: Query, ctx: ShardContext) -> str:
         if query.query is None:
             return "function_score_no_query"
         sub = _lower_flat_inner(query.query, ctx)
-        if sub is None:
+        if sub is None or sub.fs is not None:
             return "non_flat_subquery"
-        if sub.const is not None:
-            return "unscored_subquery"
         return "function_score_ineligible"
     if isinstance(query, FilteredQuery):
         return "non_flat_subquery"
@@ -624,16 +631,17 @@ def _flat_groups(plans: list[FlatPlan], tails=None) -> dict:
     """group -> positions in `plans`, in order of first sighting: the plans
     one launch a segment answers together. A group's first element names its
     kind (`search.batcher.kinds` in /_nodes/stats): plain, function_score
-    (by spec), filtered (scored and unscored apart), and by tail aggs and
-    sorted (by the tail's key; scored and unscored apart, as
-    _segment_batches does not mix them)."""
+    (by spec; scored and unscored apart), filtered (scored and unscored
+    apart), and by tail aggs and sorted (by the tail's key; scored and
+    unscored apart, as _segment_batches does not mix them)."""
     groups: dict = {}
     for i, p in enumerate(plans):
         tail = tails[i] if tails else None
         if tail is not None:
             group = (tail.kind, tail.key, p.const is not None)
         elif p.fs is not None:
-            group = ("function_score", *_fs_group_key(p.fs))
+            group = ("function_score", *_fs_group_key(p.fs),
+                     p.const is not None)
         elif p.filt is not None or p.const is not None:
             group = ("filtered", p.const is not None)
         else:
@@ -1231,34 +1239,82 @@ def _segment_batches(plans: list[FlatPlan], ctx: ShardContext):
 _FS_CHUNK = 256  # dense accumulator is O(Q·doc_pad) — bound the launch width
 
 
-def _execute_flat_fs(plans: list[FlatPlan], ctx: ShardContext, k: int) -> list[TopDocs]:
-    """Execute a group of function_score plans sharing ONE spec (see _fs_group_key)
-    through the dense kernel with the function tail fused in.
+def _fs_function_rows(fsq, seg, ctx: ShardContext, doc_pad: int):
+    """The host's side of a "rows" launch on one segment: the spec's doc-only
+    function values, score_mode-combined (functions.combined_doc_rows —
+    float32, bit-identical to the host tail), and the documents a function
+    applies to, each padded to `doc_pad`."""
+    from .functions import combined_doc_rows
 
-    "rows": the spec's doc-only function values are host-combined once per segment
-    (functions.combined_doc_rows — float32, bit-identical to the host tail) and
-    shipped as a row. "script": the single _score-reading script is traced into
-    the kernel; queries flagged bad (missing columns / non-finite values on parent
-    docs) rerun on the host so error semantics are preserved."""
+    D = seg.doc_count
+    g_row = np.ones(doc_pad, np.float32)
+    applies_row = np.zeros(doc_pad, bool)
+    if fsq.functions:
+        g_row[:D], applies_row[:D] = combined_doc_rows(
+            fsq, np.zeros(D, np.float32), seg, ctx)
+    return g_row, applies_row
+
+
+def _fs_script_rows(sf, used_fields, seg, ctx: ShardContext, doc_pad: int):
+    """The host's side of a "script" launch on one segment: one float32 row a
+    column the script reads (NaN: no value), the script function's filter
+    mask, the parent documents that lack a column (they rerun on the host)
+    and the parent mask, each padded to `doc_pad`."""
+    from .filters import segment_mask
+    from .functions import _column_first_value
+
+    D = seg.doc_count
+    col_rows = []
+    colmiss = np.zeros(D, bool)
+    for f in used_fields:
+        col = _column_first_value(seg, f)
+        colmiss |= np.isnan(col)
+        row = np.full(doc_pad, np.nan, np.float32)
+        row[:D] = col.astype(np.float32)
+        col_rows.append(row)
+    parent_row = np.zeros(doc_pad, bool)
+    parent_row[:D] = seg.parent_mask
+    bad_row = np.zeros(doc_pad, bool)
+    bad_row[:D] = seg.parent_mask & colmiss
+    fmask_row = np.zeros(doc_pad, bool)
+    if sf.filter is not None:
+        fmask_row[:D] = segment_mask(seg, sf.filter, ctx)
+    return tuple(col_rows), fmask_row, bad_row, parent_row
+
+
+def _execute_flat_fs(plans: list[FlatPlan], ctx: ShardContext, k: int) -> list[TopDocs]:
+    """Execute a group of function_score plans sharing ONE spec (see
+    _fs_group_key; all scored or all unscored: _flat_groups) through the dense
+    kernel with the function tail fused in, behind the launch ABI of their
+    kind (a scoring.TermBatch, or a ConstBatch for sub queries with no scoring
+    clause, whose `_score` is their constant).
+
+    "rows": the spec's doc-only function values are host-combined once per
+    segment (_fs_function_rows) and shipped as a row. "script": the single
+    _score-reading script is traced into the kernel; queries flagged bad
+    (missing columns / non-finite values on parent docs) rerun on the host so
+    error semantics are preserved. The host's evaluation of a segment's rows
+    is noted on the dispatch clock as `shard.fs_rows` (inside its
+    `dispatch.stage`) and the rows' bytes are counted as
+    `search_serving.launch.fs_row_put_bytes`: nothing keeps a row resident.
+
+    The group launches _GROUP_WIDTH plans at a time, each launch's query
+    count up the ladder the aggregated and sorted groups ride (_group_width:
+    a spec has two programs, not one a count), a segment's rows evaluated
+    once for all of them; the launches of a segment are pulled together."""
+    import jax
+
     from ..common.errors import ScriptError
     from ..ops.device_index import packed_for
-    from ..ops.scoring import score_fs_rows_batch, score_fs_script_batch
+    from ..ops.scoring import (LAUNCHES, _pull, _put_operands,
+                               score_fs_rows_batch_async,
+                               score_fs_script_batch_async)
     from ..script import compile_script, script_vector_info
-    from .functions import _column_first_value, combined_doc_rows
-    from .filters import segment_mask
-
-    if len(plans) > _FS_CHUNK:
-        out: list[TopDocs] = []
-        for start in range(0, len(plans), _FS_CHUNK):
-            out.extend(_execute_flat_fs(plans[start: start + _FS_CHUNK], ctx, k))
-        return out
 
     fsq = plans[0].fs
     kind = plans[0].fs_kind  # classified once at lower time
     Q = len(plans)
-    finals = [finalize_flat(p, ctx) for p in plans]
-    (all_fields, field_idx, _cache_rows, caches_stack,
-     coord_tbl, n_must, msm) = _assemble_batch(plans, finals)
+    chunks = [plans[i: i + _GROUP_WIDTH] for i in range(0, Q, _GROUP_WIDTH)]
 
     script = used_fields = sf = None
     if kind == "script":
@@ -1271,56 +1327,43 @@ def _execute_flat_fs(plans: list[FlatPlan], ctx: ShardContext, k: int) -> list[T
     seg_hits = []
     prof = _profile.current()
     try:
+        operands = [_group_operands(chunk, ctx) for chunk in chunks]
         for seg, base in zip(ctx.searcher.segments, ctx.searcher.bases):
-            t_seg = time.monotonic() if prof is not None else 0.0
+            t_seg = time.monotonic()
             packed = packed_for(seg, breaker=ctx.breaker("fielddata"),
                                 owner=ctx.index_name)
-            _ensure_norm_rows(packed, all_fields,
-                              breaker=ctx.breaker("fielddata"))
-            entries = _dense_entries(finals, seg, packed, field_idx)
-            batch = _term_batch(entries, Q, n_must, msm, coord_tbl,
-                                list(all_fields), caches_stack, packed)
             D, doc_pad = seg.doc_count, packed.doc_pad
-            if kind == "rows":
-                if fsq.functions:
-                    g_seg, applies_seg = combined_doc_rows(
-                        fsq, np.zeros(D, np.float32), seg, ctx)
-                else:
-                    g_seg = np.ones(D, np.float32)
-                    applies_seg = np.zeros(D, bool)
-                g_row = np.ones(doc_pad, np.float32)
-                g_row[:D] = g_seg
-                applies_row = np.zeros(doc_pad, bool)
-                applies_row[:D] = applies_seg
+            t_rows = time.monotonic()
+            rows = _fs_function_rows(fsq, seg, ctx, doc_pad) if kind == "rows" \
+                else _fs_script_rows(sf, used_fields, seg, ctx, doc_pad)
+            LAUNCHES.bump(fs_row_put_bytes=sum(
+                row.nbytes for row in jax.tree_util.tree_leaves(rows)))
+            tracing.note("shard.fs_rows", t_rows)
+            if len(chunks) > 1:
+                rows = _put_operands(*rows)  # once for the segment's launches
+            launched = []
+            for ops in operands:
+                batch, fmask = ops(seg, packed)
                 with compile_tag("function_score"):
-                    scores, docs, tq = score_fs_rows_batch(
-                        packed, batch, k, g_row, applies_row, fsq.max_boost,
-                        fsq.boost, fsq.min_score, fsq.boost_mode,
-                        no_functions=not fsq.functions)
-            else:
-                col_rows = []
-                colmiss = np.zeros(D, bool)
-                for f in used_fields:
-                    col = _column_first_value(seg, f)
-                    colmiss |= np.isnan(col)
-                    row = np.full(doc_pad, np.nan, np.float32)
-                    row[:D] = col.astype(np.float32)
-                    col_rows.append(row)
-                parent_row = np.zeros(doc_pad, bool)
-                parent_row[:D] = seg.parent_mask
-                bad_row = np.zeros(doc_pad, bool)
-                bad_row[:D] = seg.parent_mask & colmiss
-                if sf.filter is not None:
-                    fmask_row = np.zeros(doc_pad, bool)
-                    fmask_row[:D] = segment_mask(seg, sf.filter, ctx)
-                else:
-                    fmask_row = np.zeros(doc_pad, bool)
-                with compile_tag("function_score"):
-                    scores, docs, tq, bad = score_fs_script_batch(
-                        packed, batch, k, script, used_fields, col_rows,
-                        fmask_row, bad_row, parent_row, sf.weight,
-                        fsq.max_boost, fsq.boost, fsq.min_score,
-                        fsq.boost_mode, has_filter=sf.filter is not None)
+                    if kind == "rows":
+                        launched.append(score_fs_rows_batch_async(
+                            packed, batch, k, fmask, *rows, fsq.max_boost,
+                            fsq.boost, fsq.min_score, fsq.boost_mode,
+                            no_functions=not fsq.functions))
+                    else:
+                        launched.append(score_fs_script_batch_async(
+                            packed, batch, k, fmask, script, used_fields,
+                            *rows, sf.weight, fsq.max_boost, fsq.boost,
+                            fsq.min_score, fsq.boost_mode,
+                            has_filter=sf.filter is not None))
+            pulled = _pull(launched)
+            scores, docs, tq = (
+                np.concatenate([out[i][:len(chunk)]
+                                for out, chunk in zip(pulled, chunks)])
+                for i in range(3))
+            if kind == "script":
+                bad = np.concatenate([out[3][:len(chunk)]
+                                      for out, chunk in zip(pulled, chunks)])
                 host_idx.update(int(qi) for qi in np.nonzero(bad)[0])
             totals += tq
             valid = (docs < min(doc_pad, D)) & np.isfinite(scores)
@@ -1531,8 +1574,10 @@ def _group_operands(plans: list[FlatPlan], ctx: ShardContext):
     that match nothing before they are staged; plans with no scoring clause
     (all of `plans` or none) are staged as they are and their ConstBatch and
     mask padded after (scoring.ladder_const_batch), as the filtered family's
-    are. The callers slice the padding off every result."""
-    from ..ops.scoring import ladder_const_batch
+    are; where none of them has a filter the rung's resident mask stands in
+    (scoring.ladder_mask), so a rung launches one program whether it is full
+    or padded. The callers slice the padding off every result."""
+    from ..ops.scoring import ladder_const_batch, ladder_mask
 
     Qp = _group_width(len(plans))
     unscored = plans[0].const is not None
@@ -1545,6 +1590,8 @@ def _group_operands(plans: list[FlatPlan], ctx: ShardContext):
         batch = batch_for(seg, packed)
         fmask = _filter_mask_matrix(filters, seg, packed, ctx, n_rows=Qp)
         if unscored:
+            if fmask is None and Qp > 1:
+                fmask = ladder_mask(len(plans), Qp, packed.doc_pad)
             batch, fmask = ladder_const_batch(batch, fmask, packed.doc_pad,
                                               Qp)
         return batch, fmask
@@ -1635,23 +1682,27 @@ def launch_flat_aggs(plans: list[FlatPlan], ctx: ShardContext, k: int,
     """Dense launches of a group of plans with ONE set of aggregations fused
     into the kernel (all scored or all unscored: aggs_tail's key and
     _flat_groups), one launch a segment and NO pull: returns (device outputs
-    a segment, finish), or None when a column is not f32-exact (→ host
-    collectors for every plan). `finish(pulled)` takes the outputs on the
-    host (one device_get for all such groups of a batch: _run_flat_groups)
-    and returns a result a plan: (TopDocs, per-segment (counts int [F],
-    stats float32 [F, 4], bucket list of (keys, counts, sub_cnt|None,
-    sub_stats|None))) with F = len(fields), stats = (sum, min, max, sumsq)
-    over the plan's matched docs — its own slices of the launch's outputs,
-    which the request thread turns into partials (service._try_device_aggs).
-    bucket_aggs: (Agg, sub_field_order|None) pairs whose (doc, bucket) pairs
-    ride the kernel's scatter (aggregations.bucket_cols_for); metric sub-agg
-    folds scatter along the same pairs. Serving uses this when every
-    aggregation is device-eligible (service.execute_query_phase →
+    a segment, finish), or None when a segment holds more documents than the
+    integer limbs allow (→ host collectors for every plan). `finish(pulled)`
+    takes the outputs on the host (one device_get for all such groups of a
+    batch: _run_flat_groups) and returns a result a plan: (TopDocs,
+    per-segment (counts int [F], stats float32 [F, 4], sums [F], bucket list
+    of (keys, counts, sub_cnt|None, sub_stats|None, sub_sums|None))) with
+    F = len(fields), stats = (sum, min, max, sumsq) over the plan's matched
+    docs and sums the exact integer sum of each whole-number column (a Python
+    integer; None for a fractional column, whose sum is stats' float32 one)
+    — its own slices of the launch's outputs, which the request thread turns
+    into partials (service._try_device_aggs). bucket_aggs: (Agg,
+    sub_field_order|None) pairs whose (doc, bucket) pairs ride the kernel's
+    scatter (aggregations.bucket_cols_for); metric sub-agg folds scatter
+    along the same pairs. Serving uses this when every aggregation is
+    device-eligible (service.execute_query_phase →
     aggregations.device_agg_fields / device_bucket_eligible)."""
     import jax
     import jax.numpy as jnp
 
-    from ..ops.device_index import _pow2_bucket, ensure_agg_rows, packed_for
+    from ..ops.device_index import (_pow2_bucket, ensure_agg_rows,
+                                    limb_totals, packed_for)
     from ..ops.scoring import score_agg_batch_async
     from .aggregations import bucket_cache_key, bucket_cols_for
 
@@ -1667,7 +1718,7 @@ def launch_flat_aggs(plans: list[FlatPlan], ctx: ShardContext, k: int,
         stack = ensure_agg_rows(seg, packed, fields,
                                 breaker=ctx.breaker("fielddata"))
         if stack is None:
-            return None  # column not f32-exact → host collectors
+            return None  # the limbs do not hold a column → host collectors
         pair_args = []
         seg_keys = []
         for agg, sub_order in bucket_aggs:
@@ -1693,25 +1744,34 @@ def launch_flat_aggs(plans: list[FlatPlan], ctx: ShardContext, k: int,
             if sub_order:
                 sub_stack = ensure_agg_rows(seg, packed, sub_order,
                                             breaker=ctx.breaker("fielddata"))
-                if sub_stack is None:
-                    return None  # sub column not f32-exact → host
             pair_args.append((dev[0], dev[1], dev[2], sub_stack))
-            seg_keys.append(keys)
+            seg_keys.append((keys, None if sub_stack is None
+                             else sub_stack.limbed))
         batch, fmask = operands(seg, packed)
         with compile_tag("aggs"):
             launched.append(score_agg_batch_async(
                 packed, batch, k, stack, tuple(pair_args), fmask=fmask))
-        keys_by_seg.append((seg_keys, min(packed.doc_pad, seg.doc_count)))
+        keys_by_seg.append((seg_keys, stack.limbed,
+                            min(packed.doc_pad, seg.doc_count)))
         _prof_dense_segment(prof, seg, packed, batch, "dense_aggs", t_seg,
                             launched[-1])
+
+    def exact(limb_sums, limbed):
+        """One plan's limb totals [F, L, ...] as Python integers, a row a
+        field: None for a field whose sum is its float32 one."""
+        if not any(limbed):
+            return [None] * len(limbed)
+        whole = limb_totals(limb_sums, 1)
+        return [whole[i] if is_limbed else None
+                for i, is_limbed in enumerate(limbed)]
 
     def finish(pulled: list) -> list:
         totals = np.zeros(Q, dtype=np.int64)
         seg_hits = []
         seg_stats: list[list] = [[] for _ in range(Q)]
-        for base, (seg_keys, n_docs), out in zip(
+        for base, (seg_keys, limbed, n_docs), out in zip(
                 ctx.searcher.bases, keys_by_seg, pulled):
-            scores, docs, tq, counts, stats, bcounts = out
+            scores, docs, tq, counts, stats, limb_sums, bcounts = out
             scores, docs = scores[:Q], docs[:Q]
             totals += tq[:Q]
             valid = (docs < n_docs) & np.isfinite(scores)
@@ -1719,12 +1779,14 @@ def launch_flat_aggs(plans: list[FlatPlan], ctx: ShardContext, k: int,
                              np.int64(2**62))
             seg_hits.append((np.where(valid, scores, -np.inf), gdocs))
             for qi in range(Q):
-                seg_stats[qi].append((counts[qi], stats[qi], [
-                    (keys, bc[qi],
-                     None if sc is None else sc[qi],
-                     None if ss is None else ss[qi])
-                    for keys, (bc, sc, ss) in zip(seg_keys, bcounts)
-                ]))
+                seg_stats[qi].append((
+                    counts[qi], stats[qi], exact(limb_sums[qi], limbed), [
+                        (keys, bc[qi],
+                         None if sc is None else sc[qi],
+                         None if ss is None else ss[qi],
+                         None if sl is None else exact(sl[qi], sub_limbed))
+                        for (keys, sub_limbed), (bc, sc, ss, sl)
+                        in zip(seg_keys, bcounts)]))
         return list(zip(_merge_seg_hits(seg_hits, totals, Q, k,
                                         breaker=ctx.breaker("request")),
                         seg_stats))

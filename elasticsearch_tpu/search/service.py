@@ -648,9 +648,10 @@ def _try_device_aggs(ctx: ShardContext, req: ParsedSearchRequest, k: int,
     aggregated searches in flight that share its key and hands this thread
     the search's own slices (counts, stats, bucket counts), which become
     partials here."""
-    from .aggregations import (device_agg_field, device_bucket_eligible,
-                               device_bucket_partial, device_bucket_subs,
-                               device_partial)
+    from ..ops.device_index import agg_device_exact
+    from .aggregations import (device_agg_field, device_agg_needs_values,
+                               device_bucket_eligible, device_bucket_partial,
+                               device_bucket_subs, device_partial)
     from .execute import aggs_tail
 
     metric_fields = {}
@@ -670,6 +671,17 @@ def _try_device_aggs(ctx: ShardContext, req: ParsedSearchRequest, k: int,
             bucket_subs[name] = (subs, sorted(set(subs.values())))
         else:
             return None
+    # a whole-number column the device cannot answer exactly stays with the
+    # host collectors: a min, a max or a stats of values float32 cannot hold,
+    # a sum its integer limbs cannot (device_index.agg_device_exact)
+    valued = [(agg, metric_fields[name]) for name, agg in req.aggs.items()
+              if name in metric_fields] + [
+        (sub, bucket_subs[name][0][sub_name]) for name in bucket_names
+        for sub_name, sub in req.aggs[name].subs.items()]
+    if not all(agg_device_exact(ctx.searcher.segments, field,
+                                device_agg_needs_values(agg))
+               for agg, field in valued):
+        return None
     plan = lower_flat(req.query, ctx)
     if plan is None or plan.fs is not None:
         return None
@@ -684,27 +696,29 @@ def _try_device_aggs(ctx: ShardContext, req: ParsedSearchRequest, k: int,
     res = _execute_flat_single(ctx, plan, max(k, 1), deadline,
                                aggs_tail(fields, bucket_aggs))
     if res is None:
-        return None  # a column wasn't f32-exact — host path
+        return None  # the launch refused a column — host path
     td, seg_stats = res
     bpos = {n: i for i, n in enumerate(bucket_names)}
 
     def bucket_partial(name, agg, buckets, seg):
-        keys, bcounts, sub_cnt, sub_stats = buckets[bpos[name]]
+        keys, bcounts, sub_cnt, sub_stats, sub_sums = buckets[bpos[name]]
         sub_data = None
         field_of, order = bucket_subs[name]
         if field_of:
-            sub_data = (agg.subs, field_of, order, sub_cnt, sub_stats)
+            sub_data = (agg.subs, field_of, order, sub_cnt, sub_stats,
+                        sub_sums)
         return device_bucket_partial(agg, keys, bcounts, seg=seg,
                                      sub_data=sub_data)
 
     agg_partials = [
         {name: (device_partial(agg, counts[fpos[metric_fields[name]]],
-                               stats[fpos[metric_fields[name]]])
+                               stats[fpos[metric_fields[name]]],
+                               sums[fpos[metric_fields[name]]])
                 if name in metric_fields
                 else bucket_partial(name, agg, buckets, seg))
          for name, agg in req.aggs.items()}
-        for (counts, stats, buckets), seg in zip(seg_stats,
-                                                 ctx.searcher.segments)
+        for (counts, stats, sums, buckets), seg in zip(seg_stats,
+                                                       ctx.searcher.segments)
     ]
     return ShardQueryResult(
         total=td.total, docs=[(s, d, None) for s, d in td.hits[:max(k, 0)]],

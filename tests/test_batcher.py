@@ -413,12 +413,15 @@ class TestPendingMergeFlush:
             execute_flat_batch(plans[:2], shard_ctx, kb)
             execute_flat_batch(plans[2:], shard_ctx, kb)
             ok = False
-            for _attempt in range(3):
+            # (five attempts under 1.0 s since PR 35: on a loaded machine, six
+            # workers beside it, three of 0.8 s were once not enough; the old
+            # behaviour still needs 1.2 s in every attempt)
+            for _attempt in range(5):
                 t0 = time.monotonic()
                 out = run_concurrent(b, shard_ctx, texts)
                 elapsed = time.monotonic() - t0
                 assert all(td is not None for td in out)
-                if elapsed < 0.8 and b.stats()["pending_flushes"] >= 1:
+                if elapsed < 1.0 and b.stats()["pending_flushes"] >= 1:
                     ok = True
                     break
             assert ok, (elapsed, b.stats())

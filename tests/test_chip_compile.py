@@ -132,7 +132,8 @@ def test_unscored_launches_compile_for_v5e(one_chip, tail):
 
     D = DOC_PAD_LOGS
     head = (((D,), "bool"), ((1,), "float32"))  # live_parent, score [Q]
-    mask, no_aggs = ((1, D), "bool"), ((0, 5, D), "float32")
+    mask = ((1, D), "bool")
+    no_aggs = (((0, 5, D), "float32"), ((0, 0, D), "int32"))  # folds, limbs
     if tail.startswith("sorted"):
         fn = _get_sorted_compiled(1, 10, D, tail == "sorted_desc",
                                   _unscored_abi, "unscored")
@@ -140,13 +141,13 @@ def test_unscored_launches_compile_for_v5e(one_chip, tail):
         family = "sorted"
     elif tail == "hits":
         fn = _get_agg_compiled(1, 10, D, 0, True, _unscored_abi, "unscored")
-        args = _shapes(one_chip, *head, no_aggs) + [()] + _shapes(one_chip, mask)
+        args = _shapes(one_chip, *head, *no_aggs) + [()] + _shapes(one_chip, mask)
         family = "filtered"
     else:  # size 0 and one date_histogram: a (doc, hour) pair a document
         fn = _get_agg_compiled(1, 1, D, 1, False, _unscored_abi, "unscored")
         pairs = _shapes(one_chip, ((D,), "int32"), ((D,), "int32"),
                         ((256,), "int32"))
-        args = _shapes(one_chip, *head, no_aggs) + [((*pairs, None),)] \
+        args = _shapes(one_chip, *head, *no_aggs) + [((*pairs, None),)] \
             + _shapes(one_chip, mask)
         family = "aggs"
     with compile_tag(family):
@@ -155,6 +156,64 @@ def test_unscored_launches_compile_for_v5e(one_chip, tail):
     # the operands are the mask and key rows, megabytes; no [ROWS, 128] plane
     assert mem.argument_size_in_bytes < 64 << 20
     assert mem.temp_size_in_bytes < 256 << 20
+
+
+DOC_PAD_GEO = 1 << 17  # 80,000 places in one force-merged segment
+
+
+@pytest.mark.parametrize("Q", [1, 4])
+@pytest.mark.parametrize("tail", ["fs_rows", "fs_script", "country_facet"])
+def test_function_score_and_exact_sum_launches_compile_for_v5e(one_chip, tail, Q):
+    """`geonames.scoring`'s launches at its doc_pad and both rungs of the group's
+    ladder: function_score over match_all behind the unscored ABI (one function
+    row, or three column rows and the script traced in), and the keyword facet
+    whose sums are integer limbs scattered and added as int32."""
+    from elasticsearch_tpu.common.jaxenv import compile_tag
+    from elasticsearch_tpu.ops.scoring import (
+        _get_agg_compiled, _get_fs_compiled, _unscored_abi)
+    from elasticsearch_tpu.script import compile_script, script_vector_info
+
+    D = DOC_PAD_GEO
+    head = (((D,), "bool"), ((Q,), "float32"))  # live_parent, score [Q]
+    mask = ((1, 1), "bool") if Q == 1 else ((Q, D), "bool")
+    row, gate, scalar = ((D,), "float32"), ((D,), "bool"), ((), "float32")
+    if tail == "fs_rows":
+        fn = _get_fs_compiled("rows", Q, 10, D, _unscored_abi, "unscored",
+                              bmode="multiply", use_min_score=False,
+                              no_functions=False)
+        args = _shapes(one_chip, *head, mask, row, gate, scalar, scalar, scalar)
+        family = "function_score"
+    elif tail == "fs_script":
+        script = compile_script(
+            "abs(log(abs(doc['population'].value) + 1) + doc['location.lon'].value"
+            " + doc['location.lat'].value) * _score", {})
+        used = script_vector_info(script)[1]
+        assert len(used) == 3
+        fn = _get_fs_compiled("script", Q, 10, D, _unscored_abi, "unscored",
+                              script=script, used_fields=used, bmode="multiply",
+                              use_min_score=False, has_filter=False,
+                              has_weight=False)
+        args = _shapes(one_chip, *head, mask) + [tuple(_shapes(one_chip, row, row, row))] \
+            + _shapes(one_chip, gate, gate, gate, scalar, scalar, scalar, scalar)
+        family = "function_score"
+    else:  # size 0, terms on a keyword, a sum of a long under it
+        fn = _get_agg_compiled(Q, 1, D, 1, False, _unscored_abi, "unscored")
+        pairs = _shapes(one_chip, ((80_000,), "int32"), ((80_000,), "int32"),
+                        ((256,), "int32"))
+        sub = tuple(_shapes(one_chip, ((1, 5, D), "float32"), ((1, 3, D), "int32")))
+        args = _shapes(one_chip, *head, ((0, 5, D), "float32"), ((0, 0, D), "int32")) \
+            + [((*pairs, sub),)] + _shapes(one_chip, mask)
+        family = "aggs"
+    with compile_tag(family):
+        compiled = fn.lower(*args).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes < 64 << 20  # rows and masks: no postings plane
+    # the facet's scatters hold their [Q, Fs, pairs] operands tiled: 0.25 GB at Q = 4
+    assert mem.temp_size_in_bytes < (512 << 20 if tail == "country_facet" else 256 << 20)
+    name = fn.lower(*args).as_text().split("@", 1)[1].split(" ", 1)[0]
+    assert name == {"fs_rows": "jit_estpu_scoring_fs_rows_unscored",
+                    "fs_script": "jit_estpu_scoring_fs_script_unscored",
+                    "country_facet": "jit_estpu_scoring_aggs_unscored"}[tail]
 
 
 @pytest.mark.parametrize("descending", [True, False], ids=["desc", "asc"])

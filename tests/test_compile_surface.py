@@ -282,7 +282,8 @@ def test_every_launch_site_lowers_under_its_own_name():
               s((f,), jnp.int32),
               # SparseBatch.slots / .qplane
               s((5, 8, 8), jnp.int32), s((8, 2 + 2), jnp.int32))
-    no_aggs = (s((0, 5, dpad), jnp.float32), (), s((q, dpad), jnp.bool_))
+    no_aggs = (s((0, 5, dpad), jnp.float32), s((0, 0, dpad), jnp.int32), (),
+               s((q, dpad), jnp.bool_))
     term = s((4,), jnp.int32)
     plane = tuple(s((4, 128), d) for d in (jnp.int32, jnp.uint8, jnp.uint8))
     sites = [
@@ -290,8 +291,8 @@ def test_every_launch_site_lowers_under_its_own_name():
         (scoring._get_compiled(q, 10, dpad, False), dense),
         (scoring._get_fs_compiled("rows", q, 10, dpad, bmode="multiply",
                                   use_min_score=False, no_functions=False),
-         dense + (s((dpad,), jnp.float32), s((dpad,), jnp.bool_),
-                  scalar, scalar, scalar)),
+         dense + (s((1, 1), jnp.bool_), s((dpad,), jnp.float32),
+                  s((dpad,), jnp.bool_), scalar, scalar, scalar)),
         (scoring._get_sorted_compiled(q, 10, dpad, False),
          dense + (s((1, 1), jnp.bool_), s((dpad,), jnp.float32))),
         (scoring._get_agg_compiled(q, 10, dpad, 0), dense + no_aggs),

@@ -669,3 +669,202 @@ def test_sort_with_aggs_makes_two_submissions(ctx):
     for b, res in zip(bodies, got):
         req = parse_search_body(b)
         _assert_same_answer(req, res, execute_query_phase(ctx, req))
+
+
+# -- exact integer sums (ISSUE 35) -------------------------------------------
+#
+# A column of whole numbers adds up in integer limbs on the device
+# (device_index.agg_int_limbs): its sum, average and count are exact whatever
+# its magnitude, at the top level and under a bucket; nothing adds whole
+# numbers up in float32.
+
+LONGS = {
+    # odd values over 2^24: float32 holds none of them; a label's sum passes 2^31
+    "odd_over_2p24": lambda rng, i: int((1 << 24) + 1 + 2 * rng.integers(0, 1 << 25)),
+    # epoch milliseconds: the wide rung of limbs
+    "epoch_ms": lambda rng, i: int(1_700_000_000_123 + rng.integers(0, 10**9)),
+    # float32-exact values whose sums pass 2^24: the float32 rows rounded these
+    "small_values_big_sums": lambda rng, i: int(rng.integers(60_000, 65_536)) * 256,
+    "negative_and_multi": lambda rng, i: [
+        int(x) for x in rng.integers(-(1 << 40), 1 << 40, size=1 + i % 3)],
+}
+
+
+@pytest.fixture(scope="module", params=sorted(LONGS))
+def longs(request):
+    svc = MapperService(Settings.from_flat({}))
+    svc.put_mapping("doc", {"doc": {"properties": {
+        "body": {"type": "string"}, "n": {"type": "long"},
+        "label": {"type": "string", "index": "not_analyzed"},
+        "price": {"type": "double"}}}})
+    eng = Engine(tempfile.mkdtemp(), svc)
+    rng = np.random.default_rng(35)
+    docs = {}
+    for i in range(300):
+        d = {"body": "alpha beta" if i % 3 else "alpha", "label": "l%d" % (i % 4),
+             "price": float(np.round(rng.uniform(1, 99), 2))}
+        if i % 11:
+            d["n"] = LONGS[request.param](rng, i)
+        docs[str(i)] = d
+        eng.index("doc", str(i), d)
+        if i == 170:
+            eng.refresh()  # two segments
+    for i in ("7", "200"):
+        eng.delete("doc", i)
+        del docs[i]
+    eng.refresh()
+    c = ShardContext(eng.acquire_searcher(), svc,
+                     SimilarityService(Settings.from_flat({}), mapper_service=svc))
+    assert len(c.searcher.segments) == 2
+    yield request.param, c, docs
+    eng.close()
+
+
+def _values(doc) -> list:
+    v = doc.get("n", [])
+    return v if isinstance(v, list) else [v]
+
+
+@pytest.mark.parametrize("query", ["match_all", "match"])
+def test_integer_sums_are_exact_at_the_top_and_under_terms(longs, query):
+    from elasticsearch_tpu.ops.scoring import LAUNCHES
+    from elasticsearch_tpu.search.service import SERVING_COUNTERS
+
+    _kind, c, docs = longs
+    q = {"match_all": {}} if query == "match_all" else {"match": {"body": "beta"}}
+    hit = [d for d in docs.values() if query == "match_all" or "beta" in d["body"]]
+    req = parse_search_body({"query": q, "size": 0, "aggs": {
+        "total": {"sum": {"field": "n"}}, "mean": {"avg": {"field": "n"}},
+        "values": {"value_count": {"field": "n"}},
+        "by_label": {"terms": {"field": "label"}, "aggs": {
+            "s": {"sum": {"field": "n"}}, "a": {"avg": {"field": "n"}},
+            "p": {"sum": {"field": "price"}}}}}})
+    before = {**SERVING_COUNTERS, **LAUNCHES.snapshot()}
+    res = execute_query_phase(c, req, use_device=True)
+    after = {**SERVING_COUNTERS, **LAUNCHES.snapshot()}
+    assert after["device_aggs"] == before["device_aggs"] + 1
+    assert after["host"] == before["host"]
+    # one limbed field at the top and one under the bucket, a launch a segment
+    assert after["exact_sum_rows"] - before["exact_sum_rows"] in (2 * 2 * 3, 2 * 2 * 6)
+    got = reduce_aggs(req.aggs, res.agg_partials)
+    want = sum(v for d in hit for v in _values(d))  # a Python integer
+    n_values = sum(len(_values(d)) for d in hit)
+    assert abs(want) > 1 << 31
+    assert got["total"]["value"] == float(want) and int(got["total"]["value"]) == want
+    assert got["values"]["value"] == n_values
+    assert got["mean"]["value"] == float(want) / n_values
+    assert len(got["by_label"]["buckets"]) == 4
+    for b in got["by_label"]["buckets"]:
+        mine = [d for d in hit if d["label"] == b["key"]]
+        s = sum(v for d in mine for v in _values(d))
+        assert b["doc_count"] == len(mine)
+        assert int(b["s"]["value"]) == s and b["s"]["value"] == float(s)
+        assert b["a"]["value"] == float(s) / sum(len(_values(d)) for d in mine)
+        # the fractional column keeps its float32 rows
+        assert b["p"]["value"] == pytest.approx(sum(d["price"] for d in mine), rel=1e-5)
+    host = execute_query_phase(c, req, use_device=False)
+    _agg_equal(got, reduce_aggs(req.aggs, host.agg_partials))
+
+
+def test_a_value_of_a_column_float32_cannot_hold_stays_with_the_host(longs):
+    from elasticsearch_tpu.ops.device_index import (agg_device_exact,
+                                                    ensure_agg_rows, packed_for)
+
+    kind, c, docs = longs
+    exact = kind == "small_values_big_sums"
+    segs = c.searcher.segments
+    assert agg_device_exact(segs, "n", needs_values=True) is exact
+    # a sum asks nothing of float32; a float32 accumulator (the mesh program's)
+    # would round every one of these columns, and a fractional one has no
+    # exact answer to lose
+    assert agg_device_exact(segs, "n", needs_values=False)
+    assert not agg_device_exact(segs, "n", needs_values=True, f32_sums=True)
+    assert agg_device_exact(segs, "price", needs_values=True, f32_sums=True)
+    for name in ("min", "max", "stats"):
+        req = parse_search_body({"query": {"match": {"body": "alpha"}}, "aggs": {
+            "by_label": {"terms": {"field": "label"},
+                         "aggs": {"m": {name: {"field": "n"}}}}}})
+        assert (_try_device_aggs(c, req, 3, None, 0) is not None) is exact
+        res = execute_query_phase(c, req, use_device=True)
+        host = execute_query_phase(c, req, use_device=False)
+        _agg_equal(reduce_aggs(req.aggs, res.agg_partials),
+                   reduce_aggs(req.aggs, host.agg_partials))
+    # the limbs: a whole-number column has them, a fractional one its float32 sum
+    seg = c.searcher.segments[0]
+    stack = ensure_agg_rows(seg, packed_for(seg), ["n", "price"])
+    assert stack.limbed == (True, False)
+    assert stack.limbs.shape[:2] == (2, 3 if kind in (
+        "odd_over_2p24", "small_values_big_sums") else 6)
+    rows = np.asarray(stack.rows)
+    assert not rows[0, 1].any() and not rows[0, 4].any()  # no float32 integer sum
+    assert rows[1, 1].any()
+
+
+def test_limbs_put_any_int64_together_again():
+    from elasticsearch_tpu.ops.device_index import LIMB_BITS, limb_totals
+
+    rng = np.random.default_rng(3)
+    sums = np.concatenate([rng.integers(-(1 << 62), 1 << 62, 500),
+                           [0, -1, 1, (1 << 62) - 1, -(1 << 62)]]).astype(np.int64)
+    for n in (6,):
+        limbs = np.stack([(sums >> (LIMB_BITS * i)) & ((1 << LIMB_BITS) - 1)
+                          for i in range(n - 1)] + [sums >> (LIMB_BITS * (n - 1))])
+        assert limb_totals(limbs, 0).tolist() == sums.tolist()
+        # and their int32 totals over many documents, as the program adds them
+        assert int(limb_totals(limbs.sum(axis=1, keepdims=True), 0)[0]) == \
+            sum(sums.tolist())
+
+
+def _shard_of_longs(values: list):
+    """One segment, a document a value of `values` (an int, or a list of them)."""
+    svc = MapperService(Settings.from_flat({}))
+    svc.put_mapping("doc", {"doc": {"properties": {
+        "n": {"type": "long"},
+        "label": {"type": "string", "index": "not_analyzed"}}}})
+    eng = Engine(tempfile.mkdtemp(), svc)
+    for i, v in enumerate(values):
+        eng.index("doc", str(i), {"n": v, "label": "l%d" % (i % 2)})
+    eng.refresh()
+    return eng, ShardContext(
+        eng.acquire_searcher(), svc,
+        SimilarityService(Settings.from_flat({}), mapper_service=svc))
+
+
+@pytest.mark.parametrize("values, rides", [
+    ([(1 << 62) - (1 << 10)] * 32, True),   # the largest the limbs hold
+    ([1 << 62] * 40, False),                # one value past them
+    ([[1 << 61, 1 << 61]] * 40, False),     # a document's own sum past them
+    ([-(1 << 62)] * 39 + [5], False),
+], ids=["under_2p62", "at_2p62", "a_document_sum_at_2p62", "minus_2p62"])
+def test_a_sum_the_limbs_cannot_hold_stays_with_the_host(values, rides):
+    """No whole-number sum falls back to the float32 row: where a document's
+    sum could pass an int64 the column is refused, at the top level and under
+    a bucket, and the host collectors answer (ISSUE 35's review)."""
+    from elasticsearch_tpu.ops.device_index import (agg_device_exact,
+                                                    ensure_agg_rows, packed_for)
+    from elasticsearch_tpu.search.service import SERVING_COUNTERS
+
+    eng, c = _shard_of_longs(values)
+    try:
+        seg = c.searcher.segments[0]
+        assert agg_device_exact([seg], "n", needs_values=False) is rides
+        assert (ensure_agg_rows(seg, packed_for(seg), ["n"]) is not None) is rides
+        req = parse_search_body({"size": 0, "aggs": {
+            "total": {"sum": {"field": "n"}},
+            "by_label": {"terms": {"field": "label"},
+                         "aggs": {"s": {"sum": {"field": "n"}}}}}})
+        assert (_try_device_aggs(c, req, 1, None, 0) is not None) is rides
+        before = dict(SERVING_COUNTERS)
+        res = execute_query_phase(c, req, use_device=True)
+        assert SERVING_COUNTERS["host"] - before["host"] == (0 if rides else 1)
+        assert SERVING_COUNTERS["device_aggs"] - before["device_aggs"] == \
+            (1 if rides else 0)
+        got = reduce_aggs(req.aggs, res.agg_partials)
+        flat = [v for d in values for v in (d if isinstance(d, list) else [d])]
+        assert got["total"]["value"] == float(sum(flat))
+        if rides:  # the Python integer itself, past an int64
+            assert int(got["total"]["value"]) == sum(flat) > 1 << 63
+        host = execute_query_phase(c, req, use_device=False)
+        _agg_equal(got, reduce_aggs(req.aggs, host.agg_partials))
+    finally:
+        eng.close()

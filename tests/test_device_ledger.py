@@ -185,7 +185,7 @@ class TestLiveLedger:
             for row in shard_rows:
                 assert set(row["tiers"]) == {
                     "postings", "dense_plane", "sim_tables", "agg_rows",
-                    "sort_keys", "norms", "filter_masks"}
+                    "agg_limbs", "sort_keys", "norms", "filter_masks"}
 
             # /_nodes/stats device section (+ compile family rollup)
             st = c.nodes_stats(metric="device")

@@ -256,6 +256,53 @@ class TestMeshServingRound5:
         for bm, bt in zip(m, t):
             assert abs(bm["navg"]["value"] - bt["navg"]["value"]) < 1e-4
 
+    @pytest.mark.parametrize("index, value", [
+        # odd values over 2^24: float32 holds none of them
+        ("gazetteer", lambda i: (1 << 24) + 1 + 2 * i),
+        # float32 holds each value, not the sum a shard forms of them
+        ("ledger", lambda i: (1 << 22) + 256 * i),
+    ], ids=["values_past_float32", "sums_past_float32"])
+    def test_a_whole_number_sum_float32_would_round_declines_the_mesh(
+            self, node, index, value):
+        """The mesh program reduces in float32 and has no integer limbs
+        (ROADMAP S13): where float32 cannot add a whole-number column up
+        exactly it declines (device_index.agg_device_exact), and the transport
+        path, whose one-shard program adds limbs, gives the exact sum at the
+        top level and under a bucket."""
+        n, client = node
+        client.create_index(index, {"settings": {
+            "number_of_shards": N_SHARDS, "number_of_replicas": 0},
+            "mappings": {"doc": {"properties": {
+                "pop": {"type": "long"}, "small": {"type": "long"},
+                "body": {"type": "string"},
+                "cc": {"type": "string", "index": "not_analyzed"}}}}})
+        client.cluster_health(wait_for_status="green")
+        pops = [value(i) for i in range(48)]
+        for i, pop in enumerate(pops):
+            client.index(index, "doc", {"pop": pop, "small": i, "body": "alpha",
+                                        "cc": "c%d" % (i % 3)}, id=str(i))
+        client.refresh(index)
+        ms = n.actions.mesh_serving
+        query = {"match": {"body": "alpha"}}
+        body = {"query": query, "size": 0, "aggs": {
+            "total": {"sum": {"field": "pop"}},
+            "by_cc": {"terms": {"field": "cc"},
+                      "aggs": {"s": {"sum": {"field": "pop"}}}}}}
+        before = ms.mesh_queries
+        r = client.search(index, body)
+        assert ms.mesh_queries == before  # declined: float32 would round it
+        assert sum(pops) > 1 << 24
+        assert r["aggregations"]["total"]["value"] == float(sum(pops))
+        assert int(r["aggregations"]["total"]["value"]) == sum(pops)
+        assert {b["key"]: int(b["s"]["value"])
+                for b in r["aggregations"]["by_cc"]["buckets"]} == {
+            "c%d" % c: sum(pops[c::3]) for c in range(3)}
+        # a column float32 adds up exactly still rides the mesh on this index
+        r = client.search(index, {"query": query, "size": 0, "aggs": {
+            "t": {"sum": {"field": "small"}}}})
+        assert ms.mesh_queries == before + 1
+        assert r["aggregations"]["t"]["value"] == float(sum(range(48)))
+
     def test_range_agg_rides_mesh(self, node):
         # positional buckets: every range emits (zero-count included)
         n, client = node

@@ -146,7 +146,10 @@ class TestFallbackReasons:
         assert self._reason(
             shard_ctx, {"function_score": {
                 "query": {"range": {"n": {"gte": 3}}},
-                "functions": [{"weight": 2.0}]}}) == "unscored_subquery"
+                "functions": [
+                    {"script_score": {"script": "_score * 2"}},
+                    {"script_score": {"script": "_score + 1"}}]}}) \
+            == "function_score_ineligible"
         assert self._reason(
             shard_ctx, {"function_score": {
                 "query": {"match_phrase": {"body": "quick brown"}},
@@ -159,7 +162,10 @@ class TestFallbackReasons:
         {"match_all": {}},
         {"filtered": {"query": {"match_all": {}},
                       "filter": {"range": {"n": {"gte": 3}}}}},
-    ], ids=["numeric_term", "must_not_only", "match_all", "filtered_match_all"])
+        {"function_score": {"query": {"range": {"n": {"gte": 3}}},
+                            "functions": [{"weight": 2.0}]}},
+    ], ids=["numeric_term", "must_not_only", "match_all", "filtered_match_all",
+            "function_score_over_range"])
     def test_unscored_plans_lower(self, shard_ctx, qdict):
         """What used to fall back as `numeric_term` / `must_not_only` lowers to
         a plan with no scoring clause, and the plan's profile says so."""
@@ -171,6 +177,8 @@ class TestFallbackReasons:
         assert plan is not None and plan.const is not None
         shape = plan_profile(plan, q)
         assert shape["unscored"] is True and shape["clauses"] == []
+        assert shape["function_score"] == (
+            "rows" if "function_score" in qdict else None)
 
 
 # ---------------------------------------------------------------------------

@@ -732,16 +732,7 @@ class TestLaunchTimeline:
         no dispatch.stage hangs under its shard span, and /_nodes/stats books
         the launch under its kind."""
         _cluster, node, rc = live
-        client = node.client()
-        if not client.exists_index("numbered"):
-            client.create_index("numbered", {"settings": {
-                "number_of_shards": 1, "number_of_replicas": 0}})
-            _cluster.ensure_green("numbered")
-            for i in range(40):
-                client.index("numbered", "doc", {
-                    "body": f"{WORDS[i % 8]} {WORDS[(i + 1) % 8]}", "n": i},
-                    id=str(i))
-            client.refresh("numbered")
+        self._numbered(_cluster, node)
 
         def search():
             resp = rc.dispatch(RestRequest(
@@ -808,6 +799,104 @@ class TestLaunchTimeline:
         assert after["device_filtered"] == before["device_filtered"] + 1
         assert after["host"] == before["host"]
         assert after["request_cache_hits"] == before["request_cache_hits"]
+
+    @pytest.mark.parametrize("function", [
+        {"field_value_factor": {"field": "n", "factor": 2, "modifier": "log1p"}},
+        {"script_score": {"script": "log(doc['n'].value + 2) * _score"}}],
+        ids=["rows", "script"])
+    def test_a_function_score_over_match_all_records_its_rows_and_its_counters(
+            self, live, function):
+        """function_score over a plan with no scoring clause: the host's
+        evaluation of the launch's function rows (or script columns) is a
+        part of the stage span, and /_nodes/stats books the rows' bytes, the
+        unscored function_score launch and the batcher's kind."""
+        _cluster, node, rc = live
+        self._numbered(_cluster, node)
+
+        def stats(section):
+            resp = rc.dispatch(RestRequest(
+                method="GET", path=f"/_nodes/stats/{section}"))
+            return next(iter(resp.body["nodes"].values()))[section]
+
+        def search():
+            resp = rc.dispatch(RestRequest(
+                method="POST", path="/numbered/_search", params={"trace": "true"},
+                body={"query": {"function_score": {
+                    "query": {"match_all": {}}, "functions": [function]}},
+                    "size": 5}))
+            assert resp.status == 200, resp.body
+            return resp.body["trace"]["tree"]
+
+        search()  # first sighting compiles
+        before, kinds0 = stats("search_serving"), stats("search")["batcher"]["kinds"]
+        tree = search()
+        after, kinds1 = stats("search_serving"), stats("search")["batcher"]["kinds"]
+        _assert_nested(tree)
+        stages = [n for n in _find(tree, "dispatch.stage")
+                  if any(c["name"] == "shard.fs_rows" for c in n["children"])]
+        assert stages  # one a segment
+        for stage in stages:
+            (rows,) = [c for c in stage["children"] if c["name"] == "shard.fs_rows"]
+            assert stage["t0"] <= rows["t0"] and rows["t1"] <= stage["t1"] + 1e-6
+        launch0, launch1 = before["launch"], after["launch"]
+        assert launch1["fs_row_put_bytes"] > launch0["fs_row_put_bytes"]
+        assert launch1["launches_fs_unscored"] == \
+            launch0["launches_fs_unscored"] + len(stages)
+        assert launch1["launches_unscored"] == \
+            launch0["launches_unscored"] + len(stages)
+        assert launch1["unscored_bytes"] > launch0["unscored_bytes"]
+        assert launch1["unscored_plans"] == launch0["unscored_plans"] + 1
+        assert after["device_function_score"] == before["device_function_score"] + 1
+        assert after["host"] == before["host"]
+        for name in ("launches", "coalesced"):
+            assert kinds1["function_score"][name] == \
+                kinds0["function_score"][name] + 1
+
+    def test_an_exact_sum_counts_its_limb_rows_and_their_bytes(self, live):
+        """A sum of a long column under a terms bucket: the launch counts the
+        integer limb rows it reduced, and the device ledger holds their bytes
+        in a tier of their own beside the float32 folds."""
+        _cluster, node, rc = live
+        self._numbered(_cluster, node)
+
+        def stats(section):
+            resp = rc.dispatch(RestRequest(
+                method="GET", path=f"/_nodes/stats/{section}"))
+            return next(iter(resp.body["nodes"].values()))[section]
+
+        before = stats("search_serving")
+        resp = rc.dispatch(RestRequest(
+            method="POST", path="/numbered/_search",
+            body={"query": {"match_all": {}}, "size": 0, "aggs": {
+                "by_word": {"terms": {"field": "body"},
+                            "aggs": {"s": {"sum": {"field": "n"}}}},
+                "total": {"sum": {"field": "n"}}}}))
+        assert resp.status == 200, resp.body
+        assert resp.body["aggregations"]["total"]["value"] == sum(range(40))
+        after = stats("search_serving")
+        assert after["device_aggs"] == before["device_aggs"] + 1
+        # a segment: two stacks of one limbed field, three limbs each
+        rose = after["launch"]["exact_sum_rows"] - before["launch"]["exact_sum_rows"]
+        assert rose and rose % 6 == 0
+        totals = stats("device")["indices"]["numbered"]["totals"]
+        assert totals["agg_limbs"] > 0 and totals["agg_rows"] > 0
+
+    @staticmethod
+    def _numbered(cluster, node):
+        """40 numbered documents in ONE segment: the index refreshes when told
+        (a refresh of the clock's own, on a slow machine, split them in two,
+        and a search of two segments stages and launches twice)."""
+        client = node.client()
+        if not client.exists_index("numbered"):
+            client.create_index("numbered", {"settings": {
+                "number_of_shards": 1, "number_of_replicas": 0,
+                "index.refresh_interval": "-1"}})
+            cluster.ensure_green("numbered")
+            for i in range(40):
+                client.index("numbered", "doc", {
+                    "body": f"{WORDS[i % 8]} {WORDS[(i + 1) % 8]}", "n": i},
+                    id=str(i))
+            client.refresh("numbered")
 
     def test_unsampled_search_allocates_no_span(self, live, monkeypatch):
         _cluster, node, rc = live

@@ -157,12 +157,98 @@ def test_a_scored_plan_counts_no_unscored_launch(ctx):
     assert delta["unscored_plans"] == 0 and delta["launches_unscored"] == 0
 
 
-def test_function_score_over_an_unscored_query_stays_on_the_host(ctx):
-    req = parse_search_body({"query": {"function_score": {
-        "query": {"match_all": {}}, "functions": [{"boost_factor": 2.0}]}}})
+FS_SUBS = {
+    "match_all": {"match_all": {}},
+    "constant_score": {"constant_score": {"filter": WINDOW, "boost": 1.7}},
+    "range": {"range": {"status": {"gte": 200, "lt": 404, "boost": 1.5}}},
+}
+# kind "rows": no function reads _score; kind "script": one script that does
+FS_FUNCTIONS = {
+    "rows": [{"field_value_factor": {"field": "status", "factor": 0.5,
+                                     "modifier": "log1p"}},
+             {"gauss": {"frac": {"origin": 40, "scale": 15, "decay": 0.3}},
+              "weight": 1.25}],
+    "script": [{"script_score": {
+        "script": "abs(log(doc['status'].value + 1) - doc['frac'].value) * _score"}}],
+}
+
+
+MAX_BOOST = {"rows": 2.6, "script": 40.0}
+MIN_SCORE = {"rows": 2.3, "script": 10.0}
+
+
+def _fs_both(ctx, body, script: bool):
+    """Device against host for a function_score: totals and ids in order; the
+    scores bit for bit for host-combined rows, to 1e-6 for a script the
+    device evaluates in float32. Counted as device_function_score, the host
+    scorer not at all."""
+    req = parse_search_body(body)
     before = _counters()
-    execute_query_phase(ctx, req, use_device=True)
-    assert _counters()["host"] == before["host"] + 1
+    dev = execute_query_phase(ctx, req, use_device=True)
+    after = _counters()
+    host = execute_query_phase(ctx, req, use_device=False)
+    assert after["host"] == before["host"], "the host scorer answered"
+    assert after["device_function_score"] == before["device_function_score"] + 1
+    assert after["device_errors"] == before["device_errors"]
+    assert dev.total == host.total
+    if script:
+        np.testing.assert_allclose([s for s, _g, _v in dev.docs],
+                                   [s for s, _g, _v in host.docs], rtol=1e-6)
+    else:
+        assert [(np.float32(s).tobytes(), g) for s, g, _v in dev.docs] == \
+            [(np.float32(s).tobytes(), g) for s, g, _v in host.docs]
+    return dev, {k: after[k] - before[k] for k in after}
+
+
+@pytest.mark.parametrize("boost_mode",
+                         ["multiply", "replace", "sum", "avg", "max", "min"])
+@pytest.mark.parametrize("kind", sorted(FS_FUNCTIONS))
+@pytest.mark.parametrize("sub", sorted(FS_SUBS))
+def test_function_score_over_an_unscored_query(ctx, sub, kind, boost_mode):
+    """function_score over a query with no scoring clause launches the fused
+    function tails behind the unscored ABI: `_score` is the sub query's
+    constant, the match set its mask."""
+    dev, delta = _fs_both(ctx, {"query": {"function_score": {
+        "query": FS_SUBS[sub], "functions": FS_FUNCTIONS[kind],
+        "score_mode": "sum", "boost_mode": boost_mode, "boost": 1.3}},
+        "size": 25}, script=kind == "script")
+    assert dev.total > 0
+    assert delta["unscored_plans"] == 1
+    assert delta["launches_fs_unscored"] == len(ctx.searcher.segments)
+    assert delta["launches_unscored"] == len(ctx.searcher.segments)
+    assert delta["blocks_launched"] == 0  # no postings block was read
+    assert delta["fs_row_put_bytes"] > 0
+
+
+@pytest.mark.parametrize("kind", sorted(FS_FUNCTIONS))
+def test_function_score_over_an_unscored_query_min_score_and_max_boost(ctx, kind):
+    body = {"query": {"function_score": {
+        "query": {"match_all": {}}, "functions": FS_FUNCTIONS[kind],
+        "score_mode": "sum", "max_boost": MAX_BOOST[kind],
+        "min_score": MIN_SCORE[kind]}}, "size": 25}
+    dev, _delta = _fs_both(ctx, body, script=kind == "script")
+    everything = execute_query_phase(ctx, parse_search_body(
+        {"query": {"match_all": {}}}), use_device=False).total
+    assert 0 < dev.total < everything  # min_score gates the total
+    scores = [s for s, _g, _v in dev.docs]
+    assert all(MIN_SCORE[kind] <= s <= MAX_BOOST[kind] + 1e-6 for s in scores)
+    assert scores[0] == pytest.approx(MAX_BOOST[kind])  # max_boost caps
+
+
+def test_function_score_groups_keep_scored_and_unscored_apart(ctx):
+    from elasticsearch_tpu.search.execute import _flat_groups, lower_flat
+    from elasticsearch_tpu.search.queries import parse_query
+
+    def fs(sub):
+        return lower_flat(parse_query({"function_score": {
+            "query": sub, "functions": [{"boost_factor": 2.0}]}}), ctx)
+
+    plans = [fs({"match_all": {}}), fs({"match": {"body": "alpha"}}),
+             fs({"range": {"status": {"gte": 300}}})]
+    assert [p.const is not None for p in plans] == [True, False, True]
+    groups = _flat_groups(plans)
+    assert sorted(groups.values()) == [[0, 2], [1]]
+    assert all(g[0] == "function_score" for g in groups)
 
 
 @pytest.mark.parametrize("order", ["asc", "desc"])
@@ -571,3 +657,24 @@ def test_a_filter_earns_its_cache_entries_by_recurring(ctx):
         assert search(7) == 0  # resident rows: nothing evaluated, nothing put
     finally:
         ctx.filter_cache = old
+
+
+def test_a_rung_of_unfiltered_plans_is_one_program_full_or_padded(ctx):
+    """Two, three and four function scores over match_all in one batch launch
+    ONE program at the rung of four: the rung's resident mask stands in for a
+    filter whether the rung is full or padded (a full rung under the [1, 1]
+    no-op was a second program, first met inside a measured window)."""
+    from elasticsearch_tpu.common.jaxenv import thread_compile_totals as compile_totals
+    from elasticsearch_tpu.search.execute import execute_flat_batch, lower_flat
+    from elasticsearch_tpu.search.queries import parse_query
+
+    plan = lower_flat(parse_query({"function_score": {
+        "query": {"match_all": {}},
+        "functions": [{"field_value_factor": {"field": "status"}}]}}), ctx)
+    one = execute_flat_batch([plan], ctx, 10)[0]
+    execute_flat_batch([plan] * 3, ctx, 10)  # the rung of four, padded
+    before = compile_totals()
+    for n in (2, 4, 3, 4):
+        got = execute_flat_batch([plan] * n, ctx, 10)
+        assert all(r.total == one.total and r.hits == one.hits for r in got)
+    assert compile_totals() == before
