@@ -76,6 +76,11 @@ _UNIT_MILLIS = {
 }
 
 
+def date_math_reads_clock(value: str) -> bool:
+    """Whether parse_date_math resolves `value` against the clock (`now...`)."""
+    return _DATE_MATH_RE.match(value) is not None
+
+
 def parse_date_math(value: str, now_ms: int | None = None) -> int:
     import time
 

@@ -353,8 +353,9 @@ def packed_tier_bytes(packed: PackedSegment) -> dict:
       norms        per-field norm-byte columns + live mask + dv columns
 
     Pure host arithmetic over already-known shapes — no device sync, no
-    packing side effects. `filter_masks` is accounted separately (the holder
-    lives on the segment, not the PackedSegment — see capacity walk callers)."""
+    packing side effects. `filter_masks` and `function_rows` are accounted
+    separately (their holders live on the segment, not the PackedSegment —
+    see capacity walk callers)."""
     postings = (_plane_bytes(packed.blk_docs) + _plane_bytes(packed.blk_tf)
                 + _plane_bytes(packed.blk_nb))
     sim = 0
@@ -521,18 +522,20 @@ PACK_LEDGER = PackLedger()
 
 
 def segment_capacity(seg: FrozenSegment) -> dict | None:
-    """The ledger row for one live segment: tier bytes + filter-mask holder
-    bytes, or None when the segment never packed (nothing resident). Pure
-    host reads — safe from any stats/scrape path."""
-    packed = getattr(seg, "_device_cache", {}).get("packed")
-    holder = getattr(seg, "_device_cache", {}).get("filter_masks")
-    mask_bytes = int(holder.bytes) if holder is not None else 0
-    if packed is None and mask_bytes == 0:
+    """The ledger row for one live segment: tier bytes + the bytes of its
+    row holders (DeviceFilterCache's key spaces, a tier each), or None when
+    the segment never packed (nothing resident). Pure host reads — safe from
+    any stats/scrape path."""
+    cache = getattr(seg, "_device_cache", {})
+    packed = cache.get("packed")
+    held = {space: int(cache[space].bytes) if space in cache else 0
+            for space in ROW_SPACES}
+    if packed is None and not any(held.values()):
         return None
     tiers = packed_tier_bytes(packed) if packed is not None else {
         "postings": 0, "dense_plane": 0, "sim_tables": 0, "agg_rows": 0,
         "agg_limbs": 0, "sort_keys": 0, "norms": 0}
-    tiers["filter_masks"] = mask_bytes
+    tiers.update(held)
     return {
         "generation": int(seg.gen),
         "tf_layout": packed.tf_layout if packed is not None else None,
@@ -1217,46 +1220,92 @@ class RecentKeys:
         self._count.clear()
 
 
-class _SegmentFilterMasks:
-    """Per-segment holder of device-resident filter masks, living in
-    `seg._device_cache["filter_masks"]`. Copy-on-write tombstoning
+# The store's key spaces. A space's name is its holder's slot in
+# `seg._device_cache` and its tier in the device capacity ledger.
+FILTER_MASKS = "filter_masks"    # Filter.key() -> one bool [Dpad] mask row
+FUNCTION_ROWS = "function_rows"  # a function_score spec's row key -> the
+#                                  tuple of [Dpad] rows its launch reads
+ROW_SPACES = (FILTER_MASKS, FUNCTION_ROWS)
+_BREAKER_LABEL = {FILTER_MASKS: "<filter_mask>",
+                  FUNCTION_ROWS: "<function_rows>"}
+
+
+class _SegmentRows:
+    """Per-segment holder of one key space's device-resident rows, living in
+    `seg._device_cache[<space>]`. Copy-on-write tombstoning
     (FrozenSegment.with_deletes) shallow-copies the device cache, so views of
     one segment SHARE this holder — eviction therefore keys on the holder
     object (is it still referenced by any live segment?), not on the segment
-    wrapper identity. Filter masks are live-mask independent (filters gate
-    MATCHING; liveness is the kernel's separate live_parent gate), so sharing
-    across tombstone views is exact."""
+    wrapper identity. The rows are live-mask independent (filters gate
+    MATCHING and function rows hold a value for every document; liveness is
+    the kernel's separate live_parent gate), so sharing across tombstone
+    views is exact."""
 
-    __slots__ = ("masks", "seen", "bytes", "dead")
+    __slots__ = ("entries", "seen", "bytes", "dead")
+
+    def __init__(self, dead: bool = False):
+        self.entries: dict = {}  # key -> (device rows, nbytes)
+        self.seen = RecentKeys()  # key -> sightings it is remembered for
+        self.bytes = 0
+        self.dead = dead  # evicted with its segment: never re-stores
+
+
+class _SpaceCounters:
+    """The node-level tallies of one key space (DeviceFilterCache._lock
+    guards them)."""
+
+    __slots__ = ("hits", "misses", "builds", "evictions", "rejections",
+                 "bytes", "entries")
 
     def __init__(self):
-        self.masks: dict = {}  # filter key -> (device bool [Dpad], nbytes)
-        self.seen = RecentKeys()  # filter key -> sightings it is remembered for
-        self.bytes = 0
-        self.dead = False  # evicted with its segment: never re-stores
+        self.hits = self.misses = self.builds = self.evictions = 0
+        self.rejections = 0  # breaker-tripped stores
+        self.bytes = self.entries = 0
+
+    def stats(self) -> dict:
+        n = self.hits + self.misses
+        return {
+            "memory_size_in_bytes": self.bytes,
+            "hits": self.hits,
+            "misses": self.misses,
+            "builds": self.builds,
+            "evictions": self.evictions,
+            "rejections": self.rejections,
+            "hit_rate": round(self.hits / n, 4) if n else 0.0,
+        }
 
 
 class DeviceFilterCache:
-    """Node-level accounting + policy for per-segment device filter masks.
+    """Node-level accounting + policy for per-segment device-resident rows:
+    one store, two key spaces.
 
-    Hot filters keep their packed per-segment doc masks resident in HBM,
-    keyed by (segment identity, filter fingerprint — `Filter.key()`), so a
-    cached filtered plan skips host mask construction AND the host→device
-    mask transfer entirely; the dense kernel consumes the resident row with
-    bitwise-identical scores (the mask VALUES are identical — filters gate
-    matching, never scoring). Population is sighting-based: the first
-    evaluation of a filter on a segment only counts it (the Profile API's
-    `bool_filter_clause` fallback counter motivated exactly this "which
-    filters are hot" signal); the `min_sightings`-th (default 2nd) builds the
-    padded row host-side OUTSIDE any lock, `jax.device_put`s it once under
-    the transfer guard, charges the fielddata breaker (next to
+    FILTER_MASKS: hot filters keep their packed per-segment doc masks
+    resident in HBM, keyed by (segment identity, filter fingerprint —
+    `Filter.key()`), so a cached filtered plan skips host mask construction
+    AND the host→device mask transfer entirely; the dense kernel consumes
+    the resident row with bitwise-identical scores (the mask VALUES are
+    identical — filters gate matching, never scoring). FUNCTION_ROWS: the
+    rows a function_score launch reads of a segment (the host-combined
+    function row and its applies row, or a script's column rows and masks:
+    execute._fs_segment_rows), keyed by the part of the spec the rows are
+    computed from; the stored arrays are the ones the host built, so the
+    launch reads the same bits resident as handed down.
+
+    Both spaces are populated by sighting, each with a history of its own:
+    the first evaluation of a key on a segment only counts it (the Profile
+    API's `bool_filter_clause` fallback counter motivated exactly this
+    "which filters are hot" signal); the `min_sightings`-th (default 2nd)
+    among the segment's last 256 misses (RecentKeys) takes the padded rows
+    the caller built host-side OUTSIDE any lock, `jax.device_put`s them once
+    under the transfer guard, charges the fielddata breaker (next to
     `packed_resident_bytes` — this is device-resident state), and publishes
-    under the leaf lock. Masks are evicted with their segment on
-    refresh/merge (the engine's view listeners) and by
+    under the leaf lock. Keys that never recur, a dashboard's time windows
+    or decay origins nobody repeats, are never admitted. Rows are evicted
+    with their segment on refresh/merge (the engine's view listeners) and by
     `POST /_cache/clear?filter=true`, releasing the breaker bytes.
 
     Lock discipline: `_lock` is a LEAF guarding dicts and counters only —
-    the mask build and the device_put always happen outside it (the
+    the row build and the device_put always happen outside it (the
     build-outside/publish-under idiom, pinned by the tpulint TPU004
     fixtures)."""
 
@@ -1270,157 +1319,163 @@ class DeviceFilterCache:
             settings.get_int("indices.filter_cache.min_sightings", 2)))
         self.breaker = breaker
         self._lock = threading.Lock()
-        self.hits = 0
-        self.misses = 0
-        self.builds = 0
-        self.evictions = 0
-        self.rejections = 0  # breaker-tripped stores
-        self._bytes = 0
-        self._masks = 0
+        self._spaces = {space: _SpaceCounters() for space in ROW_SPACES}
 
     @staticmethod
-    def _holder(seg) -> _SegmentFilterMasks:
-        holder = seg._device_cache.get("filter_masks")
+    def _holder(seg, space: str) -> _SegmentRows:
+        holder = seg._device_cache.get(space)
         if holder is None:
             # benign setdefault race: both racers publish an empty holder,
             # one wins, neither has accounted bytes yet
-            holder = seg._device_cache.setdefault("filter_masks",
-                                                  _SegmentFilterMasks())
+            holder = seg._device_cache.setdefault(space, _SegmentRows())
         return holder
 
-    def lookup(self, seg, key: str):
-        """The resident device row for (segment, filter key), or None. Counts
-        the sighting — the miss path's counter is what promotes a filter to
-        resident on its next appearance."""
-        holder = self._holder(seg)
+    @staticmethod
+    def _profile_event(space: str, cache: str, key, **kv) -> None:
         prof = _profile.current()
+        if prof is None:
+            return
+        if space == FILTER_MASKS:
+            prof.event("filter_cache", cache=cache, filter=key, **kv)
+        else:
+            prof.event(space, cache=cache, key=key, **kv)
+
+    def lookup(self, seg, key, space: str = FILTER_MASKS):
+        """The resident device rows for (segment, key) in `space`, or None.
+        Counts the sighting — the miss path's counter is what promotes a key
+        to resident on its next appearance."""
+        holder = self._holder(seg, space)
+        tally = self._spaces[space]
         with self._lock:
-            entry = holder.masks.get(key)
+            entry = holder.entries.get(key)
             if entry is not None:
-                self.hits += 1
+                tally.hits += 1
             else:
-                self.misses += 1
+                tally.misses += 1
                 holder.seen.sight(key)
-        if prof is not None:
-            prof.event("filter_cache", cache="hit" if entry else "miss",
-                       filter=key)
+        self._profile_event(space, "hit" if entry else "miss", key)
         return entry[0] if entry is not None else None
 
-    def maybe_store(self, seg, key: str, padded_mask):
-        """Promote a freshly evaluated filter mask to device residency when
-        it has reached `min_sightings`. `padded_mask` is the host bool [Dpad]
-        row built OUTSIDE any lock; the device_put happens here, also outside
-        the leaf lock, and only the publish goes under it. Returns the device
-        row (freshly stored or a concurrent winner's), or None when the
-        filter is still cold / the tier is off / the breaker tripped."""
+    def maybe_store(self, seg, key, padded, space: str = FILTER_MASKS):
+        """Promote freshly evaluated rows to device residency when their key
+        has reached `min_sightings`. `padded` is what the caller built
+        OUTSIDE any lock, one host [Dpad] row (a filter's mask) or a tuple
+        of them (a function_score launch's rows); the device_put happens
+        here, also outside the leaf lock, and only the publish goes under
+        it. Returns the device rows in `padded`'s own structure (freshly
+        stored or a concurrent winner's), or None when the key is still
+        cold / the tier is off / the breaker tripped."""
         if not self.enabled:
             return None
-        holder = self._holder(seg)
+        holder = self._holder(seg, space)
+        tally = self._spaces[space]
         with self._lock:
             if holder.dead:
                 return None  # segment already evicted: a stale searcher
                 # must not repopulate bytes nobody will ever release
-            entry = holder.masks.get(key)
+            entry = holder.entries.get(key)
             if entry is not None:
                 return entry[0]
             if holder.seen.get(key) < self.min_sightings:
                 return None
         import jax
 
-        nbytes = int(padded_mask.nbytes)
+        nbytes = sum(int(row.nbytes)
+                     for row in jax.tree_util.tree_leaves(padded))
         if self.breaker is not None:
             try:
                 self.breaker.add_estimate_and_maybe_break(
-                    nbytes, "<filter_mask>")
+                    nbytes, _BREAKER_LABEL[space])
             except CircuitBreakingError:
-                self.rejections += 1  # out of fielddata budget: the host
-                return None           # mask still serves this request
-        row = jax.device_put(padded_mask)  # the ONE transfer, outside _lock
+                with self._lock:      # out of fielddata budget: the host
+                    tally.rejections += 1  # rows still serve this request
+                return None
+        rows = jax.device_put(padded)  # the ONE transfer, outside _lock
         release = 0
         with self._lock:
             if holder.dead:
                 release = nbytes
-                row = None
+                rows = None
             else:
-                entry = holder.masks.get(key)
+                entry = holder.entries.get(key)
                 if entry is not None:
                     release = nbytes  # concurrent winner: keep theirs
-                    row = entry[0]
+                    rows = entry[0]
                 else:
-                    holder.masks[key] = (row, nbytes)
+                    holder.entries[key] = (rows, nbytes)
                     holder.bytes += nbytes
-                    self._bytes += nbytes
-                    self._masks += 1
-                    self.builds += 1
+                    tally.bytes += nbytes
+                    tally.entries += 1
+                    tally.builds += 1
         if release and self.breaker is not None:
             self.breaker.release(release)
-        if row is not None and release == 0:
-            prof = _profile.current()
-            if prof is not None:
-                prof.event("filter_cache", cache="build", filter=key,
-                           bytes=nbytes)
-        return row
+        if rows is not None and release == 0:
+            self._profile_event(space, "build", key, bytes=nbytes)
+        return rows
 
     # -- eviction ------------------------------------------------------------
+    def _drop_locked(self, holder: _SegmentRows, space: str) -> tuple:
+        """Empty `holder` (caller holds `_lock`): (entries dropped, bytes to
+        release to the breaker)."""
+        tally = self._spaces[space]
+        n, released = len(holder.entries), holder.bytes
+        tally.bytes -= released
+        tally.entries -= n
+        tally.evictions += n
+        holder.entries.clear()
+        holder.seen.clear()
+        holder.bytes = 0
+        return n, released
+
     def evict_dropped(self, dropped, live) -> int:
-        """Evict the masks of segments a new view dropped. `live` is the new
-        view's segment list: a with_deletes view SHARES its predecessor's
-        holder, so a holder still referenced by any live segment is retained
-        (same filters, same postings — only tombstones changed)."""
-        live_holders = {id(s._device_cache.get("filter_masks"))
-                        for s in live
-                        if s._device_cache.get("filter_masks") is not None}
+        """Evict the rows of segments a new view dropped, in every key
+        space. `live` is the new view's segment list: a with_deletes view
+        SHARES its predecessor's holders, so a holder still referenced by
+        any live segment is retained (same filters and functions, same
+        documents — only tombstones changed)."""
         released = 0
         evicted = 0
-        for seg in dropped:
-            holder = seg._device_cache.get("filter_masks")
-            if holder is None:
-                # plant a DEAD holder so a straggler request still holding
-                # the old searcher can't create a fresh one after this
-                # eviction ran (its stores would be unreleasable bytes)
-                dead = _SegmentFilterMasks()
-                dead.dead = True
-                holder = seg._device_cache.setdefault("filter_masks", dead)
-                if holder is dead:
-                    continue  # nothing was resident; the tombstone is planted
-            if id(holder) in live_holders:
-                continue
-            with self._lock:
-                if holder.dead:
+        for space in ROW_SPACES:
+            live_holders = {id(s._device_cache.get(space)) for s in live
+                            if s._device_cache.get(space) is not None}
+            for seg in dropped:
+                holder = seg._device_cache.get(space)
+                if holder is None:
+                    # plant a DEAD holder so a straggler request still
+                    # holding the old searcher can't create a fresh one after
+                    # this eviction ran (its stores would be unreleasable
+                    # bytes)
+                    dead = _SegmentRows(dead=True)
+                    holder = seg._device_cache.setdefault(space, dead)
+                    if holder is dead:
+                        continue  # nothing was resident; tombstone planted
+                if id(holder) in live_holders:
                     continue
-                holder.dead = True
-                n = len(holder.masks)
-                released += holder.bytes
-                self._bytes -= holder.bytes
-                self._masks -= n
-                self.evictions += n
+                with self._lock:
+                    if holder.dead:
+                        continue
+                    holder.dead = True
+                    n, nbytes = self._drop_locked(holder, space)
                 evicted += n
-                holder.masks.clear()
-                holder.seen.clear()
-                holder.bytes = 0
+                released += nbytes
         if released and self.breaker is not None:
             self.breaker.release(released)
         return evicted
 
     def clear_segment(self, seg) -> int:
         """`POST /_cache/clear?filter=true` on a LIVE segment: drop its
-        resident masks and sighting counters (rebuildable — the holder stays
-        alive), returning the breaker bytes."""
-        holder = seg._device_cache.get("filter_masks")
-        if holder is None:
-            return 0
+        resident rows and sighting counters in every key space (rebuildable
+        — the holders stay alive), returning the breaker bytes."""
         released = 0
         evicted = 0
-        with self._lock:
-            n = len(holder.masks)
-            released = holder.bytes
-            self._bytes -= holder.bytes
-            self._masks -= n
-            self.evictions += n
-            evicted = n
-            holder.masks.clear()
-            holder.seen.clear()
-            holder.bytes = 0
+        for space in ROW_SPACES:
+            holder = seg._device_cache.get(space)
+            if holder is None:
+                continue
+            with self._lock:
+                n, nbytes = self._drop_locked(holder, space)
+            evicted += n
+            released += nbytes
         if released and self.breaker is not None:
             self.breaker.release(released)
         return evicted
@@ -1433,10 +1488,10 @@ class DeviceFilterCache:
         keys: set = set()
         with self._lock:
             for seg in segs:
-                holder = seg._device_cache.get("filter_masks")
+                holder = seg._device_cache.get(FILTER_MASKS)
                 if holder is None:
                     continue
-                keys.update(holder.masks.keys())
+                keys.update(holder.entries.keys())
                 keys.update(k for k, c in holder.seen.items()
                             if c >= self.min_sightings)
         return keys
@@ -1449,36 +1504,29 @@ class DeviceFilterCache:
         only — no masks are built or uploaded here."""
         if not self.enabled or not keys:
             return 0
-        holder = self._holder(seg)
+        holder = self._holder(seg, FILTER_MASKS)
         seeded = 0
         with self._lock:
             if holder.dead:
                 return 0
             for k in keys:
                 short = self.min_sightings - holder.seen.get(k)
-                if k not in holder.masks and short > 0:
+                if k not in holder.entries and short > 0:
                     holder.seen.sight(k, short)
                     seeded += 1
         return seeded
 
     # -- observability -------------------------------------------------------
-    def hit_rate(self) -> float:
-        n = self.hits + self.misses
-        return (self.hits / n) if n else 0.0
-
     def stats(self) -> dict:
+        """The filter masks' tallies under the names they always had
+        (`indices.filter_cache` in /_nodes/stats), the function rows' under
+        `function_rows`."""
         with self._lock:
-            return {
-                "enabled": self.enabled,
-                "memory_size_in_bytes": self._bytes,
-                "masks": self._masks,
-                "hits": self.hits,
-                "misses": self.misses,
-                "builds": self.builds,
-                "evictions": self.evictions,
-                "rejections": self.rejections,
-                "hit_rate": round(self.hit_rate(), 4),
-            }
+            masks = self._spaces[FILTER_MASKS]
+            rows = self._spaces[FUNCTION_ROWS]
+            return {"enabled": self.enabled, "masks": masks.entries,
+                    **masks.stats(),
+                    FUNCTION_ROWS: {"entries": rows.entries, **rows.stats()}}
 
 
 TFN_BM25 = 0  # tfn = f / (f + cache[norm_byte])        — weight multiplies outside

@@ -302,7 +302,7 @@ class LaunchCounters:
              "launches_sparse", "launches_dense", "operand_puts",
              "unscored_plans", "launches_unscored", "unscored_bytes",
              "mask_put_bytes", "launches_fs_unscored", "fs_row_put_bytes",
-             "exact_sum_rows"), 0)
+             "fs_rows_resident", "fs_rows_evaluated", "exact_sum_rows"), 0)
 
     def add(self, real: int, launched: int, nbytes: int,
             dense_rows: int = 0, head_slots: int = 0,
@@ -329,9 +329,11 @@ class LaunchCounters:
         `unscored_bytes`: _count_unscored; `launches_fs_unscored`: those of
         them behind a function_score tail), the bytes of filter-mask rows a
         search evaluated on the host and put (`mask_put_bytes`:
-        execute._filter_mask_matrix), the bytes of function rows, applies
-        rows and script column rows a function_score launch was handed
-        (`fs_row_put_bytes`: execute._execute_flat_fs), and the integer limb
+        execute._filter_mask_matrix), how often a function_score launch
+        group found a segment's rows resident or had the host evaluate them
+        (`fs_rows_resident`, `fs_rows_evaluated`) and the bytes of function
+        rows, applies rows and script column rows so evaluated and put
+        (`fs_row_put_bytes`: execute._fs_segment_rows), and the integer limb
         rows the aggregated launches reduced (`exact_sum_rows`:
         score_agg_batch_async)."""
         with self._lock:

@@ -371,7 +371,8 @@ def _prometheus_text(node) -> str:
     emitted, omitted = ranked[:cap], ranked[cap:]
     for iname, entry in emitted:
         for tier in ("postings", "dense_plane", "sim_tables", "agg_rows",
-                     "agg_limbs", "sort_keys", "norms", "filter_masks"):
+                     "agg_limbs", "sort_keys", "norms", "filter_masks",
+                     "function_rows"):
             w.gauge("estpu_device_index_bytes",
                     entry["totals"].get(tier, 0), index=iname, tier=tier)
     for iname, entry in emitted:

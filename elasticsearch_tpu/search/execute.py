@@ -29,6 +29,7 @@ import math
 import re
 import time
 from dataclasses import dataclass, field as dc_field, replace as dc_replace
+from functools import partial
 
 import numpy as np
 
@@ -1282,6 +1283,67 @@ def _fs_script_rows(sf, used_fields, seg, ctx: ShardContext, doc_pad: int):
     return tuple(col_rows), fmask_row, bad_row, parent_row
 
 
+def _fs_rows_key(fsq, kind: str, used_fields, ctx: ShardContext):
+    """What a function_score group's rows of a segment are computed from
+    (the key of the device row store's FUNCTION_ROWS space), or None where
+    the rows are not a pure function of (segment, key) and must be evaluated
+    for every launch.
+
+    "rows": the functions and the score_mode, the part of _fs_group_key that
+    combined_doc_rows reads; functions.doc_rows_are_the_segments says whether
+    they are the segment's own (no decay that reads the clock, no filter
+    whose mask spans more than the segment). "script": the columns the
+    script reads and its function's filter (the script's source and params
+    are statics of the program, not of the rows); the columns are the
+    segment's, so only the filter is asked (Filter.cacheable()).
+    `max_boost`, `boost`, `min_score` and `boost_mode` are scalars of the
+    launch and no part of either key."""
+    if kind == "rows":
+        from .functions import doc_rows_are_the_segments
+
+        if not doc_rows_are_the_segments(fsq, ctx):
+            return None
+        return ("rows", repr(fsq.functions), fsq.score_mode)
+    filt = fsq.functions[0].filter
+    if filt is None:
+        return ("script", tuple(used_fields), None)
+    return ("script", tuple(used_fields), filt.key()) if filt.cacheable() \
+        else None
+
+
+def _fs_segment_rows(key, evaluate, seg, ctx: ShardContext, doc_pad: int):
+    """The rows a function_score launch reads of `seg`, looked up before
+    they are evaluated: the resident device rows where the node's row store
+    (ops/device_index.DeviceFilterCache, its FUNCTION_ROWS space) holds
+    (segment, key), else `evaluate(seg, ctx, doc_pad)` on the host
+    (_fs_function_rows or _fs_script_rows) with the filter masks' own
+    sighting-based promotion (maybe_store: the second sighting among the
+    segment's last 256 misses puts the rows once and publishes them). The
+    values are the host's either way, bit for bit. `key` None (rows that are
+    not the segment's own: _fs_rows_key) always evaluates.
+
+    Counted a launch group a segment as `fs_rows_resident` or
+    `fs_rows_evaluated`; `fs_row_put_bytes` counts the evaluated rows' bytes
+    (they go down once, into the store or with the launch; a hit adds 0)."""
+    import jax
+
+    from ..ops.device_index import FUNCTION_ROWS
+    from ..ops.scoring import LAUNCHES
+
+    fc = ctx.filter_cache
+    kept = key is not None and fc is not None and fc.enabled
+    if kept:
+        rows = fc.lookup(seg, key, FUNCTION_ROWS)
+        if rows is not None:
+            LAUNCHES.bump(fs_rows_resident=1)
+            return rows
+    host = evaluate(seg, ctx, doc_pad)
+    LAUNCHES.bump(fs_rows_evaluated=1, fs_row_put_bytes=sum(
+        row.nbytes for row in jax.tree_util.tree_leaves(host)))
+    rows = fc.maybe_store(seg, key, host, FUNCTION_ROWS) if kept else None
+    return host if rows is None else rows
+
+
 def _execute_flat_fs(plans: list[FlatPlan], ctx: ShardContext, k: int) -> list[TopDocs]:
     """Execute a group of function_score plans sharing ONE spec (see
     _fs_group_key; all scored or all unscored: _flat_groups) through the dense
@@ -1293,20 +1355,21 @@ def _execute_flat_fs(plans: list[FlatPlan], ctx: ShardContext, k: int) -> list[T
     segment (_fs_function_rows) and shipped as a row. "script": the single
     _score-reading script is traced into the kernel; queries flagged bad
     (missing columns / non-finite values on parent docs) rerun on the host so
-    error semantics are preserved. The host's evaluation of a segment's rows
-    is noted on the dispatch clock as `shard.fs_rows` (inside its
-    `dispatch.stage`) and the rows' bytes are counted as
-    `search_serving.launch.fs_row_put_bytes`: nothing keeps a row resident.
+    error semantics are preserved. A segment's rows are looked up in the
+    device row store before they are evaluated (_fs_segment_rows): a spec
+    that recurs finds them resident from its second sighting on, and the
+    launch takes the resident arrays (same shapes and dtypes: the same
+    compiled program). The lookup-or-evaluate is noted on the dispatch clock
+    as `shard.fs_rows` (inside its `dispatch.stage`); what the host
+    evaluated is counted as `search_serving.launch.fs_row_put_bytes`.
 
     The group launches _GROUP_WIDTH plans at a time, each launch's query
     count up the ladder the aggregated and sorted groups ride (_group_width:
     a spec has two programs, not one a count), a segment's rows evaluated
     once for all of them; the launches of a segment are pulled together."""
-    import jax
-
     from ..common.errors import ScriptError
     from ..ops.device_index import packed_for
-    from ..ops.scoring import (LAUNCHES, _pull, _put_operands,
+    from ..ops.scoring import (_pull, _put_operands,
                                score_fs_rows_batch_async,
                                score_fs_script_batch_async)
     from ..script import compile_script, script_vector_info
@@ -1321,6 +1384,9 @@ def _execute_flat_fs(plans: list[FlatPlan], ctx: ShardContext, k: int) -> list[T
         sf = fsq.functions[0]
         script = compile_script(sf.script, sf.params)
         used_fields = script_vector_info(script)[1]
+    evaluate = partial(_fs_function_rows, fsq) if kind == "rows" \
+        else partial(_fs_script_rows, sf, used_fields)
+    rows_key = _fs_rows_key(fsq, kind, used_fields, ctx)
 
     host_idx: set[int] = set()
     totals = np.zeros(Q, dtype=np.int64)
@@ -1334,10 +1400,7 @@ def _execute_flat_fs(plans: list[FlatPlan], ctx: ShardContext, k: int) -> list[T
                                 owner=ctx.index_name)
             D, doc_pad = seg.doc_count, packed.doc_pad
             t_rows = time.monotonic()
-            rows = _fs_function_rows(fsq, seg, ctx, doc_pad) if kind == "rows" \
-                else _fs_script_rows(sf, used_fields, seg, ctx, doc_pad)
-            LAUNCHES.bump(fs_row_put_bytes=sum(
-                row.nbytes for row in jax.tree_util.tree_leaves(rows)))
+            rows = _fs_segment_rows(rows_key, evaluate, seg, ctx, doc_pad)
             tracing.note("shard.fs_rows", t_rows)
             if len(chunks) > 1:
                 rows = _put_operands(*rows)  # once for the segment's launches

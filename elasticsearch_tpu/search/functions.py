@@ -23,7 +23,7 @@ import zlib
 import numpy as np
 
 from ..common.errors import QueryParsingError
-from ..mapper.core import parse_date_math
+from ..mapper.core import date_math_reads_clock, parse_date_math
 from .filters import haversine_m, parse_distance, segment_mask
 
 
@@ -200,6 +200,26 @@ def evaluate_function(sf, seg, ctx, sub_scores: np.ndarray) -> np.ndarray:
         return np.where(np.isnan(out), 1.0, out).astype(np.float32)  # missing → neutral
 
     raise QueryParsingError(f"unknown score function [{sf.kind}]")
+
+
+def doc_rows_are_the_segments(q, ctx) -> bool:
+    """Whether combined_doc_rows of a spec whose functions read no `_score`
+    is a pure function of (segment, spec), so that a row built once may be
+    kept with the segment (execute._fs_segment_rows). Two tests decide: no
+    decay may resolve its origin against the clock (_parse_origin does for
+    a date field with no origin, or with date math, `now...`, in it), and
+    every function filter's mask must be the segment's own
+    (Filter.cacheable(), the rule segment_mask asks before it keeps one)."""
+    for sf in q.functions:
+        if sf.filter is not None and not sf.filter.cacheable():
+            return False
+        if sf.kind in ("gauss", "exp", "linear"):
+            ft = ctx.field_type(sf.field)
+            if ft is not None and ft.type == "date" and (
+                    sf.origin is None
+                    or date_math_reads_clock(str(sf.origin))):
+                return False
+    return True
 
 
 def combined_doc_rows(q, sub_scores: np.ndarray, seg, ctx):

@@ -553,6 +553,100 @@ class TestLiveFilterCache:
             cluster.close()
 
 
+FS_ROWS = {"query": {"function_score": {
+    "query": {"match_all": {}},
+    "functions": [{"field_value_factor": {"field": "n", "factor": 2,
+                                          "modifier": "log1p"}}],
+    "boost_mode": "replace"}}, "size": 8}
+
+
+def _ids_scores(resp):
+    return [(h["_id"], h["_score"]) for h in resp["hits"]["hits"]]
+
+
+class TestLiveFunctionRows:
+    """The store's second key space on a live node: the rows a
+    function_score launch reads of a segment (execute._fs_segment_rows)."""
+
+    def test_resident_from_the_second_sighting_and_cleared_over_rest(
+            self, tmp_path):
+        cluster, c = _boot(tmp_path)
+        node = next(iter(cluster.nodes.values()))
+        try:
+            def rows():
+                return node.filter_cache.stats()["function_rows"]
+
+            fielddata = node.breakers.breaker("fielddata")
+            cold = c.search("hot", FS_ROWS)  # first sighting: counted
+            used0 = fielddata.used
+            assert rows()["entries"] == 0 and rows()["misses"] >= 1
+            stored = c.search("hot", FS_ROWS)  # second: put once, published
+            held = rows()["memory_size_in_bytes"]
+            assert rows()["entries"] == rows()["builds"] >= 1 and held > 0
+            assert fielddata.used == used0 + held
+            hits0 = rows()["hits"]
+            resident = c.search("hot", FS_ROWS)
+            assert rows()["hits"] > hits0 and rows()["builds"] == rows()["entries"]
+            assert _ids_scores(stored) == _ids_scores(resident) == \
+                _ids_scores(cold)
+            # the masks' own tallies never moved: match_all has no mask
+            assert node.filter_cache.stats()["masks"] == 0
+            assert node.filter_cache.stats()["memory_size_in_bytes"] == 0
+
+            resp = build_rest_controller(node).dispatch(RestRequest(
+                method="POST", path="/hot/_cache/clear",
+                params={"filter": "true"}, body=None))
+            assert resp.status == 200
+            assert rows()["entries"] == 0
+            assert rows()["memory_size_in_bytes"] == 0
+            assert rows()["evictions"] >= 1
+            assert fielddata.used == used0
+            # cleared, not dead: the spec earns its entry again
+            assert _ids_scores(c.search("hot", FS_ROWS)) == _ids_scores(cold)
+            c.search("hot", FS_ROWS)
+            assert rows()["entries"] >= 1
+        finally:
+            cluster.close()
+
+    def test_rows_outlive_a_delete_and_leave_with_their_segment(
+            self, tmp_path):
+        cluster, c = _boot(tmp_path)
+        node = next(iter(cluster.nodes.values()))
+        try:
+            def rows():
+                return node.filter_cache.stats()["function_rows"]
+
+            cold = c.search("hot", FS_ROWS)
+            c.search("hot", FS_ROWS)
+            entries = rows()["entries"]
+            assert entries >= 1
+            # a tombstone view shares its predecessor's holder: the resident
+            # row serves on (it holds a value for every document; liveness is
+            # the kernel's own gate) and the deleted document is gone
+            top = cold["hits"]["hits"][0]["_id"]
+            c.delete("hot", "doc", top)
+            c.refresh("hot")
+            hits0, evictions0 = rows()["hits"], rows()["evictions"]
+            after = c.search("hot", FS_ROWS)
+            assert rows()["hits"] > hits0 and rows()["entries"] == entries
+            assert rows()["evictions"] == evictions0
+            assert after["hits"]["total"] == cold["hits"]["total"] - 1
+            assert _ids_scores(after)[:7] == _ids_scores(cold)[1:]
+            # a merge drops the segment: its rows go with it, bytes and all
+            c.index("hot", "doc", {"body": "alpha tail", "n": 200,
+                                   "tag": "t1"}, id="tail")
+            c.refresh("hot")
+            c.optimize("hot")
+            assert rows()["evictions"] >= evictions0 + entries
+            assert rows()["entries"] == 0
+            assert rows()["memory_size_in_bytes"] == 0
+            merged = c.search("hot", FS_ROWS)
+            assert merged["hits"]["hits"][0]["_id"] == "tail"
+            assert merged["hits"]["total"] == cold["hits"]["total"]
+        finally:
+            cluster.close()
+
+
 class TestObservabilitySurfaces:
     def test_nodes_stats_cat_and_prometheus(self, tmp_path):
         cluster, c = _boot(tmp_path)

@@ -470,6 +470,42 @@ class TestRestartPersistence:
             node.close()
 
 
+class TestResidentFunctionRows:
+    @pytest.mark.parametrize("function", [
+        {"field_value_factor": {"field": "n", "factor": 2, "modifier": "log1p"}},
+        {"script_score": {"script": "log(doc['n'].value + 2) * _score"}}],
+        ids=["rows", "script"])
+    def test_a_hit_after_a_miss_reaches_the_same_program(
+            self, registry_guard, tmp_path, function):
+        """A function_score launch takes its rows as host arrays (a miss), as
+        the device arrays the store just put (the second sighting) or as
+        resident ones (a hit): one compiled program, no compile event
+        between them."""
+        from elasticsearch_tpu.common.jaxenv import sanitize
+        from elasticsearch_tpu.ops.scoring import LAUNCHES
+
+        node = _boot(str(tmp_path / "n0"))
+        try:
+            c, _totals = _seed_and_serve(node)
+            body = {"query": {"function_score": {
+                "query": {"match_all": {}}, "functions": [function]}},
+                "size": 5}
+            first = c.search("warm", body)  # compiles; the host's rows
+            before = LAUNCHES.snapshot()
+            with sanitize(max_compiles=0) as rep:
+                stored = c.search("warm", body)
+                resident = c.search("warm", body)
+            assert rep.compiles == 0, rep.compile_events
+            after = LAUNCHES.snapshot()
+            assert after["fs_rows_evaluated"] > before["fs_rows_evaluated"]
+            assert after["fs_rows_resident"] > before["fs_rows_resident"]
+            for resp in (stored, resident):
+                assert [(h["_id"], h["_score"]) for h in resp["hits"]["hits"]] \
+                    == [(h["_id"], h["_score"]) for h in first["hits"]["hits"]]
+        finally:
+            node.close()
+
+
 # ---------------------------------------------------------------------------
 # request-cache compression (satellite)
 # ---------------------------------------------------------------------------

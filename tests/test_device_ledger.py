@@ -185,7 +185,8 @@ class TestLiveLedger:
             for row in shard_rows:
                 assert set(row["tiers"]) == {
                     "postings", "dense_plane", "sim_tables", "agg_rows",
-                    "agg_limbs", "sort_keys", "norms", "filter_masks"}
+                    "agg_limbs", "sort_keys", "norms", "filter_masks",
+                    "function_rows"}
 
             # /_nodes/stats device section (+ compile family rollup)
             st = c.nodes_stats(metric="device")
@@ -214,6 +215,29 @@ class TestLiveLedger:
             assert node.filter_cache.stats()["masks"] >= 1
             report = capacity_report(node.indices)
             assert report["indices"]["led"]["totals"]["filter_masks"] > 0
+        finally:
+            cluster.close()
+
+    def test_function_rows_tier_counts_resident_rows(self, tmp_path):
+        from elasticsearch_tpu.rest.controller import _prometheus_text
+
+        cluster, c = _boot(tmp_path)
+        node = next(iter(cluster.nodes.values()))
+        try:
+            body = {"query": {"function_score": {
+                "query": {"match_all": {}},
+                "functions": [{"field_value_factor": {
+                    "field": "n", "factor": 2, "modifier": "log1p"}}]}},
+                "size": 3}
+            for _ in range(3):  # 2nd sighting promotes to device residency
+                c.search("led", body)
+            held = node.filter_cache.stats()["function_rows"]
+            assert held["entries"] >= 1
+            totals = capacity_report(node.indices)["indices"]["led"]["totals"]
+            assert totals["function_rows"] == held["memory_size_in_bytes"] > 0
+            assert totals["filter_masks"] == 0
+            assert ('estpu_device_index_bytes{index="led",tier="function_rows"} '
+                    f'{totals["function_rows"]}') in _prometheus_text(node)
         finally:
             cluster.close()
 

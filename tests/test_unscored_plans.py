@@ -12,6 +12,7 @@ runs through `execute_query_phase` on both paths and must agree exactly.
 
 from __future__ import annotations
 
+import contextlib
 import math
 import tempfile
 
@@ -678,3 +679,206 @@ def test_a_rung_of_unfiltered_plans_is_one_program_full_or_padded(ctx):
         got = execute_flat_batch([plan] * n, ctx, 10)
         assert all(r.total == one.total and r.hits == one.hits for r in got)
     assert compile_totals() == before
+
+
+# ---------------------------------------------------------------------------
+# function rows in the device row store (DeviceFilterCache's FUNCTION_ROWS key
+# space): looked up before they are evaluated, admitted as the masks are
+# ---------------------------------------------------------------------------
+
+
+@contextlib.contextmanager
+def _row_store(ctx, budget="64mb"):
+    """A fresh row store on `ctx` that charges a fielddata breaker of its
+    own. The holders live on the module's segments, so the way out clears
+    them (what `_cache/clear` does a segment) and the breaker has to be back
+    at zero."""
+    from elasticsearch_tpu.common.breaker import CircuitBreakerService
+    from elasticsearch_tpu.ops.device_index import DeviceFilterCache
+
+    breaker = CircuitBreakerService(Settings.from_flat(
+        {"indices.breaker.total_budget": budget})).breaker("fielddata")
+    fc = DeviceFilterCache(breaker=breaker)
+    old, ctx.filter_cache = ctx.filter_cache, fc
+    try:
+        yield fc, breaker
+    finally:
+        ctx.filter_cache = old
+        for seg in ctx.searcher.segments:
+            fc.clear_segment(seg)
+        assert breaker.used == 0
+        assert fc.stats()["function_rows"]["memory_size_in_bytes"] == 0
+
+
+def _row_bytes(ctx, kind: str) -> int:
+    """What a launch group's rows weigh over the searcher's segments: a
+    float32 function row and a bool applies row, or the script's two float32
+    column rows and three bool rows."""
+    per_doc = {"rows": 4 + 1, "script": 2 * 4 + 3}[kind]
+    return sum(per_doc * _doc_pad(seg) for seg in ctx.searcher.segments)
+
+
+def _three_times(ctx, body, kind: str):
+    """A function_score search a first, a second and a third time on a fresh
+    row store: miss, store, hit. Every answer is the first's bit for bit
+    (and the host's: _fs_both), the rows go down once more with the store
+    and never again."""
+    n_segs = len(ctx.searcher.segments)
+    with _row_store(ctx) as (fc, breaker):
+        answers, deltas = zip(*(_fs_both(ctx, body, script=kind == "script")
+                                for _ in range(3)))
+        for dev in answers[1:]:
+            assert dev.total == answers[0].total
+            assert [(np.float32(s).tobytes(), g) for s, g, _v in dev.docs] == \
+                [(np.float32(s).tobytes(), g) for s, g, _v in answers[0].docs]
+        assert [d["fs_rows_evaluated"] for d in deltas] == [n_segs, n_segs, 0]
+        assert [d["fs_rows_resident"] for d in deltas] == [0, 0, n_segs]
+        assert [d["fs_row_put_bytes"] for d in deltas] == \
+            [_row_bytes(ctx, kind), _row_bytes(ctx, kind), 0]
+        rows = fc.stats()["function_rows"]
+        assert rows["entries"] == rows["builds"] == n_segs
+        assert rows["hits"] == n_segs and rows["misses"] == 2 * n_segs
+        assert rows["memory_size_in_bytes"] == _row_bytes(ctx, kind)
+        assert breaker.used >= _row_bytes(ctx, kind)  # and the sub query's mask
+        return deltas
+
+
+@pytest.mark.parametrize("boost_mode",
+                         ["multiply", "replace", "sum", "avg", "max", "min"])
+@pytest.mark.parametrize("kind", sorted(FS_FUNCTIONS))
+def test_function_rows_go_resident_at_their_second_sighting(ctx, kind, boost_mode):
+    deltas = _three_times(ctx, {"query": {"function_score": {
+        "query": FS_SUBS["constant_score"], "functions": FS_FUNCTIONS[kind],
+        "score_mode": "sum", "boost_mode": boost_mode, "boost": 1.3}},
+        "size": 25}, kind)
+    assert all(d["launches_fs_unscored"] == len(ctx.searcher.segments)
+               for d in deltas)
+
+
+@pytest.mark.parametrize("kind", sorted(FS_FUNCTIONS))
+def test_resident_function_rows_under_min_score_and_max_boost(ctx, kind):
+    _three_times(ctx, {"query": {"function_score": {
+        "query": {"match_all": {}}, "functions": FS_FUNCTIONS[kind],
+        "score_mode": "sum", "max_boost": MAX_BOOST[kind],
+        "min_score": MIN_SCORE[kind]}}, "size": 25}, kind)
+
+
+@pytest.mark.parametrize("kind", sorted(FS_FUNCTIONS))
+def test_resident_function_rows_behind_the_scored_abi(ctx, kind):
+    """A text query under the function_score: the tail gathers the same rows
+    beside BM25 or TF-IDF, and finds them in the same store."""
+    deltas = _three_times(ctx, {"query": {"function_score": {
+        "query": {"match": {"body": "alpha gamma"}},
+        "functions": FS_FUNCTIONS[kind], "score_mode": "sum",
+        "boost_mode": "sum"}}, "size": 25}, kind)
+    assert all(d["launches_fs_unscored"] == 0 and d["unscored_plans"] == 0
+               for d in deltas)
+
+
+def test_the_launch_scalars_are_no_part_of_a_rows_key(ctx):
+    """boost_mode, boost, max_boost and min_score are scalars of the launch:
+    specs that differ in them alone read one resident row."""
+    def body(**scalars):
+        return {"query": {"function_score": {
+            "query": {"match_all": {}}, "functions": FS_FUNCTIONS["rows"],
+            "score_mode": "sum", **scalars}}, "size": 25}
+
+    n_segs = len(ctx.searcher.segments)
+    with _row_store(ctx) as (fc, _breaker):
+        _fs_both(ctx, body(boost_mode="sum"), script=False)
+        _fs_both(ctx, body(boost_mode="max", boost=2.0), script=False)
+        _dev, delta = _fs_both(
+            ctx, body(boost_mode="replace", max_boost=2.6, min_score=2.3),
+            script=False)
+        assert delta["fs_rows_resident"] == n_segs
+        assert fc.stats()["function_rows"]["entries"] == n_segs
+        # another score_mode combines another row
+        _dev, delta = _fs_both(ctx, {"query": {"function_score": {
+            "query": {"match_all": {}}, "functions": FS_FUNCTIONS["rows"],
+            "score_mode": "multiply"}}, "size": 25}, script=False)
+        assert delta["fs_rows_resident"] == 0
+
+
+def test_two_origins_keep_two_rows(ctx):
+    """Specs that differ in a decay's origin alone are two keys: each earns
+    its own entry and neither ever reads the other's row."""
+    def near(origin):
+        return {"query": {"function_score": {
+            "query": {"match_all": {}},
+            "functions": [{"gauss": {"frac": {
+                "origin": origin, "scale": 9, "decay": 0.2}}}],
+            "boost_mode": "replace"}}, "size": 25}
+
+    n_segs = len(ctx.searcher.segments)
+    with _row_store(ctx) as (fc, _breaker):
+        answers = {}
+        for _ in range(3):
+            for origin in (20, 70):
+                dev, delta = _fs_both(ctx, near(origin), script=False)
+                answers.setdefault(origin, []).append(
+                    [(np.float32(s).tobytes(), g) for s, g, _v in dev.docs])
+        assert delta["fs_rows_resident"] == n_segs
+        assert fc.stats()["function_rows"]["entries"] == 2 * n_segs
+        for got in answers.values():
+            assert got[0] == got[1] == got[2]
+        assert answers[20][0] != answers[70][0]
+
+
+@pytest.mark.parametrize("origin", [None, "now-1h"], ids=["none", "now"])
+def test_a_decay_that_reads_the_clock_is_never_stored(ctx, origin):
+    """A date decay with no origin, or with `now` in it, resolves its origin
+    against the clock at every evaluation (functions._parse_origin): its row
+    is not the segment's own, and the store is never asked."""
+    decay = {"scale": "30s", "decay": 0.5}
+    if origin is not None:
+        decay["origin"] = origin
+    req = parse_search_body({"query": {"function_score": {
+        "query": {"match_all": {}}, "functions": [{"gauss": {"ts": decay}}],
+        "boost_mode": "replace"}}, "size": 5})
+    with _row_store(ctx) as (fc, breaker):
+        for _ in range(3):
+            before = _counters()
+            execute_query_phase(ctx, req, use_device=True)
+            after = _counters()
+            assert after["device_function_score"] == \
+                before["device_function_score"] + 1
+            assert after["fs_rows_evaluated"] - before["fs_rows_evaluated"] == \
+                len(ctx.searcher.segments)
+            assert after["fs_rows_resident"] == before["fs_rows_resident"]
+        rows = fc.stats()["function_rows"]
+        assert rows["misses"] == rows["entries"] == 0 and breaker.used == 0
+
+
+def test_a_filter_that_is_not_the_segments_own_keeps_its_rows_off_the_store(ctx):
+    """The rule segment_mask asks (Filter.cacheable()) decides for a function
+    filter too: a join's mask spans the shard, so a row gated by it is
+    evaluated for every launch."""
+    from elasticsearch_tpu.search.execute import _fs_rows_key
+    from elasticsearch_tpu.search.filters import HasChildFilter
+    from elasticsearch_tpu.search.queries import (HasChildQuery,
+                                                  MatchAllQuery, parse_query)
+
+    fsq = parse_query({"function_score": {
+        "query": {"match_all": {}},
+        "functions": [{"filter": WINDOW, "boost_factor": 2.0}]}})
+    assert _fs_rows_key(fsq, "rows", None, ctx) is not None
+    assert _fs_rows_key(fsq, "script", ("status",), ctx) is not None
+    join = HasChildFilter(HasChildQuery("doc", MatchAllQuery()))
+    assert not join.cacheable()
+    fsq.functions[0].filter = join
+    assert _fs_rows_key(fsq, "rows", None, ctx) is None
+    assert _fs_rows_key(fsq, "script", ("status",), ctx) is None
+
+
+def test_a_store_the_breaker_refuses_serves_the_hosts_rows(ctx):
+    body = {"query": {"function_score": {
+        "query": {"match_all": {}}, "functions": FS_FUNCTIONS["rows"],
+        "score_mode": "sum"}}, "size": 25}
+    with _row_store(ctx, budget="100b") as (fc, breaker):
+        answers = [_fs_both(ctx, body, script=False) for _ in range(3)]
+        assert [d["fs_rows_resident"] for _dev, d in answers] == [0, 0, 0]
+        assert all(d["fs_row_put_bytes"] == _row_bytes(ctx, "rows")
+                   for _dev, d in answers)
+        rows = fc.stats()["function_rows"]
+        assert rows["rejections"] == 2 * len(ctx.searcher.segments)
+        assert rows["entries"] == 0 and breaker.used == 0
