@@ -1715,6 +1715,8 @@ class ActionModule:
             (self.QUERY_ATTEMPT_TIMEOUT + self.QUERY_PROGRESS_TIMEOUT)
             * (max(1, max_chain) + self.QUERY_ATTEMPT_EXTENSIONS))
         collect_by = time.monotonic() + backstop + 5.0
+        span = tracing.current_span()  # sampled: the wake-up after the answers
+        answered = None  # where the last round-trip waited for ended
         for ordinal, (copy, fut) in enumerate(zip(shards, query_futs)):
             if fut is None:
                 failures.append({"index": copy.index, "shard": copy.shard_id,
@@ -1729,6 +1731,9 @@ class ActionModule:
                 cancel = getattr(fut, "cancel_chain", None)
                 if cancel is not None:
                     cancel()  # abandoned chain must not keep scheduling attempts
+            if span:
+                answered = tracing.later(answered,
+                                         getattr(fut, "answered_at", None))
             if r is not None:
                 shard_meta[ordinal] = (copy.index, r.shard_id, used, r.context_id)
                 r.shard_id = ordinal
@@ -1765,6 +1770,7 @@ class ActionModule:
                     self.admission.observe(
                         getattr(fut, "completed_at", time.monotonic())
                         - t_fanout)
+        tracing.record_wake(span, answered, "transport")
         return results, failures, chain_terminals, shard_meta
 
     def _finish_search(self, req, body, results, failures, shards, shard_meta, t0,
@@ -1808,6 +1814,8 @@ class ActionModule:
         fetched: dict[int, dict] = {}
         fetch_failed = 0
         fetch_futs = []
+        span = tracing.current_span()  # sampled: coordinator.fetch
+        answered = None  # where the last round-trip waited for ended
         for ordinal, entries in by_shard.items():
             index_name, real_shard, node, ctx_id = shard_meta[ordinal]
             fetch_futs.append(((ordinal, entries), self.transport.send_request(
@@ -1830,8 +1838,14 @@ class ActionModule:
                                  "reason": f"fetch phase failed: {e}"})
                 fetch_failed += 1
                 continue
+            if span:
+                # the shard's `shard.fetch` rides its response, as the query
+                # phase's span list does
+                span.trace.add_remote(r.get("spans"))
+                answered = tracing.later(answered, tracing.round_trip_end(fut))
             for (rank, *_), hit in zip(entries, r["hits"]):
                 fetched[rank] = hit
+        tracing.record_wake(span, answered, "transport")
         # release pinned contexts of shards that contributed no fetched hits
         # (fire-and-forget, like the reference's free-context after the merge)
         for ordinal, meta in shard_meta.items():
@@ -2196,6 +2210,9 @@ class ActionModule:
                                          if isinstance(r, dict) else None)
                     if trace_ref is not None and isinstance(r, dict):
                         trace_ref.add_remote(r.get("spans"))
+                        # where this round-trip ended, for the coordinator's
+                        # wake-up (written before `done` resolves)
+                        done.answered_at = tracing.round_trip_end(f)  # type: ignore[attr-defined]
                     prof = r.get("profile")
                     if isinstance(prof, dict):
                         # ?profile=true: record whether this shard's profile
@@ -2298,6 +2315,15 @@ class ActionModule:
                             filter_cache=getattr(self.node, "filter_cache",
                                                  None))
 
+    def _continue_trace(self, request, name: str):
+        """The sender's trace continued on this shard's node from the wire
+        context, rooted at `name`; NOOP_TRACE where the request carries none
+        (the sender injects one for sampled traces only)."""
+        tracer = getattr(self.node, "tracer", None)
+        if tracer is None:
+            return tracing.NOOP_TRACE
+        return tracer.continue_trace(request.get(tracing.TRACE_WIRE_KEY), name)
+
     def _s_query_phase(self, request, channel):
         index, shard_id = request["index"], request["shard"]
         body = dict(request.get("body") or {})
@@ -2322,10 +2348,7 @@ class ActionModule:
         # shard span covers it (`shard.lower` is cut from its start); the
         # shard span is the parent every batcher span of this request
         # attaches to
-        tracer = getattr(self.node, "tracer", None)
-        trace = tracer.continue_trace(request.get(tracing.TRACE_WIRE_KEY),
-                                      "shard") if tracer is not None \
-            else tracing.NOOP_TRACE
+        trace = self._continue_trace(request, "shard")
         shard_span = trace.root.tag(index=index, shard=shard_id)
         try:
             req = parse_search_body(body)
@@ -2596,16 +2619,29 @@ class ActionModule:
                 return
 
     def _s_fetch_phase(self, request, channel):
-        # the pinned query-time context when available (expired/restarted nodes
-        # fall back to a fresh searcher — best effort, like a lost scroll)
-        ctx = self._take_pinned(request.get("ctx"), request["index"],
-                                request["shard"]) \
-            or self._shard_ctx(request["index"], request["shard"])
-        req = parse_search_body(request.get("body") or {})
-        docs = [(s, d, sv) for s, d, sv in request["docs"]]
-        hits = execute_fetch_phase(ctx, req, docs, index_name=request["index"],
-                                   shard_id=request["shard"])
-        return {"hits": hits}
+        # a sampled search's fetch continues its trace from the wire context,
+        # as the query phase does: `shard.fetch` runs from the handler's
+        # entry until the hits are built, and its span list rides the
+        # response (an unsampled request carries no context: NOOP_TRACE)
+        trace = self._continue_trace(request, "shard.fetch")
+        try:
+            # the pinned query-time context when available (expired/restarted
+            # nodes fall back to a fresh searcher — best effort, like a lost
+            # scroll)
+            ctx = self._take_pinned(request.get("ctx"), request["index"],
+                                    request["shard"]) \
+                or self._shard_ctx(request["index"], request["shard"])
+            req = parse_search_body(request.get("body") or {})
+            docs = [(s, d, sv) for s, d, sv in request["docs"]]
+            hits = execute_fetch_phase(ctx, req, docs,
+                                       index_name=request["index"],
+                                       shard_id=request["shard"])
+        finally:
+            trace.root.end()
+        out = {"hits": hits}
+        if trace:
+            out["spans"] = trace.span_dicts()
+        return out
 
     def _s_dfs_phase(self, request, channel):
         ctx = self._shard_ctx(request["index"], request["shard"])

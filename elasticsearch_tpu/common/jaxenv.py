@@ -116,6 +116,11 @@ def enable_persistent_compile_cache() -> None:
 _COMPILE_EVENT_SUBSTR = "backend_compile"
 # the persistent compilation cache's own plain events (jax/_src/compiler.py,
 # compilation_cache.py): a retrieval that succeeded, one that did not
+# what a first sighting stalls its thread for BEFORE the backend compile:
+# tracing the function to a jaxpr, and lowering that to an MLIR module. Kept
+# as seconds beside the compile's; not compile events (nothing counts them)
+_STALL_EVENTS = {"/jax/core/compile/jaxpr_trace_duration": "trace_s",
+                 "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s"}
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
 
@@ -248,6 +253,10 @@ class _CompileCounter:
         # beside the persistent cache's own hit/miss events
         self.seconds = 0.0
         self.seconds_by_family: dict = {}
+        # the seconds of tracing and of lowering that came before the
+        # compiles (_STALL_EVENTS): with `seconds`, what first sightings
+        # stalled their threads for
+        self.stall_s = {"trace_s": 0.0, "lower_s": 0.0}
         self.cache_hits = 0
         self.cache_misses = 0
         # untagged-compile origin sites ("path:line" -> count), recorded only
@@ -267,6 +276,10 @@ class _CompileCounter:
 
     def _listener(self, key: str, duration: float, **_kw) -> None:
         if _COMPILE_EVENT_SUBSTR not in key:
+            part = _STALL_EVENTS.get(key)
+            if part is not None:
+                with self._lock:
+                    self.stall_s[part] += duration
             return
         family = getattr(_tag_local, "tag", None) or "untagged"
         # stack walk OUTSIDE the lock — frame inspection is slow-path work and
@@ -377,7 +390,10 @@ def compile_seconds() -> dict:
     """What the counted compile events cost: `seconds` whole and
     `seconds_by_family` (sums to it), plus the persistent compilation cache's
     `cache_hits` / `cache_misses` — the `/_nodes/stats` `device.compile`
-    fields beside `total` and `by_family`."""
+    fields beside `total` and `by_family`. `trace_s` and `lower_s` are the
+    seconds of jaxpr tracing and of lowering to MLIR that came before those
+    compiles, and `stall_s` their sum with `seconds`: what first sightings
+    really stalled their threads for."""
     try:
         _counter.ensure_installed()
     except Exception:  # noqa: BLE001 — no jax in this process: zeros
@@ -385,6 +401,8 @@ def compile_seconds() -> dict:
     with _counter._lock:
         return {"seconds": _counter.seconds,
                 "seconds_by_family": dict(_counter.seconds_by_family),
+                **_counter.stall_s,
+                "stall_s": _counter.seconds + sum(_counter.stall_s.values()),
                 "cache_hits": _counter.cache_hits,
                 "cache_misses": _counter.cache_misses}
 

@@ -222,6 +222,34 @@ def child_scope(parent, name: str, **tags):
     return _ChildScope(parent.child(name).tag(**tags))
 
 
+def round_trip_end(fut) -> float | None:
+    """Where the transport round-trip behind `fut` ended: the end of the
+    `transport[...]` span that send_request left on the future. None for an
+    unsampled request, and for a remote answer whose span has not ended yet
+    when the waiter asks (the local transport ends it before it resolves the
+    future, transport/service.py `respond`)."""
+    span = getattr(fut, "trace_span", None)
+    return None if span is None else span.t1
+
+
+def later(a: float | None, b: float | None) -> float | None:
+    """The later of two instants, either of which may be unknown."""
+    if a is None or (b is not None and b > a):
+        return b
+    return a
+
+
+def record_wake(span, since: float | None, after: str) -> None:
+    """`thread.wake` under `span`, on the thread that waited: from `since`,
+    the instant another thread finished what this one waited for (`after`:
+    the batcher's drainer resolving the item, or the last transport
+    round-trip ending), to now, when this thread runs again. With one
+    interpreter lock that is the hand-over: the waker's remaining slice and
+    whoever else was woken first. An unsampled request pays the truth test."""
+    if span and since is not None:
+        span.record("thread.wake", since, time.monotonic(), after=after)
+
+
 def wire_context(span) -> TraceContext | None:
     """The context to ship with an outbound request parented at `span` —
     the ONE construction site for the wire shape (transport injection and

@@ -30,9 +30,32 @@ class HttpServer:
         # the socket. The request's span tree closes before this, so it is
         # timed here for every request (`/_nodes/stats` `http.respond`)
         respond = self.respond = HistogramMetric()
+        # the handler threads, one a connection, for `/_nodes/stats`
+        # runtime.cpu.threads.http_s: the kernel's ids of the live ones
+        # (monitor.cpu_stats reads their CPU seconds from /proc when stats
+        # are asked) and the seconds of those that have ended, each booked
+        # once as its connection closes. A request pays no clock read
+        self.live_threads: set[int] = set()
+        self.retired_cpu_s = 0.0
+        self.threads_lock = threading.Lock()  # leaf: a set and a float
+        server = self
 
         class Handler(BaseHTTPRequestHandler):
             protocol_version = "HTTP/1.1"
+
+            def setup(self):
+                super().setup()
+                with server.threads_lock:
+                    server.live_threads.add(threading.get_native_id())
+
+            def finish(self):
+                try:
+                    super().finish()
+                finally:
+                    cpu = time.thread_time()  # the connection's thread ends here
+                    with server.threads_lock:
+                        server.live_threads.discard(threading.get_native_id())
+                        server.retired_cpu_s += cpu
 
             def _handle(self, method: str):
                 t_arrival = time.monotonic()
@@ -153,6 +176,12 @@ class HttpServer:
         self._thread.start()
         self.logger.info("http listening on %s:%d", self.host, self.port)
         return self
+
+    def threads(self) -> tuple[list, float]:
+        """(kernel ids of the live handler threads, CPU seconds of the ended
+        ones), one consistent reading."""
+        with self.threads_lock:
+            return list(self.live_threads), self.retired_cpu_s
 
     def stats(self) -> dict:
         """`/_nodes/stats` `http`: `respond` is response encode + socket

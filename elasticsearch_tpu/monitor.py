@@ -124,8 +124,64 @@ def fs_stats(paths: list[str]) -> dict:
     return {"timestamp": int(time.time() * 1000), "data": data}
 
 
-def runtime_stats() -> dict:
-    """The "jvm stats" analogue: Python runtime + (when live) the TPU device."""
+# the pools whose threads a search crosses, each a role of its own under
+# runtime.cpu.threads; every other pool's seconds are summed as `other`
+_CPU_ROLES = ("search", "generic", "search_batcher")
+_TICK_S = 1.0 / os.sysconf("SC_CLK_TCK")
+
+
+def thread_cpu_s(native_id: int, proc: str = "/proc") -> float:
+    """CPU seconds one live thread of this process has had so far: the
+    nanoseconds on a CPU of /proc/self/task/<tid>/schedstat or, where the
+    kernel keeps none, utime + stime of its `stat` in clock ticks; 0.0 for a
+    thread that is gone."""
+    base = os.path.join(proc, "self", "task", str(native_id))
+    try:
+        with open(os.path.join(base, "schedstat")) as fh:
+            return int(fh.read().split()[0]) / 1e9
+    except (OSError, ValueError, IndexError):
+        pass
+    try:
+        with open(os.path.join(base, "stat")) as fh:
+            fields = fh.read().rsplit(")", 1)[1].split()  # after "(comm)"
+        return (int(fields[11]) + int(fields[12])) * _TICK_S
+    except (OSError, ValueError, IndexError):
+        return 0.0
+
+
+def cpu_stats(node=None, proc: str = "/proc") -> dict:
+    """`runtime.cpu`: CPU seconds of the process (time.process_time()) and of
+    the node's Python threads by role: the HTTP handlers (one thread a
+    connection; those that have ended booked their seconds as they closed,
+    http/server.py), the `search` and `generic` pools' workers, the batcher's
+    drainer (the one worker of `search_batcher`) and the other pools'. All of
+    it is read here, when stats are asked, each live thread's from /proc by
+    the kernel's id of it (thread_cpu_s): the serving path pays no clock read
+    (time.thread_time() around every pool task and request, as first built,
+    cost `wiki.filtered` 5-7% of its searches a second on the chip's host:
+    PERF.md section 6, PR 37). Thread CPU time counts C code that has let the
+    interpreter lock go (large numpy calls, socket reads and writes) as well:
+    the threads' sum is an upper bound on the seconds the lock was held. What
+    `process_s` reads over that sum is the runtime's own threads (transfers,
+    the compiler, the profiler) and threads outside the pools."""
+    threads = dict.fromkeys(("http", *_CPU_ROLES, "other"), 0.0)
+    http = getattr(node, "http", None)
+    if http is not None:
+        live, retired_s = http.threads()
+        threads["http"] = retired_s + sum(thread_cpu_s(t, proc) for t in live)
+    pools = getattr(node, "threadpool", None)
+    if pools is not None:
+        for name, ids in pools.thread_ids().items():
+            role = name if name in _CPU_ROLES else "other"
+            threads[role] += sum(thread_cpu_s(t, proc) for t in ids)
+    return {"process_s": time.process_time(),
+            "threads": {role + "_s": v for role, v in threads.items()}}
+
+
+def runtime_stats(node=None) -> dict:
+    """The "jvm stats" analogue: Python runtime + (when live) the TPU device.
+    With a `node`, also what its threads cost and wait for: `cpu`
+    (cpu_stats) and `gil` (threadpool.ThreadPool.gil_stats)."""
     import sys
 
     counts = gc.get_count()
@@ -138,7 +194,11 @@ def runtime_stats() -> dict:
                # seconds inside those full collections, since the hook went in
                "pause_s": GC_PAUSES.pause_s},
         "uptime_in_millis": int(time.monotonic() * 1000),
+        "cpu": cpu_stats(node),
     }
+    pools = getattr(node, "threadpool", None)
+    if pools is not None:
+        out["gil"] = pools.gil_stats()
     try:
         import jax
 
@@ -178,7 +238,7 @@ class MonitorService:
             "os": os_stats,
             "process": process_stats,
             "fs": lambda: fs_stats([self.node.data_path]),
-            "runtime": runtime_stats,
+            "runtime": lambda: runtime_stats(self.node),
         }
 
     def full_stats(self) -> dict:

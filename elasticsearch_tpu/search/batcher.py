@@ -93,7 +93,7 @@ def _k_bucket(k: int) -> int:
 
 class _Item:
     __slots__ = ("family", "key", "payload", "k", "kb", "deadline", "future",
-                 "t_enq", "span", "obs")
+                 "t_enq", "t_done", "span", "obs")
 
     def __init__(self, family, key, payload, k: int, kb: int,
                  deadline: Deadline):
@@ -105,6 +105,10 @@ class _Item:
         self.deadline = deadline
         self.future: Future = Future()
         self.t_enq = time.monotonic()
+        # where the drainer finished the item's batch (the end of its
+        # batcher.merge), stamped before the future resolves: a sampled
+        # waiter's wake-up starts there. None where no merge resolved it
+        self.t_done: float | None = None
         # the enqueuing request's active span (None when untraced): the
         # drainer attributes the shared batch's queue/dispatch/merge/pull
         # timings back to EVERY member's trace through this handle
@@ -289,6 +293,9 @@ class DeviceBatcher:
         self._state_s = {"wait": 0.0, "linger": 0.0, "dispatch": 0.0,
                          "merge": 0.0, "pull": 0.0}
         self._t_state = 0.0
+        # the same partition in seconds of the drainer thread's CPU (_tick)
+        self._cpu_s = dict.fromkeys(self._state_s, 0.0)
+        self._t_cpu = 0.0
         self._batches = 0
         # the drainer's phases as annotations on the profiler's own clock (its
         # host plane, beside `XLA Ops`) for EVERY batch: a flag check each
@@ -349,7 +356,9 @@ class DeviceBatcher:
         # generous slack past the deadline: the flush logic targets the
         # deadline itself, this wait only guards against a wedged drainer
         timeout = None if remaining is None else remaining + 30.0
-        return item.future.result(timeout=timeout)
+        result = item.future.result(timeout=timeout)
+        tracing.record_wake(item.span, item.t_done, "batcher")
+        return result
 
     # -- drainer -------------------------------------------------------------
     def _ensure_drainer(self):
@@ -361,8 +370,13 @@ class DeviceBatcher:
             self._drainer_started = True
         if self._threadpool is not None:
             try:
-                # a named pool so the drainer shows in /_nodes/stats thread_pool
-                self._threadpool.submit("search_batcher", self._drain_loop)
+                # a named pool so the drainer shows in /_nodes/stats thread_pool.
+                # Submitted with no span current: the pool records a sampled
+                # submitter's wait under its span (`pool.wait`), and the
+                # drainer's start is no part of the search that happened to
+                # come first (its own wait is `batcher.queue`)
+                with tracing.activate(None):
+                    self._threadpool.submit("search_batcher", self._drain_loop)
                 return
             except Exception:  # noqa: BLE001 — pool missing/closed: plain thread
                 pass
@@ -383,15 +397,26 @@ class DeviceBatcher:
             self._fail_queued(e)
 
     def _tick(self, state: str, now: float | None = None) -> float:
-        """Book the drainer's time since its last tick to `state`."""
+        """Book the drainer's time since its last tick to `state`: the wall
+        seconds, and beside them the seconds of CPU its thread was given in
+        them (time.thread_time(), so a state's CPU seconds under its wall
+        seconds are what the drainer stood still for: the device, the
+        condition, the interpreter lock). The `pull` state is carved out of
+        dispatch and merge afterwards from the dispatch clock's wall
+        interval, which has no CPU reading: `cpu.pull_s` stays 0 and the
+        CPU of a pull stays with the state it ran in."""
         now = time.monotonic() if now is None else now
+        cpu = time.thread_time()
         self._state_s[state] += now - self._t_state
+        self._cpu_s[state] += cpu - self._t_cpu
         self._t_state = now
+        self._t_cpu = cpu
         return now
 
     def _drain(self):
         self._t_state = time.monotonic()
-        pending = None  # (family, items, handle, t0) — dispatched, not merged
+        self._t_cpu = time.thread_time()
+        pending = None  # _finish's arguments — dispatched, not merged
         while True:
             batch = None
             with self._cv:
@@ -481,7 +506,7 @@ class DeviceBatcher:
             self._note_flush(reason)
             if pending is not None:
                 self._finish(*pending)
-            pending = (family, items, handle, t0, batch_id)
+            pending = (family, items, handle, t0, batch_id, t_disp)
             with self._cv:
                 queue_empty = not self._queue
             if queue_empty:
@@ -544,8 +569,13 @@ class DeviceBatcher:
         self._queue.extend(rest)
         return taken, reason
 
-    def _finish(self, family, items, handle, t0: float, batch_id: int = 0):
-        """Merge a dispatched batch and fan results out to the item futures."""
+    def _finish(self, family, items, handle, t0: float, batch_id: int = 0,
+                t_disp: float | None = None):
+        """Merge a dispatched batch and fan results out to the item futures.
+        `t_disp` is where its dispatch ended: what lies between that and
+        this merge is `batcher.hold` in a sampled member's trace, the batch
+        held while the drainer collected and dispatched the NEXT one (the
+        double buffering; tens of microseconds where the queue was empty)."""
         t_m0 = time.monotonic()
         with self._annotate("estpu.batch.merge", batch=batch_id,
                             family=family.name, occupancy=len(items)):
@@ -582,6 +612,9 @@ class DeviceBatcher:
                 it.obs.occupancy = len(items)
             if not it.span:
                 continue
+            it.t_done = t_m1
+            if t_disp is not None:
+                it.span.record("batcher.hold", t_disp, t_m0, batch=batch_id)
             merge_span = it.span.record("batcher.merge", t_m0, t_m1,
                                         batch=batch_id)
             if merged_pull:
@@ -727,6 +760,7 @@ class DeviceBatcher:
         # linger = items queued inside _collect_locked, dispatch / merge less
         # the device_get inside them, which is pull
         out["drainer"] = {**{k + "_s": v for k, v in self._state_s.items()},
+                          "cpu": {k + "_s": v for k, v in self._cpu_s.items()},
                           "batches": self._batches}
         # batch service-time percentiles (HistogramMetric — the tail the EWMA
         # can't show); stripe locks are leaves, summed outside _stats_lock

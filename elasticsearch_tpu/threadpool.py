@@ -21,6 +21,7 @@ import threading
 import time
 from concurrent.futures import Future, ThreadPoolExecutor
 
+from .common import tracing
 from .common.errors import RejectedExecutionError
 from .common.logging import get_logger
 from .common.metrics import HistogramMetric
@@ -97,6 +98,10 @@ _DEFAULT_QUEUES = {
 }
 _DEFAULT_QUEUE_SIZE = 1000
 
+# the period of the wheel's own deadline, whose lateness is the GIL-wait gauge
+# (ThreadPool._timer_loop, _book_probe): twenty wake-ups a second
+_GIL_PROBE_S = 0.05
+
 
 class ScheduledTimer:
     """Handle for one entry on the shared timer wheel — the
@@ -171,8 +176,12 @@ class _BoundedPool:
                         f"(queued [{self.queued}], active [{self.active}])")
             self.queued += 1
         try:
+            # the span current at submit (one thread-local read; None or the
+            # falsy NOOP span where the request is not sampled) rides beside
+            # the submit stamp: the worker records its wait under it
             return self.executor.submit(self._run, fn, args, kwargs,
-                                        time.monotonic())
+                                        time.monotonic(),
+                                        tracing.current_span())
         except RuntimeError:
             # executor shut down — still a rejection, just a terminal one
             with self._lock:
@@ -182,8 +191,12 @@ class _BoundedPool:
                 f"rejected execution on [{self.name}]: pool is shut down") \
                 from None
 
-    def _run(self, fn, args, kwargs, t_submit: float):
-        self.queue_wait.observe(time.monotonic() - t_submit)
+    def _run(self, fn, args, kwargs, t_submit: float, span=None):
+        t_run = time.monotonic()
+        self.queue_wait.observe(t_run - t_submit)
+        if span:
+            # a sampled request's own wait for this pool, submit to pick-up
+            span.record("pool.wait", t_submit, t_run, pool=self.name)
         with self._lock:
             self.queued -= 1
             self.active += 1
@@ -236,6 +249,13 @@ class ThreadPool:
         self._timer_cv = threading.Condition()
         self._scheduler_thread = threading.Thread(target=self._scheduler_loop, daemon=True, name="estpu[scheduler]")
         self._shutdown = threading.Event()
+        # the GIL-wait gauge (`/_nodes/stats` runtime.gil): how late the wheel
+        # woke up for a periodic deadline of its own (_timer_loop). Written by
+        # the wheel's thread, read (and the maximum reset) by gil_stats, both
+        # with the timers' condition held
+        self._gil_probes = 0
+        self._gil_late_s = 0.0
+        self._gil_late_max_s = 0.0
         self._scheduler_thread.start()
         self._timer_thread = threading.Thread(target=self._timer_loop,
                                               daemon=True,
@@ -280,7 +300,15 @@ class ThreadPool:
     def _timer_loop(self):
         """The wheel: sleep until the earliest live deadline, then fire it.
         The submit happens OUTSIDE the condition (pool locks are the
-        submit's own; the cv stays a leaf); waits are always timed."""
+        submit's own; the cv stays a leaf); waits are always timed.
+
+        The wheel also keeps one periodic deadline of its own, every
+        _GIL_PROBE_S, which fires nothing: it books how late it woke up for
+        it (_book_probe). The deadline is no entry of the heap: a live head
+        that is always 50 ms away would keep the cancelled timers behind it
+        (every search leaves one, armed for a minute) from ever being
+        dropped."""
+        probe_at = time.monotonic() + _GIL_PROBE_S
         while True:
             with self._timer_cv:
                 while not self._shutdown.is_set():
@@ -290,11 +318,15 @@ class ThreadPool:
                             self._timer_heap[0][2].finished.is_set():
                         heapq.heappop(self._timer_heap)
                     now = time.monotonic()
+                    if now >= probe_at:
+                        self._book_probe(now - probe_at)
+                        # from now, so a late probe is not followed by a burst
+                        probe_at = now + _GIL_PROBE_S
                     if self._timer_heap and self._timer_heap[0][0] <= now:
                         break
-                    self._timer_cv.wait(
-                        min(self._timer_heap[0][0] - now, 60.0)
-                        if self._timer_heap else 60.0)
+                    due = self._timer_heap[0][0] if self._timer_heap \
+                        else probe_at
+                    self._timer_cv.wait(min(due, probe_at) - now)
                 if self._shutdown.is_set():
                     return
                 _deadline, _seq, t = heapq.heappop(self._timer_heap)
@@ -315,6 +347,37 @@ class ThreadPool:
                 # the wheel keeps that property by containing them here.
                 logger.warning("timer fire failed (pool=%s)", t.pool,
                                exc_info=True)
+
+    def _book_probe(self, late_s: float):
+        """One reading of the GIL-wait gauge, booked with the timers'
+        condition held: `late_s` is the wheel's clock at its wake-up, read in
+        its loop before any pool hop, less the periodic deadline it slept to.
+        On an idle node that is the kernel timer's slack; while other threads
+        hold the interpreter lock it is what a woken thread waits to run
+        again, which every hand-over of a search between threads pays."""
+        self._gil_probes += 1
+        self._gil_late_s += late_s
+        self._gil_late_max_s = max(self._gil_late_max_s, late_s)
+
+    def gil_stats(self) -> dict:
+        """`/_nodes/stats` runtime.gil: `late_s` over `probes` is the mean
+        wait of a woken thread; `late_max_s` is the largest since the last
+        read (a stall of seconds shows in it, and a read resets it)."""
+        with self._timer_cv:
+            out = {"probes": self._gil_probes, "late_s": self._gil_late_s,
+                   "late_max_s": self._gil_late_max_s}
+            self._gil_late_max_s = 0.0
+        return out
+
+    def thread_ids(self) -> dict:
+        """name → the kernel's ids of the pool's live worker threads: what
+        `/_nodes/stats` runtime.cpu reads each role's CPU seconds by, from
+        /proc and when stats are asked (monitor.cpu_stats), so that a task
+        pays no clock read for it."""
+        return {name: [t.native_id
+                       for t in list(getattr(pool.executor, "_threads", ()))
+                       if t.native_id is not None]
+                for name, pool in self._pools.items()}
 
     def schedule_with_fixed_delay(self, interval_s: float, fn, name: str = "generic") -> _ScheduledTask:
         task = _ScheduledTask(interval_s, fn, lambda f: self.submit(name, f))

@@ -155,6 +155,10 @@ class TransportService:
             # Span.end is idempotent and only appends under the trace's leaf
             # lock, so the callback is safe from any resolving thread
             fut.add_done_callback(lambda _f: tspan.end())
+            # the span rides the future: _send_now records the codec and the
+            # pool hops under it, and the waiter reads where the round-trip
+            # ended off it (tracing.round_trip_end): its wake-up starts there
+            fut.trace_span = tspan  # type: ignore[attr-defined]
         if timeout is not None:
             self._arm_response_timeout(fut, action, timeout)
         try:
@@ -214,27 +218,69 @@ class TransportService:
 
         fut.add_done_callback(on_done)
 
+    def _wire_copy(self, request: dict, action: str, fut: Future, tspan):
+        """The request as the receiver reads it: encoded, charged to the
+        in-flight breaker by its encoded size, decoded again. A sampled
+        request (`tspan`, its transport span) records the round trip as
+        `transport.codec`; any other pays one truth test."""
+        t0 = time.monotonic() if tspan else 0.0
+        raw = _encode(request)
+        self._charge_in_flight(raw, action, fut)
+        payload = StreamInput(raw).read_value()
+        if tspan:
+            tspan.record("transport.codec", t0, time.monotonic())
+        return payload
+
+    def _dispatch_under(self, tspan, action: str, request: Any,
+                        channel: TransportChannel):
+        """`dispatch` of a sampled local request on its `generic` thread,
+        with the sender's transport span current: the hop to the handler's
+        own pool is submitted under it, and that pool records the wait there
+        (threadpool._BoundedPool.submit keeps the span current at submit).
+        The handler itself runs on its pool's thread with no span current
+        and continues the trace from the wire context, as a remote one does."""
+        with tracing.activate(tspan):
+            self.dispatch(action, request, channel)
+
     def _send_now(self, node, action: str, request: dict, fut: Future):
+        tspan = getattr(fut, "trace_span", None)  # sampled: its transport span
         # Self-addressed requests short-circuit past the backend (the reference
         # TransportService does the same for localNode): still codec-roundtripped
         # for wire-compat assertions, but no socket / simulated-network hop.
         if self._is_local(node):
-            raw = _encode(request)
-            self._charge_in_flight(raw, action, fut)
-            payload = StreamInput(raw).read_value()
+            payload = self._wire_copy(request, action, fut, tspan)
 
             def respond(response, error):
                 if error is not None:
                     complete_fut(fut, error=error)
+                elif tspan:
+                    # the responder's thread: the response's round trip, and
+                    # the transport span ends with it, BEFORE the future
+                    # resolves — so the waiter's wake-up starts at an instant
+                    # that is written before it can wake (the done-callback's
+                    # own end() is then a no-op)
+                    t0 = time.monotonic()
+                    response = _roundtrip(response)
+                    t1 = time.monotonic()
+                    tspan.record("transport.codec", t0, t1)
+                    tspan.end(t1)
+                    complete_fut(fut, response)
                 else:
                     complete_fut(fut, _roundtrip(response))
 
             channel = TransportChannel(respond)
-            if self.threadpool is not None:
+            if self.threadpool is None:
+                self.dispatch(action, payload, channel)
+            elif tspan:
+                # both pool hops of a sampled request, `generic` here and the
+                # handler's own in _dispatch_now, record their wait under
+                # the transport span
+                with tracing.activate(tspan):
+                    self.threadpool.submit("generic", self._dispatch_under,
+                                           tspan, action, payload, channel)
+            else:
                 self.threadpool.submit("generic", self.dispatch, action, payload,
                                        channel)
-            else:
-                self.dispatch(action, payload, channel)
             return
         # Backends that truly serialize (TCP) skip the assert-roundtrip AND
         # this layer's breaker charge — double-encoding just for a size would
@@ -244,9 +290,7 @@ class TransportService:
         if getattr(self.backend, "serializes", False):
             payload = request
         else:
-            raw = _encode(request)
-            self._charge_in_flight(raw, action, fut)
-            payload = StreamInput(raw).read_value()
+            payload = self._wire_copy(request, action, fut, tspan)
         self.backend.send(node, action, payload, fut)
 
     def _apply_send_fault(self, rule, fut: Future, node, action: str,

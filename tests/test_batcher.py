@@ -457,7 +457,7 @@ class TestDrainerStates:
             d1, s1 = _drainer(b)
             elapsed = time.monotonic() - t0
             assert set(d1) == {"wait_s", "linger_s", "dispatch_s", "merge_s",
-                               "pull_s", "batches"}
+                               "pull_s", "cpu", "batches"}
             # every second since the first reading is in exactly one state;
             # the reading itself is at most one idle tick (0.1 s) stale
             assert abs((s1 - s0) - elapsed) <= 0.12, (s1 - s0, elapsed)
@@ -503,6 +503,81 @@ class TestDrainerStates:
             assert d["dispatch_s"] > 0.0 and d["merge_s"] == 0.0
         finally:
             b.shutdown()
+
+
+    def test_cpu_seconds_rise_with_the_wall_seconds_and_stay_under_them(
+            self, shard_ctx):
+        """The drainer's thread CPU seconds, booked beside the wall seconds
+        (PR 37): both rise over a batch, the CPU never passes the wall by
+        more than a tick of the clock, and an idle drainer is given none."""
+        tick = 0.02  # CLOCK_THREAD_CPUTIME_ID is accounted a scheduler tick late
+        busy = ("linger_s", "dispatch_s", "merge_s", "pull_s")
+        b = make_batcher(**{"search.batch.linger_ms": 20,
+                            "search.batch.max_batch": 4})
+        try:
+            texts = ["quick brown", "lazy dog", "red bear", "summer snack"]
+            run_concurrent(b, shard_ctx, texts)  # starts the drainer, compiles
+            d0, _ = _drainer(b)
+            assert set(d0["cpu"]) == {"wait_s", *busy}
+            for _ in range(3):
+                run_concurrent(b, shard_ctx, texts)
+            time.sleep(0.25)  # an idle tick closes the last wait
+            d1, _ = _drainer(b)
+            wall = sum(d1[k] - d0[k] for k in busy)
+            cpu = sum(d1["cpu"][k] - d0["cpu"][k] for k in busy)
+            # the pull is carved out of dispatch and merge on the wall side
+            # alone, so the states compare as a sum (and cpu.pull_s stays 0)
+            assert 0.0 < cpu <= wall + tick, (cpu, wall)
+            assert d1["cpu"]["pull_s"] == 0.0 and d1["pull_s"] > 0.0
+            for k in ("wait_s", *busy):
+                assert d1["cpu"][k] >= d0["cpu"][k] >= 0.0, k
+            assert d1["cpu"]["wait_s"] - d0["cpu"]["wait_s"] <= \
+                d1["wait_s"] - d0["wait_s"] + tick
+        finally:
+            b.shutdown()
+
+    @pytest.mark.parametrize("sampled", [True, False],
+                             ids=["sampled", "unsampled"])
+    def test_a_sampled_waiter_records_its_wake_up(self, shard_ctx, sampled):
+        """thread.wake (PR 37): from where the drainer finished the item's
+        batch, the end of its batcher.merge, to the request's thread running
+        again; an unsampled item is not stamped and records nothing."""
+        from elasticsearch_tpu.common import tracing
+        from elasticsearch_tpu.common.tracing import Tracer
+
+        tracer = Tracer(Settings.from_flat({"search.trace.sample_rate": "0"}),
+                        node_name="test")
+        b = make_batcher()
+        stamped = []
+        real_submit = b._submit
+
+        def submit(item):
+            try:
+                return real_submit(item)
+            finally:
+                stamped.append(item.t_done)
+
+        b._submit = submit
+        try:
+            trace = tracer.start_trace("shard", force=sampled)
+            with tracing.activate(trace.root):
+                b.execute(plan_for(shard_ctx, "quick brown"), shard_ctx, 10)
+            trace.root.end()
+        finally:
+            b.shutdown()
+        spans = {s["name"]: s for s in trace.span_dicts()}
+        if not sampled:
+            assert spans == {} and stamped == [None]
+            return
+        wake, merge = spans["thread.wake"], spans["batcher.merge"]
+        # what lies between the dispatch and the merge has a name too
+        hold = spans["batcher.hold"]
+        assert hold["t0"] == spans["batcher.dispatch"]["t1"]
+        assert hold["t1"] == merge["t0"] and hold["parent"] == merge["parent"]
+        assert wake["tags"] == {"after": "batcher"}
+        assert wake["parent"] == merge["parent"] == trace.root.span_id
+        assert stamped == [merge["t1"]] and wake["t0"] == merge["t1"]
+        assert wake["t1"] >= wake["t0"] and wake["t1"] <= spans["shard"]["t1"]
 
 
 # ---------------------------------------------------------------------------
@@ -847,7 +922,7 @@ class TestKindsShareACollect:
         for tree in trees:
             top = [c["name"] for c in tree["children"]]
             assert top == ["shard.lower", "batcher.queue", "batcher.dispatch",
-                           "batcher.merge"], top
+                           "batcher.hold", "batcher.merge", "thread.wake"], top
             (dispatch,) = [c for c in tree["children"]
                            if c["name"] == "batcher.dispatch"]
             assert dispatch["tags"]["occupancy"] == 2

@@ -755,7 +755,8 @@ class TestLaunchTimeline:
         _assert_nested(tree)
         (shard,) = _find(tree, "shard")
         assert [c["name"] for c in shard["children"]] == [
-            "shard.lower", "batcher.queue", "batcher.dispatch", "batcher.merge"]
+            "shard.lower", "batcher.queue", "batcher.dispatch", "batcher.hold",
+            "batcher.merge", "thread.wake"]
         (dispatch,) = _find(tree, "batcher.dispatch")
         kinds = [c["name"] for c in dispatch["children"]]
         assert kinds == ["dispatch.stage", "dispatch.launch", "device_pull"]
@@ -1061,6 +1062,264 @@ class TestLaunchTimeline:
             time.sleep(0.01)
         assert respond["count"] >= 1 and respond["sum_s"] > 0
         assert respond["p99_ms"] >= respond["p50_ms"] > 0
+
+
+# ---------------------------------------------------------------------------
+# the hand-overs between threads: pool waits, wake-ups, the local transport's
+# codec, the fetch phase; the host runtime's counters (PR 37)
+# ---------------------------------------------------------------------------
+
+QUERY_ACTION = "transport[indices:data/read/search[phase/query]]"
+FETCH_ACTION = "transport[indices:data/read/search[phase/fetch]]"
+# the spans that name work or a wait (`host_unnamed_ms` subtracts them from
+# the root); every other span is a container of these
+NAMED = ("rest.parse", "coordinator.plan", "coordinator.reduce",
+         "coordinator.render", "shard.lower", "shard.fetch", "pool.wait",
+         "thread.wake", "transport.codec", "batcher.queue",
+         "batcher.dispatch", "batcher.hold", "batcher.merge", "device_pull")
+HANDOVERS = ("pool.wait", "thread.wake", "transport.codec", "shard.fetch",
+             "batcher.hold")
+
+
+def _descendants(n):
+    return [d for c in n["children"] for d in _flatten(c)]
+
+
+class TestHostHandovers:
+    @pytest.mark.parametrize("pool", ["generic", "search"])
+    @pytest.mark.parametrize("action", [QUERY_ACTION, FETCH_ACTION],
+                             ids=["query", "fetch"])
+    def test_each_pool_hop_of_each_phase_records_its_wait(self, live, action,
+                                                          pool):
+        _cluster, _node, rc = live
+        tree = _traced_search(rc, SEARCH_BODY)
+        (tspan,) = _find(tree, action)
+        waits = [c for c in tspan["children"] if c["name"] == "pool.wait"]
+        assert [w["tags"]["pool"] for w in waits] == ["generic", "search"]
+        (wait,) = [w for w in waits if w["tags"]["pool"] == pool]
+        assert tspan["t0"] <= wait["t0"] <= wait["t1"] <= tspan["t1"]
+        # the handler runs after both hops: its span starts behind them
+        handler = "shard" if action == QUERY_ACTION else "shard.fetch"
+        (served,) = [c for c in tspan["children"] if c["name"] == handler]
+        assert wait["t1"] <= served["t0"]
+
+    @pytest.mark.parametrize("action", [QUERY_ACTION, FETCH_ACTION],
+                             ids=["query", "fetch"])
+    def test_both_round_trips_of_a_phase_record_the_codec(self, live, action):
+        _cluster, _node, rc = live
+        tree = _traced_search(rc, SEARCH_BODY)
+        assert len(_find(tree, "transport.codec")) == 4
+        (tspan,) = _find(tree, action)
+        request, response = [c for c in tspan["children"]
+                             if c["name"] == "transport.codec"]
+        # the request's before the first pool hop, the response's last: the
+        # transport span ends with it
+        assert request["t1"] <= tspan["children"][1]["t0"]
+        assert tspan["children"][0] is request
+        assert tspan["children"][-1] is response
+        assert response["t1"] == tspan["t1"]
+
+    def test_the_fetch_phase_continues_the_trace(self, live):
+        _cluster, node, rc = live
+        tree = _traced_search(rc, SEARCH_BODY)
+        (fetch,) = _find(tree, "shard.fetch")
+        (tspan,) = _find(tree, FETCH_ACTION)
+        assert fetch["parent"] == tspan["id"] and fetch["node"] == node.name
+        assert fetch in tspan["children"]
+
+    @pytest.mark.parametrize("after,parents", [
+        ("batcher", ["shard"]),
+        ("transport", ["coordinator.query", "coordinator.fetch"])])
+    def test_a_waiting_thread_records_its_wake_up(self, live, after, parents):
+        _cluster, _node, rc = live
+        tree = _traced_search(rc, SEARCH_BODY)
+        by_id = {n["id"]: n for n in _flatten(tree)}
+        wakes = [n for n in _find(tree, "thread.wake")
+                 if n["tags"]["after"] == after]
+        assert [by_id[w["parent"]]["name"] for w in wakes] == parents
+        for w in wakes:
+            parent = by_id[w["parent"]]
+            before = [c for c in parent["children"] if c["t0"] < w["t0"]][-1]
+            if after == "batcher":
+                # from where the drainer finished the batch
+                assert before["name"] == "batcher.merge"
+            else:
+                # from where the phase's round-trip ended
+                assert before["name"].startswith("transport[")
+            assert w["t0"] == before["t1"]
+
+    @pytest.mark.parametrize("body", [SEARCH_BODY, FILTERED_BODY],
+                             ids=["plain", "filtered"])
+    def test_named_spans_nest_and_never_overlap(self, live, body):
+        """Every span lies inside its parent, and of the spans that name work
+        or a wait no two cover the same instant of one search unless one
+        holds the other (a pull inside its merge or its dispatch): their
+        medians may be added up."""
+        _cluster, _node, rc = live
+        _traced_search(rc, body)
+        tree = _traced_search(rc, body)
+        _assert_nested(tree)
+        names = {n["name"] for n in _flatten(tree)}
+        assert set(HANDOVERS) <= names
+        named = [n for n in _flatten(tree) if n["name"] in NAMED]
+        for i, a in enumerate(named):
+            inside_a = {d["id"] for d in _descendants(a)}
+            for b in named[i + 1:]:
+                if b["id"] in inside_a or \
+                        a["id"] in {d["id"] for d in _descendants(b)}:
+                    continue
+                assert a["t1"] <= b["t0"] + 1e-9 or b["t1"] <= a["t0"] + 1e-9, \
+                    (a["name"], b["name"])
+
+    def test_an_unsampled_search_records_none_and_leaves_the_ring(
+            self, live, monkeypatch):
+        _cluster, node, rc = live
+        recorded = []
+        real_init = tracing.Span.__init__
+        real_record = tracing.Span.record
+
+        def spy_init(self, trace, name, *a, **kw):
+            recorded.append(name)
+            real_init(self, trace, name, *a, **kw)
+
+        def spy(self, name, *a, **kw):
+            recorded.append(name)
+            return real_record(self, name, *a, **kw)
+
+        monkeypatch.setattr(tracing.Span, "__init__", spy_init)
+        monkeypatch.setattr(tracing.Span, "record", spy)
+        monkeypatch.setattr(node.tracer, "sample_rate", 0.0)
+
+        def ring():
+            return [(t["trace_id"], len(t["spans"]))
+                    for t in node.tracer.traces()]
+
+        before = ring()
+        resp = rc.dispatch(RestRequest(
+            method="POST", path="/traced/_search", body=dict(SEARCH_BODY),
+            t_arrival=time.monotonic()))
+        assert resp.status == 200 and "trace" not in resp.body
+        assert recorded == [] and ring() == before
+        _traced_search(rc, SEARCH_BODY)
+        assert set(HANDOVERS) <= set(recorded) and ring() != before
+
+    def test_a_two_node_search_stitches_the_remote_fetch(self, tmp_path):
+        with TestCluster(n_nodes=2, data_root=tmp_path, seed=5) as cluster:
+            first = next(iter(cluster.nodes.values()))
+            client = first.client()
+            client.create_index("far", {"settings": {
+                "number_of_shards": 1, "number_of_replicas": 0}})
+            cluster.ensure_green("far")
+            for i in range(12):
+                client.index("far", "doc", {"body": WORDS[i % 8]}, id=str(i))
+            client.refresh("far")
+            copy = first.cluster_service.state.routing_table.index("far") \
+                .shard(0).active_shards()[0]
+            holder = next(n for n in cluster.nodes.values()
+                          if n.local_node.id == copy.node_id)
+            asker = next(n for n in cluster.nodes.values() if n is not holder)
+            resp = build_rest_controller(asker).dispatch(RestRequest(
+                method="POST", path="/far/_search", params={"trace": "true"},
+                body={"query": {"match": {"body": "quick"}}, "size": 3}))
+            assert resp.status == 200, resp.body
+            tree = resp.body["trace"]["tree"]
+            _assert_nested(tree)
+            assert tree["name"] == "rest" and tree["node"] == asker.name
+            (fetch,) = _find(tree, "shard.fetch")
+            (tspan,) = _find(tree, FETCH_ACTION)
+            (shard,) = _find(tree, "shard")
+            assert fetch["node"] == shard["node"] == holder.name
+            assert fetch["parent"] == tspan["id"] and tspan["node"] == asker.name
+            # the sender's side of the wire is named on both phases; the
+            # remote's pools have no span of the asker's to record under
+            assert len(_find(tree, "transport.codec")) == 2
+            assert _find(tree, "pool.wait") == []
+
+
+def _runtime(rc):
+    resp = rc.dispatch(RestRequest(method="GET", path="/_nodes/stats/runtime"))
+    return next(iter(resp.body["nodes"].values()))["runtime"]
+
+
+class TestHostRuntimeCounters:
+    ROLES = ("http_s", "search_s", "generic_s", "search_batcher_s", "other_s")
+
+    def test_cpu_seconds_are_served_by_role_and_never_fall(self, live):
+        _cluster, _node, rc = live
+        before = _runtime(rc)["cpu"]
+        assert set(before["threads"]) == set(self.ROLES)
+        for _ in range(3):
+            _concurrent_searches(rc, 2, trace=False)
+        after = _runtime(rc)["cpu"]
+        assert after["process_s"] > before["process_s"]
+        for role in self.ROLES:
+            assert after["threads"][role] >= before["threads"][role] >= 0.0
+        # the searches ran on the pools and through the drainer
+        for role in ("search_s", "generic_s", "search_batcher_s"):
+            assert after["threads"][role] > before["threads"][role], role
+
+    def test_the_http_handlers_book_their_cpu(self, live):
+        import urllib.request
+
+        _cluster, node, rc = live
+        http = node.http or node.start_http(0)
+        before = _runtime(rc)["cpu"]["threads"]["http_s"]
+        req = urllib.request.Request(
+            f"http://127.0.0.1:{http.port}/traced/_search",
+            data=json.dumps(SEARCH_BODY).encode(),
+            headers={"Content-Type": "application/json"})
+        with urllib.request.urlopen(req, timeout=30) as r:
+            assert json.loads(r.read())["hits"]["total"] > 0
+        # booked after the client has its last byte: poll, bounded
+        give_up = time.monotonic() + 10.0
+        while _runtime(rc)["cpu"]["threads"]["http_s"] <= before \
+                and time.monotonic() < give_up:
+            time.sleep(0.01)
+        assert _runtime(rc)["cpu"]["threads"]["http_s"] > before
+
+    def test_the_gil_gauge_probes_an_idle_node(self, live):
+        _cluster, _node, rc = live
+        first = _runtime(rc)["gil"]
+        assert set(first) == {"probes", "late_s", "late_max_s"}
+        give_up = time.monotonic() + 10.0
+        while _runtime(rc)["gil"]["probes"] < first["probes"] + 2 \
+                and time.monotonic() < give_up:
+            time.sleep(0.02)
+        later = _runtime(rc)["gil"]
+        assert later["probes"] >= first["probes"] + 2
+        assert later["late_s"] >= first["late_s"] >= 0.0
+        assert later["late_max_s"] >= 0.0
+
+    def test_compile_stall_seconds_are_the_three_parts(self, live):
+        _cluster, _node, rc = live
+        resp = rc.dispatch(RestRequest(method="GET",
+                                       path="/_nodes/stats/device"))
+        c = next(iter(resp.body["nodes"].values()))["device"]["compile"]
+        assert c["trace_s"] > 0 and c["lower_s"] > 0 and c["seconds"] > 0
+        assert c["stall_s"] == pytest.approx(
+            c["seconds"] + c["trace_s"] + c["lower_s"])
+
+    @pytest.mark.parametrize("event,part", [
+        ("/jax/core/compile/jaxpr_trace_duration", "trace_s"),
+        ("/jax/core/compile/jaxpr_to_mlir_module_duration", "lower_s")])
+    def test_trace_and_lower_events_are_seconds_not_compiles(self, event,
+                                                             part):
+        from elasticsearch_tpu.common import jaxenv
+
+        counter = jaxenv._CompileCounter()
+        report = jaxenv.SanitizerReport()
+        counter._active.append(report)
+        n0, s0 = jaxenv.thread_compile_totals()
+        counter._listener(event, 0.25)
+        assert counter.stall_s[part] == 0.25
+        assert sum(counter.stall_s.values()) == 0.25
+        assert counter.total == 0 and counter.by_family == {}
+        assert counter.seconds == 0.0 and counter.by_pool == {}
+        assert report.compiles == 0
+        assert jaxenv.thread_compile_totals() == (n0, s0)
+        counter._listener("/jax/core/compile/backend_compile_duration", 0.5)
+        assert counter.total == 1 and counter.seconds == 0.5
+        assert counter.stall_s[part] == 0.25
 
 
 # ---------------------------------------------------------------------------
