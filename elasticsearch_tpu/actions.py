@@ -73,6 +73,7 @@ from .search.controller import (
     collect_dfs,
     DfsResult,
     merge_responses,
+    shard_page,
     sort_docs,
 )
 from .search.execute import ShardContext
@@ -194,6 +195,11 @@ class ActionModule:
         # the histogram behind /_nodes/stats search.latency percentiles and
         # the Prometheus estpu_search_latency_seconds series
         self.search_latency = HistogramMetric()
+        # searches answered, by the trips they made to their shards: one where
+        # the query phase of the one shard they met hydrated the page, two
+        # where a fetch phase followed the reduce (plain ints booked in
+        # _finish_search; /_nodes/stats `search.phases`)
+        self.search_phases = {"one_trip": 0, "two_trip": 0}
         t = self.transport
         # master-node actions
         for action, fn in [
@@ -1589,6 +1595,12 @@ class ActionModule:
         span = tracing.current_span()
         if span:
             span.record("coordinator.plan", t0, time.monotonic())
+        # a search that meets ONE shard makes one trip: that shard's query
+        # phase hydrates the page it chose and no fetch phase follows (ref:
+        # TransportSearchAction.doExecute turns shardCount == 1 into
+        # QUERY_AND_FETCH, "no need to do THEN since we hit one shard"). With
+        # more shards the winners are not known until the reduce
+        page = [req.from_, req.size] if len(shards) == 1 else None
 
         with tracing.child_scope(span, "coordinator.query", shards=len(shards)):
             # co-located shards + flat query → one SPMD program over the device mesh
@@ -1602,7 +1614,7 @@ class ActionModule:
             if mesh_results is None:
                 results, failures, chain_terminals, shard_meta = \
                     self._query_phase(state, shards, body, alias_filters,
-                                      search_type, preference, deadline)
+                                      search_type, preference, deadline, page)
         if mesh_results is not None:
             # mesh-served searches never reach _s_query_phase, so the
             # query-shape classification happens HERE instead (one record per
@@ -1638,11 +1650,13 @@ class ActionModule:
                                    shard_meta, t0, timed_out=deadline.expired())
 
     def _query_phase(self, state, shards, body, alias_filters, search_type,
-                     preference, deadline):
+                     preference, deadline, page=None):
         """The transport query phase of one search (the DFS fan-out first,
         where the search type asks for it): every shard's chain dispatched at
-        once, then collected. Returns (results, failures, chain_terminals,
-        shard_meta); the caller decides between a 429 and a partial answer."""
+        once, then collected. `page` ([from, size], one-shard searches only)
+        asks the shard for the page's hits beside its partial. Returns
+        (results, failures, chain_terminals, shard_meta); the caller decides
+        between a 429 and a partial answer."""
         dfs_stats = None
         dfs_failed: set[int] = set()  # ordinals excluded from the query phase
         if search_type in ("dfs_query_then_fetch", "dfs_query_and_fetch"):
@@ -1703,7 +1717,8 @@ class ActionModule:
         query_futs = [
             None if ordinal in dfs_failed else
             self._query_shard_async(state, copy, body, alias_filters, dfs_stats,
-                                    deadline, allow_hedge=allow_hedge)
+                                    deadline, allow_hedge=allow_hedge,
+                                    page=page)
             for ordinal, copy in enumerate(shards)]
         # shared backstop: chains resolve themselves (every attempt is
         # timer-bounded), so this only catches a wedged chain — scaled to the
@@ -1777,6 +1792,8 @@ class ActionModule:
                        timed_out: bool = False):
         """Reduce + fetch + response assembly, shared by the transport scatter-gather
         and the mesh SPMD query phase (both deliver per-ordinal ShardQueryResults).
+        A search that met one shard got its page's hits with the partial (one
+        trip: _s_query_phase) and no fetch phase follows.
         The fetch phase deliberately ignores the request deadline: winners are
         already chosen, and hydrating them is what makes a timed-out response a
         PARTIAL answer instead of an empty one (ref: the reference's fetch runs
@@ -1786,17 +1803,30 @@ class ActionModule:
         t_reduce = time.monotonic() if span else 0.0
         merged = sort_docs(req, results)
         merged.timed_out = merged.timed_out or timed_out
-        page = merged.hits[req.from_: req.from_ + req.size]
-        # fetch phase: winners only, grouped per shard, all shards in flight at once
-        # (ref: TransportSearchQueryThenFetchAction.java:93-147)
+        # a search that met one shard brings its page with it (the shard chose
+        # it as sort_docs does: controller.shard_page); a chain that failed
+        # brings nothing and there is nothing to fetch either
+        one_trip = len(shards) == 1 and \
+            (not results or results[0].hits is not None)
+        # booked without a lock, as SERVING_COUNTERS are: exact enough for a share
+        self.search_phases["one_trip" if one_trip else "two_trip"] += 1
         by_shard: dict = {}
-        for rank, (score, ordinal, doc, sort_values) in enumerate(page):
-            by_shard.setdefault(ordinal, []).append((rank, score, doc, sort_values))
+        fetch_failed = 0
+        if not one_trip:
+            # fetch phase: winners only, grouped per shard, all shards in flight
+            # at once (ref: TransportSearchQueryThenFetchAction.java:93-147)
+            page = merged.hits[req.from_: req.from_ + req.size]
+            for rank, (score, ordinal, doc, sort_values) in enumerate(page):
+                by_shard.setdefault(ordinal, []).append(
+                    (rank, score, doc, sort_values))
         with tracing.child_scope(span, "coordinator.fetch",
                                  shards=len(by_shard)) as fetch_span:
-            fetched, fetch_failed = self._fetch_phase(
-                body, by_shard, shard_meta, failures)
-        hits = [fetched[r] for r in sorted(fetched)]
+            if one_trip:
+                hits = results[0].hits if results else []
+            else:
+                fetched, fetch_failed = self._fetch_phase(
+                    body, by_shard, shard_meta, failures)
+                hits = [fetched[r] for r in sorted(fetched)]
         response = merge_responses(req, merged, results, hits,
                                    took_ms=int((time.monotonic() - t0) * 1000),
                                    total_shards=len(shards),
@@ -1909,7 +1939,7 @@ class ActionModule:
 
     def _query_shard_async(self, state, copy: ShardRouting, body, alias_filters,
                            dfs_stats, deadline: Deadline = NO_DEADLINE,
-                           allow_hedge: bool = True) -> Future:
+                           allow_hedge: bool = True, page=None) -> Future:
         """Per-shard query phase with rank-ordered failover and hedged
         attempts, driven entirely by future callbacks — the coordinator parks
         no thread per shard (ref: performFirstPhase + onFirstPhaseResult
@@ -2047,6 +2077,10 @@ class ActionModule:
                 # cross processes); the shard restarts its own clock from it
                 "deadline_s": deadline.remaining(),
             }
+            if page is not None:
+                # one trip: every attempt of the chain, failover and hedge
+                # alike, asks its copy for the page's hits too
+                payload["fetch"] = page
             if hedge:
                 # the shard tags its span hedge:true from this (sibling shard
                 # spans in ?trace=true); the winner annotation on the profile
@@ -2231,6 +2265,7 @@ class ActionModule:
                         timed_out=bool(r.get("timed_out")),
                         degraded=bool(r.get("degraded")),
                         profile=prof,
+                        hits=r.get("hits"),
                     )
                     result.index_name = candidate.index  # type: ignore[attr-defined]
                 except Exception as e:  # noqa: BLE001 — a malformed/corrupt
@@ -2368,6 +2403,10 @@ class ActionModule:
             # speculative (hedged) attempt: its shard span shows as a sibling
             # of the primary attempt's in the stitched ?trace=true tree
             shard_span.tag(hedge=True)
+        # one trip (a search that met this shard alone): hydrate the page here,
+        # from the searcher that chose the doc ids, and pin no context for a
+        # fetch phase that will not come
+        page = request.get("fetch")
         # ---- shard request cache (search/request_cache.py) ----------------
         # key = (index, shard, point-in-time view version, fingerprint of the
         # normalized body). A hit returns the stored partial BEFORE
@@ -2400,12 +2439,18 @@ class ActionModule:
                 if data is not None:
                     try:
                         shard_span.tag(request_cache="hit")
+                        out = _decode_cached_partial(data)
+                        if page is not None:
+                            # the key holds the searcher's version: the cached
+                            # doc ids are this view's
+                            out["hits"] = self._page_hits(
+                                ctx, request, req, out["docs"], shard_span)
                     finally:
                         shard_span.end()
                     if shape_id is not None:
                         insights_reg.record(shape_id, shape, cache="hit")
-                    out = _decode_cached_partial(data)
-                    out["ctx_id"] = self._pin_context(index, shard_id, ctx)
+                    out["ctx_id"] = None if page is not None else \
+                        self._pin_context(index, shard_id, ctx)
                     out["load"] = self._load_signal()
                     if trace:
                         out["spans"] = trace.span_dicts()
@@ -2416,28 +2461,35 @@ class ActionModule:
                            cache="hit" if peek_hit else "miss")
         t_q = time.monotonic()
         obs = _insights.Observation() if shape_id is not None else None
+        hits = None
         try:
-            with tracing.activate(shard_span):
-                if obs is not None:
-                    with _insights.activate(obs):
+            try:
+                with tracing.activate(shard_span):
+                    if obs is not None:
+                        with _insights.activate(obs):
+                            result = self._execute_qp(ctx, req, shard_id,
+                                                      deadline, prof)
+                    else:
                         result = self._execute_qp(ctx, req, shard_id,
                                                   deadline, prof)
-                else:
-                    result = self._execute_qp(ctx, req, shard_id, deadline,
-                                              prof)
-        except Exception:
-            # a failing shape still classifies (outcome "error"): a query
-            # shape storming a breaker/deadline must show in
-            # /_insights/queries precisely when the operator needs it
-            if shape_id is not None:
-                obs.outcome = "error"
-                insights_reg.record(
-                    shape_id, shape, time.monotonic() - t_q, obs,
-                    cache="miss" if cache_key is not None else None)
-            raise
+            except Exception:
+                # a failing shape still classifies (outcome "error"): a query
+                # shape storming a breaker/deadline must show in
+                # /_insights/queries precisely when the operator needs it
+                if shape_id is not None:
+                    obs.outcome = "error"
+                    insights_reg.record(
+                        shape_id, shape, time.monotonic() - t_q, obs,
+                        cache="miss" if cache_key is not None else None)
+                raise
+            # the slow log and the insights record keep the query phase's
+            # own edges; the shard span ends behind the page's fetch
+            took_s = time.monotonic() - t_q
+            if page is not None:
+                hits = self._page_hits(ctx, request, req, result.docs,
+                                       shard_span)
         finally:
             shard_span.end()
-        took_s = time.monotonic() - t_q
         partial = _shard_partial_dict(result)
         if shape_id is not None:
             # profiled runs that found the entry present (peek) attribute a
@@ -2465,15 +2517,19 @@ class ActionModule:
                 prof.event("request_cache", cache="store")
         out = {
             **partial,
-            # fetch must read the SAME point-in-time searcher these doc ids
-            # come from (a merge between phases moves local ids)
-            "ctx_id": self._pin_context(index, shard_id, ctx),
+            # a fetch phase must read the SAME point-in-time searcher these doc
+            # ids come from (a merge between phases moves local ids); none
+            # follows a query phase that built the page's hits itself
+            "ctx_id": None if page is not None
+            else self._pin_context(index, shard_id, ctx),
             # response-piggybacked load signals for the coordinator's adaptive
             # replica selection (cluster/stats.py): this node's search-pool
             # queue depth + request-breaker headroom. Plain attribute reads —
             # the serving path gains no locks, clocks, or device traffic
             "load": self._load_signal(),
         }
+        if hits is not None:
+            out["hits"] = hits
         if trace:
             # the shard's span list rides the response so the coordinator can
             # stitch the cross-node tree inline (the `?trace=true` contract);
@@ -2485,6 +2541,23 @@ class ActionModule:
             # coordinator into the top-level `profile` section
             out["profile"] = prof.to_dict()
         return out
+
+    @staticmethod
+    def _page_hits(ctx, request, req, docs, shard_span) -> list:
+        """One trip: the hits of the page a search that met this shard alone
+        asked for (`request["fetch"]`: [from, size]), built on the searcher
+        that chose `docs`; of a sampled search a `shard.fetch` span inside its
+        `shard` span. An error here fails the attempt as one in the query
+        does, so the coordinator's chain tries the next copy."""
+        with tracing.child_scope(shard_span, "shard.fetch"):
+            winners = shard_page(req, docs, *request["fetch"])
+            if request.get("alias_filter"):
+                # the fetch phase reads the body as the client sent it:
+                # highlight and explain see its query, not the alias's filter
+                req = parse_search_body(request.get("body") or {})
+            return execute_fetch_phase(ctx, req, winners,
+                                       index_name=request["index"],
+                                       shard_id=request["shard"])
 
     @staticmethod
     def _execute_qp(ctx, req, shard_id: int, deadline, prof):

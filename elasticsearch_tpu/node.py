@@ -1100,10 +1100,13 @@ class Client:
             # latency percentiles (HistogramMetric — means hide the tail) +
             # the always-on query-shape insights registry (search.shapes:
             # occupancy, demotions, top shapes by cost — full entries at
-            # GET /_insights/queries)
+            # GET /_insights/queries); `phases`: the searches this node
+            # coordinated, by the trips they made to their shards (one where
+            # the one shard's query phase hydrated the page, else two)
             "search": lambda: {
                 "batcher": self.node.search_batcher.stats(),
                 "latency": self.node.actions.search_latency.stats(),
+                "phases": dict(self.node.actions.search_phases),
                 "shapes": self.node.insights.stats()},
             # device capacity ledger: per-index/per-segment HBM residency by
             # tier + pack/repack timings + compile events by plan family

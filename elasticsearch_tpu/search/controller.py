@@ -128,6 +128,14 @@ def sort_docs(req: ParsedSearchRequest, shard_results: list[ShardQueryResult]) -
     for r in shard_results:
         for (score, doc, sort_values) in r.docs:
             entries.append((score, r.shard_id, doc, sort_values))
+    _order(req, entries)
+    k = req.from_ + req.size
+    return MergedTopDocs(total=total, max_score=max_score, hits=entries[:k],
+                         timed_out=any(r.timed_out for r in shard_results))
+
+
+def _order(req: ParsedSearchRequest, entries: list) -> None:
+    """[(score, shard, doc, sort_values)] into the response's order, in place."""
     if req.sort:
         import functools
 
@@ -137,9 +145,18 @@ def sort_docs(req: ParsedSearchRequest, shard_results: list[ShardQueryResult]) -
         ))
     else:
         entries.sort(key=lambda e: (-e[0] if e[0] == e[0] else float("inf"), e[1], e[2]))
-    k = req.from_ + req.size
-    return MergedTopDocs(total=total, max_score=max_score, hits=entries[:k],
-                         timed_out=any(r.timed_out for r in shard_results))
+
+
+def shard_page(req: ParsedSearchRequest, docs: list, from_: int, size: int) -> list:
+    """The page of a search that met ONE shard, chosen on that shard from its
+    own docs [(score, doc, sort_values)]: the order sort_docs gives them and
+    the coordinator's `from`/`size` cut, so the hits the shard hydrates are
+    the page the coordinator would have asked for (ref:
+    SearchService.shortcutDocIdsToLoad)."""
+    entries = [(score, 0, doc, sort_values) for score, doc, sort_values in docs]
+    _order(req, entries)
+    return [(score, doc, sort_values) for score, _shard, doc, sort_values
+            in entries[from_: from_ + size]]
 
 
 def merge_responses(req: ParsedSearchRequest, merged: MergedTopDocs,

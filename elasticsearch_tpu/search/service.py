@@ -136,6 +136,10 @@ class ShardQueryResult:
     # domain is open (common/devicehealth) — bitwise-identical hits, but the
     # coordinator's `_shards` rollup must not count this copy as fully healthy
     degraded: bool = False
+    # the page's hits, where the shard's query phase hydrated them itself (a
+    # search that met one shard: actions._s_query_phase); None where a fetch
+    # phase follows
+    hits: list | None = None
 
 
 # process-wide serving-path counters (which executor served the query phase —

@@ -280,17 +280,22 @@ def live(tmp_path_factory):
         # a visible linger window so two concurrent requests coalesce
         "search.batch.linger_ms": "40",
         "search.batch.max_batch": "8",
+        # `traced2` goes shard by shard over the transport, as an index
+        # spread over nodes does: query phase, reduce, fetch phase
+        "search.mesh.enabled": "false",
     }) as cluster:
         node = next(iter(cluster.nodes.values()))
         client = node.client()
-        client.create_index("traced", {"settings": {
-            "number_of_shards": 1, "number_of_replicas": 0}})
-        cluster.ensure_green("traced")
-        for i in range(40):
-            client.index("traced", "doc",
-                         {"body": f"{WORDS[i % 8]} {WORDS[(i + 1) % 8]}"},
-                         id=str(i))
-        client.refresh("traced")
+        # one shard: a search makes one trip. Two: a fetch phase follows
+        for index, shards in (("traced", 1), ("traced2", 2)):
+            client.create_index(index, {"settings": {
+                "number_of_shards": shards, "number_of_replicas": 0}})
+            cluster.ensure_green(index)
+            for i in range(40):
+                client.index(index, "doc",
+                             {"body": f"{WORDS[i % 8]} {WORDS[(i + 1) % 8]}"},
+                             id=str(i))
+            client.refresh(index)
         rc = build_rest_controller(node)
         # warm occupancy-1 and occupancy-2 executables so traced passes below
         # measure bookkeeping, not XLA compiles
@@ -661,22 +666,24 @@ FILTERED_BODY = {"query": {"filtered": {
     "filter": {"term": {"body": "fox"}}}}, "size": 5}
 
 
-def _traced_search(rc, body, arrived_ago: float = 0.0):
+def _traced_search(rc, body, arrived_ago: float = 0.0, index: str = "traced"):
     resp = rc.dispatch(RestRequest(
-        method="POST", path="/traced/_search", params={"trace": "true"},
+        method="POST", path=f"/{index}/_search", params={"trace": "true"},
         body=dict(body), t_arrival=time.monotonic() - arrived_ago))
     assert resp.status == 200, resp.body
     return resp.body["trace"]["tree"]
 
 
-def _assert_nested(n):
-    """Every span lies inside its parent, and children sum to no more."""
+def _assert_nested(n, in_turn: bool = True):
+    """Every span lies inside its parent, and children sum to no more (where
+    they run in turn: the shards of a search of several run side by side)."""
     for c in n["children"]:
         assert c["t0"] >= n["t0"] - 1e-6 and c["t1"] <= n["t1"] + 1e-6, \
             (n["name"], c["name"])
-        _assert_nested(c)
+        _assert_nested(c, in_turn)
     total = sum(c["duration_ms"] for c in n["children"])
-    assert total <= n["duration_ms"] + 1.0, (n["name"], total, n["duration_ms"])
+    assert not in_turn or total <= n["duration_ms"] + 1.0, \
+        (n["name"], total, n["duration_ms"])
 
 
 class TestLaunchTimeline:
@@ -697,11 +704,12 @@ class TestLaunchTimeline:
         assert [c["name"] for c in coord["children"]] == [
             "coordinator.plan", "coordinator.query", "coordinator.reduce",
             "coordinator.fetch", "coordinator.render"]
-        # the transport round-trips of a phase nest under it
+        # the transport round-trips of a phase nest under it; the one shard
+        # built the page's hits in its query phase, so the fetch has none
         (query,) = _find(tree, "coordinator.query")
         (fetch,) = _find(tree, "coordinator.fetch")
         assert any(c["name"].startswith("transport[") for c in query["children"])
-        assert any(c["name"].startswith("transport[") for c in fetch["children"])
+        assert fetch["children"] == []
         (shard,) = _find(tree, "shard")
         assert shard["children"][0]["name"] == "shard.lower"
         (dispatch,) = _find(tree, "batcher.dispatch")
@@ -756,7 +764,7 @@ class TestLaunchTimeline:
         (shard,) = _find(tree, "shard")
         assert [c["name"] for c in shard["children"]] == [
             "shard.lower", "batcher.queue", "batcher.dispatch", "batcher.hold",
-            "batcher.merge", "thread.wake"]
+            "batcher.merge", "thread.wake", "shard.fetch"]
         (dispatch,) = _find(tree, "batcher.dispatch")
         kinds = [c["name"] for c in dispatch["children"]]
         assert kinds == ["dispatch.stage", "dispatch.launch", "device_pull"]
@@ -1085,6 +1093,12 @@ def _descendants(n):
     return [d for c in n["children"] for d in _flatten(c)]
 
 
+# a search of `traced` (one shard) makes one trip; one of `traced2` (two
+# shards, hits on both) a query phase and a fetch phase: the fetch cases live
+# where the fetch phase does
+INDEX_OF = {QUERY_ACTION: "traced", FETCH_ACTION: "traced2"}
+
+
 class TestHostHandovers:
     @pytest.mark.parametrize("pool", ["generic", "search"])
     @pytest.mark.parametrize("action", [QUERY_ACTION, FETCH_ACTION],
@@ -1092,61 +1106,99 @@ class TestHostHandovers:
     def test_each_pool_hop_of_each_phase_records_its_wait(self, live, action,
                                                           pool):
         _cluster, _node, rc = live
-        tree = _traced_search(rc, SEARCH_BODY)
-        (tspan,) = _find(tree, action)
-        waits = [c for c in tspan["children"] if c["name"] == "pool.wait"]
-        assert [w["tags"]["pool"] for w in waits] == ["generic", "search"]
-        (wait,) = [w for w in waits if w["tags"]["pool"] == pool]
-        assert tspan["t0"] <= wait["t0"] <= wait["t1"] <= tspan["t1"]
-        # the handler runs after both hops: its span starts behind them
-        handler = "shard" if action == QUERY_ACTION else "shard.fetch"
-        (served,) = [c for c in tspan["children"] if c["name"] == handler]
-        assert wait["t1"] <= served["t0"]
+        tree = _traced_search(rc, SEARCH_BODY, index=INDEX_OF[action])
+        tspans = _find(tree, action)
+        assert len(tspans) == (1 if action == QUERY_ACTION else 2)
+        for tspan in tspans:
+            waits = [c for c in tspan["children"] if c["name"] == "pool.wait"]
+            assert [w["tags"]["pool"] for w in waits] == ["generic", "search"]
+            (wait,) = [w for w in waits if w["tags"]["pool"] == pool]
+            assert tspan["t0"] <= wait["t0"] <= wait["t1"] <= tspan["t1"]
+            # the handler runs after both hops: its span starts behind them
+            handler = "shard" if action == QUERY_ACTION else "shard.fetch"
+            (served,) = [c for c in tspan["children"] if c["name"] == handler]
+            assert wait["t1"] <= served["t0"]
 
     @pytest.mark.parametrize("action", [QUERY_ACTION, FETCH_ACTION],
                              ids=["query", "fetch"])
     def test_both_round_trips_of_a_phase_record_the_codec(self, live, action):
         _cluster, _node, rc = live
-        tree = _traced_search(rc, SEARCH_BODY)
-        assert len(_find(tree, "transport.codec")) == 4
-        (tspan,) = _find(tree, action)
-        request, response = [c for c in tspan["children"]
-                             if c["name"] == "transport.codec"]
-        # the request's before the first pool hop, the response's last: the
-        # transport span ends with it
-        assert request["t1"] <= tspan["children"][1]["t0"]
-        assert tspan["children"][0] is request
-        assert tspan["children"][-1] is response
-        assert response["t1"] == tspan["t1"]
+        tree = _traced_search(rc, SEARCH_BODY, index=INDEX_OF[action])
+        transports = [n for n in _flatten(tree)
+                      if n["name"].startswith("transport[")]
+        # one shard: one round trip, a request and a response. Two shards:
+        # two round trips a phase
+        assert len(transports) == (1 if action == QUERY_ACTION else 4)
+        assert len(_find(tree, "transport.codec")) == 2 * len(transports)
+        for tspan in _find(tree, action):
+            request, response = [c for c in tspan["children"]
+                                 if c["name"] == "transport.codec"]
+            # the request's before the first pool hop, the response's last:
+            # the transport span ends with it
+            assert request["t1"] <= tspan["children"][1]["t0"]
+            assert tspan["children"][0] is request
+            assert tspan["children"][-1] is response
+            assert response["t1"] == tspan["t1"]
 
     def test_the_fetch_phase_continues_the_trace(self, live):
         _cluster, node, rc = live
-        tree = _traced_search(rc, SEARCH_BODY)
-        (fetch,) = _find(tree, "shard.fetch")
-        (tspan,) = _find(tree, FETCH_ACTION)
-        assert fetch["parent"] == tspan["id"] and fetch["node"] == node.name
-        assert fetch in tspan["children"]
+        tree = _traced_search(rc, SEARCH_BODY, index="traced2")
+        fetches = _find(tree, "shard.fetch")
+        tspans = _find(tree, FETCH_ACTION)
+        assert len(fetches) == len(tspans) == 2
+        for fetch, tspan in zip(fetches, tspans):
+            assert fetch["parent"] == tspan["id"] and fetch["node"] == node.name
+            assert fetch in tspan["children"]
 
-    @pytest.mark.parametrize("after,parents", [
-        ("batcher", ["shard"]),
-        ("transport", ["coordinator.query", "coordinator.fetch"])])
-    def test_a_waiting_thread_records_its_wake_up(self, live, after, parents):
-        _cluster, _node, rc = live
+    def test_one_shard_fetches_inside_its_query_round_trip(self, live):
+        """One trip: the shard's query handler builds the page's hits, so the
+        one `shard.fetch` is the last child of the `shard` span, inside the
+        one transport round trip; `coordinator.fetch` stays, with no round
+        trip of its own."""
+        _cluster, node, rc = live
         tree = _traced_search(rc, SEARCH_BODY)
+        (tspan,) = [n for n in _flatten(tree)
+                    if n["name"].startswith("transport[")]
+        assert tspan["name"] == QUERY_ACTION
+        (shard,) = _find(tree, "shard")
+        (fetch,) = _find(tree, "shard.fetch")
+        assert fetch["parent"] == shard["id"] and fetch["node"] == node.name
+        assert shard["children"][-1] is fetch
+        before = shard["children"][-2]
+        assert before["name"] == "thread.wake"
+        assert before["t1"] <= fetch["t0"] <= fetch["t1"] <= shard["t1"]
+        (cfetch,) = _find(tree, "coordinator.fetch")
+        assert cfetch["children"] == [] and cfetch["tags"]["shards"] == 0
+        (coord,) = _find(tree, "coordinator")
+        assert [c["name"] for c in coord["children"]] == [
+            "coordinator.plan", "coordinator.query", "coordinator.reduce",
+            "coordinator.fetch", "coordinator.render"]
+
+    @pytest.mark.parametrize("index,after,parents", [
+        ("traced", "batcher", ["shard"]),
+        ("traced", "transport", ["coordinator.query"]),
+        ("traced2", "transport", ["coordinator.query", "coordinator.fetch"])],
+        ids=["batcher", "transport-one-trip", "transport"])
+    def test_a_waiting_thread_records_its_wake_up(self, live, index, after,
+                                                  parents):
+        _cluster, _node, rc = live
+        tree = _traced_search(rc, SEARCH_BODY, index=index)
         by_id = {n["id"]: n for n in _flatten(tree)}
         wakes = [n for n in _find(tree, "thread.wake")
                  if n["tags"]["after"] == after]
         assert [by_id[w["parent"]]["name"] for w in wakes] == parents
         for w in wakes:
-            parent = by_id[w["parent"]]
-            before = [c for c in parent["children"] if c["t0"] < w["t0"]][-1]
+            waited_for = [c for c in by_id[w["parent"]]["children"]
+                          if c["t0"] < w["t0"]]
             if after == "batcher":
                 # from where the drainer finished the batch
-                assert before["name"] == "batcher.merge"
+                assert waited_for[-1]["name"] == "batcher.merge"
+                assert w["t0"] == waited_for[-1]["t1"]
             else:
-                # from where the phase's round-trip ended
-                assert before["name"].startswith("transport[")
-            assert w["t0"] == before["t1"]
+                # from where the phase's last round-trip ended
+                assert all(c["name"].startswith("transport[")
+                           for c in waited_for)
+                assert w["t0"] == max(c["t1"] for c in waited_for)
 
     @pytest.mark.parametrize("body", [SEARCH_BODY, FILTERED_BODY],
                              ids=["plain", "filtered"])
@@ -1203,12 +1255,18 @@ class TestHostHandovers:
         _traced_search(rc, SEARCH_BODY)
         assert set(HANDOVERS) <= set(recorded) and ring() != before
 
-    def test_a_two_node_search_stitches_the_remote_fetch(self, tmp_path):
+    @pytest.mark.parametrize("shards", [1, 2])
+    def test_a_two_node_search_stitches_the_remote_fetch(self, tmp_path,
+                                                         shards):
+        """Asked of the node that holds no copy of shard 0. One shard: the
+        remote query handler fetches too, inside its `shard` span. Two, one a
+        node: a fetch phase follows, and the remote shard's `shard.fetch`
+        hangs under the asker's transport span."""
         with TestCluster(n_nodes=2, data_root=tmp_path, seed=5) as cluster:
             first = next(iter(cluster.nodes.values()))
             client = first.client()
             client.create_index("far", {"settings": {
-                "number_of_shards": 1, "number_of_replicas": 0}})
+                "number_of_shards": shards, "number_of_replicas": 0}})
             cluster.ensure_green("far")
             for i in range(12):
                 client.index("far", "doc", {"body": WORDS[i % 8]}, id=str(i))
@@ -1220,20 +1278,38 @@ class TestHostHandovers:
             asker = next(n for n in cluster.nodes.values() if n is not holder)
             resp = build_rest_controller(asker).dispatch(RestRequest(
                 method="POST", path="/far/_search", params={"trace": "true"},
-                body={"query": {"match": {"body": "quick"}}, "size": 3}))
+                body={"query": {"match": {"body": " ".join(WORDS)}},
+                      "size": 12}))
             assert resp.status == 200, resp.body
+            assert len(resp.body["hits"]["hits"]) == 12  # of every shard
             tree = resp.body["trace"]["tree"]
-            _assert_nested(tree)
+            _assert_nested(tree, in_turn=shards == 1)
             assert tree["name"] == "rest" and tree["node"] == asker.name
-            (fetch,) = _find(tree, "shard.fetch")
-            (tspan,) = _find(tree, FETCH_ACTION)
-            (shard,) = _find(tree, "shard")
-            assert fetch["node"] == shard["node"] == holder.name
-            assert fetch["parent"] == tspan["id"] and tspan["node"] == asker.name
-            # the sender's side of the wire is named on both phases; the
-            # remote's pools have no span of the asker's to record under
-            assert len(_find(tree, "transport.codec")) == 2
-            assert _find(tree, "pool.wait") == []
+            (fetch,) = [n for n in _find(tree, "shard.fetch")
+                        if n["node"] == holder.name]
+            (shard,) = [n for n in _find(tree, "shard")
+                        if n["node"] == holder.name]
+            if shards == 1:
+                assert _find(tree, FETCH_ACTION) == []
+                assert fetch["parent"] == shard["id"]
+                (tspan,) = _find(tree, QUERY_ACTION)
+                assert shard["parent"] == tspan["id"]
+            else:
+                (tspan,) = [n for n in _find(tree, FETCH_ACTION)
+                            if fetch in n["children"]]
+                assert fetch["parent"] == tspan["id"]
+            assert tspan["node"] == asker.name
+            # the sender's side of the wire is named on every round trip to
+            # the holder; the remote's pools have no span of the asker's to
+            # record under
+            remote = [n for n in _flatten(tree)
+                      if n["name"].startswith("transport[")
+                      and holder.name in {c["node"] for c in n["children"]}]
+            assert len(remote) == (1 if shards == 1 else 2)
+            for tspan in remote:
+                names = [c["name"] for c in tspan["children"]]
+                assert names.count("transport.codec") == 1
+                assert "pool.wait" not in names
 
 
 def _runtime(rc):
