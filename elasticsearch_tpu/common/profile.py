@@ -34,6 +34,10 @@ left the fused device path —
   fuzzy_match, bool_filter_clause, non_term_subclause,
   function_score_no_query, function_score_ineligible,
   non_flat_subquery, similarity_not_fused, host_only_field,
+  sloppy_phrase, phrase_prefix, long_phrase (the phrase forms that stay on
+  the host: a slop, a prefix on the last term, more terms than the phrase
+  program's line holds; a phrase inside another query reads as that query's
+  reason: non_term_subclause, non_flat_subquery, unsupported_query:<Type>),
   unsupported_query:<Type>,
   device_disabled, features:<f1,f2,...>, device_error:<Type>.
 """

@@ -111,7 +111,9 @@ class DispatchClock:
     and sums `pull_s` into its drainer states for every batch. A `note` is a
     named part of the running interval (`shard.filter_mask` inside its
     `dispatch.stage`), recorded as that interval's child: the marks and what
-    they measure are as they were."""
+    they measure are as they were. The notes: `shard.filter_mask`,
+    `shard.fs_rows` and `shard.phrase_plan` (the host's assembly of a phrase
+    launch's operands: execute.launch_flat_phrase)."""
 
     __slots__ = ("spans", "pull_s", "compiled", "compile_s", "_t", "_n", "_s",
                  "_notes")

@@ -44,6 +44,31 @@ where they paid a serial gather and scatter for each posting. The plane is
 faulted in with the dense f32 plane (`ensure_head_rows`); the term → row map
 is host arithmetic at pack time. The postings of a head term stay where they
 are: the sparse program and the mesh packer read them.
+
+Positions live beside the postings, a plane a field (`PositionsPlane`):
+
+    keys : int32 [NPBpad, B] — every occurrence of every term of the field as
+               doc << pos_bits | position, a term's occurrences contiguous and
+               ascending (document, then position); after a document's last
+               occurrence of the term one MARKER, doc << pos_bits |
+               (mark_base + code << 4), which sorts behind every position
+               of the document; its code is the document's norm byte, or
+               POS_DEAD_CODE for a deleted document (no byte: it matches
+               nothing); blocked to the lane width as the postings are,
+               padded with POS_SENTINEL
+
+The exact-phrase program (ops/scoring.py, "exact phrases") merges the lists
+of a phrase's terms, each moved up by its place in the phrase: n equal keys
+are one occurrence of the phrase, and the last key of a document that holds
+the phrase is a marker, so the norm byte needs no gather. The plane is
+faulted in by the first phrase a segment's field meets (`ensure_positions`),
+from the host's own `FrozenSegment.positions`; no pack builds it, so an index
+that is never sent a phrase holds none. Non-parent documents are left out at
+the fault; DELETED documents stay in, and the plane takes a tombstone as the
+postings do: `_perform_pack`'s remask writes the dead code into the deleted
+documents' markers on the raw host copy (`host_keys`, as `host_docs` is the
+postings') and puts the plane again, with no second pass over the segment. A
+merged or a delta segment starts without one.
 """
 
 from __future__ import annotations
@@ -51,7 +76,7 @@ from __future__ import annotations
 import threading
 import time
 from collections import OrderedDict, deque
-from dataclasses import dataclass, field as dc_field
+from dataclasses import dataclass, field as dc_field, replace as dc_replace
 
 import numpy as np
 
@@ -238,9 +263,76 @@ class PackedSegment:
     host_freqs: np.ndarray | None = None  # float32 [NBpad*B]
     blk_field: np.ndarray | None = None  # int32 [NBpad] field ordinal per block (-1 pad)
     field_names: list = dc_field(default_factory=list)  # ordinal -> field name
+    # field -> PositionsPlane, faulted in by the first phrase on the field
+    # (ensure_positions); re-masked with the postings when the live mask moves
+    positions: dict = dc_field(default_factory=dict)
 
     def blocks_for_term(self, tid: int) -> tuple[int, int]:
         return int(self.term_blk_start[tid]), int(self.term_blk_start[tid + 1])
+
+
+POS_SENTINEL = np.int32(2**31 - 1)  # pads a term's last block; sorts last
+# a marker's position field is mark_base + (norm byte << POS_MARK_SHIFT): the
+# low bits stay free for the place in the phrase a launch adds to every key
+POS_MARK_SHIFT = 4
+POS_MAX_SHIFT = (1 << POS_MARK_SHIFT) - 1
+POS_DEAD_CODE = 256  # a deleted document's marker: past every norm byte
+
+
+@dataclass
+class PositionsPlane:
+    """One field's occurrences on the device (module docstring), or the
+    record that they do not fit (`keys` None: the host answers its phrases).
+    `blk_start[t] .. blk_start[t + 1]` are term t's block rows (no row for a
+    term of another field); row NPBpad - 1 is all POS_SENTINEL, the row a
+    launch's padding slots name."""
+
+    pos_bits: int  # key = doc << pos_bits | position
+    pos_max: int  # the largest position the plane holds
+    blk_start: np.ndarray | None = None  # host int64 [T+1]
+    keys: object = None  # jnp int32 [NPBpad, B], the view's dead markers set
+    host_keys: np.ndarray | None = None  # int32 [NPBpad, B], no document dead
+
+    @property
+    def mark_base(self) -> int:
+        return positions_mark_base(self.pos_bits)
+
+    def room_for(self, shift: int) -> bool:
+        """Whether every position moved up by `shift` still lies under the
+        markers, and the shift inside a marker's free bits: the narrow
+        layout's range (a phrase it cannot hold goes to the host, never to a
+        wrapped key)."""
+        return self.keys is not None and 0 <= shift <= POS_MAX_SHIFT \
+            and self.pos_max + shift < self.mark_base
+
+    def blocks_for_term(self, tid: int) -> tuple[int, int]:
+        return int(self.blk_start[tid]), int(self.blk_start[tid + 1])
+
+
+def positions_mark_base(pos_bits: int) -> int:
+    """The first position field a marker uses: 256 norm bytes and the dead
+    code, 1 << POS_MARK_SHIFT keys each, end a step under the field's top,
+    which only POS_SENTINEL reaches."""
+    return (1 << pos_bits) - ((POS_DEAD_CODE + 2) << POS_MARK_SHIFT)
+
+
+def masked_positions(plane: PositionsPlane, live: np.ndarray):
+    """`plane.host_keys` with the dead code in the marker of every document
+    `live` (bool, a document each) does not hold, as a device array: what a
+    view with tombstones launches over. One pass over the keys and one put;
+    the lists stay ascending (a marker keeps its document and its place
+    behind the document's positions)."""
+    import jax.numpy as jnp
+
+    keys = plane.host_keys
+    if live.all():
+        return jnp.asarray(keys)
+    pos_mask = (1 << plane.pos_bits) - 1
+    dead = (~live)[np.minimum(keys >> plane.pos_bits, len(live) - 1)] \
+        & ((keys & pos_mask) >= plane.mark_base) & (keys != POS_SENTINEL)
+    code = np.int32(plane.mark_base + (POS_DEAD_CODE << POS_MARK_SHIFT))
+    return jnp.asarray(np.where(dead, (keys & ~np.int32(pos_mask)) | code,
+                                keys))
 
 
 # Meta fields whose every term is one document. Blocks never span terms, so
@@ -276,7 +368,9 @@ def pack_shape_math(seg: FrozenSegment) -> tuple[int, int, str]:
     pack_estimate_bytes and pack_segment, so the breaker estimate can never
     drift from what the pack actually allocates. Memoized on the segment's
     device cache: the estimate→pack sequence (packed_for) derives it once,
-    not once per caller (the layout scan is O(postings))."""
+    not once per caller (the layout scan is O(postings)). The positions
+    planes are no part of it: no pack allocates them (ensure_positions
+    reserves its own bytes), so neither pack estimate counts them."""
     cache = getattr(seg, "_device_cache", None)
     if cache is not None:
         sm = cache.get("shape_math")
@@ -346,6 +440,8 @@ def packed_tier_bytes(packed: PackedSegment) -> dict:
       postings     the quantized sparse planes (blk_docs i32 + blk_tf + blk_nb)
       dense_plane  the lazily-faulted f32 freqs plane and the head-term rows
                    (0 until dense use)
+      positions_plane  the fields' position planes, i32 keys
+                   (0 until a phrase meets the segment)
       sim_tables   the stacked per-field similarity LUTs (modes + caches)
       agg_rows     FIFO-bounded device metric-agg stacks (float32 folds)
       agg_limbs    their integer limb rows (exact sums of whole-number columns)
@@ -373,6 +469,9 @@ def packed_tier_bytes(packed: PackedSegment) -> dict:
         "postings": postings,
         "dense_plane": (_plane_bytes(packed.blk_freqs)
                         + _plane_bytes(packed.head_rows)),
+        "positions_plane": sum(
+            _plane_bytes(plane.keys)
+            for plane in list(packed.positions.values())),
         "sim_tables": sim,
         "agg_rows": sum(_plane_bytes(stack.rows) for stack in stacks),
         "agg_limbs": sum(_plane_bytes(stack.limbs) for stack in stacks),
@@ -533,8 +632,9 @@ def segment_capacity(seg: FrozenSegment) -> dict | None:
     if packed is None and not any(held.values()):
         return None
     tiers = packed_tier_bytes(packed) if packed is not None else {
-        "postings": 0, "dense_plane": 0, "sim_tables": 0, "agg_rows": 0,
-        "agg_limbs": 0, "sort_keys": 0, "norms": 0}
+        "postings": 0, "dense_plane": 0, "positions_plane": 0,
+        "sim_tables": 0, "agg_rows": 0, "agg_limbs": 0, "sort_keys": 0,
+        "norms": 0}
     tiers.update(held)
     return {
         "generation": int(seg.gen),
@@ -786,7 +886,9 @@ def pack_segment_concat(merged: FrozenSegment,
     posting in source order (concat_source_packs); the result is bitwise
     identical to pack_segment(merged) by construction, pinned by the writes
     parity tests. Returns None when ineligible or when the layout cross-check
-    fails (callers fall back to the staged pack)."""
+    fails (callers fall back to the staged pack). The sources' positions
+    planes are not concatenated: the merged segment starts without one and
+    its first phrase faults it in from the merged host copy."""
     import jax.numpy as jnp
 
     from ..common.jaxenv import compile_tag
@@ -955,6 +1057,98 @@ def ensure_head_rows(packed: PackedSegment, breaker=None):
                     packed.host_freqs[b0 * BLOCK: b1 * BLOCK][real]
             packed.head_rows = jnp.asarray(rows)
     return packed.head_rows
+
+
+# host transients per key at the fault's peak, beyond the staged and uploaded
+# plane: the key's document, term and slot (i64 each at the peak) and the
+# boolean selections over postings and occurrences
+POSITIONS_TRANSIENT_BYTES = 32
+
+
+def positions_shape_math(seg: FrozenSegment, field: str) -> tuple[int, int]:
+    """(NPBpad, keys) of a field's positions plane before deleted documents
+    are left out, a key an occurrence and a marker a posting: what
+    ensure_positions reserves by."""
+    td = seg.term_dict.get(field) or {}
+    tids = np.fromiter(td.values(), dtype=np.int64, count=len(td))
+    p0, p1 = seg.post_offsets[tids], seg.post_offsets[tids + 1]
+    counts = (seg.pos_offsets[p1] - seg.pos_offsets[p0]) + (p1 - p0)
+    nblks = int(((counts + BLOCK - 1) // BLOCK).sum())
+    return _pow2_bucket(nblks + 1, 64), int(counts.sum())
+
+
+def ensure_positions(seg: FrozenSegment, packed: PackedSegment, field: str,
+                     breaker=None) -> PositionsPlane:
+    """Lazily fault in `field`'s positions plane (module docstring), under
+    the contract of ensure_head_rows: built from the host's own copy,
+    reserved on `breaker` (fielddata) around the build and the upload,
+    idempotent; a concurrent double build is benign (same values, the last
+    assignment wins). The plane holds every parent document, the deleted
+    ones under the dead code of this view's live mask; a later tombstone
+    re-masks it (_perform_pack), it is not built again. The row count rides
+    the pow-2 ladder (it shapes the phrase program's operand)."""
+    plane = packed.positions.get(field)
+    if plane is not None:
+        return plane
+    pos_bits = 31 - max(1, int(packed.doc_pad - 1).bit_length())
+    mark_base = positions_mark_base(pos_bits)
+    NPBpad, n_keys = positions_shape_math(seg, field)
+    est = NPBpad * BLOCK * 4 * 2 + n_keys * POSITIONS_TRANSIENT_BYTES
+    with reserve(breaker, est, f"<positions>{field}"):
+        T = len(seg.post_offsets) - 1
+        td = seg.term_dict.get(field) or {}
+        of_field = np.zeros(T, dtype=bool)
+        of_field[np.fromiter(td.values(), dtype=np.int64, count=len(td))] = True
+        per_term = np.diff(seg.post_offsets)
+        # the postings the plane keeps, and each one's term, document, norm
+        # byte and occurrences
+        kept = np.repeat(of_field, per_term) & seg.parent_mask[seg.post_docs]
+        p_tid = np.repeat(np.arange(T, dtype=np.int64), per_term)[kept]
+        p_doc = seg.post_docs[kept].astype(np.int64)
+        p_occ = np.diff(seg.pos_offsets)[kept]
+        norms = seg.norms.get(field)
+        p_nb = norms[p_doc].astype(np.int64) if norms is not None \
+            else np.zeros(len(p_doc), np.int64)
+        pos = seg.positions[expand_ranges(seg.pos_offsets[:-1][kept],
+                                          p_occ)].astype(np.int64)
+        pos_max = int(pos.max(initial=0))
+        if mark_base <= 0 or pos_max >= mark_base \
+                or int(pos.min(initial=0)) < 0:
+            # the host answers this field's phrases
+            plane = PositionsPlane(pos_bits, pos_max)
+            packed.positions[field] = plane
+            return plane
+        # a posting's occurrences, then its marker
+        n_post = len(p_doc)
+        o_post = np.repeat(np.arange(n_post, dtype=np.int64), p_occ)
+        keys = np.empty(len(pos) + n_post, dtype=np.int64)
+        tids = np.empty(len(keys), dtype=np.int64)
+        ends = np.cumsum(p_occ) + np.arange(n_post, dtype=np.int64)
+        at = np.arange(len(pos), dtype=np.int64) + o_post
+        keys[at] = (p_doc[o_post] << pos_bits) | pos
+        keys[ends] = (p_doc << pos_bits) | (mark_base + (p_nb << POS_MARK_SHIFT))
+        tids[at], tids[ends] = p_tid[o_post], p_tid
+        # the merge wants every term's list strictly ascending; the host
+        # scorer reads positions as sets, so order and repeats are not its
+        order = (tids << 32) | keys
+        if len(order) > 1 and not bool(np.all(order[1:] > order[:-1])):
+            order = np.unique(order)
+            tids, keys = order >> 32, order & 0xFFFFFFFF
+        counts = np.bincount(tids, minlength=T).astype(np.int64)
+        blk_start = np.zeros(T + 1, dtype=np.int64)
+        np.cumsum((counts + BLOCK - 1) // BLOCK, out=blk_start[1:])
+        flat = np.full(NPBpad * BLOCK, POS_SENTINEL, dtype=np.int32)
+        flat[expand_ranges(blk_start[:-1] * BLOCK, counts)] = \
+            keys.astype(np.int32)
+        plane = PositionsPlane(pos_bits, pos_max, blk_start,
+                               host_keys=flat.reshape(NPBpad, BLOCK))
+        plane.keys = masked_positions(plane, seg.live)
+    packed.positions[field] = plane
+    prof = _profile.current()
+    if prof is not None:
+        prof.event("positions_plane", cache="fault", field=field,
+                   bytes=int(NPBpad * BLOCK * 4))
+    return plane
 
 
 def _whole_numbers(vals: np.ndarray) -> bool:
@@ -1738,9 +1932,16 @@ def _perform_pack(seg: FrozenSegment, fut, breaker,
                           packed.host_docs,
                           packed.doc_pad).astype(np.int32, copy=False)
         docs_dev = jnp.asarray(masked.reshape(-1, BLOCK))
+        # the positions planes likewise, from their raw host copies: new
+        # objects, a copy-on-write view's parent keeps its own
+        planes = {
+            field: plane if plane.keys is None else dc_replace(
+                plane, keys=masked_positions(plane, seg.live))
+            for field, plane in list(packed.positions.items())}
         with _PACK_LOCK:
             packed.live_parent = lp_dev
             packed.blk_docs = docs_dev
+            packed.positions = planes
             cache["live"] = True
             cache.pop("pack_future", None)
             cache.pop("pack_claimed", None)

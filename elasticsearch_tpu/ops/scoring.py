@@ -39,13 +39,18 @@ from ..common.compilecache import REGISTRY as _WARM
 from ..common.jaxenv import current_compile_family
 from .device_index import (
     BLOCK,
+    POS_DEAD_CODE,
+    POS_MARK_SHIFT,
+    POS_SENTINEL,
     TFN_BM25,
     AggStack,
     PackedSegment,
+    PositionsPlane,
     _ladder_bucket,
     _pow2_bucket,
     ensure_blk_freqs,
     ensure_head_rows,
+    positions_mark_base,
 )
 
 GROUP_SHOULD, GROUP_MUST, GROUP_MUST_NOT = 0, 1, 2
@@ -302,7 +307,9 @@ class LaunchCounters:
              "launches_sparse", "launches_dense", "operand_puts",
              "unscored_plans", "launches_unscored", "unscored_bytes",
              "mask_put_bytes", "launches_fs_unscored", "fs_row_put_bytes",
-             "fs_rows_resident", "fs_rows_evaluated", "exact_sum_rows"), 0)
+             "fs_rows_resident", "fs_rows_evaluated", "exact_sum_rows",
+             "phrase", "phrase_searches", "position_bytes",
+             "position_pad_bytes"), 0)
 
     def add(self, real: int, launched: int, nbytes: int,
             dense_rows: int = 0, head_slots: int = 0,
@@ -333,9 +340,15 @@ class LaunchCounters:
         group found a segment's rows resident or had the host evaluate them
         (`fs_rows_resident`, `fs_rows_evaluated`) and the bytes of function
         rows, applies rows and script column rows so evaluated and put
-        (`fs_row_put_bytes`: execute._fs_segment_rows), and the integer limb
+        (`fs_row_put_bytes`: execute._fs_segment_rows), the integer limb
         rows the aggregated launches reduced (`exact_sum_rows`:
-        score_agg_batch_async)."""
+        score_agg_batch_async), and the phrase program's launches, the plans
+        it served (once a plan whatever its segments) and the bytes of
+        position blocks its launches gathered, padding included, and the
+        padding's part of them: the quarters of slots no term fills and each
+        term's rows up to its rung (`phrase`, `phrase_searches`,
+        `position_bytes`, `position_pad_bytes`: score_phrase_batch_async,
+        execute.launch_flat_phrase)."""
         with self._lock:
             for name, n in counts.items():
                 self._c[name] += n
@@ -1653,10 +1666,258 @@ def concat_pack_planes(blk_term, blk_j0, cum, starts, bases, doc_pads,
     """Launch the concat program (executables cached per sentinel/layout;
     jit re-specializes per source-shape set, which the pow-2 shape buckets
     keep bounded). Inputs stay on device; outputs are the merged segment's
-    resident planes — no pull here."""
+    resident planes — no pull here. The postings planes alone: a source's
+    positions planes are not re-blocked, the merged segment's first phrase
+    faults its own in from the host copy (device_index.ensure_positions)."""
     fn = _get_concat_compiled(int(doc_pad_new), tf_layout)
     return fn(blk_term, blk_j0, cum, starts, bases, doc_pads,
               tuple(src_docs), tuple(src_tf), tuple(src_nb))
+
+
+# ---------------------------------------------------------------------------
+# exact phrases: merge the terms' position lists
+# ---------------------------------------------------------------------------
+#
+# Lucene's ExactPhraseScorer walks the n terms' position lists of one document
+# at a time. Here the whole lists meet at once: every term's keys
+# (device_index.PositionsPlane: doc << pos_bits | position, ascending) are
+# moved up by R - rel_pos[i], so that the n keys of one occurrence of the
+# phrase are EQUAL, the lists are merged into one ascending line (they arrive
+# sorted, so a bitonic merge of log2 stages does it, no sort), and a run of n
+# equal keys is one occurrence. A running sum of the run ends, read at each
+# document's last key, is the phrase's frequency there; that last key is a
+# marker of the plane and carries the document's norm byte, so the 256-entry
+# similarity table is read without a gather over documents; a deleted
+# document's marker carries the dead code in the byte's place and matches
+# nothing (the plane keeps deleted documents: device_index.masked_positions).
+
+PHRASE_SLOTS = 4  # terms a phrase plan may hold: a quarter of the line each
+# block rows a term's quarter holds, up the ladder. Few and far apart: every
+# rung is a program, a first sighting compiles for tens of seconds on the one
+# drainer, and a warm-up has to meet every rung (padding rows cost a merge
+# stage's share of microseconds). A term past the last rung goes to the host.
+PHRASE_RUNGS = (1024, 8192, 32768)
+# columns of a phrase launch's operand plane, int32 [Q, 16]: the weight's
+# bits, the SimTables row, the number of terms; then a column a slot of the
+# first block row, the block count and the shift
+_P_WEIGHT, _P_FID, _P_TERMS, _P_START, _P_COUNT, _P_SHIFT = 0, 1, 2, 4, 8, 12
+_P_COLS = 16
+
+
+def phrase_rung(blocks: int) -> int | None:
+    """The rung of PHRASE_RUNGS that holds a term of `blocks` block rows."""
+    for rung in PHRASE_RUNGS:
+        if blocks <= rung:
+            return rung
+    return None
+
+
+def _line_shift(x, s: int, fill):
+    """[Q, R, B] read as one row-major line a query, moved by `s` places:
+    place j of the result holds place j - s (|s| < B), `fill` off the ends."""
+    import jax
+    import jax.numpy as jnp
+
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 2)
+    edge = jnp.full_like(x[:, :1], fill)
+    if s > 0:
+        other = jnp.concatenate([edge, x[:, :-1]], axis=1)  # the row before
+        return jnp.where(lane >= s, jnp.roll(x, s, axis=2),
+                         jnp.roll(other, s, axis=2))
+    other = jnp.concatenate([x[:, 1:], edge], axis=1)  # the row after
+    return jnp.where(lane < BLOCK + s, jnp.roll(x, s, axis=2),
+                     jnp.roll(other, s, axis=2))
+
+
+def _line_scan(x, op, identity):
+    """Inclusive scan of `op` along each query's row-major line of [Q, R, B]:
+    log2 doubling inside the rows, then over the rows' totals."""
+    import jax
+    import jax.numpy as jnp
+
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 2)
+    s = 1
+    while s < BLOCK:
+        x = op(x, jnp.where(lane >= s, jnp.roll(x, s, axis=2), identity))
+        s *= 2
+    tot = x[:, :, -1]  # [Q, R]
+    s = 1
+    while s < tot.shape[1]:
+        tot = op(tot, jnp.concatenate(
+            [jnp.full((tot.shape[0], s), identity, tot.dtype), tot[:, :-s]],
+            axis=1))
+        s *= 2
+    before = jnp.concatenate(
+        [jnp.full((tot.shape[0], 1), identity, tot.dtype), tot[:, :-1]], axis=1)
+    return op(x, before[:, :, None])
+
+
+def _bitonic_merge(x):
+    """[Q, G, L, B]: each [L, B] row-major line ascending over its first half
+    and descending over its second comes back ascending. log2(L * B) stages
+    of one compare-exchange each: whole rows while the stride is a row or
+    more, lanes inside a row after."""
+    import jax
+    import jax.numpy as jnp
+
+    Q, G, L, B = x.shape
+    d = L // 2
+    while d >= 1:
+        if d >= 8:  # whole (8, 128) tiles change places
+            y = x.reshape(Q, G, L // (2 * d), 2, d, B)
+            a, b = y[:, :, :, 0], y[:, :, :, 1]
+            x = jnp.stack([jnp.minimum(a, b), jnp.maximum(a, b)], axis=3)
+        else:  # a few rows: as halves of one long minor axis, no short one
+            y = x.reshape(Q, G, L // (2 * d), 2 * d * B)
+            a, b = y[..., : d * B], y[..., d * B:]
+            x = jnp.concatenate([jnp.minimum(a, b), jnp.maximum(a, b)],
+                                axis=-1)
+        x = x.reshape(Q, G, L, B)
+        d //= 2
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 3)
+    d = B // 2
+    while d >= 1:
+        upper = (lane & d) != 0
+        other = jnp.where(upper, jnp.roll(x, d, axis=3),
+                          jnp.roll(x, -d, axis=3))
+        x = jnp.where(upper, jnp.maximum(x, other), jnp.minimum(x, other))
+        d //= 2
+    return x
+
+
+def _pair_up(x):
+    """[Q, G, L, B] ascending lines -> [Q, G / 2, 2 L, B]: every second line
+    turned round behind the one before it, the shape _bitonic_merge takes."""
+    import jax.numpy as jnp
+
+    # lax.rev, not a negative-step index: that one lowers to a gather of
+    # every key (36 ms for 8.4M of them on a v5e, three quarters of a launch)
+    return jnp.concatenate(
+        [x[:, 0::2], jnp.flip(x[:, 1::2], axis=(2, 3))], axis=2)
+
+
+def _lut256(table, byte):
+    """table[q, byte[q, ...]] of a [Q, 256] table, as 256 compare-selects a
+    place: a gather of as many indices costs the chip 8 ns each, where these
+    fuse into one pass over the line."""
+    import jax.numpy as jnp
+
+    out = jnp.zeros(byte.shape, table.dtype)
+    for b in range(256):
+        out = jnp.where(byte == b, table[:, b][:, None, None], out)
+    return out
+
+
+def _phrase_impl(pos_keys, caches, modes, qplane, *, k: int, rows: int,
+                 pos_bits: int):
+    import jax
+    import jax.numpy as jnp
+
+    Q = qplane.shape[0]
+    mark_base = positions_mark_base(pos_bits)
+    pos_mask = (1 << pos_bits) - 1
+    with jax.named_scope("gather_positions"):
+        start = qplane[:, _P_START: _P_START + PHRASE_SLOTS, None]
+        count = qplane[:, _P_COUNT: _P_COUNT + PHRASE_SLOTS, None]
+        shift = qplane[:, _P_SHIFT: _P_SHIFT + PHRASE_SLOTS, None, None]
+        r = jax.lax.broadcasted_iota(jnp.int32, (1, 1, rows), 2)
+        blk = jnp.where(r < count, start + r, pos_keys.shape[0] - 1)
+        keys = pos_keys[blk]  # [Q, SLOTS, rows, B]
+        keys = jnp.where(keys == POS_SENTINEL, POS_SENTINEL, keys + shift)
+    with jax.named_scope("phrase_join"):
+        keys = _bitonic_merge(_pair_up(keys))
+        keys = _bitonic_merge(_pair_up(keys))[:, 0]  # [Q, SLOTS * rows, B]
+        n = qplane[:, _P_TERMS, None, None]
+        # markers sort behind every position and take no part in a run
+        word = (keys & pos_mask) < mark_base
+        run_end = jnp.where(n == 2, keys == _line_shift(keys, 1, -1),
+                            jnp.where(n == 3, keys == _line_shift(keys, 2, -1),
+                                      keys == _line_shift(keys, 3, -1)))
+        hit = word & run_end
+    with jax.named_scope("segment_sum"):
+        after = _line_shift(keys, -1, POS_SENTINEL)
+        last = (keys != POS_SENTINEL) & (
+            (after == POS_SENTINEL)
+            | ((after >> pos_bits) != (keys >> pos_bits)))
+        seen = _line_scan(hit.astype(jnp.int32), jnp.add, 0)
+        # the sum at the last key of the document before
+        before = _line_shift(
+            _line_scan(jnp.where(last, seen, 0), jnp.maximum, 0), 1, 0)
+        freq = seen - before
+        # a live document's last key is a marker that carries its norm byte;
+        # a deleted one's carries POS_DEAD_CODE and matches nothing
+        nb = ((keys & pos_mask) - mark_base) >> POS_MARK_SHIFT
+        match = last & (freq > 0) & (nb < POS_DEAD_CODE)
+    with jax.named_scope("top_k"):
+        fid = qplane[:, _P_FID]
+        cv = _lut256(caches[fid], nb)
+        f = freq.astype(jnp.float32)
+        w = jax.lax.bitcast_convert_type(qplane[:, _P_WEIGHT], jnp.float32)
+        # tf factor first, then weight: the host's order (HostScorer._eval_phrase)
+        tfn = jnp.where(modes[fid][:, None, None] == TFN_BM25,
+                        f / (f + cv), jnp.sqrt(f) * cv)
+        scores = jnp.where(match, w[:, None, None] * tfn, -jnp.inf)
+        top_scores, idx = jax.lax.top_k(scores.reshape(Q, -1), k)
+        top_docs = jnp.take_along_axis(
+            (keys >> pos_bits).reshape(Q, -1), idx, axis=1)
+        return top_scores, top_docs, match.sum(axis=(1, 2), dtype=jnp.int32)
+
+
+def _get_phrase_compiled(n_queries: int, rows: int, k: int, pos_bits: int):
+    import jax
+
+    key = ("phrase", n_queries, rows, k, pos_bits)
+    fn = _compiled_cache.get(key)
+    if fn is None:
+        def wrapper(pos_keys, caches, modes, qplane):
+            return _phrase_impl(pos_keys, caches, modes, qplane, k=k,
+                                rows=rows, pos_bits=pos_bits)
+
+        fn = jax.jit(_named("scoring.phrase", wrapper))
+        _compiled_cache[key] = fn
+    return fn
+
+
+def phrase_operands(entries: list, n_queries: int) -> np.ndarray:
+    """The operand plane of one phrase launch (columns _P_*): `entries` holds
+    a plan's (weight f32, SimTables row, [(first block row, block count,
+    shift) a term]); rows past them, and slots past a plan's terms, name no
+    block, so they match nothing."""
+    qplane = np.zeros((n_queries, _P_COLS), np.int32)
+    for q, (w, fid, terms) in enumerate(entries):
+        qplane[q, _P_WEIGHT] = np.float32(w).view(np.int32)
+        qplane[q, _P_FID] = fid
+        qplane[q, _P_TERMS] = len(terms)
+        for i, (b0, nb, shift) in enumerate(terms):
+            qplane[q, _P_START + i] = b0
+            qplane[q, _P_COUNT + i] = nb
+            qplane[q, _P_SHIFT + i] = shift
+    return qplane
+
+
+def score_phrase_batch_async(plane: PositionsPlane, sim, qplane: np.ndarray,
+                             rows: int, k: int, note_t0: float | None = None):
+    """Launch the phrase program over one segment's positions plane for the
+    plans of `qplane` (phrase_operands), a quarter of `rows` block rows a
+    term; returns the device arrays (scores [Q, k], docs [Q, k], totals [Q])
+    without syncing. `sim` is the SimTables whose rows the plane names.
+    `note_t0`: when the host began to assemble this launch's operands; from
+    there to the end of their one device_put is the span `shard.phrase_plan`
+    (a note inside the running `dispatch.stage`)."""
+    Q = qplane.shape[0]
+    params = (Q, rows, min(k, PHRASE_SLOTS * rows * BLOCK), plane.pos_bits)
+    fn = _get_phrase_compiled(*params)
+    args = (plane.keys, sim.caches, sim.modes, *_put_operands(qplane))
+    if note_t0 is not None:
+        _tracing.note("shard.phrase_plan", note_t0)
+    # what the launch gathers: PHRASE_SLOTS quarters of `rows` block rows a
+    # plan, BLOCK keys of 4 B each, padding included; the rows no term named
+    # (the sentinel row, gathered again and again) are the padding
+    launched = Q * PHRASE_SLOTS * rows
+    named = int(qplane[:, _P_COUNT: _P_COUNT + PHRASE_SLOTS].sum())
+    LAUNCHES.bump(phrase=1, position_bytes=launched * BLOCK * 4,
+                  position_pad_bytes=(launched - named) * BLOCK * 4)
+    return _launch(fn, args, "scoring.phrase", "phrase", params)
 
 
 # ---------------------------------------------------------------------------
@@ -1714,3 +1975,8 @@ def _build_fs_rows_unscored(params):
 @_WARM.builder("scoring.sparse")
 def _build_sparse(params):
     return _get_sparse_compiled(*params)
+
+
+@_WARM.builder("scoring.phrase")
+def _build_phrase(params):
+    return _get_phrase_compiled(*params)

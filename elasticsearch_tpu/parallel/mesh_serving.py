@@ -244,7 +244,8 @@ class MeshServingService:
                 or plan.const is not None):
             # function_score / nested-filtered / unscored plans carry a device
             # tail the mesh program doesn't express — transport path (which itself serves them
-            # on-device via execute_flat_batch's fs/filtered kernels)
+            # on-device via execute_flat_batch's fs/filtered kernels); an exact
+            # phrase is declined by lower_flat itself and goes the same way
             return None
 
         # ---- aggregation eligibility: metric aggs fuse as masked stats, bucket

@@ -370,9 +370,9 @@ def _prometheus_text(node) -> str:
                     key=lambda kv: -kv[1]["total_bytes"])
     emitted, omitted = ranked[:cap], ranked[cap:]
     for iname, entry in emitted:
-        for tier in ("postings", "dense_plane", "sim_tables", "agg_rows",
-                     "agg_limbs", "sort_keys", "norms", "filter_masks",
-                     "function_rows"):
+        for tier in ("postings", "dense_plane", "positions_plane",
+                     "sim_tables", "agg_rows", "agg_limbs", "sort_keys",
+                     "norms", "filter_masks", "function_rows"):
             w.gauge("estpu_device_index_bytes",
                     entry["totals"].get(tier, 0), index=iname, tier=tier)
     for iname, entry in emitted:

@@ -82,7 +82,8 @@ from ..ops.device_index import _ladder_bucket
 _K_MIN = 16  # smallest k bucket (top-10 pages and top-16 share executables)
 # the kinds of launch /_nodes/stats tells apart under search.batcher.kinds:
 # execute._flat_groups' and the mesh family's
-_KINDS = ("plain", "function_score", "filtered", "aggs", "sorted", "mesh")
+_KINDS = ("plain", "function_score", "filtered", "phrase", "aggs", "sorted",
+          "mesh")
 
 
 def _k_bucket(k: int) -> int:

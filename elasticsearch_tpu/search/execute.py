@@ -6,9 +6,13 @@ The analogue of the reference's QueryPhase + Lucene Weight/Scorer machinery
 - **Device path** (the common case: match / term / terms / flat bool over terms —
   exactly the queries in BASELINE.md configs): the query lowers to a flat clause list;
   clauses from a whole QUERY BATCH are fused into one TermBatch per segment and executed
-  by ops/scoring.py in a single device program (gather → FMA → scatter → top_k).
+  by ops/scoring.py in a single device program (gather → FMA → scatter → top_k). An
+  exact phrase (`match_phrase`, slop 0, 2-4 terms) lowers to a plan that carries the
+  phrase in the clauses' place and runs the phrase program over the segment's resident
+  positions plane (launch_flat_phrase).
 
-- **Host path** (everything else: phrase/positions, multi-term expansion, joins,
+- **Host path** (everything else: sloppy and prefix phrases, phrases inside other
+  queries, spans, multi-term expansion, joins,
   function_score internals, scripts): recursive numpy evaluation per segment producing
   dense (scores float32[D], match bool[D]) with the SAME similarity math, so device and
   host paths rank identically on queries both can run.
@@ -192,6 +196,16 @@ class Clause:
     group: int  # GROUP_*
 
 
+@dataclass(frozen=True)
+class PhraseClause:
+    """An exact phrase on one field: the analyzed terms and each one's place
+    (the analyzer's positions, so a removed stop word leaves its gap)."""
+
+    field: str
+    terms: tuple
+    rel_pos: tuple
+
+
 @dataclass
 class FlatPlan:
     """A query lowered to one flat weighted-term batch (device-executable)."""
@@ -222,6 +236,12 @@ class FlatPlan:
     # then holds what the host's queryNorm pre-pass squares for the query
     # (the same product, or 0 where the pre-pass counts nothing)
     const: float | None = None
+    # an exact phrase (PhraseQuery, slop 0, 2 to PHRASE_SLOTS terms) in the
+    # clauses' place: `clauses` is empty, `boost` the query's, and the plan
+    # runs the phrase program (launch_flat_phrase). It carries no tail: a
+    # phrase under a filter, a function_score, a sort or aggregations is the
+    # host's. Only lower_flat(phrases=True) gives such a plan out
+    phrase: PhraseClause | None = None
 
 
 # ---------------------------------------------------------------------------
@@ -268,19 +288,40 @@ def _msm_value(s: str, clause_count: int) -> int:
 # ---------------------------------------------------------------------------
 
 
-def lower_flat(query: Query, ctx: ShardContext) -> FlatPlan | None:
+def lower_flat(query: Query, ctx: ShardContext,
+               phrases: bool = False) -> FlatPlan | None:
     """Lower a query to a flat clause list, or None if it needs the host path.
     Fields scored by a freq/norm-generic similarity (DFR/IB/LM*) always take the host
-    path — the device kernel's fused modes are BM25/TF-IDF only."""
-    plan = _lower_flat_inner(query, ctx)
+    path — the device kernel's fused modes are BM25/TF-IDF only. `phrases`: the
+    caller hands its plans to execute_flat_batch with no tail, the one consumer
+    that can run a phrase plan (its `clauses` are empty); every other caller is
+    declined an exact phrase here and answers it from the host."""
+    plan = _lower_top(query, ctx)
+    if plan is not None and plan.phrase is not None and not phrases:
+        return None
     if plan is not None:
-        for c in plan.clauses:
-            if c.field in HOST_ONLY_FIELDS:
+        for field in _plan_fields(plan):
+            if field in HOST_ONLY_FIELDS:
                 return None  # its postings are not in the device planes
-            if not isinstance(ctx.similarity_for(c.field),
+            if not isinstance(ctx.similarity_for(field),
                               (BM25Similarity, TFIDFSimilarity)):
                 return None
     return plan
+
+
+def _lower_top(query: Query, ctx: ShardContext) -> FlatPlan | None:
+    """_lower_flat_inner, and at the top of the query alone an exact phrase:
+    nothing that wraps a sub plan (function_score, filtered) ever meets one."""
+    if isinstance(query, PhraseQuery):
+        return _lower_phrase(query, ctx)
+    return _lower_flat_inner(query, ctx)
+
+
+def _plan_fields(plan: FlatPlan):
+    """The fields whose postings or positions a plan scores by."""
+    if plan.phrase is not None:
+        return (plan.phrase.field,)
+    return [c.field for c in plan.clauses]
 
 
 def _unscored(query: Query, ctx: ShardContext, boost: float):
@@ -425,6 +466,31 @@ def _lower_flat_inner(query: Query, ctx: ShardContext) -> FlatPlan | None:
     return None
 
 
+def _lower_phrase(query: PhraseQuery, ctx: ShardContext) -> FlatPlan | None:
+    """A phrase the device answers: exact (slop 0), no prefix, the analyzed
+    terms as HostScorer._eval_phrase reads them. One analyzed term is the
+    term query the host scores it as, none an empty plan; more terms than
+    the phrase program's line holds (PHRASE_SLOTS) stay on the host."""
+    from ..ops.scoring import PHRASE_SLOTS
+
+    if query.prefix or hasattr(query, "_pre_analyzed"):
+        return None
+    toks = ctx.analyze_tokens(query.field, query.text)
+    if not toks:
+        return FlatPlan([], msm=0, n_must=0, coord_enabled=False,
+                        boost=query.boost)
+    if len(toks) == 1:
+        return FlatPlan([Clause(query.field, toks[0].term, query.boost,
+                                GROUP_SHOULD)],
+                        msm=1, n_must=0, coord_enabled=False, boost=1.0)
+    if query.slop != 0 or len(toks) > PHRASE_SLOTS:
+        return None
+    return FlatPlan([], msm=0, n_must=0, coord_enabled=False,
+                    boost=query.boost, phrase=PhraseClause(
+                        query.field, tuple(t.term for t in toks),
+                        tuple(int(t.position) for t in toks)))
+
+
 def _classify_fs(q: FunctionScoreQuery):
     """Device eligibility for a function_score spec:
       "rows"   — no function reads _score: values fold to host-combined f32 rows
@@ -490,6 +556,10 @@ def plan_profile(plan: FlatPlan, query: Query) -> dict:
         "function_score": plan.fs_kind,  # None | "rows" | "script"
         "filtered": plan.filt is not None,
         "unscored": plan.const is not None,  # no scoring clause: mask & const
+        # an exact phrase in the clauses' place: field, terms, places
+        "phrase": None if plan.phrase is None else {
+            "field": plan.phrase.field, "terms": list(plan.phrase.terms),
+            "rel_pos": list(plan.phrase.rel_pos)},
     }
 
 
@@ -500,11 +570,21 @@ def lower_fallback_reason(query: Query, ctx: ShardContext) -> str:
     pays. The classification mirrors _lower_flat_inner's decline points; when
     the inner lowering actually SUCCEEDS, the decline was lower_flat's
     similarity gate (DFR/IB/LM fields score host-side)."""
-    plan = _lower_flat_inner(query, ctx)
+    plan = _lower_top(query, ctx)
     if plan is not None:
-        if any(c.field in HOST_ONLY_FIELDS for c in plan.clauses):
+        if any(f in HOST_ONLY_FIELDS for f in _plan_fields(plan)):
             return "host_only_field"
         return "similarity_not_fused"
+    if isinstance(query, PhraseQuery):
+        # the phrases that stay on the host: a prefix on the last term, a
+        # slop (the host's sloppy frequency is an approximation of Lucene's:
+        # no exact semantics to hold a device program to), and a phrase of
+        # more terms than the phrase program's line holds
+        if query.prefix:
+            return "phrase_prefix"
+        if query.slop != 0:
+            return "sloppy_phrase"
+        return "long_phrase"
     if isinstance(query, MatchQuery):
         # the only non-lowering match query: fuzzy (empty analysis still
         # lowers — to an empty flat plan that scores nothing on-device)
@@ -577,9 +657,37 @@ def finalize_flat(plan: FlatPlan, ctx: ShardContext):
 # ---------------------------------------------------------------------------
 
 
+def finalize_phrase(plan: FlatPlan, ctx: ShardContext):
+    """A phrase plan's (weight float32, TFN_* mode, norm cache float32 [256])
+    against shard/global stats, each in HostScorer._eval_phrase's own
+    arithmetic: ONE weight from the sum of the terms' idfs (a term repeated
+    in the phrase counts twice, a term no document holds not at all), boost
+    and (k1 + 1), or for TF-IDF the sum squared and the queryNorm of
+    _weight_prepass."""
+    from ..ops.device_index import TFN_BM25, TFN_TFIDF
+
+    ph = plan.phrase
+    sim = ctx.similarity_for(ph.field)
+    max_doc = ctx.max_doc
+    cache = sim.norm_cache(ctx.field_stats(ph.field), max_doc)
+    dfs = [ctx.doc_freq(ph.field, t) for t in ph.terms]
+    idfs = sum(float(sim.idf(df, max_doc)) for df in dfs if df > 0)
+    idf_sum = np.float32(idfs)
+    if isinstance(sim, BM25Similarity):
+        return (np.float32(idf_sum * plan.boost * (sim.k1 + 1.0)), TFN_BM25,
+                cache)
+    qn = 1.0
+    ssw = float((idfs * plan.boost) ** 2)
+    if isinstance(ctx.default_similarity, TFIDFSimilarity) and ssw > 0:
+        qn = float(TFIDFSimilarity.query_norm(ssw))
+    return (np.float32(idf_sum * idf_sum * plan.boost) * np.float32(qn),
+            TFN_TFIDF, cache)
+
+
 def _is_plain(p: FlatPlan) -> bool:
     """A plan the sparse candidate path serves: scoring clauses and no tail."""
-    return p.fs is None and p.filt is None and p.const is None
+    return p.fs is None and p.filt is None and p.const is None \
+        and p.phrase is None
 
 
 def _all_plain(plans: list[FlatPlan], tails) -> bool:
@@ -633,8 +741,8 @@ def _flat_groups(plans: list[FlatPlan], tails=None) -> dict:
     one launch a segment answers together. A group's first element names its
     kind (`search.batcher.kinds` in /_nodes/stats): plain, function_score
     (by spec; scored and unscored apart), filtered (scored and unscored
-    apart), and by tail aggs and sorted (by the tail's key; scored and
-    unscored apart, as _segment_batches does not mix them)."""
+    apart), phrase, and by tail aggs and sorted (by the tail's key; scored
+    and unscored apart, as _segment_batches does not mix them)."""
     groups: dict = {}
     for i, p in enumerate(plans):
         tail = tails[i] if tails else None
@@ -645,6 +753,8 @@ def _flat_groups(plans: list[FlatPlan], tails=None) -> dict:
                      p.const is not None)
         elif p.filt is not None or p.const is not None:
             group = ("filtered", p.const is not None)
+        elif p.phrase is not None:
+            group = ("phrase",)
         else:
             group = ("plain",)
         groups.setdefault(group, []).append(i)
@@ -659,7 +769,8 @@ def execute_flat_batch(plans: list[FlatPlan], ctx: ShardContext, k: int,
     ride the dense kernel with the function tail fused in (_execute_flat_fs);
     filtered plans ride the dense kernel with per-query mask rows, and plans
     with no scoring clause the same tail behind the unscored launch ABI
-    (launch_flat_filtered, a launch for each kind). `tails` (a FlatTail or
+    (launch_flat_filtered, a launch for each kind); exact phrases ride the
+    phrase program over the positions plane (launch_flat_phrase). `tails` (a FlatTail or
     None a plan) sends a plan to the fused aggregation or the field-sort
     program with the plans that share its key (launch_flat_aggs,
     launch_flat_sorted), and its result is that executor's: everything else
@@ -669,9 +780,9 @@ def execute_flat_batch(plans: list[FlatPlan], ctx: ShardContext, k: int,
     return _run_flat_groups(plans, ctx, k, tails, _flat_groups(plans, tails))
 
 
-# the groups that launch without a pull (launch_flat_filtered / _aggs /
-# _sorted) and share the batch's one device_get
-_ONE_PULL_KINDS = ("filtered", "aggs", "sorted")
+# the groups that launch without a pull (launch_flat_filtered / _phrase /
+# _aggs / _sorted) and share the batch's one device_get
+_ONE_PULL_KINDS = ("filtered", "phrase", "aggs", "sorted")
 
 
 def _run_flat_groups(plans: list[FlatPlan], ctx: ShardContext, k: int,
@@ -700,6 +811,8 @@ def _run_flat_groups(plans: list[FlatPlan], ctx: ShardContext, k: int,
                 members = [plans[i] for i in chunk]
                 if kind == "filtered":
                     handle = launch_flat_filtered(members, ctx, k)
+                elif kind == "phrase":
+                    handle = launch_flat_phrase(members, ctx, k)
                 elif kind == "aggs":
                     handle = launch_flat_aggs(members, ctx, k, tail.fields,
                                               tail.bucket_aggs)
@@ -1569,6 +1682,120 @@ def launch_flat_filtered(plans: list[FlatPlan], ctx: ShardContext, k: int):
             gdocs = np.where(valid, docs.astype(np.int64) + base,
                              np.int64(2**62))
             seg_hits.append((np.where(valid, scores, -np.inf), gdocs))
+        return _merge_seg_hits(seg_hits, totals, Q, k,
+                               breaker=ctx.breaker("request"))
+
+    return launched, finish
+
+
+def _phrase_launches(by_rung: dict):
+    """((field, rung), plans) a launch of one segment: the plans of the first
+    rung together (_group_width: 1 or 4 a launch), those of a longer rung one
+    a launch. A merge costs the device what its lines hold, so four long
+    lines in one launch save a dispatch and nothing else, and cost a program
+    of their own that a warm-up seldom meets and four times the temporaries
+    (3.7 GB a plan at the last rung)."""
+    from ..ops.scoring import PHRASE_RUNGS
+
+    for key, group in by_rung.items():
+        if key[1] == PHRASE_RUNGS[0]:
+            yield key, group
+        else:
+            for member in group:
+                yield key, [member]
+
+
+def launch_flat_phrase(plans: list[FlatPlan], ctx: ShardContext, k: int):
+    """Phrase plans (at most _GROUP_WIDTH) over every segment's positions
+    plane, faulted in by the first phrase a segment's field meets
+    (device_index.ensure_positions). On a segment the plans launch by the
+    rung of their longest term's block rows (scoring.phrase_rung): those of
+    the first rung together at the group's width (_group_width: 1 or 4 plans
+    a launch, rows past them matching nothing), those of a longer rung one a
+    launch (_phrase_launches), so a rare phrase does not ride a head term's
+    line. NO pull: returns (device outputs a launch, finish),
+    or None where a segment's positions do not fit the plane's keys or a term
+    outgrows the last rung (the host serves every plan). `finish(pulled)`
+    takes the outputs on the host (the batch's one device_get:
+    _run_flat_groups) and returns TopDocs a plan. The host's share, the block
+    slices of each term and the operands' one device_put, is the span
+    `shard.phrase_plan` inside `dispatch.stage`."""
+    from ..ops.device_index import (ensure_positions, ensure_sim_tables,
+                                    packed_for)
+    from ..ops.scoring import (LAUNCHES, phrase_operands, phrase_rung,
+                               score_phrase_batch_async)
+
+    Q = len(plans)
+    finals = [finalize_phrase(p, ctx) for p in plans]
+    sim_tables = {p.phrase.field: (mode, cache)
+                  for p, (_w, mode, cache) in zip(plans, finals)}
+    shifts = [[max(p.phrase.rel_pos) - r for r in p.phrase.rel_pos]
+              for p in plans]
+    breaker = ctx.breaker("fielddata")
+    fields = sorted({p.phrase.field for p in plans})
+    staged = []  # a segment: (packed, sim, planes, {(field, rung): [(plan index, entry)]}, t0)
+    for seg in ctx.searcher.segments:
+        packed = packed_for(seg, breaker=breaker, owner=ctx.index_name)
+        sim = ensure_sim_tables(packed, sim_tables)
+        planes = {f: ensure_positions(seg, packed, f, breaker=breaker)
+                  for f in fields}
+        t0 = time.monotonic()
+        by_rung: dict = {}
+        for qi, (plan, (w, _mode, _cache)) in enumerate(zip(plans, finals)):
+            ph = plan.phrase
+            plane = planes[ph.field]
+            if not plane.room_for(max(shifts[qi])):
+                return None
+            tids = [seg.term_id(ph.field, t) for t in ph.terms]
+            if any(tid is None for tid in tids):
+                continue  # a term the segment lacks: no match here
+            blocks = [plane.blocks_for_term(tid) for tid in tids]
+            rung = phrase_rung(max(b1 - b0 for b0, b1 in blocks))
+            if rung is None:
+                return None
+            by_rung.setdefault((ph.field, rung), []).append((qi, (
+                w, sim.fid[ph.field],
+                [(b0, b1 - b0, shift)
+                 for (b0, b1), shift in zip(blocks, shifts[qi])])))
+        staged.append((packed, sim, planes, by_rung, t0))
+    launched = []
+    members = []  # a launch: (segment index, plan indexes)
+    for si, (packed, sim, planes, by_rung, t0) in enumerate(staged):
+        for (field, rung), group in _phrase_launches(by_rung):
+            qplane = phrase_operands([entry for _qi, entry in group],
+                                     _group_width(len(group)))
+            try:
+                if _DEVICE_FAULTS.active:
+                    _DEVICE_FAULTS.check("compile:phrase")
+                with compile_tag("phrase"):
+                    launched.append(score_phrase_batch_async(
+                        planes[field], sim, qplane, rung, max(k, 1),
+                        note_t0=t0))
+            except Exception as e:  # noqa: BLE001 — re-raised tagged
+                raise _tag_domain(e, "compile:phrase")
+            members.append((si, [qi for qi, _entry in group]))
+            t0 = time.monotonic()
+    LAUNCHES.bump(phrase_searches=Q)
+    n_docs = [min(entry[0].doc_pad, seg.doc_count)
+              for seg, entry in zip(ctx.searcher.segments, staged)]
+
+    def finish(pulled: list) -> list[TopDocs]:
+        totals = np.zeros(Q, dtype=np.int64)
+        n_seg = len(staged)
+        kk = max(k, 1)
+        scores = np.full((n_seg, Q, kk), -np.inf, np.float32)
+        docs = np.zeros((n_seg, Q, kk), np.int64)
+        for (si, qis), (s, d, tq) in zip(members, pulled):
+            n = len(qis)
+            scores[si, qis, : s.shape[1]] = s[:n]
+            docs[si, qis, : s.shape[1]] = d[:n]
+            totals[qis] += tq[:n]
+        seg_hits = []
+        for si, base in enumerate(ctx.searcher.bases):
+            valid = (docs[si] < n_docs[si]) & np.isfinite(scores[si])
+            seg_hits.append((np.where(valid, scores[si], -np.inf),
+                             np.where(valid, docs[si] + base,
+                                      np.int64(2**62))))
         return _merge_seg_hits(seg_hits, totals, Q, k,
                                breaker=ctx.breaker("request"))
 
@@ -2819,7 +3046,7 @@ def search_shard_batch(ctx: ShardContext, queries: list[Query], k: int,
     flat_plans: list[FlatPlan] = []
     if extra_filter is None:
         for i, q in enumerate(queries):
-            plan = lower_flat(q, ctx) if use_device else None
+            plan = lower_flat(q, ctx, phrases=True) if use_device else None
             if plan is not None:
                 flat_idx.append(i)
                 flat_plans.append(plan)

@@ -146,7 +146,7 @@ class ShardQueryResult:
 # surfaced via nodes stats "search_serving"; in-process test clusters share the
 # process, so treat these as process rollups, like the script registry)
 SERVING_COUNTERS = {
-    "device_sparse": 0,  # flat top-k via the sparse candidate kernel
+    "device_sparse": 0,  # flat top-k via the sparse candidate kernel, or the phrase program
     "device_filtered": 0,  # filtered dense kernel
     "device_function_score": 0,  # fs rows/script kernels
     "device_aggs": 0,  # fused agg launch (metric/bucket)
@@ -337,7 +337,7 @@ def execute_query_phase(ctx: ShardContext, req: ParsedSearchRequest,
 
     if not needs_masks:
         t_low = time.monotonic() if prof is not None else 0.0
-        plan = lower_flat(req.query, ctx) if use_device else None
+        plan = lower_flat(req.query, ctx, phrases=True) if use_device else None
         if plan is not None and plan.const is not None and k == 0:
             # a bare count (`_count` is a search of size 0): summing a mask on
             # the host launches nothing, packs nothing and compiles nothing.
@@ -354,7 +354,8 @@ def execute_query_phase(ctx: ShardContext, req: ParsedSearchRequest,
             # tail behind its own ABI, whether or not it carries a filter
             masked = plan.filt is not None or plan.const is not None
             fams = ("function_score",) if plan.fs is not None else \
-                ("filtered",) if masked else ("sparse", "dense")
+                ("filtered",) if masked else \
+                ("phrase",) if plan.phrase is not None else ("sparse", "dense")
             dom = _blocked_domain(ctx, fams)
             if dom is not None:
                 _device_degraded(dom)  # open fault domain: host serves, no launch
@@ -374,14 +375,18 @@ def execute_query_phase(ctx: ShardContext, req: ParsedSearchRequest,
                     degraded = True
                 else:
                     _note_device_ok(ctx, fams)
-                    _count("device_function_score" if plan.fs is not None
-                           else "device_filtered" if masked
-                           else "device_sparse")
-                    return ShardQueryResult(
-                        total=td.total, docs=[(s, d, None) for s, d in td.hits],
-                        max_score=td.max_score, suggest=suggest_out,
-                        shard_id=shard_id,
-                    )
+                    # None: a phrase the positions plane cannot hold
+                    # (execute.launch_flat_phrase); the host scorer answers
+                    if td is not None:
+                        _count("device_function_score" if plan.fs is not None
+                               else "device_filtered" if masked
+                               else "device_sparse")
+                        return ShardQueryResult(
+                            total=td.total,
+                            docs=[(s, d, None) for s, d in td.hits],
+                            max_score=td.max_score, suggest=suggest_out,
+                            shard_id=shard_id,
+                        )
         _count("host")
         td = _host_topk(ctx, req, k, deadline)
         return ShardQueryResult(total=td.total, docs=[(s, d, None) for s, d in td.hits],
@@ -394,8 +399,8 @@ def execute_query_phase(ctx: ShardContext, req: ParsedSearchRequest,
         # again internally; this records the plan shape (or the lowering
         # fallback reason) once, before any branch runs
         t_low = time.monotonic()
-        _prof_record_plan(prof, lower_flat(req.query, ctx) if use_device
-                          else None, req, ctx, use_device)
+        _prof_record_plan(prof, lower_flat(req.query, ctx, phrases=True)
+                          if use_device else None, req, ctx, use_device)
         prof.phase_s("lower", time.monotonic() - t_low)
 
     # device fault-domain state for the mask-needing branches: an open domain

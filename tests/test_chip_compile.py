@@ -118,6 +118,34 @@ def test_dense_overflow_launch_compiles_for_v5e(one_chip, simple):
     assert "while" in compiled.as_text()
 
 
+@pytest.mark.parametrize("n_plans,rung", [(1, 0), (4, 0), (1, -1)],
+                         ids=["alone-first", "four-first", "alone-last"])
+def test_phrase_launch_compiles_for_v5e(one_chip, n_plans, rung):
+    """The exact-phrase program over the cell `wiki.phrase`'s positions plane
+    (262,144 rows of keys at 50,000 documents, doc_pad 65,536: 15 bits of
+    position) at both group widths on the ladder's first rung and alone on
+    its last, where a head term's whole list rides a line of 16.8M keys:
+    gathers of block rows and merges (the only sort is top_k's own, over
+    1,280 candidates)."""
+    from elasticsearch_tpu.common.jaxenv import compile_tag
+    from elasticsearch_tpu.ops.scoring import (
+        _P_COLS, PHRASE_RUNGS, PHRASE_SLOTS, _get_phrase_compiled)
+
+    rows = PHRASE_RUNGS[rung]
+    args = _shapes(
+        one_chip, ((262_144, BLOCK), "int32"),  # the plane's keys
+        ((1, 256), "float32"), ((1,), "int32"),  # SimTables caches, modes
+        ((n_plans, _P_COLS), "int32"))  # the launch's one operand plane
+    fn = _get_phrase_compiled(n_plans, rows, 10, 31 - 16)
+    with compile_tag("phrase"):
+        compiled = fn.lower(*args).compile()
+    assert "tpu_custom_call" not in compiled.as_text()  # composed, no kernel
+    mem = compiled.memory_analysis()
+    line = n_plans * PHRASE_SLOTS * rows * BLOCK * 4  # one copy of the keys
+    assert line <= mem.temp_size_in_bytes < 6 << 30
+    assert mem.argument_size_in_bytes >= 262_144 * BLOCK * 4
+
+
 DOC_PAD_LOGS = 1 << 20  # a million log events in one force-merged segment
 
 

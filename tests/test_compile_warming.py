@@ -315,6 +315,10 @@ QUERIES = [
      "sort": [{"n": "desc"}], "size": 5},
     {"query": {"range": {"n": {"lt": 40}}}, "size": 0, "request_cache": False,
      "aggs": {"n": {"stats": {"field": "n"}}}},
+    # an exact phrase: the phrase program's launch is recorded and replayed
+    # (scoring.phrase); the restarted node faults the positions plane in on
+    # the path, which compiles nothing
+    {"query": {"match_phrase": {"body": "alpha beta"}}, "size": 10},
 ]
 
 

@@ -184,9 +184,9 @@ class TestLiveLedger:
             (shard_rows,) = led["shards"].values()
             for row in shard_rows:
                 assert set(row["tiers"]) == {
-                    "postings", "dense_plane", "sim_tables", "agg_rows",
-                    "agg_limbs", "sort_keys", "norms", "filter_masks",
-                    "function_rows"}
+                    "postings", "dense_plane", "positions_plane",
+                    "sim_tables", "agg_rows", "agg_limbs", "sort_keys",
+                    "norms", "filter_masks", "function_rows"}
 
             # /_nodes/stats device section (+ compile family rollup)
             st = c.nodes_stats(metric="device")

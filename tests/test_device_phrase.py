@@ -1,0 +1,624 @@
+"""Exact phrases on the device (ops/device_index.py positions plane, ops/scoring.py
+phrase program, search/execute.py launch_flat_phrase).
+
+On the CPU, seeded and small: the device's answer to a `match_phrase` against the
+host scorer (`HostScorer._eval_phrase`, the semantics) and against the benchmark's
+plain reference (`benchmark/queries/phrase_terms.py` `expected`: numpy over the
+token stream, nothing of the program): totals and ids in order exactly, scores to
+1e-6 relative. The forms that stay on the host reach it under a named reason, the
+plane is faulted in by the first phrase and not before, and every counter the
+benchmark reads moves as stated."""
+
+import json
+import threading
+
+import numpy as np
+import pytest
+
+from benchmark.harness import registry
+from benchmark.harness.reference import Reference, word
+from elasticsearch_tpu.common.breaker import CircuitBreakerService
+from elasticsearch_tpu.common.deadline import NO_DEADLINE
+from elasticsearch_tpu.common.errors import CircuitBreakingError
+from elasticsearch_tpu.common.settings import Settings
+from elasticsearch_tpu.index import Engine
+from elasticsearch_tpu.mapper import MapperService
+from elasticsearch_tpu.node import Node
+from elasticsearch_tpu.ops import scoring
+from elasticsearch_tpu.ops.device_index import (
+    POS_DEAD_CODE, PositionsPlane, ensure_positions, packed_for,
+    packed_tier_bytes,
+    positions_mark_base)
+from elasticsearch_tpu.search import ShardContext, parse_query, search_shard
+from elasticsearch_tpu.search.batcher import DeviceBatcher
+from elasticsearch_tpu.search.execute import (
+    execute_flat_batch, lower_fallback_reason, lower_flat, plan_profile,
+    search_shard_batch)
+from elasticsearch_tpu.search.similarity import SimilarityService
+from elasticsearch_tpu.transport.local import LocalTransportRegistry
+
+pytestmark = pytest.mark.serving
+
+CORPUS = {"vocabulary": 400, "mean_length": 30, "min_length": 5, "max_length": 90,
+          "zipf_a": 1.25, "text_field": "body",
+          "collocations": {"count": 40, "lengths": {"2": 0.5, "3": 0.3, "4": 0.2},
+                           "df_share": [0.01, 0.2]}}
+N_DOCS = 500  # doc_pad 512
+
+
+def _shard(tmp, docs, sim="BM25", refresh_at=(), breakers=None):
+    """A shard over `docs` (a `_source` each), a segment a refresh."""
+    settings = Settings.from_flat({"index.similarity.default.type": sim})
+    svc = MapperService(settings)
+    eng = Engine(str(tmp), svc)
+    for i, d in enumerate(docs):
+        eng.index("doc", str(i), d)
+        if i in refresh_at:
+            eng.refresh()
+    eng.refresh()
+    sims = SimilarityService(settings, mapper_service=svc)
+
+    def ctx():
+        return ShardContext(eng.acquire_searcher(), svc, sims, index_name="idx",
+                            breakers=breakers)
+
+    return eng, ctx
+
+
+def _phrase(text, field="body", **more):
+    return parse_query({"match_phrase": {field: {"query": text, **more}}})
+
+
+def _same(dev, host, rtol=1e-6):
+    assert dev.total == host.total
+    assert [d for _s, d in dev.hits] == [d for _s, d in host.hits]
+    np.testing.assert_allclose([s for s, _d in dev.hits],
+                               [s for s, _d in host.hits], rtol=rtol)
+
+
+def _both(ctx, query, k=10):
+    assert lower_flat(query, ctx, phrases=True).phrase is not None
+    before = scoring.LAUNCHES.snapshot()["phrase_searches"]
+    dev = search_shard(ctx, query, k, use_device=True)
+    assert scoring.LAUNCHES.snapshot()["phrase_searches"] == before + 1
+    return dev, search_shard(ctx, query, k, use_device=False)
+
+
+# ---------------------------------------------------------------------------
+# a seeded corpus with planted collocations: host scorer and plain reference
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def planted(tmp_path_factory):
+    gen = registry.module("corpora", "zipf_collocations")
+    corpus = gen.generate(CORPUS, 39, N_DOCS)
+    docs = [json.loads(s) for s in corpus.sources(0, N_DOCS)]
+    _eng, ctx = _shard(tmp_path_factory.mktemp("planted"), docs)
+    return corpus, Reference(corpus, 1.2, 0.75), ctx()
+
+
+def _pick(planted, n_terms):
+    """The corpus' collocations of `n_terms` terms that some document holds."""
+    corpus, ref, _ctx = planted
+    fam = registry.module("queries", "phrase_terms")
+    out = [t for t in corpus.collocations
+           if len(t) == n_terms and fam.phrase_freq(ref, t).any()]
+    assert out
+    return out[:6]
+
+
+@pytest.mark.parametrize("n_terms", [2, 3, 4])
+def test_device_answers_as_the_host_and_the_plain_reference(planted, n_terms):
+    corpus, ref, ctx = planted
+    fam = registry.module("queries", "phrase_terms")
+    for terms in _pick(planted, n_terms):
+        dev, host = _both(ctx, _phrase(" ".join(word(t) for t in terms)))
+        _same(dev, host)
+        scores, matched = fam.expected(ref, {"terms": list(terms)})
+        total, ranked = ref.top(scores, matched, 10)
+        assert dev.total == total > 0
+        got = np.array([d for _s, d in dev.hits])
+        want = ranked[:10]
+        # ids in the reference's order wherever its scores are apart
+        apart = np.abs(np.diff(scores[want])) > 1e-6 * scores[want][:-1]
+        clear = np.concatenate([[True], apart]) & np.concatenate([apart, [True]])
+        assert (got[clear] == want[clear]).all()
+        np.testing.assert_allclose([s for s, _d in dev.hits], scores[want],
+                                   rtol=1e-6)
+        np.testing.assert_allclose([s for s, _d in dev.hits], scores[got],
+                                   rtol=1e-6)
+
+
+def test_head_term_bigrams_answer_as_the_host(planted):
+    corpus, ref, ctx = planted
+    head = [int(t) for t in ref.by_df[:3]]
+    for a in head:
+        for b in head:
+            _same(*_both(ctx, _phrase(f"{word(a)} {word(b)}")))
+
+
+@pytest.mark.parametrize("n_plans", [1, 4, 5])
+def test_a_batch_launches_at_both_widths(planted, n_plans):
+    """1 plan launches alone, 4 together, 5 as 4 and 1 (_GROUP_WIDTH): each
+    plan's answer is what it is alone."""
+    corpus, ref, ctx = planted
+    texts = [" ".join(word(t) for t in terms)
+             for n in (2, 3, 4) for terms in _pick(planted, n)][:n_plans]
+    assert len(texts) == n_plans
+    queries = [_phrase(t) for t in texts]
+    before = scoring.LAUNCHES.snapshot()
+    got = search_shard_batch(ctx, queries, 10)
+    after = scoring.LAUNCHES.snapshot()
+    assert after["phrase_searches"] - before["phrase_searches"] == n_plans
+    assert after["phrase"] - before["phrase"] == (2 if n_plans == 5 else 1)
+    rows = scoring.PHRASE_RUNGS[0]
+    width = {1: 1, 4: 4, 5: 5}[n_plans]
+    assert after["position_bytes"] - before["position_bytes"] == \
+        width * scoring.PHRASE_SLOTS * rows * 128 * 4
+    # the padding is every row of those that no term of a plan named
+    (seg,) = ctx.searcher.segments
+    plane = packed_for(seg).positions["body"]
+    named = 0
+    for text in texts:
+        for w in text.split():
+            b0, b1 = plane.blocks_for_term(seg.term_id("body", w))
+            named += b1 - b0
+    assert after["position_pad_bytes"] - before["position_pad_bytes"] == \
+        (width * scoring.PHRASE_SLOTS * rows - named) * 128 * 4
+    for q, td in zip(queries, got):
+        _same(td, search_shard(ctx, q, 10, use_device=False))
+
+
+def test_longer_lists_ride_longer_rungs_alone_and_the_longest_go_to_the_host(
+        planted, monkeypatch):
+    """With the ladder cut down to 2 / 4 / 8 block rows a quarter the corpus'
+    own terms meet every rung: plans of the first rung launch together, those
+    of a longer rung one a launch, and a term past the last rung sends its
+    batch to the host; every answer is the host's."""
+    corpus, ref, ctx = planted
+    monkeypatch.setattr(scoring, "PHRASE_RUNGS", (2, 4, 8))
+    (seg,) = ctx.searcher.segments
+    plane = packed_for(seg).positions["body"]
+    by_rung: dict = {}
+    for t in ref.by_df[:60]:
+        b0, b1 = plane.blocks_for_term(seg.term_id("body", word(int(t))))
+        by_rung.setdefault(scoring.phrase_rung(b1 - b0), []).append(word(int(t)))
+    assert set(by_rung) == {None, 2, 4, 8}
+    rare = by_rung[2][0]
+    for rung, launches in ((2, 1), (4, 2), (8, 2)):
+        texts = [f"{w} {rare}" for w in by_rung[rung][:2]]
+        queries = [_phrase(t) for t in texts]
+        before = scoring.LAUNCHES.snapshot()
+        got = search_shard_batch(ctx, queries, 10)
+        after = scoring.LAUNCHES.snapshot()
+        assert after["phrase"] - before["phrase"] == launches
+        assert after["position_bytes"] - before["position_bytes"] == \
+            (4 if rung == 2 else 2) * scoring.PHRASE_SLOTS * rung * 128 * 4
+        for q, td in zip(queries, got):
+            _same(td, search_shard(ctx, q, 10, use_device=False))
+    queries = [_phrase(f"{by_rung[None][0]} {rare}"), _phrase(f"{rare} {rare}")]
+    before = scoring.LAUNCHES.snapshot()
+    got = search_shard_batch(ctx, queries, 10)
+    after = scoring.LAUNCHES.snapshot()
+    assert (after["phrase"], after["phrase_searches"]) == \
+        (before["phrase"], before["phrase_searches"])
+    for q, td in zip(queries, got):
+        _same(td, search_shard(ctx, q, 10, use_device=False), rtol=0)
+
+
+def test_a_phrase_beside_plain_and_filtered_plans_in_one_batch(planted):
+    corpus, ref, ctx = planted
+    (terms,) = _pick(planted, 2)[:1]
+    a, b = word(terms[0]), word(terms[1])
+    queries = [
+        _phrase(f"{a} {b}"),
+        parse_query({"match": {"body": f"{a} {b}"}}),
+        parse_query({"filtered": {"query": {"match": {"body": a}},
+                                  "filter": {"term": {"body": b}}}}),
+        _phrase(f"{b} {a}"),
+    ]
+    plans = [lower_flat(q, ctx, phrases=True) for q in queries]
+    assert [p.phrase is not None for p in plans] == [True, False, False, True]
+    for q, td in zip(queries, execute_flat_batch(plans, ctx, 10)):
+        _same(td, search_shard(ctx, q, 10, use_device=False))
+
+
+# ---------------------------------------------------------------------------
+# edge cases by hand
+# ---------------------------------------------------------------------------
+
+HAND = [
+    {"body": "a b c d"},
+    {"body": "a a a b"},
+    {"body": "x a b a b"},
+    {"body": "b a"},
+    {"body": "c d a b c d"},
+    {"body": ["a b", "c d"]},          # multi-valued: b and c are not neighbours
+    {"body": "q r s d"},               # ends in d ...
+    {"body": "a y z"},                 # ... and the next starts with a
+    {"body": "the quick brown fox"},
+] + [{"body": f"pad{i} a x{i} b"} for i in range(40)]
+
+PHRASES = ["a b", "a a", "a a a", "a b c d", "b c", "d a", "a nosuch", "b a b",
+           "c d a b", "a b a b"]
+
+
+@pytest.fixture(scope="module", params=["BM25", "default"])
+def hand(request, tmp_path_factory):
+    eng, ctx = _shard(tmp_path_factory.mktemp("hand" + request.param), HAND,
+                      sim=request.param, refresh_at=(4, 20))
+    return eng, ctx
+
+
+@pytest.mark.parametrize("text", PHRASES)
+def test_edge_cases_answer_as_the_host(hand, text):
+    """Repeated terms, an absent term (no match), a phrase that would straddle
+    two documents or two values (no match), three segments; BM25 and TF-IDF."""
+    _eng, ctx = hand
+    c = ctx()
+    assert len(c.searcher.segments) == 3
+    dev, host = _both(c, _phrase(text))
+    _same(dev, host, rtol=0)  # the host's own float operations: bitwise
+    if text == "a nosuch":
+        assert dev.total == 0
+    if text == "d a":  # inside document 4; never from document 6 into 7
+        assert [d for _s, d in dev.hits] == [4]
+    if text == "b c":  # inside one document, never across two values
+        assert [d for _s, d in dev.hits] == [0, 4]
+
+
+def test_deletes_and_a_delta_segment(tmp_path):
+    eng, ctx = _shard(tmp_path, HAND, refresh_at=(20,))
+    q = _phrase("a b")
+    c = c_old = ctx()
+    dev, host = _both(c, q)
+    _same(dev, host)
+    planes = [packed_for(s).positions["body"] for s in c.searcher.segments]
+    eng.delete("doc", "0")
+    eng.delete("doc", "2")
+    eng.refresh()
+    c = ctx()
+    dev2, host2 = _both(c, q)
+    _same(dev2, host2)
+    assert dev2.total == dev.total - 2
+    # a tombstone re-masks the plane from its raw host copy, as the postings:
+    # no second build, the deleted documents' markers under the dead code
+    seg = c.searcher.segments[0]
+    plane = packed_for(seg).positions["body"]
+    assert plane is not planes[0] and plane.host_keys is planes[0].host_keys
+    assert ensure_positions(seg, packed_for(seg), "body") is plane
+    field = np.asarray(plane.keys) & ((1 << plane.pos_bits) - 1)
+    dead = field == plane.mark_base + (POS_DEAD_CODE << 4)
+    gone = np.flatnonzero(~seg.live)
+    assert len(gone) == 2
+    assert sorted(set((np.asarray(plane.keys)[dead] >> plane.pos_bits).tolist())) \
+        == gone.tolist()
+    assert dead.sum() == sum(  # a marker a posting of a deleted document
+        int(np.isin(seg.post_docs[seg.post_offsets[t]: seg.post_offsets[t + 1]],
+                    gone).sum()) for t in seg.term_dict["body"].values())
+    assert not (np.asarray(planes[0].keys) != plane.host_keys).any()
+    # the searcher acquired before the deletes keeps its documents and its plane
+    dev_old, host_old = _both(c_old, q)
+    _same(dev_old, host_old)
+    assert dev_old.total == dev.total
+    eng.index("doc", "new", {"body": "fresh a b"})
+    eng.refresh()
+    c = ctx()
+    assert len(c.searcher.segments) == 3
+    dev3, host3 = _both(c, q)
+    _same(dev3, host3)
+    assert dev3.total == dev2.total + 1
+
+
+def test_a_merged_segment_faults_its_own_plane(tmp_path):
+    eng, ctx = _shard(tmp_path, HAND, refresh_at=(4, 20))
+    q = _phrase("a b")
+    dev, _host = _both(ctx(), q)
+    eng.optimize(max_num_segments=1)
+    c = ctx()
+    (seg,) = c.searcher.segments
+    assert packed_for(seg).positions == {}
+    dev2, host2 = _both(c, q)
+    _same(dev2, host2)
+    assert dev2.total == dev.total
+    assert packed_for(seg).positions["body"].keys is not None
+
+
+def test_analyzed_gaps_keep_their_places(tmp_path):
+    settings = {"index.analysis.analyzer.default.type": "standard",
+                "index.analysis.analyzer.default.stopwords": "of,the"}
+    svc = MapperService(Settings.from_flat(settings))
+    eng = Engine(str(tmp_path), svc)
+    for i, text in enumerate(["king of the hill", "king hill", "king x y hill",
+                              "hill of the king"]):
+        eng.index("doc", str(i), {"body": text})
+    eng.refresh()
+    ctx = ShardContext(eng.acquire_searcher(), svc, SimilarityService(
+        Settings.from_flat(settings), mapper_service=svc))
+    q = _phrase("king of the hill")
+    plan = lower_flat(q, ctx, phrases=True)
+    assert plan.phrase.terms == ("king", "hill")
+    assert plan.phrase.rel_pos == (0, 3)
+    dev, host = _both(ctx, q)
+    _same(dev, host, rtol=0)
+    assert [d for _s, d in dev.hits] == [0, 2]
+
+
+# ---------------------------------------------------------------------------
+# the plane: absent until the first phrase, booked under the breaker, ranged
+# ---------------------------------------------------------------------------
+
+
+def test_the_plane_is_faulted_in_by_the_first_phrase(tmp_path):
+    breakers = CircuitBreakerService(Settings.from_flat({}))
+    fielddata = breakers.breaker("fielddata")
+    eng, ctx = _shard(tmp_path, HAND, breakers=breakers)
+    c = ctx()
+    (seg,) = c.searcher.segments
+    search_shard(c, parse_query({"match": {"body": "a b"}}), 10)
+    packed = packed_for(seg)
+    assert packed.positions == {}
+    assert packed_tier_bytes(packed)["positions_plane"] == 0
+    seen = []
+    grant = fielddata.add_estimate_and_maybe_break
+
+    def watch(n, label=""):
+        seen.append((label, n))
+        return grant(n, label)
+
+    fielddata.add_estimate_and_maybe_break = watch
+    try:
+        search_shard(c, _phrase("a b"), 10)
+    finally:
+        fielddata.add_estimate_and_maybe_break = grant
+    plane = packed.positions["body"]
+    resident = int(np.prod(plane.keys.shape)) * 4
+    assert packed_tier_bytes(packed)["positions_plane"] == resident > 0
+    (booked,) = [n for label, n in seen if label == "<positions>body"]
+    assert booked >= 2 * resident  # the host's staging and the device's copy
+    assert fielddata.used == 0  # transient, as every fault-in's
+    # a key an occurrence and a marker a posting, ascending inside each term
+    keys = np.asarray(plane.keys).reshape(-1)
+    tid = seg.term_id("body", "a")
+    b0, b1 = plane.blocks_for_term(tid)
+    mine = keys[b0 * 128: b1 * 128]
+    real = mine[mine != np.int32(2**31 - 1)]
+    docs, freqs = seg.postings("body", "a")
+    assert len(real) == int(freqs.sum()) + len(docs)
+    assert (np.diff(real) > 0).all()
+    markers = (real & ((1 << plane.pos_bits) - 1)) >= plane.mark_base
+    assert markers.sum() == len(docs)
+
+
+def test_a_tripped_breaker_sends_the_phrase_to_the_host(tmp_path):
+    eng, ctx = _shard(tmp_path, HAND)
+    c = ctx()
+    search_shard(c, parse_query({"match": {"body": "a b"}}), 10)  # packs
+    c.breakers = CircuitBreakerService(Settings.from_flat(
+        {"indices.breaker.total_budget": "64kb"}))
+    with pytest.raises(CircuitBreakingError) as err:
+        search_shard(c, _phrase("a b"), 10)
+    assert err.value.breaker == "fielddata"  # service degrades this to the host
+    assert packed_for(c.searcher.segments[0]).positions == {}
+
+
+def test_a_position_past_the_keys_range_goes_to_the_host(tmp_path, monkeypatch):
+    eng, ctx = _shard(tmp_path, HAND)
+    c = ctx()
+    (seg,) = c.searcher.segments
+    packed = packed_for(seg)
+    base = positions_mark_base(31 - 6)  # doc_pad 128 leaves 24 bits
+    seg.positions = seg.positions.copy()
+    seg.positions[int(np.argmax(seg.positions))] = base + 5
+    plane = ensure_positions(seg, packed, "body")
+    assert plane.keys is None and not plane.room_for(0)
+    q = _phrase("a b")
+    before = scoring.LAUNCHES.snapshot()
+    td = search_shard(c, q, 10, use_device=True)
+    assert scoring.LAUNCHES.snapshot()["phrase"] == before["phrase"]
+    _same(td, search_shard(c, q, 10, use_device=False), rtol=0)
+    ok = PositionsPlane(24, 100, np.zeros(2, np.int64), keys=object())
+    assert ok.room_for(15) and not ok.room_for(16)
+    assert not PositionsPlane(24, base - 3, None, keys=object()).room_for(3)
+
+
+def test_the_two_ceilings_of_the_plane_in_documents_a_segment():
+    """What the documents state (PERF.md section 6, ROADMAP S14, the
+    configuration's `reduced_why`), held to the code: one int32 key leaves
+    positions of up to 1,000 tokens room beside 262,144 documents a segment
+    and no further, and a term of more than 32,768 block rows (4,194,304
+    occurrences and markers) sends its phrases to the host."""
+    longest = 1000 + 15  # a position moved up by the largest shift
+    for doc_pad, fits in ((131_072, True), (262_144, True), (524_288, False)):
+        pos_bits = 31 - (doc_pad - 1).bit_length()
+        base = positions_mark_base(pos_bits)
+        assert (base > longest) is fits
+        if fits:  # the dead code and the largest shift stay under the sentinel
+            top = ((doc_pad - 1) << pos_bits) | (base + (POS_DEAD_CODE << 4) + 15)
+            assert top < 2**31 - 1
+    assert scoring.phrase_rung(scoring.PHRASE_RUNGS[-1]) == 32_768
+    assert scoring.phrase_rung(scoring.PHRASE_RUNGS[-1] + 1) is None
+    assert scoring.PHRASE_RUNGS[-1] * 128 == 4_194_304
+
+
+# ---------------------------------------------------------------------------
+# what stays on the host, and why
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("body,reason", [
+    ({"match_phrase": {"body": {"query": "a b", "slop": 1}}}, "sloppy_phrase"),
+    ({"match_phrase_prefix": {"body": "a b"}}, "phrase_prefix"),
+    ({"match_phrase": {"body": "a b c d a"}}, "long_phrase"),
+    ({"bool": {"must": [{"match_phrase": {"body": "a b"}}]}},
+     "non_term_subclause"),
+    ({"dis_max": {"queries": [{"match_phrase": {"body": "a b"}}]}},
+     "unsupported_query:DisMaxQuery"),
+    ({"multi_match": {"query": "a b", "fields": ["body"], "type": "phrase"}},
+     None),
+    ({"span_near": {"clauses": [{"span_term": {"body": "a"}},
+                                {"span_term": {"body": "b"}}],
+                    "slop": 0, "in_order": True}},
+     "unsupported_query:SpanNearQuery"),
+    ({"filtered": {"query": {"match_phrase": {"body": "a b"}},
+                   "filter": {"term": {"body": "c"}}}}, "non_flat_subquery"),
+    ({"function_score": {"query": {"match_phrase": {"body": "a b"}},
+                         "boost_factor": 2}}, "non_flat_subquery"),
+])
+def test_the_forms_that_stay_on_the_host(hand, body, reason):
+    _eng, ctx = hand
+    c = ctx()
+    q = parse_query(body)
+    assert lower_flat(q, c, phrases=True) is None
+    if reason is not None:
+        assert lower_fallback_reason(q, c) == reason
+    else:
+        assert lower_fallback_reason(q, c).startswith("unsupported_query:")
+    before = scoring.LAUNCHES.snapshot()["phrase_searches"]
+    dev = search_shard(c, q, 10, use_device=True)
+    assert scoring.LAUNCHES.snapshot()["phrase_searches"] == before
+    _same(dev, search_shard(c, q, 10, use_device=False), rtol=0)
+
+
+def test_one_term_is_a_term_plan_and_the_profile_names_the_phrase(hand):
+    _eng, ctx = hand
+    c = ctx()
+    one = lower_flat(_phrase("fox"), c)  # a term plan for every caller
+    assert one.phrase is None and [x.term for x in one.clauses] == ["fox"]
+    q = _phrase("a b", boost=2.0)
+    # a phrase plan only for the caller that can run one (the query phase)
+    assert lower_flat(q, c) is None
+    plan = lower_flat(q, c, phrases=True)
+    assert plan.boost == 2.0 and plan.clauses == []
+    prof = plan_profile(plan, q)
+    assert prof["phrase"] == {"field": "body", "terms": ["a", "b"],
+                              "rel_pos": [0, 1]}
+    assert plan_profile(one, _phrase("fox"))["phrase"] is None
+    _same(*_both(c, q), rtol=0)
+
+
+# ---------------------------------------------------------------------------
+# through the batcher and through REST: the counters the benchmark reads
+# ---------------------------------------------------------------------------
+
+
+def test_the_batcher_counts_the_kind(planted):
+    corpus, ref, ctx = planted
+    texts = [" ".join(word(t) for t in terms) for terms in _pick(planted, 2)[:3]]
+    plans = [lower_flat(_phrase(t), ctx, phrases=True) for t in texts] + [
+        lower_flat(parse_query({"match": {"body": texts[0]}}), ctx)]
+    b = DeviceBatcher(Settings.from_flat({"search.batch.linger_ms": "5000",
+                                          "search.batch.max_batch": "4"}))
+    out = [None] * len(plans)
+
+    def worker(i):
+        out[i] = b.execute(plans[i], ctx, 10, deadline=NO_DEADLINE)
+
+    threads = [threading.Thread(target=worker, args=(i,)) for i in range(4)]
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+        stats = b.stats()
+    finally:
+        b.shutdown()
+    assert stats["kinds"]["phrase"] == {"launches": 1, "coalesced": 3}
+    assert stats["kinds"]["plain"] == {"launches": 1, "coalesced": 1}
+    for t, td in zip(texts, out):
+        _same(td, search_shard(ctx, _phrase(t), 10, use_device=False))
+
+
+def _node(tmp, name, shards):
+    n = Node(name=name, registry=LocalTransportRegistry(), data_path=str(tmp),
+             settings={"index.similarity.default.type": "BM25"})
+    n.start([n.local_node.transport_address])
+    n.wait_for_master()
+    client = n.client()
+    client.create_index("lib", {"settings": {
+        "number_of_shards": shards, "number_of_replicas": 0,
+        "index.similarity.default.type": "BM25"}})
+    client.cluster_health(wait_for_status="green")
+    for i, d in enumerate(HAND):
+        client.index("lib", "doc", d, id=str(i))
+    client.refresh("lib")
+    return n, client
+
+
+def _launch_stats(client):
+    (stats,) = client.nodes_stats()["nodes"].values()
+    return stats
+
+
+def test_a_match_phrase_over_rest_reaches_the_phrase_program(tmp_path):
+    n, client = _node(tmp_path, "phrase_node", 1)
+    try:
+        s0 = _launch_stats(client)
+        assert s0["device"]["indices"].get("lib", {}).get(
+            "totals", {}).get("positions_plane", 0) == 0
+        body = {"query": {"match_phrase": {"body": "a b"}}, "size": 10}
+        got = client.search("lib", body)
+        s1 = _launch_stats(client)
+        launch0, launch1 = (s["search_serving"]["launch"] for s in (s0, s1))
+        assert launch1["phrase"] - launch0["phrase"] == 1
+        assert launch1["phrase_searches"] - launch0["phrase_searches"] == 1
+        assert launch1["position_bytes"] - launch0["position_bytes"] == \
+            scoring.PHRASE_SLOTS * scoring.PHRASE_RUNGS[0] * 128 * 4
+        assert s1["search_serving"]["device_sparse"] \
+            - s0["search_serving"]["device_sparse"] == 1
+        assert s1["search_serving"]["host"] == s0["search_serving"]["host"]
+        kinds0, kinds1 = (s["search"]["batcher"]["kinds"] for s in (s0, s1))
+        assert kinds1["phrase"]["launches"] - kinds0["phrase"]["launches"] == 1
+        assert s1["device"]["indices"]["lib"]["totals"]["positions_plane"] > 0
+        # the host's answer: the same phrase where only the host can go
+        host = client.search("lib", {"query": {"bool": {"must": [
+            {"match_phrase": {"body": "a b"}}]}}, "size": 10})
+        assert _launch_stats(client)["search_serving"]["host"] \
+            == s1["search_serving"]["host"] + 1
+        assert got["hits"]["total"] == host["hits"]["total"] > 0
+        assert [h["_id"] for h in got["hits"]["hits"]] == \
+            [h["_id"] for h in host["hits"]["hits"]]
+        np.testing.assert_allclose([h["_score"] for h in got["hits"]["hits"]],
+                                   [h["_score"] for h in host["hits"]["hits"]],
+                                   rtol=1e-6)
+        # a profiled request names the plan, and a sloppy one its reason
+        prof = client.search("lib", dict(body, profile=True))
+        (shard,) = prof["profile"]["shards"]
+        assert shard["plan"]["phrase"]["terms"] == ["a", "b"]
+        sloppy = client.search("lib", {"query": {"match_phrase": {"body": {
+            "query": "a b", "slop": 2}}}, "profile": True})
+        assert sloppy["profile"]["shards"][0]["plan"]["fallback_reason"] == \
+            "sloppy_phrase"
+    finally:
+        n.close()
+
+
+@pytest.mark.mesh
+def test_the_mesh_declines_a_phrase(tmp_path):
+    """Four shards on four virtual devices: a match rides the mesh program, a
+    phrase goes the transport path to each shard's own phrase program, and
+    `mesh_fallbacks` says so; the answer is the host's."""
+    n, client = _node(tmp_path, "phrase_mesh", 4)
+    try:
+        ms = n.actions.mesh_serving
+        client.search("lib", {"query": {"match": {"body": "a b"}}})
+        assert ms.mesh_queries >= 1
+        queries, fallbacks = ms.mesh_queries, ms.mesh_fallbacks
+        before = scoring.LAUNCHES.snapshot()["phrase_searches"]
+        body = {"query": {"match_phrase": {"body": "a b"}}, "size": 10}
+        got = client.search("lib", body, search_type="dfs_query_then_fetch")
+        assert ms.mesh_queries == queries
+        assert ms.mesh_fallbacks == fallbacks + 1
+        assert scoring.LAUNCHES.snapshot()["phrase_searches"] == before + 4
+        host = client.search("lib", {"query": {"bool": {"must": [
+            {"match_phrase": {"body": "a b"}}]}}, "size": 10},
+            search_type="dfs_query_then_fetch")
+        assert got["hits"]["total"] == host["hits"]["total"] > 0
+        assert [h["_id"] for h in got["hits"]["hits"]] == \
+            [h["_id"] for h in host["hits"]["hits"]]
+        np.testing.assert_allclose([h["_score"] for h in got["hits"]["hits"]],
+                                   [h["_score"] for h in host["hits"]["hits"]],
+                                   rtol=1e-6)
+    finally:
+        n.close()

@@ -129,8 +129,14 @@ class TestFallbackReasons:
         return lower_fallback_reason(q, ctx)
 
     def test_vocabulary(self, shard_ctx):
-        assert self._reason(shard_ctx, {"match_phrase": {"body": "a b"}}) \
-            == "unsupported_query:PhraseQuery"
+        # an exact phrase lowers (tests/test_device_phrase.py); the phrase
+        # forms that stay on the host have reasons of their own
+        assert self._reason(shard_ctx, {"match_phrase": {"body": {
+            "query": "a b", "slop": 1}}}) == "sloppy_phrase"
+        assert self._reason(shard_ctx, {"match_phrase_prefix": {
+            "body": "a b"}}) == "phrase_prefix"
+        assert self._reason(shard_ctx, {"prefix": {"body": "qui"}}) \
+            == "unsupported_query:PrefixQuery"
         assert self._reason(
             shard_ctx, {"match": {"body": {"query": "quik",
                                            "fuzziness": "AUTO"}}}) \
@@ -280,14 +286,13 @@ class TestLiveProfile:
 
     def test_host_fallback_reasons(self, live):
         _cluster, _node, rc = live
-        # a phrase query never lowers flat — vocabulary reason
+        # a sloppy phrase never lowers flat — vocabulary reason
         resp = _search(rc, params={"profile": "true"},
                        body={"query": {"match_phrase": {
-                           "body": "quick brown"}}})
+                           "body": {"query": "quick brown", "slop": 1}}}})
         for shard in resp.body["profile"]["shards"]:
             assert shard["plan"]["outcome"] == "host"
-            assert shard["plan"]["fallback_reason"] == \
-                "unsupported_query:PhraseQuery"
+            assert shard["plan"]["fallback_reason"] == "sloppy_phrase"
             assert any(s["path"] == "host" for s in shard["segments"])
         # a lowerable query forced host by a mask-needing feature
         resp = _search(rc, params={"profile": "true"},

@@ -861,6 +861,47 @@ class TestLaunchTimeline:
             assert kinds1["function_score"][name] == \
                 kinds0["function_score"][name] + 1
 
+    def test_an_exact_phrase_records_its_plan_and_its_counters(self, live):
+        """A `match_phrase` rides the batcher to the phrase program: the
+        host's assembly of the launch's operands (each term's block slices,
+        the operand plane, its one device_put) is a part of the stage span,
+        and /_nodes/stats books the launch, the plan, the position blocks'
+        bytes, the batcher's kind and the plane's resident bytes."""
+        _cluster, _node, rc = live
+
+        def stats(section):
+            resp = rc.dispatch(RestRequest(
+                method="GET", path=f"/_nodes/stats/{section}"))
+            return next(iter(resp.body["nodes"].values()))[section]
+
+        body = {"query": {"match_phrase": {"body": "quick brown"}}, "size": 5}
+        _traced_search(rc, body)  # first sighting faults the plane in, compiles
+        before, kinds0 = stats("search_serving"), stats("search")["batcher"]["kinds"]
+        tree = _traced_search(rc, body)
+        after, kinds1 = stats("search_serving"), stats("search")["batcher"]["kinds"]
+        _assert_nested(tree)
+        (plan,) = _find(tree, "shard.phrase_plan")
+        (stage,) = [n for n in _find(tree, "dispatch.stage")
+                    if any(c["name"] == "shard.phrase_plan"
+                           for c in n["children"])]
+        assert stage["t0"] <= plan["t0"] and plan["t1"] <= stage["t1"] + 1e-6
+        (dispatch,) = _find(tree, "batcher.dispatch")
+        assert [c["name"] for c in dispatch["children"]] == \
+            ["dispatch.stage", "dispatch.launch", "device_pull"]
+        launch0, launch1 = before["launch"], after["launch"]
+        assert launch1["phrase"] == launch0["phrase"] + 1
+        assert launch1["phrase_searches"] == launch0["phrase_searches"] + 1
+        assert launch1["position_bytes"] > launch0["position_bytes"]
+        assert 0 < launch1["position_pad_bytes"] - launch0["position_pad_bytes"] \
+            < launch1["position_bytes"] - launch0["position_bytes"]
+        assert launch1["posting_bytes"] == launch0["posting_bytes"]
+        assert after["device_sparse"] == before["device_sparse"] + 1
+        assert after["host"] == before["host"]
+        for name in ("launches", "coalesced"):
+            assert kinds1["phrase"][name] == kinds0["phrase"][name] + 1
+        assert stats("device")["indices"]["traced"]["totals"][
+            "positions_plane"] > 0
+
     def test_an_exact_sum_counts_its_limb_rows_and_their_bytes(self, live):
         """A sum of a long column under a terms bucket: the launch counts the
         integer limb rows it reduced, and the device ledger holds their bytes
