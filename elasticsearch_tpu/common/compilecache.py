@@ -389,8 +389,9 @@ MANIFEST_NAME = "compile_manifest.json"
 # manifest written under another list would replay calls the programs no
 # longer take, so its specs are dropped at load (the ladders are kept)
 # 2: operand planes (scoring.TermBatch.tri / SparseBatch.slots); 3: the dense
-# launches take the head rows and the head-slot plane (TermBatch.head)
-MANIFEST_VERSION = 3
+# launches take the head rows and the head-slot plane (TermBatch.head); 4: the
+# phrase launch takes the block rows it gathers as a list (phrase_operands)
+MANIFEST_VERSION = 4
 _MESH_RING = 4  # recent mesh plan batches kept per index
 
 

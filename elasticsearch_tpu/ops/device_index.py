@@ -69,6 +69,13 @@ postings do: `_perform_pack`'s remask writes the dead code into the deleted
 documents' markers on the raw host copy (`host_keys`, as `host_docs` is the
 postings') and puts the plane again, with no second pass over the segment. A
 merged or a delta segment starts without one.
+
+Beside the keys the host keeps each block row's first and last document
+(`blk_first`, `blk_last`): a phrase can only occur in the documents of its
+rarest term, so a launch names, of every term's rows, those whose document
+range holds one of them (`docs_below`, `PositionsPlane.rows_holding`) and the
+program gathers that list. A tombstone changes a marker's code, never its document:
+a re-mask leaves the bounds alone.
 """
 
 from __future__ import annotations
@@ -285,13 +292,16 @@ class PositionsPlane:
     record that they do not fit (`keys` None: the host answers its phrases).
     `blk_start[t] .. blk_start[t + 1]` are term t's block rows (no row for a
     term of another field); row NPBpad - 1 is all POS_SENTINEL, the row a
-    launch's padding slots name."""
+    launch's padding slots name. `blk_first` / `blk_last` are the documents
+    of a row's first and last key (a row with no key: last -1)."""
 
     pos_bits: int  # key = doc << pos_bits | position
     pos_max: int  # the largest position the plane holds
     blk_start: np.ndarray | None = None  # host int64 [T+1]
     keys: object = None  # jnp int32 [NPBpad, B], the view's dead markers set
     host_keys: np.ndarray | None = None  # int32 [NPBpad, B], no document dead
+    blk_first: np.ndarray | None = None  # host int32 [NPBpad]
+    blk_last: np.ndarray | None = None  # host int32 [NPBpad]
 
     @property
     def mark_base(self) -> int:
@@ -307,6 +317,25 @@ class PositionsPlane:
 
     def blocks_for_term(self, tid: int) -> tuple[int, int]:
         return int(self.blk_start[tid]), int(self.blk_start[tid + 1])
+
+    def rows_holding(self, tid: int, below: np.ndarray) -> np.ndarray:
+        """Term `tid`'s block rows, ascending, whose [first, last] document
+        holds a candidate document, where `below` (docs_below) counts the
+        candidates under each document: every row with a key of a candidate,
+        both bounds inclusive, so a document that straddles rows keeps them
+        all. Two reads of the count a row, whatever the candidates number."""
+        b0, b1 = self.blocks_for_term(tid)
+        held = below[self.blk_last[b0:b1] + 1] > below[self.blk_first[b0:b1]]
+        return (b0 + np.flatnonzero(held)).astype(np.int32)
+
+
+def docs_below(docs: np.ndarray, n_docs: int) -> np.ndarray:
+    """int32 [n_docs + 1]: how many of `docs` (document ids under `n_docs`)
+    lie below each document, so that `below[b + 1] - below[a]` of them lie in
+    [a, b] (PositionsPlane.rows_holding asks that of every row)."""
+    below = np.zeros(n_docs + 1, np.int32)
+    below[docs + 1] = 1
+    return np.cumsum(below, out=below)
 
 
 def positions_mark_base(pos_bits: int) -> int:
@@ -1093,7 +1122,8 @@ def ensure_positions(seg: FrozenSegment, packed: PackedSegment, field: str,
     pos_bits = 31 - max(1, int(packed.doc_pad - 1).bit_length())
     mark_base = positions_mark_base(pos_bits)
     NPBpad, n_keys = positions_shape_math(seg, field)
-    est = NPBpad * BLOCK * 4 * 2 + n_keys * POSITIONS_TRANSIENT_BYTES
+    # the staged and the uploaded plane, the rows' two bounds, the transients
+    est = NPBpad * (BLOCK * 4 * 2 + 8) + n_keys * POSITIONS_TRANSIENT_BYTES
     with reserve(breaker, est, f"<positions>{field}"):
         T = len(seg.post_offsets) - 1
         td = seg.term_dict.get(field) or {}
@@ -1140,8 +1170,14 @@ def ensure_positions(seg: FrozenSegment, packed: PackedSegment, field: str,
         flat = np.full(NPBpad * BLOCK, POS_SENTINEL, dtype=np.int32)
         flat[expand_ranges(blk_start[:-1] * BLOCK, counts)] = \
             keys.astype(np.int32)
-        plane = PositionsPlane(pos_bits, pos_max, blk_start,
-                               host_keys=flat.reshape(NPBpad, BLOCK))
+        host_keys = flat.reshape(NPBpad, BLOCK)
+        n_real = (host_keys != POS_SENTINEL).sum(axis=1)
+        last_key = host_keys[np.arange(NPBpad), np.maximum(n_real - 1, 0)]
+        plane = PositionsPlane(
+            pos_bits, pos_max, blk_start, host_keys=host_keys,
+            blk_first=host_keys[:, 0] >> pos_bits,
+            blk_last=np.where(n_real > 0, last_key >> pos_bits,
+                              -1).astype(np.int32))
         plane.keys = masked_positions(plane, seg.live)
     packed.positions[field] = plane
     prof = _profile.current()

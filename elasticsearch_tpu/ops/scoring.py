@@ -309,7 +309,8 @@ class LaunchCounters:
              "mask_put_bytes", "launches_fs_unscored", "fs_row_put_bytes",
              "fs_rows_resident", "fs_rows_evaluated", "exact_sum_rows",
              "phrase", "phrase_searches", "position_bytes",
-             "position_pad_bytes"), 0)
+             "position_pad_bytes", "position_list_bytes",
+             "position_skip_bytes"), 0)
 
     def add(self, real: int, launched: int, nbytes: int,
             dense_rows: int = 0, head_slots: int = 0,
@@ -348,7 +349,10 @@ class LaunchCounters:
         padding's part of them: the quarters of slots no term fills and each
         term's rows up to its rung (`phrase`, `phrase_searches`,
         `position_bytes`, `position_pad_bytes`: score_phrase_batch_async,
-        execute.launch_flat_phrase)."""
+        execute.launch_flat_phrase); beside them the bytes of the launched
+        plans' WHOLE lists, every block row of every term, and the part of
+        those the lead term's documents left out of the launch
+        (`position_list_bytes`, `position_skip_bytes`)."""
         with self._lock:
             for name, n in counts.items():
                 self._c[name] += n
@@ -1690,22 +1694,39 @@ def concat_pack_planes(blk_term, blk_j0, cum, starts, bases, doc_pads,
 # similarity table is read without a gather over documents; a deleted
 # document's marker carries the dead code in the byte's place and matches
 # nothing (the plane keeps deleted documents: device_index.masked_positions).
+#
+# A phrase can only occur in a document that holds every one of its terms, so
+# only in the documents of its RAREST term, the lead (ExactPhraseScorer is
+# driven by that conjunction). The host therefore names, of every term's block
+# rows, those whose document range holds a document of the lead
+# (PositionsPlane.rows_holding), and the program gathers that LIST. The answer
+# is the whole lists' answer bit for bit. Every key of a candidate document is
+# there in every term (the rows' bounds are inclusive on both sides), so its
+# runs, its frequency, its marker and its norm byte are what they were. A
+# document that is no candidate lacks the lead term, and a term's keys are
+# strictly ascending, so no run of n equal keys can form in it: a kept row may
+# hold some or all of its keys, its frequency is 0, it matches nothing and
+# adds nothing to the total, whatever key ends its group on the line. The
+# kept rows stay ascending, so the merge still gets ascending lists, and the
+# matching documents keep their order on the line, so top_k breaks ties as it
+# did.
 
 PHRASE_SLOTS = 4  # terms a phrase plan may hold: a quarter of the line each
 # block rows a term's quarter holds, up the ladder. Few and far apart: every
 # rung is a program, a first sighting compiles for tens of seconds on the one
 # drainer, and a warm-up has to meet every rung (padding rows cost a merge
-# stage's share of microseconds). A term past the last rung goes to the host.
+# stage's share of microseconds). A term whose rows beside the lead term's
+# documents pass the last rung goes to the host.
 PHRASE_RUNGS = (1024, 8192, 32768)
-# columns of a phrase launch's operand plane, int32 [Q, 16]: the weight's
-# bits, the SimTables row, the number of terms; then a column a slot of the
-# first block row, the block count and the shift
-_P_WEIGHT, _P_FID, _P_TERMS, _P_START, _P_COUNT, _P_SHIFT = 0, 1, 2, 4, 8, 12
-_P_COLS = 16
+# columns of a phrase launch's operand plane, int32 [Q, 8]: the weight's
+# bits, the SimTables row, the number of terms; then a slot's shift a column
+_P_WEIGHT, _P_FID, _P_TERMS, _P_SHIFT = 0, 1, 2, 4
+_P_COLS = 8
 
 
 def phrase_rung(blocks: int) -> int | None:
-    """The rung of PHRASE_RUNGS that holds a term of `blocks` block rows."""
+    """The rung of PHRASE_RUNGS that holds a term of `blocks` block rows in
+    a launch (the rows its list names, not the term's whole list)."""
     for rung in PHRASE_RUNGS:
         if blocks <= rung:
             return rung
@@ -1808,7 +1829,7 @@ def _lut256(table, byte):
     return out
 
 
-def _phrase_impl(pos_keys, caches, modes, qplane, *, k: int, rows: int,
+def _phrase_impl(pos_keys, caches, modes, qplane, blk, *, k: int,
                  pos_bits: int):
     import jax
     import jax.numpy as jnp
@@ -1817,11 +1838,7 @@ def _phrase_impl(pos_keys, caches, modes, qplane, *, k: int, rows: int,
     mark_base = positions_mark_base(pos_bits)
     pos_mask = (1 << pos_bits) - 1
     with jax.named_scope("gather_positions"):
-        start = qplane[:, _P_START: _P_START + PHRASE_SLOTS, None]
-        count = qplane[:, _P_COUNT: _P_COUNT + PHRASE_SLOTS, None]
         shift = qplane[:, _P_SHIFT: _P_SHIFT + PHRASE_SLOTS, None, None]
-        r = jax.lax.broadcasted_iota(jnp.int32, (1, 1, rows), 2)
-        blk = jnp.where(r < count, start + r, pos_keys.shape[0] - 1)
         keys = pos_keys[blk]  # [Q, SLOTS, rows, B]
         keys = jnp.where(keys == POS_SENTINEL, POS_SENTINEL, keys + shift)
     with jax.named_scope("phrase_join"):
@@ -1869,54 +1886,64 @@ def _get_phrase_compiled(n_queries: int, rows: int, k: int, pos_bits: int):
     key = ("phrase", n_queries, rows, k, pos_bits)
     fn = _compiled_cache.get(key)
     if fn is None:
-        def wrapper(pos_keys, caches, modes, qplane):
-            return _phrase_impl(pos_keys, caches, modes, qplane, k=k,
-                                rows=rows, pos_bits=pos_bits)
+        def wrapper(pos_keys, caches, modes, qplane, blk):
+            return _phrase_impl(pos_keys, caches, modes, qplane, blk, k=k,
+                                pos_bits=pos_bits)
 
         fn = jax.jit(_named("scoring.phrase", wrapper))
         _compiled_cache[key] = fn
     return fn
 
 
-def phrase_operands(entries: list, n_queries: int) -> np.ndarray:
-    """The operand plane of one phrase launch (columns _P_*): `entries` holds
-    a plan's (weight f32, SimTables row, [(first block row, block count,
-    shift) a term]); rows past them, and slots past a plan's terms, name no
-    block, so they match nothing."""
+def phrase_operands(entries: list, n_queries: int, rows: int, pad_row: int):
+    """The operands of one phrase launch: the plane of columns _P_* and the
+    block rows each slot gathers, int32 [Q, PHRASE_SLOTS, rows]. `entries`
+    holds a plan's (weight f32, SimTables row, [(block rows ascending, block
+    rows of the term's whole list, shift) a term]); places past a term's
+    rows, slots past a plan's terms and plans past the entries name
+    `pad_row`, the plane's row of POS_SENTINEL, so they match nothing."""
     qplane = np.zeros((n_queries, _P_COLS), np.int32)
+    blk = np.full((n_queries, PHRASE_SLOTS, rows), pad_row, np.int32)
     for q, (w, fid, terms) in enumerate(entries):
         qplane[q, _P_WEIGHT] = np.float32(w).view(np.int32)
         qplane[q, _P_FID] = fid
         qplane[q, _P_TERMS] = len(terms)
-        for i, (b0, nb, shift) in enumerate(terms):
-            qplane[q, _P_START + i] = b0
-            qplane[q, _P_COUNT + i] = nb
+        for i, (named, _whole, shift) in enumerate(terms):
+            blk[q, i, : len(named)] = named
             qplane[q, _P_SHIFT + i] = shift
-    return qplane
+    return qplane, blk
 
 
-def score_phrase_batch_async(plane: PositionsPlane, sim, qplane: np.ndarray,
-                             rows: int, k: int, note_t0: float | None = None):
+def score_phrase_batch_async(plane: PositionsPlane, sim, entries: list,
+                             n_queries: int, rows: int, k: int,
+                             note_t0: float | None = None):
     """Launch the phrase program over one segment's positions plane for the
-    plans of `qplane` (phrase_operands), a quarter of `rows` block rows a
-    term; returns the device arrays (scores [Q, k], docs [Q, k], totals [Q])
-    without syncing. `sim` is the SimTables whose rows the plane names.
-    `note_t0`: when the host began to assemble this launch's operands; from
-    there to the end of their one device_put is the span `shard.phrase_plan`
-    (a note inside the running `dispatch.stage`)."""
-    Q = qplane.shape[0]
-    params = (Q, rows, min(k, PHRASE_SLOTS * rows * BLOCK), plane.pos_bits)
+    plans of `entries` (phrase_operands) at a width of `n_queries`, a quarter
+    of `rows` block rows a term; returns the device arrays (scores [Q, k],
+    docs [Q, k], totals [Q]) without syncing. `sim` is the SimTables whose
+    rows the plane names. `note_t0`: when the host began to assemble this
+    launch's operands; from there to the end of their one device_put is the
+    span `shard.phrase_plan` (a note inside the running `dispatch.stage`)."""
+    params = (n_queries, rows, min(k, PHRASE_SLOTS * rows * BLOCK),
+              plane.pos_bits)
     fn = _get_phrase_compiled(*params)
-    args = (plane.keys, sim.caches, sim.modes, *_put_operands(qplane))
+    operands = phrase_operands(entries, n_queries, rows,
+                               len(plane.host_keys) - 1)
+    args = (plane.keys, sim.caches, sim.modes, *_put_operands(*operands))
     if note_t0 is not None:
         _tracing.note("shard.phrase_plan", note_t0)
     # what the launch gathers: PHRASE_SLOTS quarters of `rows` block rows a
-    # plan, BLOCK keys of 4 B each, padding included; the rows no term named
-    # (the sentinel row, gathered again and again) are the padding
-    launched = Q * PHRASE_SLOTS * rows
-    named = int(qplane[:, _P_COUNT: _P_COUNT + PHRASE_SLOTS].sum())
+    # plan, BLOCK keys of 4 B each, padding included; the places no term named
+    # (the sentinel row, gathered again and again) are the padding. What the
+    # terms named is what the lead term left of their whole lists
+    launched = n_queries * PHRASE_SLOTS * rows
+    terms = [term for _w, _fid, terms in entries for term in terms]
+    named = sum(len(rows_named) for rows_named, _whole, _shift in terms)
+    listed = sum(whole for _rows_named, whole, _shift in terms)
     LAUNCHES.bump(phrase=1, position_bytes=launched * BLOCK * 4,
-                  position_pad_bytes=(launched - named) * BLOCK * 4)
+                  position_pad_bytes=(launched - named) * BLOCK * 4,
+                  position_list_bytes=listed * BLOCK * 4,
+                  position_skip_bytes=(listed - named) * BLOCK * 4)
     return _launch(fn, args, "scoring.phrase", "phrase", params)
 
 

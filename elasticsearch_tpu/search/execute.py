@@ -1708,21 +1708,27 @@ def _phrase_launches(by_rung: dict):
 def launch_flat_phrase(plans: list[FlatPlan], ctx: ShardContext, k: int):
     """Phrase plans (at most _GROUP_WIDTH) over every segment's positions
     plane, faulted in by the first phrase a segment's field meets
-    (device_index.ensure_positions). On a segment the plans launch by the
-    rung of their longest term's block rows (scoring.phrase_rung): those of
-    the first rung together at the group's width (_group_width: 1 or 4 plans
-    a launch, rows past them matching nothing), those of a longer rung one a
-    launch (_phrase_launches), so a rare phrase does not ride a head term's
-    line. NO pull: returns (device outputs a launch, finish),
-    or None where a segment's positions do not fit the plane's keys or a term
-    outgrows the last rung (the host serves every plan). `finish(pulled)`
-    takes the outputs on the host (the batch's one device_get:
-    _run_flat_groups) and returns TopDocs a plan. The host's share, the block
-    slices of each term and the operands' one device_put, is the span
-    `shard.phrase_plan` inside `dispatch.stage`."""
-    from ..ops.device_index import (ensure_positions, ensure_sim_tables,
-                                    packed_for)
-    from ..ops.scoring import (LAUNCHES, phrase_operands, phrase_rung,
+    (device_index.ensure_positions). A phrase can only occur in the documents
+    of its rarest term in the segment, the lead: of every term's block rows a
+    launch names those whose document range holds one of the lead's documents
+    (docs_below, PositionsPlane.rows_holding; the lead's postings, deleted
+    and non-parent documents among them, is a superset, which is enough), and
+    the program gathers that list (scoring, "exact phrases", argues why the
+    answer is the whole lists'). On a segment the plans launch by the rung of
+    the most rows a term keeps (scoring.phrase_rung): those of the first rung
+    together at the group's width (_group_width: 1 or 4 plans a launch, rows
+    past them matching nothing), those of a longer rung one a launch
+    (_phrase_launches), so a rare phrase does not ride a head term's line,
+    and a head term beside a rare one rides the first rung. NO pull: returns
+    (device outputs a launch, finish), or None where a segment's positions do
+    not fit the plane's keys or the rows a term keeps outgrow the last rung
+    (the host serves every plan). `finish(pulled)` takes the outputs on the
+    host (the batch's one device_get: _run_flat_groups) and returns TopDocs a
+    plan. The host's share, the rows each term keeps and the operands' one
+    device_put, is the span `shard.phrase_plan` inside `dispatch.stage`."""
+    from ..ops.device_index import (docs_below, ensure_positions,
+                                    ensure_sim_tables, packed_for)
+    from ..ops.scoring import (LAUNCHES, phrase_rung,
                                score_phrase_batch_async)
 
     Q = len(plans)
@@ -1749,27 +1755,29 @@ def launch_flat_phrase(plans: list[FlatPlan], ctx: ShardContext, k: int):
             tids = [seg.term_id(ph.field, t) for t in ph.terms]
             if any(tid is None for tid in tids):
                 continue  # a term the segment lacks: no match here
-            blocks = [plane.blocks_for_term(tid) for tid in tids]
-            rung = phrase_rung(max(b1 - b0 for b0, b1 in blocks))
+            lead = min(ph.terms, key=lambda t: seg.doc_freq(ph.field, t))
+            below = docs_below(seg.postings(ph.field, lead)[0], seg.doc_count)
+            named = {tid: plane.rows_holding(tid, below) for tid in set(tids)}
+            rung = phrase_rung(max(len(rows) for rows in named.values()))
             if rung is None:
                 return None
             by_rung.setdefault((ph.field, rung), []).append((qi, (
                 w, sim.fid[ph.field],
-                [(b0, b1 - b0, shift)
-                 for (b0, b1), shift in zip(blocks, shifts[qi])])))
+                [(named[tid], int(plane.blk_start[tid + 1]
+                                  - plane.blk_start[tid]), shift)
+                 for tid, shift in zip(tids, shifts[qi])])))
         staged.append((packed, sim, planes, by_rung, t0))
     launched = []
     members = []  # a launch: (segment index, plan indexes)
     for si, (packed, sim, planes, by_rung, t0) in enumerate(staged):
         for (field, rung), group in _phrase_launches(by_rung):
-            qplane = phrase_operands([entry for _qi, entry in group],
-                                     _group_width(len(group)))
             try:
                 if _DEVICE_FAULTS.active:
                     _DEVICE_FAULTS.check("compile:phrase")
                 with compile_tag("phrase"):
                     launched.append(score_phrase_batch_async(
-                        planes[field], sim, qplane, rung, max(k, 1),
+                        planes[field], sim, [entry for _qi, entry in group],
+                        _group_width(len(group)), rung, max(k, 1),
                         note_t0=t0))
             except Exception as e:  # noqa: BLE001 — re-raised tagged
                 raise _tag_domain(e, "compile:phrase")

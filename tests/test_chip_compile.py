@@ -124,9 +124,9 @@ def test_phrase_launch_compiles_for_v5e(one_chip, n_plans, rung):
     """The exact-phrase program over the cell `wiki.phrase`'s positions plane
     (262,144 rows of keys at 50,000 documents, doc_pad 65,536: 15 bits of
     position) at both group widths on the ladder's first rung and alone on
-    its last, where a head term's whole list rides a line of 16.8M keys:
-    gathers of block rows and merges (the only sort is top_k's own, over
-    1,280 candidates)."""
+    its last, where the rows two head terms keep of each other ride a line of
+    16.8M keys: a gather of the block rows the launch lists and merges (the
+    only sort is top_k's own, over 1,280 candidates)."""
     from elasticsearch_tpu.common.jaxenv import compile_tag
     from elasticsearch_tpu.ops.scoring import (
         _P_COLS, PHRASE_RUNGS, PHRASE_SLOTS, _get_phrase_compiled)
@@ -135,7 +135,9 @@ def test_phrase_launch_compiles_for_v5e(one_chip, n_plans, rung):
     args = _shapes(
         one_chip, ((262_144, BLOCK), "int32"),  # the plane's keys
         ((1, 256), "float32"), ((1,), "int32"),  # SimTables caches, modes
-        ((n_plans, _P_COLS), "int32"))  # the launch's one operand plane
+        # the launch's operand plane and the block rows each slot gathers
+        ((n_plans, _P_COLS), "int32"),
+        ((n_plans, PHRASE_SLOTS, rows), "int32"))
     fn = _get_phrase_compiled(n_plans, rows, 10, 31 - 16)
     with compile_tag("phrase"):
         compiled = fn.lower(*args).compile()

@@ -5,9 +5,12 @@ On the CPU, seeded and small: the device's answer to a `match_phrase` against th
 host scorer (`HostScorer._eval_phrase`, the semantics) and against the benchmark's
 plain reference (`benchmark/queries/phrase_terms.py` `expected`: numpy over the
 token stream, nothing of the program): totals and ids in order exactly, scores to
-1e-6 relative. The forms that stay on the host reach it under a named reason, the
-plane is faulted in by the first phrase and not before, and every counter the
-benchmark reads moves as stated."""
+1e-6 relative. A launch names, of every term's block rows, those that hold a
+document of the phrase's rarest term: its answer is the whole lists' bit for bit,
+the rung follows the rows it names, and the counters say what it left out. The
+forms that stay on the host reach it under a named reason, the plane is faulted
+in by the first phrase and not before, and every counter the benchmark reads
+moves as stated."""
 
 import json
 import threading
@@ -26,9 +29,8 @@ from elasticsearch_tpu.mapper import MapperService
 from elasticsearch_tpu.node import Node
 from elasticsearch_tpu.ops import scoring
 from elasticsearch_tpu.ops.device_index import (
-    POS_DEAD_CODE, PositionsPlane, ensure_positions, packed_for,
-    packed_tier_bytes,
-    positions_mark_base)
+    POS_DEAD_CODE, POS_SENTINEL, PositionsPlane, docs_below, ensure_positions,
+    packed_for, packed_tier_bytes, positions_mark_base)
 from elasticsearch_tpu.search import ShardContext, parse_query, search_shard
 from elasticsearch_tpu.search.batcher import DeviceBatcher
 from elasticsearch_tpu.search.execute import (
@@ -82,6 +84,38 @@ def _both(ctx, query, k=10):
     dev = search_shard(ctx, query, k, use_device=True)
     assert scoring.LAUNCHES.snapshot()["phrase_searches"] == before + 1
     return dev, search_shard(ctx, query, k, use_device=False)
+
+
+def _named_rows(seg, plane, words, field="body"):
+    """The block rows a launch of the phrase `words` names, a list a term, by
+    the rule and by hand: the lead is the term of the fewest postings, and a
+    term's row stays where a document of the lead lies between the documents
+    of the row's first and last key, both included."""
+    docs, _freqs = seg.postings(
+        field, min(words, key=lambda w: seg.doc_freq(field, w)))
+    named = []
+    for w in words:
+        rows = []
+        for r in range(*plane.blocks_for_term(seg.term_id(field, w))):
+            keys = plane.host_keys[r]
+            held = keys[keys != POS_SENTINEL] >> plane.pos_bits
+            if ((docs >= held[0]) & (docs <= held[-1])).any():
+                rows.append(r)
+        named.append(rows)
+    return named
+
+
+def _whole_rows(seg, plane, words, field="body"):
+    """The block rows of the whole lists of the phrase `words`."""
+    return sum(b1 - b0 for b0, b1 in (
+        plane.blocks_for_term(seg.term_id(field, w)) for w in words))
+
+
+def _every_row(plane, tid, _below):
+    """PositionsPlane.rows_holding as it would be with no lead term: every
+    block row of the term (the launch every pruned one is held to)."""
+    b0, b1 = plane.blocks_for_term(tid)
+    return np.arange(b0, b1, dtype=np.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -156,16 +190,19 @@ def test_a_batch_launches_at_both_widths(planted, n_plans):
     width = {1: 1, 4: 4, 5: 5}[n_plans]
     assert after["position_bytes"] - before["position_bytes"] == \
         width * scoring.PHRASE_SLOTS * rows * 128 * 4
-    # the padding is every row of those that no term of a plan named
+    # the padding is every row of those that no term of a plan named, and
+    # what the plans named is what the lead term left of their whole lists
     (seg,) = ctx.searcher.segments
     plane = packed_for(seg).positions["body"]
-    named = 0
-    for text in texts:
-        for w in text.split():
-            b0, b1 = plane.blocks_for_term(seg.term_id("body", w))
-            named += b1 - b0
+    named = sum(len(rows) for text in texts
+                for rows in _named_rows(seg, plane, text.split()))
+    whole = sum(_whole_rows(seg, plane, text.split()) for text in texts)
     assert after["position_pad_bytes"] - before["position_pad_bytes"] == \
         (width * scoring.PHRASE_SLOTS * rows - named) * 128 * 4
+    assert after["position_list_bytes"] - before["position_list_bytes"] == \
+        whole * 512
+    assert after["position_skip_bytes"] - before["position_skip_bytes"] == \
+        (whole - named) * 512
     for q, td in zip(queries, got):
         _same(td, search_shard(ctx, q, 10, use_device=False))
 
@@ -173,22 +210,25 @@ def test_a_batch_launches_at_both_widths(planted, n_plans):
 def test_longer_lists_ride_longer_rungs_alone_and_the_longest_go_to_the_host(
         planted, monkeypatch):
     """With the ladder cut down to 2 / 4 / 8 block rows a quarter the corpus'
-    own terms meet every rung: plans of the first rung launch together, those
-    of a longer rung one a launch, and a term past the last rung sends its
-    batch to the host; every answer is the host's."""
+    own pairs meet every rung, and the rung follows the rows the lead term
+    LEAVES, not the lists: plans of the first rung launch together, those of
+    a longer rung one a launch, the top term beside a rare one rides the
+    first rung though its whole list passes the last, and only a pair whose
+    kept rows pass the last rung sends its batch to the host; every answer is
+    the host's."""
     corpus, ref, ctx = planted
     monkeypatch.setattr(scoring, "PHRASE_RUNGS", (2, 4, 8))
     (seg,) = ctx.searcher.segments
-    plane = packed_for(seg).positions["body"]
+    plane = ensure_positions(seg, packed_for(seg), "body")
+    words = [word(int(t)) for t in ref.by_df[:24]]
     by_rung: dict = {}
-    for t in ref.by_df[:60]:
-        b0, b1 = plane.blocks_for_term(seg.term_id("body", word(int(t))))
-        by_rung.setdefault(scoring.phrase_rung(b1 - b0), []).append(word(int(t)))
+    for i, a in enumerate(words):
+        for b in words[i + 1:]:
+            kept = max(len(rows) for rows in _named_rows(seg, plane, [a, b]))
+            by_rung.setdefault(scoring.phrase_rung(kept), []).append(f"{a} {b}")
     assert set(by_rung) == {None, 2, 4, 8}
-    rare = by_rung[2][0]
     for rung, launches in ((2, 1), (4, 2), (8, 2)):
-        texts = [f"{w} {rare}" for w in by_rung[rung][:2]]
-        queries = [_phrase(t) for t in texts]
+        queries = [_phrase(t) for t in by_rung[rung][:2]]
         before = scoring.LAUNCHES.snapshot()
         got = search_shard_batch(ctx, queries, 10)
         after = scoring.LAUNCHES.snapshot()
@@ -197,7 +237,24 @@ def test_longer_lists_ride_longer_rungs_alone_and_the_longest_go_to_the_host(
             (4 if rung == 2 else 2) * scoring.PHRASE_SLOTS * rung * 128 * 4
         for q, td in zip(queries, got):
             _same(td, search_shard(ctx, q, 10, use_device=False))
-    queries = [_phrase(f"{by_rung[None][0]} {rare}"), _phrase(f"{rare} {rare}")]
+    # the top term's list alone passes the last rung; beside a rare term the
+    # launch names a row or two of it and rides the first
+    top, rare = words[0], word(int(ref.by_df[ref.n_present - 1]))
+    b0, b1 = plane.blocks_for_term(seg.term_id("body", top))
+    assert scoring.phrase_rung(b1 - b0) is None
+    for text in (f"{top} {rare}", f"{rare} {top}"):
+        named = _named_rows(seg, plane, text.split())
+        assert scoring.phrase_rung(max(map(len, named))) == 2
+        before = scoring.LAUNCHES.snapshot()
+        _same(*_both(ctx, _phrase(text)))
+        after = scoring.LAUNCHES.snapshot()
+        assert after["position_bytes"] - before["position_bytes"] == \
+            scoring.PHRASE_SLOTS * 2 * 128 * 4
+        assert after["position_skip_bytes"] - before["position_skip_bytes"] == \
+            (b1 - b0 + 1 - sum(map(len, named))) * 512 > 0
+    # two head terms keep every row of each other: past the last rung, so the
+    # batch, its rare phrase with it, is the host's
+    queries = [_phrase(by_rung[None][0]), _phrase(f"{rare} {rare}")]
     before = scoring.LAUNCHES.snapshot()
     got = search_shard_batch(ctx, queries, 10)
     after = scoring.LAUNCHES.snapshot()
@@ -205,6 +262,92 @@ def test_longer_lists_ride_longer_rungs_alone_and_the_longest_go_to_the_host(
         (before["phrase"], before["phrase_searches"])
     for q, td in zip(queries, got):
         _same(td, search_shard(ctx, q, 10, use_device=False), rtol=0)
+
+
+def _phrases_of_a_head_term(planted, n_terms):
+    """Phrases of `n_terms` terms that hold one of the corpus' three most
+    frequent terms: windows of the documents' own tokens with the head term
+    first or last (they match somewhere), and a head and a rare term by turns
+    in each order (`a b`, `b a`, `a b a`, `b a b a`: a repeated term, seldom a
+    match, the lists merged all the same)."""
+    corpus, ref, _ctx = planted
+    head = [int(t) for t in ref.by_df[:3]]
+    rare = [int(t) for t in ref.by_df[ref.n_present - 3: ref.n_present]]
+    texts = []
+    ends = np.cumsum(corpus.lengths)
+    for at in np.flatnonzero(np.isin(corpus.tokens, head)):
+        for lo in (at, at - n_terms + 1):
+            doc = int(np.searchsorted(ends, lo, "right"))
+            if lo >= 0 and lo + n_terms <= ends[doc]:
+                texts.append(" ".join(
+                    word(int(t)) for t in corpus.tokens[lo: lo + n_terms]))
+    texts = list(dict.fromkeys(texts))[:: max(1, len(set(texts)) // 8)][:8]
+    for h, r in zip(head, rare):
+        for pair in ((h, r), (r, h)):
+            texts.append(" ".join(word(pair[i % 2]) for i in range(n_terms)))
+    return texts
+
+
+@pytest.mark.parametrize("ladder", [None, (2, 8, 32)], ids=["whole", "cut"])
+@pytest.mark.parametrize("n_terms", [2, 3, 4])
+def test_the_lead_term_changes_no_answer(planted, monkeypatch, n_terms, ladder):
+    """Every phrase with a head term, launched over the rows the lead term
+    leaves and launched again with every row listed: totals, documents and
+    float32 scores bit for bit the same, both the host's. With the ladder cut
+    to 2 / 8 / 32 rows the two launches ride different rungs, so different
+    programs, and still agree."""
+    corpus, ref, ctx = planted
+    if ladder is not None:
+        monkeypatch.setattr(scoring, "PHRASE_RUNGS", ladder)
+    texts = _phrases_of_a_head_term(planted, n_terms)
+    assert len(texts) >= 10
+    pruned = []
+    skipped = matched = 0
+    for text in texts:
+        before = scoring.LAUNCHES.snapshot()
+        dev, host = _both(ctx, _phrase(text))
+        after = scoring.LAUNCHES.snapshot()
+        _same(dev, host)
+        pruned.append(dev)
+        skipped += after["position_skip_bytes"] - before["position_skip_bytes"]
+        matched += dev.total
+    assert skipped > 0 and matched > 0
+    monkeypatch.setattr(PositionsPlane, "rows_holding", _every_row)
+    for text, dev in zip(texts, pruned):
+        before = scoring.LAUNCHES.snapshot()
+        whole, _host = _both(ctx, _phrase(text))
+        after = scoring.LAUNCHES.snapshot()
+        assert after["position_skip_bytes"] == before["position_skip_bytes"]
+        assert after["phrase"] == before["phrase"] + 1
+        assert whole.total == dev.total
+        assert whole.hits == dev.hits  # ids and float32 scores, bit for bit
+
+
+def test_the_counters_of_the_lead_term(planted):
+    """Two head terms hold each other's documents in every row: nothing is
+    skipped. Beside a rare term the launch names the rows that hold the rare
+    term's documents, and `position_list_bytes` less `position_skip_bytes` is
+    those rows' bytes."""
+    corpus, ref, ctx = planted
+    (seg,) = ctx.searcher.segments
+    plane = ensure_positions(seg, packed_for(seg), "body")
+    a, b = (word(int(t)) for t in ref.by_df[:2])
+    rare = word(int(ref.by_df[ref.n_present - 2]))
+    for text, skips in ((f"{a} {b}", False), (f"{a} {rare}", True),
+                        (f"{rare} {b} {a}", True)):
+        named = sum(map(len, _named_rows(seg, plane, text.split())))
+        whole = _whole_rows(seg, plane, text.split())
+        before = scoring.LAUNCHES.snapshot()
+        _same(*_both(ctx, _phrase(text)))
+        after = scoring.LAUNCHES.snapshot()
+        listed = after["position_list_bytes"] - before["position_list_bytes"]
+        skipped = after["position_skip_bytes"] - before["position_skip_bytes"]
+        assert listed == whole * 512
+        assert listed - skipped == named * 512
+        assert (skipped > 0) is skips
+        assert after["position_bytes"] - before["position_bytes"] - (
+            after["position_pad_bytes"] - before["position_pad_bytes"]) == \
+            named * 512
 
 
 def test_a_phrase_beside_plain_and_filtered_plans_in_one_batch(planted):
@@ -309,6 +452,80 @@ def test_deletes_and_a_delta_segment(tmp_path):
     dev3, host3 = _both(c, q)
     _same(dev3, host3)
     assert dev3.total == dev2.total + 1
+
+
+def test_a_deleted_lead_document(tmp_path):
+    """`c` leads `c d` (documents 0, 4, 5 of the first segment). With document
+    4 deleted its rows are still named (the lead's postings keep a tombstone,
+    a superset is enough), its marker carries the dead code and it matches
+    nothing; the re-mask leaves the rows' bounds as they were."""
+    eng, ctx = _shard(tmp_path, HAND, refresh_at=(20,))
+    q = _phrase("c d")
+    c = ctx()
+    dev, host = _both(c, q)
+    _same(dev, host, rtol=0)
+    assert [d for _s, d in sorted(dev.hits, key=lambda h: h[1])] == [0, 4, 5]
+    seg = c.searcher.segments[0]
+    plane = packed_for(seg).positions["body"]
+    eng.delete("doc", "4")
+    eng.refresh()
+    c = ctx()
+    dev2, host2 = _both(c, q)
+    _same(dev2, host2, rtol=0)
+    assert sorted(d for _s, d in dev2.hits) == [0, 5]
+    seg2 = c.searcher.segments[0]
+    plane2 = packed_for(seg2).positions["body"]
+    assert plane2 is not plane
+    assert plane2.blk_first is plane.blk_first
+    assert plane2.blk_last is plane.blk_last
+    assert 4 in seg2.postings("body", "c")[0]
+
+
+def test_a_document_that_straddles_block_rows_keeps_them_all(tmp_path):
+    """`x` fills nine block rows; `y`, the lead of `y x`, stands in two
+    documents: one whose 300 occurrences of `x` and their marker lie across
+    three rows, one whose 100 lie across two. The launch names exactly those
+    rows (the third is shared), in order, and every occurrence counts."""
+    docs = [{"body": "x " * 50},
+            {"body": "y " + "x " * 300},
+            {"body": "x " * 20},
+            {"body": "x y " + "x " * 99},
+            ] + [{"body": "x " * 200} for _ in range(3)]
+    eng, ctx = _shard(tmp_path, docs)
+    c = ctx()
+    (seg,) = c.searcher.segments
+    for text, total in (("y x", 2), ("x x", 7), ("x y x", 1), ("x y", 1)):
+        dev, host = _both(c, _phrase(text))
+        _same(dev, host, rtol=0)
+        assert dev.total == total
+    plane = packed_for(seg).positions["body"]
+    x = seg.term_id("body", "x")
+    b0, b1 = plane.blocks_for_term(x)
+    assert b1 - b0 == 9
+    # keys 51..351 are document 1's (300 occurrences and a marker), keys
+    # 373..473 document 3's (100 and a marker): rows 0-2 and rows 2-3
+    held = plane.host_keys[b0: b1] >> plane.pos_bits
+    assert [sorted(set(np.flatnonzero((held == d).any(axis=1)).tolist()))
+            for d in (1, 3)] == [[0, 1, 2], [2, 3]]
+    lead_docs, _freqs = seg.postings("body", "y")
+    assert lead_docs.tolist() == [1, 3]
+    named = plane.rows_holding(x, docs_below(lead_docs, seg.doc_count))
+    assert named.dtype == np.int32
+    assert named.tolist() == [b0, b0 + 1, b0 + 2, b0 + 3]
+    assert _named_rows(seg, plane, ["y", "x"])[1] == named.tolist()
+
+    def rows(*docs):
+        return plane.rows_holding(
+            x, docs_below(np.array(docs, np.int32), seg.doc_count)).tolist()
+
+    # a candidate names every row its keys lie in, and a row between whose
+    # first and last document it lies (the range, not the keys); no
+    # candidate names nothing, all of them every row
+    assert rows(2) == [b0 + 2]
+    assert rows(0) == [b0]
+    assert rows(6) == (b0 + np.flatnonzero((held == 6).any(axis=1))).tolist()
+    assert rows() == []
+    assert rows(*range(7)) == list(range(b0, b1))
 
 
 def test_a_merged_segment_faults_its_own_plane(tmp_path):
@@ -424,11 +641,14 @@ def test_a_position_past_the_keys_range_goes_to_the_host(tmp_path, monkeypatch):
 
 
 def test_the_two_ceilings_of_the_plane_in_documents_a_segment():
-    """What the documents state (PERF.md section 6, ROADMAP S14, the
-    configuration's `reduced_why`), held to the code: one int32 key leaves
-    positions of up to 1,000 tokens room beside 262,144 documents a segment
-    and no further, and a term of more than 32,768 block rows (4,194,304
-    occurrences and markers) sends its phrases to the host."""
+    """What the documents state (PERF.md section 6, ROADMAP S14, ARCHITECTURE.md),
+    held to the code: one int32 key leaves positions of up to 1,000 tokens room
+    beside 262,144 documents a segment and no further; and a phrase goes to
+    the host when the block rows of one of its terms that hold a document of
+    the LEAD term pass 32,768 (4,194,304 occurrences and markers), whatever
+    the term's whole list holds: a list of 40,000 rows rides the first rung
+    beside a lead of 500 documents and goes to the host beside a lead that
+    stands in every row."""
     longest = 1000 + 15  # a position moved up by the largest shift
     for doc_pad, fits in ((131_072, True), (262_144, True), (524_288, False)):
         pos_bits = 31 - (doc_pad - 1).bit_length()
@@ -440,6 +660,22 @@ def test_the_two_ceilings_of_the_plane_in_documents_a_segment():
     assert scoring.phrase_rung(scoring.PHRASE_RUNGS[-1]) == 32_768
     assert scoring.phrase_rung(scoring.PHRASE_RUNGS[-1] + 1) is None
     assert scoring.PHRASE_RUNGS[-1] * 128 == 4_194_304
+    # a head term of 40,000 block rows, three documents a row
+    n_rows = 40_000
+    first = np.arange(n_rows, dtype=np.int32) * 3
+    plane = PositionsPlane(13, 100, np.array([0, n_rows], np.int64),
+                           blk_first=first, blk_last=first + 2)
+    assert scoring.phrase_rung(n_rows) is None
+
+    def named(docs):
+        return plane.rows_holding(0, docs_below(docs, 3 * n_rows))
+
+    rare = named(np.arange(500, dtype=np.int32) * 240 + 7)
+    assert len(rare) == 500 and (np.diff(rare) > 0).all()
+    assert scoring.phrase_rung(len(rare)) == scoring.PHRASE_RUNGS[0]
+    everywhere = np.arange(n_rows, dtype=np.int32) * 3 + 1
+    assert scoring.phrase_rung(len(named(everywhere))) is None
+    assert scoring.phrase_rung(len(named(everywhere[:: 2]))) == 32_768
 
 
 # ---------------------------------------------------------------------------
