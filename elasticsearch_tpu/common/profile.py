@@ -38,6 +38,12 @@ left the fused device path —
   the host: a slop, a prefix on the last term, more terms than the phrase
   program's line holds; a phrase inside another query reads as that query's
   reason: non_term_subclause, non_flat_subquery, unsupported_query:<Type>),
+  scoring_rewrite, multiterm_expansion, multiterm_numeric_field, fuzzy_query,
+  span_multi (a prefix, wildcard or regexp lowers to an unscored plan whose
+  mask row the chip builds, but for: a scoring rewrite, an expansion of more
+  block rows on a segment than the ladder's last rung holds, a numeric field
+  (a field of _id / _uid reads host_only_field); `fuzzy` has no exact host
+  semantics to hold a program to, `span_multi` is a span's),
   unsupported_query:<Type>,
   device_disabled, features:<f1,f2,...>, device_error:<Type>.
 """

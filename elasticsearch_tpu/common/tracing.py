@@ -112,8 +112,11 @@ class DispatchClock:
     named part of the running interval (`shard.filter_mask` inside its
     `dispatch.stage`), recorded as that interval's child: the marks and what
     they measure are as they were. The notes: `shard.filter_mask`,
-    `shard.fs_rows` and `shard.phrase_plan` (the host's assembly of a phrase
-    launch's operands: execute.launch_flat_phrase)."""
+    `shard.fs_rows`, `shard.phrase_plan` (the host's assembly of a phrase
+    launch's operands: execute.launch_flat_phrase) and
+    `shard.multiterm_expand` (the expansion of a batch's prefixes, wildcards
+    and regexps into block rows and the put of the mask launches' operands:
+    execute._filter_mask_matrix, scoring.build_multiterm_rows)."""
 
     __slots__ = ("spans", "pull_s", "compiled", "compile_s", "_t", "_n", "_s",
                  "_notes")

@@ -339,8 +339,25 @@ class FrozenSegment:
             for i in range(s, e)
         ]
 
+    def sorted_terms(self, field: str) -> tuple[list[str], int]:
+        """(the field's terms in dictionary order, the first one's term id).
+        Term ids were handed out in (field, term) order (freeze, store
+        recovery), so a field's dict IS its sorted dictionary and term i of
+        the list has the id `first + i`: a range of the list is a range of
+        term ids, of the CSR postings and of the device's block rows. Built at
+        first use and kept with the segment's other derived state (views
+        after deletes share it: the dictionary never changes)."""
+        kept = self._device_cache.setdefault("sorted_terms", {})
+        entry = kept.get(field)
+        if entry is None:
+            td = self.term_dict.get(field) or {}
+            terms = list(td)
+            entry = kept[field] = (terms, td[terms[0]] if terms else 0)
+        return entry
+
     def terms_for_field(self, field: str) -> list[str]:
-        return sorted(self.term_dict.get(field, ()))
+        """The field's terms in dictionary order: the kept list, not a copy."""
+        return self.sorted_terms(field)[0]
 
     # --- doc access ---------------------------------------------------------
     def live_count(self) -> int:

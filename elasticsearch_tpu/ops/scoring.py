@@ -36,7 +36,7 @@ from ..common import profile as _profile
 from ..common import tracing as _tracing
 from ..common.breaker import reserve
 from ..common.compilecache import REGISTRY as _WARM
-from ..common.jaxenv import current_compile_family
+from ..common.jaxenv import compile_tag, current_compile_family
 from .device_index import (
     BLOCK,
     POS_DEAD_CODE,
@@ -310,7 +310,9 @@ class LaunchCounters:
              "fs_rows_resident", "fs_rows_evaluated", "exact_sum_rows",
              "phrase", "phrase_searches", "position_bytes",
              "position_pad_bytes", "position_list_bytes",
-             "position_skip_bytes"), 0)
+             "position_skip_bytes", "multiterm", "multiterm_searches",
+             "multiterm_terms", "multiterm_runs", "multiterm_bytes",
+             "multiterm_pad_bytes", "multiterm_field_scans"), 0)
 
     def add(self, real: int, launched: int, nbytes: int,
             dense_rows: int = 0, head_slots: int = 0,
@@ -352,7 +354,18 @@ class LaunchCounters:
         execute.launch_flat_phrase); beside them the bytes of the launched
         plans' WHOLE lists, every block row of every term, and the part of
         those the lead term's documents left out of the launch
-        (`position_list_bytes`, `position_skip_bytes`)."""
+        (`position_list_bytes`, `position_skip_bytes`); and the mask rows
+        built on the chip for multi-term queries and filters: the program's
+        launches (beside the plans, what an operator reads the sharing of a
+        launch from: rows built a launch), the plans with such a row (once a
+        plan whatever its segments), the terms their patterns matched and the runs of block
+        rows those own, the bytes of block rows the launches gathered,
+        padding included, and the padding's part (`multiterm`,
+        `multiterm_searches`, `multiterm_terms`, `multiterm_runs`,
+        `multiterm_bytes`, `multiterm_pad_bytes`: build_multiterm_rows,
+        execute._filter_mask_matrix), and the expansions whose pattern had
+        no literal head, so that the whole field's dictionary was tested
+        (`multiterm_field_scans`)."""
         with self._lock:
             for name, n in counts.items():
                 self._c[name] += n
@@ -1948,6 +1961,137 @@ def score_phrase_batch_async(plane: PositionsPlane, sim, entries: list,
 
 
 # ---------------------------------------------------------------------------
+# multi-term masks (prefix, wildcard, regexp)
+# ---------------------------------------------------------------------------
+#
+# A multi-term query or filter matches every document that holds at least
+# one term its pattern names (search/multiterm.py). Every term owns a run of
+# block rows of `blk_docs` in term-id order, so a prefix is ONE slab of the
+# plane and a wildcard a list of runs inside its head's slab: the host names
+# the rows (filters.MultiTermFilter.block_rows) and this program gathers them
+# and ORs their documents into a mask row a search. No new plane, and no
+# tail of its own: the rows are the `fmask` of the dense and unscored
+# launches (execute._filter_mask_matrix), which gate the match by the live
+# documents as they always did. A dead or nested document's slots hold
+# `doc_pad` in the plane already, as its padding slots do, so they fall out
+# of the scatter with the padding rows a launch names.
+
+# block rows a search's row gathers, up the ladder. Every rung is a program
+# a query count: few and far apart, so that a warm-up meets them all. A
+# pattern that names more rows than the last rung holds stays on the host
+# (a query: lower_fallback_reason `multiterm_expansion`; a filter: its row is
+# evaluated there and put).
+MULTITERM_RUNGS = (256, 2048, 8192)
+_MULTITERM_GROUP = 8  # searches of the first rung one launch builds rows for
+
+
+def multiterm_rung(rows: int) -> int | None:
+    """The rung of MULTITERM_RUNGS that holds `rows` block rows."""
+    for rung in MULTITERM_RUNGS:
+        if rows <= rung:
+            return rung
+    return None
+
+
+def _multiterm_impl(blk_docs, rows, *, doc_pad: int):
+    """(bool [G, doc_pad], the same G rows apart): a row a search, the
+    documents in the block rows `rows` (int32 [G, rung]) names of
+    `blk_docs`. A search whose places all name padding (one past the
+    launch's own) matches nothing. Both forms, because the caller takes the
+    matrix whole where the launch built every mask row of its batch, and
+    otherwise stacks single rows with resident and host rows in any order:
+    a row sliced out on the host would be a dispatch, and a program, a
+    place."""
+    import jax
+    import jax.numpy as jnp
+
+    n = rows.shape[0]
+    with jax.named_scope("gather_rows"):
+        docs = blk_docs[rows]  # [G, rung, B]; a padding slot holds doc_pad
+        flat = jnp.where(
+            docs < doc_pad,
+            jnp.arange(n, dtype=jnp.int32)[:, None, None] * doc_pad + docs,
+            n * doc_pad).reshape(-1)
+    with jax.named_scope("scatter_or"):
+        # out-of-range slots index past the end and are dropped
+        hits = jnp.zeros(n * doc_pad, jnp.int32).at[flat].add(
+            1, mode="drop").reshape(n, doc_pad) > 0
+    return hits, tuple(hits[g] for g in range(n))
+
+
+def _get_multiterm_compiled(n_queries: int, rung: int, doc_pad: int):
+    import jax
+
+    key = ("multiterm", n_queries, rung, doc_pad)
+    fn = _compiled_cache.get(key)
+    if fn is None:
+        def wrapper(blk_docs, rows):
+            return _multiterm_impl(blk_docs, rows, doc_pad=doc_pad)
+
+        fn = jax.jit(_named("scoring.multiterm", wrapper))
+        _compiled_cache[key] = fn
+    return fn
+
+
+def multiterm_launches(row_lists: list) -> list:
+    """(places in `row_lists`, query count, rung) a launch: the lists of the
+    first rung together, _MULTITERM_GROUP at a time and their count up the
+    pow-2 ladder; a list of a longer rung alone (a window meets few of them,
+    and a program for every count of them would be met by no warm-up). A
+    list with no row is in no launch."""
+    first = [i for i, rows in enumerate(row_lists)
+             if 0 < len(rows) <= MULTITERM_RUNGS[0]]
+    groups = [first[i: i + _MULTITERM_GROUP]
+              for i in range(0, len(first), _MULTITERM_GROUP)]
+    return [(group, _pow2_bucket(len(group), 1), MULTITERM_RUNGS[0])
+            for group in groups] + [
+        ([i], 1, multiterm_rung(len(rows))) for i, rows in enumerate(row_lists)
+        if len(rows) > MULTITERM_RUNGS[0]]
+
+
+def build_multiterm_rows(packed: PackedSegment, row_lists: list,
+                         note_t0: float | None = None) -> list:
+    """(places in `row_lists`, device bool [n, doc_pad], its rows apart) a
+    launch (multiterm_launches): row q holds the documents of the segment's
+    postings plane that the block rows `row_lists[places[q]]` (int32, at
+    most MULTITERM_RUNGS[-1] of them) hold, and the rows past the launch's
+    places match nothing. A list with no row is in no launch.
+    The operands of every launch go down in ONE device_put; places past a
+    list's rows and lists past the launch's name the plane's last row, all
+    padding. `note_t0`: when the host began the expansion; from there to the
+    end of the put is the span `shard.multiterm_expand` (a note inside the
+    running `dispatch.stage`). Returns without syncing."""
+    launches = multiterm_launches(row_lists)
+    pad_row = packed.blk_docs.shape[0] - 1
+    operands = []
+    for places, n_queries, rung in launches:
+        blk = np.full((n_queries, rung), pad_row, np.int32)
+        for q, at in enumerate(places):
+            blk[q, : len(row_lists[at])] = row_lists[at]
+        operands.append(blk)
+    device = _put_operands(*operands) if operands else ()
+    if note_t0 is not None:
+        _tracing.note("shard.multiterm_expand", note_t0)
+    # what the launches gather: BLOCK document ids of 4 B a block row,
+    # padding included; the rows no list named are the padding
+    launched = sum(blk.size for blk in operands)
+    named = sum(len(rows) for rows in row_lists)
+    LAUNCHES.bump(multiterm=len(launches),
+                  multiterm_bytes=launched * BLOCK * 4,
+                  multiterm_pad_bytes=(launched - named) * BLOCK * 4)
+    built = []
+    for (places, n_queries, rung), blk in zip(launches, device):
+        params = (n_queries, rung, packed.doc_pad)
+        # the outermost scope wins: a row built inside a sorted or aggregated
+        # launch's staging compiles under that family
+        with compile_tag("filtered"):
+            built.append((places, *_launch(
+                _get_multiterm_compiled(*params), (packed.blk_docs, blk),
+                "scoring.multiterm", "filtered", params)))
+    return built
+
+
+# ---------------------------------------------------------------------------
 # compile-warm builders (common/compilecache)
 # ---------------------------------------------------------------------------
 # Each builder maps a WarmSpec's recorded params back to the SAME jitted
@@ -2007,3 +2151,8 @@ def _build_sparse(params):
 @_WARM.builder("scoring.phrase")
 def _build_phrase(params):
     return _get_phrase_compiled(*params)
+
+
+@_WARM.builder("scoring.multiterm")
+def _build_multiterm(params):
+    return _get_multiterm_compiled(*params)

@@ -44,12 +44,14 @@ from ..index.segment import FrozenSegment
 from .filters import (
     BoolFilter,
     Filter,
+    MULTI_TERM_FILTERS,
     MatchAllFilter,
     QueryWrapperFilter,
     RangeFilter,
     TermFilter,
     segment_mask,
 )
+from . import multiterm
 from .queries import (
     BoolQuery,
     BoostingQuery,
@@ -324,6 +326,40 @@ def _plan_fields(plan: FlatPlan):
     return [c.field for c in plan.clauses]
 
 
+_MULTI_TERM_QUERIES = (PrefixQuery, WildcardQuery, RegexpQuery)
+
+
+def _multiterm_lowering(query: Query, ctx: ShardContext):
+    """(None, the filter whose mask is a prefix, wildcard or regexp QUERY's
+    match set) or (why the host scorer answers it, None). The filter is not
+    cacheable: the reference caches filters, never queries, so every such
+    search builds its row (on the chip: _filter_mask_matrix). Declined: a
+    scoring rewrite (the device holds no per-term scores of an expansion), a
+    field whose postings are not in the device planes, and an expansion of
+    more block rows on some segment than the ladder's last rung holds. The
+    pattern is expanded here, once a segment, to count those rows, and the
+    filter carries what was expanded to the launch (it lives as long as the
+    plan: one request)."""
+    from ..ops.scoring import MULTITERM_RUNGS
+
+    rewrite = (query.rewrite or "constant_score_auto").lower()
+    if not rewrite.startswith("constant_score"):
+        return "scoring_rewrite", None
+    kind, pattern = _multiterm_pattern(query)
+    filt = MULTI_TERM_FILTERS[kind](query.field, pattern, cached=False)
+    reason = filt.host_reason(ctx)
+    if reason:
+        return reason, None
+    expanded = []
+    for seg in ctx.searcher.segments:
+        exp = filt.expansion(seg)
+        if exp.rows > MULTITERM_RUNGS[-1]:
+            return "multiterm_expansion", None
+        expanded.append((seg, exp))
+    filt.expanded = tuple(expanded)
+    return None, filt
+
+
 def _unscored(query: Query, ctx: ShardContext, boost: float):
     """(const, filter | None, norm_boost) of a query with no scoring clause,
     or None: the filter whose mask is the query's whole match set (None: every
@@ -341,6 +377,11 @@ def _unscored(query: Query, ctx: ShardContext, boost: float):
         if ft is None or not ft.is_numeric:
             return None
         return b, TermFilter(query.field, query.value), 0.0
+    if isinstance(query, _MULTI_TERM_QUERIES):
+        # every document with a matching term scores the boost, as
+        # ConstantScoreQuery over the expansion does (the default rewrite)
+        reason, filt = _multiterm_lowering(query, ctx)
+        return None if reason else (b, filt, b)
     if isinstance(query, ConstantScoreQuery):
         return b, (query.filter if query.filter is not None
                    else QueryWrapperFilter(query.query)), b
@@ -589,6 +630,17 @@ def lower_fallback_reason(query: Query, ctx: ShardContext) -> str:
         # the only non-lowering match query: fuzzy (empty analysis still
         # lowers — to an empty flat plan that scores nothing on-device)
         return "fuzzy_match"
+    if isinstance(query, _MULTI_TERM_QUERIES):
+        # a prefix, wildcard or regexp lowers (an unscored plan whose mask
+        # row the chip builds) but for these
+        return _multiterm_lowering(query, ctx)[0]
+    if isinstance(query, FuzzyQuery):
+        # the host's expansion (the first max_expansions terms in dictionary
+        # order, constant score) is not Lucene's (the top terms by edit
+        # distance, each scored): no exact semantics to hold a program to
+        return "fuzzy_query"
+    if isinstance(query, SpanMultiTermQuery):
+        return "span_multi"
     if isinstance(query, BoolQuery):
         if query.filter:
             return "bool_filter_clause"
@@ -1570,21 +1622,43 @@ def _filter_mask_matrix(filters: list, seg, packed, ctx: ShardContext,
     MatchAllFilter's row; where no query has one the result is None and the
     launch takes scoring's resident [1, 1] no-op.
 
-    Per query: a resident device row from the node's filter cache when the
-    (segment, filter-key) mask is already in HBM (zero host evaluation, zero
-    transfer), else host evaluation via the per-segment host filter cache
-    (`segment_mask`) with sighting-based promotion to device residency
-    (DeviceFilterCache.maybe_store — build outside locks, device_put once,
-    publish under the leaf lock). Mask VALUES are identical either way, so
-    cached filtered plans score bitwise-identically to the uncached path.
+    Per query, a row has one of three sources. A resident device row from
+    the node's filter cache when the (segment, filter-key) mask is already in
+    HBM (zero host evaluation, zero transfer). Else, for a prefix, wildcard
+    or regexp (filters.MultiTermFilter: a filter, or the wrapper of such a
+    QUERY, which is not cacheable and never resident), a row BUILT ON THE
+    CHIP: the host expands the pattern over the segment's sorted dictionary
+    into block rows of the postings plane (search/multiterm.py) and
+    scoring.build_multiterm_rows gathers and ORs them, with no pull and no
+    put of a row between. Else host evaluation via the per-segment host
+    filter cache (`segment_mask`) with sighting-based promotion to device
+    residency (DeviceFilterCache.maybe_store: build outside locks,
+    device_put once, publish under the leaf lock).
+
+    A host row holds every document with a matching value, dead and nested
+    ones too: the launch's live gate is the view's own, so one cached row
+    serves every view of the segment (with_deletes views share the cache's
+    holder). A built row comes from `blk_docs`, in which THIS view's dead
+    documents are masked already: it answers this view exactly as a host
+    row does, but an older view (a scroll, a search in flight) would miss
+    the documents it still holds. So a cacheable filter's built row is
+    admitted by the sighting rule, without the put, only where the view has
+    no tombstone; on a view with one the row is built again each search.
 
     Returns a host bool [Q, Dpad] when every row stayed host-side (the
     pre-cache behavior, one implicit-free jnp.asarray commit at dispatch) or
-    a device [Q, Dpad] stack when any row is resident (host stragglers are
-    device_put explicitly). Where a row was evaluated on the host, the whole
-    assembly is noted on the dispatch clock as `shard.filter_mask` (inside
-    its `dispatch.stage`), and every host row's bytes are counted as
-    `search_serving.launch.mask_put_bytes`. `n_rows` pads the matrix with
+    a device [Q, Dpad] matrix when any row is on the device: the matrix one
+    build launch returned where it built every row of the batch in place (a
+    batch of multi-term searches of the first rung: nothing is stacked),
+    else the eager stack of the rows (host stragglers are device_put
+    explicitly; a build launch hands out its rows apart as well as
+    together, so nothing is ever sliced on the host). Where a row was
+    evaluated on the host, the whole assembly is noted on the dispatch clock
+    as `shard.filter_mask` (inside its `dispatch.stage`), and every host
+    row's bytes are counted as `search_serving.launch.mask_put_bytes`; where
+    one was built on the chip, the expansion and the operands' put are noted
+    as `shard.multiterm_expand` and counted under
+    `search_serving.launch.multiterm_*`. `n_rows` pads the matrix with
     rows that match nothing (a coalesced batch of unscored plans rides the
     pow-2 ladder of query counts, whether its rows are resident or not)."""
     if all(f is None for f in filters):
@@ -1593,6 +1667,9 @@ def _filter_mask_matrix(filters: list, seg, packed, ctx: ShardContext,
     rows = []
     any_dev = False
     host_bytes = 0
+    builds = []  # (place in `rows`, cache key | None, block rows to gather)
+    tally = {"multiterm_terms": 0, "multiterm_runs": 0,
+             "multiterm_field_scans": 0}
     t0 = time.monotonic()
     for f in filters:
         if f is None:
@@ -1602,6 +1679,15 @@ def _filter_mask_matrix(filters: list, seg, packed, ctx: ShardContext,
         if fc is not None and fc.enabled and f.cacheable():
             key = f.key()
             row = fc.lookup(seg, key)
+        named = f.block_rows(seg, packed, ctx) if row is None else None
+        if named is not None:
+            blk, terms, runs, whole_field = named
+            builds.append((len(rows), key, blk))
+            tally["multiterm_terms"] += terms
+            tally["multiterm_runs"] += runs
+            tally["multiterm_field_scans"] += whole_field
+            rows.append(None)
+            continue
         if row is None:
             m = np.zeros(packed.doc_pad, dtype=bool)
             m[: seg.doc_count] = segment_mask(seg, f, ctx)
@@ -1613,18 +1699,43 @@ def _filter_mask_matrix(filters: list, seg, packed, ctx: ShardContext,
         if not isinstance(row, np.ndarray):
             any_dev = True
         rows.append(row)
-    if n_rows is not None and n_rows > len(rows):
-        from ..ops.scoring import _false_row
+    want = max(n_rows or 0, len(rows))
+    whole = None
+    if builds:
+        from ..ops.scoring import LAUNCHES, _false_row, build_multiterm_rows
 
-        rows.extend([_false_row(packed.doc_pad) if any_dev
-                     else np.zeros(packed.doc_pad, dtype=bool)]
-                    * (n_rows - len(rows)))
-    if not any_dev:
-        out = np.stack(rows)
+        any_dev = True
+        admit = bool(seg.live.all())
+        for at, _key, _blk in builds:
+            rows[at] = _false_row(packed.doc_pad)  # a list with no row
+        launched = build_multiterm_rows(
+            packed, [blk for _at, _key, blk in builds], t0)
+        for places, _matrix, built in launched:
+            for place, row in zip(places, built):
+                at, key, _blk = builds[place]
+                if key is not None and admit:
+                    # the sighting rule's admission, with nothing to transfer
+                    kept = fc.maybe_store(seg, key, row)
+                    row = row if kept is None else kept
+                rows[at] = row
+        if len(launched) == 1 and launched[0][0] == list(range(len(rows))) \
+                and launched[0][1].shape[0] == want:
+            whole = launched[0][1]  # one launch built every row, in place
+        # a plan is counted once, on the view's first segment
+        LAUNCHES.bump(multiterm_searches=len(builds)
+                      if seg is ctx.searcher.segments[0] else 0, **tally)
+    if whole is not None:
+        out = whole
+    elif not any_dev:
+        out = np.stack(rows + [np.zeros(packed.doc_pad, dtype=bool)]
+                       * (want - len(rows)))
     else:
         import jax
         import jax.numpy as jnp
 
+        from ..ops.scoring import _false_row
+
+        rows.extend([_false_row(packed.doc_pad)] * (want - len(rows)))
         # compile_tag: the eager stack fuses cached device rows with fresh host
         # masks for the filtered kernels — outermost scope wins, so launches from
         # inside dense/sorted paths keep their own family.
@@ -2427,17 +2538,11 @@ class HostScorer:
             return q.field, spans, {(q.field, q.value)}
         if isinstance(q, SpanMultiTermQuery):
             inner = q.match
-            if isinstance(inner, (PrefixQuery, WildcardQuery, RegexpQuery)):
-                if isinstance(inner, PrefixQuery):
-                    pred = lambda t: t.startswith(inner.prefix)  # noqa: E731
-                elif isinstance(inner, WildcardQuery):
-                    rex = re.compile(_wildcard_to_regex(inner.pattern))
-                    pred = lambda t: rex.fullmatch(t) is not None  # noqa: E731
-                else:
-                    rex = re.compile(inner.pattern)
-                    pred = lambda t: rex.fullmatch(t) is not None  # noqa: E731
-                terms = [t for t in seg.terms_for_field(inner.field) if pred(t)]
+            if isinstance(inner, _MULTI_TERM_QUERIES):
                 field = inner.field
+                sorted_terms, first = seg.sorted_terms(field)
+                terms = [sorted_terms[t - first] for t in multiterm.expand(
+                    seg, field, *_multiterm_pattern(inner)).tids.tolist()]
             elif isinstance(inner, FuzzyQuery):
                 terms = self._fuzzy_terms(inner)
                 field = inner.field
@@ -2534,21 +2639,9 @@ class HostScorer:
 
     # -- multi-term ----------------------------------------------------------
     def _multi_term_mask(self, q) -> np.ndarray:
-        seg = self.seg
-        mask = np.zeros(self.D, bool)
-        if isinstance(q, PrefixQuery):
-            pred = lambda t: t.startswith(q.prefix)  # noqa: E731
-        elif isinstance(q, WildcardQuery):
-            rex = re.compile(_wildcard_to_regex(q.pattern))
-            pred = lambda t: rex.fullmatch(t) is not None  # noqa: E731
-        else:
-            rex = re.compile(q.pattern)
-            pred = lambda t: rex.fullmatch(t) is not None  # noqa: E731
-        for term in seg.terms_for_field(q.field):
-            if pred(term):
-                docs, _ = seg.postings(q.field, term)
-                mask[docs] = True
-        return mask
+        """The documents that hold a term the prefix, wildcard or regexp
+        names: multiterm.expand's terms, the ones the device path gathers."""
+        return multiterm.host_mask(self.seg, q.field, *_multiterm_pattern(q))
 
     def _fuzzy_terms(self, q: FuzzyQuery) -> list[str]:
         max_edits = _fuzzy_max_edits(q.fuzziness, q.value)
@@ -2580,8 +2673,9 @@ class HostScorer:
             return self._term_scores(q.field, terms[0], boost)
         last_terms = [terms[-1]]
         if q.prefix:
-            last_terms = [t for t in seg.terms_for_field(q.field)
-                          if t.startswith(terms[-1])][: q.max_expansions] or []
+            sorted_terms = seg.terms_for_field(q.field)
+            lo, hi = multiterm.head_range(sorted_terms, terms[-1])
+            last_terms = sorted_terms[lo: min(hi, lo + q.max_expansions)]
             if not last_terms:
                 return scores, match
         # candidate docs: intersection of postings
@@ -2797,16 +2891,13 @@ def _phrase_freq(pos_sets: list[set], rel_pos: list[int], slop: int, in_order: b
     return count
 
 
-def _wildcard_to_regex(pattern: str) -> str:
-    out = []
-    for ch in pattern:
-        if ch == "*":
-            out.append(".*")
-        elif ch == "?":
-            out.append(".")
-        else:
-            out.append(re.escape(ch))
-    return "".join(out)
+def _multiterm_pattern(q) -> tuple[str, str]:
+    """(kind, pattern) of a prefix, wildcard or regexp query, as
+    multiterm.expand takes them."""
+    if isinstance(q, PrefixQuery):
+        return multiterm.PREFIX, q.prefix
+    return (multiterm.WILDCARD if isinstance(q, WildcardQuery)
+            else multiterm.REGEXP), q.pattern
 
 
 def _fuzzy_max_edits(fuzziness, value: str) -> int:

@@ -24,7 +24,8 @@ import numpy as np
 from ..common.errors import QueryParsingError
 from ..index.segment import FrozenSegment
 from ..mapper.core import parse_date_math
-from ..ops.device_index import RecentKeys
+from ..ops.device_index import HOST_ONLY_FIELDS, RecentKeys
+from . import multiterm
 
 
 class Filter:
@@ -40,6 +41,13 @@ class Filter:
         would serve stale results after other segments change. Composites
         propagate from their children."""
         return True
+
+    def block_rows(self, seg, packed, ctx):
+        """Where the chip can build this filter's row from block rows of the
+        segment's postings plane (execute._filter_mask_matrix): (the rows
+        int32 ascending, terms matched, runs of rows, whether the whole
+        field's dictionary was tested). None: the host evaluates the row."""
+        return None
 
 
 _SIGHTINGS_LOCK = threading.Lock()  # leaf: guards every segment's RecentKeys
@@ -185,22 +193,86 @@ class RangeFilter(Filter):
         return mask
 
 
-@dataclass
-class PrefixFilter(Filter):
-    field: str
-    prefix: str
+@dataclass(kw_only=True)
+class MultiTermFilter(Filter):
+    """A filter whose match set is the union of the postings of the terms a
+    pattern names (`kind`, `pattern`: search/multiterm.py, the ONE expansion
+    the host scorer shares): PrefixFilter, WildcardFilter, RegexpFilter.
+
+    `evaluate` is the host's row. execute._filter_mask_matrix asks
+    `block_rows` first: the block rows of the postings plane the expansion
+    names, which a launch ORs into the mask row ON the chip
+    (scoring.build_multiterm_rows), or None where the row stays the host's:
+    a field whose postings are not in the device planes, or more rows than
+    the ladder's last rung. `cached` False is the wrapper of a multi-term
+    QUERY (execute._multiterm_lowering): the reference caches filters, never
+    queries, so every such search builds its row. That lowering expands the
+    pattern to count its rows, and hands what it expanded to the launch in
+    `expanded`: (segment, Expansion) pairs that HOLD their segments, so only
+    a filter made for one request carries any. A parsed filter can outlive
+    its request and its segments (a registered percolator query meets a new
+    segment every document) and expands where it is asked."""
+
+    cached: bool = True
+    expanded: tuple = dc_field(default=(), repr=False, compare=False)
+    kind = ""  # multiterm.PREFIX | WILDCARD | REGEXP; `pattern` is the subclass'
 
     def key(self):
-        return f"prefix:{self.field}:{self.prefix}"
+        return f"{self.kind}:{self.field}:{self.pattern}"
+
+    def cacheable(self):
+        return self.cached
+
+    def expansion(self, seg):
+        for held, exp in self.expanded:
+            if held is seg:
+                return exp
+        return multiterm.expand(seg, self.field, self.kind, self.pattern)
 
     def evaluate(self, seg, ctx):
-        mask = np.zeros(seg.doc_count, dtype=bool)
-        for term in seg.terms_for_field(self.field):
-            if term.startswith(self.prefix):
-                mask |= _postings_mask(seg, self.field, term)
-            elif term > self.prefix and not term.startswith(self.prefix):
-                break
-        return mask
+        return multiterm.docs_mask(seg, self.expansion(seg).tids)
+
+    def host_reason(self, ctx) -> str | None:
+        """Why the field's postings are not in the device planes (the
+        profile API's fallback reason), or None where they are."""
+        if self.field in HOST_ONLY_FIELDS:
+            return "host_only_field"
+        ft = ctx.field_type(self.field)
+        if ft is not None and ft.is_numeric:
+            return "multiterm_numeric_field"
+        return None
+
+    def block_rows(self, seg, packed, ctx):
+        from ..ops.scoring import MULTITERM_RUNGS
+
+        if self.host_reason(ctx):
+            return None
+        exp = self.expansion(seg)
+        if exp.rows > MULTITERM_RUNGS[-1]:
+            return None
+        rows, runs = multiterm.ranges_of(packed.term_blk_start, exp.tids)
+        return rows.astype(np.int32), len(exp.tids), runs, exp.whole_field
+
+
+@dataclass
+class PrefixFilter(MultiTermFilter):
+    field: str
+    prefix: str
+    kind = "prefix"
+
+    @property
+    def pattern(self):
+        return self.prefix
+
+
+@dataclass
+class WildcardFilter(MultiTermFilter):
+    """`*` any run of characters, `?` any one (the wrapper of a
+    WildcardQuery: ES 1.x has no wildcard filter of its own)."""
+
+    field: str
+    pattern: str
+    kind = "wildcard"
 
 
 @dataclass
@@ -508,20 +580,14 @@ class ScriptFilter(Filter):
 
 
 @dataclass
-class RegexpFilter(Filter):
+class RegexpFilter(MultiTermFilter):
     field: str
     pattern: str
+    kind = "regexp"
 
-    def key(self):
-        return f"regexp:{self.field}:{self.pattern}"
 
-    def evaluate(self, seg, ctx):
-        rex = re.compile(self.pattern)
-        mask = np.zeros(seg.doc_count, dtype=bool)
-        for term in seg.terms_for_field(self.field):
-            if rex.fullmatch(term):
-                mask |= _postings_mask(seg, self.field, term)
-        return mask
+MULTI_TERM_FILTERS = {cls.kind: cls for cls in (
+    PrefixFilter, WildcardFilter, RegexpFilter)}
 
 
 EARTH_RADIUS_M = 6371008.7714

@@ -145,6 +145,7 @@ class WildcardQuery(Query):
     field: str
     pattern: str
     boost: float = 1.0
+    rewrite: str | None = None
 
 
 @dataclass
@@ -152,6 +153,7 @@ class RegexpQuery(Query):
     field: str
     pattern: str
     boost: float = 1.0
+    rewrite: str | None = None
 
 
 @dataclass
@@ -735,11 +737,14 @@ _QUERY_PARSERS = {
     "dis_max": _parse_dis_max,
     "range": _parse_range_q,
     "prefix": lambda s: (lambda f, o: PrefixQuery(f, str(o.get("value", o.get("prefix", ""))),
-                                                  float(o.get("boost", 1.0))))(*_field_spec(s, "value")),
+                                                  float(o.get("boost", 1.0)),
+                                                  o.get("rewrite")))(*_field_spec(s, "value")),
     "wildcard": lambda s: (lambda f, o: WildcardQuery(f, str(o.get("value", o.get("wildcard", ""))),
-                                                      float(o.get("boost", 1.0))))(*_field_spec(s, "value")),
+                                                      float(o.get("boost", 1.0)),
+                                                      o.get("rewrite")))(*_field_spec(s, "value")),
     "regexp": lambda s: (lambda f, o: RegexpQuery(f, str(o.get("value", "")),
-                                                  float(o.get("boost", 1.0))))(*_field_spec(s, "value")),
+                                                  float(o.get("boost", 1.0)),
+                                                  o.get("rewrite")))(*_field_spec(s, "value")),
     "fuzzy": lambda s: (lambda f, o: FuzzyQuery(f, str(o.get("value", "")),
                                                 o.get("fuzziness", "AUTO"),
                                                 int(o.get("prefix_length", 0)),

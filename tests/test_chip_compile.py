@@ -148,6 +148,34 @@ def test_phrase_launch_compiles_for_v5e(one_chip, n_plans, rung):
     assert mem.argument_size_in_bytes >= 262_144 * BLOCK * 4
 
 
+@pytest.mark.parametrize("n_queries,rung", [(1, 0), (8, 0), (1, 1), (1, -1)],
+                         ids=["alone-first", "eight-first", "alone-second",
+                              "alone-last"])
+def test_multiterm_launch_compiles_for_v5e(one_chip, n_queries, rung):
+    """The multi-term mask program over the cell `wiki.multiterm`'s postings
+    plane (147,532 block rows at 50,000 documents: 147,584 padded, doc_pad
+    65,536) at both ends of the first rung's query counts and alone on the two
+    longer rungs: a gather of block rows and one scatter into the mask
+    matrix, a bool [doc_pad] row a search."""
+    from elasticsearch_tpu.common.jaxenv import compile_tag
+    from elasticsearch_tpu.ops.scoring import (MULTITERM_RUNGS,
+                                               _get_multiterm_compiled)
+
+    rows, doc_pad = MULTITERM_RUNGS[rung], 65_536
+    args = _shapes(one_chip, ((147_584, BLOCK), "int32"),  # the plane's doc ids
+                   ((n_queries, rows), "int32"))  # the launch's one operand
+    fn = _get_multiterm_compiled(n_queries, rows, doc_pad)
+    with compile_tag("filtered"):
+        compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text  # composed, no kernel
+    assert " scatter(" in text and " sort(" not in text  # one scatter, no sort
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 256 << 20
+    assert mem.argument_size_in_bytes >= 147_584 * BLOCK * 4
+    assert mem.output_size_in_bytes >= n_queries * doc_pad  # the rows, a byte a doc
+
+
 DOC_PAD_LOGS = 1 << 20  # a million log events in one force-merged segment
 
 

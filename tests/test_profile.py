@@ -135,8 +135,18 @@ class TestFallbackReasons:
             "query": "a b", "slop": 1}}}) == "sloppy_phrase"
         assert self._reason(shard_ctx, {"match_phrase_prefix": {
             "body": "a b"}}) == "phrase_prefix"
-        assert self._reason(shard_ctx, {"prefix": {"body": "qui"}}) \
-            == "unsupported_query:PrefixQuery"
+        # a prefix, wildcard or regexp lowers (tests/test_device_multiterm.py);
+        # the multi-term forms that stay on the host have reasons of their own
+        assert self._reason(shard_ctx, {"prefix": {"body": {
+            "value": "qui", "rewrite": "scoring_boolean"}}}) == "scoring_rewrite"
+        assert self._reason(shard_ctx, {"wildcard": {"_uid": "doc#*"}}) \
+            == "host_only_field"
+        assert self._reason(shard_ctx, {"fuzzy": {"body": "quik"}}) \
+            == "fuzzy_query"
+        assert self._reason(shard_ctx, {"span_multi": {"match": {
+            "prefix": {"body": "qui"}}}}) == "span_multi"
+        assert self._reason(shard_ctx, {"ids": {"values": ["1"]}}) \
+            == "unsupported_query:IdsQuery"
         assert self._reason(
             shard_ctx, {"match": {"body": {"query": "quik",
                                            "fuzziness": "AUTO"}}}) \

@@ -902,6 +902,49 @@ class TestLaunchTimeline:
         assert stats("device")["indices"]["traced"]["totals"][
             "positions_plane"] > 0
 
+    def test_a_prefix_records_its_expansion_and_its_counters(self, live):
+        """A `prefix` rides the batcher as an unscored filtered plan whose
+        mask row the chip builds: the host's expansion of the pattern into
+        block rows and the put of the launch's operand are a part of the stage
+        span, and /_nodes/stats books the launch, the plan, the terms and
+        runs matched and the block rows' bytes; no mask row is put and the
+        host scorer answers nothing."""
+        _cluster, _node, rc = live
+
+        def stats(section):
+            resp = rc.dispatch(RestRequest(
+                method="GET", path=f"/_nodes/stats/{section}"))
+            return next(iter(resp.body["nodes"].values()))[section]
+
+        body = {"query": {"prefix": {"body": "qu"}}, "size": 5}
+        _traced_search(rc, body)  # first sighting compiles the mask program
+        before, kinds0 = stats("search_serving"), stats("search")["batcher"]["kinds"]
+        tree = _traced_search(rc, body)
+        after, kinds1 = stats("search_serving"), stats("search")["batcher"]["kinds"]
+        _assert_nested(tree)
+        (expand,) = _find(tree, "shard.multiterm_expand")
+        (stage,) = [n for n in _find(tree, "dispatch.stage")
+                    if any(c["name"] == "shard.multiterm_expand"
+                           for c in n["children"])]
+        assert stage["t0"] <= expand["t0"] and expand["t1"] <= stage["t1"] + 1e-6
+        assert not _find(tree, "shard.filter_mask")  # no row evaluated on the host
+        launch0, launch1 = before["launch"], after["launch"]
+        assert launch1["multiterm"] == launch0["multiterm"] + 1
+        assert launch1["multiterm_searches"] == launch0["multiterm_searches"] + 1
+        assert launch1["multiterm_terms"] > launch0["multiterm_terms"]
+        assert launch1["multiterm_runs"] == launch0["multiterm_runs"] + 1
+        assert launch1["multiterm_bytes"] - launch0["multiterm_bytes"] == 256 * 128 * 4
+        assert 0 < launch1["multiterm_pad_bytes"] - launch0["multiterm_pad_bytes"] \
+            < launch1["multiterm_bytes"] - launch0["multiterm_bytes"]
+        assert launch1["multiterm_field_scans"] == launch0["multiterm_field_scans"]
+        assert launch1["unscored_plans"] == launch0["unscored_plans"] + 1
+        assert launch1["mask_put_bytes"] == launch0["mask_put_bytes"]
+        assert launch1["posting_bytes"] == launch0["posting_bytes"]
+        assert after["device_filtered"] == before["device_filtered"] + 1
+        assert after["host"] == before["host"]
+        for name in ("launches", "coalesced"):
+            assert kinds1["filtered"][name] == kinds0["filtered"][name] + 1
+
     def test_an_exact_sum_counts_its_limb_rows_and_their_bytes(self, live):
         """A sum of a long column under a terms bucket: the launch counts the
         integer limb rows it reduced, and the device ledger holds their bytes
