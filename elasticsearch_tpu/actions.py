@@ -121,6 +121,9 @@ A_SHARD_BROADCAST = "indices:admin/broadcast[s]"
 # shows the cluster-wide causal record
 A_EVENTS_PUBLISH = "internal:cluster/events/publish"
 
+# the search types whose query phase waits for a DFS round over every shard
+DFS_SEARCH_TYPES = ("dfs_query_then_fetch", "dfs_query_and_fetch")
+
 
 def _normalize_alias_specs(aliases: dict) -> dict:
     """Alias metadata stores index_routing/search_routing; a bare `routing` key sets
@@ -198,8 +201,10 @@ class ActionModule:
         # searches answered, by the trips they made to their shards: one where
         # the query phase of the one shard they met hydrated the page, two
         # where a fetch phase followed the reduce (plain ints booked in
-        # _finish_search; /_nodes/stats `search.phases`)
-        self.search_phases = {"one_trip": 0, "two_trip": 0}
+        # _finish_search; /_nodes/stats `search.phases`); `inline_query`: those
+        # of them whose query phase ran on the asking thread
+        # (_query_shard_inline books it)
+        self.search_phases = {"one_trip": 0, "two_trip": 0, "inline_query": 0}
         t = self.transport
         # master-node actions
         for action, fn in [
@@ -1608,8 +1613,7 @@ class ActionModule:
             # None = ineligible or failed, fall through to the transport path unchanged
             mesh_results = self.mesh_serving.try_search(
                 state, self.node.local_node.id, indices, alias_filters, shards, req,
-                use_global_stats=search_type in ("dfs_query_then_fetch",
-                                                 "dfs_query_and_fetch"),
+                use_global_stats=search_type in DFS_SEARCH_TYPES,
                 deadline=deadline)
             if mesh_results is None:
                 results, failures, chain_terminals, shard_meta = \
@@ -1654,12 +1658,14 @@ class ActionModule:
         """The transport query phase of one search (the DFS fan-out first,
         where the search type asks for it): every shard's chain dispatched at
         once, then collected. `page` ([from, size], one-shard searches only)
-        asks the shard for the page's hits beside its partial. Returns
-        (results, failures, chain_terminals, shard_meta); the caller decides
-        between a 429 and a partial answer."""
+        asks the shard for the page's hits beside its partial. A search that
+        met one shard whose only copy is on this node builds no chain: its
+        query phase runs here, on the calling thread (_inline_node,
+        _query_shard_inline). Returns (results, failures, chain_terminals,
+        shard_meta); the caller decides between a 429 and a partial answer."""
         dfs_stats = None
         dfs_failed: set[int] = set()  # ordinals excluded from the query phase
-        if search_type in ("dfs_query_then_fetch", "dfs_query_and_fetch"):
+        if search_type in DFS_SEARCH_TYPES:
             # concurrent DFS fan-out — the distributed-IDF all-reduce's gather leg
             # (ref: TransportSearchDfsQueryThenFetchAction async per-shard phase).
             # Each shard fails over across its copies like the query phase; a
@@ -1714,12 +1720,17 @@ class ActionModule:
         _, pin = self.routing.split_preference(preference)
         pin = pin or ""
         allow_hedge = not pin.startswith("_only_node:") and pin != "_primary"
-        query_futs = [
-            None if ordinal in dfs_failed else
-            self._query_shard_async(state, copy, body, alias_filters, dfs_stats,
-                                    deadline, allow_hedge=allow_hedge,
-                                    page=page)
-            for ordinal, copy in enumerate(shards)]
+        inline_node = self._inline_node(state, shards, search_type, page)
+        if inline_node is not None:
+            query_futs = [self._query_shard_inline(
+                shards[0], inline_node, body, alias_filters, deadline, page)]
+        else:
+            query_futs = [
+                None if ordinal in dfs_failed else
+                self._query_shard_async(state, copy, body, alias_filters,
+                                        dfs_stats, deadline,
+                                        allow_hedge=allow_hedge, page=page)
+                for ordinal, copy in enumerate(shards)]
         # shared backstop: chains resolve themselves (every attempt is
         # timer-bounded), so this only catches a wedged chain — scaled to the
         # longest possible failover chain, and clamped by the request deadline
@@ -1787,6 +1798,88 @@ class ActionModule:
                         - t_fanout)
         tracing.record_wake(span, answered, "transport")
         return results, failures, chain_terminals, shard_meta
+
+    def _inline_node(self, state, shards, search_type, page):
+        """The local node, where this search's query phase can run on the
+        calling thread with nothing lost: the search met ONE shard (`page`,
+        PR 38's predicate), no DFS round goes before the query, the chosen
+        copy is on this node, no other copy on a live node could take a
+        failover or a hedge, and no fault rule could match the message the
+        call stands for. Else None, and the chain runs. All of it is read off
+        this search's own input (ref: TransportSearchAction's shardCount == 1
+        and TransportService's localNode short-circuit); there is no setting."""
+        if page is None or search_type in DFS_SEARCH_TYPES:
+            return None
+        copy = shards[0]
+        node = state.nodes.get(copy.node_id)
+        if node is None or \
+                not self.transport.runs_locally(node, A_QUERY_PHASE):
+            return None
+        group = state.routing_table.index(copy.index).shard(copy.shard_id)
+        if any(state.nodes.get(other.node_id) is not None
+               for other in self.routing.other_copies(group, copy)):
+            return None
+        return node
+
+    def _query_shard_inline(self, copy: ShardRouting, node, body,
+                            alias_filters, deadline: Deadline, page) -> Future:
+        """The query phase of a search whose one shard has its only copy on
+        this node (_inline_node), run on the calling thread inside one of the
+        `search` pool's slots (TransportService.call_local): no chain, no
+        timer, no message to oneself, no thread to hand over to and wake up
+        after. Resolves, before it returns, what _query_shard_async's future
+        resolves to, with the same `attempt_errors` and `completed_at`, so
+        the collection loop reads either alike; the selector, the hedge
+        budget and admission control are fed as the chain's one attempt would
+        feed them. An exception is the chain's terminal error: a `failures`
+        entry of a partial answer, or the 429 where it is the pool's
+        rejection or a breaker's.
+
+        There is no attempt timer here to fail a late copy over: there is no
+        other copy. What bounds the wait is what bounds the `search` pool's
+        thread under the chain: the shard's deadline, and the batcher's own
+        guard (the remaining budget plus 30 s, search/batcher.py `_submit`)."""
+        done: Future = Future()
+        attempt_errors: list = []
+        done.attempt_errors = attempt_errors  # type: ignore[attr-defined]
+        payload = self._query_payload(copy, body, alias_filters, None,
+                                      deadline, page)
+        span = tracing.current_span()
+        if span:
+            # the shard continues the trace from the wire context, as one on
+            # another node does: its `shard` span is a child of this one
+            payload[tracing.TRACE_WIRE_KEY] = tracing.wire_context(span)
+        selector = self.routing.selector
+        if selector is not None:
+            selector.begin_attempt(copy)
+            selector.hedges.note_request()  # accrue hedge budget
+        t_sent = time.monotonic()
+        try:
+            r = self.transport.call_local(A_QUERY_PHASE, payload)
+            if selector is not None:
+                selector.observe(copy, time.monotonic() - t_sent,
+                                 load=r.get("load"))
+            if span:
+                span.trace.add_remote(r.get("spans"))
+            outcome = (self._shard_result(r, copy, hedge=False), node, None)
+        except Exception as e:  # noqa: BLE001 — the one attempt's failure,
+            # whatever it is, is the shard's failure (_query_shard_async's
+            # on_done: any error fails the attempt, the last one the chain)
+            if selector is not None:
+                selector.failure(copy)
+            attempt_errors.append((copy.node_id, e))
+            outcome = (None, None, e)
+        finally:
+            if selector is not None:
+                selector.end_attempt(copy)
+        if not isinstance(outcome[2], (CircuitBreakingError,
+                                       RejectedExecutionError)):
+            # a search shed with a 429 is booked under no trip either
+            # (_finish_search never sees it). No lock, as there
+            self.search_phases["inline_query"] += 1
+        done.completed_at = time.monotonic()  # type: ignore[attr-defined]
+        done.set_result(outcome)
+        return done
 
     def _finish_search(self, req, body, results, failures, shards, shard_meta, t0,
                        timed_out: bool = False):
@@ -1970,7 +2063,13 @@ class ActionModule:
 
         Resolves to (ShardQueryResult | None, node | None, error | None);
         every failed attempt is recorded on the returned future's
-        `attempt_errors` as (node_id, error)."""
+        `attempt_errors` as (node_id, error).
+
+        Not built at all where there is nothing for it to do: a search that
+        met one shard whose only copy is on the coordinator's own node, with
+        no DFS round and no fault rule in the way (_inline_node), runs its
+        query phase on the calling thread (_query_shard_inline) and resolves
+        the same triple."""
         done: Future = Future()
         # stamp resolution time for admission-control latency: the collection
         # loop drains futures in ordinal order, so "time until collected" of a
@@ -2068,23 +2167,12 @@ class ActionModule:
             # liveness was checked by try_next's claim loop against the SAME
             # immutable ClusterState snapshot — node cannot be None here
             node = state.nodes.get(candidate.node_id)
-            payload = {
-                "index": candidate.index, "shard": candidate.shard_id,
-                "body": body or {},
-                "alias_filter": alias_filters.get(candidate.index),
-                "dfs": dfs_stats,
-                # remaining budget as a DURATION (monotonic clocks don't
-                # cross processes); the shard restarts its own clock from it
-                "deadline_s": deadline.remaining(),
-            }
-            if page is not None:
-                # one trip: every attempt of the chain, failover and hedge
-                # alike, asks its copy for the page's hits too
-                payload["fetch"] = page
+            payload = self._query_payload(candidate, body, alias_filters,
+                                          dfs_stats, deadline, page)
             if hedge:
                 # the shard tags its span hedge:true from this (sibling shard
                 # spans in ?trace=true); the winner annotation on the profile
-                # happens coordinator-side below
+                # happens coordinator-side (_shard_result)
                 payload["hedge"] = True
             # re-activate the coordinator's span around the send: retry
             # attempts run on timer / transport-callback threads whose
@@ -2247,27 +2335,7 @@ class ActionModule:
                         # where this round-trip ended, for the coordinator's
                         # wake-up (written before `done` resolves)
                         done.answered_at = tracing.round_trip_end(f)  # type: ignore[attr-defined]
-                    prof = r.get("profile")
-                    if isinstance(prof, dict):
-                        # ?profile=true: record whether this shard's profile
-                        # came from the winning primary attempt or a hedge
-                        prof = {**prof,
-                                "winner": "hedge" if hedge else "primary"}
-                    result = ShardQueryResult(
-                        total=r["total"],
-                        docs=[tuple(d) for d in r["docs"]],
-                        max_score=r["max_score"] if r["max_score"] is not None else float("nan"),
-                        agg_partials=_decode_partials(r.get("agg_partials")),
-                        facet_partials=_decode_partials(r.get("facet_partials")),
-                        suggest=r.get("suggest"),
-                        context_id=r.get("ctx_id"),
-                        shard_id=candidate.shard_id,
-                        timed_out=bool(r.get("timed_out")),
-                        degraded=bool(r.get("degraded")),
-                        profile=prof,
-                        hits=r.get("hits"),
-                    )
-                    result.index_name = candidate.index  # type: ignore[attr-defined]
+                    result = self._shard_result(r, candidate, hedge)
                 except Exception as e:  # noqa: BLE001 — a malformed/corrupt
                     # response is an attempt failure like any other: fail
                     # over instead of terminally resolving (which would
@@ -2288,6 +2356,55 @@ class ActionModule:
 
         try_next(None)
         return done
+
+    @staticmethod
+    def _query_payload(copy: ShardRouting, body, alias_filters, dfs_stats,
+                       deadline: Deadline, page) -> dict:
+        """What one attempt of a shard's query phase asks of `copy`: the ONE
+        construction site, for every attempt of a chain and for the call that
+        builds none (_query_shard_inline)."""
+        payload = {
+            "index": copy.index, "shard": copy.shard_id,
+            "body": body or {},
+            "alias_filter": alias_filters.get(copy.index),
+            "dfs": dfs_stats,
+            # remaining budget as a DURATION (monotonic clocks don't
+            # cross processes); the shard restarts its own clock from it
+            "deadline_s": deadline.remaining(),
+        }
+        if page is not None:
+            # one trip: every attempt of the chain, failover and hedge
+            # alike, asks its copy for the page's hits too
+            payload["fetch"] = page
+        return payload
+
+    @staticmethod
+    def _shard_result(r: dict, copy: ShardRouting,
+                      hedge: bool) -> ShardQueryResult:
+        """The shard's answer (_s_query_phase's dict, over the wire or as the
+        handler returned it) as the reduce reads it: the ONE construction
+        site. Raises on a malformed answer."""
+        prof = r.get("profile")
+        if isinstance(prof, dict):
+            # ?profile=true: record whether this shard's profile
+            # came from the winning primary attempt or a hedge
+            prof = {**prof, "winner": "hedge" if hedge else "primary"}
+        result = ShardQueryResult(
+            total=r["total"],
+            docs=[tuple(d) for d in r["docs"]],
+            max_score=r["max_score"] if r["max_score"] is not None else float("nan"),
+            agg_partials=_decode_partials(r.get("agg_partials")),
+            facet_partials=_decode_partials(r.get("facet_partials")),
+            suggest=r.get("suggest"),
+            context_id=r.get("ctx_id"),
+            shard_id=copy.shard_id,
+            timed_out=bool(r.get("timed_out")),
+            degraded=bool(r.get("degraded")),
+            profile=prof,
+            hits=r.get("hits"),
+        )
+        result.index_name = copy.index  # type: ignore[attr-defined]
+        return result
 
     _PIN_KEEP_S = 60.0
 

@@ -199,7 +199,14 @@ class OperationRouting:
         `first` copy, then the remaining active copies best-first by the
         adaptive rank (quarantined copies last) — the first fallback is the
         best REMAINING copy, not the next array slot."""
-        rest = [s for s in group.active_shards() if s.node_id != first.node_id]
+        rest = self.other_copies(group, first)
         if self.selector is not None and rest:
             rest = self.selector.ranked(rest)
         return [first] + rest
+
+    @staticmethod
+    def other_copies(group: IndexShardRoutingTable,
+                     first: ShardRouting) -> list[ShardRouting]:
+        """The group's active copies on other nodes than `first`'s, unranked:
+        what a failover or a hedge could go to. Empty for the only copy."""
+        return [s for s in group.active_shards() if s.node_id != first.node_id]

@@ -194,13 +194,33 @@ def thread_compile_totals() -> tuple[int, float]:
             getattr(_thread_compiles, "s", 0.0))
 
 
-def _pool_label() -> str:
-    """Which named threadpool the current thread belongs to — pool workers are
-    named "estpu[<pool>]_N" (threadpool._BoundedPool); anything else reads as
-    "other". The compile listener's pool attribution: the warmed-node
-    invariant is that steady-state compile events show pool=warmer/merge
-    only (same parse as device_index._pool_label, kept local so this module
-    stays import-leaf)."""
+_lent = threading.local()
+
+
+@contextlib.contextmanager
+def serving_pool(name: str):
+    """The calling thread runs one of the named pool's tasks for the scope:
+    the pool lent it a slot and no thread (threadpool._BoundedPool
+    .run_inline), so where work is attributed to pools it answers to that
+    pool's name and not to its own."""
+    prev = getattr(_lent, "pool", None)
+    _lent.pool = name
+    try:
+        yield
+    finally:
+        _lent.pool = prev
+
+
+def pool_label() -> str:
+    """Which named threadpool the current thread works for — pool workers are
+    named "estpu[<pool>]_N" (threadpool._BoundedPool), a thread inside
+    `serving_pool` works for the pool that lent it a slot; anything else (a
+    test's main thread, a raw Thread) reads as "other". The compile listener's
+    and the pack ledger's pool attribution: the warmed-node invariant is that
+    steady-state compile events and packs show pool=warmer/merge only."""
+    lent = getattr(_lent, "pool", None)
+    if lent is not None:
+        return lent
     name = threading.current_thread().name
     if name.startswith("estpu[") and "]" in name:
         return name[len("estpu["): name.index("]")]
@@ -286,7 +306,7 @@ class _CompileCounter:
         # must not extend the critical section other compiling threads share
         origin = _package_origin() \
             if family == "untagged" and self._record_origins else None
-        pool = _pool_label()
+        pool = pool_label()
         # XLA compiles on the triggering thread, so the thread's own tally
         # lets the batcher drainer name the batch a stall fell into
         _thread_compiles.n = getattr(_thread_compiles, "n", 0) + 1

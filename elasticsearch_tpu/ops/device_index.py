@@ -91,6 +91,7 @@ from ..common import profile as _profile
 from ..common.breaker import reserve
 from ..common.devicehealth import tag_domain as _tag_domain
 from ..common.errors import CircuitBreakingError
+from ..common.jaxenv import pool_label
 from ..index.segment import FrozenSegment
 from ..transport.faults import DEVICE_FAULTS as _DEVICE_FAULTS
 
@@ -510,18 +511,6 @@ def packed_tier_bytes(packed: PackedSegment) -> dict:
     }
 
 
-def _pool_label() -> str:
-    """Which named threadpool is running the current thread — the ledger's
-    pack attribution. Pool workers are named "estpu[<pool>]_N"
-    (threadpool._BoundedPool's thread_name_prefix); anything else (a test's
-    main thread, a raw Thread) reads as "other". One string parse on the
-    already-cold pack path."""
-    name = threading.current_thread().name
-    if name.startswith("estpu[") and "]" in name:
-        return name[len("estpu["): name.index("]")]
-    return "other"
-
-
 # ledger kind= vocabulary: "pack" (initial/full pack), "delta_pack" (a
 # refresh-frozen increment — bounded by the buffer, not the index),
 # "remask" (tombstone-driven live-mask refresh), "compact" (a merged
@@ -598,7 +587,7 @@ class PackLedger:
                layout: str, kind: str = "pack", pool: str | None = None,
                method: str | None = None) -> None:
         index = index or "_unattributed"
-        pool = pool or _pool_label()
+        pool = pool or pool_label()
         with self._lock:
             entry = self._by_index.get(index)
             if entry is None:

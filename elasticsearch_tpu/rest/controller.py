@@ -398,7 +398,7 @@ def _prometheus_text(node) -> str:
     for family in COMPILE_FAMILIES:
         w.counter("estpu_jax_compile_family_total",
                   by_family.get(family, 0), family=family)
-    # compile events by OBSERVING POOL (jaxenv._pool_label thread-name parse):
+    # compile events by OBSERVING POOL (jaxenv.pool_label):
     # the warmed-node invariant made scrapable — steady state puts every
     # compile on warmer/startup labels, serving pools read 0. Labels are
     # bounded (fixed threadpool names + "other"); declared so the family

@@ -19,10 +19,12 @@ import heapq
 import itertools
 import threading
 import time
+from collections import deque
 from concurrent.futures import Future, ThreadPoolExecutor
 
 from .common import tracing
 from .common.errors import RejectedExecutionError
+from .common.jaxenv import serving_pool
 from .common.logging import get_logger
 from .common.metrics import HistogramMetric
 
@@ -142,11 +144,17 @@ class _ScheduledTask:
 
 
 class _BoundedPool:
-    """ThreadPoolExecutor wrapper tracking queued/active/rejected/completed and
-    enforcing the queue bound. `queued` counts tasks submitted but not yet
-    picked up by a worker; rejection triggers when the queued backlog exceeds
-    the bound plus currently-idle workers (an idle worker consumes a submit
-    near-immediately, so it is headroom, not queue)."""
+    """`size` slots over a ThreadPoolExecutor of `size` threads, tracking
+    queued/active/rejected/completed and enforcing the queue bound. A task
+    takes a slot where it arrives (`_enter`), at once while one is free and
+    else a place in the line for one, and hands it on where it ends
+    (`_leave`), to the head of the line: so slots are granted in arrival
+    order, to a pooled task (`submit`: one of the pool's threads runs it)
+    and to an inline one (`run_inline`: the calling thread does) alike, and
+    the two kinds together never run more than `size` at once. `active`
+    counts the slots held, `queued` the tasks in the line; rejection
+    triggers when the line exceeds the bound plus the free slots (a free
+    slot consumes an arrival at once, so it is headroom, not queue)."""
 
     def __init__(self, name: str, size: int, queue_size: int):
         self.name = name
@@ -155,17 +163,29 @@ class _BoundedPool:
         self.executor = ThreadPoolExecutor(max_workers=size,
                                            thread_name_prefix=f"estpu[{name}]")
         self._lock = threading.Lock()
+        # the line for a slot: one gate a waiting task, opened by the task
+        # that hands its slot on (or by shutdown). Not empty only while
+        # every slot is held
+        self._line: deque = deque()
+        self._closed = False
         self.queued = 0
         self.active = 0
         self.rejected = 0
         self.completed = 0
-        # queue-wait (submit → a worker picks the task up) per task: the
-        # histogram that separates "slow because queued" from "slow because
-        # device" in /_nodes/stats (lock-striped, own leaf locks)
+        # queue-wait (arrival → the task runs) per task: the histogram that
+        # separates "slow because queued" from "slow because device" in
+        # /_nodes/stats (lock-striped, own leaf locks)
         self.queue_wait = HistogramMetric()
 
-    def submit(self, fn, *args, **kwargs) -> Future:
+    def _enter(self):
+        """Admission, the same for a pooled and an inline task: reject where
+        the queue is full, else take a slot or a place in the line. Returns
+        the gate to wait at, None where the slot is already held."""
         with self._lock:
+            if self._closed:
+                self.rejected += 1
+                raise RejectedExecutionError(
+                    f"rejected execution on [{self.name}]: pool is shut down")
             if self.queue_size >= 0:
                 idle = max(0, self.size - self.active)
                 if self.queued - idle >= self.queue_size:
@@ -174,38 +194,99 @@ class _BoundedPool:
                         f"rejected execution on [{self.name}]: queue capacity "
                         f"[{self.queue_size}] full "
                         f"(queued [{self.queued}], active [{self.active}])")
+            if self.active < self.size and not self._line:
+                self.active += 1
+                return None
+            gate = threading.Event()
+            self._line.append(gate)
             self.queued += 1
+            return gate
+
+    def _leave(self):
+        """Give the slot up: to the head of the line where there is one (the
+        slot changes hands, `active` stands), else back to the pool."""
+        with self._lock:
+            self.completed += 1
+            if self._line:
+                self.queued -= 1
+                self._line.popleft().set()
+            else:
+                self.active -= 1
+
+    def _start(self, gate, t_arrived: float, span):
+        """Wait at `gate` for the slot, then book the wait: the histogram's
+        sample and, of a sampled request (`span`, current where the task
+        arrived), its `pool.wait`."""
+        if gate is not None:
+            gate.wait()
+            if self._closed:
+                # shutdown opened every gate: nothing runs on a closed pool
+                raise RejectedExecutionError(
+                    f"rejected execution on [{self.name}]: pool is shut down")
+        t_run = time.monotonic()
+        self.queue_wait.observe(t_run - t_arrived)
+        if span:
+            span.record("pool.wait", t_arrived, t_run, pool=self.name)
+
+    def submit(self, fn, *args, **kwargs) -> Future:
+        gate = self._enter()
         try:
             # the span current at submit (one thread-local read; None or the
             # falsy NOOP span where the request is not sampled) rides beside
-            # the submit stamp: the worker records its wait under it
-            return self.executor.submit(self._run, fn, args, kwargs,
+            # the arrival stamp: the worker records its wait under it
+            return self.executor.submit(self._run, fn, args, kwargs, gate,
                                         time.monotonic(),
                                         tracing.current_span())
         except RuntimeError:
             # executor shut down — still a rejection, just a terminal one
             with self._lock:
-                self.queued -= 1
                 self.rejected += 1
+                if gate is None:
+                    self.active -= 1
+                elif gate in self._line:
+                    self._line.remove(gate)
+                    self.queued -= 1
             raise RejectedExecutionError(
                 f"rejected execution on [{self.name}]: pool is shut down") \
                 from None
 
-    def _run(self, fn, args, kwargs, t_submit: float, span=None):
-        t_run = time.monotonic()
-        self.queue_wait.observe(t_run - t_submit)
-        if span:
-            # a sampled request's own wait for this pool, submit to pick-up
-            span.record("pool.wait", t_submit, t_run, pool=self.name)
-        with self._lock:
-            self.queued -= 1
-            self.active += 1
+    def _run(self, fn, args, kwargs, gate, t_submit: float, span=None):
+        self._start(gate, t_submit, span)
         try:
             return fn(*args, **kwargs)
         finally:
-            with self._lock:
-                self.active -= 1
-                self.completed += 1
+            self._leave()
+
+    def run_inline(self, fn, *args):
+        """Run `fn(*args)` on the CALLING thread as one of the pool's `size`
+        workers: the pool lends a slot, not a thread. Admission is `submit`'s
+        (the queue bound and its RejectedExecutionError, the counters, the
+        `queue_wait` sample, the `pool.wait` span under the span current
+        here, whose length is the wait for a slot), and while `fn` runs the
+        thread answers to the pool's name where work is attributed to pools
+        (jaxenv.pool_label). For a caller that would block for the task's
+        result anyway: it pays no hand-over to a pool thread and none back.
+        What bounds the wait for a slot is what bounds a pooled task's wait
+        in the queue: the tasks ahead of it."""
+        t_arrived = time.monotonic()
+        self._start(self._enter(), t_arrived, tracing.current_span())
+        try:
+            with serving_pool(self.name):
+                return fn(*args)
+        finally:
+            self._leave()
+
+    def shutdown(self):
+        """Close the pool: pending pooled tasks are cancelled, and every task
+        in the line for a slot is let go with a rejection (a cancelled task
+        hands no slot on, so the line would never move again)."""
+        with self._lock:
+            self._closed = True
+            line, self._line = self._line, deque()
+            self.queued -= len(line)
+        for gate in line:
+            gate.set()
+        self.executor.shutdown(wait=False, cancel_futures=True)
 
     def stats(self) -> dict:
         with self._lock:
@@ -278,6 +359,14 @@ class ThreadPool:
                 f.set_exception(e)
             return f
         return self._pools[name].submit(fn, *args, **kwargs)
+
+    def run_inline(self, name: str, fn, *args):
+        """Run fn on the calling thread inside one of the named pool's slots
+        and return its result (_BoundedPool.run_inline); "same" has no slots
+        to lend and just calls it."""
+        if name == "same":
+            return fn(*args)
+        return self._pools[name].run_inline(fn, *args)
 
     # scheduling -------------------------------------------------------------
     def schedule(self, delay_s: float, name: str, fn) -> "ScheduledTimer":
@@ -417,7 +506,7 @@ class ThreadPool:
         self._scheduler_thread.join(timeout=1.0)
         self._timer_thread.join(timeout=1.0)
         for pool in self._pools.values():
-            pool.executor.shutdown(wait=False, cancel_futures=True)
+            pool.shutdown()
 
     def stats(self) -> dict:
         return {name: pool.stats() for name, pool in self._pools.items()}

@@ -158,6 +158,19 @@ class FaultPolicy:
                 return rule
         return None
 
+    def may_match(self, action: str, address: str) -> bool:
+        """Whether some armed rule, of either direction, names this action at
+        this address; a rule's `where` and `probability` are not asked, so the
+        answer errs towards yes. No hit is recorded and the RNG is not
+        advanced: for a caller that must know BEFORE it chooses a path on
+        which the rules do not apply (TransportService.call_local)."""
+        with self._lock:
+            return any(
+                (rule.max_hits is None or rule.hits < rule.max_hits)
+                and _glob_match(action, rule.action)
+                and _glob_match(str(address), rule.node)
+                for rule in self._rules)
+
     # --- installation ------------------------------------------------------
     def install(self, transport_service) -> "FaultPolicy":
         """Attach to a live TransportService (e.g. a TestCluster node's

@@ -5,7 +5,9 @@ Analogue of transport/TransportService.java (SURVEY.md §2.2): a handler registr
 per-request timeouts, and pluggable backends (LocalTransport in-process; NettyTransport's
 role is filled by tcp.py). Payloads are JSON-able dicts; every message round-trips
 through the wire codec even in-process, so serialization bugs surface in unit tests
-exactly like the reference's AssertingLocalTransport (SURVEY.md §4.3).
+exactly like the reference's AssertingLocalTransport (SURVEY.md §4.3). The one way
+past the codec is `call_local`, which sends no message: a blocking caller runs a
+handler of its own node on its own thread.
 """
 
 from __future__ import annotations
@@ -247,6 +249,10 @@ class TransportService:
         # Self-addressed requests short-circuit past the backend (the reference
         # TransportService does the same for localNode): still codec-roundtripped
         # for wire-compat assertions, but no socket / simulated-network hop.
+        # They still cross two pools, `generic` here and the handler's own in
+        # _dispatch_now, and the caller of a future needs that. A caller that
+        # would block on the future at once, for one answer of its own node,
+        # has `call_local` instead: no message, no pool thread, no future.
         if self._is_local(node):
             payload = self._wire_copy(request, action, fut, tspan)
 
@@ -331,6 +337,60 @@ class TransportService:
         wait — no per-request timer thread; send_request's future-level
         timeout is for CALLBACK-driven callers that have no thread parked."""
         return fut_result(self.send_request(node, action, request), timeout)
+
+    def runs_locally(self, node, action: str) -> bool:
+        """Whether `call_local` would run `action`'s handler on the calling
+        thread: `node` is this node, the action has a handler here, and no
+        fault rule could match it (send side or receive side, whatever its
+        probability or `where`: the rules were written for messages, so a
+        node under fault injection sends messages). Asking draws nothing
+        from the policy's RNG and records no hit."""
+        return self._is_local(node) and action in self.handlers and \
+            (self.fault_policy is None or not self.fault_policy.may_match(
+                action, self.local_node.transport_address))
+
+    def call_local(self, action: str, request: dict):
+        """Blocking, for an action of THIS node: run its handler on the
+        calling thread, inside one of its executor's slots
+        (threadpool.run_inline: the pool's admission, bound and counters, no
+        hand-over to a pool thread and none back), with `request` as it is,
+        and return what the handler answered as it is; the handler's
+        exception, or the pool's RejectedExecutionError, is raised here.
+
+        No message is made: no `_wire_copy`, no `_roundtrip`, no `generic`
+        hop, no future, so nothing is charged to the in-flight breaker
+        either, which charges the encoded bytes of messages in flight. Request
+        and answer are SHARED with the handler, not copies: neither side may
+        mutate what it was handed. `tx_count` / `rx_count` count the call as
+        the message it stands for. Where `runs_locally` says no (a fault rule
+        could match), the request takes the ordinary path, `submit_request`
+        to this node. Wire compatibility stays asserted where `send_request`
+        is used, which is everywhere else."""
+        if not self.runs_locally(self.local_node, action):
+            return self.submit_request(self.local_node, action, request)
+        self.stats["tx_count"] += 1
+        self.stats["rx_count"] += 1
+        handler = self.handlers[action]
+        answer: list = []
+
+        def respond(response, error):
+            answer.append((response, error))
+
+        channel = TransportChannel(respond)
+        if self.threadpool is None:
+            result = handler.fn(request, channel)
+        else:
+            result = self.threadpool.run_inline(handler.executor, handler.fn,
+                                                request, channel)
+        if result is not None:
+            return result
+        if not answer:
+            raise TransportError(
+                f"handler of [{action}] answered nothing on the calling thread")
+        response, error = answer[0]
+        if error is not None:
+            raise error
+        return response
 
     # --- receiving (called by backends) -------------------------------------
     def dispatch(self, action: str, request: Any, channel: TransportChannel):

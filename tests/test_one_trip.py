@@ -55,20 +55,31 @@ def served(tmp_path_factory):
 
 @contextlib.contextmanager
 def _watched(node, monkeypatch, two_trips: bool):
-    """The actions this node sends while the scope runs; with `two_trips` a
-    query phase goes out without its page, as to an index of several shards,
-    so the coordinator has to follow it with a fetch phase."""
+    """The actions this node sends, or runs on the asking thread in a
+    message's place (TransportService.call_local: the query phase of a search
+    whose one shard has its only copy here), while the scope runs; with
+    `two_trips` a query phase goes out without its page, as to an index of
+    several shards, so the coordinator has to follow it with a fetch phase."""
     sent = []
-    real = node.transport.send_request
+    real_send = node.transport.send_request
+    real_call = node.transport.call_local
 
-    def send(target, action, payload, *args, **kwargs):
+    def asked(action, payload):
         sent.append(action)
         if two_trips and action == A_QUERY_PHASE:
-            payload = {k: v for k, v in payload.items() if k != "fetch"}
-        return real(target, action, payload, *args, **kwargs)
+            return {k: v for k, v in payload.items() if k != "fetch"}
+        return payload
+
+    def send(target, action, payload, *args, **kwargs):
+        return real_send(target, action, asked(action, payload), *args,
+                         **kwargs)
+
+    def call(action, payload):
+        return real_call(action, asked(action, payload))
 
     with monkeypatch.context() as m:
         m.setattr(node.transport, "send_request", send)
+        m.setattr(node.transport, "call_local", call)
         yield sent
 
 
@@ -127,9 +138,11 @@ class TestParity:
         page = bool(two["hits"]["hits"])
         assert sent == [A_QUERY_PHASE,
                         A_FETCH_PHASE if page else actions_mod.A_FREE_CONTEXT]
+        # both query phases ran on the asking thread: the only copy is here
         assert node.actions.search_phases == {
             "one_trip": before["one_trip"] + 1,
-            "two_trip": before["two_trip"] + 1}
+            "two_trip": before["two_trip"] + 1,
+            "inline_query": before["inline_query"] + 2}
         assert _but_took(one) == _but_took(two)
         assert one["_shards"] == {"total": 1, "successful": 1, "degraded": 0,
                                   "failed": 0}
@@ -228,7 +241,8 @@ class TestPhaseCounters:
         assert client.count("one", {"query": MATCH})["count"] > 0
         after = _phases(node)
         assert after == {"one_trip": before["one_trip"] + 1,
-                         "two_trip": before["two_trip"]}
+                         "two_trip": before["two_trip"],
+                         "inline_query": before["inline_query"] + 1}
 
 
 class TestFetchFailure:

@@ -185,6 +185,11 @@ def test_a_compiling_or_packing_copy_is_late_not_wedged(tmp_path, busy):
 
     n1.transport.register_handler(A_QUERY_PHASE, late, executor="search")
     n1.transport.register_handler(A_QUERY_PROGRESS, progress, executor="management")
+    # the attempt timer is the failover chain's, and a search whose one shard has
+    # its only copy on the asking node builds no chain (it runs its query phase on
+    # the asking thread: actions._inline_node): take that path away, so that this
+    # one copy is asked as a copy with a replica elsewhere is
+    n1.actions._inline_node = lambda *a, **kw: None
     cls = type(n1.actions)
     old_timeout = cls.QUERY_ATTEMPT_TIMEOUT
     cls.QUERY_ATTEMPT_TIMEOUT = 0.3

@@ -306,7 +306,7 @@ def live(tmp_path_factory):
 SEARCH_BODY = {"query": {"match": {"body": "quick brown"}}, "size": 5}
 
 
-def _concurrent_searches(rc, n, trace=True):
+def _concurrent_searches(rc, n, trace=True, index="traced"):
     barrier = threading.Barrier(n)
     out = [None] * n
 
@@ -314,7 +314,7 @@ def _concurrent_searches(rc, n, trace=True):
         barrier.wait()
         params = {"trace": "true"} if trace else {}
         out[i] = rc.dispatch(RestRequest(
-            method="POST", path="/traced/_search", params=params,
+            method="POST", path=f"/{index}/_search", params=params,
             body=dict(SEARCH_BODY)))
 
     threads = [threading.Thread(target=worker, args=(i,)) for i in range(n)]
@@ -359,7 +359,7 @@ class TestLiveTraceTree:
         assert coalesced is not None, "requests never coalesced in 8 attempts"
         results, trees, tags = coalesced
         for resp, tree in zip(results, trees):
-            # the acceptance chain: rest → coordinator → (transport) → shard →
+            # the acceptance chain: rest → coordinator → shard →
             # batcher{queue,dispatch,merge} → device_pull
             assert tree["name"] == "rest"
             names = {n["name"] for n in _flatten(tree)}
@@ -368,9 +368,13 @@ class TestLiveTraceTree:
                     "device_pull"} <= names, names
             (coord,) = _find(tree, "coordinator")
             (shard,) = _find(tree, "shard")
-            # the shard span nests (via the transport span) under coordinator
-            assert any(n["name"].startswith("transport[")
-                       for n in _flatten(coord))
+            # the shard span nests under the coordinator's query phase with
+            # no transport span between: the one shard's only copy is on the
+            # coordinator's node, so its query phase ran on the asking thread
+            (query,) = _find(coord, "coordinator.query")
+            assert shard["id"] in {c["id"] for c in query["children"]}
+            assert not any(n["name"].startswith("transport[")
+                           for n in _flatten(tree))
             batcher_names = {c["name"] for c in shard["children"]}
             assert {"batcher.queue", "batcher.dispatch",
                     "batcher.merge"} <= batcher_names
@@ -704,11 +708,13 @@ class TestLaunchTimeline:
         assert [c["name"] for c in coord["children"]] == [
             "coordinator.plan", "coordinator.query", "coordinator.reduce",
             "coordinator.fetch", "coordinator.render"]
-        # the transport round-trips of a phase nest under it; the one shard
-        # built the page's hits in its query phase, so the fetch has none
+        # the one shard's query phase ran on the asking thread, inside a slot
+        # of the `search` pool, and built the page's hits, so the fetch has
+        # no child; the round-trips of an index of several shards nest under
+        # their phase (TestHostHandovers, `traced2`)
         (query,) = _find(tree, "coordinator.query")
         (fetch,) = _find(tree, "coordinator.fetch")
-        assert any(c["name"].startswith("transport[") for c in query["children"])
+        assert [c["name"] for c in query["children"]] == ["pool.wait", "shard"]
         assert fetch["children"] == []
         (shard,) = _find(tree, "shard")
         assert shard["children"][0]["name"] == "shard.lower"
@@ -1171,16 +1177,20 @@ NAMED = ("rest.parse", "coordinator.plan", "coordinator.reduce",
          "batcher.dispatch", "batcher.hold", "batcher.merge", "device_pull")
 HANDOVERS = ("pool.wait", "thread.wake", "transport.codec", "shard.fetch",
              "batcher.hold")
+# a search whose one shard has its only copy on the asking node sends itself
+# no message (actions._query_shard_inline): every hand-over but the codec's
+HANDOVERS_INLINE = tuple(h for h in HANDOVERS if h != "transport.codec")
 
 
 def _descendants(n):
     return [d for c in n["children"] for d in _flatten(c)]
 
 
-# a search of `traced` (one shard) makes one trip; one of `traced2` (two
-# shards, hits on both) a query phase and a fetch phase: the fetch cases live
-# where the fetch phase does
-INDEX_OF = {QUERY_ACTION: "traced", FETCH_ACTION: "traced2"}
+# a search of `traced` (one shard, its only copy here) makes one trip, on the
+# asking thread: no round trip at all. One of `traced2` (two shards, hits on
+# both) sends a query phase and a fetch phase to each: the cases of the
+# transport's spans live where the transport is used
+ROUND_TRIPS = "traced2"
 
 
 class TestHostHandovers:
@@ -1190,9 +1200,9 @@ class TestHostHandovers:
     def test_each_pool_hop_of_each_phase_records_its_wait(self, live, action,
                                                           pool):
         _cluster, _node, rc = live
-        tree = _traced_search(rc, SEARCH_BODY, index=INDEX_OF[action])
+        tree = _traced_search(rc, SEARCH_BODY, index=ROUND_TRIPS)
         tspans = _find(tree, action)
-        assert len(tspans) == (1 if action == QUERY_ACTION else 2)
+        assert len(tspans) == 2  # one a shard
         for tspan in tspans:
             waits = [c for c in tspan["children"] if c["name"] == "pool.wait"]
             assert [w["tags"]["pool"] for w in waits] == ["generic", "search"]
@@ -1207,12 +1217,11 @@ class TestHostHandovers:
                              ids=["query", "fetch"])
     def test_both_round_trips_of_a_phase_record_the_codec(self, live, action):
         _cluster, _node, rc = live
-        tree = _traced_search(rc, SEARCH_BODY, index=INDEX_OF[action])
+        tree = _traced_search(rc, SEARCH_BODY, index=ROUND_TRIPS)
         transports = [n for n in _flatten(tree)
                       if n["name"].startswith("transport[")]
-        # one shard: one round trip, a request and a response. Two shards:
-        # two round trips a phase
-        assert len(transports) == (1 if action == QUERY_ACTION else 4)
+        # two shards: two round trips a phase, each a request and a response
+        assert len(transports) == 4
         assert len(_find(tree, "transport.codec")) == 2 * len(transports)
         for tspan in _find(tree, action):
             request, response = [c for c in tspan["children"]
@@ -1234,17 +1243,22 @@ class TestHostHandovers:
             assert fetch["parent"] == tspan["id"] and fetch["node"] == node.name
             assert fetch in tspan["children"]
 
-    def test_one_shard_fetches_inside_its_query_round_trip(self, live):
+    def test_one_shard_fetches_inside_its_query_phase(self, live):
         """One trip: the shard's query handler builds the page's hits, so the
-        one `shard.fetch` is the last child of the `shard` span, inside the
-        one transport round trip; `coordinator.fetch` stays, with no round
-        trip of its own."""
+        one `shard.fetch` is the last child of the `shard` span, which hangs
+        under `coordinator.query` behind the wait for the `search` pool's
+        slot, with no round trip (the only copy is here: the handler ran on
+        the asking thread); `coordinator.fetch` stays, with no child."""
         _cluster, node, rc = live
         tree = _traced_search(rc, SEARCH_BODY)
-        (tspan,) = [n for n in _flatten(tree)
-                    if n["name"].startswith("transport[")]
-        assert tspan["name"] == QUERY_ACTION
+        assert not any(n["name"].startswith("transport[")
+                       for n in _flatten(tree))
+        assert _find(tree, "transport.codec") == []
         (shard,) = _find(tree, "shard")
+        (query,) = _find(tree, "coordinator.query")
+        (wait,) = _find(tree, "pool.wait")
+        assert query["children"] == [wait, shard]
+        assert wait["tags"]["pool"] == "search" and wait["t1"] <= shard["t0"]
         (fetch,) = _find(tree, "shard.fetch")
         assert fetch["parent"] == shard["id"] and fetch["node"] == node.name
         assert shard["children"][-1] is fetch
@@ -1260,9 +1274,11 @@ class TestHostHandovers:
 
     @pytest.mark.parametrize("index,after,parents", [
         ("traced", "batcher", ["shard"]),
-        ("traced", "transport", ["coordinator.query"]),
+        # one shard, its only copy here: the asking thread ran the query
+        # phase itself and waited for no round trip
+        ("traced", "transport", []),
         ("traced2", "transport", ["coordinator.query", "coordinator.fetch"])],
-        ids=["batcher", "transport-one-trip", "transport"])
+        ids=["batcher", "no-transport-one-trip", "transport"])
     def test_a_waiting_thread_records_its_wake_up(self, live, index, after,
                                                   parents):
         _cluster, _node, rc = live
@@ -1296,7 +1312,7 @@ class TestHostHandovers:
         tree = _traced_search(rc, body)
         _assert_nested(tree)
         names = {n["name"] for n in _flatten(tree)}
-        assert set(HANDOVERS) <= names
+        assert set(HANDOVERS_INLINE) <= names
         named = [n for n in _flatten(tree) if n["name"] in NAMED]
         for i, a in enumerate(named):
             inside_a = {d["id"] for d in _descendants(a)}
@@ -1337,7 +1353,9 @@ class TestHostHandovers:
         assert resp.status == 200 and "trace" not in resp.body
         assert recorded == [] and ring() == before
         _traced_search(rc, SEARCH_BODY)
-        assert set(HANDOVERS) <= set(recorded) and ring() != before
+        assert set(HANDOVERS_INLINE) <= set(recorded) and ring() != before
+        _traced_search(rc, SEARCH_BODY, index=ROUND_TRIPS)
+        assert set(HANDOVERS) <= set(recorded)
 
     @pytest.mark.parametrize("shards", [1, 2])
     def test_a_two_node_search_stitches_the_remote_fetch(self, tmp_path,
@@ -1409,7 +1427,9 @@ class TestHostRuntimeCounters:
         before = _runtime(rc)["cpu"]
         assert set(before["threads"]) == set(self.ROLES)
         for _ in range(3):
-            _concurrent_searches(rc, 2, trace=False)
+            # two shards: each phase is a message to each, handled on the
+            # pools (a search of `traced` runs on the thread that asks)
+            _concurrent_searches(rc, 2, trace=False, index="traced2")
         after = _runtime(rc)["cpu"]
         assert after["process_s"] > before["process_s"]
         for role in self.ROLES:
