@@ -390,8 +390,10 @@ MANIFEST_NAME = "compile_manifest.json"
 # longer take, so its specs are dropped at load (the ladders are kept)
 # 2: operand planes (scoring.TermBatch.tri / SparseBatch.slots); 3: the dense
 # launches take the head rows and the head-slot plane (TermBatch.head); 4: the
-# phrase launch takes the block rows it gathers as a list (phrase_operands)
-MANIFEST_VERSION = 4
+# phrase launch takes the block rows it gathers as a list (phrase_operands);
+# 5: the dense launches take ONE packed operand plane and M as a literal
+# (scoring.TermBatch.plane), and a mask may be a tuple of rows
+MANIFEST_VERSION = 5
 _MESH_RING = 4  # recent mesh plan batches kept per index
 
 

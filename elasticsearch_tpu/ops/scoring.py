@@ -81,14 +81,19 @@ class TermBatch:
     per-query bool-semantics arrays. Built host-side by the query planner
     (search/execute.py).
 
-    A launch hands the device three planes: `tri` (int32 [6, M], one row per
-    triple column, the f32 weights as their bits), `qplane` (int32
-    [Q, 2 + C+1]: n_must, msm, then the coord row as its bits) and `head`
-    (int32 [5, Q, HEAD_SLOTS]: a clause whose term has a row of
-    device_index head_rows names that row here and no block in `tri`). The
-    per-triple columns below are host VIEWS of `tri`."""
+    A launch hands the device ONE flat int32 `plane`, in one put
+    (_dense_args), and the program takes it apart by static slices
+    (_plane_views): `tri` (int32 [6, M], one row per triple column, the f32
+    weights as their bits), then `qplane` (int32 [Q, 2 + C+1]: n_must, msm,
+    then the coord row as its bits), then `head` (int32 [5, Q, HEAD_SLOTS]: a
+    clause whose term has a row of device_index head_rows names that row here
+    and no block in `tri`). `tri`, `qplane` and `head` are host VIEWS of
+    `plane` (build_term_batch fills the one buffer), and the per-triple
+    columns below are views of `tri`; nothing else of a batch crosses to the
+    device."""
 
     n_queries: int
+    plane: np.ndarray  # int32 [6*M + Q*(2 + C+1) + 5*Q*HEAD_SLOTS]: tri | qplane | head
     tri: np.ndarray  # int32 [6, M] — rows _T_*
     qplane: np.ndarray  # int32 [Q, 2 + C+1]
     head: np.ndarray  # int32 [5, Q, HEAD_SLOTS] — rows _H_*; pad: head_rows' last (zero) row
@@ -128,7 +133,7 @@ class ConstBatch:
     coord — one constant score a query (execute.unscored_score). The match set
     is the family's filter mask and the live documents; such a batch launches
     the `scoring_*_unscored` programs (_unscored_abi) behind the same tails as
-    a TermBatch."""
+    a TermBatch. What crosses to the device is `plane`: the scores' bits."""
 
     score: np.ndarray  # float32 [Q]
     blocks_real: int = 0  # no postings block is read (the profile's record)
@@ -136,6 +141,11 @@ class ConstBatch:
     @property
     def n_queries(self) -> int:
         return len(self.score)
+
+    @property
+    def plane(self) -> np.ndarray:
+        """The launch's one operand plane (int32 [Q]): a view, no copy."""
+        return self.score.view(np.int32)
 
 
 def _top_k_tail(scores, match, *, k: int):
@@ -293,11 +303,13 @@ class LaunchCounters:
     of device_index head_rows they added and `blocks_as_rows` the postings
     blocks those rows stood in for (`blocks_real` / `blocks_launched` count
     what is still scattered). `operand_puts` counts the host arrays launch
-    sites put on the device (_put_operands: each leaf of a launch's one
-    device_put is its own transfer): two for a warmed sparse launch, three
-    for a warmed dense one, one for a plain mesh search (its operand plane;
-    parallel/mesh_search.py). The launches of plans with no scoring clause
-    are tallied apart (`bump`): they read no postings."""
+    sites put on the device (_put_operands: each host leaf of a launch's one
+    device_put is its own transfer): two for a warmed sparse launch, ONE for
+    a warmed dense one of any family (its packed operand plane, _dense_args;
+    two where a mask the host evaluated rides along), one for a plain mesh
+    search (its operand plane; parallel/mesh_search.py). The launches of
+    plans with no scoring clause are tallied apart (`bump`): they read no
+    postings."""
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -378,17 +390,28 @@ class LaunchCounters:
 LAUNCHES = LaunchCounters()
 
 
-def _put_operands(*host, shardings=None):
-    """A launch's ONE explicit host→device transfer: every host operand goes
-    down in a single jax.device_put (legal under transfer_guard("disallow");
-    leaves already on the device pass through untouched). Returns the
-    operands as device arrays, in order. `shardings`, one per operand, is
-    the mesh launch's: where each operand goes on a mesh of several chips."""
+def _put_operands(*operands, shardings=None):
+    """A launch's ONE explicit host→device transfer (legal under
+    transfer_guard("disallow")): the HOST leaves of `operands` (numpy arrays
+    and scalars, at any depth of a tuple operand) go down in a single
+    jax.device_put of a flat list, and nothing else is handed to it: a
+    resident operand is no leaf of the put's pytree, it is passed on as it
+    is. Returns the operands, in order and in their own structure, every leaf
+    a device array. `shardings`, one per operand, is the mesh launch's: where
+    each operand goes on a mesh of several chips (there the whole tuple is
+    put, and a resident operand comes back untouched)."""
     import jax
 
-    LAUNCHES.puts(sum(isinstance(leaf, (np.ndarray, np.generic))
-                      for leaf in jax.tree_util.tree_leaves(host)))
-    return jax.device_put(host, shardings)
+    leaves, tree = jax.tree_util.tree_flatten(operands)
+    at = [i for i, leaf in enumerate(leaves)
+          if isinstance(leaf, (np.ndarray, np.generic))]
+    LAUNCHES.puts(len(at))
+    if shardings is not None:
+        return jax.device_put(operands, shardings)
+    if at:
+        for i, placed in zip(at, jax.device_put([leaves[i] for i in at])):
+            leaves[i] = placed
+    return jax.tree_util.tree_unflatten(tree, leaves)
 
 
 def _unpack_qplane(qplane):
@@ -401,14 +424,43 @@ def _unpack_qplane(qplane):
             jax.lax.bitcast_convert_type(qplane[:, 2:], jnp.float32))
 
 
-def _pack_qplane(n_must, msm, coord) -> np.ndarray:
-    """The per-query operand plane (host side of _unpack_qplane)."""
+def _pack_qplane(n_must, msm, coord, out: np.ndarray | None = None) -> np.ndarray:
+    """The per-query operand plane (host side of _unpack_qplane), into `out`
+    (int32 [Q, 2 + C+1]: a view of a launch's one plane) where given."""
     coord = np.ascontiguousarray(coord, np.float32)
-    qplane = np.empty((coord.shape[0], 2 + coord.shape[1]), np.int32)
+    qplane = out if out is not None else np.empty(
+        (coord.shape[0], 2 + coord.shape[1]), np.int32)
     qplane[:, 0] = n_must
     qplane[:, 1] = msm
     qplane[:, 2:] = coord.view(np.int32)
     return qplane
+
+
+def _plane_views(plane: np.ndarray, m: int, n_queries: int):
+    """(tri [6, M], qplane [Q, 2 + C+1], head [5, Q, HEAD_SLOTS]) of a dense
+    launch's flat operand plane: host views for build_term_batch to fill,
+    and, `plane` being a traced array, the program's own static slices
+    (_dense_abi). The coord width is what is left of the plane's length, as C
+    is in mesh_search._unpack_plane."""
+    o, h = 6 * m, 5 * n_queries * HEAD_SLOTS
+    end = plane.shape[0] - h
+    return (plane[:o].reshape(6, m),
+            plane[o:end].reshape(n_queries, (end - o) // n_queries),
+            plane[end:].reshape(5, n_queries, HEAD_SLOTS))
+
+
+def _plane_scalars(plane, n: int):
+    """(the plane without its last `n` words, those words as f32 scalars): the
+    few per-launch scalars of a function_score tail ride the launch's plane
+    as their bits (_dense_args `scalars`), inside a program."""
+    import jax
+    import jax.numpy as jnp
+
+    if not n:
+        return plane, ()
+    cut = plane.shape[0] - n
+    words = jax.lax.bitcast_convert_type(plane[cut:], jnp.float32)
+    return plane[:cut], tuple(words[i] for i in range(n))
 
 
 def _launch(fn, args, site: str | None = None, family: str = "", params=()):
@@ -459,23 +511,47 @@ def _count_dense(packed: PackedSegment, batch: TermBatch) -> None:
                  blocks_as_rows=batch.blocks_as_rows)
 
 
+# where the dense launch ABI carries M, the one size of the plane's layout
+# that neither its length nor the program's other statics give (jax.jit's
+# static_argnums; a rung of the `terms` ladder, so a handful of values)
+_DENSE_STATIC_ARGNUMS = (6,)
+
+
+def _mask_matrix(fmask):
+    """A tail's mask operand as the [Q, Dpad] (or broadcastable [1, 1])
+    matrix it gates the match by, inside a program: a tuple of Q [Dpad] rows
+    (execute._filter_mask_matrix: resident rows of the filter cache, host
+    stragglers the launch's put carried) is stacked HERE, so that no eager
+    program runs between the drainer's collect and the launch."""
+    import jax.numpy as jnp
+
+    return jnp.stack(fmask) if isinstance(fmask, tuple) else fmask
+
+
 def _dense_abi(tail, *, n_queries: int, doc_pad: int, simple: bool = False,
-               **statics):
+               scalars: int = 0, **statics):
     """`tail` behind the dense launch ABI (blk_docs, blk_freqs, head_rows,
-    live_parent, doc_table, tri, qplane, head, *extra): the ONE
-    scoring core every dense family shares. The three operand planes a launch
-    puts on the device are taken apart INSIDE the program (row slices and a
-    bit-exact bitcast), accumulated (_dense_accumulate) and matched; the
-    family's `tail(scores, match, *extra, **statics)` ranks and reduces.
+    live_parent, doc_table, plane, M, *extra): the ONE scoring core every
+    dense family shares. Of these only `plane` was put for the launch
+    (_dense_args): the batch's flat int32 operand plane, taken apart INSIDE
+    the program by static slices and bit-exact bitcasts (_plane_views:
+    tri | qplane | head, then the tail's `scalars` f32 words), accumulated
+    (_dense_accumulate) and matched; the family's `tail(scores, match,
+    *extra, *scalars, **statics)` ranks and reduces. `M` (the triples' rung)
+    is a static argument (_DENSE_STATIC_ARGNUMS): it decided the program as
+    `tri`'s shape, it does so as a value. `extra` are the family's resident
+    operands and, where the host evaluated one, a mask that rode the put.
 
     simple=True is a host-detected static fast path: every clause is a SHOULD
     with msm<=1, no coord — match reduces to score>0, so the int counters and
     the per-doc match bookkeeping are skipped entirely (the bulk-query hot
     shape)."""
     def wrapper(blk_docs, blk_freqs, head_rows, live_parent, doc_table,
-                tri, qplane, head, *extra):
+                plane, m, *extra):
         import jax
 
+        plane, tail_scalars = _plane_scalars(plane, scalars)
+        tri, qplane, head = _plane_views(plane, m, n_queries)
         scores, counts = _dense_accumulate(
             blk_docs, blk_freqs, head_rows, doc_table, tri, head,
             Q=n_queries, doc_pad=doc_pad, counters=not simple)
@@ -485,7 +561,7 @@ def _dense_abi(tail, *, n_queries: int, doc_pad: int, simple: bool = False,
         else:
             scores, match = _dense_semantics(scores, counts, live_parent,
                                              *_unpack_qplane(qplane))
-        return tail(scores, match, *extra, **statics)
+        return tail(scores, match, *extra, *tail_scalars, **statics)
 
     return wrapper
 
@@ -499,20 +575,25 @@ def _count_unscored(packed: PackedSegment, batch: ConstBatch,
                   * packed.doc_pad * (1 + 4 + row_bytes))
 
 
-def _unscored_abi(tail, *, n_queries: int, doc_pad: int, **statics):
+def _unscored_abi(tail, *, n_queries: int, doc_pad: int, scalars: int = 0,
+                  **statics):
     """`tail` behind the launch ABI of plans with no scoring clause
-    (live_parent, score [Q], *extra): every live document matches and scores
-    its query's constant; the tail gates the match by the filter mask it takes
-    (as it does for a scored plan) and ranks and reduces. No postings plane,
-    head row, document table or coord is an operand."""
-    def wrapper(live_parent, score, *extra):
+    (live_parent, plane, *extra): every live document matches and scores its
+    query's constant, the first Q words of the launch's one plane (their f32
+    bits; then the tail's `scalars` words, as in _dense_abi); the tail gates
+    the match by the filter mask it takes (as it does for a scored plan) and
+    ranks and reduces. No postings plane, head row, document table or coord
+    is an operand."""
+    def wrapper(live_parent, plane, *extra):
         import jax
         import jax.numpy as jnp
 
+        plane, tail_scalars = _plane_scalars(plane, scalars)
         with jax.named_scope("match_const"):
+            score = jax.lax.bitcast_convert_type(plane, jnp.float32)
             match = jnp.broadcast_to(live_parent[None, :], (n_queries, doc_pad))
             scores = jnp.broadcast_to(score[:, None], (n_queries, doc_pad))
-        return tail(scores, match, *extra, **statics)
+        return tail(scores, match, *extra, *tail_scalars, **statics)
 
     return wrapper
 
@@ -529,6 +610,11 @@ def _abi_for(batch):
         else (_dense_abi, "")
 
 
+def _static_argnums(abi) -> tuple:
+    """jax.jit's static_argnums of a program behind `abi`: the dense ABI's M."""
+    return _DENSE_STATIC_ARGNUMS if abi is _dense_abi else ()
+
+
 def _get_compiled(n_queries: int, k: int, doc_pad: int, simple: bool = False):
     import jax
 
@@ -537,7 +623,8 @@ def _get_compiled(n_queries: int, k: int, doc_pad: int, simple: bool = False):
     if fn is None:
         wrapper = _dense_abi(_top_k_tail, n_queries=n_queries, doc_pad=doc_pad,
                              simple=simple, k=k)
-        fn = jax.jit(_named("scoring.dense", wrapper, "simple" if simple else "bool"))
+        fn = jax.jit(_named("scoring.dense", wrapper, "simple" if simple else "bool"),
+                     static_argnums=_DENSE_STATIC_ARGNUMS)
         _compiled_cache[key] = fn
     return fn
 
@@ -585,7 +672,7 @@ def _fs_rows_impl(scores, match, fmask, g_row, applies_row, max_boost, fboost,
                   no_functions: bool):
     import jax.numpy as jnp
 
-    match = match & fmask
+    match = match & _mask_matrix(fmask)
     if no_functions:
         out = scores * fboost
     else:
@@ -606,7 +693,7 @@ def _fs_script_impl(scores, match, fmask, col_rows, fmask_row, bad_row,
 
     from ..script import jax_vectorizer_cls
 
-    match = match & fmask
+    match = match & _mask_matrix(fmask)
     cols = dict(zip(used_fields, col_rows))
     vec = jax_vectorizer_cls()(script, lambda f: cols[f], scores)
     val = jnp.broadcast_to(jnp.asarray(vec.vectorize(), jnp.float32), scores.shape)
@@ -630,21 +717,24 @@ def _get_fs_compiled(kind: str, n_queries: int, k: int, doc_pad: int,
                      abi=_dense_abi, suffix: str = "", **statics):
     import jax
 
+    # the tail's last operands are per-launch f32 scalars (max_boost, boost,
+    # min_score; the script's weight before them): they ride the plane
     if kind == "rows":
         key = ("fs_rows", n_queries, k, doc_pad, tuple(sorted(statics.items())))
-        impl = _fs_rows_impl
+        impl, scalars = _fs_rows_impl, 3
     else:
         script = statics.pop("script")
         key = ("fs_script", n_queries, k, doc_pad, script.source,
                repr(sorted(script.params.items())),
                tuple(sorted((k2, v) for k2, v in statics.items())))
-        impl = functools.partial(_fs_script_impl, script=script)
+        impl, scalars = functools.partial(_fs_script_impl, script=script), 4
     key += (suffix,) if suffix else ()
     fn = _compiled_cache.get(key)
     if fn is None:
         wrapper = abi(impl, n_queries=n_queries, doc_pad=doc_pad, k=k,
-                      **statics)
-        fn = jax.jit(_named("scoring.fs_" + kind, wrapper, suffix))
+                      scalars=scalars, **statics)
+        fn = jax.jit(_named("scoring.fs_" + kind, wrapper, suffix),
+                     static_argnums=_static_argnums(abi))
         _compiled_cache[key] = fn
     return fn
 
@@ -701,19 +791,31 @@ def _doc_table(packed: PackedSegment, batch: TermBatch):
     return table
 
 
-def _dense_args(packed: PackedSegment, batch: TermBatch, *host):
-    """The argument list of a dense launch (the _dense_abi order): resident
-    planes and tables, then the batch's three operand planes and the family's
-    own `host` operands, all put on the device in one transfer. A ConstBatch
-    (_unscored_abi) names no resident plane but the live mask."""
+def _dense_args(packed: PackedSegment, batch: TermBatch, *extra, scalars=()):
+    """The argument list of a dense launch (the _dense_abi order). Between
+    the drainer's collect and the compiled call this is the ONE place a dense
+    launch touches the device, once: a single put (_put_operands) of the
+    batch's flat operand plane (`scalars`, a function_score tail's few f32,
+    appended as their bits) and of whatever of the family's `extra` operands
+    is a host array: a mask the host evaluated, as a matrix or as stragglers
+    among a tuple of resident rows; function or column rows the row store
+    does not hold yet. Everything else is resident and passed as it is: the
+    postings planes, head rows, live mask and document table, and of `extra`
+    the agg stack's rows and limbs, the bucket pairs, a device mask or its
+    rows, sort key rows, function rows. A ConstBatch (_unscored_abi) names no
+    resident plane but the live mask."""
+    plane = batch.plane
+    if scalars:
+        plane = np.concatenate(
+            [plane, np.asarray(scalars, np.float32).view(np.int32)])
     if isinstance(batch, ConstBatch):
-        return (packed.live_parent, *_put_operands(batch.score, *host))
+        return (packed.live_parent, *_put_operands(plane, *extra))
     doc_table = _doc_table(packed, batch)
     head_rows = ensure_head_rows(packed)
     _count_dense(packed, batch)
+    plane, *extra = _put_operands(plane, *extra)
     return (packed.blk_docs, ensure_blk_freqs(packed), head_rows,
-            packed.live_parent, doc_table,
-            *_put_operands(batch.tri, batch.qplane, batch.head, *host))
+            packed.live_parent, doc_table, plane, len(batch.blk), *extra)
 
 
 def _count_fs_unscored(packed: PackedSegment, batch, row_bytes: int) -> None:
@@ -743,8 +845,8 @@ def score_fs_rows_batch_async(packed: PackedSegment, batch: TermBatch, k: int,
     args = _dense_args(
         packed, batch, _no_mask() if fmask is None else fmask,
         g_row, applies_row,
-        np.float32(max_boost), np.float32(fboost),
-        np.float32(min_score if min_score is not None else 0.0))
+        scalars=(max_boost, fboost,
+                 min_score if min_score is not None else 0.0))
     # the script variant is NOT recorded: its executable closes over a live
     # sandboxed script object that has no JSON form to replay from a manifest
     return _launch(fn, args, _site("scoring.fs_rows", suffix),
@@ -769,9 +871,8 @@ def score_fs_script_batch_async(packed: PackedSegment, batch: TermBatch, k: int,
     return _launch(fn, _dense_args(
         packed, batch, _no_mask() if fmask is None else fmask,
         tuple(col_rows), fmask_row, bad_row, parent_row,
-        np.float32(weight if weight is not None else 1.0),
-        np.float32(max_boost), np.float32(fboost),
-        np.float32(min_score if min_score is not None else 0.0)))
+        scalars=(weight if weight is not None else 1.0, max_boost, fboost,
+                 min_score if min_score is not None else 0.0)))
 
 
 # ---------------------------------------------------------------------------
@@ -870,7 +971,7 @@ def _dense_sort_impl(scores, match,
     import jax
     import jax.numpy as jnp
 
-    match = match & fmask
+    match = match & _mask_matrix(fmask)
     key = jnp.broadcast_to(key_row[None, :], match.shape)
     pad = jnp.float32(-jnp.inf) if descending else jnp.float32(jnp.inf)
     sortable = jnp.where(match, key, pad)
@@ -897,7 +998,8 @@ def _get_sorted_compiled(n_queries: int, k: int, doc_pad: int,
     if fn is None:
         wrapper = abi(_dense_sort_impl, n_queries=n_queries,
                       doc_pad=doc_pad, k=k, descending=descending)
-        fn = jax.jit(_named("scoring.sorted", wrapper, suffix))
+        fn = jax.jit(_named("scoring.sorted", wrapper, suffix),
+                     static_argnums=_static_argnums(abi))
         _compiled_cache[key] = fn
     return fn
 
@@ -998,9 +1100,9 @@ def _dense_aggstats_impl(scores, match,
                          agg_rows,  # [F, 5, Dpad] f32 (F may be 0)
                          agg_limbs,  # [F, L, Dpad] int32 (L may be 0)
                          bucket_pairs,  # tuple of (pair_doc, pair_bucket, nb zeros, (sub rows, sub limbs)|None)
-                         fmask,  # bool [Q, Dpad] — FilteredQuery masks (all-true when none)
+                         fmask,  # bool [Q, Dpad] | tuple of Q [Dpad] rows — FilteredQuery masks (all-true when none)
                          *, k: int):
-    match = match & fmask
+    match = match & _mask_matrix(fmask)
     counts, stats, limb_sums = agg_stat_reduction(match, agg_rows, agg_limbs)
     bucket_counts = tuple(
         _bucket_scatter(match, pdoc, pbucket, zeros_nb.shape[0],
@@ -1027,7 +1129,8 @@ def _get_agg_compiled(n_queries: int, k: int, doc_pad: int, nb_bucket: int,
         wrapper = abi(_dense_aggstats_impl, n_queries=n_queries,
                       doc_pad=doc_pad, k=k)
         fn = jax.jit(_named("scoring.aggs", wrapper, "_".join(filter(
-            None, ["filtered" if filtered else "", suffix]))))
+            None, ["filtered" if filtered else "", suffix]))),
+                     static_argnums=_static_argnums(abi))
         _compiled_cache[key] = fn
     return fn
 
@@ -1067,8 +1170,8 @@ def score_agg_batch_async(packed: PackedSegment, batch: TermBatch, k: int,
     limb_rows = sum(sum(st.limbed) * st.limbs.shape[1] for st in stacks)
     if limb_rows:
         LAUNCHES.bump(exact_sum_rows=limb_rows)
-    # a host mask rides the launch's one put (device arrays pass through
-    # it); a raw numpy arg would be an implicit H2D at dispatch
+    # a host mask rides the launch's one put; the resident stacks and pairs
+    # are no part of it
     args = _dense_args(packed, batch, agg_stack.rows, agg_stack.limbs, pairs,
                        fmask)
     return _launch(fn, args, _site("scoring.aggs", suffix), "aggs", params)
@@ -1570,14 +1673,6 @@ def build_term_batch(entries: list, n_queries: int, n_must: np.ndarray, msm: np.
         else:
             heads.append((*e, used[q]))
             used[q] += 1
-    head = np.zeros((5, n_queries, HEAD_SLOTS), np.int32)
-    head[_H_ROW] = head_pad_row
-    blocks_as_rows = 0
-    if heads:
-        q, b0, b1, w, f, g, m, row, slot = zip(*heads)
-        head[:, q, slot] = (row, np.asarray(w, np.float32).view(np.int32),
-                            f, g, m)
-        blocks_as_rows = sum(b1) - sum(b0)
     cols = np.zeros((6, len(tail)), np.int32)
     nb = np.zeros(len(tail), np.int64)
     if tail:
@@ -1591,11 +1686,6 @@ def build_term_batch(entries: list, n_queries: int, n_must: np.ndarray, msm: np.
         cols[_T_BLK] = b0 - (np.cumsum(nb) - nb)
     n = int(nb.sum())
     M = _ladder_bucket("terms", max(n, 1), TAIL_FLOOR)
-    tri = np.zeros((6, M), np.int32)
-    tri[:, :n] = np.repeat(cols, nb, axis=1)
-    tri[_T_BLK, :n] += np.arange(n, dtype=np.int32)
-    tri[_T_BLK, n:] = nb_pad_row
-    n_must, msm = n_must.astype(np.int32), msm.astype(np.int32)
     # the coord table's width is a dimension of the program's key too: up the
     # pow-2 ladder, each row continued with its last value (what the overlap
     # clamp reads past a row's end anyway)
@@ -1603,9 +1693,24 @@ def build_term_batch(entries: list, n_queries: int, n_must: np.ndarray, msm: np.
     wide[:, :coord.shape[1]] = coord
     wide[:, coord.shape[1]:] = coord[:, -1:]
     coord = wide
+    # the launch's ONE operand plane, filled in place through its views
+    plane = np.zeros(6 * M + n_queries * (2 + coord.shape[1])
+                     + 5 * n_queries * HEAD_SLOTS, np.int32)
+    tri, qplane, head = _plane_views(plane, M, n_queries)
+    head[_H_ROW] = head_pad_row
+    blocks_as_rows = 0
+    if heads:
+        q, b0, b1, w, f, g, m, row, slot = zip(*heads)
+        head[:, q, slot] = (row, np.asarray(w, np.float32).view(np.int32),
+                            f, g, m)
+        blocks_as_rows = sum(b1) - sum(b0)
+    tri[:, :n] = np.repeat(cols, nb, axis=1)
+    tri[_T_BLK, :n] += np.arange(n, dtype=np.int32)
+    tri[_T_BLK, n:] = nb_pad_row
+    n_must, msm = n_must.astype(np.int32), msm.astype(np.int32)
+    _pack_qplane(n_must, msm, coord, out=qplane)
     return TermBatch(
-        n_queries=n_queries, tri=tri, qplane=_pack_qplane(n_must, msm, coord),
-        head=head,
+        n_queries=n_queries, plane=plane, tri=tri, qplane=qplane, head=head,
         qidx=tri[_T_QIDX], blk=tri[_T_BLK], weight=tri[_T_WEIGHT].view(np.float32),
         fidx=tri[_T_FIDX], group=tri[_T_GROUP], tfmode=tri[_T_TFMODE],
         n_must=n_must, msm=msm, coord=coord, norm_fields=norm_fields,

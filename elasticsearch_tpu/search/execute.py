@@ -1645,20 +1645,24 @@ def _filter_mask_matrix(filters: list, seg, packed, ctx: ShardContext,
     admitted by the sighting rule, without the put, only where the view has
     no tombstone; on a view with one the row is built again each search.
 
-    Returns a host bool [Q, Dpad] when every row stayed host-side (the
-    pre-cache behavior, one implicit-free jnp.asarray commit at dispatch) or
-    a device [Q, Dpad] matrix when any row is on the device: the matrix one
-    build launch returned where it built every row of the batch in place (a
-    batch of multi-term searches of the first rung: nothing is stacked),
-    else the eager stack of the rows (host stragglers are device_put
-    explicitly; a build launch hands out its rows apart as well as
-    together, so nothing is ever sliced on the host). Where a row was
+    Returns the mask operand of the launch, which the tail turns into its
+    [Q, Dpad] matrix inside the program (scoring._mask_matrix); no row is
+    put for the launch and no program is dispatched here but a build launch
+    (a row the sighting rule promotes is put once, into the cache). A host bool
+    [Q, Dpad] when every row stayed host-side (one leaf of the launch's one
+    put: scoring._dense_args); the device matrix one build launch returned
+    where it built every row of the batch in place (a batch of multi-term
+    searches of the first rung); else a TUPLE of Q [Dpad] rows, resident
+    ones as they are and host stragglers as numpy rows that ride the
+    launch's put: the program stacks them, so the drainer dispatches no
+    eager stack (it was an expand_dims a row and a concatenate, each a
+    Python-dispatched device call, for every batch). Where a row was
     evaluated on the host, the whole assembly is noted on the dispatch clock
     as `shard.filter_mask` (inside its `dispatch.stage`), and every host
     row's bytes are counted as `search_serving.launch.mask_put_bytes`; where
     one was built on the chip, the expansion and the operands' put are noted
     as `shard.multiterm_expand` and counted under
-    `search_serving.launch.multiterm_*`. `n_rows` pads the matrix with
+    `search_serving.launch.multiterm_*`. `n_rows` pads the mask with
     rows that match nothing (a coalesced batch of unscored plans rides the
     pow-2 ladder of query counts, whether its rows are resident or not)."""
     if all(f is None for f in filters):
@@ -1730,18 +1734,10 @@ def _filter_mask_matrix(filters: list, seg, packed, ctx: ShardContext,
         out = np.stack(rows + [np.zeros(packed.doc_pad, dtype=bool)]
                        * (want - len(rows)))
     else:
-        import jax
-        import jax.numpy as jnp
-
         from ..ops.scoring import _false_row
 
         rows.extend([_false_row(packed.doc_pad)] * (want - len(rows)))
-        # compile_tag: the eager stack fuses cached device rows with fresh host
-        # masks for the filtered kernels — outermost scope wins, so launches from
-        # inside dense/sorted paths keep their own family.
-        with compile_tag("filtered"):
-            out = jnp.stack([row if not isinstance(row, np.ndarray)
-                             else jax.device_put(row) for row in rows])
+        out = tuple(rows)
     if host_bytes:
         from ..ops.scoring import LAUNCHES
 

@@ -5,7 +5,9 @@ attached (`jax.experimental.topologies`). These cases compile the launches
 `chip_smoke.py` produces at the r03 shape (100k documents, one force-merged segment:
 524,288 block rows, doc_pad 131,072; read from the rehearsal's compile manifest) for a
 `v5e:2x2` topology: the sparse launch at its largest bucket in both variants, the dense
-launch an overflow query takes (64 head rows, three searches, both variants), and the
+launch an overflow query takes (64 head rows, three searches, both variants; its
+operands one packed plane, and a filtered launch's mask four rows stacked in the
+program), and the
 mesh program of `chip_smoke.py --chips 4` on a
 four-device `Mesh`. A compile that passes is not a chip run; it says the chip's
 compiler accepts the program and how much device memory it plans.
@@ -63,6 +65,14 @@ def _shapes(sharding, *specs):
             for shape, dt in specs]
 
 
+def _dense_plane(M: int, Q: int, coord_w: int, scalars: int = 0):
+    """The dense launch's one operand plane (TermBatch.plane: tri | qplane |
+    head, then a tail's scalars) as a shape."""
+    from elasticsearch_tpu.ops.scoring import HEAD_SLOTS
+
+    return ((6 * M + Q * (2 + coord_w) + 5 * Q * HEAD_SLOTS + scalars,), "int32")
+
+
 def _sparse_args(sharding, Qb, TB, coord_w):
     return _shapes(
         sharding,
@@ -96,7 +106,7 @@ def test_dense_overflow_launch_compiles_for_v5e(one_chip, simple):
     [Q, doc_pad] f32 accumulator that the head terms' rows are added to, then the
     blocks that are left scattered from the lazily faulted f32 freqs plane."""
     from elasticsearch_tpu.common.jaxenv import compile_tag
-    from elasticsearch_tpu.ops.scoring import HEAD_SLOTS, _get_compiled
+    from elasticsearch_tpu.ops.scoring import _get_compiled
 
     Q, E, H = 3, 256, 64  # searches, (query, block) entries of the tails, head rows
     args = _shapes(
@@ -104,8 +114,8 @@ def test_dense_overflow_launch_compiles_for_v5e(one_chip, simple):
         ((ROWS, BLOCK), "int32"), ((ROWS, BLOCK), "float32"),  # docs, f32 freqs
         ((H, DOC_PAD), "uint8"),  # head rows, the tf plane's dtype
         ((DOC_PAD,), "bool"), ((1, DOC_PAD), "float32"),  # live, per-document table
-        # the launch's three operand planes (TermBatch.tri / .qplane / .head)
-        ((6, E), "int32"), ((Q, 2 + 8), "int32"), ((5, Q, HEAD_SLOTS), "int32"))
+        # the launch's one operand plane (TermBatch.plane), then M, a literal
+        _dense_plane(E, Q, 8)) + [E]
     fn = _get_compiled(Q, 16, DOC_PAD, simple)  # the launch site's own program
     with compile_tag("dense"):
         compiled = fn.lower(*args).compile()
@@ -116,6 +126,39 @@ def test_dense_overflow_launch_compiles_for_v5e(one_chip, simple):
     # the rows are added in a loop whose trip count is data: one program whatever
     # the number of head clauses
     assert "while" in compiled.as_text()
+
+
+def test_filtered_launch_of_a_packed_plane_and_four_mask_rows_compiles_for_v5e(
+        one_chip):
+    """`wiki.filtered`'s launch as the drainer hands it over: the operands one
+    flat int32 plane the program takes apart by static slices (tri | qplane |
+    head), and the mask a tuple of four resident [doc_pad] rows the tail
+    stacks inside the program. The chip's compiler must take the slices of a
+    plane whose sections are not tile-aligned and the stack of bool rows."""
+    from elasticsearch_tpu.common.jaxenv import compile_tag
+    from elasticsearch_tpu.ops.scoring import _get_agg_compiled
+
+    Q, E, H = 4, 256, 64
+    args = _shapes(
+        one_chip,
+        ((ROWS, BLOCK), "int32"), ((ROWS, BLOCK), "float32"),
+        ((H, DOC_PAD), "uint8"), ((DOC_PAD,), "bool"), ((1, DOC_PAD), "float32"),
+        _dense_plane(E, Q, 4)) + [E] + _shapes(
+        one_chip,
+        ((0, 5, DOC_PAD), "float32"), ((0, 0, DOC_PAD), "int32")) + [
+        (),  # no bucket aggregation: the filtered family's empty stack
+        tuple(_shapes(one_chip, *[((DOC_PAD,), "bool")] * Q))]  # the mask rows
+    fn = _get_agg_compiled(Q, 10, DOC_PAD, 0, True)
+    with compile_tag("filtered"):
+        lowered = fn.lower(*args)
+        compiled = lowered.compile()
+    assert lowered.as_text().split("@", 1)[1].split(" ", 1)[0] == \
+        "jit_estpu_scoring_aggs_filtered"
+    mem = compiled.memory_analysis()
+    # arguments: the two postings planes, the head rows, and Q mask rows of a
+    # byte a document beside a plane of a few kilobytes
+    assert mem.argument_size_in_bytes >= 2 * ROWS * BLOCK * 4 + Q * DOC_PAD
+    assert mem.temp_size_in_bytes < 1 << 30
 
 
 @pytest.mark.parametrize("n_plans,rung", [(1, 0), (4, 0), (1, -1)],
@@ -189,7 +232,7 @@ def test_unscored_launches_compile_for_v5e(one_chip, tail):
         _get_agg_compiled, _get_sorted_compiled, _unscored_abi)
 
     D = DOC_PAD_LOGS
-    head = (((D,), "bool"), ((1,), "float32"))  # live_parent, score [Q]
+    head = (((D,), "bool"), ((1,), "int32"))  # live_parent, the plane: score bits [Q]
     mask = ((1, D), "bool")
     no_aggs = (((0, 5, D), "float32"), ((0, 0, D), "int32"))  # folds, limbs
     if tail.startswith("sorted"):
@@ -232,14 +275,18 @@ def test_function_score_and_exact_sum_launches_compile_for_v5e(one_chip, tail, Q
     from elasticsearch_tpu.script import compile_script, script_vector_info
 
     D = DOC_PAD_GEO
-    head = (((D,), "bool"), ((Q,), "float32"))  # live_parent, score [Q]
+    live = ((D,), "bool")
+
+    def head(scalars=0):  # live_parent, the plane: score bits [Q], the tail's scalars
+        return live, ((Q + scalars,), "int32")
+
     mask = ((1, 1), "bool") if Q == 1 else ((Q, D), "bool")
-    row, gate, scalar = ((D,), "float32"), ((D,), "bool"), ((), "float32")
+    row, gate = ((D,), "float32"), ((D,), "bool")
     if tail == "fs_rows":
         fn = _get_fs_compiled("rows", Q, 10, D, _unscored_abi, "unscored",
                               bmode="multiply", use_min_score=False,
                               no_functions=False)
-        args = _shapes(one_chip, *head, mask, row, gate, scalar, scalar, scalar)
+        args = _shapes(one_chip, *head(3), mask, row, gate)
         family = "function_score"
     elif tail == "fs_script":
         script = compile_script(
@@ -251,15 +298,15 @@ def test_function_score_and_exact_sum_launches_compile_for_v5e(one_chip, tail, Q
                               script=script, used_fields=used, bmode="multiply",
                               use_min_score=False, has_filter=False,
                               has_weight=False)
-        args = _shapes(one_chip, *head, mask) + [tuple(_shapes(one_chip, row, row, row))] \
-            + _shapes(one_chip, gate, gate, gate, scalar, scalar, scalar, scalar)
+        args = _shapes(one_chip, *head(4), mask) + [tuple(_shapes(one_chip, row, row, row))] \
+            + _shapes(one_chip, gate, gate, gate)
         family = "function_score"
     else:  # size 0, terms on a keyword, a sum of a long under it
         fn = _get_agg_compiled(Q, 1, D, 1, False, _unscored_abi, "unscored")
         pairs = _shapes(one_chip, ((80_000,), "int32"), ((80_000,), "int32"),
                         ((256,), "int32"))
         sub = tuple(_shapes(one_chip, ((1, 5, D), "float32"), ((1, 3, D), "int32")))
-        args = _shapes(one_chip, *head, ((0, 5, D), "float32"), ((0, 0, D), "int32")) \
+        args = _shapes(one_chip, *head(), ((0, 5, D), "float32"), ((0, 0, D), "int32")) \
             + [((*pairs, sub),)] + _shapes(one_chip, mask)
         family = "aggs"
     with compile_tag(family):
@@ -279,13 +326,14 @@ def test_scored_sort_launch_compiles_for_v5e(one_chip, descending):
     """A one-term match sorted on a date: the dense launch with the sort tail
     over a resident key row (values, or exact ranks: float32 either way)."""
     from elasticsearch_tpu.common.jaxenv import compile_tag
-    from elasticsearch_tpu.ops.scoring import HEAD_SLOTS, _get_sorted_compiled
+    from elasticsearch_tpu.ops.scoring import _get_sorted_compiled
 
     args = _shapes(
         one_chip,
         ((ROWS, BLOCK), "int32"), ((ROWS, BLOCK), "float32"),
         ((64, DOC_PAD), "uint8"), ((DOC_PAD,), "bool"), ((1, DOC_PAD), "float32"),
-        ((6, 256), "int32"), ((1, 2 + 4), "int32"), ((5, 1, HEAD_SLOTS), "int32"),
+        _dense_plane(256, 1, 4)) + [256] + _shapes(
+        one_chip,
         ((1, 1), "bool"), ((DOC_PAD,), "float32"))  # the no-op mask, the key row
     fn = _get_sorted_compiled(1, 10, DOC_PAD, descending)
     with compile_tag("sorted"):

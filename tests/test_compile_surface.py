@@ -273,10 +273,11 @@ def test_every_launch_site_lowers_under_its_own_name():
     dense = (s((nb, 128), jnp.int32), s((nb, 128), jnp.float32),
              s((4, dpad), jnp.uint8),  # head rows
              s((dpad,), jnp.bool_), s((f, dpad), jnp.float32),  # doc table
-             # the launch's three operand planes (TermBatch.tri / .qplane / .head)
-             s((6, m), jnp.int32), s((q, 2 + 2), jnp.int32),
-             s((5, q, scoring.HEAD_SLOTS), jnp.int32))
-    scalar = s((), jnp.float32)
+             # the launch's one operand plane (TermBatch.plane: tri | qplane |
+             # head), then M, a literal
+             s((6 * m + q * (2 + 2) + 5 * q * scoring.HEAD_SLOTS,), jnp.int32),
+             m)
+    with_scalars = (*dense[:5], s((dense[5].shape[0] + 3,), jnp.int32), m)
     sparse = (s((nb, 128), jnp.int32), s((nb, 128), jnp.uint8),
               s((nb, 128), jnp.uint8), s((f, 256), jnp.float32),
               s((f,), jnp.int32),
@@ -291,8 +292,9 @@ def test_every_launch_site_lowers_under_its_own_name():
         (scoring._get_compiled(q, 10, dpad, False), dense),
         (scoring._get_fs_compiled("rows", q, 10, dpad, bmode="multiply",
                                   use_min_score=False, no_functions=False),
-         dense + (s((1, 1), jnp.bool_), s((dpad,), jnp.float32),
-                  s((dpad,), jnp.bool_), scalar, scalar, scalar)),
+         # max_boost, boost and min_score ride the plane's end
+         with_scalars + (s((1, 1), jnp.bool_), s((dpad,), jnp.float32),
+                         s((dpad,), jnp.bool_))),
         (scoring._get_sorted_compiled(q, 10, dpad, False),
          dense + (s((1, 1), jnp.bool_), s((dpad,), jnp.float32))),
         (scoring._get_agg_compiled(q, 10, dpad, 0), dense + no_aggs),

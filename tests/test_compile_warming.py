@@ -245,10 +245,10 @@ class TestRegistryWarm:
         path = tmp_path / MANIFEST_NAME
         REGISTRY.save_manifest(str(path))
         payload = _json.loads(path.read_text())
-        assert payload["version"] == 4 and len(payload["specs"]) == 1
+        assert payload["version"] == 5 and len(payload["specs"]) == 1
         REGISTRY.reset()
         assert REGISTRY.load_manifest(str(path)) == 1
-        payload["version"] = 3  # before the phrase launch took a list of rows
+        payload["version"] = 4  # before the dense launches took one packed plane
         path.write_text(_json.dumps(payload))
         REGISTRY.reset()
         LADDERS.reset()

@@ -663,7 +663,8 @@ def test_one_launchs_matrix_is_the_mask_matrix_itself(shard, monkeypatch):
     """Where one build launch built every row of the batch in place, the
     matrix it returned IS the mask matrix (nothing stacked); a row from
     elsewhere, an empty expansion or a second launch, and the rows are
-    stacked as device rows always were."""
+    handed over as a tuple of device rows, which the launch's program
+    stacks (scoring._mask_matrix): nothing is stacked on the drainer."""
     from elasticsearch_tpu.search import execute
 
     ctx, _docs_ = shard
@@ -685,8 +686,10 @@ def test_one_launchs_matrix_is_the_mask_matrix_itself(shard, monkeypatch):
         for q, f in enumerate(filters):
             want[q, : seg.doc_count] = True if f is None else f.evaluate(seg, ctx)
         want &= np.asarray(packed.live_parent)  # the plane holds the live alone
+        if isinstance(out, tuple):
+            assert all(row.shape == (packed.doc_pad,) for row in out)
         got = np.asarray(out) & np.asarray(packed.live_parent)
-        assert out.shape == want.shape and (got == want).all()
+        assert got.shape == want.shape and (got == want).all()
         return out
 
     three = [PrefixFilter("body", p, cached=False) for p in ("w21", "w22", "w23")]

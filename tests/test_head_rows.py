@@ -256,7 +256,7 @@ def test_counters_count_what_the_launch_did(shards):
     execute_flat_batch(plans, ctx, 10)
     d = {key: v - before[key] for key, v in scoring.LAUNCHES.snapshot().items()}
     assert (d["launches_dense"], d["launches_sparse"]) == (1, 0)
-    assert d["operand_puts"] == 3
+    assert d["operand_puts"] == 1  # the packed plane: tri | qplane | head
     assert d["head_slots"] == 3
     assert d["blocks_as_rows"] == sum(b1 - b0 for (_q, b0, b1, *_r) in rows)
     assert d["blocks_real"] == 2  # rare, w3: a block each

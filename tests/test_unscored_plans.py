@@ -301,7 +301,8 @@ def test_rank_rows_are_resident_and_on_the_ledger(ctx):
     before = LAUNCHES.snapshot()["operand_puts"]
     _both(ctx, {"query": {"match_all": {}}, "size": 5,
                 "sort": [{"ts": "desc"}]}, "device_sort")
-    # a warmed sorted launch of an unscored plan puts its constant alone
+    # a warmed sorted launch of an unscored plan puts ONE leaf: its plane,
+    # the constant's bits (the key row and the no-op mask are resident)
     assert LAUNCHES.snapshot()["operand_puts"] - before == \
         len(ctx.searcher.segments)
     for seg in ctx.searcher.segments:
