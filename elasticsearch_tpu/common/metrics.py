@@ -118,10 +118,6 @@ class MeterMetric:
         return self._counter.count
 
     @property
-    def one_minute_rate(self) -> float:
-        return self._ewma.rate
-
-    @property
     def mean_rate(self) -> float:
         elapsed = time.monotonic() - self._start
         return self._counter.count / elapsed if elapsed > 0 else 0.0
@@ -242,27 +238,3 @@ class HistogramMetric:
             out.append((bound, cum))
         out.append((float("inf"), total))
         return out, total, vsum
-
-
-class StopWatch:
-    """Simple phase timer (ref: common/StopWatch.java) used by benches."""
-
-    def __init__(self, name: str = ""):
-        self.name = name
-        self.tasks: list[tuple[str, float]] = []
-        self._current: str | None = None
-        self._start = 0.0
-
-    def start(self, task: str = ""):
-        self._current = task
-        self._start = time.monotonic()
-        return self
-
-    def stop(self):
-        assert self._current is not None
-        self.tasks.append((self._current, time.monotonic() - self._start))
-        self._current = None
-        return self
-
-    def total_time(self) -> float:
-        return sum(t for _, t in self.tasks)

@@ -91,22 +91,6 @@ def jnp_byte315_to_float(b):
         return jnp.take(jnp_norm_table(), jnp.asarray(b).astype(jnp.int32))
 
 
-def jnp_doclen_table():
-    """Device-side BM25 doc-length table: jnp float32 [256], the device twin of
-    decode_norm_doclen over all bytes (dl = 1/f², byte 0 → length 0)."""
-    import jax.numpy as jnp
-
-    from .jaxenv import compile_tag
-
-    with compile_tag("pack"):
-        return jnp.asarray(decode_norm_doclen(np.arange(256, dtype=np.uint8)))
-
-
-def decode_norm_tfidf(norm_byte: np.ndarray) -> np.ndarray:
-    """TF-IDF: decoded norm multiplies the score directly."""
-    return NORM_TABLE[np.asarray(norm_byte, dtype=np.uint8)]
-
-
 def decode_norm_doclen(norm_byte: np.ndarray) -> np.ndarray:
     """BM25: decoded value f represents boost/sqrt(len); doc length = 1/f² (quantized).
     Bytes decoding to 0 (empty field) get length 0."""

@@ -833,7 +833,7 @@ def score_fs_rows_batch_async(packed: PackedSegment, batch: TermBatch, k: int,
                               no_functions: bool):
     """Dense launch with host-combined function rows; returns device (scores,
     docs, total) [Q, k]/[Q] without syncing (the caller pulls:
-    execute._execute_flat_fs). `fmask`: optional bool [Q, Dpad] match gates of
+    execute.launch_flat_fs). `fmask`: optional bool [Q, Dpad] match gates of
     filtered or unscored sub queries."""
     params = (batch.n_queries, min(k, packed.doc_pad), packed.doc_pad,
               bmode, min_score is not None, no_functions)

@@ -170,9 +170,6 @@ class ShardedIndex:
     agg_stacks: dict = dc_field(default_factory=dict)  # fields-tuple -> device
     searchers: list = dc_field(default_factory=list)  # for lazy agg-row builds
 
-    def global_max_doc(self) -> int:
-        return int(self.max_doc.sum())
-
     def resident_postings_bytes(self) -> int:
         """Device-resident postings-plane bytes across all shards (docs i32 +
         quantized tf) — surfaced by mesh_serving's repack log/stats so the

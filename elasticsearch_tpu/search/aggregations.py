@@ -85,14 +85,6 @@ def parse_aggs(spec: dict) -> dict[str, Agg]:
     return out
 
 
-def run_aggs(aggs: dict[str, Agg], seg_masks: list, ctx) -> list[dict]:
-    """Collect partials per segment: seg_masks = [(seg, mask, scores)]."""
-    partials = []
-    for seg, mask, scores in seg_masks:
-        partials.append({n: a.collect(seg, ctx, mask, scores) for n, a in aggs.items()})
-    return partials
-
-
 def reduce_aggs(aggs: dict[str, Agg], partial_list: list[dict]) -> dict:
     """Merge partials (across segments AND shards — same operation) + finalize."""
     return {

@@ -27,10 +27,10 @@ kind of search: plain, function_score, filtered, aggregated and sorted plans
 on one view share a collect (seven operations behind 8 clients would
 otherwise collect batches of one and pay a linger each), an aggregated or
 sorted plan carries its execute.FlatTail on its item, and
-execute.execute_flat_batch launches each group of the batch once a segment
-(execute._flat_groups); the filtered, aggregated and sorted groups of a
-batch are pulled together, in one device_get at the dispatch's end
-(execute._run_flat_groups). Only what a shared batch cannot serve launches on
+execute.dispatch_flat_batch launches each group of the batch once a segment
+(execute._flat_groups, by its row of execute.GROUP_KINDS); every group but
+the plain one is pulled with the others, in one device_get at the dispatch's
+end (execute._run_flat_groups). Only what a shared batch cannot serve launches on
 its request thread: profiled and DFS requests and a node without a batcher
 (service._execute_flat_single). `stats()["kinds"]` tells the launches apart.
 
@@ -81,7 +81,8 @@ from ..ops.device_index import _ladder_bucket
 
 _K_MIN = 16  # smallest k bucket (top-10 pages and top-16 share executables)
 # the kinds of launch /_nodes/stats tells apart under search.batcher.kinds:
-# execute._flat_groups' and the mesh family's
+# execute.GROUP_KINDS' keys (tests/test_launch_seam.py holds this to them) and
+# the mesh family's
 _KINDS = ("plain", "function_score", "filtered", "phrase", "aggs", "sorted",
           "mesh")
 

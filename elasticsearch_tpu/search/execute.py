@@ -34,6 +34,7 @@ import re
 import time
 from dataclasses import dataclass, field as dc_field, replace as dc_replace
 from functools import partial
+from typing import NamedTuple
 
 import numpy as np
 
@@ -736,18 +737,6 @@ def finalize_phrase(plan: FlatPlan, ctx: ShardContext):
             TFN_TFIDF, cache)
 
 
-def _is_plain(p: FlatPlan) -> bool:
-    """A plan the sparse candidate path serves: scoring clauses and no tail."""
-    return p.fs is None and p.filt is None and p.const is None \
-        and p.phrase is None
-
-
-def _all_plain(plans: list[FlatPlan], tails) -> bool:
-    """Every plan rides the sparse path and asks for TopDocs alone: the batch
-    is one plain launch and skips the grouping (_flat_groups)."""
-    return not (tails and any(tails)) and all(_is_plain(p) for p in plans)
-
-
 @dataclass(frozen=True)
 class FlatTail:
     """What an aggregated or a sorted search asks of its dense launch besides
@@ -788,96 +777,91 @@ def sort_tail(spec) -> FlatTail:
     return FlatTail("sorted", _sort_row_key(spec), spec=spec)
 
 
+def plan_kind(plan: FlatPlan, tail: FlatTail | None = None) -> str:
+    """The kind of group a plan launches in, a key of GROUP_KINDS: its tail's
+    (aggs, sorted) where it has one, else function_score, filtered (a filter,
+    or no scoring clause at all), phrase or plain."""
+    if tail is not None:
+        return tail.kind
+    if plan.fs is not None:
+        return "function_score"
+    if plan.filt is not None or plan.const is not None:
+        return "filtered"
+    if plan.phrase is not None:
+        return "phrase"
+    return "plain"
+
+
+# the group of the plans the sparse candidate path serves: scoring clauses
+# and neither a tail nor a filter
+_PLAIN_GROUP = ("plain", None, False)
+
+
 def _flat_groups(plans: list[FlatPlan], tails=None) -> dict:
     """group -> positions in `plans`, in order of first sighting: the plans
-    one launch a segment answers together. A group's first element names its
-    kind (`search.batcher.kinds` in /_nodes/stats): plain, function_score
-    (by spec; scored and unscored apart), filtered (scored and unscored
-    apart), phrase, and by tail aggs and sorted (by the tail's key; scored
-    and unscored apart, as _segment_batches does not mix them)."""
+    one launch a segment answers together. A group is (kind, key, unscored):
+    its kind (plan_kind; `search.batcher.kinds` in /_nodes/stats), what the
+    launch's operands and compiled shape depend on beside it (a
+    function_score's spec, a tail's key) and whether its plans have no
+    scoring clause (scored and unscored plans launch apart, as
+    _segment_batches does not mix them)."""
     groups: dict = {}
     for i, p in enumerate(plans):
         tail = tails[i] if tails else None
-        if tail is not None:
-            group = (tail.kind, tail.key, p.const is not None)
-        elif p.fs is not None:
-            group = ("function_score", *_fs_group_key(p.fs),
-                     p.const is not None)
-        elif p.filt is not None or p.const is not None:
-            group = ("filtered", p.const is not None)
-        elif p.phrase is not None:
-            group = ("phrase",)
-        else:
-            group = ("plain",)
-        groups.setdefault(group, []).append(i)
+        key = tail.key if tail is not None else \
+            _fs_group_key(p.fs) if p.fs is not None else None
+        groups.setdefault((plan_kind(p, tail), key, p.const is not None),
+                          []).append(i)
     return groups
 
 
 def execute_flat_batch(plans: list[FlatPlan], ctx: ShardContext, k: int,
                        tails: list | None = None) -> list:
-    """Run a batch of flat plans through the device kernels, a launch a
-    segment for each group of _flat_groups. Plain plans ride the sparse
-    candidate-centric path; function_score plans are grouped by spec and
-    ride the dense kernel with the function tail fused in (_execute_flat_fs);
-    filtered plans ride the dense kernel with per-query mask rows, and plans
-    with no scoring clause the same tail behind the unscored launch ABI
-    (launch_flat_filtered, a launch for each kind); exact phrases ride the
-    phrase program over the positions plane (launch_flat_phrase). `tails` (a FlatTail or
-    None a plan) sends a plan to the fused aggregation or the field-sort
-    program with the plans that share its key (launch_flat_aggs,
-    launch_flat_sorted), and its result is that executor's: everything else
-    is answered with TopDocs."""
-    if _all_plain(plans, tails):
-        return _execute_flat_plain(plans, ctx, k)
-    return _run_flat_groups(plans, ctx, k, tails, _flat_groups(plans, tails))
-
-
-# the groups that launch without a pull (launch_flat_filtered / _phrase /
-# _aggs / _sorted) and share the batch's one device_get
-_ONE_PULL_KINDS = ("filtered", "phrase", "aggs", "sorted")
+    """Run a batch of flat plans through the device kernels on the calling
+    thread, a launch a segment for each group of _flat_groups: the dispatch
+    half and its merge, one after the other (_dispatch_flat says which
+    protocol carries which group). A result a plan: TopDocs, or for a plan
+    with a FlatTail (`tails`: one or None a plan) what its kind's launcher
+    hands back (launch_flat_aggs, launch_flat_sorted; None where the host
+    serves)."""
+    return _dispatch_flat(plans, ctx, k, tails).merge()
 
 
 def _run_flat_groups(plans: list[FlatPlan], ctx: ShardContext, k: int,
                      tails, groups: dict) -> list:
-    """A result a plan, group by group. The plain and function_score groups
-    run first and pull as they always did. The filtered, aggregated and
-    sorted groups are then launched one after the other and pulled TOGETHER,
-    in one device_get for the batch: a group's program runs while the next
-    group is staged, and the drainer gives the GIL up once a batch and not
-    once a group (a mix of operations holds two or three groups a batch).
-    They launch last because the device runs its programs in order: a group
-    that pulls at once must not wait behind one that does not."""
+    """A result a plan, group by group, by the two protocols a launch has.
+
+    The plain group runs first and whole (_execute_flat_plain). It is the one
+    launch whose pull can happen off the dispatch half (_PendingFlat: the
+    overlap a batch of plain plans alone runs on), and its merge carries the
+    scratch pool's release and the DEVICE_PULL / DEVICE_FAULTS seams. First,
+    because the device runs its programs in order: a group that pulls at
+    once must not wait behind one that does not.
+
+    Every other group launches by its row of GROUP_KINDS with NO pull, and
+    all of them are pulled TOGETHER, in one device_get for the batch: a
+    group's program runs while the next group is staged, and the drainer
+    gives the GIL up once a batch and not once a group (a mix of operations
+    holds two or three groups a batch)."""
     from ..ops.scoring import _pull
 
     out: list = [None] * len(plans)
-    launched = []  # (positions, device outputs, finish) a group of one pull
-    for group, idxs in sorted(groups.items(),
-                              key=lambda g: g[0][0] in _ONE_PULL_KINDS):  # stable
-        kind = group[0]
-        if kind in _ONE_PULL_KINDS:
-            tail = tails[idxs[0]] if tails else None
-            # the dense accumulator is O(Q·doc_pad): bound the launch width
-            step = _FS_CHUNK if kind == "filtered" else _GROUP_WIDTH
-            for start in range(0, len(idxs), step):
-                chunk = idxs[start: start + step]
-                members = [plans[i] for i in chunk]
-                if kind == "filtered":
-                    handle = launch_flat_filtered(members, ctx, k)
-                elif kind == "phrase":
-                    handle = launch_flat_phrase(members, ctx, k)
-                elif kind == "aggs":
-                    handle = launch_flat_aggs(members, ctx, k, tail.fields,
-                                              tail.bucket_aggs)
-                else:
-                    handle = launch_flat_sorted(members, ctx, k, tail.spec)
-                if handle is not None:  # None: the host serves every member
-                    launched.append((chunk, *handle))
-            continue
-        members = [plans[i] for i in idxs]
-        res = _execute_flat_plain(members, ctx, k) if kind == "plain" \
-            else _execute_flat_fs(members, ctx, k)
-        for i, r in zip(idxs, res):
+    idxs = groups.get(_PLAIN_GROUP)
+    if idxs:
+        for i, r in zip(idxs, _execute_flat_plain([plans[i] for i in idxs],
+                                                  ctx, k)):
             out[i] = r
+    launched = []  # (positions, device outputs, finish) a launch
+    for group, idxs in groups.items():
+        kind = GROUP_KINDS[group[0]]
+        if kind.launch is None:
+            continue
+        tail = tails[idxs[0]] if tails else None
+        for start in range(0, len(idxs), kind.width):
+            chunk = idxs[start: start + kind.width]
+            handle = kind.launch([plans[i] for i in chunk], ctx, k, tail)
+            if handle is not None:  # None: the host serves every member
+                launched.append((chunk, *handle))
     if launched:
         pulled = _pull([refs for _chunk, refs, _finish in launched])
         for (chunk, _refs, finish), host in zip(launched, pulled):
@@ -976,44 +960,52 @@ class _PendingFlat:
 
 
 class _PendingDone:
-    """Already-merged results behind the pending interface — every family but
-    the plain one (function_score, filtered, aggregated, sorted) executes
-    synchronously inside the dispatch half (their kernels pull there: a
-    function_score group per launch, the filtered, aggregated and sorted
-    groups of a batch in one device_get, _run_flat_groups). `clock` holds
-    that dispatch's stage / launch / device_pull intervals
-    (tracing.DispatchClock): the pull happened INSIDE the dispatch, so the
-    batcher records it there, not under its merge. `kinds` is what the batch
-    launched, (kind, members) a group of _flat_groups, for the batcher's
-    per-kind counters."""
+    """Already-merged results behind the pending interface: a batch that is
+    not plain plans alone runs whole inside the dispatch half
+    (_run_flat_groups: its one-pull groups are pulled there, in one
+    device_get). `clock` holds that dispatch's stage / launch / device_pull
+    intervals (tracing.DispatchClock): the pull happened INSIDE the dispatch,
+    so the batcher records it there, not under its merge. `kinds` is what the
+    batch launched, (kind, members) a group of _flat_groups, for the
+    batcher's per-kind counters."""
 
     __slots__ = ("results", "clock", "kinds")
 
-    def __init__(self, results: list, clock=None, kinds: tuple = ()):
+    def __init__(self, results: list, kinds: tuple = ()):
         self.results = results
-        self.clock = clock
+        self.clock = None
         self.kinds = kinds
 
     def merge(self) -> list:
         return self.results
 
 
+def _dispatch_flat(plans: list[FlatPlan], ctx: ShardContext, k: int,
+                   tails: list | None = None):
+    """A batch's device work behind a pending handle whose merge() yields a
+    result a plan: the ONE place that chooses the protocol. Plain plans alone
+    enqueue their launches without syncing (_PendingFlat: the pull is the
+    merge's); any other batch runs whole here, group by group
+    (_run_flat_groups), and so does a PROFILED plain one, whose per-request
+    sync sits between its dispatch and its merge (_execute_flat_plain)."""
+    groups = _flat_groups(plans, tails)
+    if groups.keys() == {_PLAIN_GROUP} and _profile.current() is None:
+        return _dispatch_flat_plain(plans, ctx, k)
+    return _PendingDone(
+        _run_flat_groups(plans, ctx, k, tails, groups),
+        tuple((group[0], len(idxs)) for group, idxs in groups.items()))
+
+
 def dispatch_flat_batch(plans: list[FlatPlan], ctx: ShardContext, k: int,
                         tails: list | None = None):
-    """Dispatch half of execute_flat_batch for the cross-request batcher:
-    returns a pending handle whose merge() yields a result a plan (TopDocs,
-    or what its FlatTail's executor returns). Plain plans enqueue device work
-    without syncing; batches carrying function_score, filtered, aggregated
-    or sorted plans run whole (synchronously) here, group by group."""
+    """Dispatch half of execute_flat_batch for the cross-request batcher: the
+    pending handle of _dispatch_flat with the dispatch's clock on it (its
+    stage / launch intervals, and the device_pull of a batch that ran
+    whole)."""
     with tracing.timing_dispatch() as clock:
-        if plans and _all_plain(plans, tails):
-            pending = _dispatch_flat_plain(plans, ctx, k)
-            pending.clock = clock
-            return pending
-        groups = _flat_groups(plans, tails)
-        return _PendingDone(
-            _run_flat_groups(plans, ctx, k, tails, groups), clock,
-            tuple((group[0], len(idxs)) for group, idxs in groups.items()))
+        pending = _dispatch_flat(plans, ctx, k, tails)
+    pending.clock = clock
+    return pending
 
 
 @contextlib.contextmanager
@@ -1206,9 +1198,8 @@ def _merge_flat_plain(pending: _PendingFlat) -> list[TopDocs]:
             docs[sub, kk:] = doc_pad
             tq[sub] = res.total_hits
         totals += tq
-        valid = (docs < min(doc_pad, seg.doc_count)) & np.isfinite(scores)
-        gdocs = np.where(valid, docs.astype(np.int64) + base, np.int64(2**62))
-        seg_hits.append((np.where(valid, scores, -np.inf), gdocs))
+        seg_hits.append(_segment_hits(scores, docs,
+                                      min(doc_pad, seg.doc_count), base))
     return _merge_seg_hits(seg_hits, totals, Q, k, breaker=pending.breaker)
 
 
@@ -1242,6 +1233,31 @@ def _execute_flat_plain(plans: list[FlatPlan], ctx: ShardContext, k: int) -> lis
     prof.phase_s("pull", pull_s)
     prof.phase_s("merge", max(t3 - t2 - pull_s, 0.0))
     return out
+
+
+def _segment_hits(scores, docs, n_docs: int, base: int):
+    """One segment's pulled top documents (scores float32 [Q, k], local docs
+    [Q, k]) as _merge_seg_hits takes them: a slot counts where its document
+    is one of the segment's `n_docs` and its score is finite, and holds the
+    global doc id; every other slot -inf and an id past every document."""
+    valid = (docs < n_docs) & np.isfinite(scores)
+    return (np.where(valid, scores, -np.inf),
+            np.where(valid, docs.astype(np.int64, copy=False) + base,
+                     np.int64(2**62)))
+
+
+def _merge_pulled(ctx: ShardContext, n_docs: list, pulled: list, Q: int,
+                  k: int) -> list[TopDocs]:
+    """TopDocs a plan from a dense group's pulled outputs, a segment each:
+    (scores, docs, totals, ...), whose rows past `Q` are the launch's padding
+    up its ladder; `n_docs` a segment, as _segment_hits takes it."""
+    totals = np.zeros(Q, dtype=np.int64)
+    seg_hits = []
+    for base, n, out in zip(ctx.searcher.bases, n_docs, pulled):
+        totals += out[2][:Q]
+        seg_hits.append(_segment_hits(out[0][:Q], out[1][:Q], n, base))
+    return _merge_seg_hits(seg_hits, totals, Q, k,
+                           breaker=ctx.breaker("request"))
 
 
 def _merge_seg_hits(seg_hits, totals, Q: int, k: int,
@@ -1402,9 +1418,6 @@ def _segment_batches(plans: list[FlatPlan], ctx: ShardContext):
     return batch_for
 
 
-_FS_CHUNK = 256  # dense accumulator is O(Q·doc_pad) — bound the launch width
-
-
 def _fs_function_rows(fsq, seg, ctx: ShardContext, doc_pad: int):
     """The host's side of a "rows" launch on one segment: the spec's doc-only
     function values, score_mode-combined (functions.combined_doc_rows —
@@ -1509,40 +1522,45 @@ def _fs_segment_rows(key, evaluate, seg, ctx: ShardContext, doc_pad: int):
     return host if rows is None else rows
 
 
-def _execute_flat_fs(plans: list[FlatPlan], ctx: ShardContext, k: int) -> list[TopDocs]:
-    """Execute a group of function_score plans sharing ONE spec (see
-    _fs_group_key; all scored or all unscored: _flat_groups) through the dense
-    kernel with the function tail fused in, behind the launch ABI of their
-    kind (a scoring.TermBatch, or a ConstBatch for sub queries with no scoring
-    clause, whose `_score` is their constant).
+def launch_flat_fs(plans: list[FlatPlan], ctx: ShardContext, k: int,
+                    tail=None):
+    """function_score plans (at most _GROUP_WIDTH) sharing ONE spec (see
+    _fs_group_key; all scored or all unscored: _flat_groups) through the
+    dense kernel with the function tail fused in, behind the launch ABI of
+    their kind (a scoring.TermBatch, or a ConstBatch for sub queries with no
+    scoring clause, whose `_score` is their constant). One launch a segment,
+    its query count up the ladder the aggregated and sorted groups ride
+    (_group_operands: a spec has two programs, not one a count), and NO
+    pull: returns (device outputs a segment, finish); `finish(pulled)` takes
+    the outputs on the host (the batch's one device_get: _run_flat_groups)
+    and returns TopDocs a plan.
 
     "rows": the spec's doc-only function values are host-combined once per
     segment (_fs_function_rows) and shipped as a row. "script": the single
     _score-reading script is traced into the kernel; queries flagged bad
-    (missing columns / non-finite values on parent docs) rerun on the host so
-    error semantics are preserved. A segment's rows are looked up in the
-    device row store before they are evaluated (_fs_segment_rows): a spec
-    that recurs finds them resident from its second sighting on, and the
-    launch takes the resident arrays (same shapes and dtypes: the same
-    compiled program). The lookup-or-evaluate is noted on the dispatch clock
-    as `shard.fs_rows` (inside its `dispatch.stage`); what the host
-    evaluated is counted as `search_serving.launch.fs_row_put_bytes`.
+    (missing columns / non-finite values on parent docs) rerun on the host
+    in `finish`, so error semantics are preserved. A segment's rows are
+    looked up in the device row store before they are evaluated
+    (_fs_segment_rows): a spec that recurs finds them resident from its
+    second sighting on, and the launch takes the resident arrays (same
+    shapes and dtypes: the same compiled program). The lookup-or-evaluate is
+    noted on the dispatch clock as `shard.fs_rows` (inside its
+    `dispatch.stage`); what the host evaluated is counted as
+    `search_serving.launch.fs_row_put_bytes`.
 
-    The group launches _GROUP_WIDTH plans at a time, each launch's query
-    count up the ladder the aggregated and sorted groups ride (_group_width:
-    a spec has two programs, not one a count), a segment's rows evaluated
-    once for all of them; the launches of a segment are pulled together."""
+    A ScriptError raised while a segment's rows are evaluated sends the
+    whole group to the host scorer, which is authoritative for error
+    semantics: the handle then has no device outputs and its `finish` runs
+    every plan there."""
     from ..common.errors import ScriptError
     from ..ops.device_index import packed_for
-    from ..ops.scoring import (_pull, _put_operands,
-                               score_fs_rows_batch_async,
+    from ..ops.scoring import (score_fs_rows_batch_async,
                                score_fs_script_batch_async)
     from ..script import compile_script, script_vector_info
 
     fsq = plans[0].fs
     kind = plans[0].fs_kind  # classified once at lower time
     Q = len(plans)
-    chunks = [plans[i: i + _GROUP_WIDTH] for i in range(0, Q, _GROUP_WIDTH)]
 
     script = used_fields = sf = None
     if kind == "script":
@@ -1553,65 +1571,52 @@ def _execute_flat_fs(plans: list[FlatPlan], ctx: ShardContext, k: int) -> list[T
         else partial(_fs_script_rows, sf, used_fields)
     rows_key = _fs_rows_key(fsq, kind, used_fields, ctx)
 
-    host_idx: set[int] = set()
-    totals = np.zeros(Q, dtype=np.int64)
-    seg_hits = []
+    def on_host(_pulled=None) -> list[TopDocs]:
+        return [_host_search(ctx, p.fs, k) for p in plans]
+
+    launched = []
+    n_docs = []
     prof = _profile.current()
     try:
-        operands = [_group_operands(chunk, ctx) for chunk in chunks]
-        for seg, base in zip(ctx.searcher.segments, ctx.searcher.bases):
-            t_seg = time.monotonic()
+        operands = _group_operands(plans, ctx)
+        for seg in ctx.searcher.segments:
+            t_seg = time.monotonic() if prof is not None else 0.0
             packed = packed_for(seg, breaker=ctx.breaker("fielddata"),
                                 owner=ctx.index_name)
-            D, doc_pad = seg.doc_count, packed.doc_pad
             t_rows = time.monotonic()
-            rows = _fs_segment_rows(rows_key, evaluate, seg, ctx, doc_pad)
+            rows = _fs_segment_rows(rows_key, evaluate, seg, ctx,
+                                    packed.doc_pad)
             tracing.note("shard.fs_rows", t_rows)
-            if len(chunks) > 1:
-                rows = _put_operands(*rows)  # once for the segment's launches
-            launched = []
-            for ops in operands:
-                batch, fmask = ops(seg, packed)
-                with compile_tag("function_score"):
-                    if kind == "rows":
-                        launched.append(score_fs_rows_batch_async(
-                            packed, batch, k, fmask, *rows, fsq.max_boost,
-                            fsq.boost, fsq.min_score, fsq.boost_mode,
-                            no_functions=not fsq.functions))
-                    else:
-                        launched.append(score_fs_script_batch_async(
-                            packed, batch, k, fmask, script, used_fields,
-                            *rows, sf.weight, fsq.max_boost, fsq.boost,
-                            fsq.min_score, fsq.boost_mode,
-                            has_filter=sf.filter is not None))
-            pulled = _pull(launched)
-            scores, docs, tq = (
-                np.concatenate([out[i][:len(chunk)]
-                                for out, chunk in zip(pulled, chunks)])
-                for i in range(3))
-            if kind == "script":
-                bad = np.concatenate([out[3][:len(chunk)]
-                                      for out, chunk in zip(pulled, chunks)])
-                host_idx.update(int(qi) for qi in np.nonzero(bad)[0])
-            totals += tq
-            valid = (docs < min(doc_pad, D)) & np.isfinite(scores)
-            gdocs = np.where(valid, docs.astype(np.int64) + base, np.int64(2**62))
-            seg_hits.append((np.where(valid, scores, -np.inf), gdocs))
+            batch, fmask = operands(seg, packed)
+            with compile_tag("function_score"):
+                if kind == "rows":
+                    launched.append(score_fs_rows_batch_async(
+                        packed, batch, k, fmask, *rows, fsq.max_boost,
+                        fsq.boost, fsq.min_score, fsq.boost_mode,
+                        no_functions=not fsq.functions))
+                else:
+                    launched.append(score_fs_script_batch_async(
+                        packed, batch, k, fmask, script, used_fields,
+                        *rows, sf.weight, fsq.max_boost, fsq.boost,
+                        fsq.min_score, fsq.boost_mode,
+                        has_filter=sf.filter is not None))
+            n_docs.append(min(packed.doc_pad, seg.doc_count))
             _prof_dense_segment(prof, seg, packed, batch,
-                                "dense_function_score", t_seg)
+                                "dense_function_score", t_seg, launched[-1])
     except ScriptError:
-        # a host-side per-doc evaluation raised while building rows — the host
-        # path is authoritative for error semantics; rerun the whole group there
-        host_idx = set(range(Q))
-        seg_hits = []
+        return (), on_host
+    if not launched:
+        return (), on_host  # a view with no segment
 
-    merged = _merge_seg_hits(seg_hits, totals, Q, k,
-                             breaker=ctx.breaker("request"))
-    return [
-        _host_search(ctx, plans[qi].fs, k) if (qi in host_idx or not seg_hits)
-        else merged[qi]
-        for qi in range(Q)
-    ]
+    def finish(pulled: list) -> list[TopDocs]:
+        merged = _merge_pulled(ctx, n_docs, pulled, Q, k)
+        if kind == "script":  # the members a segment's program flagged bad
+            for qi in sorted({int(qi) for out in pulled
+                              for qi in np.nonzero(out[3][:Q])[0]}):
+                merged[qi] = _host_search(ctx, plans[qi].fs, k)
+        return merged
+
+    return launched, finish
 
 
 def _filter_mask_matrix(filters: list, seg, packed, ctx: ShardContext,
@@ -1746,7 +1751,8 @@ def _filter_mask_matrix(filters: list, seg, packed, ctx: ShardContext,
     return out
 
 
-def launch_flat_filtered(plans: list[FlatPlan], ctx: ShardContext, k: int):
+def launch_flat_filtered(plans: list[FlatPlan], ctx: ShardContext, k: int,
+                         tail=None):
     """Filtered plans: per-query filter masks (host-evaluated via the per-segment
     filter cache — the same masks the host scorer uses) gate matching inside the
     dense kernel. Scores/weights are untouched, so sub-query scoring parity is
@@ -1778,21 +1784,7 @@ def launch_flat_filtered(plans: list[FlatPlan], ctx: ShardContext, k: int):
         _prof_dense_segment(prof, seg, packed, batch, "dense_filtered",
                             t_seg, launched[-1])
 
-    def finish(pulled: list) -> list[TopDocs]:
-        totals = np.zeros(Q, dtype=np.int64)
-        seg_hits = []
-        for base, n, (scores, docs, tq) in zip(ctx.searcher.bases, n_docs,
-                                               pulled):
-            scores, docs = scores[:Q], docs[:Q]
-            totals += tq[:Q]
-            valid = (docs < n) & np.isfinite(scores)
-            gdocs = np.where(valid, docs.astype(np.int64) + base,
-                             np.int64(2**62))
-            seg_hits.append((np.where(valid, scores, -np.inf), gdocs))
-        return _merge_seg_hits(seg_hits, totals, Q, k,
-                               breaker=ctx.breaker("request"))
-
-    return launched, finish
+    return launched, partial(_merge_pulled, ctx, n_docs, Q=Q, k=k)
 
 
 def _phrase_launches(by_rung: dict):
@@ -1812,7 +1804,8 @@ def _phrase_launches(by_rung: dict):
                 yield key, [member]
 
 
-def launch_flat_phrase(plans: list[FlatPlan], ctx: ShardContext, k: int):
+def launch_flat_phrase(plans: list[FlatPlan], ctx: ShardContext, k: int,
+                       tail=None):
     """Phrase plans (at most _GROUP_WIDTH) over every segment's positions
     plane, faulted in by the first phrase a segment's field meets
     (device_index.ensure_positions). A phrase can only occur in the documents
@@ -1905,12 +1898,8 @@ def launch_flat_phrase(plans: list[FlatPlan], ctx: ShardContext, k: int):
             scores[si, qis, : s.shape[1]] = s[:n]
             docs[si, qis, : s.shape[1]] = d[:n]
             totals[qis] += tq[:n]
-        seg_hits = []
-        for si, base in enumerate(ctx.searcher.bases):
-            valid = (docs[si] < n_docs[si]) & np.isfinite(scores[si])
-            seg_hits.append((np.where(valid, scores[si], -np.inf),
-                             np.where(valid, docs[si] + base,
-                                      np.int64(2**62))))
+        seg_hits = [_segment_hits(scores[si], docs[si], n_docs[si], base)
+                    for si, base in enumerate(ctx.searcher.bases)]
         return _merge_seg_hits(seg_hits, totals, Q, k,
                                breaker=ctx.breaker("request"))
 
@@ -1954,8 +1943,8 @@ def _sort_key_row(spec, seg, packed, breaker=None):
 _NO_MATCH_PLAN = FlatPlan([], msm=1, n_must=0, coord_enabled=False, boost=1.0)
 
 
-# the most aggregated or sorted plans one launch takes (_run_flat_groups
-# launches a larger group four at a time)
+# the most function_score, phrase, aggregated or sorted plans one launch
+# takes (_run_flat_groups launches a larger group four at a time)
 _GROUP_WIDTH = 4
 
 
@@ -2005,9 +1994,10 @@ def _group_operands(plans: list[FlatPlan], ctx: ShardContext):
 
 
 def launch_flat_sorted(plans: list[FlatPlan], ctx: ShardContext, k: int,
-                       spec):
-    """Field-sorted dense launches of a group of plans under ONE sort (all
-    scored or all unscored: sort_tail's key and _flat_groups), one launch a
+                       tail: FlatTail):
+    """Field-sorted dense launches of a group of plans under ONE sort
+    (`tail.spec`, the leader's; all scored or all unscored: sort_tail's key
+    and _flat_groups), one launch a
     segment and NO pull: returns (device outputs a segment, finish), or None
     when any segment's column refuses device keys (_sort_key_row: the host
     sorts every plan). `finish(pulled)` takes the outputs on the host (one
@@ -2019,6 +2009,7 @@ def launch_flat_sorted(plans: list[FlatPlan], ctx: ShardContext, k: int,
     from ..ops.scoring import score_sorted_batch_async
 
     Q = len(plans)
+    spec = tail.spec
     # validate EVERY segment's eligibility before the first launch — a
     # late-segment refusal must not waste completed kernel work
     packeds = [packed_for(seg, breaker=ctx.breaker("fielddata"),
@@ -2083,10 +2074,11 @@ def sorted_entries(result, ctx: ShardContext, k: int, spec):
 
 
 def launch_flat_aggs(plans: list[FlatPlan], ctx: ShardContext, k: int,
-                     fields: list[str], bucket_aggs: list = ()):
+                     tail: FlatTail):
     """Dense launches of a group of plans with ONE set of aggregations fused
-    into the kernel (all scored or all unscored: aggs_tail's key and
-    _flat_groups), one launch a segment and NO pull: returns (device outputs
+    into the kernel (`tail.fields` and `tail.bucket_aggs`, the leader's; all
+    scored or all unscored: aggs_tail's key and _flat_groups), one launch a
+    segment and NO pull: returns (device outputs
     a segment, finish), or None when a segment holds more documents than the
     integer limbs allow (→ host collectors for every plan). `finish(pulled)`
     takes the outputs on the host (one device_get for all such groups of a
@@ -2112,9 +2104,11 @@ def launch_flat_aggs(plans: list[FlatPlan], ctx: ShardContext, k: int,
     from .aggregations import bucket_cache_key, bucket_cols_for
 
     Q = len(plans)
+    fields, bucket_aggs = tail.fields, tail.bucket_aggs
     operands = _group_operands(plans, ctx)
     launched = []
     keys_by_seg = []
+    n_docs = []
     prof = _profile.current()
     for seg in ctx.searcher.segments:
         t_seg = time.monotonic() if prof is not None else 0.0
@@ -2156,8 +2150,8 @@ def launch_flat_aggs(plans: list[FlatPlan], ctx: ShardContext, k: int,
         with compile_tag("aggs"):
             launched.append(score_agg_batch_async(
                 packed, batch, k, stack, tuple(pair_args), fmask=fmask))
-        keys_by_seg.append((seg_keys, stack.limbed,
-                            min(packed.doc_pad, seg.doc_count)))
+        keys_by_seg.append((seg_keys, stack.limbed))
+        n_docs.append(min(packed.doc_pad, seg.doc_count))
         _prof_dense_segment(prof, seg, packed, batch, "dense_aggs", t_seg,
                             launched[-1])
 
@@ -2171,18 +2165,9 @@ def launch_flat_aggs(plans: list[FlatPlan], ctx: ShardContext, k: int,
                 for i, is_limbed in enumerate(limbed)]
 
     def finish(pulled: list) -> list:
-        totals = np.zeros(Q, dtype=np.int64)
-        seg_hits = []
         seg_stats: list[list] = [[] for _ in range(Q)]
-        for base, (seg_keys, limbed, n_docs), out in zip(
-                ctx.searcher.bases, keys_by_seg, pulled):
-            scores, docs, tq, counts, stats, limb_sums, bcounts = out
-            scores, docs = scores[:Q], docs[:Q]
-            totals += tq[:Q]
-            valid = (docs < n_docs) & np.isfinite(scores)
-            gdocs = np.where(valid, docs.astype(np.int64) + base,
-                             np.int64(2**62))
-            seg_hits.append((np.where(valid, scores, -np.inf), gdocs))
+        for (seg_keys, limbed), out in zip(keys_by_seg, pulled):
+            counts, stats, limb_sums, bcounts = out[3:]
             for qi in range(Q):
                 seg_stats[qi].append((
                     counts[qi], stats[qi], exact(limb_sums[qi], limbed), [
@@ -2192,11 +2177,49 @@ def launch_flat_aggs(plans: list[FlatPlan], ctx: ShardContext, k: int,
                          None if sl is None else exact(sl[qi], sub_limbed))
                         for (keys, sub_limbed), (bc, sc, ss, sl)
                         in zip(seg_keys, bcounts)]))
-        return list(zip(_merge_seg_hits(seg_hits, totals, Q, k,
-                                        breaker=ctx.breaker("request")),
-                        seg_stats))
+        return list(zip(_merge_pulled(ctx, n_docs, pulled, Q, k), seg_stats))
 
     return launched, finish
+
+
+class GroupKind(NamedTuple):
+    """How one kind of group (plan_kind) reaches the device.
+
+    `launch(members, ctx, k, tail)` makes the members' launches, a segment
+    each, with NO pull, and returns (device outputs, finish), or None where
+    the host serves every member; `finish(pulled)` takes the outputs on the
+    host and returns a result a member; `tail` is the leader's FlatTail
+    (None for a kind without one). The plain group has no launcher: it keeps
+    its own protocol (_PendingFlat; _run_flat_groups says why). `width`: the
+    most members one launch takes (the dense accumulator is O(Q * doc_pad),
+    and a kind whose query count rides a ladder has a program a rung).
+    `families`: the compile families a launch may reach, which are the fault
+    domains a search of this kind asks about first (service). `served`: the
+    SERVING_COUNTERS outcome of a search this kind answers."""
+
+    launch: object
+    width: int
+    families: tuple
+    served: str
+
+
+# The ONE place that knows the kinds' names on the launch side.
+# batcher._KINDS and jaxenv.COMPILE_FAMILIES, the stats and lint
+# vocabularies, are held to it by tests/test_launch_seam.py
+_FILTERED_WIDTH = 256  # it pads up no ladder: the accumulator's bound alone
+GROUP_KINDS = {
+    "plain": GroupKind(None, 0, ("sparse", "dense"), "device_sparse"),
+    "function_score": GroupKind(launch_flat_fs, _GROUP_WIDTH,
+                                ("function_score",), "device_function_score"),
+    "filtered": GroupKind(launch_flat_filtered, _FILTERED_WIDTH,
+                          ("filtered",), "device_filtered"),
+    "phrase": GroupKind(launch_flat_phrase, _GROUP_WIDTH, ("phrase",),
+                        "device_sparse"),
+    "aggs": GroupKind(launch_flat_aggs, _GROUP_WIDTH, ("aggs",),
+                      "device_aggs"),
+    "sorted": GroupKind(launch_flat_sorted, _GROUP_WIDTH, ("sorted",),
+                        "device_sort"),
+}
 
 
 # ---------------------------------------------------------------------------

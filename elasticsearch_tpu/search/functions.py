@@ -228,7 +228,7 @@ def combined_doc_rows(q, sub_scores: np.ndarray, seg, ctx):
     The per-doc part of function_score — everything up to (but excluding) the
     no-function default, max_boost cap and boost_mode. Shared by the host tail
     (apply_functions) and the device factor-row builder
-    (execute._execute_flat_fs): all math is float32 so the two paths are
+    (execute.launch_flat_fs): all math is float32 so the two paths are
     bit-identical."""
     D = seg.doc_count
     vals: list[np.ndarray] = []

@@ -13,6 +13,7 @@ import itertools
 import threading
 import time
 from dataclasses import dataclass, field as dc_field
+from functools import partial
 
 import numpy as np
 
@@ -31,6 +32,7 @@ from ..common.errors import (
 )
 from .aggregations import facet_response, parse_aggs, parse_facets, reduce_aggs
 from .execute import (
+    GROUP_KINDS,
     HostScorer,
     ShardContext,
     TopDocs,
@@ -38,6 +40,7 @@ from .execute import (
     execute_flat_batch,
     iter_match_masks,
     match_masks,
+    plan_kind,
     query_norm_for,
     search_shard,
 )
@@ -296,18 +299,60 @@ def _prof_record_plan(prof, plan, req: ParsedSearchRequest, ctx: ShardContext,
                       else lower_fallback_reason(req.query, ctx))
 
 
-def _prof_host_features(prof, req: ParsedSearchRequest):
-    """The general host path was taken because of mask-needing request
-    features — record which ones (set-if-unset: a lowering-level reason
-    already recorded wins)."""
-    feats = [name for name, present in (
-        ("aggs", bool(req.aggs)), ("facets", bool(req.facets)),
-        ("sort", bool(req.sort)), ("post_filter", req.post_filter is not None),
-        ("rescore", bool(req.rescore)),
-        ("min_score", req.min_score is not None), ("explain", req.explain),
-    ) if present]
-    if feats:
-        prof.fallback("features:" + ",".join(feats))
+def _mask_consumers(req: ParsedSearchRequest) -> tuple:
+    """What of the request reads the per-segment match masks of the general
+    host path, computed once: each device route says which sets it serves
+    (_MASK_ROUTES), and the profile names them when none does."""
+    return tuple(name for name, present in (
+        ("aggs", req.aggs), ("facets", req.facets), ("sort", req.sort),
+        ("post_filter", req.post_filter is not None),
+        ("rescore", req.rescore), ("min_score", req.min_score is not None),
+        ("explain", req.explain),
+    ) if present)
+
+
+def _device_attempt(ctx: ShardContext, families: tuple, route):
+    """The ONE envelope around a device attempt of the query phase:
+    (`route()`'s result, degraded).
+
+    An open fault domain among `families` (devicehealth) launches nothing:
+    (None, True). A `fielddata` CircuitBreakingError (out of device-pack
+    budget) and any exception that is not the search's own answer count as a
+    device failure (_device_failed): (None, True), the host serves, marked
+    degraded. Any other breaker trip (request / parent: load-shed, the 429)
+    and a SearchEngineError (scripts, parsing: the answer itself) re-raise.
+    A clean result closes a half-open domain the attempt probed
+    (_note_device_ok); a clean None is a route that declined before or at
+    its launch: the host serves, NOT degraded, and a probe is left to the
+    next window."""
+    dom = _blocked_domain(ctx, families)
+    if dom is not None:
+        _device_degraded(dom)  # open fault domain: host serves, no launch
+        return None, True
+    try:
+        result = route()
+    except CircuitBreakingError as e:
+        if getattr(e, "breaker", None) != "fielddata":
+            raise
+        _device_failed(e, ctx)
+        return None, True
+    except SearchEngineError:
+        raise
+    except Exception as e:  # noqa: BLE001 — device trouble must not fail
+        _device_failed(e, ctx)  # the search; the host scorer answers
+        return None, True
+    if result is not None:
+        _note_device_ok(ctx, families)
+    return result, False
+
+
+def _top_docs_result(td: TopDocs, n: int | None, suggest_out,
+                     shard_id: int) -> ShardQueryResult:
+    """A device launch's TopDocs as the shard's answer, its first `n` hits
+    (None: all the launch returned)."""
+    return ShardQueryResult(
+        total=td.total, docs=[(s, d, None) for s, d in td.hits[:n]],
+        max_score=td.max_score, suggest=suggest_out, shard_id=shard_id)
 
 
 def execute_query_phase(ctx: ShardContext, req: ParsedSearchRequest,
@@ -321,8 +366,7 @@ def execute_query_phase(ctx: ShardContext, req: ParsedSearchRequest,
         deadline = Deadline.after(req.timeout_s) if req.timeout_s is not None \
             else NO_DEADLINE
     k = req.from_ + req.size
-    needs_masks = bool(req.aggs or req.facets or req.sort or req.post_filter
-                       or req.rescore or req.min_score is not None)
+    consumers = _mask_consumers(req)
     suggest_out = run_suggest(ctx, req.suggest) if req.suggest else None
     if deadline.expired():
         # budget gone before any segment was scored: legal partial = nothing
@@ -335,7 +379,8 @@ def execute_query_phase(ctx: ShardContext, req: ParsedSearchRequest,
     # fused path is declined (execute.lower_fallback_reason vocabulary)
     prof = _profile.current()
 
-    if not needs_masks:
+    # no mask consumer (`explain` alone is none: the fetch phase explains)
+    if not consumers or consumers == ("explain",):
         t_low = time.monotonic() if prof is not None else 0.0
         plan = lower_flat(req.query, ctx, phrases=True) if use_device else None
         if plan is not None and plan.const is not None and k == 0:
@@ -350,43 +395,22 @@ def execute_query_phase(ctx: ShardContext, req: ParsedSearchRequest,
             _prof_record_plan(prof, plan, req, ctx, use_device)
         degraded = False
         if plan is not None:
-            # a plan with no scoring clause launches the filtered family's
-            # tail behind its own ABI, whether or not it carries a filter
-            masked = plan.filt is not None or plan.const is not None
-            fams = ("function_score",) if plan.fs is not None else \
-                ("filtered",) if masked else \
-                ("phrase",) if plan.phrase is not None else ("sparse", "dense")
-            dom = _blocked_domain(ctx, fams)
-            if dom is not None:
-                _device_degraded(dom)  # open fault domain: host serves, no launch
-                degraded = True
-            else:
-                try:
-                    td = _execute_flat_single(ctx, plan, max(k, 1), deadline)
-                except CircuitBreakingError as e:
-                    if getattr(e, "breaker", None) != "fielddata":
-                        raise  # request/parent trip: load-shed (429), not degradable
-                    _device_failed(e, ctx)  # out of device-pack budget → host serves
-                    degraded = True
-                except SearchEngineError:
-                    raise  # domain errors (scripts, parsing) are the answer itself
-                except Exception as e:  # noqa: BLE001 — device trouble must not
-                    _device_failed(e, ctx)  # fail the search; the host scorer answers
-                    degraded = True
-                else:
-                    _note_device_ok(ctx, fams)
-                    # None: a phrase the positions plane cannot hold
-                    # (execute.launch_flat_phrase); the host scorer answers
-                    if td is not None:
-                        _count("device_function_score" if plan.fs is not None
-                               else "device_filtered" if masked
-                               else "device_sparse")
-                        return ShardQueryResult(
-                            total=td.total,
-                            docs=[(s, d, None) for s, d in td.hits],
-                            max_score=td.max_score, suggest=suggest_out,
-                            shard_id=shard_id,
-                        )
+            # the plan's own kind says which families it may launch and
+            # which outcome it books (a plan with no scoring clause launches
+            # the filtered family's tail, whether or not it carries a filter)
+            kind = GROUP_KINDS[plan_kind(plan)]
+            td, degraded = _device_attempt(
+                ctx, kind.families,
+                lambda: _execute_flat_single(ctx, plan, max(k, 1), deadline))
+            if td is not None:
+                _count(kind.served)
+                return _top_docs_result(td, None, suggest_out, shard_id)
+            if not degraded:
+                # here a clean None is the LAUNCH's, made after the pack and
+                # the positions plane were reached (a phrase the plane cannot
+                # hold: execute.launch_flat_phrase): the attempt still counts
+                # as clean, and the host scorer answers, not degraded
+                _note_device_ok(ctx, kind.families)
         _count("host")
         td = _host_topk(ctx, req, k, deadline)
         return ShardQueryResult(total=td.total, docs=[(s, d, None) for s, d in td.hits],
@@ -403,143 +427,19 @@ def execute_query_phase(ctx: ShardContext, req: ParsedSearchRequest,
                           if use_device else None, req, ctx, use_device)
         prof.phase_s("lower", time.monotonic() - t_low)
 
-    # device fault-domain state for the mask-needing branches: an open domain
-    # (or a device failure below) degrades to the general host path, which
-    # marks its ShardQueryResult so `_shards` stays honest
+    # the device route that serves this set of mask consumers, if one does
+    # (_MASK_ROUTES), attempted under the envelope. An open domain or a
+    # device failure degrades to the general host path below, which marks
+    # its ShardQueryResult so `_shards` stays honest
     degraded = False
-
-    # device metric-agg path: when the ONLY mask consumer is a set of
-    # device-eligible metric aggs, the agg reduction fuses into the scoring
-    # kernel (execute.launch_flat_aggs) instead of materializing host masks
-    if (use_device and req.aggs and not req.facets and not req.sort
-            and req.post_filter is None and not req.rescore
-            and req.min_score is None and not req.explain):
-        dom = _blocked_domain(ctx, ("aggs",))
-        if dom is not None:
-            _device_degraded(dom)
-            degraded = True
-            device = None
-        else:
-            try:
-                device = _try_device_aggs(ctx, req, k, suggest_out, shard_id,
-                                          deadline)
-            except CircuitBreakingError as e:
-                if getattr(e, "breaker", None) != "fielddata":
-                    raise  # request/parent trip: load-shed (429), not degradable
-                _device_failed(e, ctx)  # out of device-pack budget → host collectors
-                degraded = True
-                device = None
-            except SearchEngineError:
-                raise  # domain errors (scripts, parsing) are the answer itself
-            except Exception as e:  # noqa: BLE001
-                _device_failed(e, ctx)
-                degraded = True
-                device = None
-        if device is not None:
-            _note_device_ok(ctx, ("aggs",))
-            _count("device_aggs")
-            return device
-
-    # device min_score path: the function_score rows kernel with no functions IS
-    # a score threshold gate — synthesize an empty fs wrapper around the query
-    if (use_device and req.min_score is not None and not req.aggs
-            and not req.facets and not req.sort and req.post_filter is None
-            and not req.rescore and not req.explain):
-        from .queries import FunctionScoreQuery
-
-        wrapped = FunctionScoreQuery(query=req.query, min_score=req.min_score)
-        plan = lower_flat(wrapped, ctx)
-        if plan is not None:
-            dom = _blocked_domain(ctx, ("function_score",))
-            if dom is not None:
-                _device_degraded(dom)
-                degraded = True
-            else:
-                try:
-                    td = _execute_flat_single(ctx, plan, max(k, 1), deadline)
-                except CircuitBreakingError as e:
-                    if getattr(e, "breaker", None) != "fielddata":
-                        raise  # request/parent trip: load-shed (429), not degradable
-                    _device_failed(e, ctx)  # out of device-pack budget → host serves
-                    degraded = True
-                except SearchEngineError:
-                    raise  # domain errors are the answer itself
-                except Exception as e:  # noqa: BLE001
-                    _device_failed(e, ctx)
-                    degraded = True
-                else:
-                    _note_device_ok(ctx, ("function_score",))
-                    _count("device_filtered")
-                    return ShardQueryResult(
-                        total=td.total,
-                        docs=[(s, d, None) for s, d in td.hits[: max(k, 0)]],
-                        max_score=td.max_score, suggest=suggest_out,
-                        shard_id=shard_id,
-                    )
-
-    # device post_filter path: aggs (if any) reduce over the FULL match set while
-    # hits gate on the post filter — two composed launches sharing the dense core
-    # (the reference's faceting idiom: post_filter never affects aggregations)
-    if (use_device and req.post_filter is not None and not req.sort
-            and not req.facets and not req.rescore and req.min_score is None
-            and not req.explain):
-        dom = _blocked_domain(ctx, ("filtered", "aggs"))
-        if dom is not None:
-            _device_degraded(dom)
-            degraded = True
-            device = None
-        else:
-            try:
-                device = _try_device_post_filter(ctx, req, k, suggest_out,
-                                                 shard_id, deadline)
-            except CircuitBreakingError as e:
-                if getattr(e, "breaker", None) != "fielddata":
-                    raise  # request/parent trip: load-shed (429), not degradable
-                _device_failed(e, ctx)  # out of device-pack budget → host serves
-                degraded = True
-                device = None
-            except SearchEngineError:
-                raise  # domain errors (scripts, parsing) are the answer itself
-            except Exception as e:  # noqa: BLE001
-                _device_failed(e, ctx)
-                degraded = True
-                device = None
-        if device is not None:
-            _note_device_ok(ctx, ("filtered", "aggs"))
-            _count("device_filtered")
-            return device
-
-    # device field-sort path: single numeric field sort, top-k over pre-folded
-    # key rows inside the kernel (execute.launch_flat_sorted); combines with
-    # device-eligible aggs (agg launch supplies partials, sort launch ordering)
-    if (use_device and req.sort and len(req.sort) == 1
-            and not req.facets and req.post_filter is None and not req.rescore
-            and req.min_score is None and not req.explain):
-        dom = _blocked_domain(ctx, ("sorted", "aggs"))
-        if dom is not None:
-            _device_degraded(dom)
-            degraded = True
-            device = None
-        else:
-            try:
-                device = _try_device_sort(ctx, req, k, suggest_out, shard_id,
-                                          deadline)
-            except CircuitBreakingError as e:
-                if getattr(e, "breaker", None) != "fielddata":
-                    raise  # request/parent trip: load-shed (429), not degradable
-                _device_failed(e, ctx)  # out of device-pack budget → host serves
-                degraded = True
-                device = None
-            except SearchEngineError:
-                raise  # domain errors (scripts, parsing) are the answer itself
-            except Exception as e:  # noqa: BLE001
-                _device_failed(e, ctx)
-                degraded = True
-                device = None
-        if device is not None:
-            _note_device_ok(ctx, ("sorted", "aggs"))
-            _count("device_sort")
-            return device
+    for serves, launch_for, families, served in _MASK_ROUTES:
+        launch = launch_for(ctx, req, k, suggest_out, shard_id, deadline) \
+            if use_device and consumers in serves else None
+        if launch is not None:
+            device, degraded = _device_attempt(ctx, families, launch)
+            if device is not None:
+                _count(served)
+                return device
 
     # general path: the whole host materialization (per-segment score/match
     # arrays, agg/facet bucket state, the sort-entry list) is reserved on the
@@ -554,7 +454,9 @@ def execute_query_phase(ctx: ShardContext, req: ParsedSearchRequest,
         # segments already scored as an honest partial (timed_out below)
         _count("host")
         if prof is not None:
-            _prof_host_features(prof, req)
+            # which mask-needing features sent the request here (set-if-unset:
+            # a lowering-level reason already recorded wins)
+            prof.fallback("features:" + ",".join(consumers))
         timed_out = False
         seg_results = []
         masks_iter = iter_match_masks(ctx, req.query)
@@ -812,6 +714,63 @@ def _try_device_sort(ctx: ShardContext, req: ParsedSearchRequest, k: int,
         agg_partials=agg_result.agg_partials if agg_result is not None else [],
         suggest=suggest_out, shard_id=shard_id,
     )
+
+
+def _min_score_launch(ctx: ShardContext, req: ParsedSearchRequest, k: int,
+                      suggest_out, shard_id: int, deadline: Deadline):
+    """The function_score rows kernel with no functions IS a score threshold
+    gate: an empty function_score wrapper around the query carries the
+    request's `min_score`. A query that does not lower flat stays with the
+    host, and asks no domain."""
+    from .queries import FunctionScoreQuery
+
+    plan = lower_flat(FunctionScoreQuery(query=req.query,
+                                         min_score=req.min_score), ctx)
+    if plan is None:
+        return None
+    return lambda: _top_docs_result(
+        _execute_flat_single(ctx, plan, max(k, 1), deadline), max(k, 0),
+        suggest_out, shard_id)
+
+
+def _sort_launch(ctx: ShardContext, req: ParsedSearchRequest, *rest):
+    """The field-sort kernel takes one sort key; several stay with the host,
+    and ask no domain."""
+    return partial(_try_device_sort, ctx, req, *rest) \
+        if len(req.sort) == 1 else None
+
+
+def _whole(attempt):
+    """A route with nothing to decide before its domains are asked."""
+    return lambda *request: partial(attempt, *request)
+
+
+# The device routes of a request with mask consumers, in the order of trial:
+# (the sets of _mask_consumers it serves; launch_for(ctx, req, k, suggest_out,
+# shard_id, deadline) -> the attempt _device_attempt runs, or None where the
+# route does not apply; the compile families it may launch, its fault
+# domains; the SERVING_COUNTERS outcome it books). No two serve one set, and
+# a set none of them names (a facet, a rescore, an `explain` beside anything,
+# two of post_filter / sort / min_score) is the general host path's.
+_MASK_ROUTES = (
+    # aggregations alone fuse into the scoring kernel
+    # (execute.launch_flat_aggs) instead of materializing host masks
+    ((("aggs",),), _whole(_try_device_aggs), ("aggs",), "device_aggs"),
+    # booked as device_filtered though the function_score family launches
+    # it: to the user a min_score is a gate on the hits
+    ((("min_score",),), _min_score_launch, ("function_score",),
+     "device_filtered"),
+    # aggs (if any) reduce over the FULL match set while hits gate on the
+    # post filter: two composed launches sharing the dense core (the
+    # reference's faceting idiom: post_filter never affects aggregations)
+    ((("post_filter",), ("aggs", "post_filter")),
+     _whole(_try_device_post_filter), ("filtered", "aggs"), "device_filtered"),
+    # one numeric field sort, top-k over pre-folded key rows inside the
+    # kernel (execute.launch_flat_sorted); device-eligible aggs ride beside
+    # it (the agg launch supplies partials, the sort launch the ordering)
+    ((("sort",), ("aggs", "sort")), _sort_launch, ("sorted", "aggs"),
+     "device_sort"),
+)
 
 
 def _sort_values_by_rank(specs: list, ctx: ShardContext, seg_locals: list,

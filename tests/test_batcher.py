@@ -764,8 +764,8 @@ class TestKindsShareACollect:
         device, and no kind counter books the batch."""
         import elasticsearch_tpu.search.execute as ex
 
-        name = "launch_flat_aggs" if "aggs" in extra else "launch_flat_sorted"
-        real = getattr(ex, name)
+        kind = "aggs" if "aggs" in extra else "sorted"
+        real = ex.GROUP_KINDS[kind].launch
         calls = []
 
         def failing(pulled):
@@ -778,7 +778,8 @@ class TestKindsShareACollect:
             refs, finish = real(plans, *a, **kw)
             return refs, failing if len(plans) > 1 else finish
 
-        monkeypatch.setattr(ex, name, failing_in_company)
+        monkeypatch.setitem(ex.GROUP_KINDS, kind, ex.GROUP_KINDS[kind]._replace(
+            launch=failing_in_company))
         bodies = [{"query": _match(i), "size": 4, **extra} for i in range(3)]
         b = make_batcher(**{"search.batch.linger_ms": 5000,
                             "search.batch.max_batch": 3})
@@ -802,14 +803,15 @@ class TestKindsShareACollect:
                                                       execute_query_phase,
                                                       parse_search_body)
 
-        real = ex.launch_flat_aggs
+        real = ex.GROUP_KINDS["aggs"].launch
 
         def poisoned(plans, *a, **kw):
             if any(p.filt is not None for p in plans):
                 raise RuntimeError("XLA: this plan's launch fails")
             return real(plans, *a, **kw)
 
-        monkeypatch.setattr(ex, "launch_flat_aggs", poisoned)
+        monkeypatch.setitem(ex.GROUP_KINDS, "aggs",
+                            ex.GROUP_KINDS["aggs"]._replace(launch=poisoned))
         bodies = [{"query": _match(0), "size": 4, "aggs": AGGS},
                   {"query": {"filtered": {"query": _match(1),
                                           "filter": _rank_filter(3)}},

@@ -26,8 +26,10 @@ imported by the jitted root, a collective in a function only *reachable* from a
   these).
 
 Resolution is intentionally static and conservative: anything dynamic (getattr,
-dict dispatch, decorators that rewrap) stays unresolved and never creates
-findings by itself.
+decorators that rewrap) stays unresolved and never creates findings by itself.
+The one dispatch that resolves is a module-level TABLE of functions (a dict,
+tuple or list literal naming them, as execute.GROUP_KINDS does): a function of
+that module that reads the table may call every function it names.
 """
 
 from __future__ import annotations
@@ -106,6 +108,7 @@ class Project:
         self.shard_map_covered: set[int] = set()  # fids inside a shard_map region
         self.device_returning: set[int] = set()
         self._fid_of_node: dict[int, int] = {}  # id(ast node) -> fid
+        self._tables: dict[tuple[str, str], set[str]] = {}  # (module, name) -> names held
 
         for sf in files:
             self._index_file(sf)
@@ -155,6 +158,15 @@ class Project:
                     walk(child, parents, nested)
 
         walk(sf.tree, [], False)
+
+        # module-level tables of functions: name -> the names its literal holds
+        for stmt in sf.tree.body:
+            if isinstance(stmt, ast.Assign) and len(stmt.targets) == 1 \
+                    and isinstance(stmt.targets[0], ast.Name) \
+                    and isinstance(stmt.value, (ast.Dict, ast.Tuple, ast.List)):
+                self._tables[(mod, stmt.targets[0].id)] = {
+                    n.id for n in ast.walk(stmt.value)
+                    if isinstance(n, ast.Name)}
 
     def _note_mesh_axes(self, call: ast.Call) -> None:
         """Mesh(devices, ("a", "b")) / Mesh(..., axis_names=...) literal axes."""
@@ -240,6 +252,10 @@ class Project:
                     for a in list(node.args) + [kw.value for kw in node.keywords]:
                         if isinstance(a, ast.Name):
                             self._mark_escape(fi.module, a.id)
+                elif isinstance(node, ast.Name):
+                    # a read of a module-level table of functions
+                    for name in self._tables.get((fi.module, node.id), ()):
+                        fi.calls.update(self.resolve(fi.module, (name,)))
                 elif isinstance(node, ast.Return) and node.value is not None:
                     self._note_return(fi, node.value)
                 elif isinstance(node, (ast.Assign, ast.AnnAssign)):
