@@ -153,8 +153,8 @@ class SanitizerReport:
 _tag_local = threading.local()
 
 COMPILE_FAMILIES = ("sparse", "dense", "function_score", "filtered",
-                    "phrase", "sorted", "aggs", "percolate", "mesh",
-                    "compact", "pack", "untagged")
+                    "phrase", "dis_max", "sorted", "aggs", "percolate",
+                    "mesh", "compact", "pack", "untagged")
 _FAMILY_SET = frozenset(COMPILE_FAMILIES)
 
 

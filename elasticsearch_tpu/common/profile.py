@@ -44,6 +44,14 @@ left the fused device path —
   block rows on a segment than the ladder's last rung holds, a numeric field
   (a field of _id / _uid reads host_only_field); `fuzzy` has no exact host
   semantics to hold a program to, `span_multi` is a span's),
+  multi_match_type, dismax_subquery, dismax_similarity, dismax_disjuncts,
+  dismax_tail (a `multi_match` of type best_fields and a `dis_max` of
+  one-field OR queries lower to a dis_max plan, most_fields to a plain one,
+  but for: another multi_match type; a sub-query that is not a `term` or an
+  OR `match` with no minimum_should_match above 1, a numeric field, a boost
+  that is not positive; a TF-IDF default similarity or a field that is not
+  BM25's; more disjuncts than the program's slots; a dis_max plan under
+  `filtered` or `function_score`, which has no tail to carry them),
   unsupported_query:<Type>,
   device_disabled, features:<f1,f2,...>, device_error:<Type>.
 """

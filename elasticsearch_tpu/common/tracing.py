@@ -113,10 +113,13 @@ class DispatchClock:
     `dispatch.stage`), recorded as that interval's child: the marks and what
     they measure are as they were. The notes: `shard.filter_mask`,
     `shard.fs_rows`, `shard.phrase_plan` (the host's assembly of a phrase
-    launch's operands: execute.launch_flat_phrase) and
+    launch's operands: execute.launch_flat_phrase),
     `shard.multiterm_expand` (the expansion of a batch's prefixes, wildcards
     and regexps into block rows and the put of the mask launches' operands:
-    execute._filter_mask_matrix, scoring.build_multiterm_rows)."""
+    execute._filter_mask_matrix, scoring.build_multiterm_rows) and
+    `shard.dismax_plan` (the host's staging of a dis_max group: the clauses
+    under their disjuncts' accumulators, the operand plane and its one put:
+    execute.launch_flat_dismax)."""
 
     __slots__ = ("spans", "pull_s", "compiled", "compile_s", "_t", "_n", "_s",
                  "_notes")

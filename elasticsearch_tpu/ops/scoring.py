@@ -298,7 +298,7 @@ class LaunchCounters:
     what the padded launch shape scans (`blocks_padding` their difference).
     `posting_bytes` is the program's own reckoning of the HBM bytes a launch
     reads, no measurement; each formula sits beside its launch
-    (score_sparse_batch_async, _count_dense). `dense_rows` counts the
+    (score_sparse_batch_async, _dense_bytes). `dense_rows` counts the
     [doc_pad]-wide score rows of the dense launches, `head_slots` the rows
     of device_index head_rows they added and `blocks_as_rows` the postings
     blocks those rows stood in for (`blocks_real` / `blocks_launched` count
@@ -324,7 +324,9 @@ class LaunchCounters:
              "position_pad_bytes", "position_list_bytes",
              "position_skip_bytes", "multiterm", "multiterm_searches",
              "multiterm_terms", "multiterm_runs", "multiterm_bytes",
-             "multiterm_pad_bytes", "multiterm_field_scans"), 0)
+             "multiterm_pad_bytes", "multiterm_field_scans",
+             "dismax", "dismax_searches", "dismax_disjuncts", "dismax_bytes",
+             "dismax_blocks", "dismax_pad_blocks"), 0)
 
     def add(self, real: int, launched: int, nbytes: int,
             dense_rows: int = 0, head_slots: int = 0,
@@ -377,7 +379,14 @@ class LaunchCounters:
         `multiterm_bytes`, `multiterm_pad_bytes`: build_multiterm_rows,
         execute._filter_mask_matrix), and the expansions whose pattern had
         no literal head, so that the whole field's dictionary was tested
-        (`multiterm_field_scans`)."""
+        (`multiterm_field_scans`); and the dis_max program's launches, the
+        plans it served (once a plan whatever its segments) and the
+        disjuncts those held, the HBM bytes its launches read by the
+        program's own reckoning, and the postings blocks they scanned with
+        the ladder's padding among them (`dismax`, `dismax_searches`,
+        `dismax_disjuncts`, `dismax_bytes`, `dismax_blocks`,
+        `dismax_pad_blocks`: score_dismax_batch_async,
+        execute.launch_flat_dismax)."""
         with self._lock:
             for name, n in counts.items():
                 self._c[name] += n
@@ -495,19 +504,23 @@ def _pull(out):
     return host
 
 
-def _count_dense(packed: PackedSegment, batch: TermBatch) -> None:
+def _dense_bytes(packed: PackedSegment, batch: TermBatch) -> int:
     """A dense launch reads, per launched (query, block) triple, BLOCK slots
     of doc id i32 + freq f32 + one gathered table value f32; per trip of the
     head loop (it runs to the fullest query) a [Q, doc_pad] gather of rows in
     the segment's tf dtype and one of table values f32; and top_k reads the
     [Q, doc_pad] f32 score plane back."""
-    m, q = len(batch.blk), batch.n_queries
-    LAUNCHES.add(batch.blocks_real, m,
-                 m * BLOCK * (4 + 4 + 4)
-                 + q * batch.head_trips * packed.doc_pad
-                 * (packed.head_rows.dtype.itemsize + 4)
-                 + q * packed.doc_pad * 4,
-                 dense_rows=q, head_slots=batch.head_slots,
+    q = batch.n_queries
+    return (len(batch.blk) * BLOCK * (4 + 4 + 4)
+            + q * batch.head_trips * packed.doc_pad
+            * (packed.head_rows.dtype.itemsize + 4)
+            + q * packed.doc_pad * 4)
+
+
+def _count_dense(packed: PackedSegment, batch: TermBatch) -> None:
+    LAUNCHES.add(batch.blocks_real, len(batch.blk),
+                 _dense_bytes(packed, batch), dense_rows=batch.n_queries,
+                 head_slots=batch.head_slots,
                  blocks_as_rows=batch.blocks_as_rows)
 
 
@@ -1649,7 +1662,8 @@ def collect_flat_sparse(launches: list, pulled: list, Q: int, k: int,
 
 def build_term_batch(entries: list, n_queries: int, n_must: np.ndarray, msm: np.ndarray,
                      coord: np.ndarray, norm_fields: list[str], caches: np.ndarray,
-                     nb_pad_row: int, head_pad_row: int = 0) -> TermBatch:
+                     nb_pad_row: int, head_pad_row: int = 0,
+                     floor: int = 0) -> TermBatch:
     """Lay a batch's clauses out as the head-slot plane and the flat triple
     plane, bucket-padded.
 
@@ -1663,7 +1677,9 @@ def build_term_batch(entries: list, n_queries: int, n_must: np.ndarray, msm: np.
     of the clause columns, never a Python loop over blocks; padding triples
     point at `nb_pad_row` (a row of doc_pad sentinels — contributes nothing)
     with every other column zero. M rides the `terms` ladder from
-    TAIL_FLOOR; the coord table's width rides the pow-2 ladder from 4."""
+    TAIL_FLOOR and is never under `floor` (a launch whose rung is fixed by
+    its query count: execute.launch_flat_dismax); the coord table's width
+    rides the pow-2 ladder from 4."""
     used = [0] * n_queries
     heads, tail = [], []
     for e in entries:
@@ -1685,7 +1701,7 @@ def build_term_batch(entries: list, n_queries: int, n_must: np.ndarray, msm: np.
         # the triple index back (below) walks [b0, b1)
         cols[_T_BLK] = b0 - (np.cumsum(nb) - nb)
     n = int(nb.sum())
-    M = _ladder_bucket("terms", max(n, 1), TAIL_FLOOR)
+    M = max(floor, _ladder_bucket("terms", max(n, 1), TAIL_FLOOR))
     # the coord table's width is a dimension of the program's key too: up the
     # pow-2 ladder, each row continued with its last value (what the overlap
     # clamp reads past a row's end anyway)
@@ -2197,6 +2213,109 @@ def build_multiterm_rows(packed: PackedSegment, row_lists: list,
 
 
 # ---------------------------------------------------------------------------
+# dis_max: an accumulator a disjunct, combined before the top-k cut
+# ---------------------------------------------------------------------------
+#
+# A dis_max of one-field OR queries (a `multi_match` of type best_fields is
+# one, a `match` a field) scores a document `best + tie * (total - best)`
+# over its disjuncts' sums (the reference's DisjunctionMaxQuery; HostScorer's
+# DisMaxQuery branch). No plain program can: each adds every clause into ONE
+# accumulator a document, and two plain launches merged on the host cannot
+# either, since a document outside both fields' top k can lead the combined
+# order. So the dense core runs with an accumulator a (query, disjunct): a
+# launch of Q plans of D disjuncts is a TermBatch of Q * D rows, row q * D + d
+# holding the clauses of plan q's disjunct d (execute.launch_flat_dismax), and
+# the combine folds the D planes of a query into one before _top_k_tail. Every
+# clause is a SHOULD of positive weight under BM25, so a disjunct matches
+# where its sum is positive and the query where its best disjunct does: no
+# counter plane (the dense programs' `simple` case). A plan of fewer disjuncts
+# than the launch's leaves its last rows empty: zero is the neutral element
+# of both the max and the sum.
+
+# the most disjuncts a dis_max plan may hold: more stay on the host
+# (lower_fallback_reason `dismax_disjuncts`); the count is static in a
+# program's key (2, 3 or 4)
+DISMAX_SLOTS = 4
+
+
+def _dismax_impl(blk_docs, blk_freqs, head_rows, live_parent, doc_table,
+                 plane, m, *, n_queries: int, disjuncts: int, doc_pad: int,
+                 k: int):
+    """The dense launch ABI (_dense_abi's operands; `plane` holds Q * D rows'
+    batch and then the Q tie-breakers' f32 bits) behind the dis_max combine."""
+    import jax
+    import jax.numpy as jnp
+
+    rows = n_queries * disjuncts
+    cut = plane.shape[0] - n_queries
+    tie = jax.lax.bitcast_convert_type(plane[cut:], jnp.float32)
+    tri, _qplane, head = _plane_views(plane[:cut], m, rows)
+    with jax.named_scope("disjunct_accumulate"):
+        sums, _counts = _dense_accumulate(
+            blk_docs, blk_freqs, head_rows, doc_table, tri, head,
+            Q=rows, doc_pad=doc_pad, counters=False)
+    with jax.named_scope("dismax_combine"):
+        # the host's order (HostScorer, DisMaxQuery): best and total over the
+        # disjuncts in turn, then best + tie * (total - best), all float32
+        sums = sums.reshape(n_queries, disjuncts, doc_pad)
+        best = total = sums[:, 0]
+        for d in range(1, disjuncts):
+            best = jnp.maximum(best, sums[:, d])
+            total = total + sums[:, d]
+        scores = best + tie[:, None] * (total - best)
+        match = (best > 0.0) & live_parent[None, :]
+    return _top_k_tail(scores, match, k=k)
+
+
+def _get_dismax_compiled(n_queries: int, disjuncts: int, k: int, doc_pad: int):
+    import jax
+
+    key = ("dismax", n_queries, disjuncts, k, doc_pad)
+    fn = _compiled_cache.get(key)
+    if fn is None:
+        def wrapper(blk_docs, blk_freqs, head_rows, live_parent, doc_table,
+                    plane, m):
+            return _dismax_impl(blk_docs, blk_freqs, head_rows, live_parent,
+                                doc_table, plane, m, n_queries=n_queries,
+                                disjuncts=disjuncts, doc_pad=doc_pad, k=k)
+
+        fn = jax.jit(_named("scoring.dismax", wrapper),
+                     static_argnums=_DENSE_STATIC_ARGNUMS)
+        _compiled_cache[key] = fn
+    return fn
+
+
+def score_dismax_batch_async(packed: PackedSegment, batch: TermBatch, k: int,
+                             ties: tuple, disjuncts: int,
+                             note_t0: float | None = None):
+    """Launch the dis_max program over one segment for len(`ties`) plans of
+    `disjuncts` accumulators each: `batch` holds their product of rows, row
+    q * disjuncts + d the clauses of plan q's disjunct d, and `ties` the
+    plans' tie-breakers, which ride the batch's one plane as their bits.
+    Returns the device arrays (scores [Q, k], docs [Q, k], totals [Q])
+    without syncing. `note_t0`: when the host began to stage this launch;
+    from there to the end of the operands' one device_put is the span
+    `shard.dismax_plan` (a note inside the running `dispatch.stage`)."""
+    n_queries = len(ties)
+    params = (n_queries, disjuncts, min(k, packed.doc_pad), packed.doc_pad)
+    fn = _get_dismax_compiled(*params)
+    args = _dense_args(packed, batch, scalars=ties)
+    if note_t0 is not None:
+        _tracing.note("shard.dismax_plan", note_t0)
+    # what the launch reads, by its own reckoning: a dense launch's bytes for
+    # the batch's rows (_dense_bytes: the triples' slots, the head loop's
+    # planes, the accumulators, which here the combine reads) and the
+    # combined plane a query that top_k reads; the triples past the blocks
+    # the batch names are padding
+    m = len(batch.blk)
+    LAUNCHES.bump(
+        dismax=1, dismax_blocks=m, dismax_pad_blocks=m - batch.blocks_real,
+        dismax_bytes=_dense_bytes(packed, batch)
+        + n_queries * packed.doc_pad * 4)
+    return _launch(fn, args, "scoring.dismax", "dis_max", params)
+
+
+# ---------------------------------------------------------------------------
 # compile-warm builders (common/compilecache)
 # ---------------------------------------------------------------------------
 # Each builder maps a WarmSpec's recorded params back to the SAME jitted
@@ -2261,3 +2380,8 @@ def _build_phrase(params):
 @_WARM.builder("scoring.multiterm")
 def _build_multiterm(params):
     return _get_multiterm_compiled(*params)
+
+
+@_WARM.builder("scoring.dismax")
+def _build_dismax(params):
+    return _get_dismax_compiled(*params)

@@ -83,8 +83,8 @@ _K_MIN = 16  # smallest k bucket (top-10 pages and top-16 share executables)
 # the kinds of launch /_nodes/stats tells apart under search.batcher.kinds:
 # execute.GROUP_KINDS' keys (tests/test_launch_seam.py holds this to them) and
 # the mesh family's
-_KINDS = ("plain", "function_score", "filtered", "phrase", "aggs", "sorted",
-          "mesh")
+_KINDS = ("plain", "function_score", "filtered", "phrase", "dis_max", "aggs",
+          "sorted", "mesh")
 
 
 def _k_bucket(k: int) -> int:

@@ -197,6 +197,7 @@ class Clause:
     term: str
     boost: float
     group: int  # GROUP_*
+    disjunct: int = 0  # of a dis_max plan: which disjunct's sum it adds to
 
 
 @dataclass(frozen=True)
@@ -245,6 +246,15 @@ class FlatPlan:
     # phrase under a filter, a function_score, a sort or aggregations is the
     # host's. Only lower_flat(phrases=True) gives such a plan out
     phrase: PhraseClause | None = None
+    # a dis_max of `n_disjuncts` (2 to DISMAX_SLOTS) one-field OR queries:
+    # each clause adds to its own disjunct's sum (Clause.disjunct), a document
+    # matches where any clause does and scores best + tie_breaker * (total -
+    # best) over the disjuncts' sums. `boost` is 1 and every boost is folded
+    # into its clause's, in the host's order. Like a phrase it carries no
+    # tail and only lower_flat(phrases=True) gives it out; 0: a plan with ONE
+    # accumulator a document (every other plan)
+    tie_breaker: float = 0.0
+    n_disjuncts: int = 0
 
 
 # ---------------------------------------------------------------------------
@@ -297,10 +307,12 @@ def lower_flat(query: Query, ctx: ShardContext,
     Fields scored by a freq/norm-generic similarity (DFR/IB/LM*) always take the host
     path — the device kernel's fused modes are BM25/TF-IDF only. `phrases`: the
     caller hands its plans to execute_flat_batch with no tail, the one consumer
-    that can run a phrase plan (its `clauses` are empty); every other caller is
-    declined an exact phrase here and answers it from the host."""
+    that can run a phrase plan (its `clauses` are empty) or a dis_max plan (an
+    accumulator a disjunct); every other caller is declined both here and
+    answers them from the host."""
     plan = _lower_top(query, ctx)
-    if plan is not None and plan.phrase is not None and not phrases:
+    if plan is not None and not phrases and (
+            plan.phrase is not None or plan.n_disjuncts > 1):
         return None
     if plan is not None:
         for field in _plan_fields(plan):
@@ -480,7 +492,7 @@ def _lower_flat_inner(query: Query, ctx: ShardContext) -> FlatPlan | None:
         if query.query is None:
             return None
         sub = _lower_flat_inner(query.query, ctx)
-        if sub is None or sub.fs is not None:
+        if sub is None or sub.fs is not None or sub.n_disjuncts > 1:
             return None
         kind = _classify_fs(query)
         if kind is None:
@@ -500,12 +512,97 @@ def _lower_flat_inner(query: Query, ctx: ShardContext) -> FlatPlan | None:
         # boost folds into the sub clauses (host: eval(q.query, b)), the filter
         # becomes a match-gating mask row in the dense kernel
         sub = _lower_flat_inner(query.query, ctx)
-        if sub is None or sub.fs is not None or sub.filt is not None:
+        if sub is None or sub.fs is not None or sub.filt is not None \
+                or sub.n_disjuncts > 1:
             return None
         return FlatPlan(sub.clauses, msm=sub.msm, n_must=sub.n_must,
                         coord_enabled=sub.coord_enabled,
                         boost=sub.boost * query.boost, filt=query.filter)
+    if isinstance(query, (MultiMatchQuery, DisMaxQuery)):
+        return _multi_field_lowering(query, ctx)[1]
     return None
+
+
+def multi_match_subqueries(q: MultiMatchQuery) -> list:
+    """One `match` a field of a multi_match, a field's `^boost` as its
+    match's boost: what HostScorer evaluates and _multi_field_lowering
+    lowers."""
+    subs = []
+    for fspec in q.fields:
+        fname, _, fboost = fspec.partition("^")
+        subs.append(MatchQuery(fname, q.text, operator=q.operator,
+                               minimum_should_match=q.minimum_should_match,
+                               boost=float(fboost) if fboost else 1.0))
+    return subs
+
+
+def _multi_field_lowering(query: Query, ctx: ShardContext):
+    """(None, the plan of a `multi_match` or an explicit `dis_max`) or (why
+    the host scorer answers it, None).
+
+    A dis_max whose every sub-query is a one-field OR (`match`, operator or,
+    no minimum_should_match above 1; or `term`) under BM25 lowers to ONE plan
+    of SHOULD clauses in the host's order, each with its disjunct's index and
+    its boosts folded as HostScorer.eval folds them on its way down (outer
+    boost x the sub-query's, as Python floats; the plan's own boost is 1).
+    `multi_match` of type best_fields is that dis_max with a `match` a field;
+    of type most_fields it is the flat sum of every field's clauses (the host
+    evaluates a bool of should matches with coord off), a plain plan. A
+    sub-query that analyzes to nothing is no disjunct; one disjunct left is
+    the plain plan it is (best + tie * (total - best) of one sum is the sum),
+    none the empty plan of an empty match.
+
+    Declined: a multi_match of another type (phrase and phrase_prefix score
+    positions, cross_fields blends statistics); a sub-query that is not a
+    one-field OR; a TF-IDF default similarity (there every disjunct's bool
+    takes a coord and the query a queryNorm: HostScorer._eval_bool) or a field
+    whose similarity is not BM25; more disjuncts than the program's slots."""
+    from ..ops.scoring import DISMAX_SLOTS
+
+    if isinstance(query, MultiMatchQuery):
+        if query.type not in ("best_fields", "most_fields"):
+            return "multi_match_type", None
+        subs = multi_match_subqueries(query)
+        tie = query.tie_breaker if query.type == "best_fields" else None
+    else:
+        subs, tie = query.queries, query.tie_breaker
+    if not all(isinstance(sub, TermQuery) or (
+            isinstance(sub, MatchQuery) and sub.fuzziness is None
+            and sub.operator == "or") for sub in subs):
+        return "dismax_subquery", None
+    if isinstance(ctx.default_similarity, TFIDFSimilarity) or not all(
+            isinstance(ctx.similarity_for(sub.field), BM25Similarity)
+            for sub in subs):
+        return "dismax_similarity", None
+    disjuncts = []  # (field, terms, boost) of the disjuncts that hold a term
+    for sub in subs:
+        ft = ctx.field_type(sub.field)
+        boost = query.boost * sub.boost
+        if (ft is not None and ft.is_numeric) or boost <= 0:
+            # a numeric term is a filter's constant; under a boost that is
+            # not positive a disjunct's sum no longer says whether it matched
+            return "dismax_subquery", None
+        if isinstance(sub, TermQuery):
+            terms = [str(sub.value)]
+        else:
+            terms = ctx.analyze(sub.field, sub.text)
+            if calculate_msm(sub.minimum_should_match, len(terms)) > 1:
+                return "dismax_subquery", None
+        if terms:
+            disjuncts.append((sub.field, terms, boost))
+    if not disjuncts:
+        return None, FlatPlan([], msm=0, n_must=0, coord_enabled=False,
+                              boost=1.0)
+    flat = tie is None or len(disjuncts) == 1
+    if not flat and len(disjuncts) > DISMAX_SLOTS:
+        return "dismax_disjuncts", None
+    clauses = [Clause(field, t, boost, GROUP_SHOULD, 0 if flat else d)
+               for d, (field, terms, boost) in enumerate(disjuncts)
+               for t in terms]
+    plan = FlatPlan(clauses, msm=1, n_must=0, coord_enabled=False, boost=1.0)
+    if not flat:
+        plan.tie_breaker, plan.n_disjuncts = float(tie), len(disjuncts)
+    return None, plan
 
 
 def _lower_phrase(query: PhraseQuery, ctx: ShardContext) -> FlatPlan | None:
@@ -589,7 +686,8 @@ def plan_profile(plan: FlatPlan, query: Query) -> dict:
     return {
         "query_type": type(query).__name__,
         "clauses": [{"field": c.field, "term": c.term,
-                     "boost": float(c.boost), "group": _GROUP_NAMES[c.group]}
+                     "boost": float(c.boost), "group": _GROUP_NAMES[c.group],
+                     "disjunct": int(c.disjunct)}
                     for c in plan.clauses],
         "msm": int(plan.msm),
         "n_must": int(plan.n_must),
@@ -602,6 +700,10 @@ def plan_profile(plan: FlatPlan, query: Query) -> dict:
         "phrase": None if plan.phrase is None else {
             "field": plan.phrase.field, "terms": list(plan.phrase.terms),
             "rel_pos": list(plan.phrase.rel_pos)},
+        # a dis_max plan: an accumulator a disjunct, combined by the tie-breaker
+        "dis_max": None if plan.n_disjuncts < 2 else {
+            "disjuncts": int(plan.n_disjuncts),
+            "tie_breaker": float(plan.tie_breaker)},
     }
 
 
@@ -649,15 +751,19 @@ def lower_fallback_reason(query: Query, ctx: ShardContext) -> str:
         # (a bool with no must or should lowers as an unscored plan; one with
         # them takes term subclauses alone, an unscored one among them not)
         return "non_term_subclause"
-    if isinstance(query, FunctionScoreQuery):
+    if isinstance(query, (MultiMatchQuery, DisMaxQuery)):
+        # a dis_max of one-field OR queries lowers but for these
+        return _multi_field_lowering(query, ctx)[0]
+    if isinstance(query, (FunctionScoreQuery, FilteredQuery)):
         if query.query is None:
             return "function_score_no_query"
         sub = _lower_flat_inner(query.query, ctx)
-        if sub is None or sub.fs is not None:
+        if sub is not None and sub.n_disjuncts > 1:
+            return "dismax_tail"  # a dis_max plan carries no tail
+        if isinstance(query, FilteredQuery) or sub is None \
+                or sub.fs is not None:
             return "non_flat_subquery"
         return "function_score_ineligible"
-    if isinstance(query, FilteredQuery):
-        return "non_flat_subquery"
     return f"unsupported_query:{type(query).__name__}"
 
 
@@ -780,7 +886,7 @@ def sort_tail(spec) -> FlatTail:
 def plan_kind(plan: FlatPlan, tail: FlatTail | None = None) -> str:
     """The kind of group a plan launches in, a key of GROUP_KINDS: its tail's
     (aggs, sorted) where it has one, else function_score, filtered (a filter,
-    or no scoring clause at all), phrase or plain."""
+    or no scoring clause at all), phrase, dis_max or plain."""
     if tail is not None:
         return tail.kind
     if plan.fs is not None:
@@ -789,6 +895,8 @@ def plan_kind(plan: FlatPlan, tail: FlatTail | None = None) -> str:
         return "filtered"
     if plan.phrase is not None:
         return "phrase"
+    if plan.n_disjuncts > 1:
+        return "dis_max"
     return "plain"
 
 
@@ -1260,6 +1368,29 @@ def _merge_pulled(ctx: ShardContext, n_docs: list, pulled: list, Q: int,
                            breaker=ctx.breaker("request"))
 
 
+def _merge_launches(ctx: ShardContext, n_docs: list, members: list,
+                    pulled: list, Q: int, k: int) -> list[TopDocs]:
+    """TopDocs a plan from the pulled outputs of a group whose plans launch
+    apart on a segment (the phrase and dis_max groups: by rung): `members`
+    holds (segment index, plan indexes) a launch, `pulled` its (scores, docs,
+    totals), whose rows past the launch's members are its ladder's padding. A
+    plan no launch of a segment names matches nothing there."""
+    totals = np.zeros(Q, dtype=np.int64)
+    n_seg = len(n_docs)
+    kk = max(k, 1)
+    scores = np.full((n_seg, Q, kk), -np.inf, np.float32)
+    docs = np.zeros((n_seg, Q, kk), np.int64)
+    for (si, qis), (s, d, tq) in zip(members, pulled):
+        n = len(qis)
+        scores[si, qis, : s.shape[1]] = s[:n]
+        docs[si, qis, : s.shape[1]] = d[:n]
+        totals[qis] += tq[:n]
+    seg_hits = [_segment_hits(scores[si], docs[si], n_docs[si], base)
+                for si, base in enumerate(ctx.searcher.bases)]
+    return _merge_seg_hits(seg_hits, totals, Q, k,
+                           breaker=ctx.breaker("request"))
+
+
 def _merge_seg_hits(seg_hits, totals, Q: int, k: int,
                     breaker=None) -> list[TopDocs]:
     """Cross-segment top-k merge: score desc, global doc asc — the Lucene
@@ -1310,28 +1441,30 @@ def _ensure_norm_rows(packed, all_fields, breaker=None):
             packed.norm_bytes[f] = jnp.zeros(packed.doc_pad, dtype=jnp.uint8)
 
 
-def _dense_entries(finals, seg, packed, field_idx) -> list:
+def _dense_entries(finals, seg, packed, field_idx, slots=None) -> list:
     """One (qidx, b0, b1, weight, fidx, group, mode, row) record per clause
     whose term this segment holds — [b0, b1) its block rows in the packed
     planes, `row` its row of the segment's head_rows (-1: the term has none),
-    qidx = position in `finals`. scoring.build_term_batch gives a dense launch
-    the row where there is one and expands the range where there is not; the
-    sparse planner reads the ranges alone."""
+    qidx = position in `finals`, or where `slots` is given (a list a final, an
+    accumulator row a clause: launch_flat_dismax) the clause's entry there.
+    scoring.build_term_batch gives a dense launch the row where there is one
+    and expands the range where there is not; the sparse planner reads the
+    ranges alone."""
     entries = []
     row_of = packed.head_row_of
     for qi, (resolved, _f, _c, _coord) in enumerate(finals):
-        for (f, t, w, _fi, g, mode, df) in resolved:
+        for ci, (f, t, w, _fi, g, mode, df) in enumerate(resolved):
             tid = seg.term_id(f, t)
             if tid is None:
                 continue
             b0, b1 = packed.blocks_for_term(tid)
-            entries.append((qi, b0, b1, w, field_idx[f], g, mode,
-                            row_of.get(tid, -1)))
+            entries.append((qi if slots is None else slots[qi][ci], b0, b1, w,
+                            field_idx[f], g, mode, row_of.get(tid, -1)))
     return entries
 
 
 def _term_batch(entries, Q, n_must, msm, coord_tbl, all_fields, caches_stack,
-                packed):
+                packed, floor: int = 0):
     """scoring.build_term_batch over `packed`'s planes (after
     _ensure_norm_rows): padding triples point at its all-sentinel block row,
     padding head slots at its head plane's last row."""
@@ -1340,7 +1473,8 @@ def _term_batch(entries, Q, n_must, msm, coord_tbl, all_fields, caches_stack,
     return build_term_batch(entries, Q, n_must, msm, coord_tbl, all_fields,
                             caches_stack,
                             nb_pad_row=packed.blk_docs.shape[0] - 1,
-                            head_pad_row=packed.head_rows.shape[0] - 1)
+                            head_pad_row=packed.head_rows.shape[0] - 1,
+                            floor=floor)
 
 
 def _postings_scanned(finals, seg) -> int:
@@ -1887,23 +2021,90 @@ def launch_flat_phrase(plans: list[FlatPlan], ctx: ShardContext, k: int,
     n_docs = [min(entry[0].doc_pad, seg.doc_count)
               for seg, entry in zip(ctx.searcher.segments, staged)]
 
-    def finish(pulled: list) -> list[TopDocs]:
-        totals = np.zeros(Q, dtype=np.int64)
-        n_seg = len(staged)
-        kk = max(k, 1)
-        scores = np.full((n_seg, Q, kk), -np.inf, np.float32)
-        docs = np.zeros((n_seg, Q, kk), np.int64)
-        for (si, qis), (s, d, tq) in zip(members, pulled):
-            n = len(qis)
-            scores[si, qis, : s.shape[1]] = s[:n]
-            docs[si, qis, : s.shape[1]] = d[:n]
-            totals[qis] += tq[:n]
-        seg_hits = [_segment_hits(scores[si], docs[si], n_docs[si], base)
-                    for si, base in enumerate(ctx.searcher.bases)]
-        return _merge_seg_hits(seg_hits, totals, Q, k,
-                               breaker=ctx.breaker("request"))
+    return launched, partial(_merge_launches, ctx, n_docs, members, Q=Q, k=k)
 
-    return launched, finish
+
+def launch_flat_dismax(plans: list[FlatPlan], ctx: ShardContext, k: int,
+                       tail=None):
+    """dis_max plans (at most _GROUP_WIDTH) on the dense core with an
+    accumulator a (plan, disjunct): a launch of Qp plans holds a TermBatch of
+    Qp * D rows, D the most disjuncts a member of the group has (static in
+    the program's key: 2 to DISMAX_SLOTS), and the clause of its q-th plan's
+    disjunct d is staged under row q * D + d, so the dense gather, scatter
+    and head rows run as they are and the program's combine folds a plan's D
+    planes into one before the top-k cut (scoring, "dis_max"). Rows past a
+    plan's disjuncts and plans past the members hold no clause: they add
+    zero to a max and a sum, and match nothing.
+
+    A launch's shape is its query count and its triples' rung M, and every
+    program a window launches has to have been met before it (a first
+    sighting compiles for seconds on the one drainer), so neither follows
+    what a batch happens to hold. On a segment the plans whose tail blocks
+    fit TAIL_FLOOR launch together at the group's width (_group_width: 1 or
+    4) with M fixed at a TAIL_FLOOR a plan, whatever they sum to: two
+    programs, which a warm-up's first answers and its pool pass meet. A plan
+    of more blocks launches alone at its own rung, which the pool pass met
+    when it sent that plan (the sum of four plans' blocks rode three rungs,
+    the rarest once in a hundred launches: one window in six compiled).
+
+    NO pull: returns (device outputs a launch, finish); `finish(pulled)`
+    takes the outputs on the host (the batch's one device_get:
+    _run_flat_groups) and returns TopDocs a plan. The host's share of a
+    launch, the clauses' block rows under their rows and the operand plane's
+    one device_put, is the span `shard.dismax_plan` inside `dispatch.stage`."""
+    from ..ops.device_index import packed_for
+    from ..ops.scoring import (LAUNCHES, TAIL_FLOOR,
+                               score_dismax_batch_async)
+
+    t0 = time.monotonic()
+    Q = len(plans)
+    D = max(p.n_disjuncts for p in plans)
+    finals = [finalize_flat(p, ctx) for p in plans]
+    all_fields, field_idx, _rows, caches_stack, *_bool_semantics = \
+        _assemble_batch(plans, finals)
+    slots = [[(qi, c.disjunct) for c in p.clauses]
+             for qi, p in enumerate(plans)]
+    launched = []
+    members = []  # a launch: (segment index, plan indexes)
+    n_docs = []
+    prof = _profile.current()
+    for si, seg in enumerate(ctx.searcher.segments):
+        packed = packed_for(seg, breaker=ctx.breaker("fielddata"),
+                            owner=ctx.index_name)
+        _ensure_norm_rows(packed, all_fields,
+                          breaker=ctx.breaker("fielddata"))
+        n_docs.append(min(packed.doc_pad, seg.doc_count))
+        by_plan = [[] for _ in plans]
+        tail_blocks = [0] * Q
+        for ((qi, d), b0, b1, *rest) in _dense_entries(
+                finals, seg, packed, field_idx, slots):
+            by_plan[qi].append((d, b0, b1, *rest))
+            if rest[-1] < 0:  # no head row: its blocks are scattered
+                tail_blocks[qi] += b1 - b0
+        together = [qi for qi in range(Q) if tail_blocks[qi] <= TAIL_FLOOR]
+        alone = [[qi] for qi in range(Q) if tail_blocks[qi] > TAIL_FLOOR]
+        for qis in ([together] if together else []) + alone:
+            Qp = _group_width(len(qis))
+            # every row is SHOULD clauses alone: no must, no msm, no coord
+            none = np.zeros(Qp * D, np.int32)
+            batch = _term_batch(
+                [(pos * D + d, *rest) for pos, qi in enumerate(qis)
+                 for (d, *rest) in by_plan[qi]],
+                Qp * D, none, none, np.ones((Qp * D, 2), np.float32),
+                list(all_fields), caches_stack, packed,
+                floor=0 if qis in alone else Qp * TAIL_FLOOR)
+            ties = tuple(plans[qi].tie_breaker for qi in qis) \
+                + (0.0,) * (Qp - len(qis))
+            with compile_tag("dis_max"):
+                launched.append(score_dismax_batch_async(
+                    packed, batch, max(k, 1), ties, D, note_t0=t0))
+            members.append((si, qis))
+            _prof_dense_segment(prof, seg, packed, batch, "dense_dismax", t0,
+                                launched[-1])
+            t0 = time.monotonic()
+    LAUNCHES.bump(dismax_searches=Q,
+                  dismax_disjuncts=sum(p.n_disjuncts for p in plans))
+    return launched, partial(_merge_launches, ctx, n_docs, members, Q=Q, k=k)
 
 
 def _sort_row_key(spec) -> tuple:
@@ -2215,6 +2416,8 @@ GROUP_KINDS = {
                           ("filtered",), "device_filtered"),
     "phrase": GroupKind(launch_flat_phrase, _GROUP_WIDTH, ("phrase",),
                         "device_sparse"),
+    "dis_max": GroupKind(launch_flat_dismax, _GROUP_WIDTH, ("dis_max",),
+                         "device_sparse"),
     "aggs": GroupKind(launch_flat_aggs, _GROUP_WIDTH, ("aggs",),
                       "device_aggs"),
     "sorted": GroupKind(launch_flat_sorted, _GROUP_WIDTH, ("sorted",),
@@ -2368,16 +2571,7 @@ class HostScorer:
             return self.eval(sub, b)
 
         if isinstance(q, MultiMatchQuery):
-            subs = []
-            for fspec in q.fields:
-                if "^" in fspec:
-                    fname, fboost = fspec.split("^")
-                    fboost = float(fboost)
-                else:
-                    fname, fboost = fspec, 1.0
-                subs.append(MatchQuery(fname, q.text, operator=q.operator,
-                                       minimum_should_match=q.minimum_should_match,
-                                       boost=fboost))
+            subs = multi_match_subqueries(q)
             if q.type in ("best_fields", "phrase", "phrase_prefix"):
                 return self.eval(DisMaxQuery(queries=subs, tie_breaker=q.tie_breaker), b)
             return self.eval(BoolQuery(should=subs, minimum_should_match=1,

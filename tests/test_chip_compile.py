@@ -191,6 +191,39 @@ def test_phrase_launch_compiles_for_v5e(one_chip, n_plans, rung):
     assert mem.argument_size_in_bytes >= 262_144 * BLOCK * 4
 
 
+@pytest.mark.parametrize("n_plans,disjuncts", [(1, 2), (4, 2), (4, 4)],
+                         ids=["alone-two", "four-two", "four-four"])
+def test_dismax_launch_compiles_for_v5e(one_chip, n_plans, disjuncts):
+    """The dis_max program at the cell `beir.bestfields`' size (100,000
+    passages of two analyzed fields, doc_pad 131,072): the dense core over an
+    accumulator a (plan, disjunct), the combine, top-k. The operands are the
+    dense launch's own, one packed plane with the plans' tie-breakers as its
+    last words, at both group widths and the widest disjunct count."""
+    from elasticsearch_tpu.common.jaxenv import compile_tag
+    from elasticsearch_tpu.ops.scoring import _get_dismax_compiled
+
+    # the triples' rung is fixed by the width: TAIL_FLOOR (256) blocks a plan
+    rows, E, H = n_plans * disjuncts, 256 * n_plans, 64
+    args = _shapes(
+        one_chip,
+        ((ROWS, BLOCK), "int32"), ((ROWS, BLOCK), "float32"),
+        ((H, DOC_PAD), "uint8"), ((DOC_PAD,), "bool"), ((2, DOC_PAD), "float32"),
+        _dense_plane(E, rows, 4, scalars=n_plans)) + [E]
+    fn = _get_dismax_compiled(n_plans, disjuncts, 10, DOC_PAD)
+    with compile_tag("dis_max"):
+        lowered = fn.lower(*args)
+        compiled = lowered.compile()
+    assert lowered.as_text().split("@", 1)[1].split(" ", 1)[0] == \
+        "jit_estpu_scoring_dismax"
+    assert "tpu_custom_call" not in compiled.as_text()  # composed, no kernel
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes >= 2 * ROWS * BLOCK * 4 + H * DOC_PAD
+    assert mem.temp_size_in_bytes < 1 << 30
+    # the head rows are added in a loop whose trip count is data, as in the
+    # dense programs: one program whatever the number of head clauses
+    assert "while" in compiled.as_text()
+
+
 @pytest.mark.parametrize("n_queries,rung", [(1, 0), (8, 0), (1, 1), (1, -1)],
                          ids=["alone-first", "eight-first", "alone-second",
                               "alone-last"])

@@ -690,9 +690,9 @@ def test_the_two_ceilings_of_the_plane_in_documents_a_segment():
     ({"bool": {"must": [{"match_phrase": {"body": "a b"}}]}},
      "non_term_subclause"),
     ({"dis_max": {"queries": [{"match_phrase": {"body": "a b"}}]}},
-     "unsupported_query:DisMaxQuery"),
+     "dismax_subquery"),
     ({"multi_match": {"query": "a b", "fields": ["body"], "type": "phrase"}},
-     None),
+     "multi_match_type"),
     ({"span_near": {"clauses": [{"span_term": {"body": "a"}},
                                 {"span_term": {"body": "b"}}],
                     "slop": 0, "in_order": True}},

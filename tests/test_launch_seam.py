@@ -78,6 +78,13 @@ def test_every_kind_a_plan_can_have_has_a_row_and_the_vocabularies_hold_it(ctx):
 
     rank = {"range": {"rank": {"gte": 10}}}
     spec = parse_search_body({"sort": [{"rank": "asc"}]}).sort[0]
+    # a dis_max plan is BM25's: under TF-IDF every disjunct takes a coord
+    bm25 = Settings.from_flat({"index.similarity.default.type": "BM25"})
+    dis_max = ex.lower_flat(
+        parse_query({"dis_max": {"queries": [{"match": {"body": "quick dog"}},
+                                             {"term": {"body": "fox"}}]}}),
+        ShardContext(ctx.searcher, ctx.mapper_service, SimilarityService(
+            bm25, mapper_service=ctx.mapper_service)), phrases=True)
     named = {ex.plan_kind(plan, tail) for plan, tail in (
         (lowered(MATCH), None),
         (lowered(FS), None),
@@ -85,6 +92,7 @@ def test_every_kind_a_plan_can_have_has_a_row_and_the_vocabularies_hold_it(ctx):
         (lowered({"constant_score": {"filter": rank}}), None),
         (lowered({"match_phrase": {"body": "quick brown"}}, phrases=True),
          None),
+        (dis_max, None),
         (lowered(MATCH), ex.aggs_tail(["rank"], [])),
         (lowered(MATCH), ex.sort_tail(spec)))}
     assert named == set(ex.GROUP_KINDS)

@@ -951,6 +951,67 @@ class TestLaunchTimeline:
         for name in ("launches", "coalesced"):
             assert kinds1["filtered"][name] == kinds0["filtered"][name] + 1
 
+    def test_a_dis_max_records_its_staging_and_its_counters(self, live):
+        """A `dis_max` of one-field OR queries rides the batcher to the
+        dis_max program: the host's staging of the group (the clauses under
+        their disjuncts' accumulators, the operand plane, its one device_put)
+        is a part of the stage span, and /_nodes/stats books the launch, the
+        plan, its disjuncts, the bytes the program reckons it reads, the
+        ladder's padding and the batcher's kind."""
+        cluster, node, rc = live
+        client = node.client()
+        # BM25, two analyzed fields, ONE segment (under TF-IDF every disjunct
+        # takes a coord and the host scorer answers)
+        client.create_index("titled", {"settings": {
+            "number_of_shards": 1, "number_of_replicas": 0,
+            "index.refresh_interval": "-1",
+            "index.similarity.default.type": "BM25"}})
+        cluster.ensure_green("titled")
+        for i in range(40):
+            client.index("titled", "doc", {
+                "title": WORDS[i % 8],
+                "txt": f"{WORDS[i % 8]} {WORDS[(i + 1) % 8]} {WORDS[(i + 3) % 8]}"},
+                id=str(i))
+        client.refresh("titled")
+
+        def stats(section):
+            resp = rc.dispatch(RestRequest(
+                method="GET", path=f"/_nodes/stats/{section}"))
+            return next(iter(resp.body["nodes"].values()))[section]
+
+        body = {"query": {"multi_match": {
+            "query": "quick brown", "type": "best_fields",
+            "fields": ["txt", "title"], "tie_breaker": 0.5}}, "size": 5}
+        _traced_search(rc, body, index="titled")  # the first sighting compiles
+        before, kinds0 = stats("search_serving"), stats("search")["batcher"]["kinds"]
+        tree = _traced_search(rc, body, index="titled")
+        after, kinds1 = stats("search_serving"), stats("search")["batcher"]["kinds"]
+        _assert_nested(tree)
+        (plan,) = _find(tree, "shard.dismax_plan")
+        (stage,) = [n for n in _find(tree, "dispatch.stage")
+                    if any(c["name"] == "shard.dismax_plan"
+                           for c in n["children"])]
+        assert stage["t0"] <= plan["t0"] and plan["t1"] <= stage["t1"] + 1e-6
+        (dispatch,) = _find(tree, "batcher.dispatch")
+        assert [c["name"] for c in dispatch["children"]] == \
+            ["dispatch.stage", "dispatch.launch", "device_pull"]
+        launch0, launch1 = before["launch"], after["launch"]
+        assert launch1["dismax"] == launch0["dismax"] + 1
+        assert launch1["dismax_searches"] == launch0["dismax_searches"] + 1
+        assert launch1["dismax_disjuncts"] == launch0["dismax_disjuncts"] + 2
+        assert launch1["dismax_blocks"] - launch0["dismax_blocks"] == 256
+        assert 0 < launch1["dismax_pad_blocks"] - launch0["dismax_pad_blocks"] \
+            < 256
+        # the accumulators' planes and the combined one, beside the postings
+        assert launch1["dismax_bytes"] - launch0["dismax_bytes"] > \
+            launch1["posting_bytes"] - launch0["posting_bytes"] > 0
+        assert launch1["operand_puts"] == launch0["operand_puts"] + 1
+        assert after["device_sparse"] == before["device_sparse"] + 1
+        assert after["host"] == before["host"]
+        for name in ("launches", "coalesced"):
+            assert kinds1["dis_max"][name] == kinds0["dis_max"][name] + 1
+        assert stats("device")["compile"]["by_family"]["dis_max"] >= 1
+
     def test_an_exact_sum_counts_its_limb_rows_and_their_bytes(self, live):
         """A sum of a long column under a terms bucket: the launch counts the
         integer limb rows it reduced, and the device ledger holds their bytes
