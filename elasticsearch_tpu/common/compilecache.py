@@ -392,8 +392,9 @@ MANIFEST_NAME = "compile_manifest.json"
 # launches take the head rows and the head-slot plane (TermBatch.head); 4: the
 # phrase launch takes the block rows it gathers as a list (phrase_operands);
 # 5: the dense launches take ONE packed operand plane and M as a literal
-# (scoring.TermBatch.plane), and a mask may be a tuple of rows
-MANIFEST_VERSION = 5
+# (scoring.TermBatch.plane), and a mask may be a tuple of rows; 6: the
+# phrase launch's params end in the slots of its line (scoring.phrase_slots)
+MANIFEST_VERSION = 6
 _MESH_RING = 4  # recent mesh plan batches kept per index
 
 

@@ -329,6 +329,14 @@ class PositionsPlane:
         held = below[self.blk_last[b0:b1] + 1] > below[self.blk_first[b0:b1]]
         return (b0 + np.flatnonzero(held)).astype(np.int32)
 
+    def rows_between(self, tid: int, lo: int, hi: int) -> int:
+        """How many of term `tid`'s block rows hold a key of a document in
+        [lo, hi): the term's whole list inside a tile's documents (all of
+        its rows over every document)."""
+        b0, b1 = self.blocks_for_term(tid)
+        return int(np.count_nonzero((self.blk_last[b0:b1] >= lo)
+                                    & (self.blk_first[b0:b1] < hi)))
+
 
 def docs_below(docs: np.ndarray, n_docs: int) -> np.ndarray:
     """int32 [n_docs + 1]: how many of `docs` (document ids under `n_docs`)

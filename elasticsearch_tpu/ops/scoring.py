@@ -320,8 +320,8 @@ class LaunchCounters:
              "unscored_plans", "launches_unscored", "unscored_bytes",
              "mask_put_bytes", "launches_fs_unscored", "fs_row_put_bytes",
              "fs_rows_resident", "fs_rows_evaluated", "exact_sum_rows",
-             "phrase", "phrase_searches", "position_bytes",
-             "position_pad_bytes", "position_list_bytes",
+             "phrase", "phrase_pair_launches", "phrase_searches",
+             "position_bytes", "position_pad_bytes", "position_list_bytes",
              "position_skip_bytes", "multiterm", "multiterm_searches",
              "multiterm_terms", "multiterm_runs", "multiterm_bytes",
              "multiterm_pad_bytes", "multiterm_field_scans",
@@ -362,10 +362,12 @@ class LaunchCounters:
         score_agg_batch_async), and the phrase program's launches, the plans
         it served (once a plan whatever its segments) and the bytes of
         position blocks its launches gathered, padding included, and the
-        padding's part of them: the quarters of slots no term fills and each
+        padding's part of them: the slots of the line no term fills and each
         term's rows up to its rung (`phrase`, `phrase_searches`,
         `position_bytes`, `position_pad_bytes`: score_phrase_batch_async,
-        execute.launch_flat_phrase); beside them the bytes of the launched
+        execute.launch_flat_phrase), and those of its launches whose line
+        held two slots, every plan a pair (`phrase_pair_launches`, a part of
+        `phrase`); beside them the bytes of the launched
         plans' WHOLE lists, every block row of every term, and the part of
         those the lead term's documents left out of the launch
         (`position_list_bytes`, `position_skip_bytes`); and the mask rows
@@ -1845,17 +1847,54 @@ def concat_pack_planes(blk_term, blk_j0, cum, starts, bases, doc_pads,
 # matching documents keep their order on the line, so top_k breaks ties as it
 # did.
 
-PHRASE_SLOTS = 4  # terms a phrase plan may hold: a quarter of the line each
-# block rows a term's quarter holds, up the ladder. Few and far apart: every
+# A launch's line is as long as its plans have terms: `slots` slots of `rows`
+# block rows, `slots` a static of the program that the launch reads from its
+# members (phrase_slots, execute.launch_flat_phrase): 2 where every plan of
+# the launch is a pair, else PHRASE_SLOTS. Two ascending lists are ONE bitonic
+# merge over a line of 2 * rows rows; four are two merges, the second over
+# 4 * rows. A pair on a line of four would merge its two lists, merge two
+# slots of sentinels, and merge the two results: the same keys in the same
+# order on the first half of a line whose second half is sentinels, so the
+# shorter line's answer is the longer's bit for bit at half the places and a
+# quarter of the merge passes' bytes. A phrase of three rides the line of four
+# (one merge tree, two shapes).
+PHRASE_SLOTS = 4  # terms a phrase plan may hold: a slot of the line each
+# block rows a term's slot holds, up the ladder. Few and far apart: every
 # rung is a program, a first sighting compiles for tens of seconds on the one
 # drainer, and a warm-up has to meet every rung (padding rows cost a merge
 # stage's share of microseconds). A term whose rows beside the lead term's
 # documents pass the last rung goes to the host.
+#
+# The LAST rung is no program where the ladder has three rungs: a plan whose
+# longest kept list rides it is cut by document ranges into tiles of the rung
+# before, a launch each (phrase_tile_rows, execute._phrase_tiles). A phrase
+# is matched inside one document, so a range of documents with the rows that
+# hold its candidates is a whole problem of its own, and a merge costs more
+# than its length (64.6 ms for a line of 4 x 32,768 rows against 9.4 for
+# 4 x 8,192 on a v5e: PERF.md section 6, PR 47): two or three launches of
+# 9.4 ms stand where one of 64.6 stood, and the eight clients of a closed
+# loop wait behind a third of the stall. A row at a tile's edge holds keys of
+# the next tile's documents too, so each launch is told the documents it
+# answers for (_P_LO, _P_HI) and counts a match there alone; the host adds
+# the tiles' totals and merges their hits as it does segments'.
 PHRASE_RUNGS = (1024, 8192, 32768)
-# columns of a phrase launch's operand plane, int32 [Q, 8]: the weight's
-# bits, the SimTables row, the number of terms; then a slot's shift a column
-_P_WEIGHT, _P_FID, _P_TERMS, _P_SHIFT = 0, 1, 2, 4
-_P_COLS = 8
+# columns of a phrase launch's operand plane, int32 [Q, 16]: the weight's
+# bits, the SimTables row, the number of terms; a slot's shift a column; then
+# the documents the plan's launch answers for, [lo, hi) (a tile's range, or
+# every document: PHRASE_ALL_DOCS)
+_P_WEIGHT, _P_FID, _P_TERMS, _P_SHIFT, _P_LO, _P_HI = 0, 1, 2, 4, 8, 9
+_P_COLS = 16
+PHRASE_ALL_DOCS = (0, int(np.iinfo(np.int32).max))
+
+
+def phrase_tile_rows() -> int | None:
+    """The block rows a slot holds in a launch of a plan whose longest kept
+    list rides the LAST rung: the rung before it, where the ladder has three
+    rungs or more (no such launch on a shorter ladder). Such a plan is cut by
+    document ranges into launches of that rung (execute.launch_flat_phrase),
+    so the last rung is no program any more: it is the most rows a term may
+    keep before the host answers."""
+    return PHRASE_RUNGS[-2] if len(PHRASE_RUNGS) > 2 else None
 
 
 def phrase_rung(blocks: int) -> int | None:
@@ -1971,13 +2010,15 @@ def _phrase_impl(pos_keys, caches, modes, qplane, blk, *, k: int,
     Q = qplane.shape[0]
     mark_base = positions_mark_base(pos_bits)
     pos_mask = (1 << pos_bits) - 1
+    slots = blk.shape[1]  # the launch's line: 2 (every plan a pair) or 4
     with jax.named_scope("gather_positions"):
-        shift = qplane[:, _P_SHIFT: _P_SHIFT + PHRASE_SLOTS, None, None]
-        keys = pos_keys[blk]  # [Q, SLOTS, rows, B]
+        shift = qplane[:, _P_SHIFT: _P_SHIFT + slots, None, None]
+        keys = pos_keys[blk]  # [Q, slots, rows, B]
         keys = jnp.where(keys == POS_SENTINEL, POS_SENTINEL, keys + shift)
     with jax.named_scope("phrase_join"):
-        keys = _bitonic_merge(_pair_up(keys))
-        keys = _bitonic_merge(_pair_up(keys))[:, 0]  # [Q, SLOTS * rows, B]
+        for _ in range(slots.bit_length() - 1):  # a merge a halving of the lists
+            keys = _bitonic_merge(_pair_up(keys))
+        keys = keys[:, 0]  # [Q, slots * rows, B]
         n = qplane[:, _P_TERMS, None, None]
         # markers sort behind every position and take no part in a run
         word = (keys & pos_mask) < mark_base
@@ -1998,7 +2039,12 @@ def _phrase_impl(pos_keys, caches, modes, qplane, blk, *, k: int,
         # a live document's last key is a marker that carries its norm byte;
         # a deleted one's carries POS_DEAD_CODE and matches nothing
         nb = ((keys & pos_mask) - mark_base) >> POS_MARK_SHIFT
-        match = last & (freq > 0) & (nb < POS_DEAD_CODE)
+        # a tile answers for its own documents alone: a row at its edge holds
+        # keys of the tile beside it too, whose launch counts those
+        doc = keys >> pos_bits
+        mine = (doc >= qplane[:, _P_LO, None, None]) \
+            & (doc < qplane[:, _P_HI, None, None])
+        match = last & (freq > 0) & (nb < POS_DEAD_CODE) & mine
     with jax.named_scope("top_k"):
         fid = qplane[:, _P_FID]
         cv = _lut256(caches[fid], nb)
@@ -2009,15 +2055,20 @@ def _phrase_impl(pos_keys, caches, modes, qplane, blk, *, k: int,
                         f / (f + cv), jnp.sqrt(f) * cv)
         scores = jnp.where(match, w[:, None, None] * tfn, -jnp.inf)
         top_scores, idx = jax.lax.top_k(scores.reshape(Q, -1), k)
-        top_docs = jnp.take_along_axis(
-            (keys >> pos_bits).reshape(Q, -1), idx, axis=1)
+        top_docs = jnp.take_along_axis(doc.reshape(Q, -1), idx, axis=1)
         return top_scores, top_docs, match.sum(axis=(1, 2), dtype=jnp.int32)
 
 
-def _get_phrase_compiled(n_queries: int, rows: int, k: int, pos_bits: int):
+def _get_phrase_compiled(n_queries: int, rows: int, k: int, pos_bits: int,
+                         slots: int):
+    """The phrase program of a launch of `n_queries` plans on a line of
+    `slots` (2 or PHRASE_SLOTS) times `rows` block rows. `slots` is in the
+    key and in the warm params though the program reads it from `blk`'s shape:
+    jit would specialise on the shape anyway, and a restart's replay has to
+    know which of the two lines a launch rode."""
     import jax
 
-    key = ("phrase", n_queries, rows, k, pos_bits)
+    key = ("phrase", n_queries, rows, k, pos_bits, slots)
     fn = _compiled_cache.get(key)
     if fn is None:
         def wrapper(pos_keys, caches, modes, qplane, blk):
@@ -2029,19 +2080,32 @@ def _get_phrase_compiled(n_queries: int, rows: int, k: int, pos_bits: int):
     return fn
 
 
+def phrase_slots(entries: list) -> int:
+    """The slots of the line a launch of these plans rides: 2 where every
+    plan holds two terms, else PHRASE_SLOTS (a phrase of three keeps the line
+    of four). Read from the launch's members alone."""
+    return 2 if all(len(terms) == 2 for _w, _fid, terms, _docs in entries) \
+        else PHRASE_SLOTS
+
+
 def phrase_operands(entries: list, n_queries: int, rows: int, pad_row: int):
     """The operands of one phrase launch: the plane of columns _P_* and the
-    block rows each slot gathers, int32 [Q, PHRASE_SLOTS, rows]. `entries`
-    holds a plan's (weight f32, SimTables row, [(block rows ascending, block
-    rows of the term's whole list, shift) a term]); places past a term's
-    rows, slots past a plan's terms and plans past the entries name
-    `pad_row`, the plane's row of POS_SENTINEL, so they match nothing."""
+    block rows each slot gathers, int32 [Q, slots, rows], `slots` the
+    entries' own (phrase_slots). `entries` holds a plan's (weight f32,
+    SimTables row, [(block rows ascending, block rows of the term's whole
+    list, shift) a term], (lo, hi): the documents the launch answers for, a
+    tile's or PHRASE_ALL_DOCS); places past a term's rows, slots past a
+    plan's terms and plans past the entries name `pad_row`, the plane's row
+    of POS_SENTINEL, so they match nothing (a plan past the entries answers
+    for no document at all). The plane keeps its PHRASE_SLOTS shift columns
+    on either line; those past the line's slots are not read."""
     qplane = np.zeros((n_queries, _P_COLS), np.int32)
-    blk = np.full((n_queries, PHRASE_SLOTS, rows), pad_row, np.int32)
-    for q, (w, fid, terms) in enumerate(entries):
+    blk = np.full((n_queries, phrase_slots(entries), rows), pad_row, np.int32)
+    for q, (w, fid, terms, (lo, hi)) in enumerate(entries):
         qplane[q, _P_WEIGHT] = np.float32(w).view(np.int32)
         qplane[q, _P_FID] = fid
         qplane[q, _P_TERMS] = len(terms)
+        qplane[q, _P_LO], qplane[q, _P_HI] = lo, hi
         for i, (named, _whole, shift) in enumerate(terms):
             blk[q, i, : len(named)] = named
             qplane[q, _P_SHIFT + i] = shift
@@ -2052,29 +2116,32 @@ def score_phrase_batch_async(plane: PositionsPlane, sim, entries: list,
                              n_queries: int, rows: int, k: int,
                              note_t0: float | None = None):
     """Launch the phrase program over one segment's positions plane for the
-    plans of `entries` (phrase_operands) at a width of `n_queries`, a quarter
-    of `rows` block rows a term; returns the device arrays (scores [Q, k],
-    docs [Q, k], totals [Q]) without syncing. `sim` is the SimTables whose
+    plans of `entries` (phrase_operands) at a width of `n_queries`, a slot
+    of `rows` block rows a term on a line of phrase_slots(entries) slots;
+    returns the device arrays (scores [Q, k], docs [Q, k], totals [Q])
+    without syncing. `sim` is the SimTables whose
     rows the plane names. `note_t0`: when the host began to assemble this
     launch's operands; from there to the end of their one device_put is the
     span `shard.phrase_plan` (a note inside the running `dispatch.stage`)."""
-    params = (n_queries, rows, min(k, PHRASE_SLOTS * rows * BLOCK),
-              plane.pos_bits)
-    fn = _get_phrase_compiled(*params)
     operands = phrase_operands(entries, n_queries, rows,
                                len(plane.host_keys) - 1)
+    slots = operands[1].shape[1]
+    params = (n_queries, rows, min(k, slots * rows * BLOCK), plane.pos_bits,
+              slots)
+    fn = _get_phrase_compiled(*params)
     args = (plane.keys, sim.caches, sim.modes, *_put_operands(*operands))
     if note_t0 is not None:
         _tracing.note("shard.phrase_plan", note_t0)
-    # what the launch gathers: PHRASE_SLOTS quarters of `rows` block rows a
-    # plan, BLOCK keys of 4 B each, padding included; the places no term named
-    # (the sentinel row, gathered again and again) are the padding. What the
-    # terms named is what the lead term left of their whole lists
-    launched = n_queries * PHRASE_SLOTS * rows
-    terms = [term for _w, _fid, terms in entries for term in terms]
+    # what the launch gathers: the line's `slots` slots of `rows` block rows
+    # a plan, BLOCK keys of 4 B each, padding included; the places no term
+    # named (the sentinel row, gathered again and again) are the padding.
+    # What the terms named is what the lead term left of their whole lists
+    launched = n_queries * slots * rows
+    terms = [term for _w, _fid, terms, _docs in entries for term in terms]
     named = sum(len(rows_named) for rows_named, _whole, _shift in terms)
     listed = sum(whole for _rows_named, whole, _shift in terms)
-    LAUNCHES.bump(phrase=1, position_bytes=launched * BLOCK * 4,
+    LAUNCHES.bump(phrase=1, phrase_pair_launches=int(slots == 2),
+                  position_bytes=launched * BLOCK * 4,
                   position_pad_bytes=(launched - named) * BLOCK * 4,
                   position_list_bytes=listed * BLOCK * 4,
                   position_skip_bytes=(listed - named) * BLOCK * 4)

@@ -1374,21 +1374,22 @@ def _merge_launches(ctx: ShardContext, n_docs: list, members: list,
     apart on a segment (the phrase and dis_max groups: by rung): `members`
     holds (segment index, plan indexes) a launch, `pulled` its (scores, docs,
     totals), whose rows past the launch's members are its ladder's padding. A
-    plan no launch of a segment names matches nothing there."""
+    plan no launch of a segment names matches nothing there; one that several
+    name (a long phrase's tiles, each over its own documents) has their
+    totals added and their hits merged like those of as many segments."""
     totals = np.zeros(Q, dtype=np.int64)
-    n_seg = len(n_docs)
     kk = max(k, 1)
-    scores = np.full((n_seg, Q, kk), -np.inf, np.float32)
-    docs = np.zeros((n_seg, Q, kk), np.int64)
+    bases = ctx.searcher.bases
+    hits = []
     for (si, qis), (s, d, tq) in zip(members, pulled):
         n = len(qis)
-        scores[si, qis, : s.shape[1]] = s[:n]
-        docs[si, qis, : s.shape[1]] = d[:n]
+        scores = np.full((Q, kk), -np.inf, np.float32)
+        docs = np.zeros((Q, kk), np.int64)
+        scores[qis, : s.shape[1]] = s[:n]
+        docs[qis, : s.shape[1]] = d[:n]
         totals[qis] += tq[:n]
-    seg_hits = [_segment_hits(scores[si], docs[si], n_docs[si], base)
-                for si, base in enumerate(ctx.searcher.bases)]
-    return _merge_seg_hits(seg_hits, totals, Q, k,
-                           breaker=ctx.breaker("request"))
+        hits.append(_segment_hits(scores, docs, n_docs[si], bases[si]))
+    return _merge_seg_hits(hits, totals, Q, k, breaker=ctx.breaker("request"))
 
 
 def _merge_seg_hits(seg_hits, totals, Q: int, k: int,
@@ -1923,11 +1924,11 @@ def launch_flat_filtered(plans: list[FlatPlan], ctx: ShardContext, k: int,
 
 def _phrase_launches(by_rung: dict):
     """((field, rung), plans) a launch of one segment: the plans of the first
-    rung together (_group_width: 1 or 4 a launch), those of a longer rung one
-    a launch. A merge costs the device what its lines hold, so four long
-    lines in one launch save a dispatch and nothing else, and cost a program
-    of their own that a warm-up seldom meets and four times the temporaries
-    (3.7 GB a plan at the last rung)."""
+    rung together (_group_width: 1 or 4 a launch), those of a longer rung,
+    a long phrase's tiles among them, one a launch. A merge costs the device
+    what its lines hold, so four long lines in one launch save a dispatch and
+    nothing else, and cost a program of their own that a warm-up seldom meets
+    and four times the temporaries (0.86 GB a plan at the second rung)."""
     from ..ops.scoring import PHRASE_RUNGS
 
     for key, group in by_rung.items():
@@ -1936,6 +1937,38 @@ def _phrase_launches(by_rung: dict):
         else:
             for member in group:
                 yield key, [member]
+
+
+def _phrase_tiles(plane, named: dict, below: np.ndarray, n_docs: int,
+                  tile_rows: int):
+    """A plan whose longest kept list passes `tile_rows` block rows, cut by
+    document ranges: [(lo, hi, {term id: block rows})], every term's rows of
+    a tile within `tile_rows`, or None where no cut of a few tiles does it
+    (the host serves). A phrase is matched inside one document, so the
+    documents [lo, hi) with the rows that hold their candidates
+    (PositionsPlane.rows_holding over the candidates of the range alone) are
+    a whole problem: the tiles' totals add up and their hits merge like
+    segments'. A row at a tile's edge may hold keys of the next tile's
+    documents, so the program counts a match inside [lo, hi) alone
+    (scoring._phrase_impl, `mine`). The cuts fall on the first document of
+    every (rows / tiles)-th row of the longest list; more tiles are tried
+    where a shorter list crowds one range."""
+    longest = max(named.values(), key=len)
+    least = -(-len(longest) // tile_rows)
+    for n_tiles in range(least, 2 * least + 1):
+        firsts = longest[np.arange(1, n_tiles) * len(longest) // n_tiles]
+        cuts = [0, *plane.blk_first[firsts].tolist(), n_docs]
+        tiles = []
+        for lo, hi in zip(cuts, cuts[1:]):
+            if below[hi] == below[lo]:
+                continue  # no candidate in the range
+            inside = np.clip(below, below[lo], below[hi])
+            tiles.append((lo, hi, {tid: plane.rows_holding(tid, inside)
+                                   for tid in named}))
+        if all(len(rows) <= tile_rows for _lo, _hi, by_term in tiles
+               for rows in by_term.values()):
+            return tiles
+    return None
 
 
 def launch_flat_phrase(plans: list[FlatPlan], ctx: ShardContext, k: int,
@@ -1953,16 +1986,28 @@ def launch_flat_phrase(plans: list[FlatPlan], ctx: ShardContext, k: int,
     together at the group's width (_group_width: 1 or 4 plans a launch, rows
     past them matching nothing), those of a longer rung one a launch
     (_phrase_launches), so a rare phrase does not ride a head term's line,
-    and a head term beside a rare one rides the first rung. NO pull: returns
-    (device outputs a launch, finish), or None where a segment's positions do
-    not fit the plane's keys or the rows a term keeps outgrow the last rung
-    (the host serves every plan). `finish(pulled)` takes the outputs on the
+    and a head term beside a rare one rides the first rung. A launch's line
+    is as long as its members have terms (scoring.phrase_slots reads the
+    entries it is handed, nothing else): two slots of the rung where every
+    member is a pair, one merge over half the places, else the four a plan
+    may hold; a long rung launches one plan, so a pair there is a pair's
+    program, and on the first rung a pair beside a longer phrase rides the
+    longer one's line (to launch them apart would add a launch to a batch for
+    a rung that holds a fourteenth of the device's time). A plan whose
+    longest kept list passes the second rung is cut by document ranges into
+    tiles of that rung, a launch each (_phrase_tiles; scoring, PHRASE_RUNGS:
+    the last rung is no program), every tile told the documents it answers
+    for. NO pull: returns (device outputs a launch, finish), or None where a
+    segment's positions do not fit the plane's keys, the rows a term keeps
+    outgrow the last rung or no cut fits its tiles (the host serves every
+    plan). `finish(pulled)` takes the outputs on the
     host (the batch's one device_get: _run_flat_groups) and returns TopDocs a
     plan. The host's share, the rows each term keeps and the operands' one
     device_put, is the span `shard.phrase_plan` inside `dispatch.stage`."""
     from ..ops.device_index import (docs_below, ensure_positions,
                                     ensure_sim_tables, packed_for)
-    from ..ops.scoring import (LAUNCHES, phrase_rung,
+    from ..ops.scoring import (LAUNCHES, PHRASE_ALL_DOCS, PHRASE_RUNGS,
+                               phrase_rung, phrase_tile_rows,
                                score_phrase_batch_async)
 
     Q = len(plans)
@@ -1995,11 +2040,17 @@ def launch_flat_phrase(plans: list[FlatPlan], ctx: ShardContext, k: int,
             rung = phrase_rung(max(len(rows) for rows in named.values()))
             if rung is None:
                 return None
-            by_rung.setdefault((ph.field, rung), []).append((qi, (
-                w, sim.fid[ph.field],
-                [(named[tid], int(plane.blk_start[tid + 1]
-                                  - plane.blk_start[tid]), shift)
-                 for tid, shift in zip(tids, shifts[qi])])))
+            tiles = [(*PHRASE_ALL_DOCS, named)]
+            if rung == PHRASE_RUNGS[-1] and phrase_tile_rows():
+                rung = phrase_tile_rows()
+                tiles = _phrase_tiles(plane, named, below, seg.doc_count, rung)
+                if tiles is None:
+                    return None
+            for lo, hi, rows in tiles:
+                by_rung.setdefault((ph.field, rung), []).append((qi, (
+                    w, sim.fid[ph.field],
+                    [(rows[tid], plane.rows_between(tid, lo, hi), shift)
+                     for tid, shift in zip(tids, shifts[qi])], (lo, hi))))
         staged.append((packed, sim, planes, by_rung, t0))
     launched = []
     members = []  # a launch: (segment index, plan indexes)

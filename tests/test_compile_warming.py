@@ -19,6 +19,7 @@ warmer pool, and the observed mix then serves under
 
 from __future__ import annotations
 
+import json
 import os
 import time
 import zlib
@@ -245,10 +246,10 @@ class TestRegistryWarm:
         path = tmp_path / MANIFEST_NAME
         REGISTRY.save_manifest(str(path))
         payload = _json.loads(path.read_text())
-        assert payload["version"] == 5 and len(payload["specs"]) == 1
+        assert payload["version"] == 6 and len(payload["specs"]) == 1
         REGISTRY.reset()
         assert REGISTRY.load_manifest(str(path)) == 1
-        payload["version"] = 4  # before the dense launches took one packed plane
+        payload["version"] = 5  # before a phrase launch's params held its slots
         path.write_text(_json.dumps(payload))
         REGISTRY.reset()
         LADDERS.reset()
@@ -315,10 +316,12 @@ QUERIES = [
      "sort": [{"n": "desc"}], "size": 5},
     {"query": {"range": {"n": {"lt": 40}}}, "size": 0, "request_cache": False,
      "aggs": {"n": {"stats": {"field": "n"}}}},
-    # an exact phrase: the phrase program's launch is recorded and replayed
-    # (scoring.phrase); the restarted node faults the positions plane in on
-    # the path, which compiles nothing
+    # exact phrases: the phrase program's launches are recorded and replayed
+    # (scoring.phrase), a pair's on its line of two slots and a phrase of
+    # three's on the line of four; the restarted node faults the positions
+    # plane in on the path, which compiles nothing
     {"query": {"match_phrase": {"body": "alpha beta"}}, "size": 10},
+    {"query": {"match_phrase": {"body": "alpha beta beta"}}, "size": 10},
 ]
 
 
@@ -368,6 +371,13 @@ class TestRestartPersistence:
             node.close()  # persists the manifest under path.data
         manifest = os.path.join(data, MANIFEST_NAME)
         assert os.path.exists(manifest)
+        # both lines of the phrase program are in it: the params' last word
+        # is the launch's slot count, and `blk` is [1, slots, rows]
+        with open(manifest) as f:
+            phrase = [s for s in json.load(f)["specs"]
+                      if s["site"] == "scoring.phrase"]
+        assert sorted((s["params"][-1], s["args"][-1]["s"][1])
+                      for s in phrase) == [(2, 2), (4, 4)]
 
         # simulated process restart: every in-process executable and all
         # warm/ladder state is gone; only path.data survives

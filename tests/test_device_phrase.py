@@ -7,7 +7,10 @@ plain reference (`benchmark/queries/phrase_terms.py` `expected`: numpy over the
 token stream, nothing of the program): totals and ids in order exactly, scores to
 1e-6 relative. A launch names, of every term's block rows, those that hold a
 document of the phrase's rarest term: its answer is the whole lists' bit for bit,
-the rung follows the rows it names, and the counters say what it left out. The
+the rung follows the rows it names, and the counters say what it left out. A
+launch whose plans are all pairs rides a line of two slots, one merge: its answer
+is the line of four's bit for bit, alone on every rung, four in a launch, of one
+term twice, over a deleted document's marker and across block rows. The
 forms that stay on the host reach it under a named reason, the plane is faulted
 in by the first phrase and not before, and every counter the benchmark reads
 moves as stated."""
@@ -86,6 +89,41 @@ def _both(ctx, query, k=10):
     return dev, search_shard(ctx, query, k, use_device=False)
 
 
+def _line_of_four(entries):
+    """scoring.phrase_slots as it was when every launch rode four slots: the
+    program every two-slot launch is held to, on the same plans."""
+    return scoring.PHRASE_SLOTS
+
+
+LINES = ["own", "four"]  # a launch's own line, and the line of four for all
+
+
+def _ride(monkeypatch, line):
+    if line == "four":
+        monkeypatch.setattr(scoring, "phrase_slots", _line_of_four)
+
+
+def _same_on_the_line_of_four(monkeypatch, ctx, queries, got, k=10):
+    """The plans of `queries` launched again with every line four slots long:
+    no launch of two slots, and totals, documents and float32 scores are
+    `got`'s bit for bit."""
+    with monkeypatch.context() as m:
+        m.setattr(scoring, "phrase_slots", _line_of_four)
+        before = scoring.LAUNCHES.snapshot()
+        four = search_shard_batch(ctx, queries, k)
+        after = scoring.LAUNCHES.snapshot()
+    assert after["phrase"] > before["phrase"]
+    assert after["phrase_pair_launches"] == before["phrase_pair_launches"]
+    for td, want in zip(four, got):
+        assert td.total == want.total
+        assert td.hits == want.hits
+
+
+def _slots_of(texts):
+    """The slots of the line a launch of these phrases rides."""
+    return 2 if all(len(t.split()) == 2 for t in texts) else scoring.PHRASE_SLOTS
+
+
 def _named_rows(seg, plane, words, field="body"):
     """The block rows a launch of the phrase `words` names, a list a term, by
     the rule and by hand: the lead is the term of the fewest postings, and a
@@ -142,13 +180,23 @@ def _pick(planted, n_terms):
     return out[:6]
 
 
-@pytest.mark.parametrize("n_terms", [2, 3, 4])
-def test_device_answers_as_the_host_and_the_plain_reference(planted, n_terms):
+@pytest.mark.parametrize("n_terms,line", [(2, "own"), (2, "four"), (3, "own"),
+                                          (4, "own")])
+def test_device_answers_as_the_host_and_the_plain_reference(
+        planted, monkeypatch, n_terms, line):
     corpus, ref, ctx = planted
     fam = registry.module("queries", "phrase_terms")
+    _ride(monkeypatch, line)
     for terms in _pick(planted, n_terms):
-        dev, host = _both(ctx, _phrase(" ".join(word(t) for t in terms)))
+        query = _phrase(" ".join(word(t) for t in terms))
+        pairs = scoring.LAUNCHES.snapshot()["phrase_pair_launches"]
+        dev, host = _both(ctx, query)
         _same(dev, host)
+        # a pair alone rides two slots, and answers as on the line of four
+        assert scoring.LAUNCHES.snapshot()["phrase_pair_launches"] - pairs == \
+            int(n_terms == 2 and line == "own")
+        if n_terms == 2 and line == "own":
+            _same_on_the_line_of_four(monkeypatch, ctx, [query], [dev])
         scores, matched = fam.expected(ref, {"terms": list(terms)})
         total, ranked = ref.top(scores, matched, 10)
         assert dev.total == total > 0
@@ -164,22 +212,38 @@ def test_device_answers_as_the_host_and_the_plain_reference(planted, n_terms):
                                    rtol=1e-6)
 
 
-def test_head_term_bigrams_answer_as_the_host(planted):
+@pytest.mark.parametrize("line", LINES)
+def test_head_term_bigrams_answer_as_the_host(planted, monkeypatch, line):
+    """The nine ordered pairs of the three top terms, a term twice among
+    them: on the line of two slots and on the line of four."""
     corpus, ref, ctx = planted
     head = [int(t) for t in ref.by_df[:3]]
+    _ride(monkeypatch, line)
     for a in head:
         for b in head:
-            _same(*_both(ctx, _phrase(f"{word(a)} {word(b)}")))
+            query = _phrase(f"{word(a)} {word(b)}")
+            pairs = scoring.LAUNCHES.snapshot()["phrase_pair_launches"]
+            dev, host = _both(ctx, query)
+            _same(dev, host)
+            assert scoring.LAUNCHES.snapshot()["phrase_pair_launches"] - pairs \
+                == int(line == "own")
+            if line == "own":
+                _same_on_the_line_of_four(monkeypatch, ctx, [query], [dev])
 
 
+@pytest.mark.parametrize("mix", ["pairs", "mixed"])
 @pytest.mark.parametrize("n_plans", [1, 4, 5])
-def test_a_batch_launches_at_both_widths(planted, n_plans):
+def test_a_batch_launches_at_both_widths(planted, monkeypatch, n_plans, mix):
     """1 plan launches alone, 4 together, 5 as 4 and 1 (_GROUP_WIDTH): each
-    plan's answer is what it is alone."""
+    plan's answer is what it is alone. A launch of pairs alone rides a line of
+    two slots, four of them as one; a pair beside a phrase of three or four
+    rides their line of four, and the pair left over after such a launch its
+    own line of two."""
     corpus, ref, ctx = planted
-    texts = [" ".join(word(t) for t in terms)
-             for n in (2, 3, 4) for terms in _pick(planted, n)][:n_plans]
-    assert len(texts) == n_plans
+    lengths = (2, 2, 2, 2, 2) if mix == "pairs" else (3, 2, 4, 3, 2)
+    picked = {n: [" ".join(word(t) for t in terms) for terms in _pick(planted, n)]
+              for n in set(lengths)}
+    texts = [picked[n].pop(0) for n in lengths[:n_plans]]
     queries = [_phrase(t) for t in texts]
     before = scoring.LAUNCHES.snapshot()
     got = search_shard_batch(ctx, queries, 10)
@@ -187,9 +251,14 @@ def test_a_batch_launches_at_both_widths(planted, n_plans):
     assert after["phrase_searches"] - before["phrase_searches"] == n_plans
     assert after["phrase"] - before["phrase"] == (2 if n_plans == 5 else 1)
     rows = scoring.PHRASE_RUNGS[0]
-    width = {1: 1, 4: 4, 5: 5}[n_plans]
+    # (plans a launch at its width, the slots of its line)
+    shapes = [(1 if len(group) == 1 else 4, _slots_of(group))
+              for group in (texts[:4], texts[4:]) if group]
+    assert after["phrase_pair_launches"] - before["phrase_pair_launches"] == \
+        sum(slots == 2 for _w, slots in shapes)
+    gathered = sum(w * slots for w, slots in shapes) * rows
     assert after["position_bytes"] - before["position_bytes"] == \
-        width * scoring.PHRASE_SLOTS * rows * 128 * 4
+        gathered * 128 * 4
     # the padding is every row of those that no term of a plan named, and
     # what the plans named is what the lead term left of their whole lists
     (seg,) = ctx.searcher.segments
@@ -198,19 +267,22 @@ def test_a_batch_launches_at_both_widths(planted, n_plans):
                 for rows in _named_rows(seg, plane, text.split()))
     whole = sum(_whole_rows(seg, plane, text.split()) for text in texts)
     assert after["position_pad_bytes"] - before["position_pad_bytes"] == \
-        (width * scoring.PHRASE_SLOTS * rows - named) * 128 * 4
+        (gathered - named) * 128 * 4
     assert after["position_list_bytes"] - before["position_list_bytes"] == \
         whole * 512
     assert after["position_skip_bytes"] - before["position_skip_bytes"] == \
         (whole - named) * 512
     for q, td in zip(queries, got):
         _same(td, search_shard(ctx, q, 10, use_device=False))
+    _same_on_the_line_of_four(monkeypatch, ctx, queries, got)
 
 
+@pytest.mark.parametrize("line", LINES)
 def test_longer_lists_ride_longer_rungs_alone_and_the_longest_go_to_the_host(
-        planted, monkeypatch):
-    """With the ladder cut down to 2 / 4 / 8 block rows a quarter the corpus'
-    own pairs meet every rung, and the rung follows the rows the lead term
+        planted, monkeypatch, line):
+    """With the ladder cut down to 2 / 4 / 8 block rows a slot the corpus'
+    own pairs meet every rung, each on its line of two slots and on the line
+    of four (bit for bit the same answers), and the rung follows the rows the lead term
     LEAVES, not the lists: plans of the first rung launch together, those of
     a longer rung one a launch, the top term beside a rare one rides the
     first rung though its whole list passes the last, and only a pair whose
@@ -218,6 +290,8 @@ def test_longer_lists_ride_longer_rungs_alone_and_the_longest_go_to_the_host(
     the host's."""
     corpus, ref, ctx = planted
     monkeypatch.setattr(scoring, "PHRASE_RUNGS", (2, 4, 8))
+    _ride(monkeypatch, line)
+    slots = 2 if line == "own" else scoring.PHRASE_SLOTS
     (seg,) = ctx.searcher.segments
     plane = ensure_positions(seg, packed_for(seg), "body")
     words = [word(int(t)) for t in ref.by_df[:24]]
@@ -227,16 +301,43 @@ def test_longer_lists_ride_longer_rungs_alone_and_the_longest_go_to_the_host(
             kept = max(len(rows) for rows in _named_rows(seg, plane, [a, b]))
             by_rung.setdefault(scoring.phrase_rung(kept), []).append(f"{a} {b}")
     assert set(by_rung) == {None, 2, 4, 8}
-    for rung, launches in ((2, 1), (4, 2), (8, 2)):
+    for rung in (2, 4, 8):
         queries = [_phrase(t) for t in by_rung[rung][:2]]
         before = scoring.LAUNCHES.snapshot()
         got = search_shard_batch(ctx, queries, 10)
         after = scoring.LAUNCHES.snapshot()
-        assert after["phrase"] - before["phrase"] == launches
+        launches = after["phrase"] - before["phrase"]
+        if rung == 8:
+            # the last rung is no launch: a list that long is cut by document
+            # ranges into tiles of the rung before, two or three a plan here
+            assert 4 <= launches <= 6
+            rows = 4
+        else:
+            assert launches == (1 if rung == 2 else 2)
+            rows = rung
+        assert after["phrase_pair_launches"] - before["phrase_pair_launches"] \
+            == (launches if line == "own" else 0)
         assert after["position_bytes"] - before["position_bytes"] == \
-            (4 if rung == 2 else 2) * scoring.PHRASE_SLOTS * rung * 128 * 4
+            (4 if rung == 2 else launches) * slots * rows * 128 * 4
+        assert after["phrase_searches"] - before["phrase_searches"] == 2
         for q, td in zip(queries, got):
             _same(td, search_shard(ctx, q, 10, use_device=False))
+        if line == "own":
+            _same_on_the_line_of_four(monkeypatch, ctx, queries, got)
+        if rung == 8:
+            # a ladder of two rungs cuts nothing: the same plans, one launch
+            # each at eight rows a slot, answer bit for bit as the tiles did
+            with monkeypatch.context() as m:
+                m.setattr(scoring, "PHRASE_RUNGS", (2, 8))
+                before = scoring.LAUNCHES.snapshot()
+                whole = search_shard_batch(ctx, queries, 10)
+                after = scoring.LAUNCHES.snapshot()
+            assert after["phrase"] - before["phrase"] == 2
+            assert after["position_bytes"] - before["position_bytes"] == \
+                2 * slots * 8 * 128 * 4
+            for td, want in zip(whole, got):
+                assert td.total == want.total
+                assert td.hits == want.hits
     # the top term's list alone passes the last rung; beside a rare term the
     # launch names a row or two of it and rides the first
     top, rare = words[0], word(int(ref.by_df[ref.n_present - 1]))
@@ -249,7 +350,7 @@ def test_longer_lists_ride_longer_rungs_alone_and_the_longest_go_to_the_host(
         _same(*_both(ctx, _phrase(text)))
         after = scoring.LAUNCHES.snapshot()
         assert after["position_bytes"] - before["position_bytes"] == \
-            scoring.PHRASE_SLOTS * 2 * 128 * 4
+            slots * 2 * 128 * 4
         assert after["position_skip_bytes"] - before["position_skip_bytes"] == \
             (b1 - b0 + 1 - sum(map(len, named))) * 512 > 0
     # two head terms keep every row of each other: past the last rung, so the
@@ -262,6 +363,87 @@ def test_longer_lists_ride_longer_rungs_alone_and_the_longest_go_to_the_host(
         (before["phrase"], before["phrase_searches"])
     for q, td in zip(queries, got):
         _same(td, search_shard(ctx, q, 10, use_device=False), rtol=0)
+
+
+def _head_phrases(planted, n_terms):
+    """Phrases of `n_terms` of the corpus' four most frequent terms, whose
+    lists keep each other's rows: windows of the documents' own tokens that
+    hold nothing else (they match somewhere), a term twice among them."""
+    corpus, ref, _ctx = planted
+    head = [int(t) for t in ref.by_df[:4]]
+    ends = np.cumsum(corpus.lengths)
+    of_head = np.isin(corpus.tokens, head)
+    run = np.convolve(of_head, np.ones(n_terms, int), "valid") == n_terms
+    texts = []
+    for lo in np.flatnonzero(run):
+        doc = int(np.searchsorted(ends, lo, "right"))
+        if lo + n_terms <= ends[doc]:
+            texts.append(" ".join(
+                word(int(t)) for t in corpus.tokens[lo: lo + n_terms]))
+    texts = list(dict.fromkeys(texts))
+    assert len(texts) >= 4
+    return texts[:6]
+
+
+@pytest.mark.parametrize("n_terms,line", [(2, "own"), (2, "four"), (3, "own"),
+                                          (4, "own")])
+def test_a_list_past_the_second_rung_is_cut_into_tiles_by_document_ranges(
+        planted, monkeypatch, n_terms, line):
+    """With the ladder cut to 1 / 2 / 32 block rows a slot, a phrase of head
+    terms keeps more rows than a launch of the second rung holds and is cut
+    by document ranges into tiles of two rows a slot, one launch each: more
+    launches than plans, every one at the tile's shape, a pair's on its line
+    of two slots. Totals, documents and float32 scores are the host's, and
+    bit for bit those of ONE launch of the whole lists (a ladder of two rungs
+    cuts nothing). A row at a tile's edge holds keys of both tiles'
+    documents: with every tile answering for every document some phrase here
+    counts a document twice, so the range each tile answers for does work."""
+    corpus, ref, ctx = planted
+    monkeypatch.setattr(scoring, "PHRASE_RUNGS", (1, 2, 32))
+    _ride(monkeypatch, line)
+    slots = 2 if (n_terms == 2 and line == "own") else scoring.PHRASE_SLOTS
+    texts = _head_phrases(planted, n_terms)
+    tiled = []
+    for text in texts:
+        before = scoring.LAUNCHES.snapshot()
+        dev, host = _both(ctx, _phrase(text))
+        after = scoring.LAUNCHES.snapshot()
+        _same(dev, host)
+        assert dev.total > 0
+        launches = after["phrase"] - before["phrase"]
+        assert launches >= 2
+        assert after["phrase_pair_launches"] - before["phrase_pair_launches"] \
+            == (launches if slots == 2 else 0)
+        gathered = after["position_bytes"] - before["position_bytes"]
+        assert gathered == launches * slots * 2 * 512
+        named = gathered - (after["position_pad_bytes"]
+                            - before["position_pad_bytes"])
+        listed = after["position_list_bytes"] - before["position_list_bytes"]
+        skipped = after["position_skip_bytes"] - before["position_skip_bytes"]
+        assert listed - skipped == named > 0
+        tiled.append(dev)
+    assert max(td.total for td in tiled) > 10  # more matches than one page
+    with monkeypatch.context() as m:
+        m.setattr(scoring, "PHRASE_RUNGS", (1, 32))
+        for text, dev in zip(texts, tiled):
+            before = scoring.LAUNCHES.snapshot()
+            whole, _host = _both(ctx, _phrase(text))
+            after = scoring.LAUNCHES.snapshot()
+            assert after["phrase"] - before["phrase"] == 1
+            assert whole.total == dev.total
+            assert whole.hits == dev.hits  # ids and float32 scores, bit for bit
+    # every tile made to answer for every document: a document at an edge is
+    # counted by both tiles whose rows hold its keys
+    operands = scoring.phrase_operands
+
+    def for_every_document(entries, *shape):
+        return operands([(w, fid, terms, scoring.PHRASE_ALL_DOCS)
+                         for w, fid, terms, _docs in entries], *shape)
+
+    monkeypatch.setattr(scoring, "phrase_operands", for_every_document)
+    over = [search_shard(ctx, _phrase(text), 10, use_device=True).total - td.total
+            for text, td in zip(texts, tiled)]
+    assert min(over) >= 0 and max(over) > 0
 
 
 def _phrases_of_a_head_term(planted, n_terms):
@@ -289,16 +471,20 @@ def _phrases_of_a_head_term(planted, n_terms):
 
 
 @pytest.mark.parametrize("ladder", [None, (2, 8, 32)], ids=["whole", "cut"])
-@pytest.mark.parametrize("n_terms", [2, 3, 4])
-def test_the_lead_term_changes_no_answer(planted, monkeypatch, n_terms, ladder):
+@pytest.mark.parametrize("n_terms,line", [(2, "own"), (2, "four"), (3, "own"),
+                                          (4, "own")])
+def test_the_lead_term_changes_no_answer(planted, monkeypatch, n_terms, line,
+                                         ladder):
     """Every phrase with a head term, launched over the rows the lead term
     leaves and launched again with every row listed: totals, documents and
     float32 scores bit for bit the same, both the host's. With the ladder cut
     to 2 / 8 / 32 rows the two launches ride different rungs, so different
-    programs, and still agree."""
+    programs, and still agree; so do the pairs on their line of two slots
+    and on the line of four."""
     corpus, ref, ctx = planted
     if ladder is not None:
         monkeypatch.setattr(scoring, "PHRASE_RUNGS", ladder)
+    _ride(monkeypatch, line)
     texts = _phrases_of_a_head_term(planted, n_terms)
     assert len(texts) >= 10
     pruned = []
@@ -313,6 +499,10 @@ def test_the_lead_term_changes_no_answer(planted, monkeypatch, n_terms, ladder):
         matched += dev.total
     assert skipped > 0 and matched > 0
     monkeypatch.setattr(PositionsPlane, "rows_holding", _every_row)
+    if ladder is not None:
+        # every row of a head term passes a tile; a ladder of two rungs cuts
+        # nothing, so the whole list rides ONE launch of the last rung
+        monkeypatch.setattr(scoring, "PHRASE_RUNGS", (ladder[0], ladder[-1]))
     for text, dev in zip(texts, pruned):
         before = scoring.LAUNCHES.snapshot()
         whole, _host = _both(ctx, _phrase(text))
@@ -321,13 +511,18 @@ def test_the_lead_term_changes_no_answer(planted, monkeypatch, n_terms, ladder):
         assert after["phrase"] == before["phrase"] + 1
         assert whole.total == dev.total
         assert whole.hits == dev.hits  # ids and float32 scores, bit for bit
+    if n_terms == 2 and line == "own":  # every row listed, on the line of four
+        _same_on_the_line_of_four(monkeypatch, ctx,
+                                  [_phrase(text) for text in texts], pruned)
 
 
 def test_the_counters_of_the_lead_term(planted):
     """Two head terms hold each other's documents in every row: nothing is
     skipped. Beside a rare term the launch names the rows that hold the rare
     term's documents, and `position_list_bytes` less `position_skip_bytes` is
-    those rows' bytes."""
+    those rows' bytes. What is gathered is the launched shape: one plan on
+    the first rung, two slots for a pair and four for a phrase of three, and
+    the padding is what of it no term named."""
     corpus, ref, ctx = planted
     (seg,) = ctx.searcher.segments
     plane = ensure_positions(seg, packed_for(seg), "body")
@@ -345,9 +540,14 @@ def test_the_counters_of_the_lead_term(planted):
         assert listed == whole * 512
         assert listed - skipped == named * 512
         assert (skipped > 0) is skips
-        assert after["position_bytes"] - before["position_bytes"] - (
-            after["position_pad_bytes"] - before["position_pad_bytes"]) == \
-            named * 512
+        slots = _slots_of([text])
+        gathered = after["position_bytes"] - before["position_bytes"]
+        assert gathered == slots * scoring.PHRASE_RUNGS[0] * 512
+        assert after["position_pad_bytes"] - before["position_pad_bytes"] == \
+            gathered - named * 512
+        assert after["phrase"] - before["phrase"] == 1
+        assert after["phrase_pair_launches"] - before["phrase_pair_launches"] \
+            == int(slots == 2)
 
 
 def test_a_phrase_beside_plain_and_filtered_plans_in_one_batch(planted):
@@ -395,14 +595,17 @@ def hand(request, tmp_path_factory):
 
 
 @pytest.mark.parametrize("text", PHRASES)
-def test_edge_cases_answer_as_the_host(hand, text):
+def test_edge_cases_answer_as_the_host(hand, monkeypatch, text):
     """Repeated terms, an absent term (no match), a phrase that would straddle
-    two documents or two values (no match), three segments; BM25 and TF-IDF."""
+    two documents or two values (no match), three segments; BM25 and TF-IDF.
+    A pair's line of two slots answers as the line of four."""
     _eng, ctx = hand
     c = ctx()
     assert len(c.searcher.segments) == 3
     dev, host = _both(c, _phrase(text))
     _same(dev, host, rtol=0)  # the host's own float operations: bitwise
+    if len(text.split()) == 2 and text != "a nosuch":
+        _same_on_the_line_of_four(monkeypatch, c, [_phrase(text)], [dev])
     if text == "a nosuch":
         assert dev.total == 0
     if text == "d a":  # inside document 4; never from document 6 into 7
@@ -411,8 +614,10 @@ def test_edge_cases_answer_as_the_host(hand, text):
         assert [d for _s, d in dev.hits] == [0, 4]
 
 
-def test_deletes_and_a_delta_segment(tmp_path):
+@pytest.mark.parametrize("line", LINES)
+def test_deletes_and_a_delta_segment(tmp_path, monkeypatch, line):
     eng, ctx = _shard(tmp_path, HAND, refresh_at=(20,))
+    _ride(monkeypatch, line)
     q = _phrase("a b")
     c = c_old = ctx()
     dev, host = _both(c, q)
@@ -454,12 +659,15 @@ def test_deletes_and_a_delta_segment(tmp_path):
     assert dev3.total == dev2.total + 1
 
 
-def test_a_deleted_lead_document(tmp_path):
+@pytest.mark.parametrize("line", LINES)
+def test_a_deleted_lead_document(tmp_path, monkeypatch, line):
     """`c` leads `c d` (documents 0, 4, 5 of the first segment). With document
     4 deleted its rows are still named (the lead's postings keep a tombstone,
     a superset is enough), its marker carries the dead code and it matches
-    nothing; the re-mask leaves the rows' bounds as they were."""
+    nothing; the re-mask leaves the rows' bounds as they were. On the pair's
+    line of two slots and on the line of four."""
     eng, ctx = _shard(tmp_path, HAND, refresh_at=(20,))
+    _ride(monkeypatch, line)
     q = _phrase("c d")
     c = ctx()
     dev, host = _both(c, q)
@@ -470,9 +678,15 @@ def test_a_deleted_lead_document(tmp_path):
     eng.delete("doc", "4")
     eng.refresh()
     c = ctx()
+    pairs = scoring.LAUNCHES.snapshot()["phrase_pair_launches"]
     dev2, host2 = _both(c, q)
     _same(dev2, host2, rtol=0)
     assert sorted(d for _s, d in dev2.hits) == [0, 5]
+    # a launch a segment that holds both terms, every one the pair's own line
+    assert scoring.LAUNCHES.snapshot()["phrase_pair_launches"] - pairs == \
+        (1 if line == "own" else 0)
+    if line == "own":
+        _same_on_the_line_of_four(monkeypatch, c, [q], [dev2])
     seg2 = c.searcher.segments[0]
     plane2 = packed_for(seg2).positions["body"]
     assert plane2 is not plane
@@ -481,11 +695,14 @@ def test_a_deleted_lead_document(tmp_path):
     assert 4 in seg2.postings("body", "c")[0]
 
 
-def test_a_document_that_straddles_block_rows_keeps_them_all(tmp_path):
+@pytest.mark.parametrize("line", LINES)
+def test_a_document_that_straddles_block_rows_keeps_them_all(
+        tmp_path, monkeypatch, line):
     """`x` fills nine block rows; `y`, the lead of `y x`, stands in two
     documents: one whose 300 occurrences of `x` and their marker lie across
     three rows, one whose 100 lie across two. The launch names exactly those
-    rows (the third is shared), in order, and every occurrence counts."""
+    rows (the third is shared), in order, and every occurrence counts, on the
+    pairs' line of two slots as on the line of four."""
     docs = [{"body": "x " * 50},
             {"body": "y " + "x " * 300},
             {"body": "x " * 20},
@@ -494,10 +711,16 @@ def test_a_document_that_straddles_block_rows_keeps_them_all(tmp_path):
     eng, ctx = _shard(tmp_path, docs)
     c = ctx()
     (seg,) = c.searcher.segments
+    _ride(monkeypatch, line)
     for text, total in (("y x", 2), ("x x", 7), ("x y x", 1), ("x y", 1)):
+        pairs = scoring.LAUNCHES.snapshot()["phrase_pair_launches"]
         dev, host = _both(c, _phrase(text))
         _same(dev, host, rtol=0)
         assert dev.total == total
+        assert scoring.LAUNCHES.snapshot()["phrase_pair_launches"] - pairs == \
+            int(line == "own" and _slots_of([text]) == 2)
+        if line == "own":
+            _same_on_the_line_of_four(monkeypatch, c, [_phrase(text)], [dev])
     plane = packed_for(seg).positions["body"]
     x = seg.term_id("body", "x")
     b0, b1 = plane.blocks_for_term(x)
@@ -526,6 +749,55 @@ def test_a_document_that_straddles_block_rows_keeps_them_all(tmp_path):
     assert rows(6) == (b0 + np.flatnonzero((held == 6).any(axis=1))).tolist()
     assert rows() == []
     assert rows(*range(7)) == list(range(b0, b1))
+
+
+@pytest.mark.parametrize("line", LINES)
+def test_tiles_keep_a_straddling_document_whole_and_a_deleted_one_out(
+        tmp_path, monkeypatch, line):
+    """The corpus of the test above with the ladder cut to 1 / 4 / 16 rows:
+    `x x` keeps all nine rows of `x` and is cut into tiles of four rows, whose
+    cuts fall where a row starts, inside documents that lie across rows: a
+    tile that starts at such a document names the rows before the cut that
+    hold its keys, so every occurrence counts once. Deleting a document takes
+    it out of its tile, and a delta segment is one more launch. A document
+    is never cut: with tiles of two rows document 1, three rows long, fits
+    none, and the host answers."""
+    docs = [{"body": "x " * 50},
+            {"body": "y " + "x " * 300},
+            {"body": "x " * 20},
+            {"body": "x y " + "x " * 99},
+            ] + [{"body": "x " * 200} for _ in range(3)]
+    eng, ctx = _shard(tmp_path, docs)
+    monkeypatch.setattr(scoring, "PHRASE_RUNGS", (1, 4, 16))
+    _ride(monkeypatch, line)
+    c = ctx()
+    for text, total in (("x x", 7), ("x x x", 7), ("x x x x", 7)):
+        before = scoring.LAUNCHES.snapshot()
+        dev, host = _both(c, _phrase(text))
+        after = scoring.LAUNCHES.snapshot()
+        _same(dev, host, rtol=0)
+        assert dev.total == total
+        assert after["phrase"] - before["phrase"] >= 2  # tiles
+        assert after["position_bytes"] - before["position_bytes"] == \
+            (after["phrase"] - before["phrase"]) \
+            * (2 if line == "own" and len(text.split()) == 2 else 4) * 4 * 512
+    with monkeypatch.context() as m:
+        m.setattr(scoring, "PHRASE_RUNGS", (1, 2, 16))
+        before = scoring.LAUNCHES.snapshot()
+        host = search_shard(c, _phrase("x x"), 10, use_device=True)
+        assert scoring.LAUNCHES.snapshot()["phrase"] == before["phrase"]
+        assert host.total == 7
+    eng.delete("doc", "1")
+    eng.delete("doc", "5")
+    eng.index("doc", "new", {"body": "y x x x x"})
+    eng.refresh()
+    c = ctx()
+    assert len(c.searcher.segments) == 2
+    for text, total in (("x x", 6), ("x x x", 6), ("x x x x", 6)):
+        dev, host = _both(c, _phrase(text))
+        _same(dev, host, rtol=0)
+        assert dev.total == total
+        assert not {1, 5} & {d for _s, d in dev.hits}
 
 
 def test_a_merged_segment_faults_its_own_plane(tmp_path):
@@ -799,8 +1071,11 @@ def test_a_match_phrase_over_rest_reaches_the_phrase_program(tmp_path):
         launch0, launch1 = (s["search_serving"]["launch"] for s in (s0, s1))
         assert launch1["phrase"] - launch0["phrase"] == 1
         assert launch1["phrase_searches"] - launch0["phrase_searches"] == 1
+        # a pair: a line of two slots of the first rung
+        assert launch1["phrase_pair_launches"] \
+            - launch0["phrase_pair_launches"] == 1
         assert launch1["position_bytes"] - launch0["position_bytes"] == \
-            scoring.PHRASE_SLOTS * scoring.PHRASE_RUNGS[0] * 128 * 4
+            2 * scoring.PHRASE_RUNGS[0] * 128 * 4
         assert s1["search_serving"]["device_sparse"] \
             - s0["search_serving"]["device_sparse"] == 1
         assert s1["search_serving"]["host"] == s0["search_serving"]["host"]

@@ -896,6 +896,9 @@ class TestLaunchTimeline:
             ["dispatch.stage", "dispatch.launch", "device_pull"]
         launch0, launch1 = before["launch"], after["launch"]
         assert launch1["phrase"] == launch0["phrase"] + 1
+        # two terms: the launch rode the line of two slots
+        assert launch1["phrase_pair_launches"] == \
+            launch0["phrase_pair_launches"] + 1
         assert launch1["phrase_searches"] == launch0["phrase_searches"] + 1
         assert launch1["position_bytes"] > launch0["position_bytes"]
         assert 0 < launch1["position_pad_bytes"] - launch0["position_pad_bytes"] \
