@@ -286,7 +286,7 @@ def _prometheus_text(node) -> str:
     w.counter("estpu_batcher_coalesced_total", bs["coalesced"])
     w.counter("estpu_batcher_bypassed_total", bs["bypassed"])
     w.counter("estpu_batcher_splits_total", bs["splits"])
-    for reason in ("full", "linger", "deadline", "pending"):
+    for reason in ("full", "linger", "deadline", "pending", "alone"):
         w.counter("estpu_batcher_flushes_total", bs[f"{reason}_flushes"],
                   reason=reason)
     w.gauge("estpu_batcher_queue", bs["queue"])

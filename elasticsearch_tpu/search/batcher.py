@@ -36,11 +36,16 @@ its request thread: profiled and DFS requests and a node without a batcher
 
 Flush policy — whichever fires first:
   * batch-full  : `search.batch.max_batch` same-key plans are waiting
+  * alone       : the drainer is idle and the batcher's own record says a
+                  linger buys (almost) no companion here and now — the head
+                  is taken at once, with whatever is queued beside it and
+                  without the floor of `min_linger_ms` (the record: below)
   * linger      : the oldest item has waited `linger_eff`, where
                   linger_eff = linger_ms * (1 - queued/max_batch), floored at
                   `search.batch.min_linger_ms` — a hot queue shrinks the
                   linger toward zero because latency is only spent when it
-                  buys occupancy; a lone request pays at most linger_ms
+                  buys occupancy; a lone request pays at most linger_ms, and
+                  pays it only while the record says lingers buy companions
   * deadline    : now >= tightest enqueued Deadline - EWMA(batch service
                   time) — flushing early leaves budget for the device launch
                   AND the host merge, so PR-3 timeout semantics survive
@@ -49,6 +54,24 @@ Flush policy — whichever fires first:
                   would hold its answered futures hostage to the NEXT batch's
                   linger window; with the device already busy, waiting buys
                   no occupancy, so the queue flushes immediately
+
+The linger is a bet, and the batcher keeps its score. For every head an idle
+drainer takes it counts the COMPANIONS: the items of the head's key enqueued
+before a lone head's linger would end (`linger_eff` at one item queued, from
+the head's own enqueue) and before the head is answered, counted where they
+arrive (`_submit`), whether the head waited for them or not. That reads
+arrivals, which the policy does not cause, and not the occupancy of the
+batches it made, which it does (a shorter linger makes smaller batches makes
+a shorter linger), and it needs no linger to be kept, so the policy cannot
+starve its own evidence. Over the last `_RECORD_HEADS` such heads the mean is
+what a linger buys (`stats()["linger_bought"]`, companions a head). While that
+stays at `_LONELY_BELOW` or above the queue lingers as described; once a FULL
+record reads less, a head that finds the drainer idle is flushed `alone`. A
+fresh batcher has no record and lingers: the bet stands until it is seen to
+lose. A burst on a lonely node sends its first search alone and the rest in
+one `pending` batch behind it, and is itself the evidence that turns the
+linger back on for the next head. `linger_ms: 0` never lingers and keeps no
+record.
 
 Double buffering: the drainer dispatches batch N+1 BEFORE merging batch N, so
 batch N's host merge overlaps batch N+1's device compute. The dispatch half
@@ -80,6 +103,20 @@ from ..common.metrics import HistogramMetric
 from ..ops.device_index import _ladder_bucket
 
 _K_MIN = 16  # smallest k bucket (top-10 pages and top-16 share executables)
+# the linger's record (module docstring): the heads it remembers, and the
+# companions a head under which a full record flushes a head `alone`. What the
+# cells read, companions a head: one client 0, Poisson at 62.5/s 0.09 (the 32
+# heads then hold 7 or more in 3% of the moments), a closed loop of eight 2-3
+# (its sets of four arrive together); `wiki.aggs` loses an eighth of its rate
+# where it never lingers and `passage.steady` gains 22% of its median (PERF.md
+# section 6, PR 48). 0.2 is one companion in five heads: far from both, and
+# under the 7 in 32 that ONE burst of eight leaves, so a burst turns the
+# linger back on for the next head. 32 heads are half a second of a lone
+# stream at 62.5/s: long enough that two neighbours inside one linger do not
+# tip it, short enough that a node that has gone quiet stops paying within a
+# second.
+_RECORD_HEADS = 32
+_LONELY_BELOW = 0.2
 # the kinds of launch /_nodes/stats tells apart under search.batcher.kinds:
 # execute.GROUP_KINDS' keys (tests/test_launch_seam.py holds this to them) and
 # the mesh family's
@@ -121,6 +158,22 @@ class _Item:
         # reads — the item's Future resolution is the happens-before edge
         # back to the reader
         self.obs = _insights.current()
+
+
+class _Line:
+    """The line of the linger's record that is still being counted: the head
+    an idle drainer took last (`t_head`, its t_enq, names it), the moment
+    until which an arrival of its `key` is a companion (the end of a lone
+    head's linger, or the head's answer where that came sooner), and the
+    companions so far. Read and written under the batcher's condition."""
+
+    __slots__ = ("key", "t_head", "t_end", "companions")
+
+    def __init__(self, key, t_head: float, t_end: float, companions: int):
+        self.key = key
+        self.t_head = t_head
+        self.t_end = t_end
+        self.companions = companions
 
 
 class _FlatFamily:
@@ -264,6 +317,15 @@ class DeviceBatcher:
         self._linger_flushes = 0
         self._deadline_flushes = 0
         self._pending_flushes = 0  # flushed early because a merge was waiting
+        self._alone_flushes = 0  # flushed at once: lingers buy nothing here
+        # the linger's record (module docstring), under _cv: the companions
+        # of each of the last heads an idle drainer took, and the line still
+        # being counted (_submit counts the arrivals, the next such head
+        # closes it). _linger_bought is the record's mean, written by the
+        # drainer and read unlocked by stats()
+        self._bought: deque[int] = deque(maxlen=_RECORD_HEADS)
+        self._line: _Line | None = None
+        self._linger_bought = 0.0
         self._bypassed = 0  # queue full / disabled / drainer dead -> inline
         # profiled requests bypass BEFORE enqueueing (service._execute_flat_
         # single: their per-request sync must not serialize a shared batch) —
@@ -347,6 +409,10 @@ class DeviceBatcher:
                 self._queue.append(item)
                 self._cv.notify_all()
                 inline = False
+                line = self._line
+                if (line is not None and item.t_enq <= line.t_end
+                        and item.key == line.key):
+                    line.companions += 1
         if inline:
             # a saturated coalescing queue must not become a second rejection
             # layer on top of the search pool's — serve directly instead
@@ -531,6 +597,10 @@ class DeviceBatcher:
         latency-for-occupancy trade buys nothing."""
         head = self._queue[0]
         key = head.key
+        # an idle drainer's head is one more line of the linger's record, and
+        # the record says whether this head lingers (linger_ms 0 keeps none)
+        lonely = (not urgent and self.linger_s > 0.0
+                  and self._lingers_buy_nothing(head))
         while True:
             same = [it for it in self._queue if it.key == key]
             n = len(same)
@@ -540,12 +610,11 @@ class DeviceBatcher:
             if urgent:
                 reason = "pending"
                 break
+            if lonely:
+                reason = "alone"
+                break
             now = time.monotonic()
-            # adaptive linger: shrinks linearly as the queue fills — waiting
-            # longer only pays when it buys occupancy
-            linger_eff = max(self.min_linger_s,
-                             self.linger_s * (1.0 - n / float(self.max_batch)))
-            flush_at = head.t_enq + linger_eff
+            flush_at = head.t_enq + self._linger_eff(n)
             reason = "linger"
             for it in same:
                 rem = it.deadline.remaining()
@@ -570,6 +639,30 @@ class DeviceBatcher:
         self._queue.clear()
         self._queue.extend(rest)
         return taken, reason
+
+    def _linger_eff(self, n: int) -> float:
+        """The adaptive linger of a head with `n` same-key items queued: it
+        shrinks linearly as the queue fills — waiting longer only pays when
+        it buys occupancy."""
+        return max(self.min_linger_s,
+                   self.linger_s * (1.0 - n / float(self.max_batch)))
+
+    def _lingers_buy_nothing(self, head: _Item) -> bool:
+        """Close the record's open line, open `head`'s, and say whether the
+        record as it now stands tells this head to go alone (module
+        docstring, "The linger is a bet"). Drainer only, with the condition
+        held. The new line starts with the companions already queued behind
+        the head; _submit counts the later ones, _finish ends the count
+        where the head is answered."""
+        if self._line is not None:
+            self._bought.append(self._line.companions)
+            self._linger_bought = sum(self._bought) / len(self._bought)
+        t_end = head.t_enq + self._linger_eff(1)
+        self._line = _Line(head.key, head.t_enq, t_end, sum(
+            1 for it in self._queue
+            if it is not head and it.key == head.key and it.t_enq <= t_end))
+        return (len(self._bought) == _RECORD_HEADS
+                and self._linger_bought < _LONELY_BELOW)
 
     def _finish(self, family, items, handle, t0: float, batch_id: int = 0,
                 t_disp: float | None = None):
@@ -635,6 +728,11 @@ class DeviceBatcher:
                 tally = self._kinds.setdefault(kind, [0, 0])
                 tally[0] += 1
                 tally[1] += members
+        line = self._line
+        if line is not None and line.t_head == items[0].t_enq:
+            # the head being counted is answered: its line counts no further
+            with self._cv:
+                line.t_end = min(line.t_end, t_m1)
         for it, res in zip(items, results):
             it.future.set_result(res)
         # everything since the dispatch tick — the merge, its bookkeeping and
@@ -682,6 +780,8 @@ class DeviceBatcher:
                 self._deadline_flushes += 1
             elif reason == "pending":
                 self._pending_flushes += 1
+            elif reason == "alone":
+                self._alone_flushes += 1
             else:
                 self._linger_flushes += 1
 
@@ -747,6 +847,10 @@ class DeviceBatcher:
                 "linger_flushes": self._linger_flushes,
                 "deadline_flushes": self._deadline_flushes,
                 "pending_flushes": self._pending_flushes,
+                "alone_flushes": self._alone_flushes,
+                # what a linger buys here and now: companions a head over the
+                # record's last heads (drainer-written, one float)
+                "linger_bought": round(self._linger_bought, 3),
                 "bypassed": self._bypassed,
                 "profile_bypassed": self._profile_bypassed,
                 "splits": self._splits,

@@ -21,7 +21,8 @@ from elasticsearch_tpu.common.settings import Settings
 from elasticsearch_tpu.index import Engine
 from elasticsearch_tpu.mapper import MapperService
 from elasticsearch_tpu.search import ShardContext, parse_query
-from elasticsearch_tpu.search.batcher import DeviceBatcher, _Item, _k_bucket
+from elasticsearch_tpu.search.batcher import (_RECORD_HEADS, DeviceBatcher, _Item,
+                                              _k_bucket)
 from elasticsearch_tpu.search.execute import execute_flat_batch, lower_flat
 from elasticsearch_tpu.search.similarity import SimilarityService
 
@@ -933,3 +934,168 @@ class TestKindsShareACollect:
             # of the four launches, in order, then one pull for them all
             assert kinds == ["dispatch.stage", "dispatch.launch"] * 4 \
                 + ["device_pull"], kinds
+
+
+# ---------------------------------------------------------------------------
+# the linger's record: a lone search does not wait for companions that never come
+# ---------------------------------------------------------------------------
+
+
+class _EchoFamily:
+    """Answers each item with its payload and keeps, a batch, its size and
+    its head's wait from enqueue to dispatch. Where `gate` is set a dispatch
+    stands in it until the test opens it (`entered` says one stands there)."""
+
+    name = "fake"
+
+    def __init__(self):
+        self.sizes: list = []
+        self.waits: list = []
+        self.gate = None
+        self.entered = threading.Event()
+
+    def dispatch(self, items, kb):
+        self.waits.append(time.monotonic() - items[0].t_enq)
+        self.sizes.append(len(items))
+        if self.gate is not None:
+            self.entered.set()
+            self.gate.wait(30)
+        return [it.payload for it in items]
+
+    def fan_out(self, handle, items):
+        return handle
+
+    def execute_single(self, item):
+        return item.payload
+
+
+def _echo_batcher(**flat):
+    """(batcher, its fake family, the reasons of its flushes in order)."""
+    b = make_batcher(**flat)
+    reasons: list = []
+    note = b._note_flush
+
+    def noting(reason):
+        reasons.append(reason)
+        note(reason)
+
+    b._note_flush = noting
+    return b, _EchoFamily(), reasons
+
+
+def _search(b, fam, payload="p"):
+    return b._submit(_Item(fam, ("fake", "key"), payload, 10, 16, NO_DEADLINE))
+
+
+def _together(b, fam, n):
+    threads = [threading.Thread(target=_search, args=(b, fam, i))
+               for i in range(n)]
+    for t in threads:
+        t.start()
+    return threads
+
+
+LINGER_S = 0.2
+
+
+@pytest.fixture(scope="module")
+def gone_lonely():
+    """A batcher under a linger of 200 ms after a stream of lone searches,
+    one at a time, eight more than its record holds: (batcher, family,
+    reasons, its stats there)."""
+    b, fam, reasons = _echo_batcher(**{"search.batch.linger_ms": LINGER_S * 1000})
+    for i in range(_RECORD_HEADS + 8):
+        assert _search(b, fam, i) == i
+    yield b, fam, reasons, b.stats()
+    b.shutdown()
+
+
+class TestLingerRecord:
+    def test_a_lone_stream_stops_paying_the_linger(self, gone_lonely):
+        _b, fam, reasons, st = gone_lonely
+        n = _RECORD_HEADS
+        # while the record fills, the bet stands: every head waits it out
+        assert reasons[:n] == ["linger"] * n
+        assert min(fam.waits[:n]) >= LINGER_S * 0.9
+        # a full record of heads that nobody joined: the later ones go at once
+        assert reasons[n:n + 8] == ["alone"] * 8
+        assert max(fam.waits[n:n + 8]) < LINGER_S / 4
+        assert st["linger_flushes"] == n and st["alone_flushes"] == 8, st
+        assert st["linger_bought"] == 0.0
+        assert fam.sizes[:n + 8] == [1] * (n + 8)
+
+    def test_a_burst_on_a_lonely_batcher_turns_the_linger_back_on(
+            self, gone_lonely):
+        b, fam, reasons, _st = gone_lonely
+        at = len(reasons)
+        fam.gate = threading.Event()
+        fam.entered.clear()
+        try:
+            threads = _together(b, fam, 1)
+            assert fam.entered.wait(10)  # the first of the burst, in dispatch
+            threads += _together(b, fam, 7)
+            t_end = time.monotonic() + 10
+            while b.stats()["queue"] < 7 and time.monotonic() < t_end:
+                time.sleep(0.001)
+        finally:
+            fam.gate.set()
+            fam.gate = None
+        for t in threads:
+            t.join(30)
+        # the first went alone, the seven behind it in ONE batch that waited
+        # for no linger: the drainer had a batch to merge
+        assert reasons[at:] == ["alone", "pending"]
+        assert fam.sizes[at:] == [1, 7]
+        # the head that did not wait was counted all the same, and the NEXT
+        # head finds a record that says lingers buy companions here
+        assert _search(b, fam) == "p"
+        assert reasons[at + 2:] == ["linger"]
+        assert fam.waits[-1] >= LINGER_S * 0.9
+        assert b.stats()["linger_bought"] == round(7 / _RECORD_HEADS, 3)
+
+    def test_groups_of_four_keep_their_batches(self):
+        """Four searches that arrive inside one linger, a hundred times over:
+        each head's line reads three companions, so the record never tips
+        and the four keep leaving as one batch."""
+        b, fam, reasons = _echo_batcher(**{"search.batch.linger_ms": 30})
+        rounds = 100
+        gate = threading.Barrier(4)
+
+        def client():
+            for _ in range(rounds):
+                gate.wait(30)
+                _search(b, fam)
+
+        try:
+            threads = [threading.Thread(target=client) for _ in range(4)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(120)
+            st = b.stats()
+        finally:
+            b.shutdown()
+        assert st["coalesced"] == 4 * rounds
+        assert st["occupancy_mean"] >= 3.5, st
+        assert st["alone_flushes"] == 0 and "alone" not in reasons, st
+        assert st["linger_bought"] >= 2.0, st
+
+    @pytest.mark.parametrize("linger_ms, searches", [
+        (40, 3),  # a fresh batcher has no record: the bet stands
+        (0, _RECORD_HEADS + 8),  # `linger_ms: 0` never lingers: no bet, no record
+    ])
+    def test_what_never_goes_alone(self, linger_ms, searches):
+        b, fam, reasons = _echo_batcher(**{"search.batch.linger_ms": linger_ms})
+        try:
+            for i in range(searches):
+                assert _search(b, fam, i) == i
+            st = b.stats()
+        finally:
+            b.shutdown()
+        assert reasons == ["linger"] * searches
+        assert st["alone_flushes"] == 0 and st["linger_flushes"] == searches
+        if linger_ms:
+            assert min(fam.waits) >= linger_ms / 1000.0 * 0.9
+        else:
+            assert max(fam.waits) < 0.05
+            assert len(b._bought) == 0 and st["linger_bought"] == 0.0

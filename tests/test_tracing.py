@@ -378,6 +378,12 @@ class TestLiveTraceTree:
             batcher_names = {c["name"] for c in shard["children"]}
             assert {"batcher.queue", "batcher.dispatch",
                     "batcher.merge"} <= batcher_names
+            # the queue span says why its batch was taken, one of the flush
+            # policy's reasons (search/batcher.py: full, alone, linger,
+            # deadline, pending); a pair that coalesced did not go `alone`
+            (queue,) = _find(shard, "batcher.queue")
+            assert queue["tags"]["reason"] in {"full", "linger", "deadline",
+                                               "pending"}
             (merge,) = _find(shard, "batcher.merge")
             assert [c["name"] for c in merge["children"]] == ["device_pull"]
             # every coalesced member carries the shared batch's device span
